@@ -2423,10 +2423,18 @@ def main():
         ap.error("--prefix-routing is its own comparison mode (real "
                  "processes; --sink-dir feeds the merged-trace block)")
 
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
+    from paddle_tpu.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     import jax
 
-    jax.config.update("jax_platforms", "cpu")
+    # runs on the backend jax finds (CI legs and tests that mean the CPU
+    # say JAX_PLATFORMS=cpu). The process-spawning modes are CPU
+    # harnesses by construction — tools/mp_mesh.py and serve_worker.py
+    # pin their children to the CPU, and a chip belongs to one process
+    # — so their parent stays on the CPU as well.
+    if args.hosts > 1 or args.elastic or args.prefix_routing:
+        jax.config.update("jax_platforms", "cpu")
 
     if args.live_status and not args.sink_dir and args.hosts <= 1:
         ap.error("--live-status tails a sink's telemetry frames — "
@@ -2489,6 +2497,9 @@ def main():
             "ticks": live_agg.status["tick"] if live_agg.status
             else 0,
             "mesh_status": live_agg.status}
+    from paddle_tpu.profiler.instrument import device_stamp
+
+    out.setdefault("extra", {})["device"] = device_stamp()
     print(json.dumps(out))
 
 

@@ -1,0 +1,635 @@
+"""The quickest proof that the system still starts on the chip.
+
+    python chip_smoke.py
+
+One process, no arguments. Drives the two normal entry points once at the
+full width of GPT-3 1.3B (h2048 x L24 x 16 heads, head_dim 128, seq 2048,
+vocab 50304; weights random from a seed) on whatever accelerator jax
+finds, and checks what comes out by the repo's own means:
+
+  0 device    a TPU that the one peak table knows; memory_stats reports;
+              every Pallas gate reads "compile with Mosaic"
+  1 kernels   flash fwd+bwd and the ragged paged kernel (bf16 and int8
+              pools), compiled by Mosaic, against their references
+  2 serve     ServingEngine.submit/step/run, ten requests over ~150 ticks
+  3 train     HybridPipelineTrainer.step, bench.py's headline knobs
+  4 multichip the same trainer on dp2 x tp2 and pp2 x tp2, and the ZeRO /
+              int8-ring arms of compile_train_step on dp=4 (only with
+              >= 4 devices)
+
+There is no CPU mode: with no accelerator it exits non-zero and prints no
+result. A failed phase does not stop the later ones, but the exit code is
+non-zero and the last line says which failed. The last line of stdout on
+success is ``{"ok": true, "device": {...}}``.
+
+What it prints are smoke observations, not benchmark results. The phase
+bodies take a model config and sizes so that tests/test_chip_smoke.py can
+rehearse them at toy sizes on the CPU mesh.
+"""
+from __future__ import annotations
+
+import gc
+import json
+import os
+import sys
+import time
+import traceback
+import warnings
+
+import numpy as np
+
+# normalised max error |a - ref|_inf / |ref|_inf allowed between a Pallas
+# kernel and its reference evaluated in f32 at "highest" matmul precision
+# on the same inputs. The kernels round probabilities and outputs to bf16
+# (2^-8 relative) and accumulate in f32; backward rounds ds to bf16 too.
+TOL_FLASH_FWD = 2e-2
+TOL_FLASH_BWD = 3e-2
+TOL_RAGGED = 2e-2
+
+#: (arrival tick, prompt length, new tokens, length of the prefix shared
+#: with the other requests that name one). Ten requests on eight slots:
+#: admission, chunked prefill (32 tokens a tick, so 1500 is 47 chunks),
+#: mixed and decode-only ticks, prefix aliasing (request 7 arrives after
+#: request 1 published its 256-token prefix) and slot reuse all happen.
+SERVE_REQUESTS = (
+    (0, 16, 32, 0), (0, 300, 48, 256), (0, 1500, 32, 0), (3, 64, 64, 0),
+    (5, 130, 40, 0), (8, 700, 32, 0), (12, 37, 33, 0), (90, 356, 48, 256),
+    (100, 20, 32, 0), (110, 512, 32, 0))
+
+
+class SmokeFailure(AssertionError):
+    """A check of this script that did not hold."""
+
+
+def check(cond, msg: str) -> None:
+    if not cond:
+        raise SmokeFailure(msg)
+
+
+def say(phase: str, msg: str) -> None:
+    print(f"[{phase}] {msg}", flush=True)
+
+
+def memory_stat(key: str):
+    import jax
+
+    stats = jax.local_devices()[0].memory_stats()
+    return None if not stats else stats.get(key)
+
+
+def peak_bytes():
+    return memory_stat("peak_bytes_in_use")
+
+
+def _gb(n) -> str:
+    return "n/a" if n is None else f"{n / 1e9:.2f} GB"
+
+
+def on_default_platform(tree, what: str) -> None:
+    """Every array of ``tree`` lives on devices of the platform jax chose
+    (phase 0 has established that this is the TPU): core/place.py falls
+    back to "any device" quietly, and this is where that would show."""
+    import jax
+
+    want = jax.devices()[0].platform
+    for leaf in jax.tree_util.tree_leaves(tree):
+        got = {d.platform for d in leaf.devices()}
+        check(got == {want}, f"{what}: array on {got}, expected {want}")
+
+
+def release_device_memory() -> None:
+    """bench.py's release_hbm: reference cycles and the jit/executable
+    caches both pin device buffers."""
+    import jax
+
+    gc.collect()
+    jax.clear_caches()
+    gc.collect()
+
+
+# ---------------------------------------------------------------------------
+# phase 0: device
+# ---------------------------------------------------------------------------
+def phase_device(cache_dir: str) -> dict:
+    import importlib.metadata as md
+
+    import jax
+    import jaxlib
+
+    from paddle_tpu.ops import flash_attention as fa
+    from paddle_tpu.ops import paged_attention as pa
+    from paddle_tpu.profiler.instrument import device_stamp
+    from paddle_tpu.profiler.peaks import device_peak
+    from paddle_tpu.utils.compile_cache import cache_entries
+
+    devs = jax.devices()
+    dev = devs[0]
+    say("device", f"jax {jax.__version__} jaxlib {jaxlib.__version__} "
+        f"libtpu {md.version('libtpu')} python "
+        f"{sys.version.split()[0]}")
+    say("device", f"platform={dev.platform} kind={dev.device_kind} "
+        f"count={len(devs)}")
+    if dev.platform != "tpu":
+        sys.exit(f"chip_smoke: jax found no TPU (devices: {devs}); "
+                 "this script has no CPU mode")
+    peak = device_peak(dev)       # unknown device_kind raises
+    say("device", f"peak {peak.bf16_flops / 1e12:.0f} TFLOP/s bf16 "
+        f"({peak.source})")
+    stats = dev.memory_stats()
+    check(stats and "peak_bytes_in_use" in stats,
+          f"memory_stats() has no peak_bytes_in_use: {stats}")
+    say("device", f"memory_stats: bytes_limit={_gb(stats.get('bytes_limit'))}"
+        f" peak_bytes_in_use={_gb(stats['peak_bytes_in_use'])}")
+    check(not fa._interpret() and not pa._interpret(),
+          "a Pallas gate reads interpret mode on a TPU backend")
+    say("device", f"compile cache {cache_dir}: "
+        f"{cache_entries(cache_dir)} entries at start")
+    from paddle_tpu.core import native
+
+    came_with_tree = os.path.exists(native._SO)
+    say("device", "native runtime library: " + (
+        "absent (python fallbacks)" if not native.available()
+        else "came with the tree" if came_with_tree
+        else "built here from native/ by its Makefile")
+        + "; nothing below depends on it")
+    return device_stamp()
+
+
+# ---------------------------------------------------------------------------
+# phase 1: kernels against their references
+# ---------------------------------------------------------------------------
+def _nerr(a, ref) -> float:
+    a = np.asarray(a, np.float32)
+    ref = np.asarray(ref, np.float32)
+    return float(np.max(np.abs(a - ref)) / max(np.max(np.abs(ref)), 1e-30))
+
+
+def _mosaic_check(fn, args, interpret: bool, what: str) -> None:
+    """The lowered program holds a Mosaic call wherever the kernel's own
+    gate says it is not interpreted."""
+    import jax
+
+    if not interpret:
+        check("tpu_custom_call" in jax.jit(fn).lower(*args).as_text(),
+              f"{what}: no tpu_custom_call in the lowered program")
+
+
+def check_flash(b: int, s: int, h: int, d: int, dtype) -> dict:
+    """Flash fwd+bwd at [b, s, h, d] against ``mha_reference`` evaluated
+    in f32 at highest precision on the same (dtype-rounded) inputs."""
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.ops import flash_attention as fa
+
+    ks = jax.random.split(jax.random.PRNGKey(0), 4)
+    q, k, v, w = (jax.random.normal(kk, (b, s, h, d), jnp.float32)
+                  .astype(dtype) for kk in ks)
+
+    def kernel(q_, k_, v_):
+        return fa._flash_mha(q_, k_, v_, True, None)
+
+    def loss_of(f):
+        return lambda q_, k_, v_: jnp.sum(
+            f(q_, k_, v_).astype(jnp.float32) * w.astype(jnp.float32))
+
+    def reference(q_, k_, v_):
+        return fa.mha_reference(q_, k_, v_, causal=True)
+
+    _mosaic_check(jax.grad(loss_of(kernel), (0, 1, 2)), (q, k, v),
+                  fa._interpret(), "flash fwd+bwd")
+    out = jax.jit(kernel)(q, k, v)
+    grads = jax.jit(jax.grad(loss_of(kernel), (0, 1, 2)))(q, k, v)
+    f32 = [x.astype(jnp.float32) for x in (q, k, v)]
+    with jax.default_matmul_precision("highest"):
+        ref = jax.jit(reference)(*f32)
+        ref_g = jax.jit(jax.grad(loss_of(reference), (0, 1, 2)))(*f32)
+    jax.block_until_ready((out, grads, ref, ref_g))
+    errs = {"fwd": _nerr(out, ref)}
+    errs.update({n: _nerr(g, rg)
+                 for n, g, rg in zip(("dq", "dk", "dv"), grads, ref_g)})
+    check(np.isfinite(list(errs.values())).all(), f"flash non-finite {errs}")
+    check(errs["fwd"] <= TOL_FLASH_FWD, f"flash fwd error {errs}")
+    check(max(errs["dq"], errs["dk"], errs["dv"]) <= TOL_FLASH_BWD,
+          f"flash bwd error {errs}")
+    return errs
+
+
+def check_ragged(num_pages: int, ps: int, nh: int, hd: int, nps: int,
+                 t: int, int8: bool) -> float:
+    """``ragged_paged_attention(impl="pallas")`` against ``impl="xla"``
+    in f32 on the same pools: four rows of ``t`` queries with ragged
+    pos0/true_len, null-page table entries behind each row's frontier,
+    and one row that ends exactly at the slot capacity ``nps * ps``.
+    Only real queries (index < true_len) are compared."""
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.ops import paged_attention as pa
+
+    cap = nps * ps
+    rng = np.random.RandomState(1)
+    true_len = np.array([t, max(1, t // 2 + 1), 1, t], np.int32)
+    pos0 = np.array([cap - t, ps + 3, 0, 5 * ps - 1], np.int32)
+    pos0 = np.minimum(pos0, cap - true_len).astype(np.int32)
+    r = len(pos0)
+    table = np.zeros((r, nps), np.int32)          # 0 = null page
+    free = rng.permutation(np.arange(1, num_pages))
+    used = 0
+    for i in range(r):
+        n = -(-int(pos0[i] + true_len[i]) // ps)
+        table[i, :n] = free[used:used + n]
+        used += n
+    check(used <= num_pages - 1, "ragged check: pool too small")
+    kq, kk, kv = jax.random.split(jax.random.PRNGKey(2), 3)
+    q = jax.random.normal(kq, (r, t, nh, hd), jnp.float32).astype(
+        jnp.bfloat16)
+    shape = (num_pages, ps, nh, hd)
+    if int8:
+        k_pool = jax.random.randint(kk, shape, -127, 128, jnp.int8)
+        v_pool = jax.random.randint(kv, shape, -127, 128, jnp.int8)
+        sc = rng.uniform(0.5, 1.5, (2, num_pages, nh)) / 64.0
+        sc[:, 0] = 0.0                              # the null page's scale
+        scales = dict(k_scale=jnp.asarray(sc[0], jnp.float32),
+                      v_scale=jnp.asarray(sc[1], jnp.float32))
+    else:
+        k_pool = jax.random.normal(kk, shape, jnp.float32).astype(
+            jnp.bfloat16)
+        v_pool = jax.random.normal(kv, shape, jnp.float32).astype(
+            jnp.bfloat16)
+        scales = {}
+    meta = (jnp.asarray(table), jnp.asarray(pos0), jnp.asarray(true_len))
+
+    def kernel(q_, k_, v_):
+        return pa.ragged_paged_attention(q_, k_, v_, *meta, impl="pallas",
+                                         **scales)
+
+    _mosaic_check(kernel, (q, k_pool, v_pool), pa._interpret(),
+                  f"ragged T={t} int8={int8}")
+    out = jax.jit(kernel)(q, k_pool, v_pool)
+    with jax.default_matmul_precision("highest"):
+        ref = jax.jit(lambda q_, k_, v_: pa.ragged_paged_attention(
+            q_, k_, v_, *meta, impl="xla", **scales))(
+                q.astype(jnp.float32),
+                k_pool if int8 else k_pool.astype(jnp.float32),
+                v_pool if int8 else v_pool.astype(jnp.float32))
+    real = np.arange(t)[None, :] < true_len[:, None]          # [r, t]
+    out, ref = np.asarray(out, np.float32), np.asarray(ref, np.float32)
+    check(np.isfinite(out[real]).all(), "ragged kernel output not finite")
+    err = _nerr(out[real], ref[real])
+    check(err <= TOL_RAGGED,
+          f"ragged T={t} int8={int8}: error {err:.3e} > {TOL_RAGGED}")
+    return err
+
+
+def phase_kernels(flash_shape, pool_shape, nps: int, chunk: int) -> None:
+    import jax.numpy as jnp
+
+    t0 = time.perf_counter()
+    errs = check_flash(*flash_shape, jnp.bfloat16)
+    say("kernels", f"flash fwd+bwd {flash_shape} bf16 vs mha_reference: " +
+        " ".join(f"{k}={v:.2e}" for k, v in errs.items()) +
+        f" (tol {TOL_FLASH_FWD}/{TOL_FLASH_BWD})")
+    for int8 in (False, True):
+        for t in (1, chunk):
+            err = check_ragged(*pool_shape, nps, t, int8)
+            say("kernels", f"ragged pallas vs xla pools={pool_shape} "
+                f"{'int8+scales' if int8 else 'bf16'} T={t}: "
+                f"err={err:.2e} (tol {TOL_RAGGED})")
+    say("kernels", f"{time.perf_counter() - t0:.1f} s, "
+        f"peak so far {_gb(peak_bytes())}")
+
+
+# ---------------------------------------------------------------------------
+# phase 2: serve
+# ---------------------------------------------------------------------------
+def phase_serve(cfg, num_slots: int, page_size: int, requests) -> dict:
+    import jax
+    import jax.numpy as jnp
+
+    import paddle_tpu as paddle
+    from paddle_tpu.models import GPT
+    from paddle_tpu.profiler import recompile, registry
+    from paddle_tpu.serving import ServingConfig, ServingEngine
+
+    reg = registry()
+
+    def counter(name):
+        return reg.counter("serving/" + name).value
+
+    t_setup = time.perf_counter()
+    paddle.seed(0)
+    net = GPT(cfg)
+    net.eval()
+    net.bfloat16()
+    eng = ServingEngine(net, ServingConfig(num_slots=num_slots,
+                                           page_size=page_size))
+    on_default_platform((eng._stacked, eng._other, eng.pool.k, eng.pool.v),
+                        "serving state")
+    setup_s = time.perf_counter() - t_setup
+
+    rng = np.random.RandomState(7)
+    shared = rng.randint(0, cfg.vocab_size,
+                         max(r[3] for r in requests)).astype(np.int32)
+    prompts = []
+    for _, n, _, pre in requests:
+        p = rng.randint(0, cfg.vocab_size, n).astype(np.int32)
+        p[:pre] = shared[:pre]
+        prompts.append(p)
+
+    base = {n: counter(n) for n in ("ticks", "prefix_hit_tokens",
+                                    "tokens_generated", "prefill_chunks")}
+    pending = sorted(range(len(requests)), key=lambda i: requests[i][0])
+    rids = {}
+    kinds = {"mixed": 0, "decode_only": 0, "prefill_only": 0}
+    tick, first_tick_s = 0, None
+    with warnings.catch_warnings(record=True) as caught:
+        warnings.simplefilter("always")
+        t_run = time.perf_counter()
+        while pending or not eng.idle():
+            while pending and requests[pending[0]][0] <= tick:
+                i = pending.pop(0)
+                rids[i] = eng.submit(prompts[i], requests[i][2])
+            if eng.step():
+                dec = reg.gauge("serving/mixed_rows_decode").value
+                pre = reg.gauge("serving/mixed_rows_prefill").value
+                kinds["mixed" if dec and pre else
+                      "decode_only" if dec else "prefill_only"] += 1
+            else:
+                eng.drain(0)
+            if first_tick_s is None:
+                # the one tick program's compile sits in this first sync
+                eng.drain(0)
+                first_tick_s = time.perf_counter() - t_run
+            tick = requests[pending[0]][0] if pending and eng.idle() \
+                else tick + 1
+            check(tick < 100_000, "the engine stopped making progress")
+        results = eng.run()              # nothing is left: the results
+        jax.block_until_ready((eng.pool.k, eng.pool.v))
+        run_s = time.perf_counter() - t_run
+    donation = [str(w.message) for w in caught
+                if "donated buffers were not usable" in str(w.message)]
+    check(not donation, f"the tick's pool donation was declined: "
+          f"{donation[:1]}")
+
+    for i, (_, _, want, _) in enumerate(requests):
+        out = results.get(rids[i])
+        check(out is not None, f"request {i} did not finish")
+        check(len(out) == want, f"request {i}: {len(out)} tokens, "
+              f"asked for {want}")
+        check(((out >= 0) & (out < cfg.vocab_size)).all(),
+              f"request {i}: token id out of range")
+    hits = counter("prefix_hit_tokens") - base["prefix_hit_tokens"]
+    check(hits > 0, "serving/prefix_hit_tokens did not move")
+    check(len(requests) <= num_slots or kinds["mixed"] > 0,
+          f"no mixed tick happened: {kinds}")
+    check(kinds["decode_only"] > 0, f"no decode-only tick happened: {kinds}")
+    traces = recompile.trace_counts()
+    check(len(eng.compiled_sites) == 1
+          and traces.get(eng.compiled_sites[0]) == 1,
+          f"the tick is not one site traced once: "
+          f"{[(s, traces.get(s)) for s in eng.compiled_sites]}")
+    on_default_platform((eng.pool.k, eng.pool.v), "page pools after the run")
+
+    # one request's greedy tokens against the dense generate() path
+    probe = min(range(len(requests)), key=lambda i: requests[i][1])
+    t_dense = time.perf_counter()
+    dense, _ = net.generate(jnp.asarray(prompts[probe])[None],
+                            max_new_tokens=requests[probe][2])
+    dense = np.asarray(dense._value)[0]
+    dense_s = time.perf_counter() - t_dense
+    paged = results[rids[probe]]
+    match = int(np.sum(dense == paged))
+    check(dense[0] == paged[0], f"first greedy token differs: dense "
+          f"{dense[0]} vs paged {paged[0]}")
+
+    out = {"setup_s": setup_s, "first_tick_s": first_tick_s, "run_s": run_s,
+           "ticks": int(counter("ticks") - base["ticks"]),
+           "tokens": int(counter("tokens_generated")
+                         - base["tokens_generated"]),
+           "prefill_chunks": int(counter("prefill_chunks")
+                                 - base["prefill_chunks"]),
+           "prefix_hit_tokens": int(hits), "tick_kinds": kinds,
+           "dense_match": f"{match}/{len(paged)}", "dense_s": dense_s,
+           "peak_bytes": peak_bytes()}
+    say("serve", f"{len(requests)} requests on {num_slots} slots, all "
+        f"finished; set-up {setup_s:.1f} s, first tick (compile) "
+        f"{first_tick_s:.1f} s, run {run_s:.1f} s wall to "
+        f"block_until_ready")
+    say("serve", f"ticks={out['ticks']} {kinds} chunks="
+        f"{out['prefill_chunks']} tokens={out['tokens']} "
+        f"prefix_hit_tokens={out['prefix_hit_tokens']} sites="
+        f"{eng.compiled_sites} traced once, no donation warning")
+    say("serve", f"greedy paged vs dense generate() (prompt "
+        f"{requests[probe][1]}): {out['dense_match']} tokens equal, first "
+        f"equal; dense compile+run {dense_s:.1f} s; peak so far "
+        f"{_gb(out['peak_bytes'])}")
+    return out
+
+
+# ---------------------------------------------------------------------------
+# phases 3 and 4: the hybrid trainer
+# ---------------------------------------------------------------------------
+def flash_in_program(tr, tokens, what: str) -> None:
+    """The step program handed to XLA (a diagnostic re-lowering) holds the
+    Pallas flash kernel, not the jnp path of nn/functional/attention.py.
+    An interpreted kernel is plain HLO, so there only the lowering runs."""
+    from paddle_tpu.ops import flash_attention as fa
+
+    text = tr.aot_lower(tokens).as_text()
+    check(fa._interpret() or "tpu_custom_call" in text,
+          f"{what}: no Pallas flash kernel in the step program")
+
+
+def train_steps(cfg, mesh_axes: dict, devices, micro: int, n_micro: int,
+                steps: int) -> dict:
+    """``steps`` steps of ``HybridPipelineTrainer`` with bench.py's
+    headline knobs (bf16 params and moments, recompute, free_eager) on a
+    fixed batch, each ended by block_until_ready. Returns losses, times,
+    the trainer's state arrays and whether the step program holds the
+    Pallas flash kernel."""
+    import jax
+
+    import paddle_tpu as paddle
+    from paddle_tpu.distributed.fleet import DistributedStrategy
+    from paddle_tpu.distributed.hybrid import HybridPipelineTrainer
+    from paddle_tpu.distributed.mesh import create_mesh
+    from paddle_tpu.models import GPT
+    from paddle_tpu.profiler import recompile
+
+    paddle.seed(0)
+    model = GPT(cfg)
+    opt = paddle.optimizer.AdamW(1e-4, parameters=model.parameters())
+    s = DistributedStrategy()
+    s.amp = True
+    s.recompute = True
+    axes = {"dp": 1, "pp": 1, "tp": 1, "sp": 1, **mesh_axes}
+    mesh = create_mesh(axes, list(devices))
+    tr = HybridPipelineTrainer(model, opt, s, mesh, n_micro=n_micro,
+                               param_dtype="bfloat16",
+                               moment_dtype="bfloat16", free_eager=True)
+    built_peak = peak_bytes()    # f32 eager init + the trainer's copies
+    seq = cfg.max_seq_len
+    tokens = np.random.RandomState(0).randint(
+        0, cfg.vocab_size, (micro * n_micro, seq)).astype(np.int32)
+    losses, times = [], []
+    for _ in range(steps):
+        t0 = time.perf_counter()
+        loss = jax.block_until_ready(tr.step(tokens))
+        times.append(time.perf_counter() - t0)
+        losses.append(float(loss))
+    what = f"train {mesh_axes or '1 chip'}"
+    check(np.isfinite(losses).all(), f"{what}: loss not finite {losses}")
+    check(losses[-1] < losses[0], f"{what}: loss not descending {losses}")
+    check(recompile.trace_counts().get(tr._prof_site) == 1,
+          f"{what}: the step retraced: "
+          f"{recompile.trace_counts().get(tr._prof_site)} traces")
+    state = (tr.block_vals, tr.other_vals, tr.block_opt, tr.other_opt)
+    on_default_platform(state, what)
+    flash_in_program(tr, tokens, what)     # after the retrace check
+    return {"losses": losses, "times": times, "state": state,
+            "tokens_per_step": int(tokens.size), "built_peak": built_peak}
+
+
+def phase_train(cfg, micro: int, n_micro: int, steps: int) -> dict:
+    import jax
+
+    r = train_steps(cfg, {}, jax.devices()[:1], micro, n_micro, steps)
+    steady = min(r["times"][1:]) if len(r["times"]) > 1 else r["times"][0]
+    say("train", f"{steps} steps x {r['tokens_per_step']} tokens, losses "
+        + " ".join(f"{x:.4f}" for x in r["losses"]))
+    say("train", f"first step (compile) {r['times'][0]:.1f} s, later steps "
+        + " ".join(f"{x:.2f}" for x in r["times"][1:])
+        + f" s (best {steady:.2f} s), Pallas flash in the program, one "
+        f"trace; peak so far {_gb(r['built_peak'])} once the trainer was "
+        f"built, {_gb(peak_bytes())} after the steps")
+    return {"loss0": r["losses"][0]}
+
+
+def _distinct_devices(tree) -> int:
+    import jax
+
+    return len({s.device for leaf in jax.tree_util.tree_leaves(tree)
+                for s in leaf.addressable_shards})
+
+
+def phase_multichip(cfg, micro: int, n_micro: int, loss0: float,
+                    zero_cfg, zero_batch: int) -> None:
+    """Four chips: the trainer on dp2 x tp2 (pp = 1) and pp2 x tp2, then
+    compile_train_step on dp=4 replicated / ZeRO-1 f32 ring / ZeRO-2 int8
+    ring."""
+    import jax
+
+    import paddle_tpu as paddle
+    from paddle_tpu.distributed.fleet import DistributedStrategy
+    from paddle_tpu.distributed.mesh import create_mesh
+    from paddle_tpu.distributed.strategy_compiler import compile_train_step
+    from paddle_tpu.models import GPT
+
+    devs = jax.devices()[:4]
+    for axes in ({"dp": 2, "tp": 2}, {"pp": 2, "tp": 2}):
+        r = train_steps(cfg, axes, devs, micro, n_micro, steps=2)
+        rel = abs(r["losses"][0] - loss0) / abs(loss0)
+        check(rel < 0.02, f"train {axes}: step-0 loss {r['losses'][0]} vs "
+              f"one chip {loss0} ({rel:.3%})")
+        params, opt_state = r["state"][:2], r["state"][2:]
+        n_p, n_o = _distinct_devices(params), _distinct_devices(opt_state)
+        check(n_p == 4 and n_o == 4, f"train {axes}: state on {n_p}/{n_o} "
+              "devices, expected 4")
+        say("multichip", f"{axes}: losses "
+            + " ".join(f"{x:.4f}" for x in r["losses"])
+            + f" (step 0 within {rel:.2%} of one chip), first step "
+            f"{r['times'][0]:.1f} s, second {r['times'][1]:.2f} s, params "
+            f"and optimizer shards on {n_p} devices")
+        del r, params, opt_state
+        release_device_memory()
+
+    tokens = np.random.RandomState(0).randint(
+        0, zero_cfg.vocab_size,
+        (zero_batch, zero_cfg.max_seq_len)).astype(np.int32)
+    ledgers = {}
+    for name, stage, comm in (("replicated", 0, "f32"),
+                              ("zero1_f32_ring", 1, "f32"),
+                              ("zero2_int8_ring", 2, "int8")):
+        paddle.seed(3)
+        net = GPT(zero_cfg)
+        opt = paddle.optimizer.AdamW(2e-3, parameters=net.parameters())
+        s = DistributedStrategy()
+        if stage:
+            s.sharding = True
+            s.sharding_configs = {"sharding_stage": stage}
+        tr = compile_train_step(net, opt, s, create_mesh({"dp": 4}, devs),
+                                dp_grad_comm=comm, dp_grad_block=512)
+        losses = [float(jax.block_until_ready(tr.step(tokens)))
+                  for _ in range(2)]
+        check(np.isfinite(losses).all() and losses[1] < losses[0],
+              f"{name}: losses {losses}")
+        on_default_platform((tr.params, tr.opt_states), name)
+        check(_distinct_devices(tr.params) == 4,
+              f"{name}: params not on 4 devices")
+        flash_in_program(tr, tokens, f"dp=4 {name}")
+        ledgers[name] = tr.memory_ledger()["opt_state"]
+        say("multichip", f"dp=4 {name}: losses {losses[0]:.4f} "
+            f"{losses[1]:.4f}, Pallas flash in the program, opt_state "
+            f"{ledgers[name]} bytes per rank")
+    for name in ("zero1_f32_ring", "zero2_int8_ring"):
+        ratio = ledgers[name] / ledgers["replicated"]
+        check(0.2 < ratio < 0.3, f"{name}: opt_state {ratio:.3f} of "
+              "replicated, expected about 1/4")
+
+
+# ---------------------------------------------------------------------------
+def main() -> int:
+    from paddle_tpu.utils.compile_cache import (cache_entries,
+                                                enable_compile_cache)
+
+    cache_dir = enable_compile_cache()
+    t_start = time.perf_counter()
+    device = phase_device(cache_dir)         # exits when there is no TPU
+
+    import jax
+
+    from paddle_tpu.models import GPTConfig
+
+    cfg = GPTConfig.gpt3_1_3b()
+    heads, head_dim = cfg.num_heads, cfg.hidden_size // cfg.num_heads
+    page, slots = 16, 8
+    nps = cfg.max_seq_len // page
+    failed, seen = [], {}
+
+    def run(name, fn):
+        t0 = time.perf_counter()
+        say(name, f"start, {_gb(memory_stat('bytes_in_use'))} in use")
+        try:
+            seen[name] = fn()
+        except Exception:       # reported, and carried by the exit code
+            traceback.print_exc()
+            failed.append(name)
+            say(name, "FAILED")
+        release_device_memory()
+        say(name, f"phase wall {time.perf_counter() - t0:.1f} s")
+
+    run("kernels", lambda: phase_kernels(
+        (2, cfg.max_seq_len, heads, head_dim),
+        (slots * nps + 1, page, heads, head_dim), nps, chunk=2 * page))
+    run("serve", lambda: phase_serve(cfg, slots, page, SERVE_REQUESTS))
+    run("train", lambda: phase_train(cfg, micro=2, n_micro=6, steps=4))
+    if len(jax.devices()) >= 4 and "train" not in failed:
+        run("multichip", lambda: phase_multichip(
+            cfg, 2, 6, seen["train"]["loss0"],
+            GPTConfig(vocab_size=512, hidden_size=512, num_layers=4,
+                      num_heads=4, max_seq_len=128), zero_batch=8))
+    else:
+        say("multichip", f"{len(jax.devices())} device, not run"
+            if len(jax.devices()) < 4 else "not run: train failed")
+
+    say("done", f"total {time.perf_counter() - t_start:.1f} s, compile "
+        f"cache now {cache_entries(cache_dir)} entries, peak "
+        f"{_gb(peak_bytes())}; smoke observations, not benchmark results")
+    print(json.dumps({"ok": not failed, "device": device,
+                      **({"failed": failed} if failed else {})}))
+    return 1 if failed else 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

@@ -1,7 +1,8 @@
 """Train GPT-3 1.3B on ONE 16 GB TPU v5e chip, from an on-disk corpus.
 
-The memory recipe (distributed/hybrid.py knobs; measured MFU 0.57 =
-12.4k tokens/s on a v5e, BENCH_r03):
+The memory recipe (distributed/hybrid.py knobs; the MFU quoted for it
+earlier was measured in an earlier environment, not reproduced; see the
+ledger once there is one — chip_smoke.py phase 3 runs the same recipe):
   - bf16 master params + bf16 AdamW moments resident in HBM
     (param_dtype / moment_dtype),
   - full per-block rematerialization (strategy.recompute),

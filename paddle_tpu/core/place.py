@@ -69,12 +69,15 @@ _expected_place: Optional[Place] = None
 
 def target_platform() -> str:
     """Platform the current computation is being COMPILED FOR — not the
-    process's default backend. AOT lowering against a TPU topology
-    (jax.experimental.topologies) happens in a CPU-only process; the
-    CPU-backend workarounds (bf16-collective promotion, pallas interpret
-    mode) must key off the target, or the AOT artifact would bake the
-    workarounds into the TPU program. Overridden by
-    PADDLE_TPU_TARGET_PLATFORM; defaults to jax.default_backend()."""
+    process's default backend. It is ``jax.default_backend()`` ("tpu" on
+    the chip: every Pallas gate then compiles with Mosaic), unless
+    PADDLE_TPU_TARGET_PLATFORM overrides it. The override is for AOT
+    lowering against a TPU topology (jax.experimental.topologies with
+    the bundled libtpu) from a CPU-only process, the pre-flight
+    tests/test_tpu_lowering.py runs: the CPU-backend workarounds
+    (bf16-collective promotion, pallas interpret mode) must key off the
+    target, or the AOT artifact would bake the workarounds into the TPU
+    program."""
     import os
 
     forced = os.environ.get("PADDLE_TPU_TARGET_PLATFORM")

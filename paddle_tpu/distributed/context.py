@@ -13,6 +13,7 @@ from __future__ import annotations
 import contextlib
 from typing import Optional, Tuple
 
+import jax
 from jax.sharding import Mesh
 
 _SP: Optional[Tuple[Mesh, str, bool]] = None
@@ -52,64 +53,73 @@ def current_sequence_parallel() -> Optional[Tuple[Mesh, str, bool]]:
     return _SP
 
 
-_PIPE_AUTO: Optional[Tuple[Mesh, Tuple[str, ...]]] = None
+_AUTO_AXES: Optional[Tuple[Mesh, Tuple[str, ...]]] = None
 
 
 @contextlib.contextmanager
-def pipeline_auto_axes_scope(mesh: Mesh, axes):
-    """Inside the pipeline's shard_map (manual over 'pp'), the remaining
-    mesh axes are GSPMD-auto. Mosaic (pallas) kernels cannot be
-    auto-partitioned in a *partially* manual region — XLA requires every
-    mesh axis manual around a Mosaic call — so kernels consult this scope
-    and open a nested shard_map over the listed axes (flash_attention.py).
-    CPU meshes never need it (interpret mode is plain HLO)."""
-    global _PIPE_AUTO
-    prev = _PIPE_AUTO
-    _PIPE_AUTO = (mesh, tuple(axes))
+def auto_axes_scope(mesh: Mesh, axes):
+    """Declares the axes of ``mesh`` that are still GSPMD-auto where the
+    model is being traced. XLA does not partition a Mosaic (Pallas)
+    call: in a jit region with any auto axis of more than one device —
+    fully auto as much as partially manual — lowering for a TPU fails
+    with "Mosaic kernels cannot be automatically partitioned. Please
+    wrap the call in a shard_map." So kernels consult this scope and
+    open a nested shard_map over the listed axes (flash_attention.py,
+    ring_attention.py). Trainers open it through ``kernel_scope``.
+    A CPU mesh never shows the refusal (interpret mode is plain HLO);
+    tests/test_tpu_lowering.py compiles for a v5e topology to see it."""
+    global _AUTO_AXES
+    prev = _AUTO_AXES
+    _AUTO_AXES = (mesh, tuple(axes))
     try:
         yield
     finally:
-        _PIPE_AUTO = prev
+        _AUTO_AXES = prev
 
 
-def current_pipeline_auto_axes() -> Optional[Tuple[Mesh, Tuple[str, ...]]]:
-    return _PIPE_AUTO
+def current_auto_axes() -> Optional[Tuple[Mesh, Tuple[str, ...]]]:
+    return _AUTO_AXES
 
 
-def in_partial_manual_region() -> bool:
-    """True when tracing inside a partially-manual region on a real
-    (non-interpret) target — the condition under which a Mosaic kernel
-    must be nested or avoided. One copy, consulted by both
-    flash_attention and ring_attention."""
+@contextlib.contextmanager
+def kernel_scope(mesh: Mesh):
+    """``auto_axes_scope`` over whatever axes of ``mesh`` are auto at
+    this point of the trace, read from jax's own context: every axis in
+    a plain jit, the axes a surrounding shard_map left auto inside one,
+    none (no scope) inside an all-manual region or on a one-device
+    mesh."""
+    am = jax.sharding.get_abstract_mesh()
+    manual = () if am.empty else am.manual_axes
+    axes = tuple(a for a in mesh.axis_names if a not in manual)
+    if mesh.size == 1 or not axes:
+        yield
+        return
+    with auto_axes_scope(mesh, axes):
+        yield
+
+
+def kernel_auto_axes() -> Optional[Tuple[Mesh, Tuple[str, ...]]]:
+    """(mesh, axes) a Mosaic kernel traced here has to make manual, or
+    None when it can be called as it is: no scope is open, or the
+    target is the CPU, where the kernel is interpreted into plain HLO.
+    One copy, consulted by flash_attention and ring_attention."""
     from ..core.place import target_platform
 
-    return _PIPE_AUTO is not None and target_platform() != "cpu"
+    if _AUTO_AXES is None or target_platform() == "cpu":
+        return None
+    return _AUTO_AXES
 
 
 def nested_kernel_shard(fn, in_specs, out_specs):
-    """Single shared implementation of the "make every axis manual around
-    a Mosaic kernel" rule (used by flash_attention and ring_attention —
-    one copy so the mesh-selection logic cannot drift): wraps ``fn`` in a
-    shard_map over the scope's remaining auto axes. Returns None when no
-    scope is active (fully-auto region — GSPMD handles the kernel
-    directly). Inside the pipeline's shard_map the context mesh is the
-    AbstractMesh with 'pp' already Manual — shard_map must receive that
-    mesh; fall back to the recorded concrete mesh otherwise."""
-    pa = current_pipeline_auto_axes()
-    if pa is None:
-        return None
-    mesh, axes = pa
-
-    try:
-        from jax.sharding import get_abstract_mesh
-
-        am = get_abstract_mesh()
-        use = am if (am is not None and getattr(am, "axis_names", ())) \
-            else mesh
-    except Exception:
-        use = mesh
-    from ._compat import shard_map
-
-    return shard_map(fn, mesh=use, in_specs=in_specs,
-                     out_specs=out_specs, axis_names=frozenset(axes),
-                     check_vma=False)
+    """Single shared implementation of the "make every axis manual
+    around a Mosaic kernel" rule: wraps ``fn`` in a shard_map over the
+    open scope's auto axes. Inside another shard_map (the pipeline's,
+    manual over 'pp') the context mesh is an AbstractMesh with those
+    axes already Manual, and the nested shard_map must be given that
+    mesh; in a plain jit there is none and the scope's concrete mesh is
+    used."""
+    mesh, axes = _AUTO_AXES
+    am = jax.sharding.get_abstract_mesh()
+    return jax.shard_map(fn, mesh=mesh if am.empty else am,
+                         in_specs=in_specs, out_specs=out_specs,
+                         axis_names=frozenset(axes), check_vma=False)

@@ -44,7 +44,6 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
 from ..profiler.trace import annotate as _annotate
-from ._compat import shard_map as _shard_map
 
 
 def stack_block_params(block_param_lists):
@@ -123,27 +122,12 @@ def pipeline_apply(mesh: Mesh, stage_fn: Callable, stacked_params: Any,
 
     param_specs = jax.tree_util.tree_map(
         lambda _: P(pp_axis), stacked_params)
-    # jax < 0.5 (no ``jax.shard_map``): the old experimental dialect
-    # cannot TRANSPOSE a partially-manual region (``auto=`` non-empty —
-    # the same limitation ring_attention works around), so the schedule
-    # goes manual over EVERY mesh axis there instead. The specs are
-    # unchanged: params stay split over 'pp' only, so the entry reshards
-    # replicate them over dp/tp and each of those ranks runs the stage
-    # redundantly — same math, gradient-exact (the transpose psums the
-    # replicated params' cotangents over the extra axes, measured exact
-    # against the modern partial-manual program), only the partitioning
-    # dialect differs. dp/tp parallelism inside the schedule is a
-    # modern-jax (GSPMD-auto) feature; on old jax it degrades to
-    # replication, never to wrong numbers.
-    legacy_all_manual = not hasattr(jax, "shard_map")
-    manual = None if legacy_all_manual else \
-        frozenset({pp_axis} if sp_axis is None else {pp_axis, sp_axis})
-    # params are pp-sharded but REPLICATED over sp (and over EVERY other
-    # axis in the legacy all-manual fallback): the shard_map transpose
-    # psums their cotangents over the replicated axes — promote that
-    # boundary too on CPU (same XLA:CPU bf16-collective crash as above;
-    # TPU unaffected).
-    param_f32 = boundary_f32 and (sp_axis is not None or legacy_all_manual)
+    manual = frozenset({pp_axis} if sp_axis is None else {pp_axis, sp_axis})
+    # params are pp-sharded but REPLICATED over sp: the shard_map
+    # transpose psums their cotangents over the replicated axis —
+    # promote that boundary too on CPU (same XLA:CPU bf16-collective
+    # crash as above; TPU unaffected).
+    param_f32 = boundary_f32 and sp_axis is not None
 
     def _pf(a):
         return a.astype(jnp.float32) if (param_f32
@@ -158,7 +142,7 @@ def pipeline_apply(mesh: Mesh, stage_fn: Callable, stacked_params: Any,
     # over outer-traced sharded values are rejected inside shard_map
     head_specs = jax.tree_util.tree_map(lambda _: P(), head_args)
 
-    @partial(_shard_map, mesh=mesh,
+    @partial(jax.shard_map, mesh=mesh,
              in_specs=(param_specs, x_spec, head_specs), out_specs=out_spec,
              check_vma=False, axis_names=manual)
     def pipelined(params, xs, head_args):

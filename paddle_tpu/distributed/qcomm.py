@@ -149,8 +149,6 @@ def dp_quantized_value_and_grads(mesh, axis_size: int, block: int,
     (loss, aux, grads)."""
     from jax.sharding import PartitionSpec as P
 
-    from ._compat import shard_map
-
     def body(rep, key_, *batch_):
         key_ = jax.random.fold_in(key_, jax.lax.axis_index("dp"))
         loss, aux, grads = fn(rep, key_, batch_)
@@ -163,10 +161,10 @@ def dp_quantized_value_and_grads(mesh, axis_size: int, block: int,
                                           block=block, mean=True)
         return loss, aux, grads
 
-    return shard_map(body, mesh=mesh,
-                     in_specs=(P(), P()) + tuple(batch_specs),
-                     out_specs=(P(), P(), P()),
-                     check_vma=False)(rep_args, key, *batch)
+    return jax.shard_map(body, mesh=mesh,
+                         in_specs=(P(), P()) + tuple(batch_specs),
+                         out_specs=(P(), P(), P()),
+                         check_vma=False)(rep_args, key, *batch)
 
 
 def dp_batch_specs(batch, dp: int):
@@ -434,8 +432,6 @@ def dp_zero_step(mesh, axis_size: int, block: int, grad_comm: str,
     """
     from jax.sharding import PartitionSpec as P
 
-    from ._compat import shard_map
-
     n = int(axis_size)
     leaves, treedef = jax.tree_util.tree_flatten(params)
     sizes = [int(jnp.size(l)) for l in leaves]
@@ -520,7 +516,7 @@ def dp_zero_step(mesh, axis_size: int, block: int, grad_comm: str,
     out_specs = (P(), P(), P(), P("dp"))
     if guard:
         out_specs = out_specs + (P(),)
-    return shard_map(body, mesh=mesh, in_specs=in_specs,
-                     out_specs=out_specs, check_vma=False)(
+    return jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)(
         rep_args, params, flat_state, key, lr, step_no, plr, wd,
         *batch)

@@ -387,28 +387,35 @@ class HybridParallelTrainer:
                 if i < n_cast and jnp.issubdtype(
                     jnp.asarray(b).dtype, jnp.floating)
                 else b for i, b in enumerate(batch))
-        if self.loss_fn is not None:
-            with _ptrace.annotate("fwd"):
-                out, new_buf = functional_call(layer, cast, buffers,
-                                               batch[:-1], training=True,
-                                               rng_key=key)
-                loss = self.loss_fn(
-                    Tensor(out) if not isinstance(out, Tensor) else out,
-                    Tensor(batch[-1]))
-            loss = loss._value if isinstance(loss, Tensor) else loss
-        else:
-            # model exposes .loss(*batch) (e.g. GPT)
-            from ..core import rng as rng_mod
+        # Pallas kernels in the model nest a shard_map over the mesh axes
+        # that are GSPMD-auto here (all of them on the pure-GSPMD path,
+        # none inside qcomm's all-manual dp wrap): XLA does not
+        # partition a Mosaic call
+        from . import context as dctx
 
-            pt = self._param_tensors
-            bt = self._buffer_tensors
-            from ..static.functional import _swapped_state
+        with dctx.kernel_scope(self.mesh):
+            if self.loss_fn is not None:
+                with _ptrace.annotate("fwd"):
+                    out, new_buf = functional_call(layer, cast, buffers,
+                                                   batch[:-1], training=True,
+                                                   rng_key=key)
+                    loss = self.loss_fn(
+                        Tensor(out) if not isinstance(out, Tensor) else out,
+                        Tensor(batch[-1]))
+                loss = loss._value if isinstance(loss, Tensor) else loss
+            else:
+                # model exposes .loss(*batch) (e.g. GPT)
+                from ..core import rng as rng_mod
 
-            with _swapped_state(pt + bt, list(cast) + list(buffers)):
-                with rng_mod.key_scope(key), _ptrace.annotate("fwd"):
-                    loss_t = layer.loss(*[Tensor(b) for b in batch])
-                new_buf = [t._value for t in bt]
-            loss = loss_t._value
+                pt = self._param_tensors
+                bt = self._buffer_tensors
+                from ..static.functional import _swapped_state
+
+                with _swapped_state(pt + bt, list(cast) + list(buffers)):
+                    with rng_mod.key_scope(key), _ptrace.annotate("fwd"):
+                        loss_t = layer.loss(*[Tensor(b) for b in batch])
+                    new_buf = [t._value for t in bt]
+                loss = loss_t._value
         return loss.astype(jnp.float32), new_buf
 
     def _build(self):
@@ -594,8 +601,8 @@ class HybridParallelTrainer:
         step_no = jnp.asarray(self._step, jnp.int32)
         key = rng_mod.next_key()
         # disabled cost: one bool read. Enabled, the step is host-timed
-        # against a loss value fetch (the only truthful sync, bench.py
-        # NOTE) and the train counters/memory high-water are recorded.
+        # against a loss value fetch and the train counters/memory
+        # high-water are recorded.
         if _ptrace.is_enabled():
             t0 = time.perf_counter_ns()
             with _ptrace.scope("compiled/h2d"):
@@ -620,6 +627,17 @@ class HybridParallelTrainer:
         return loss
 
     __call__ = step
+
+    def aot_lower(self, *batch):
+        """Lower the train step on the trainer's own (concrete) state
+        without executing it — the program handed to XLA, for
+        inspection. suppressed(): this re-trace is by design, not a
+        silent recompile, and the training RNG stream is not advanced."""
+        with _precomp.suppressed():
+            return self._step_fn.lower(
+                self.params, self.opt_states, self.buffers,
+                self._shard_batch(batch), jnp.asarray(0.0, jnp.float32),
+                jnp.asarray(0, jnp.int32), jax.random.PRNGKey(0))
 
     def profile_step_phases(self, *batch, iters: int = 2,
                             trace_window: int = 0):
@@ -648,11 +666,7 @@ class HybridParallelTrainer:
             lambda: fb(self.params, self.buffers), iters)
         t_step = _pinstr.time_compiled(lambda: self.step(*batch), iters)
 
-        with _precomp.suppressed():
-            lowered = self._step_fn.lower(
-                self.params, self.opt_states, self.buffers, vs,
-                jnp.asarray(0.0, jnp.float32), jnp.asarray(0, jnp.int32),
-                key)
+        lowered = self.aot_lower(*batch)
         st = _pinstr.record_collectives_from(lowered, self.mesh)
         # same program inventory + measured/estimated comm split as
         # HybridPipelineTrainer.profile_step_phases

@@ -149,7 +149,7 @@ class GPTAttention(nn.Layer):
                 # already inside a shard_map manual over `axis`; capture
                 # the remaining-auto-axes scope NOW — the custom_vjp
                 # backward traces at transpose time, after the scope exits
-                auto_ctx = _dctx.current_pipeline_auto_axes()
+                auto_ctx = _dctx.current_auto_axes()
                 fn = lambda q_, k_, v_: _ring_mha(q_, k_, v_, True, None,
                                                   axis, auto_ctx)
             else:

@@ -39,8 +39,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ._pallas_compat import CompilerParams as _CompilerParams
-
 from ..tensor._helper import apply
 
 _BLOCK_Q = 1024        # default tile edges (capped by seq len). Large tiles
@@ -202,7 +200,7 @@ def _fwd(q3, k3, v3, scale, causal, block_q, block_k):
             pltpu.VMEM((block_q, 1), jnp.float32),       # running denom
             pltpu.VMEM((block_q, d), jnp.float32),       # output accumulator
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         cost_estimate=pl.CostEstimate(
             flops=4 * bh * sq * sk * d // (2 if causal else 1),
@@ -375,7 +373,7 @@ def _bwd_single_tile(scale, causal, res, do3, delta, dtypes):
             jax.ShapeDtypeStruct((bh, sk, d), dk_dtype),
             jax.ShapeDtypeStruct((bh, sk, d), dv_dtype),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=_interpret(),
     )(q3, k3, v3, do3, lse, delta)
@@ -427,7 +425,7 @@ def _bwd(scale, causal, block_q, block_k, res, do3, delta=None,
         out_specs=[pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0))],
         out_shape=[jax.ShapeDtypeStruct((bh, sq, d), dq_dtype)],
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=_interpret(),
     )(q3, k3, v3, do3, lse, delta)[0]
@@ -457,7 +455,7 @@ def _bwd(scale, causal, block_q, block_k, res, do3, delta=None,
             pltpu.VMEM((block_k, d), jnp.float32),
             pltpu.VMEM((block_k, d), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=_interpret(),
     )(q3, k3, v3, do3, lse, delta)
@@ -514,18 +512,18 @@ def _flash_mha_bwd(causal, scale, res, do):
 _flash_mha.defvjp(_flash_fwd_res, _flash_mha_bwd)
 
 
-def _maybe_nested_shard(q_shape, causal, scale):
-    """Inside the pipeline's manual-'pp' region the remaining mesh axes
-    are GSPMD-auto, and XLA refuses to auto-partition a Mosaic kernel in
-    a partially-manual region. Returns a callable that nests a shard_map
-    over those axes (dp shards batch, tp shards heads — the framework's
-    axis convention) so every mesh axis is manual around the pallas call,
-    or None when not applicable (full-auto region, CPU interpret, or
-    non-divisible shapes → caller falls back)."""
+def _nested_shard(q_shape, causal, scale):
+    """Where some mesh axis is still GSPMD-auto (distributed/context.py
+    ``auto_axes_scope``) XLA refuses the Mosaic call, so the kernel is
+    wrapped in a shard_map over those axes: dp shards batch, tp shards
+    heads (the framework's axis convention), any other axis replicates.
+    Returns the wrapped callable, or None when the kernel can be called
+    as it is. A batch or head count the mesh does not divide is an
+    error: there is no substitute that is still the flash kernel."""
     from ..distributed import context as dctx
 
-    pa = dctx.current_pipeline_auto_axes()
-    if pa is None or _interpret():
+    pa = dctx.kernel_auto_axes()
+    if pa is None:
         return None
     mesh, axes = pa
     from jax.sharding import PartitionSpec as P
@@ -533,18 +531,18 @@ def _maybe_nested_shard(q_shape, causal, scale):
     b, s, h, d = q_shape
     dp = mesh.shape.get("dp", 1) if "dp" in axes else 1
     tp = mesh.shape.get("tp", 1) if "tp" in axes else 1
-    if b % max(dp, 1) or h % max(tp, 1):
-        return None
+    if b % dp or h % tp:
+        raise ValueError(
+            f"flash_attention on mesh {dict(mesh.shape)}: q shape "
+            f"{tuple(q_shape)} needs batch {b} divisible by dp={dp} and "
+            f"heads {h} divisible by tp={tp} (the Pallas kernel is "
+            "sharded batch-over-dp, heads-over-tp; XLA cannot partition "
+            "it automatically)")
     spec = P("dp" if dp > 1 else None, None, "tp" if tp > 1 else None,
              None)
-
-    def call(q, k, v):
-        fn = dctx.nested_kernel_shard(
-            lambda q_, k_, v_: _flash_mha(q_, k_, v_, causal, scale),
-            in_specs=(spec, spec, spec), out_specs=spec)
-        return fn(q, k, v)
-
-    return call
+    return dctx.nested_kernel_shard(
+        lambda q_, k_, v_: _flash_mha(q_, k_, v_, causal, scale),
+        in_specs=(spec, spec, spec), out_specs=spec)
 
 
 def flash_attention(query, key, value, causal=False, scale=None, name=None):
@@ -554,23 +552,12 @@ def flash_attention(query, key, value, causal=False, scale=None, name=None):
     entry used by jitted functional paths (distributed/hybrid_gpt.py).
     """
     def f(q, k, v):
-        nested = _maybe_nested_shard(q.shape, causal, scale)
+        nested = _nested_shard(q.shape, causal, scale)
         if nested is not None:
             return nested(q, k, v)
-        if _pipeline_partial_manual():
-            # partially-manual region but shapes not shardable: the
-            # Mosaic kernel would be rejected — use the auto-partitionable
-            # jnp reference instead
-            return mha_reference(q, k, v, causal, scale)
         return _flash_mha(q, k, v, causal, scale)
 
     return apply(f, query, key, value, name="flash_attention")
-
-
-def _pipeline_partial_manual() -> bool:
-    from ..distributed import context as dctx
-
-    return dctx.in_partial_manual_region()
 
 
 def mha_reference(q, k, v, causal=False, scale=None):

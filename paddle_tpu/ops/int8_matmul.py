@@ -38,8 +38,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ._pallas_compat import CompilerParams as _CompilerParams
-
 
 def _interpret() -> bool:
     from ..core.place import target_platform
@@ -142,7 +140,7 @@ def int8_matmul(x, wq, scale, bias=None, qscale=None, *,
         out_shape=jax.ShapeDtypeStruct(
             (mp, np_), jnp.int8 if quant_out else out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=_interpret(),
     )(xp, wp, qs, sp, bp)

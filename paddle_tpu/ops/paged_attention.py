@@ -44,8 +44,9 @@ spelling is the measured default until the kernel wins on hardware):
   behind the same TPU guard as ``ops/flash_attention.py`` (interpret
   mode on CPU). Numerics are allclose, not bitwise, vs the XLA path
   (online softmax reassociates the reduction), so the serving engine
-  only selects it on explicit request, and a default flip waits for a
-  real-TPU measurement (ROADMAP).
+  only selects it on explicit request. On a v5e it compiles and agrees
+  with the XLA spelling for bf16 and int8 pools (chip_smoke.py phase
+  1); a default flip waits for a speed measurement (ROADMAP S6).
 
 Layout note: pools are ``[num_pages, page_size, NH, D]`` per layer;
 page 0 is the null page (writes of inactive rows land there, gathers
@@ -62,8 +63,8 @@ scale is unchanged, which is the steady state), and the new token is
 quantized at the final scale; the null page's scale contribution is
 masked so it stays 0 forever. The read side dequantizes inside
 ``_gather_attend`` — so the XLA spelling, both delegating entry
-points, AND the Pallas kernel (which prefetches the scale rows
-alongside the page table and dequantizes in VMEM before the online
+points, AND the Pallas kernel (which DMAs each page's scale rows by
+the page's own index map and dequantizes in VMEM before the online
 softmax) all inherit it from the one shared helper. The f32 path is
 bit-for-bit untouched (no cast, no extra ops) — the engine's bitwise
 parity contract only ever applied to unquantized pools, and still
@@ -78,8 +79,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-
-from ._pallas_compat import CompilerParams as _CompilerParams
 
 __all__ = ["ragged_paged_attention", "paged_decode_attention",
            "paged_prefill_attention", "paged_kv_scatter"]
@@ -327,9 +326,9 @@ def _ragged_kernel(pt_ref, pos0_ref, tl_ref, q_ref, k_ref, v_ref, *rest,
         k = k_ref[0].astype(jnp.float32)                # [ps, NH, D]
         v = v_ref[0].astype(jnp.float32)
         if ks_ref is not None:
-            # in-VMEM dequant: page values × this page's [NH] scales
-            k = k * ks_ref[0][None, :, None]
-            v = v * vs_ref[0][None, :, None]
+            # in-VMEM dequant: page values × this page's [NH, 1] scales
+            k = k * ks_ref[0][None]
+            v = v * vs_ref[0][None]
         hd = q.shape[-1]
         # s[n, t, p] = q[t, n] · k[p, n] / sqrt(D)
         s = jax.lax.dot_general(
@@ -377,7 +376,8 @@ def _ragged_attention_pallas(q, k_pool, v_pool, page_table, pos0,
 
     def _scale_index(i, j, pt, p0, tl):
         # the scale row rides the same page choice as the page itself
-        return (jnp.where(j * ps <= p0[i] + tl[i] - 1, pt[i, j], 0), 0)
+        return (jnp.where(j * ps <= p0[i] + tl[i] - 1, pt[i, j], 0),
+                0, 0)
 
     in_specs = [
         pl.BlockSpec((1, t, nh, hd),
@@ -387,9 +387,14 @@ def _ragged_attention_pallas(q, k_pool, v_pool, page_table, pos0,
     ]
     args = (page_table, pos0, true_len, q, k_pool, v_pool)
     if k_scale is not None:
-        in_specs += [pl.BlockSpec((1, nh), _scale_index),
-                     pl.BlockSpec((1, nh), _scale_index)]
-        args += (k_scale, v_scale)
+        # scales enter as [P, NH, 1]: a (1, NH) block of the [P, NH]
+        # array breaks Mosaic's rule that a block's last two dims be
+        # (8, 128)-divisible or the array's own, and [NH, 1] is already
+        # the page tile's layout (heads on sublanes, broadcast along
+        # the head_dim lanes)
+        in_specs += [pl.BlockSpec((1, nh, 1), _scale_index),
+                     pl.BlockSpec((1, nh, 1), _scale_index)]
+        args += (k_scale[:, :, None], v_scale[:, :, None])
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
@@ -407,7 +412,7 @@ def _ragged_attention_pallas(q, k_pool, v_pool, page_table, pos0,
         functools.partial(_ragged_kernel, page_size=ps, n_pages=nps),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((r, t, nh, hd), q.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=_interpret(),
     )(*args)

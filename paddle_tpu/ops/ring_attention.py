@@ -80,20 +80,19 @@ def _chunk_fwd_jnp(q3, k3, v3, scale, causal):
 
 
 def _bh_kernel_shard(fn, n_in, n_out, bh):
-    """Mosaic inside the pipeline's partially-manual region: wrap a
-    [BH, S, *]-chunk kernel call in a shard_map over the remaining auto
-    axes (shared rule: distributed/context.nested_kernel_shard). Row
-    attention is independent per BH row, so ANY even partition of dim 0
-    is numerically exact — P((dp, tp)) contiguous chunks are used even
+    """Mosaic where some mesh axis is still GSPMD-auto: wrap a
+    [BH, S, *]-chunk kernel call in a shard_map over those axes (shared
+    rule: distributed/context.nested_kernel_shard). Row attention is
+    independent per BH row, so ANY even partition of dim 0 is
+    numerically exact — P((dp, tp)) contiguous chunks are used even
     though flattened b-major/h-minor order interleaves them. Returns
-    None when no scope is active or BH does not split evenly (caller
-    falls back to the auto-partitionable jnp path)."""
+    None when the kernel can be called as it is; a BH the mesh does not
+    divide is an error, not a reason to leave the kernel."""
     from ..distributed import context as dctx
     from jax.sharding import PartitionSpec as P
 
-    pa = dctx.current_pipeline_auto_axes()
-    if pa is None or fa._interpret():
-        # CPU interpret mode is plain HLO — auto-partitionable, no nest
+    pa = dctx.kernel_auto_axes()
+    if pa is None:
         return None
     mesh, axes = pa
     dim0 = tuple(a for a in ("dp", "tp")
@@ -102,7 +101,11 @@ def _bh_kernel_shard(fn, n_in, n_out, bh):
     for a in dim0:
         size *= mesh.shape[a]
     if bh % size:
-        return None
+        raise ValueError(
+            f"ring_attention on mesh {dict(mesh.shape)}: batch×heads "
+            f"{bh} must be divisible by dp×tp={size} (the Pallas chunk "
+            "kernels are sharded over those axes; XLA cannot partition "
+            "them automatically)")
     spec = P(dim0 if dim0 else None, None, None)
     return dctx.nested_kernel_shard(fn, in_specs=(spec,) * n_in,
                                     out_specs=(spec,) * n_out)
@@ -121,16 +124,8 @@ def _chunk_fwd(q3, k3, v3, scale, causal):
             n_in=3, n_out=2, bh=bh)
         if nested is not None:
             return nested(q3, k3, v3)
-        if _in_partial_manual():
-            return _chunk_fwd_jnp(q3, k3, v3, scale, causal)
         return fa._fwd(q3, k3, v3, scale, causal, bq, bk)
     return _chunk_fwd_jnp(q3, k3, v3, scale, causal)
-
-
-def _in_partial_manual() -> bool:
-    from ..distributed import context as dctx
-
-    return dctx.in_partial_manual_region()
 
 
 def _chunk_skip(q3, k3, v3, scale):
@@ -182,9 +177,6 @@ def _chunk_bwd(q3, k3, v3, do3, lse, delta, scale, causal):
             n_in=6, n_out=3, bh=bh)
         if nested is not None:
             return nested(q3, k3, v3, do3, lse, delta)
-        if _in_partial_manual():
-            return _chunk_bwd_jnp(q3, k3, v3, do3, lse, delta, scale,
-                                  causal)
         return fa._bwd(scale, causal, bq, bk, (q3, k3, v3, None, lse), do3,
                        delta=delta, out_dtype=jnp.float32)
     return _chunk_bwd_jnp(q3, k3, v3, do3, lse, delta, scale, causal)
@@ -209,7 +201,7 @@ def _branch(t, idx, sp, causal):
 
 
 def _auto_scope(auto_ctx):
-    """Re-enter the pipeline_auto_axes scope captured at call time.
+    """Re-enter the auto_axes scope captured at call time.
     custom_vjp backwards are traced at TRANSPOSE time, long after the
     caller's ``with`` scope exited — so the (mesh, axes) pair rides the
     nondiff args and both fwd and bwd re-enter it around their chunk
@@ -220,7 +212,7 @@ def _auto_scope(auto_ctx):
 
     if auto_ctx is None:
         return contextlib.nullcontext()
-    return dctx.pipeline_auto_axes_scope(auto_ctx[0], auto_ctx[1])
+    return dctx.auto_axes_scope(auto_ctx[0], auto_ctx[1])
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
@@ -351,7 +343,7 @@ def ring_attention(q, k, v, axis_name: str = "sp", causal: bool = True,
     from ..distributed import context as dctx
 
     return _ring_mha(q, k, v, causal, scale, axis_name,
-                     dctx.current_pipeline_auto_axes())
+                     dctx.current_auto_axes())
 
 
 def sequence_parallel_attention(q, k, v, mesh: Mesh, causal: bool = True,
@@ -359,58 +351,26 @@ def sequence_parallel_attention(q, k, v, mesh: Mesh, causal: bool = True,
     """shard_map wrapper: q/k/v are GLOBAL [B, S, H, D] arrays (or traced
     values inside a pjit program); sequence dim is sharded over
     `axis_name`, everything else stays in GSPMD auto mode (so dp-sharded
-    batch and tp-sharded heads compose).
-
-    jax < 0.5 (no ``jax.shard_map``): the old experimental dialect
-    cannot TRANSPOSE a partially-manual region (its ``auto=`` mode —
-    the ROADMAP open item), so the wrapper goes ALL-manual there
-    instead: manual over every mesh axis, with the batch dim explicitly
-    mapped to 'dp' and the head dim to 'tp' when those axes exist and
-    divide the dim (attention rows are independent per batch×head, so
-    any even split is exact). Unmapped extra axes replicate. Same math,
-    same ring — only the partitioning dialect differs. Routed through
-    ``distributed/_compat.shard_map`` so the translation cannot drift
-    per call site."""
-    from ..distributed._compat import shard_map as _shard_map
-
+    batch and tp-sharded heads compose)."""
     # when already inside another shard_map (e.g. the 'pp' pipeline,
     # distributed/pipeline.py), the context mesh is an AbstractMesh with
     # that axis Manual — the nested shard_map must be given THAT mesh.
-    use_mesh = mesh
-    try:
-        am = jax.sharding.get_abstract_mesh()
-        if am is not None and axis_name in (am.axis_names or ()):
-            use_mesh = am
-    except AttributeError:
-        pass
+    am = jax.sharding.get_abstract_mesh()
+    use_mesh = am if axis_name in am.axis_names else mesh
 
-    modern = hasattr(jax, "shard_map")
-    if modern:
-        spec = P(None, axis_name, None, None)
-        # inside this sp-manual region the other mesh axes stay
-        # GSPMD-auto; pass them as the kernels' auto-context so the
-        # chunk kernels nest a shard_map over them on the TPU target
-        # (Mosaic cannot live in a partially-manual region) — threaded
-        # through _ring_mha's static args so the transpose-time
-        # backward sees it too
-        remaining = tuple(a for a in mesh.axis_names if a != axis_name)
-        auto_ctx = (mesh, remaining) if remaining else None
-        manual = frozenset({axis_name})
-    else:
-        def _dim_axis(name, dim):
-            ok = (name in mesh.axis_names and mesh.shape[name] > 1
-                  and dim % mesh.shape[name] == 0)
-            return name if ok else None
+    spec = P(None, axis_name, None, None)
+    # inside this sp-manual region the other mesh axes stay GSPMD-auto;
+    # pass them as the kernels' auto-context so the chunk kernels nest a
+    # shard_map over them on the TPU target (XLA does not partition a
+    # Mosaic call) — threaded through _ring_mha's static args so the
+    # transpose-time backward sees it too
+    remaining = tuple(a for a in mesh.axis_names if a != axis_name)
+    auto_ctx = (mesh, remaining) if remaining else None
 
-        b, _, h, _ = q.shape
-        spec = P(_dim_axis("dp", b), axis_name, _dim_axis("tp", h), None)
-        auto_ctx = None         # fully manual: no auto region to nest in
-        manual = None           # _compat: None == manual over ALL axes
-
-    mapped = _shard_map(
+    mapped = jax.shard_map(
         lambda a, b_, c: _ring_mha(a, b_, c, causal, scale, axis_name,
                                    auto_ctx),
         mesh=use_mesh,
         in_specs=(spec, spec, spec), out_specs=spec,
-        check_vma=False, axis_names=manual)
+        check_vma=False, axis_names=frozenset({axis_name}))
     return mapped(q, k, v)

@@ -56,11 +56,12 @@ and the busy union measures the thunk executor, not an accelerator.
 On TPU the same parser reads the device-stream slices. Every parser
 path is exercised by checked-in fixture tests on any backend.
 
-**Peak FLOPs** for MFU: TPU generations get their bf16 peak; CPU gets
-a one-shot MEASURED matmul calibration at the first capture (source
-``"calibrated"`` — ISSUE 16 satellite, retiring the nominal
-placeholder), falling back to the labeled nominal
-``_PEAK_FLOPS["cpu"]`` only if the measurement itself fails —
+**Peak FLOPs** for MFU: an accelerator gets its published bf16 peak
+from ``profiler/peaks.py`` (a ``device_kind`` the table does not list
+is an error); CPU gets a one-shot MEASURED matmul calibration at the
+first capture (source ``"calibrated"`` — ISSUE 16 satellite, retiring
+the nominal placeholder), falling back to the labeled nominal
+``_NOMINAL_CPU_FLOPS`` only if the measurement itself fails —
 ``peak_flops_source`` says which one was used; pass ``peak_flops=``
 or set ``PADDLE_PEAK_FLOPS`` to override (the env var always wins).
 
@@ -362,12 +363,11 @@ def parse_timeline(doc: dict) -> Timeline:
 # ---------------------------------------------------------------------------
 # peak FLOPs (MFU denominator)
 # ---------------------------------------------------------------------------
-#: bf16 peak FLOP/s per chip by device-kind substring (bench.py table);
-#: the CPU entry is the FALLBACK for hosts where the measured matmul
-#: calibration below fails — peak_flops_source labels which one a
-#: ledger actually used.
-_PEAK_FLOPS = {"v6": 918e12, "v5p": 459e12, "v5": 197e12,
-               "v4": 275e12, "cpu": 5e10}
+#: CPU FALLBACK for hosts where the measured matmul calibration below
+#: fails (CPU tests only — accelerator peaks live in profiler/peaks.py
+#: and an unknown accelerator is an error); peak_flops_source labels
+#: which one a ledger actually used.
+_NOMINAL_CPU_FLOPS = 5e10
 
 #: one-shot CPU calibration cache: (peak FLOP/s or None, done flag) —
 #: measured at the FIRST capture's summarize and reused for the
@@ -410,9 +410,11 @@ def _measure_cpu_peak_flops(n: int = 512,
 
 def default_peak_flops() -> Tuple[Optional[float], str]:
     """(peak FLOP/s, source label) for the local device. Precedence:
-    ``PADDLE_PEAK_FLOPS`` env var, the TPU-generation table, the
-    one-shot measured CPU matmul calibration (source
-    ``"calibrated"``), the labeled nominal CPU fallback."""
+    ``PADDLE_PEAK_FLOPS`` env var; on an accelerator the published
+    table (``profiler/peaks.py`` — a ``device_kind`` it does not list
+    raises); on the CPU backend the one-shot measured matmul
+    calibration (source ``"calibrated"``), then the labeled nominal
+    CPU fallback."""
     global _cpu_calibration, _cpu_calibrated
     env = os.environ.get("PADDLE_PEAK_FLOPS")
     if env:
@@ -420,25 +422,20 @@ def default_peak_flops() -> Tuple[Optional[float], str]:
             return float(env), "env:PADDLE_PEAK_FLOPS"
         except ValueError:
             pass
-    try:
-        import jax
+    import jax
 
-        dev = jax.devices()[0]
-        kind = getattr(dev, "device_kind", "").lower()
-        if dev.platform != "cpu":
-            for key in ("v6", "v5p", "v5", "v4"):
-                if key in kind or (key == "v5" and "lite" in kind):
-                    return _PEAK_FLOPS[key], f"tpu-{key}-bf16-peak"
-            return _PEAK_FLOPS["v5"], "tpu-default-v5e-bf16-peak"
-    except Exception:
-        pass
+    from .peaks import device_peak
+
+    dev = jax.devices()[0]
+    if dev.platform != "cpu":
+        return device_peak(dev).bf16_flops, f"published:{dev.device_kind}"
     with _calib_lock:
         if not _cpu_calibrated:
             _cpu_calibration = _measure_cpu_peak_flops()
             _cpu_calibrated = True
         if _cpu_calibration is not None:
             return _cpu_calibration, "calibrated"
-    return _PEAK_FLOPS["cpu"], "nominal-cpu-placeholder"
+    return _NOMINAL_CPU_FLOPS, "nominal-cpu-placeholder"
 
 
 # ---------------------------------------------------------------------------

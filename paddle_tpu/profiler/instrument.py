@@ -5,8 +5,7 @@
   makes an allreduce-compression experiment (EQuARX-style) attributable
   instead of inferred from wall-clock deltas;
 - device memory high-water marks via ``Device.memory_stats()`` (absent on
-  CPU and behind some remote-device tunnels — callers get None, never an
-  exception);
+  the CPU backend — callers get None there);
 - batch token counting for throughput metrics.
 """
 from __future__ import annotations
@@ -217,14 +216,18 @@ def record_collectives_from(lowered, mesh=None, prefix: str = "comm") -> dict:
     return record_collective_stats(text, prefix)
 
 
+def device_stamp() -> dict:
+    """The device as jax reports it — what every result a benchmark or
+    smoke run prints is stamped with."""
+    devs = jax.devices()
+    return {"platform": devs[0].platform, "kind": devs[0].device_kind,
+            "count": len(devs)}
+
+
 def device_memory_stats(device=None) -> Optional[dict]:
     """``Device.memory_stats()`` of the first (or given) local device;
-    None where the backend does not report (CPU, some remote tunnels)."""
-    try:
-        d = device or jax.local_devices()[0]
-        stats = d.memory_stats()
-    except Exception:
-        return None
+    None where the backend does not report (the CPU backend)."""
+    stats = (device or jax.local_devices()[0]).memory_stats()
     if not stats:
         return None
     return dict(stats)

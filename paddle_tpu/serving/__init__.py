@@ -18,8 +18,8 @@ resident decodes, as ragged rows of one
 ``ops/paged_attention.ragged_paged_attention`` call per layer
 ("Ragged Paged Attention": per-row ``(pos0, true_len)`` metadata; a
 decode row is simply ``true_len == 1``). XLA gather spelling is the
-measured default; a Pallas ragged kernel is interpret-verified and
-gated for the real-TPU follow-up; ``attention_kernel="legacy"`` keeps
+default; a Pallas ragged kernel is opt-in (correct on the chip, its
+speed not measured); ``attention_kernel="legacy"`` keeps
 the pre-unification two-dispatch engine for benchmarking. Speculative
 decoding (``ServingConfig.spec`` = ``SpecConfig(draft_model, k)``,
 ``spec.py``) amortizes the target over k drafted tokens per verify

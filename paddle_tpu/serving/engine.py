@@ -171,9 +171,10 @@ def _proc_index() -> int:
     return _detect_rank()
 
 #: attention_kernel values: the unified mixed-row tick on the XLA
-#: gather spelling (measured default), the unified tick on the Pallas
-#: ragged kernel (interpret-verified; real-TPU measurement pending per
-#: the int8_matmul precedent), and the pre-unification two-dispatch
+#: gather spelling (the default), the unified tick on the Pallas
+#: ragged kernel (compiles with Mosaic and matches the XLA spelling on
+#: a v5e for bf16 and int8 pools — chip_smoke.py phase 1; its speed is
+#: not measured), and the pre-unification two-dispatch
 #: engine (decode tick + separate prefill program) kept for
 #: benchmarking the dispatch collapse.
 ATTENTION_KERNELS = ("ragged-xla", "ragged-pallas", "legacy")
@@ -181,11 +182,16 @@ ATTENTION_KERNELS = ("ragged-xla", "ragged-pallas", "legacy")
 
 @contextmanager
 def _quiet_donation():
-    """CPU jax may decline buffer donation for the page pools; the
-    fallback copy is correct, just slower — don't spam the log for it.
-    Scoped to the engine's own dispatches: a global filter would also
-    swallow the training stack's donation-failure warnings (a real perf
-    signal in hybrid.py's jitted step)."""
+    """XLA:CPU declines buffer donation for the page pools; the
+    fallback copy is correct there — don't spam the log for it. On any
+    other backend a declined pool donation is a full pool copy per
+    tick, so the warning is left to be seen (chip_smoke.py fails on
+    it). Scoped to the engine's own dispatches: a global filter would
+    also swallow the training stack's donation-failure warnings (a
+    real perf signal in hybrid.py's jitted step)."""
+    if jax.default_backend() != "cpu":
+        yield
+        return
     with warnings.catch_warnings():
         warnings.filterwarnings(
             "ignore", message="Some donated buffers were not usable")

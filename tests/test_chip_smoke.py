@@ -1,0 +1,99 @@
+"""CPU rehearsal of chip_smoke.py: the same phase functions the chip run
+calls, at toy sizes on the virtual CPU mesh (Pallas kernels interpreted),
+plus the script's refusals — no accelerator, no backend at import, and
+where the compile cache goes."""
+import os
+import subprocess
+import sys
+
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+import chip_smoke
+from paddle_tpu.models import GPTConfig
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+pytestmark = pytest.mark.filterwarnings("ignore")
+
+TOY = GPTConfig(vocab_size=128, hidden_size=64, num_layers=2, num_heads=2,
+                max_seq_len=128)
+
+
+@pytest.fixture
+def keep_cache_config():
+    """main() and the helper place the compile cache through jax.config;
+    the rest of the suite must not inherit that."""
+    before = jax.config.jax_compilation_cache_dir
+    yield before
+    jax.config.update("jax_compilation_cache_dir", before)
+
+
+def test_main_refuses_a_cpu_backend(capsys, keep_cache_config):
+    with pytest.raises(SystemExit) as e:
+        chip_smoke.main()
+    assert e.value.code not in (0, None)
+    assert "no TPU" in str(e.value.code)
+    assert '"ok"' not in capsys.readouterr().out     # no result printed
+
+
+def test_kernels_rehearsal():
+    errs = chip_smoke.check_flash(1, 128, 2, 64, jnp.bfloat16)
+    assert set(errs) == {"fwd", "dq", "dk", "dv"}
+    # both pool dtypes and both row kinds; the four combinations are
+    # compiled for the v5e in test_tpu_lowering.py
+    chip_smoke.check_ragged(41, 4, 2, 64, 8, 8, False)
+    chip_smoke.check_ragged(41, 4, 2, 64, 8, 1, True)
+
+
+def test_kernel_check_catches_a_wrong_kernel(monkeypatch):
+    from paddle_tpu.ops import paged_attention as pa
+
+    real = pa._ragged_attention_pallas
+    monkeypatch.setattr(pa, "_ragged_attention_pallas",
+                        lambda *a, **k: real(*a, **k) * 1.1)
+    with pytest.raises(chip_smoke.SmokeFailure):
+        chip_smoke.check_ragged(41, 4, 2, 64, 8, 8, False)
+
+
+def test_serve_rehearsal():
+    cfg = GPTConfig(vocab_size=128, hidden_size=64, num_layers=2,
+                    num_heads=2, max_seq_len=256)
+    out = chip_smoke.phase_serve(cfg, 4, 4, (
+        (0, 6, 8, 0), (0, 40, 8, 16), (0, 120, 6, 0), (2, 9, 10, 0),
+        (30, 48, 8, 16), (34, 5, 6, 0)))
+    assert out["prefix_hit_tokens"] >= 16
+    assert out["tokens"] == 8 + 8 + 6 + 10 + 8 + 6
+    assert out["tick_kinds"]["mixed"] and out["tick_kinds"]["decode_only"]
+
+
+def test_train_and_multichip_rehearsal():
+    loss0 = chip_smoke.phase_train(TOY, micro=2, n_micro=2,
+                                   steps=3)["loss0"]
+    chip_smoke.phase_multichip(TOY, 2, 2, loss0, TOY, zero_batch=8)
+
+
+def test_import_starts_no_backend():
+    """One process per chip: whoever imports the package (a launcher, a
+    parent of workers) must not take the accelerator by doing so."""
+    code = ("import paddle_tpu, paddle_tpu.serving, "
+            "paddle_tpu.distributed.launch, chip_smoke\n"
+            "from jax._src import xla_bridge\n"
+            "assert not xla_bridge._backends, xla_bridge._backends\n")
+    r = subprocess.run([sys.executable, "-c", code], cwd=REPO,
+                       capture_output=True, text=True, timeout=300)
+    assert r.returncode == 0, r.stderr[-2000:]
+
+
+def test_compile_cache_placement(monkeypatch, keep_cache_config):
+    from paddle_tpu.utils import compile_cache
+
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/somewhere/else")
+    assert compile_cache.enable_compile_cache() == "/somewhere/else"
+    assert jax.config.jax_compilation_cache_dir == keep_cache_config
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    want = os.path.join(REPO, ".jax_cache")
+    assert compile_cache.enable_compile_cache() == want
+    assert jax.config.jax_compilation_cache_dir == want

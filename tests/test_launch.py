@@ -33,7 +33,8 @@ def test_launcher_env_protocol(tmp_path):
         "cur = os.environ['PADDLE_CURRENT_ENDPOINT']\n"
         "assert cur == eps[int(rank)] and n == '2' and len(eps) == 2\n"
         f"open(r'{tmp_path}' + '/env_ok.' + rank, 'w').write('ok')\n")
-    r = _run_launch(["--nproc_per_node", "2", str(script)])
+    r = _run_launch(["--nproc_per_node", "2", "--backend", "cpu",
+                     str(script)])
     assert r.returncode == 0, r.stderr[-2000:]
     assert (tmp_path / "env_ok.0").exists()
     assert (tmp_path / "env_ok.1").exists()
@@ -44,8 +45,21 @@ def test_launcher_propagates_failure(tmp_path):
     script.write_text("import os, sys\n"
                       "sys.exit(3 if os.environ['PADDLE_TRAINER_ID'] == '1'"
                       " else 0)\n")
-    r = _run_launch(["--nproc_per_node", "2", str(script)])
+    r = _run_launch(["--nproc_per_node", "2", "--backend", "cpu",
+                     str(script)])
     assert r.returncode == 3
+
+
+def test_launcher_refuses_many_procs_on_an_accelerator(tmp_path):
+    """One process per host owns the chips: several children that all
+    inherit the accelerator would fail or hang on it."""
+    from paddle_tpu.distributed.launch import launch
+
+    script = tmp_path / "never.py"
+    script.write_text(f"open(r'{tmp_path}/ran', 'w').write('x')\n")
+    with pytest.raises(SystemExit, match="--backend cpu"):
+        launch(["--nproc_per_node", "2", str(script)])
+    assert not (tmp_path / "ran").exists()
 
 
 @pytest.mark.slow

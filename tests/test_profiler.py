@@ -339,14 +339,12 @@ class TestCollectiveStats:
 
     def test_real_lowering_all_reduce_bytes(self):
         # the same check against what THIS jax actually prints
-        from paddle_tpu.distributed._compat import shard_map
-
         if jax.device_count() < 2:
             pytest.skip("needs >= 2 devices")
         mesh = jax.sharding.Mesh(np.array(jax.devices()[:2]), ("dp",))
         P = jax.sharding.PartitionSpec
 
-        f = jax.jit(shard_map(
+        f = jax.jit(jax.shard_map(
             lambda x: jax.lax.psum(x, "dp"), mesh=mesh,
             in_specs=P("dp"), out_specs=P()))
         text = f.lower(jnp.ones((8, 4), jnp.float32)).as_text()

@@ -6,6 +6,8 @@ quantized-DP training, and the collective-byte accounting showing the
 satellite added). Heavy legs (the pipeline-trainer variant) are
 slow-marked per the saturated-cap rule; the tier-1 legs use a micro
 GPT so the two trainer compiles stay cheap."""
+import functools
+
 import numpy as np
 import pytest
 
@@ -17,12 +19,13 @@ from jax.sharding import PartitionSpec as P  # noqa: E402
 
 import paddle_tpu as paddle  # noqa: E402
 from paddle_tpu.distributed import qcomm  # noqa: E402
-from paddle_tpu.distributed._compat import shard_map  # noqa: E402
 from paddle_tpu.distributed.fleet import DistributedStrategy  # noqa: E402
 from paddle_tpu.distributed.mesh import create_mesh  # noqa: E402
 from paddle_tpu.distributed.strategy_compiler import (  # noqa: E402
     build_mesh_from_strategy, compile_train_step)
 from paddle_tpu.models import GPT, GPTConfig  # noqa: E402
+
+shard_map = functools.partial(jax.shard_map, check_vma=False)
 
 N_DEV = len(jax.devices())
 needs_mesh = pytest.mark.skipif(N_DEV < 8,

@@ -1,4 +1,4 @@
-"""stream_layers (round 5, MEMO_SCALING_r05 enabler): per-layer
+"""stream_layers (round 5): per-layer
 host-stream ZeRO-Offload update in the hybrid trainer.
 
 The TPU path stores host-offloaded state per-layer in pinned_host and

@@ -157,3 +157,93 @@ def test_tpu_lowering_ring_attention_sp(monkeypatch):
     hlo = tr.aot_lower(batch).as_text()
     cps = re.findall(r".*collective_permute.*", hlo)
     assert any("bf16" in l for l in cps), "ring/pipeline permutes not bf16"
+
+# ---------------------------------------------------------------------------
+# The refusals only a TPU target shows (interpret mode turns a Pallas call
+# into plain HLO, so a CPU mesh never sees them). Lowering is enough to
+# meet XLA's "Mosaic kernels cannot be automatically partitioned" and
+# Mosaic's block-shape rules; the kernels are also compiled.
+# ---------------------------------------------------------------------------
+@pytest.mark.parametrize("dp,tp,batch", [(4, 1, 16), (2, 2, 8)])
+def test_tpu_lowering_flash_without_a_pipeline_axis(monkeypatch, dp, tp,
+                                                    batch):
+    """pp = 1 is a fully-auto GSPMD region: every mesh axis has to be made
+    manual around the flash kernel (distributed/context.kernel_scope)."""
+    devices = _tpu_topology_devices()
+    monkeypatch.setenv("PADDLE_TPU_TARGET_PLATFORM", "tpu")
+    tr = _build_abstract_trainer(devices, dp=dp, tp=tp, pp=1)
+    hlo = tr.aot_lower(jax.ShapeDtypeStruct((batch, 128), np.int32)).as_text()
+    assert "tpu_custom_call" in hlo, "flash kernel lost from the program"
+
+
+def test_tpu_lowering_flash_indivisible_batch_is_an_error(monkeypatch):
+    """A micro-batch the dp axis does not divide has no flash spelling:
+    the trainer says so, naming the shape, instead of substituting the
+    jnp reference."""
+    devices = _tpu_topology_devices()
+    monkeypatch.setenv("PADDLE_TPU_TARGET_PLATFORM", "tpu")
+    tr = _build_abstract_trainer(devices, dp=4, tp=1, pp=1)
+    with pytest.raises(ValueError, match=r"batch 2 divisible by dp=4"):
+        tr.aot_lower(jax.ShapeDtypeStruct((8, 128), np.int32))
+
+
+def _on_tpu(device, shape, dtype):
+    from jax.sharding import SingleDeviceSharding
+
+    return jax.ShapeDtypeStruct(shape, dtype,
+                                sharding=SingleDeviceSharding(device))
+
+
+@pytest.mark.parametrize("int8", [False, True])
+@pytest.mark.parametrize("t", [1, 32])
+def test_tpu_compile_ragged_pallas(monkeypatch, int8, t):
+    """The ragged paged kernel at head_dim 128 compiles with Mosaic for
+    decode rows and chunk rows, bf16 pools and int8 pools with scales
+    (the [P, NH] scale rows once broke the (8, 128) block rule)."""
+    import jax.numpy as jnp
+
+    from paddle_tpu.ops.paged_attention import ragged_paged_attention
+
+    dev = _tpu_topology_devices()[0]
+    monkeypatch.setenv("PADDLE_TPU_TARGET_PLATFORM", "tpu")
+    pages, ps, nh, hd, nps, r = 33, 16, 4, 128, 8, 4
+    pool = _on_tpu(dev, (pages, ps, nh, hd),
+                   jnp.int8 if int8 else jnp.bfloat16)
+    args = [_on_tpu(dev, (r, t, nh, hd), jnp.bfloat16), pool, pool,
+            _on_tpu(dev, (r, nps), jnp.int32),
+            _on_tpu(dev, (r,), jnp.int32), _on_tpu(dev, (r,), jnp.int32)]
+    if int8:
+        args += [_on_tpu(dev, (pages, nh), jnp.float32)] * 2
+
+    def f(q, k, v, pt, p0, tl, ks=None, vs=None):
+        return ragged_paged_attention(q, k, v, pt, p0, tl, impl="pallas",
+                                      k_scale=ks, v_scale=vs)
+
+    compiled = jax.jit(f).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_tpu_compile_serving_tick(monkeypatch):
+    """The unified serving tick, re-lowered from the avals an engine
+    captured at its first dispatch (engine._note_avals) with TPU
+    shardings, compiles for the v5e."""
+    import paddle_tpu as paddle
+    from paddle_tpu.models import GPT, GPTConfig
+    from paddle_tpu.serving import ServingConfig, ServingEngine
+
+    dev = _tpu_topology_devices()[0]
+    paddle.seed(0)
+    net = GPT(GPTConfig(vocab_size=256, hidden_size=256, num_layers=2,
+                        num_heads=2, max_seq_len=128))
+    net.eval()
+    net.bfloat16()
+    eng = ServingEngine(net, ServingConfig(num_slots=2, page_size=16))
+    eng.submit(np.arange(5, dtype=np.int32), 2)
+    eng.step()
+    eng.drain(0)
+    fn, avals = eng._program_args[eng.compiled_sites[0]]
+    avals = jax.tree_util.tree_map(
+        lambda a: _on_tpu(dev, a.shape, a.dtype), avals)
+    monkeypatch.setenv("PADDLE_TPU_TARGET_PLATFORM", "tpu")
+    ma = fn.lower(*avals).compile().memory_analysis()
+    assert ma.alias_size_in_bytes > 0, "the donated page pools are not aliased"

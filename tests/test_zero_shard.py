@@ -9,6 +9,8 @@ per trainer pair (conftest orders this file with the compile-heavy
 tail); the quantized and guarded-hybrid legs are slow-marked."""
 import os
 
+import functools
+
 import numpy as np
 import pytest
 
@@ -20,7 +22,6 @@ from jax.sharding import PartitionSpec as P  # noqa: E402
 
 import paddle_tpu as paddle  # noqa: E402
 from paddle_tpu.distributed import qcomm  # noqa: E402
-from paddle_tpu.distributed._compat import shard_map  # noqa: E402
 from paddle_tpu.distributed.elastic import ElasticTrainer  # noqa: E402
 from paddle_tpu.distributed.fleet import DistributedStrategy  # noqa: E402
 from paddle_tpu.distributed.mesh import create_mesh  # noqa: E402
@@ -28,6 +29,8 @@ from paddle_tpu.distributed.strategy_compiler import (  # noqa: E402
     build_mesh_from_strategy, compile_train_step)
 from paddle_tpu.models import GPT, GPTConfig  # noqa: E402
 from paddle_tpu.resilience.runner import _resilience_reducer  # noqa: E402
+
+shard_map = functools.partial(jax.shard_map, check_vma=False)
 
 N_DEV = len(jax.devices())
 needs_mesh = pytest.mark.skipif(N_DEV < 8,
