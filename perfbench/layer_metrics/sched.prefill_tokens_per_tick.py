@@ -1,0 +1,10 @@
+"""Prompt tokens a tick prefills: the engine's prefill rows a tick
+(gauge ``serving/mixed_rows_prefill``, read after every tick of the window)
+times its chunk width."""
+
+
+def read(run):
+    f = run["facts"]
+    if "prefill_rows_per_tick" not in f:
+        return None
+    return f["prefill_rows_per_tick"] * f["prefill_chunk"]

@@ -1,0 +1,8 @@
+"""Median rate over the equal consecutive slices the traffic file cuts the
+window into. ``serve_tokens_per_s`` is all progress over all time; this
+stands beside it and passes over a slice that a stall spoils, so the two
+apart say that the window was not even."""
+
+
+def read(run):
+    return run["facts"].get("serve_tokens_per_s_slice_p50")

@@ -1,0 +1,6 @@
+"""Median device time of one serving tick under fixed-rate chat load."""
+from perfbench import loader
+
+
+def read(run):
+    return loader.load_module("layer_metrics", "_tick").device_ms_p50(run)
