@@ -1045,7 +1045,8 @@ class HybridPipelineTrainer:
             else:
                 loss, (g_blk, g_oth) = grads_of(bp_c, op_c, batch, key,
                                                 fault)
-            g_blk, g_oth = functional_clip(clip, (g_blk, g_oth))
+            with _ptrace.annotate("opt/update"):     # the optimizer's clip
+                g_blk, g_oth = functional_clip(clip, (g_blk, g_oth))
 
             ok = None
             if guard:
@@ -1084,28 +1085,33 @@ class HybridPipelineTrainer:
                 return p, g, s
 
             new_blk, new_blk_opt = {}, {}
-            for sfx in block_params:
-                p, g, s = barriered(block_params[sfx], g_blk[sfx],
-                                    block_opt[sfx])
-                np_, ns = upd2(p, g, s, self.block_opt_specs[sfx],
-                               lr, step_no, lr_block[sfx], wd_block[sfx],
-                               pspec=self.block_specs[sfx], stacked=True,
-                               ok=ok)
-                new_blk[sfx] = np_
-                new_blk_opt[sfx] = ns
-                if any_offload:
-                    chain.append(np_)
             new_oth, new_oth_opt = [], []
-            for p, g, s, sspec, pspec, plr, wd in zip(
-                    other_params, g_oth, other_opt, self.other_opt_specs,
-                    self.other_specs, lr_other, wd_other):
-                p, g, s = barriered(p, g, s)
-                np_, ns = upd2(p, g, s, sspec, lr, step_no, plr, wd,
-                               pspec=pspec, ok=ok)
-                new_oth.append(np_)
-                new_oth_opt.append(ns)
-                if any_offload:
-                    chain.append(np_)
+            # opt/update: scope name of the optimizer update, beside the
+            # forward's fwd/* (read by train.opt_ms_per_step)
+            with _ptrace.annotate("opt/update"):
+                for sfx in block_params:
+                    p, g, s = barriered(block_params[sfx], g_blk[sfx],
+                                        block_opt[sfx])
+                    np_, ns = upd2(p, g, s, self.block_opt_specs[sfx],
+                                   lr, step_no, lr_block[sfx],
+                                   wd_block[sfx],
+                                   pspec=self.block_specs[sfx],
+                                   stacked=True, ok=ok)
+                    new_blk[sfx] = np_
+                    new_blk_opt[sfx] = ns
+                    if any_offload:
+                        chain.append(np_)
+                for p, g, s, sspec, pspec, plr, wd in zip(
+                        other_params, g_oth, other_opt,
+                        self.other_opt_specs, self.other_specs, lr_other,
+                        wd_other):
+                    p, g, s = barriered(p, g, s)
+                    np_, ns = upd2(p, g, s, sspec, lr, step_no, plr, wd,
+                                   pspec=pspec, ok=ok)
+                    new_oth.append(np_)
+                    new_oth_opt.append(ns)
+                    if any_offload:
+                        chain.append(np_)
             if guard:
                 return (loss, ok, new_blk, new_oth, new_blk_opt,
                         new_oth_opt)

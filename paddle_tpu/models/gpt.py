@@ -30,6 +30,7 @@ from ..distributed.parallel_layers import (ColumnParallelLinear,
                                            VocabParallelEmbedding)
 from ..nn import functional as F
 from ..nn import initializer as I
+from ..profiler.trace import annotate
 from ..tensor import arange
 
 
@@ -122,10 +123,19 @@ class GPTAttention(nn.Layer):
                                          "bias": P("tp")}
 
     def forward(self, x):
+        # blk/* scope names: one vocabulary with gpt_block_body (the
+        # serving forwards), read by the traced run's per-part metrics
         b, s, h = x.shape[0], x.shape[1], x.shape[2]
-        qkv = self.qkv_proj(x)
-        qkv = qkv.reshape([b, s, 3, self.num_heads, self.head_dim])
-        q, k, v = qkv.unbind(2)
+        with annotate("blk/qkv"):
+            qkv = self.qkv_proj(x)
+            qkv = qkv.reshape([b, s, 3, self.num_heads, self.head_dim])
+            q, k, v = qkv.unbind(2)
+        with annotate("blk/attn"):
+            out = self._attend(q, k, v)
+        with annotate("blk/attn_out"):
+            return self.out_proj(out.reshape([b, s, h]))
+
+    def _attend(self, q, k, v):
         sp = _dctx.current_sequence_parallel()
         dropout_active = bool(self.dropout) and self.training
         if sp is not None:
@@ -155,13 +165,10 @@ class GPTAttention(nn.Layer):
             else:
                 fn = lambda q_, k_, v_: sequence_parallel_attention(
                     q_, k_, v_, mesh, causal=True, axis_name=axis)
-            out = apply(fn, q, k, v, name="ring_attention")
-        else:
-            out = F.scaled_dot_product_attention(
-                q, k, v, is_causal=True, dropout_p=self.dropout,
-                training=self.training)
-        out = out.reshape([b, s, h])
-        return self.out_proj(out)
+            return apply(fn, q, k, v, name="ring_attention")
+        return F.scaled_dot_product_attention(
+            q, k, v, is_causal=True, dropout_p=self.dropout,
+            training=self.training)
 
 
 class GPTMLP(nn.Layer):
@@ -206,9 +213,13 @@ class GPTBlock(nn.Layer):
             self.mlp = GPTMLP(config)
 
     def forward(self, x):
-        x = x + self.attn(self.ln_1(x))
-        x = x + self.mlp(self.ln_2(x))
-        return x
+        with annotate("blk/qkv"):
+            h = self.ln_1(x)
+        h = self.attn(h)
+        with annotate("blk/attn_out"):
+            x = x + h
+        with annotate("blk/ffn"):
+            return x + self.mlp(self.ln_2(x))
 
 
 class GPTEmbeddings(nn.Layer):
@@ -506,17 +517,24 @@ def gpt_block_body(xc, p, eps, nh, hd, attend):
     extra)`` writes this layer's KV into its cache and attends."""
     n, t = xc.shape[0], xc.shape[1]
     h = nh * hd
-    hn = _ln(xc, p["ln_1.weight"], p["ln_1.bias"], eps)
-    qkv = hn @ p["attn.qkv_proj.weight"] + p["attn.qkv_proj.bias"]
-    qkv = qkv.reshape(n, t, 3, nh, hd)
-    q, kk, vv = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
-    o, extra = attend(q, kk, vv)
-    o = o.reshape(n, t, h)
-    xc = xc + o @ p["attn.out_proj.weight"] + p["attn.out_proj.bias"]
-    h2 = _ln(xc, p["ln_2.weight"], p["ln_2.bias"], eps)
-    mid = jax.nn.gelu(h2 @ p["mlp.fc_in.weight"] + p["mlp.fc_in.bias"],
-                      approximate=True)
-    xc = xc + mid @ p["mlp.fc_out.weight"] + p["mlp.fc_out.bias"]
+    # blk/* scope names (metadata only): one vocabulary with GPTBlock's
+    # forward, read by the traced run's per-part metrics (PERF.md)
+    with annotate("blk/qkv"):
+        hn = _ln(xc, p["ln_1.weight"], p["ln_1.bias"], eps)
+        qkv = hn @ p["attn.qkv_proj.weight"] + p["attn.qkv_proj.bias"]
+        qkv = qkv.reshape(n, t, 3, nh, hd)
+        q, kk, vv = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
+    with annotate("blk/attn"):
+        o, extra = attend(q, kk, vv)
+    with annotate("blk/attn_out"):
+        o = o.reshape(n, t, h)
+        xc = xc + o @ p["attn.out_proj.weight"] + p["attn.out_proj.bias"]
+    with annotate("blk/ffn"):
+        h2 = _ln(xc, p["ln_2.weight"], p["ln_2.bias"], eps)
+        mid = jax.nn.gelu(
+            h2 @ p["mlp.fc_in.weight"] + p["mlp.fc_in.bias"],
+            approximate=True)
+        xc = xc + mid @ p["mlp.fc_out.weight"] + p["mlp.fc_out.bias"]
     return xc, extra
 
 
@@ -673,7 +691,8 @@ def gpt_ragged_apply(cfg: GPTConfig, stacked, other, kpool, vpool,
     nps = row_tab.shape[1]
     wte = other["embeddings.wte.weight"]
     wpe = other["embeddings.wpe.weight"]
-    x = wte[tokens[:, None]] + wpe[tok_pos[:, None]]    # [NT, 1, h]
+    with annotate("tick/embed"):
+        x = wte[tokens[:, None]] + wpe[tok_pos[:, None]]    # [NT, 1, h]
     # token -> ragged row (static: the flat layout never changes);
     # draft tokens share their slot's row (same page table)
     parts = [jnp.arange(nd, dtype=jnp.int32)]
@@ -702,8 +721,11 @@ def gpt_ragged_apply(cfg: GPTConfig, stacked, other, kpool, vpool,
             ksl0 = vsl0 = None
 
         def attend(q, kk, vv):
-            kpl, ksl = paged_kv_scatter(kpl0, ksl0, page, off, kk[:, 0])
-            vpl, vsl = paged_kv_scatter(vpl0, vsl0, page, off, vv[:, 0])
+            with annotate("blk/kv_scatter"):
+                kpl, ksl = paged_kv_scatter(kpl0, ksl0, page, off,
+                                            kk[:, 0])
+                vpl, vsl = paged_kv_scatter(vpl0, vsl0, page, off,
+                                            vv[:, 0])
             outs = []
             if nd and spec_k:
                 # verify grouping [nd, 1 + spec_k]: each slot's last
@@ -740,12 +762,13 @@ def gpt_ragged_apply(cfg: GPTConfig, stacked, other, kpool, vpool,
     else:
         x, (kpool, vpool) = jax.lax.scan(block, x,
                                          (stacked, kpool, vpool))
-    x = _ln(x, other["ln_f.weight"], other["ln_f.bias"], eps)
-    last = x[sample_ix, 0]                              # [S, h]
-    if "lm_head.weight" in other:
-        logits = last @ other["lm_head.weight"]
-    else:
-        logits = last @ wte.T
+    with annotate("tick/head"):
+        x = _ln(x, other["ln_f.weight"], other["ln_f.bias"], eps)
+        last = x[sample_ix, 0]                          # [S, h]
+        if "lm_head.weight" in other:
+            logits = last @ other["lm_head.weight"]
+        else:
+            logits = last @ wte.T
     if quantized:
         return logits, kpool, vpool, kscale, vscale
     return logits, kpool, vpool

@@ -181,6 +181,7 @@ def _fwd(q3, k3, v3, scale, causal, block_q, block_k):
         kv_idx = lambda b, i, j: (b, j, 0)
     o, lse = pl.pallas_call(
         kern,
+        name="flash_fwd",
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
@@ -354,6 +355,7 @@ def _bwd_single_tile(scale, causal, res, do3, delta, dtypes):
                              causal=causal)
     dq, dk, dv = pl.pallas_call(
         kern,
+        name="flash_bwd",
         grid=(bh,),
         in_specs=[
             pl.BlockSpec((1, sq, d), lambda b: (b, 0, 0)),
@@ -413,6 +415,7 @@ def _bwd(scale, causal, block_q, block_k, res, do3, delta=None,
                                 block_q=block_q, block_k=block_k)
     dq = pl.pallas_call(
         dq_kern,
+        name="flash_bwd_dq",
         grid=(bh, nq, nk),
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
@@ -434,6 +437,7 @@ def _bwd(scale, causal, block_q, block_k, res, do3, delta=None,
                                  block_q=block_q, block_k=block_k)
     dk, dv = pl.pallas_call(
         dkv_kern,
+        name="flash_bwd_dkv",
         grid=(bh, nk, nq),
         in_specs=[
             pl.BlockSpec((1, block_q, d), q_row_idx),

@@ -410,6 +410,7 @@ def _ragged_attention_pallas(q, k_pool, v_pool, page_table, pos0,
     )
     return pl.pallas_call(
         functools.partial(_ragged_kernel, page_size=ps, n_pages=nps),
+        name="ragged_paged_attn",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((r, t, nh, hd), q.dtype),
         compiler_params=pltpu.CompilerParams(

@@ -59,13 +59,15 @@ so sync-vs-streamed saves are directly comparable.
 
 Serving signals (ISSUE 4; paddle_tpu.serving): gauges
 ``serving/queue_depth``, ``serving/active_slots``,
-``serving/page_util`` (allocated fraction of the KV page pool),
-``serving/decode_batch`` (slots advanced by the last tick) and
-``serving/tokens_per_sec`` (set by ``ServingEngine.run``); counters
-``serving/tokens_generated``, ``serving/prefills``, ``serving/ticks``,
-``serving/preemptions``, ``serving/requests_finished`` and
-``serving/token_syncs`` (host materializations of deferred tick
-outputs); histogram ``serving/ttft_ms``; gauges
+``serving/page_util`` (allocated fraction of the KV page pool);
+counters ``serving/tokens_generated``, ``serving/prefills``,
+``serving/ticks``, ``serving/preemptions``,
+``serving/requests_finished`` and ``serving/drain_waited`` /
+``serving/drain_ready`` (deferred tick outputs the host waited for /
+found ready); histograms ``serving/ttft_ms`` and
+``serving/tick_turnaround_ms`` (dispatch to tokens on the host, one
+observation a drained tick); host spans ``pt:step/*`` and
+``pt:submit/fold_key`` inside any live profiler session; gauges
 ``serving/mixed_rows`` / ``serving/mixed_rows_decode`` /
 ``serving/mixed_rows_prefill`` (the prefill-vs-decode row mix of the
 last unified tick). Per-shape executable caches (``GPT.generate``'s

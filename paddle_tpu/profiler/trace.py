@@ -10,15 +10,22 @@ never sees device op boundaries):
     device time to the phase. Host timing a tracer would measure tracing,
     not execution, so no host span is recorded there.
   - **outside jit** (eager ops, dispatch, h2d staging, host pre/post) a
-    scope is a ``perf_counter_ns`` span, nested via a thread-local stack,
-    and doubles as ``jax.profiler.TraceAnnotation`` so the span also shows
-    up inside a ``jax.profiler.start_trace`` device timeline.
+    scope ALWAYS opens a ``jax.profiler.TraceAnnotation`` named
+    ``pt:<name>`` (keyword ids ride as the annotation's stats), so the
+    span sits on the profiler's clock inside any
+    ``jax.profiler.start_trace`` session, whoever started it: the session
+    is the only switch. While ``enable()`` is on it is also a
+    ``perf_counter_ns`` span, nested via a thread-local stack, kept in
+    the in-memory event list for ``scope_summary``/``chrome_trace``.
 
-Disabled mode is the fast path: ``scope()`` is a no-op context manager
-guarded by one module-global bool — no allocation, no lock, no event.
+Disabled mode is the fast path: with ``enable()`` off and no profiler
+session live (``TraceMe.is_enabled``, the test a TraceMe makes of itself)
+``scope()`` hands back one shared no-op context — no allocation, no lock,
+no event.
 """
 from __future__ import annotations
 
+import contextlib
 import json
 import threading
 import time
@@ -32,6 +39,27 @@ except ImportError:  # pragma: no cover - future jax renames
     def _trace_state_clean():
         return True
 
+try:  # is a jax.profiler session live? (TraceMe's own no-op test)
+    from jax._src.lib import _profiler as _jaxlib_profiler
+    _session_live = _jaxlib_profiler.TraceMe.is_enabled
+except (ImportError, AttributeError):  # pragma: no cover - future jaxlib
+    def _session_live():
+        return True     # open the annotation always: it tests for itself
+
+
+#: prefix of every host annotation a scope writes into a profiler session
+SPAN_PREFIX = "pt:"
+
+# The names ``annotate`` bakes into a program live in its op metadata, and
+# jax strips metadata before it hashes a program for the persistent compile
+# cache: a program that names its parts would be answered with the nameless
+# executable an older checkout left in a shared cache directory, and a
+# traced run would attribute nothing (seen on the CPU cache, PR 24). So
+# the metadata is part of the key; a checkout compiles once for itself.
+try:
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
+except AttributeError:  # pragma: no cover - a jax without the option
+    pass
 
 _enabled = False
 _lock = threading.Lock()
@@ -133,44 +161,63 @@ def enabled_window_s() -> float:
     return max(end - _t_enable_ns, 0) / 1e9
 
 
-class scope:  # noqa: N801 - context manager, lowercase like jax.named_scope
+_NO_SPAN = contextlib.nullcontext()
+
+
+def scope(name: str, **ids):
     """``with profiler.scope("hybrid/fwd"):`` — see module docstring for
     the per-regime lowering. Nesting composes: host spans inherit the
-    enclosing scopes' names ("step/h2d"), traced scopes nest via
-    jax.named_scope's own stack."""
+    enclosing scopes' names ("step/h2d") in the in-memory record, traced
+    scopes nest via jax.named_scope's own stack. ``scope("step/drain",
+    tick=7)``: keyword ids become stats of the ``pt:step/drain``
+    annotation (the profiler's own timeline carries the nesting, so the
+    annotation keeps the plain name)."""
+    if not _enabled and not _session_live():
+        return _NO_SPAN
+    return _Span(name, **ids)
 
-    __slots__ = ("name", "_t0", "_full", "_jax_ctx", "_mode")
 
-    def __init__(self, name: str):
+class _Span:
+    """What ``scope`` opens while ``enable()`` is on or a profiler
+    session is live."""
+
+    __slots__ = ("name", "_ids", "_t0", "_full", "_jax_ctx", "_mode")
+
+    def __init__(self, name: str, **ids):
         self.name = name
+        self._ids = ids
         self._t0 = 0
         self._full = name
         self._jax_ctx = None
-        self._mode = 0  # 0: off, 1: host span, 2: named_scope
+        self._mode = 0  # 0: off, 1: recorded host span, 2: named_scope,
+        #                 3: annotation only
 
     def __enter__(self):
-        if not _enabled:
-            return self
         if not _trace_state_clean():
-            # inside a jit/grad trace: metadata only
-            self._mode = 2
-            self._jax_ctx = jax.named_scope(self.name)
-            self._jax_ctx.__enter__()
+            # inside a jit/grad trace: metadata only, and a host clock
+            # would time the tracing
+            if _enabled:
+                self._mode = 2
+                self._jax_ctx = jax.named_scope(self.name)
+                self._jax_ctx.__enter__()
+            return self
+        self._jax_ctx = jax.profiler.TraceAnnotation(
+            SPAN_PREFIX + self.name, **self._ids)
+        self._jax_ctx.__enter__()
+        if not _enabled:
+            self._mode = 3
             return self
         self._mode = 1
         stack = _tls.stack
         self._full = "/".join(stack + [self.name]) if stack else self.name
         stack.append(self.name)
-        self._jax_ctx = jax.profiler.TraceAnnotation(self._full)
-        self._jax_ctx.__enter__()
         self._t0 = time.perf_counter_ns()
         return self
 
     def __exit__(self, *exc):
         if self._mode == 1:
             t1 = time.perf_counter_ns()
-            if self._jax_ctx is not None:
-                self._jax_ctx.__exit__(None, None, None)
+            self._jax_ctx.__exit__(None, None, None)
             if _tls.stack and _tls.stack[-1] == self.name:
                 _tls.stack.pop()
             global _dropped
@@ -192,14 +239,14 @@ class scope:  # noqa: N801 - context manager, lowercase like jax.named_scope
                     drop = len(_events) - _MAX_EVENTS
                     del _events[:drop]
                     _dropped += drop
-        elif self._mode == 2 and self._jax_ctx is not None:
+        elif self._mode:
             self._jax_ctx.__exit__(None, None, None)
         self._mode = 0
         self._jax_ctx = None
         return False
 
 
-class RecordEvent(scope):
+class RecordEvent(_Span):
     """RAII span under the reference's name (profiler.h:127): explicit
     ``begin()`` / ``end()`` in addition to the context-manager protocol."""
 

@@ -165,10 +165,17 @@ def _result_bytes(type_str: str) -> int:
     return total
 
 
-def _dot_flops(line: str, result_type: str) -> Optional[float]:
+def _dot_flops(line: str, result_type: str,
+               types: Optional[dict] = None) -> Optional[float]:
     """2 * prod(result dims) * prod(lhs contracting dims) — exact for
-    dot_general including batch dims (both live in the result)."""
-    lhs = _DOT_LHS_RE.search(line[line.index("dot("):])
+    dot_general including batch dims (both live in the result).
+    ``types`` {instruction: result type}: jax 0.9 prints operands bare
+    (``dot(%x, %w)``), so the lhs shape is looked up by its name."""
+    args = line[line.index("dot("):]
+    lhs = _DOT_LHS_RE.search(args)
+    bare = re.match(r"dot\(%([\w.\-]+)", args)
+    if lhs is None and bare and types:
+        lhs = _DOT_LHS_RE.search("(" + types.get(bare.group(1), ""))
     cm = _CONTRACT_RE.search(line)
     rm = _SHAPE_RE.search(result_type)
     if not (lhs and cm and rm):
@@ -238,6 +245,7 @@ def category_breakdown(hlo_text: str,
     in_entry = False
     matmul_flops = 0.0
     flops_known = False
+    types: Dict[str, str] = {}
     for line in hlo_text.splitlines():
         if line.startswith("ENTRY "):
             in_entry = True
@@ -249,10 +257,11 @@ def category_breakdown(hlo_text: str,
         if not m:
             continue
         name, rtype, op = m.groups()
+        types[name] = rtype
         if op in _SKIP_OPS:
             continue
         if op == "dot" or op == "convolution":
-            f = _dot_flops(line, rtype) if op == "dot" \
+            f = _dot_flops(line, rtype, types) if op == "dot" \
                 else _conv_flops(line, rtype)
             if f is not None:
                 matmul_flops += f

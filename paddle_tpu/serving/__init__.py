@@ -47,15 +47,15 @@ or, per request batch with the familiar surface::
 
 Profiler integration (``paddle_tpu.profiler``): gauges
 ``serving/queue_depth``, ``serving/active_slots``,
-``serving/page_util``, ``serving/tokens_per_sec``,
-``serving/decode_batch``, ``serving/mixed_rows`` (+ ``_decode`` /
+``serving/page_util``, ``serving/mixed_rows`` (+ ``_decode`` /
 ``_prefill`` split per tick); counters ``serving/tokens_generated``,
 ``serving/prefills``, ``serving/prefill_chunks``, ``serving/ticks``,
 ``serving/preemptions``, ``serving/requests_finished``,
-``serving/token_syncs``, ``serving/prefix_lookups``,
-``serving/prefix_hit_tokens``, ``cache_share/*`` (refcount traffic:
-shares, releases, cow_copies, prefix_evictions); histograms
-``serving/ttft_ms``, ``serving/prefill_queue_wait_ms``,
+``serving/drain_waited``, ``serving/drain_ready``,
+``serving/prefix_lookups``, ``serving/prefix_hit_tokens``,
+``cache_share/*`` (refcount traffic: shares, releases, cow_copies,
+prefix_evictions); histograms ``serving/ttft_ms``,
+``serving/tick_turnaround_ms``, ``serving/prefill_queue_wait_ms``,
 ``serving/chunk_wait_ms`` (admission -> first chunk open); scheduler
 policy (ISSUE 15, ``sched.py``) counters
 ``serving/aged_promotions``/``serving/budget_cuts`` and the

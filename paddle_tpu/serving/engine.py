@@ -97,10 +97,13 @@ Profiler signals: ``serving/queue_depth``, ``serving/active_slots``,
 chunk, FRESH admissions only), ``serving/requeue_wait_ms`` (histogram:
 preempt → re-prefill start — requeue cycles used to fold back into the
 submit-anchored wait, conflating scheduler delay with preemption
-cost), ``serving/tokens_per_sec``, ``serving/tokens_generated``,
+cost), ``serving/tokens_generated``,
 ``serving/prefills``, ``serving/prefill_chunks``, ``serving/ticks``,
 ``serving/preemptions``, ``serving/requests_finished``,
-``serving/token_syncs``, ``serving/prefix_lookups``,
+``serving/drain_waited`` / ``serving/drain_ready`` (drained ticks whose
+tokens the host had to wait for / found ready),
+``serving/tick_turnaround_ms`` (histogram, one observation a drained
+tick: dispatch to tokens on the host), ``serving/prefix_lookups``,
 ``serving/prefix_hit_tokens``, ``serving/mixed_rows`` (+ the
 ``_decode``/``_prefill`` split: rows of each kind in the last unified
 tick — a dispatch-site regression shows up here and in the
@@ -147,6 +150,7 @@ import jax.numpy as jnp
 from ..profiler import events as _events
 from ..profiler import recompile as _recompile
 from ..profiler import registry as _registry
+from ..profiler import trace as _ptrace
 from .paged_cache import PagePool
 from .sched import SCHED_POLICIES, ChunkScheduler, SpecKController
 from .spec import SpecConfig
@@ -269,6 +273,7 @@ class Request:
     #: holds again).
     hold: bool = False
     submit_t: float = 0.0
+    due_t: Optional[float] = None    # when it was due, if the driver said
     queue_t: float = 0.0             # (re)queue anchor: submit, or requeue
     preempts: int = 0                # times this request was preempted
     first_token_t: Optional[float] = None
@@ -287,13 +292,21 @@ class Request:
     #: surface as a served output (``run()``/coordinators skip it)
     canceled: bool = False
 
+    @property
+    def ttft_from(self) -> float:
+        """Where ``serving/ttft_ms`` runs from: the due time if the
+        driver gave one, else the submit."""
+        return self.submit_t if self.due_t is None else self.due_t
+
 
 class _Inflight:
-    __slots__ = ("tok", "meta")
+    __slots__ = ("tok", "meta", "tick", "dispatch_t")
 
-    def __init__(self, tok, meta):
+    def __init__(self, tok, meta, tick):
         self.tok = tok               # device int32 array
         self.meta = meta             # [(index_into_tok, slot, rid)]
+        self.tick = tick             # the engine's tick that computes it
+        self.dispatch_t = time.perf_counter()
 
 
 #: one selected-but-not-yet-dispatched prompt chunk of the unified tick
@@ -447,6 +460,9 @@ class ServingEngine:
         self._requests: Dict[int, Request] = {}
         self._next_rid = 0
         self._inflight: deque[_Inflight] = deque()
+        #: ticks dispatched so far: the ``tick`` id of the ``pt:step/*``
+        #: spans, laid against the device's runs of the tick program
+        self._tick_no = 0
         #: held requests whose first token has materialized — ready for
         #: export_held() (disaggregated prefill group, ISSUE 13)
         self._held_ready: set = set()
@@ -687,12 +703,17 @@ class ServingEngine:
                top_k: Optional[int] = None,
                top_p: Optional[float] = None,
                hold_after_prefill: bool = False,
-               trace_id: Optional[str] = None) -> int:
+               trace_id: Optional[str] = None,
+               due_t: Optional[float] = None) -> int:
         """Queue one request. ``temperature``/``top_k``/``top_p``
         override the engine-global sampling params for this request
         only (ignored under greedy decode). Returns its request id.
         ``trace_id`` (ISSUE 14) tags every event of this request with
         a cross-host ``trace`` attr and rides any KV handoff.
+        ``due_t`` (``time.perf_counter`` clock): when the request was
+        due, for a driver whose loop submits late; ``serving/ttft_ms``
+        then runs from it, and the ``submit`` event says how late
+        (``late_ms``).
 
         ``hold_after_prefill`` puts the request in prefill-group mode
         (ISSUE 13): the engine prefills the prompt (chunked, prefix-
@@ -719,11 +740,13 @@ class ServingEngine:
         rid = self._next_rid
         self._next_rid += 1
         if key is None:
-            key = np.asarray(jax.random.fold_in(self._base_key, rid))
+            with _ptrace.scope("submit/fold_key"):
+                key = np.asarray(jax.random.fold_in(self._base_key, rid))
         now = time.perf_counter()
         req = Request(rid=rid, prompt=p, max_new=int(max_new_tokens),
                       key=np.asarray(key, np.uint32),
-                      submit_t=now, queue_t=now, orig_prompt_len=t0,
+                      submit_t=now, due_t=due_t, queue_t=now,
+                      orig_prompt_len=t0,
                       temperature=temperature, top_k=top_k, top_p=top_p,
                       hold=bool(hold_after_prefill),
                       trace_id=trace_id)
@@ -732,8 +755,10 @@ class ServingEngine:
         # the prefix-hit-rate denominator (ISSUE 16 mesh rollup:
         # prefix_hit_tokens / prompt_tokens)
         _registry().counter("serving/prompt_tokens").add(t0)
+        late = {} if due_t is None else \
+            {"late_ms": round((now - due_t) * 1e3, 3)}
         self._emit("submit", rid, prompt_tokens=t0,
-                   max_new=int(max_new_tokens))
+                   max_new=int(max_new_tokens), **late)
         return rid
 
     def step(self) -> bool:
@@ -745,19 +770,24 @@ class ServingEngine:
         pair). Returns whether any device work was dispatched."""
         self._sched.on_tick()
         self._drain(self.config.max_inflight)
-        self._admit()
+        # host phases of the tick about to be dispatched, on the
+        # profiler's clock (profiler/trace.py: ``pt:step/*``)
+        n = self._tick_no
+        with _ptrace.scope("step/admit", tick=n):
+            self._admit()
         if self._legacy:
             dispatched = self._prefill_chunks()
-            self._grow_pages()
+            with _ptrace.scope("step/grow", tick=n):
+                self._grow_pages()
             dispatched = self._dispatch_legacy_tick() or dispatched
-        elif self._spec is not None:
-            chunks = self._collect_chunks()
-            self._grow_pages()
-            dispatched = self._dispatch_spec(chunks)
         else:
-            chunks = self._collect_chunks()
-            self._grow_pages()
-            dispatched = self._dispatch_unified(chunks)
+            with _ptrace.scope("step/chunks", tick=n):
+                chunks = self._collect_chunks()
+            with _ptrace.scope("step/grow", tick=n):
+                self._grow_pages()
+            dispatched = self._dispatch_spec(chunks) \
+                if self._spec is not None \
+                else self._dispatch_unified(chunks)
         reg = _registry()
         reg.gauge("serving/queue_depth").set(float(len(self._queue)))
         reg.gauge("serving/active_slots").set(
@@ -768,8 +798,6 @@ class ServingEngine:
     def run(self) -> Dict[int, np.ndarray]:
         """Drive until every submitted request finished; returns
         {rid: generated ids np.int32[<=max_new]}."""
-        t0 = time.perf_counter()
-        tokens0 = self._tokens_done()
         while True:
             progressed = self.step()
             if not progressed:
@@ -786,9 +814,6 @@ class ServingEngine:
                 raise RuntimeError(
                     "serving scheduler deadlock: resident requests but "
                     "nothing dispatchable")
-        wall = max(time.perf_counter() - t0, 1e-9)
-        done = self._tokens_done() - tokens0
-        _registry().gauge("serving/tokens_per_sec").set(done / wall)
         return {rid: np.asarray(r.out, np.int32)
                 for rid, r in self._requests.items()
                 if r.done and not r.canceled}
@@ -801,6 +826,17 @@ class ServingEngine:
         """True when nothing is queued, resident, or in flight."""
         return (not self._queue and not self._inflight
                 and all(r is None for r in self._slot_rid))
+
+    def tokens_so_far(self, rid: int) -> Tuple[int, ...]:
+        """The tokens request ``rid`` has been handed so far (those the
+        host has drained), finished or not."""
+        return tuple(self._requests[rid].out)
+
+    def served_weights(self) -> Tuple[dict, dict]:
+        """The weights as the tick reads them: ``(stacked, other)``,
+        block parameters stacked ``[L, ...]`` by suffix and the rest by
+        name — what ``models.gpt.gpt_ragged_apply`` takes."""
+        return self._stacked, self._other
 
     def reset_results(self) -> None:
         """Forget finished requests (long-running host keeps memory flat)."""
@@ -1200,36 +1236,44 @@ class ServingEngine:
         ``target`` remain. The ONLY place device data reaches the host."""
         while len(self._inflight) > target:
             ent = self._inflight.popleft()
-            toks = np.asarray(ent.tok)
-            _registry().counter("serving/token_syncs").add(1)
-            now = time.perf_counter()
-            for idx, slot, rid in ent.meta:
-                req = self._requests[rid]
-                if req.done:
-                    continue        # EOS discovered while this was in flight
-                tok = int(toks[idx])
-                req.out.append(tok)
-                _registry().counter("serving/tokens_generated").add(1)
-                if req.first_token_t is None:
-                    req.first_token_t = now
-                    _registry().histogram("serving/ttft_ms").observe(
-                        (now - req.submit_t) * 1000.0)
-                    self._emit("first_token", rid, slot=slot)
-                if req.hold:
-                    # prefill-group mode: the first token is the LAST
-                    # thing this engine computes for the request — park
-                    # it for export; eos/max_new stops are the decode
-                    # group's business (export_held ships the token)
-                    self._held_ready.add(rid)
-                    continue
-                eos = self.config.eos_token_id
-                # max_new counts tokens wanted since the LAST (re)queue —
-                # preemption moved earlier output into the prompt and
-                # shrank max_new to the remainder
-                if eos is not None and tok == eos:
-                    self._finish(slot, rid, reason="eos")
-                elif len(req.out) >= req.max_new:
-                    self._finish(slot, rid, reason="max_new")
+            # did the host get here before the device finished the tick?
+            waited = not ent.tok.is_ready()
+            with _ptrace.scope("step/drain", tick=ent.tick,
+                               waited=int(waited)):
+                toks = np.asarray(ent.tok)
+                now = time.perf_counter()
+                for idx, slot, rid in ent.meta:
+                    req = self._requests[rid]
+                    if req.done:
+                        continue    # EOS discovered while in flight
+                    tok = int(toks[idx])
+                    req.out.append(tok)
+                    _registry().counter("serving/tokens_generated").add(1)
+                    if req.first_token_t is None:
+                        req.first_token_t = now
+                        _registry().histogram("serving/ttft_ms").observe(
+                            (now - req.ttft_from) * 1000.0)
+                        self._emit("first_token", rid, slot=slot)
+                    if req.hold:
+                        # prefill-group mode: the first token is the LAST
+                        # thing this engine computes for the request — park
+                        # it for export; eos/max_new stops are the decode
+                        # group's business (export_held ships the token)
+                        self._held_ready.add(rid)
+                        continue
+                    eos = self.config.eos_token_id
+                    # max_new counts tokens wanted since the LAST (re)queue —
+                    # preemption moved earlier output into the prompt and
+                    # shrank max_new to the remainder
+                    if eos is not None and tok == eos:
+                        self._finish(slot, rid, reason="eos")
+                    elif len(req.out) >= req.max_new:
+                        self._finish(slot, rid, reason="max_new")
+            reg = _registry()
+            reg.counter("serving/drain_waited" if waited
+                        else "serving/drain_ready").add(1)
+            reg.histogram("serving/tick_turnaround_ms").observe(
+                (now - ent.dispatch_t) * 1000.0)
 
     def _insert_prefix(self, slot: int, tokens: np.ndarray,
                        written: int) -> None:
@@ -1646,6 +1690,49 @@ class ServingEngine:
         ticking = self._ticking_slots()
         if not ticking and not chunks:
             return False
+        with _ptrace.scope("step/build", tick=self._tick_no):
+            args, finishers = self._build_unified(chunks, ticking)
+        self._note_avals(self._tick_site, self._tick, args)
+        with _ptrace.scope("step/dispatch", tick=self._tick_no), \
+                _quiet_donation():
+            tok, self._last_tok = self._store_pools(self._tick(*args))
+        meta = [(s, s, self._slot_rid[s]) for s in ticking]
+        meta += [(s, s, rid) for s, rid in finishers]
+        if meta:
+            # chunk-only ticks (no decodes, no finishers) emit nothing
+            # worth syncing — queueing them would stall the host on a
+            # token vector nobody reads once the window fills
+            self._inflight.append(_Inflight(tok, meta, self._tick_no))
+        self._tick_no += 1
+        self.max_inflight_seen = max(self.max_inflight_seen,
+                                     len(self._inflight))
+        for s in ticking:
+            self._slot_len[s] += 1
+            self._slot_dispatched[s] += 1
+        for s, rid, start, end, t0 in chunks:
+            self._slot_len[s] = end
+            self._emit("chunk", rid, slot=s, start=start, end=end,
+                       final=bool(end >= t0))
+            if end >= t0:
+                self._slot_dispatched[s] = 1
+                _registry().counter("serving/prefills").add(1)
+            # publish the pages this chunk completed (progressively: a
+            # long shared prompt becomes hittable page-by-page)
+            self._insert_prefix(s, self._requests[rid].prompt, end)
+        reg = _registry()
+        reg.counter("serving/ticks").add(1)
+        if chunks:
+            reg.counter("serving/prefill_chunks").add(len(chunks))
+        reg.gauge("serving/mixed_rows").set(float(len(ticking)
+                                                  + len(chunks)))
+        reg.gauge("serving/mixed_rows_decode").set(float(len(ticking)))
+        reg.gauge("serving/mixed_rows_prefill").set(float(len(chunks)))
+        return True
+
+    def _build_unified(self, chunks: List[_Chunk],
+                       ticking: List[int]) -> tuple:
+        """The mixed-row tick's arguments (numpy row metadata) and the
+        ``(slot, rid)`` pairs whose prompt this tick finishes."""
         ns = self.config.num_slots
         w = self.prefill_chunk
         npf = self.config.prefill_chunks_per_tick
@@ -1698,42 +1785,8 @@ class ServingEngine:
                 np.ascontiguousarray(self._temps),
                 np.ascontiguousarray(self._topks),
                 np.ascontiguousarray(self._topps))
-        args = (self._stacked, self._other) + self._pool_args() + tail
-        self._note_avals(self._tick_site, self._tick, args)
-        with _quiet_donation():
-            tok, self._last_tok = self._store_pools(self._tick(*args))
-        meta = [(s, s, self._slot_rid[s]) for s in ticking]
-        meta += [(s, s, rid) for s, rid in finishers]
-        if meta:
-            # chunk-only ticks (no decodes, no finishers) emit nothing
-            # worth syncing — queueing them would stall the host on a
-            # token vector nobody reads once the window fills
-            self._inflight.append(_Inflight(tok, meta))
-        self.max_inflight_seen = max(self.max_inflight_seen,
-                                     len(self._inflight))
-        for s in ticking:
-            self._slot_len[s] += 1
-            self._slot_dispatched[s] += 1
-        for s, rid, start, end, t0 in chunks:
-            self._slot_len[s] = end
-            self._emit("chunk", rid, slot=s, start=start, end=end,
-                       final=bool(end >= t0))
-            if end >= t0:
-                self._slot_dispatched[s] = 1
-                _registry().counter("serving/prefills").add(1)
-            # publish the pages this chunk completed (progressively: a
-            # long shared prompt becomes hittable page-by-page)
-            self._insert_prefix(s, self._requests[rid].prompt, end)
-        reg = _registry()
-        reg.counter("serving/ticks").add(1)
-        if chunks:
-            reg.counter("serving/prefill_chunks").add(len(chunks))
-        reg.gauge("serving/decode_batch").set(float(len(ticking)))
-        reg.gauge("serving/mixed_rows").set(float(len(ticking)
-                                                  + len(chunks)))
-        reg.gauge("serving/mixed_rows_decode").set(float(len(ticking)))
-        reg.gauge("serving/mixed_rows_prefill").set(float(len(chunks)))
-        return True
+        return ((self._stacked, self._other) + self._pool_args() + tail,
+                finishers)
 
     def _make_unified_tick(self):
         """The ONE compiled hot-path program: every resident decode and
@@ -1814,9 +1867,10 @@ class ServingEngine:
                     stacked, other, (kpool, vpool, kscale, vscale),
                     last_tok, pf_toks, tok_pos, tok_limit, row_tab,
                     row_pos0, row_len, sample_ix, has_chunks)
-                nxt = self._sample_tok(logits, keys, sample_pos, temps,
-                                       top_ks, top_ps)
-                new_last = jnp.where(emit, nxt, last_tok)
+                with _ptrace.annotate("tick/sample"):
+                    nxt = self._sample_tok(logits, keys, sample_pos,
+                                           temps, top_ks, top_ps)
+                    new_last = jnp.where(emit, nxt, last_tok)
                 return kpool, vpool, kscale, vscale, nxt, new_last
         else:
             def tick(stacked, other, kpool, vpool, last_tok, pf_toks,
@@ -1829,9 +1883,10 @@ class ServingEngine:
                     stacked, other, (kpool, vpool), last_tok, pf_toks,
                     tok_pos, tok_limit, row_tab, row_pos0, row_len,
                     sample_ix, has_chunks)
-                nxt = self._sample_tok(logits, keys, sample_pos, temps,
-                                       top_ks, top_ps)
-                new_last = jnp.where(emit, nxt, last_tok)
+                with _ptrace.annotate("tick/sample"):
+                    nxt = self._sample_tok(logits, keys, sample_pos,
+                                           temps, top_ks, top_ps)
+                    new_last = jnp.where(emit, nxt, last_tok)
                 return kpool, vpool, nxt, new_last
 
         return tick
@@ -2097,6 +2152,7 @@ class ServingEngine:
                     np.bool_(len(chunks) > 0), np.bool_(has_drafts))
         args = (self._stacked, self._other) + self._pool_args() + tail
         self._note_avals(self._tick_site, self._tick, args)
+        dispatch_t = time.perf_counter()
         with _quiet_donation():
             tok_m, acc = self._store_pools(self._tick(*args))
 
@@ -2159,8 +2215,10 @@ class ServingEngine:
         # ---- synchronous absorb: acceptance, rewind, finishes ----
         toks = np.asarray(tok_m)                       # [ns, 1+k]
         accs = np.asarray(acc)
-        reg.counter("serving/token_syncs").add(1)
         now = time.perf_counter()
+        reg.counter("serving/drain_waited").add(1)      # an inline sync
+        reg.histogram("serving/tick_turnaround_ms").observe(
+            (now - dispatch_t) * 1000.0)
         eos = self.config.eos_token_id
         for s, rid in [(t, self._slot_rid[t]) for t in ticking] \
                 + finishers:
@@ -2178,7 +2236,7 @@ class ServingEngine:
                 if req.first_token_t is None:
                     req.first_token_t = now
                     reg.histogram("serving/ttft_ms").observe(
-                        (now - req.submit_t) * 1000.0)
+                        (now - req.ttft_from) * 1000.0)
                     self._emit("first_token", rid, slot=s)
                 if eos is not None and tok == eos:
                     finished = "eos"
@@ -2236,10 +2294,10 @@ class ServingEngine:
             self._slot_dispatched[s] = len(req.out)
             if finished is not None:
                 self._finish(s, rid, reason=finished)
+        self._tick_no += 1
         reg.counter("serving/ticks").add(1)
         if chunks:
             reg.counter("serving/prefill_chunks").add(len(chunks))
-        reg.gauge("serving/decode_batch").set(float(len(ticking)))
         reg.gauge("serving/mixed_rows").set(
             float(len(ticking) + len(chunks)))
         reg.gauge("serving/mixed_rows_decode").set(float(len(ticking)))
@@ -2312,7 +2370,8 @@ class ServingEngine:
                    final=bool(end >= t0))
         if end >= t0:                # final chunk: tok0 is real
             self._last_tok = self._last_tok.at[s].set(tok0[0])
-            self._inflight.append(_Inflight(tok0, [(0, s, req.rid)]))
+            self._inflight.append(_Inflight(tok0, [(0, s, req.rid)],
+                                            self._tick_no))
             self.max_inflight_seen = max(self.max_inflight_seen,
                                          len(self._inflight))
             self._slot_dispatched[s] = 1
@@ -2341,7 +2400,8 @@ class ServingEngine:
             self.pool.k, self.pool.v, tok = self._tick(*args)
         self._last_tok = tok
         meta = [(s, s, self._slot_rid[s]) for s in ticking]
-        self._inflight.append(_Inflight(tok, meta))
+        self._inflight.append(_Inflight(tok, meta, self._tick_no))
+        self._tick_no += 1
         self.max_inflight_seen = max(self.max_inflight_seen,
                                      len(self._inflight))
         for s in ticking:
@@ -2349,7 +2409,6 @@ class ServingEngine:
             self._slot_dispatched[s] += 1
         _registry().counter("serving/ticks").add(1)
         reg = _registry()
-        reg.gauge("serving/decode_batch").set(float(len(ticking)))
         reg.gauge("serving/mixed_rows").set(float(len(ticking)))
         reg.gauge("serving/mixed_rows_decode").set(float(len(ticking)))
         reg.gauge("serving/mixed_rows_prefill").set(0.0)
