@@ -46,6 +46,11 @@ TOL_FLASH_FWD = 2e-2
 TOL_FLASH_BWD = 3e-2
 TOL_RAGGED = 2e-2
 
+#: the most a ``submit()`` may take while ticks are in flight, ms: it is
+#: tens of microseconds, and was two to three ticks (137 ms at 1.3B) while
+#: the request's sampling key was folded on the serving device
+SUBMIT_LIMIT_MS = 5.0
+
 #: (arrival tick, prompt length, new tokens, length of the prefix shared
 #: with the other requests that name one). Ten requests on eight slots:
 #: admission, chunked prefill (32 tokens a tick, so 1500 is 47 chunks),
@@ -303,7 +308,18 @@ def phase_kernels(flash_shape, pool_shape, nps: int, chunk: int) -> None:
 # ---------------------------------------------------------------------------
 # phase 2: serve
 # ---------------------------------------------------------------------------
-def phase_serve(cfg, num_slots: int, page_size: int, requests) -> dict:
+def check_submits(submits, limit_ms: float) -> None:
+    """``submit()`` asks nothing of the serving device, so it does not wait
+    for the ticks in flight (on the device's queue it took two to three of
+    them). ``submits``: (ticks in flight, host ms) of each call."""
+    behind = [ms for inflight, ms in submits if inflight]
+    check(all(ms <= limit_ms for ms in behind),
+          f"submit() waited behind the ticks in flight: {behind} ms, "
+          f"each may take {limit_ms}")
+
+
+def phase_serve(cfg, num_slots: int, page_size: int, requests,
+                submit_limit_ms: float = SUBMIT_LIMIT_MS) -> dict:
     import jax
     import jax.numpy as jnp
 
@@ -341,6 +357,7 @@ def phase_serve(cfg, num_slots: int, page_size: int, requests) -> dict:
                                     "tokens_generated", "prefill_chunks")}
     pending = sorted(range(len(requests)), key=lambda i: requests[i][0])
     rids = {}
+    submits = []        # (ticks in flight, host ms) of each submit()
     kinds = {"mixed": 0, "decode_only": 0, "prefill_only": 0}
     tick, first_tick_s = 0, None
     with warnings.catch_warnings(record=True) as caught:
@@ -349,7 +366,11 @@ def phase_serve(cfg, num_slots: int, page_size: int, requests) -> dict:
         while pending or not eng.idle():
             while pending and requests[pending[0]][0] <= tick:
                 i = pending.pop(0)
+                inflight = len(eng._inflight)
+                t_submit = time.perf_counter()
                 rids[i] = eng.submit(prompts[i], requests[i][2])
+                submits.append(
+                    (inflight, (time.perf_counter() - t_submit) * 1e3))
             if eng.step():
                 dec = reg.gauge("serving/mixed_rows_decode").value
                 pre = reg.gauge("serving/mixed_rows_prefill").value
@@ -390,6 +411,7 @@ def phase_serve(cfg, num_slots: int, page_size: int, requests) -> dict:
           f"the tick is not one site traced once: "
           f"{[(s, traces.get(s)) for s in eng.compiled_sites]}")
     on_default_platform((eng.pool.k, eng.pool.v), "page pools after the run")
+    check_submits(submits, submit_limit_ms)
 
     # one request's greedy tokens against the dense generate() path
     probe = min(range(len(requests)), key=lambda i: requests[i][1])
@@ -411,7 +433,7 @@ def phase_serve(cfg, num_slots: int, page_size: int, requests) -> dict:
                                  - base["prefill_chunks"]),
            "prefix_hit_tokens": int(hits), "tick_kinds": kinds,
            "dense_match": f"{match}/{len(paged)}", "dense_s": dense_s,
-           "peak_bytes": peak_bytes()}
+           "submits": submits, "peak_bytes": peak_bytes()}
     say("serve", f"{len(requests)} requests on {num_slots} slots, all "
         f"finished; set-up {setup_s:.1f} s, first tick (compile) "
         f"{first_tick_s:.1f} s, run {run_s:.1f} s wall to "
@@ -420,6 +442,8 @@ def phase_serve(cfg, num_slots: int, page_size: int, requests) -> dict:
         f"{out['prefill_chunks']} tokens={out['tokens']} "
         f"prefix_hit_tokens={out['prefix_hit_tokens']} sites="
         f"{eng.compiled_sites} traced once, no donation warning")
+    say("serve", "submit() host ms (ticks in flight): "
+        + ", ".join(f"{ms:.3f} ({inflight})" for inflight, ms in submits))
     say("serve", f"greedy paged vs dense generate() (prompt "
         f"{requests[probe][1]}): {out['dense_match']} tokens equal, first "
         f"equal; dense compile+run {dense_s:.1f} s; peak so far "

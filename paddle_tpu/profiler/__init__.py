@@ -64,9 +64,10 @@ counters ``serving/tokens_generated``, ``serving/prefills``,
 ``serving/ticks``, ``serving/preemptions``,
 ``serving/requests_finished`` and ``serving/drain_waited`` /
 ``serving/drain_ready`` (deferred tick outputs the host waited for /
-found ready); histograms ``serving/ttft_ms`` and
+found ready); histograms ``serving/ttft_ms``,
 ``serving/tick_turnaround_ms`` (dispatch to tokens on the host, one
-observation a drained tick); host spans ``pt:step/*`` and
+observation a drained tick) and ``serving/submit_ms`` (host time of
+each ``submit()``); host spans ``pt:step/*`` and
 ``pt:submit/fold_key`` inside any live profiler session; gauges
 ``serving/mixed_rows`` / ``serving/mixed_rows_decode`` /
 ``serving/mixed_rows_prefill`` (the prefill-vs-decode row mix of the

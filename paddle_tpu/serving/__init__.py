@@ -45,6 +45,13 @@ or, per request batch with the familiar surface::
 
     ids, _ = gpt_model.generate(tokens, max_new_tokens=64, paged=True)
 
+The process needs the CPU backend beside the accelerator
+(``JAX_PLATFORMS`` unset, or ``tpu,cpu``): ``submit()`` folds a
+request's default sampling key on the process's own CPU device, so that
+a request never queues behind the ticks in flight on the serving device
+(ISSUE 25). Without it the engine's constructor raises and names the
+setting.
+
 Profiler integration (``paddle_tpu.profiler``): gauges
 ``serving/queue_depth``, ``serving/active_slots``,
 ``serving/page_util``, ``serving/mixed_rows`` (+ ``_decode`` /
@@ -55,7 +62,8 @@ Profiler integration (``paddle_tpu.profiler``): gauges
 ``serving/prefix_lookups``, ``serving/prefix_hit_tokens``,
 ``cache_share/*`` (refcount traffic: shares, releases, cow_copies,
 prefix_evictions); histograms ``serving/ttft_ms``,
-``serving/tick_turnaround_ms``, ``serving/prefill_queue_wait_ms``,
+``serving/tick_turnaround_ms``, ``serving/submit_ms`` (host time of
+each ``submit()``), ``serving/prefill_queue_wait_ms``,
 ``serving/chunk_wait_ms`` (admission -> first chunk open); scheduler
 policy (ISSUE 15, ``sched.py``) counters
 ``serving/aged_promotions``/``serving/budget_cuts`` and the

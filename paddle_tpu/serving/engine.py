@@ -103,7 +103,8 @@ cost), ``serving/tokens_generated``,
 ``serving/drain_waited`` / ``serving/drain_ready`` (drained ticks whose
 tokens the host had to wait for / found ready),
 ``serving/tick_turnaround_ms`` (histogram, one observation a drained
-tick: dispatch to tokens on the host), ``serving/prefix_lookups``,
+tick: dispatch to tokens on the host), ``serving/submit_ms``
+(histogram: host time of each ``submit()``), ``serving/prefix_lookups``,
 ``serving/prefix_hit_tokens``, ``serving/mixed_rows`` (+ the
 ``_decode``/``_prefill`` split: rows of each kind in the last unified
 tick — a dispatch-site regression shows up here and in the
@@ -160,6 +161,27 @@ __all__ = ["ServingConfig", "ServingEngine", "Request", "SpecConfig"]
 #: engine ids stamped on every event (``eng`` attr) so co-resident
 #: engines' timelines don't alias in the process-global log
 _ENGINE_SEQ = iter(range(1 << 20))
+
+
+#: a request's default sampling key: the request id folded into the
+#: engine's base key on the host's CPU backend, where ``__init__`` commits
+#: the base key. ``submit()`` calls it, so a request puts nothing on the
+#: serving device's queue and waits for nothing behind the ticks in flight
+_fold_key = jax.jit(jax.random.fold_in)
+
+
+def _host_device():
+    """This process's own CPU device (under ``jax.distributed`` the global
+    list starts with process 0's, which no other rank can address)."""
+    try:
+        return jax.local_devices(backend="cpu")[0]
+    except RuntimeError as e:
+        raise RuntimeError(
+            "ServingEngine makes each request's sampling key on the host's "
+            "CPU backend, so that submit() never queues behind the ticks in "
+            "flight on the accelerator, and this process has no CPU backend: "
+            "leave JAX_PLATFORMS unset, or name cpu after the accelerator "
+            f"(JAX_PLATFORMS=tpu,cpu); jax said: {e}") from e
 
 
 def _proc_index() -> int:
@@ -476,7 +498,11 @@ class ServingEngine:
         self._temps = np.full(b_slots, cfg.temperature, np.float32)
         self._topks = np.full(b_slots, cfg.top_k, np.int32)
         self._topps = np.full(b_slots, cfg.top_p, np.float32)
-        self._base_key = np.asarray(jax.random.PRNGKey(cfg.seed))
+        # on this process's CPU device, with ``_fold_key`` compiled for it
+        # now: ``submit()`` folds there, and compiles nothing under traffic
+        self._base_key = jax.device_put(jax.random.PRNGKey(cfg.seed),
+                                        _host_device())
+        _fold_key(self._base_key, np.uint32(0))
         # compiled programs. Unified (default): ONE mixed-row tick site
         # serving decodes AND prefill chunks, asserted single-trace.
         # Legacy: the pre-unification pair (decode tick + suffix-prefill
@@ -715,6 +741,14 @@ class ServingEngine:
         then runs from it, and the ``submit`` event says how late
         (``late_ms``).
 
+        Nothing here touches the serving device. The default ``key``
+        (``fold_in(PRNGKey(config.seed), rid)``, whatever the decode
+        mode: a hand-off to a sampling engine carries it) is made on
+        the host's CPU backend. Folded on the serving device it queued
+        behind every tick in flight, and the loop that calls
+        ``submit()`` between steps stood still for three ticks
+        (ISSUE 25). ``serving/submit_ms`` has this call's host time.
+
         ``hold_after_prefill`` puts the request in prefill-group mode
         (ISSUE 13): the engine prefills the prompt (chunked, prefix-
         cached, preemptible — all the normal machinery) and samples the
@@ -723,6 +757,7 @@ class ServingEngine:
         engine and releases the slot (``release_exported``). Held slots
         never ride decode ticks, so a prefill-group engine's tick only
         ever carries chunk rows."""
+        began = time.perf_counter()
         p = np.asarray(prompt_ids, np.int32).reshape(-1)
         t0 = p.shape[0]
         cap = self.pool.slot_capacity
@@ -741,7 +776,7 @@ class ServingEngine:
         self._next_rid += 1
         if key is None:
             with _ptrace.scope("submit/fold_key"):
-                key = np.asarray(jax.random.fold_in(self._base_key, rid))
+                key = np.asarray(_fold_key(self._base_key, np.uint32(rid)))
         now = time.perf_counter()
         req = Request(rid=rid, prompt=p, max_new=int(max_new_tokens),
                       key=np.asarray(key, np.uint32),
@@ -759,6 +794,8 @@ class ServingEngine:
             {"late_ms": round((now - due_t) * 1e3, 3)}
         self._emit("submit", rid, prompt_tokens=t0,
                    max_new=int(max_new_tokens), **late)
+        _registry().histogram("serving/submit_ms").observe(
+            (time.perf_counter() - began) * 1000.0)
         return rid
 
     def step(self) -> bool:
@@ -2357,11 +2394,12 @@ class ServingEngine:
         chunk = self.prefill_chunk
         toks = np.zeros((1, chunk), np.int32)
         toks[0, :end - start] = req.prompt[start:end]
-        page_row = np.ascontiguousarray(self.pool.tables[s])
+        # copies, as in ``_dispatch_legacy_tick``
+        page_row = self.pool.tables[s].copy()
         args = (self._stacked, self._other, self.pool.k, self.pool.v,
                 toks, np.int32(start), np.int32(t0), page_row, req.key,
-                self._temps[s:s + 1], self._topks[s:s + 1],
-                self._topps[s:s + 1])
+                self._temps[s:s + 1].copy(), self._topks[s:s + 1].copy(),
+                self._topps[s:s + 1].copy())
         self._note_avals(self._prefill_site, self._prefill, args)
         with _quiet_donation():
             self.pool.k, self.pool.v, tok0 = self._prefill(*args)
@@ -2387,14 +2425,12 @@ class ServingEngine:
         ticking = self._ticking_slots()
         if not ticking:
             return False
-        tab = np.ascontiguousarray(self.pool.tables)
-        pos = np.ascontiguousarray(self._slot_len)
-        keys = np.ascontiguousarray(self._keys)
+        # copies: the CPU backend may read a numpy argument in place, after
+        # this method has moved on and written the next tick's state there
         args = (self._stacked, self._other, self.pool.k, self.pool.v,
-                tab, pos, self._last_tok, keys,
-                np.ascontiguousarray(self._temps),
-                np.ascontiguousarray(self._topks),
-                np.ascontiguousarray(self._topps))
+                self.pool.tables.copy(), self._slot_len.copy(),
+                self._last_tok, self._keys.copy(), self._temps.copy(),
+                self._topks.copy(), self._topps.copy())
         self._note_avals(self._tick_site, self._tick, args)
         with _quiet_donation():
             self.pool.k, self.pool.v, tok = self._tick(*args)

@@ -58,15 +58,34 @@ def test_kernel_check_catches_a_wrong_kernel(monkeypatch):
         chip_smoke.check_ragged(41, 4, 2, 64, 8, 8, False)
 
 
+SERVE_TOY = GPTConfig(vocab_size=128, hidden_size=64, num_layers=2,
+                      num_heads=2, max_seq_len=256)
+SERVE_REQUESTS = ((0, 6, 8, 0), (0, 40, 8, 16), (0, 120, 6, 0), (2, 9, 10, 0),
+                  (30, 48, 8, 16), (34, 5, 6, 0))
+
+
 def test_serve_rehearsal():
-    cfg = GPTConfig(vocab_size=128, hidden_size=64, num_layers=2,
-                    num_heads=2, max_seq_len=256)
-    out = chip_smoke.phase_serve(cfg, 4, 4, (
-        (0, 6, 8, 0), (0, 40, 8, 16), (0, 120, 6, 0), (2, 9, 10, 0),
-        (30, 48, 8, 16), (34, 5, 6, 0)))
+    # served from the second CPU device, as the chip serves beside the
+    # host's: on the first one the key's fold queues behind the toy ticks.
+    # The limit is ten times the chip's, for a host that six workers share
+    with jax.default_device(jax.local_devices(backend="cpu")[1]):
+        out = chip_smoke.phase_serve(
+            SERVE_TOY, 4, 4, SERVE_REQUESTS,
+            submit_limit_ms=10 * chip_smoke.SUBMIT_LIMIT_MS)
     assert out["prefix_hit_tokens"] >= 16
     assert out["tokens"] == 8 + 8 + 6 + 10 + 8 + 6
     assert out["tick_kinds"]["mixed"] and out["tick_kinds"]["decode_only"]
+    assert len(out["submits"]) == 6
+    assert sum(1 for inflight, _ in out["submits"] if inflight) >= 2
+
+
+def test_a_submit_behind_the_ticks_in_flight_fails_the_serve_phase():
+    limit = chip_smoke.SUBMIT_LIMIT_MS
+    # with nothing in flight a slow submit proves nothing
+    chip_smoke.check_submits([(0, 9 * limit), (3, 0.1), (2, limit)], limit)
+    with pytest.raises(chip_smoke.SmokeFailure, match="submit"):
+        chip_smoke.check_submits([(0, 0.1), (3, 0.2), (2, 1.1 * limit),
+                                  (3, 0.1)], limit)
 
 
 def test_train_and_multichip_rehearsal():
