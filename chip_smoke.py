@@ -11,6 +11,10 @@ finds, and checks what comes out by the repo's own means:
               every Pallas gate reads "compile with Mosaic"
   1 kernels   flash fwd+bwd and the ragged paged kernel (bf16 and int8
               pools), compiled by Mosaic, against their references
+  1b experts  the drop-less expert layer at OLMoE's widths (64 experts of
+              1024, 8 a token, 4096 tokens), forward and gradients against
+              the float32 reference, balanced and with one expert forced
+              to over four times its share: nothing is dropped
   2 serve     ServingEngine.submit/step/run, ten requests over ~150 ticks
   3 train     HybridPipelineTrainer.step, bench.py's headline knobs
   4 multichip the same trainer on dp2 x tp2 and pp2 x tp2, and the ZeRO /
@@ -45,6 +49,15 @@ import numpy as np
 TOL_FLASH_FWD = 2e-2
 TOL_FLASH_BWD = 3e-2
 TOL_RAGGED = 2e-2
+# the drop-less expert layer against models/olmoe_reference.moe in f32 at
+# "highest" on the same bf16-rounded inputs: the program rounds the gated
+# product, each expert's output and the routing weight to bf16 (2^-8 each,
+# up to 8 experts summed a token); the gradients pass through those
+# roundings once more. Both route on the same bf16 inputs with f32
+# accumulation, so a token whose 8th choice differs (it would err by about
+# an eighth of its output) is not expected and not allowed for.
+TOL_EXPERTS_FWD = 2e-2
+TOL_EXPERTS_BWD = 3e-2
 
 #: the most a ``submit()`` may take while ticks are in flight, ms: it is
 #: tens of microseconds, and was two to three ticks (137 ms at 1.3B) while
@@ -302,6 +315,88 @@ def phase_kernels(flash_shape, pool_shape, nps: int, chunk: int) -> None:
                 f"{'int8+scales' if int8 else 'bf16'} T={t}: "
                 f"err={err:.2e} (tol {TOL_RAGGED})")
     say("kernels", f"{time.perf_counter() - t0:.1f} s, "
+        f"peak so far {_gb(peak_bytes())}")
+
+
+def check_dropless(t: int, h: int, f: int, e: int, k: int, dtype,
+                   forced: bool) -> dict:
+    """``dropless_moe`` forward and gradients on ``t`` tokens against the
+    float32 reference on the same (dtype-rounded) seeded inputs.
+    ``forced``: the tokens share a direction that the router's column 0
+    is aligned with, so expert 0 is nearly every token's choice."""
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.distributed.moe import dropless_moe
+    from paddle_tpu.models import olmoe_reference as ref
+
+    ks = jax.random.split(jax.random.PRNGKey(1 + forced), 6)
+    x = jax.random.normal(ks[0], (t, h), jnp.float32)
+    router = jax.random.normal(ks[1], (h, e), jnp.float32) * 0.02
+    if forced:
+        x = x + 1.0
+        router = router.at[:, 0].add(4.0 / h)
+    args = [a.astype(dtype) for a in (
+        x, router, jax.random.normal(ks[2], (e, h, f), jnp.float32) * 0.02,
+        jax.random.normal(ks[3], (e, h, f), jnp.float32) * 0.02,
+        jax.random.normal(ks[4], (e, f, h), jnp.float32) * 0.02)]
+    w = jax.random.normal(ks[5], (t, h), jnp.float32)
+
+    def program(*a):
+        return dropless_moe(*a, top_k=k)
+
+    def reference(x_, gate, w_gate, w_up, w_down):
+        return ref.moe(x_, {"mlp.gate": gate, "mlp.w_gate": w_gate,
+                            "mlp.w_up": w_up, "mlp.w_down": w_down}, k)
+
+    def loss_of(fn):
+        def loss(*a):
+            y, balance, z = fn(*a)[:3]
+            return jnp.sum(y.astype(jnp.float32) * w) + balance + z
+        return loss
+
+    out = jax.jit(program)(*args)
+    grads = jax.jit(jax.grad(loss_of(program), range(5)))(*args)
+    f32 = [a.astype(jnp.float32) for a in args]
+    # the reference compiles one expert and loops: jitted as a whole, its
+    # 64 experts unroll into a program that takes minutes to compile
+    with jax.default_matmul_precision("highest"):
+        want = reference(*f32)
+        want_g = jax.grad(loss_of(reference), range(5))(*f32)
+    jax.block_until_ready((out, grads, want, want_g))
+    rows = np.asarray(out[3])
+    load = float(rows.max()) * e / (t * k)
+    errs = {"y": _nerr(out[0], want[0]),
+            "balance": abs(float(out[1]) - float(want[1])) / float(want[1]),
+            "z": abs(float(out[2]) - float(want[2])) / float(want[2])}
+    errs.update({n: _nerr(g, wg) for n, g, wg in zip(
+        ("dx", "drouter", "dw_gate", "dw_up", "dw_down"), grads, want_g)})
+    check(np.isfinite(list(errs.values())).all(),
+          f"experts non-finite {errs}")
+    check(int(rows.sum()) == t * k,
+          f"experts: {t * k - int(rows.sum())} assignments dropped")
+    check(load >= 4.0 if forced else load < 2.0,
+          f"experts: load max/mean {load} with forced={forced}")
+    check(max(errs["y"], errs["balance"], errs["z"]) <= TOL_EXPERTS_FWD,
+          f"experts fwd error {errs}")
+    check(max(errs[n] for n in ("dx", "drouter", "dw_gate", "dw_up",
+                                "dw_down")) <= TOL_EXPERTS_BWD,
+          f"experts bwd error {errs}")
+    return {**errs, "load": load}
+
+
+def phase_experts(t: int, h: int, f: int, e: int, k: int) -> None:
+    import jax.numpy as jnp
+
+    t0 = time.perf_counter()
+    for forced in (False, True):
+        r = check_dropless(t, h, f, e, k, jnp.bfloat16, forced)
+        say("experts", f"dropless_moe T={t} h={h} {e} experts of {f}, "
+            f"top-{k}, {'one expert forced' if forced else 'balanced'}: "
+            f"load max/mean {r.pop('load'):.2f}, dropped 0; vs float32 "
+            "reference " + " ".join(f"{n}={v:.2e}" for n, v in r.items())
+            + f" (tol {TOL_EXPERTS_FWD}/{TOL_EXPERTS_BWD})")
+    say("experts", f"{time.perf_counter() - t0:.1f} s, "
         f"peak so far {_gb(peak_bytes())}")
 
 
@@ -636,6 +731,10 @@ def main() -> int:
     run("kernels", lambda: phase_kernels(
         (2, cfg.max_seq_len, heads, head_dim),
         (slots * nps + 1, page, heads, head_dim), nps, chunk=2 * page))
+    olmoe = GPTConfig.olmoe_1b_7b()
+    run("experts", lambda: phase_experts(
+        olmoe.max_seq_len, olmoe.hidden_size, olmoe.moe_expert_width,
+        olmoe.moe_num_experts, olmoe.moe_top_k))
     run("serve", lambda: phase_serve(cfg, slots, page, SERVE_REQUESTS))
     run("train", lambda: phase_train(cfg, micro=2, n_micro=6, steps=4))
     if len(jax.devices()) >= 4 and "train" not in failed:

@@ -184,11 +184,13 @@ class HybridPipelineTrainer:
             picture — per-scope spans, counters, tokens/sec + steps/sec
             over the enabled window, phases, retraces."""
         _check_protocol(model)
-        # MoE composes with pp: blocks return (h, aux) and pipeline_apply
-        # carries the load-balance scalar across the schedule (stage_aux)
+        # MoE composes with pp: a block leaves its auxiliary loss, already
+        # weighted, in ``block.aux_loss`` and its counts in
+        # ``block.aux_stats``; pipeline_apply carries both across the
+        # schedule (stage_aux) and the step hands the counts out
+        # (``aux_stats``)
         cfg = getattr(model, "config", None)
         self.moe = bool(getattr(cfg, "moe_num_experts", 0))
-        self.moe_aux_weight = float(getattr(cfg, "moe_aux_weight", 0.0))
         self.model = model
         self.optimizer = optimizer
         self.strategy = strategy or DistributedStrategy()
@@ -661,12 +663,18 @@ class HybridPipelineTrainer:
         self._step = 0
         self._n_batch_args: Optional[int] = None
         self._step_fn = None
+        #: the blocks' ``aux_stats`` of the last step, summed over its
+        #: layers and micro-batches (device arrays; {} for a dense model)
+        self.aux_stats = {}
         # recompilation telemetry: every (re)trace of this trainer's step
         # program is reported to profiler.recompile under this site
         self._prof_site = _precomp.unique_site("hybrid.step")
 
     # ---------------------------------------------------------------------
     def _forward_loss(self, block_params, other_params, batch, key):
+        """``(loss, stats)``: the scalar to differentiate, the blocks'
+        weighted auxiliary losses added, and their counts summed over
+        layers and micro-batches ({} for a dense model)."""
         model = self.model
         from ..core import rng as rng_mod
 
@@ -697,12 +705,19 @@ class HybridPipelineTrainer:
         block0 = model.pipeline_blocks()[0]
 
         moe = self.moe
-        aux_w = self.moe_aux_weight
+
+        def split_aux(aux):
+            # the carry holds means over the micro-batches: right for the
+            # loss; the counts are sums
+            stats = {k: v * self.n_micro for k, v in aux.items()
+                     if k != "loss"}
+            return aux["loss"], stats
 
         def block_apply(stage_local, x):
             """Apply one stage's lps blocks (lax.scan over layers).
-            MoE models: returns (out, weighted aux-loss sum of the
-            stage's blocks) — the pipeline's stage_aux contract."""
+            MoE models: returns (out, sums over the stage's blocks of
+            their weighted auxiliary loss and of their counts) — the
+            pipeline's stage_aux contract."""
             def one_block(h, layer_params):
                 vals = [layer_params[s] for s in self.block_suffixes]
                 # kernel_scope: Pallas kernels nest a shard_map over
@@ -718,7 +733,9 @@ class HybridPipelineTrainer:
                             out = block0(Tensor(h))._value
                     else:
                         out = block0(Tensor(h))._value
-                    aux = block0.mlp._aux._value if moe else None
+                    aux = {"loss": block0.aux_loss._value,
+                           **{k: v._value for k, v in
+                              block0.aux_stats.items()}} if moe else None
                 return (out, aux) if moe else out
 
             if self.remat:
@@ -730,20 +747,16 @@ class HybridPipelineTrainer:
                 else:
                     one_block = jax.checkpoint(one_block)
 
-            def body(carry, layer_params):
-                if moe:
-                    h, a = carry
-                    out, aux = one_block(h, layer_params)
-                    return (out, a + aux.astype(jnp.float32)), None
-                return one_block(carry, layer_params), None
+            def body(h, layer_params):
+                out = one_block(h, layer_params)
+                return out if moe else (out, None)
 
-            init = (x, jnp.zeros((), jnp.float32)) if moe else x
             unroll = self.unroll_layers if self.unroll_layers is not None \
                 else (_target_platform() != "cpu" and not self.remat)
-            out, _ = jax.lax.scan(body, init, stage_local, unroll=unroll)
+            out, auxs = jax.lax.scan(body, x, stage_local, unroll=unroll)
             if moe:
-                h, a = out
-                return h, a * aux_w
+                return out, jax.tree_util.tree_map(
+                    lambda a: jnp.sum(a.astype(jnp.float32), axis=0), auxs)
             return out
 
         batch_tensors = [Tensor(b) for b in batch]
@@ -784,22 +797,24 @@ class HybridPipelineTrainer:
                             stage_aux=moe)
                     if moe:
                         loss_v, aux = loss_v
-                        return (loss_v + aux).astype(jnp.float32)
-                    return loss_v.astype(jnp.float32)
+                        aux, stats = split_aux(aux)
+                        return (loss_v + aux).astype(jnp.float32), stats
+                    return loss_v.astype(jnp.float32), {}
                 with _ptrace.annotate("fwd/blocks"):
                     x = pipeline_apply(self.mesh, block_apply, block_cast,
                                        x, self.n_micro, v_virtual=self.v,
                                        sp_axis="sp" if manual_sp else None,
                                        stage_aux=moe)
-                aux = None
+                aux, stats = None, {}
                 if moe:
                     x, aux = x
+                    aux, stats = split_aux(aux)
                 with _ptrace.annotate("fwd/head"):
                     x = Tensor(seq_constraint(x))
                     loss = model.pipeline_head(x, *batch_tensors)
                     if aux is not None:
                         loss = loss + Tensor(aux)
-        return loss._value.astype(jnp.float32)
+        return loss._value.astype(jnp.float32), stats
 
     def _cast_back(self, np_, ns, store_p_dtype, store_s):
         """Shared storage-dtype rule for both update builders: the f32
@@ -978,15 +993,17 @@ class HybridPipelineTrainer:
 
             def grads_of(bp, op, batch_, key_, fault_):
                 def loss_of(bp_, op_):
-                    l = self._forward_loss(bp_, op_, batch_, key_)
+                    l, stats = self._forward_loss(bp_, op_, batch_, key_)
                     # fault is 1.0 in normal operation (exact IEEE
                     # noop); the chaos harness sets it to NaN for one
                     # step, which poisons the loss AND (through the
                     # cotangent) every gradient leaf — the guard below
                     # must catch all of it
-                    return l * fault_ if guard else l
+                    return (l * fault_ if guard else l), stats
 
-                return jax.value_and_grad(loss_of, argnums=(0, 1))(bp, op)
+                (loss, stats), grads = jax.value_and_grad(
+                    loss_of, argnums=(0, 1), has_aux=True)(bp, op)
+                return loss, stats, grads
 
             if zero_manual:
                 # ZeRO-1/2 sharded update: the ONE shared shard_map
@@ -1003,8 +1020,7 @@ class HybridPipelineTrainer:
 
                 def local(rep, params_, key_, batch_):
                     bp, op = params_
-                    loss, grads = grads_of(bp, op, batch_, key_, rep)
-                    return loss, (), grads
+                    return grads_of(bp, op, batch_, key_, rep)
 
                 ft = fault if guard else jnp.float32(1.0)
                 res = _zq.dp_zero_step(
@@ -1016,11 +1032,11 @@ class HybridPipelineTrainer:
                     plr_knob, wd_knob, clip_norm=clip_norm,
                     guard=guard)
                 if guard:
-                    loss, _, (nb, no), new_flat, ok = res
+                    loss, stats, (nb, no), new_flat, ok = res
                     return (loss, ok, nb, no, {_ZERO_SLAB: new_flat},
-                            [])
-                loss, _, (nb, no), new_flat = res
-                return loss, nb, no, {_ZERO_SLAB: new_flat}, []
+                            [], stats)
+                loss, stats, (nb, no), new_flat = res
+                return loss, nb, no, {_ZERO_SLAB: new_flat}, [], stats
 
             if qcomm_dp > 1:
                 # quantized DP-grad sync: per-shard local grads inside
@@ -1033,18 +1049,17 @@ class HybridPipelineTrainer:
 
                 def local(rep, key_, batch_):
                     bp, op, ft = rep
-                    loss, grads = grads_of(bp, op, batch_, key_, ft)
-                    return loss, (), grads
+                    return grads_of(bp, op, batch_, key_, ft)
 
                 ft = fault if guard else jnp.float32(1.0)
-                loss, _, (g_blk, g_oth) = \
+                loss, stats, (g_blk, g_oth) = \
                     _qcomm.dp_quantized_value_and_grads(
                         mesh, qcomm_dp, self.dp_grad_block, local,
                         (bp_c, op_c, ft), batch,
                         _qcomm.dp_batch_specs(batch, qcomm_dp), key)
             else:
-                loss, (g_blk, g_oth) = grads_of(bp_c, op_c, batch, key,
-                                                fault)
+                loss, stats, (g_blk, g_oth) = grads_of(
+                    bp_c, op_c, batch, key, fault)
             with _ptrace.annotate("opt/update"):     # the optimizer's clip
                 g_blk, g_oth = functional_clip(clip, (g_blk, g_oth))
 
@@ -1114,8 +1129,8 @@ class HybridPipelineTrainer:
                         chain.append(np_)
             if guard:
                 return (loss, ok, new_blk, new_oth, new_blk_opt,
-                        new_oth_opt)
-            return loss, new_blk, new_oth, new_blk_opt, new_oth_opt
+                        new_oth_opt, stats)
+            return loss, new_blk, new_oth, new_blk_opt, new_oth_opt, stats
 
         ns = lambda spec: NamedSharding(mesh, spec)
         ons = self._opt_ns          # pinned_host when offloading
@@ -1129,7 +1144,8 @@ class HybridPipelineTrainer:
         self._batch_spec = self._make_batch_spec()
         in_sh = (blk_sh, oth_sh, blk_opt_sh, oth_opt_sh,
                  None, None, None, None)
-        out_sh = (ns(P()), blk_sh, oth_sh, blk_opt_sh, oth_opt_sh)
+        out_sh = (ns(P()), blk_sh, oth_sh, blk_opt_sh, oth_opt_sh,
+                  ns(P()))                                # + aux_stats
         if guard:
             in_sh = in_sh + (None,)                       # fault scalar
             out_sh = (ns(P()), ns(P())) + out_sh[1:]      # + ok verdict
@@ -1242,8 +1258,8 @@ class HybridPipelineTrainer:
             def loss_of(b, o):
                 return self._forward_loss(b, o, batch, key)
 
-            loss, (g_blk, g_oth) = jax.value_and_grad(
-                loss_of, argnums=(0, 1))(bp, op)
+            (loss, stats), (g_blk, g_oth) = jax.value_and_grad(
+                loss_of, argnums=(0, 1), has_aux=True)(bp, op)
             g_blk, g_oth = functional_clip(clip, (g_blk, g_oth))
 
             chain = [step_no] * depth
@@ -1306,7 +1322,7 @@ class HybridPipelineTrainer:
                     [new_o[s][i][k] for i in range(lps)], 1)
                     for k in blk_o[s]} for s in sfx_list}
             return (loss, out_blk_m, new_oth_m, out_blk_c, new_oth_c,
-                    out_blk_o, new_oth_o)
+                    out_blk_o, new_oth_o, stats)
 
         ns = lambda spec: NamedSharding(mesh, spec)  # noqa: E731
         pns = self._param_ns
@@ -1340,7 +1356,7 @@ class HybridPipelineTrainer:
             in_shardings=(blk_m_sh, oth_m_sh, blk_c_sh, oth_c_sh,
                           blk_o_sh, oth_o_sh, None, None, None, None),
             out_shardings=(ns(P()), blk_m_sh, oth_m_sh, blk_c_sh,
-                           oth_c_sh, blk_o_sh, oth_o_sh),
+                           oth_c_sh, blk_o_sh, oth_o_sh, ns(P())),
             donate_argnums=(0, 1, 2, 3, 4, 5))
         self._n_batch_args = n_batch_args
 
@@ -1368,6 +1384,7 @@ class HybridPipelineTrainer:
         # fetch), so the enabled step_ms histogram measures execution,
         # not dispatch.
         prof = _ptrace.is_enabled()
+        sync = prof and getattr(self, "profiled_step_sync", True)
         t0 = time.perf_counter_ns() if prof else 0
         h2d = _ptrace.scope("hybrid/h2d") if prof else contextlib.nullcontext()
         with h2d:
@@ -1390,7 +1407,6 @@ class HybridPipelineTrainer:
             # measured — and the deferred materialization records the
             # honest hybrid/sync_wait span instead; the histogram is
             # then named hybrid/dispatch_ms, because that is what it is.
-            sync = getattr(self, "profiled_step_sync", True)
             with _ptrace.scope("hybrid/step"):
                 out = self._step_fn(*args)
                 if sync:
@@ -1413,10 +1429,14 @@ class HybridPipelineTrainer:
             out = (out[0],) + out[2:]
         if self.stream_layers:
             (loss, self.block_vals, self.other_vals, self.block_comp,
-             self.other_comp, self.block_opt, self.other_opt) = out
+             self.other_comp, self.block_opt, self.other_opt,
+             self.aux_stats) = out
         else:
             (loss, self.block_vals, self.other_vals, self.block_opt,
-             self.other_opt) = out
+             self.other_opt, self.aux_stats) = out
+        if prof and sync and self.aux_stats:
+            # outputs of the step whose loss was just waited for
+            self.model.publish_aux_stats(jax.device_get(self.aux_stats))
         self.optimizer._global_step = self._step
         return loss
 
@@ -1509,11 +1529,11 @@ class HybridPipelineTrainer:
         t_fwd = t_fb = None
         if not (self.stream_layers or self.offload_params):
             fwd = jax.jit(lambda bp, op: self._forward_loss(
-                bp, op, vs, key))
+                bp, op, vs, key)[0])
             t_fwd = _pinstr.time_compiled(
                 lambda: fwd(self.block_vals, self.other_vals), iters)
             fb = jax.jit(lambda bp, op: jax.value_and_grad(
-                lambda b_, o_: self._forward_loss(b_, o_, vs, key),
+                lambda b_, o_: self._forward_loss(b_, o_, vs, key)[0],
                 argnums=(0, 1))(bp, op))
             t_fb = _pinstr.time_compiled(
                 lambda: fb(self.block_vals, self.other_vals), iters)

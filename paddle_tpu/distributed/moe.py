@@ -20,6 +20,14 @@ TPU-first per the GShard/Switch pattern:
   - the Switch load-balance auxiliary loss (E * Σ_e fraction_e · prob_e)
     is exposed as ``layer.aux_loss`` for the model to add.
 
+``dropless_moe`` beside it is token choice as today's open sparse models
+publish it (OLMoE, arXiv:2409.02060): every token keeps all its ``top_k``
+experts whatever the imbalance. There is no capacity: the ``T x k``
+assignments are ordered by expert and the experts run as grouped matrix
+multiplications over ragged groups (``jax.lax.ragged_dot``, which XLA:TPU
+compiles to its own Mosaic kernel with exactly the needed operations,
+forward and both backward products).
+
 Composes with dp/tp/ep through the strategy compiler
 (compile_train_step picks up the P("ep", ...) param_shardings and the
 model.loss aux term) AND with pipeline parallelism: blocks return
@@ -41,7 +49,8 @@ from ..nn import initializer as I
 from ..profiler.trace import annotate as _annotate
 from ..tensor._helper import apply
 
-__all__ = ["MoEMLP", "switch_moe"]
+__all__ = ["MoEMLP", "switch_moe", "DroplessMoEMLP", "dropless_moe",
+           "publish_expert_load"]
 
 
 # ---------------------------------------------------------------------------
@@ -221,6 +230,161 @@ def switch_moe(x, gate_w, w_in, b_in, w_out, b_out, *, top_k=1,
     aux = e * jnp.sum((aux_fraction / top_k)
                       * jnp.mean(probs_t, axis=1))
     return y, aux.astype(jnp.float32)
+
+
+# ---------------------------------------------------------------------------
+# drop-less token choice
+# ---------------------------------------------------------------------------
+def publish_expert_load(stats) -> None:
+    """The drop-less layers' counters in the profiler's registry, on the
+    host, from the ``stats`` a forward or a training step left
+    (``DroplessMoEMLP.stats``, ``HybridPipelineTrainer.aux_stats``: sums
+    over the expert-layer calls of the step). ``moe/dropped_tokens`` keeps
+    the most any step lost (0 by construction: every assignment has a row
+    in some expert's group) and ``moe/expert_load_max_over_mean`` the last
+    step's fullest expert over the mean one, averaged over its calls."""
+    from ..profiler import metrics
+
+    rows = np.asarray(stats["moe/rows"])
+    assigned = float(stats["moe/assigned"])
+    reg = metrics.registry()
+    reg.gauge("moe/dropped_tokens").set_max(
+        int(round(assigned - float(rows.sum()))))
+    reg.gauge("moe/expert_load_max_over_mean").set(
+        float(stats["moe/load_max"]) * rows.size / max(assigned, 1.0))
+
+
+def dropless_moe(x, router_w, w_gate, w_up, w_down, top_k):
+    """Token-choice MoE FFN with no token dropped. x: [T, H]; router_w:
+    [H, E], no bias; SiLU-gated experts stacked w_gate, w_up [E, H, F] and
+    w_down [E, F, H], no biases.
+
+    Router logits (float32 accumulation) and softmax in float32 over all
+    E; the ``top_k`` largest probabilities weigh their experts
+    **unrenormalised**; the ``T*top_k`` assignments are ordered by expert,
+    rows gathered to ``[T*top_k, H]``, three grouped matmuls over the [E]
+    group sizes, gate-weighted un-sort and sum over ``top_k``.
+
+    Returns ``(y [T, H], balance, z, rows)``: ``rows`` [E] int32 are the
+    assignments each expert was given, what the grouped matmuls are told
+    to compute (they sum to ``T * top_k``: none is dropped); ``balance`` is HF
+    ``load_balancing_loss_func`` on this layer's logits, ``E * sum_{j,e}
+    f[j,e] * P[e]`` with ``f[j,e]`` the share of tokens whose j-th choice
+    is ``e`` and ``P[e]`` the mean probability of ``e`` (``top_k`` when
+    balanced); ``z`` is the router z-loss, the mean over tokens of
+    ``logsumexp(logits)**2``. The caller weighs them.
+    """
+    t, h = x.shape
+    e = router_w.shape[1]
+    n = t * top_k
+    # routing in the transposed [E, T] layout, T on the lanes, and top_k
+    # as rounds of argmax, as switch_moe does and for its reasons
+    with _annotate("moe/route"):
+        logits_t = jnp.dot(router_w.astype(x.dtype).T, x.T,
+                           preferred_element_type=jnp.float32)   # [E, T]
+        lse = jax.nn.logsumexp(logits_t, axis=0)                 # [T]
+        probs_t = jnp.exp(logits_t - lse[None, :])
+        z = jnp.mean(jnp.square(lse))
+        rows_e = jnp.arange(e, dtype=jnp.int32)[:, None]
+        remaining = probs_t
+        counts = jnp.zeros((e,), jnp.float32)    # rows given out so far
+        expert_rounds, gate_rounds, pos_rounds = [], [], []
+        for _ in range(top_k):
+            idx = jnp.argmax(remaining, axis=0).astype(jnp.int32)  # [T]
+            onehot_t = (rows_e == idx[None, :]).astype(jnp.float32)
+            gate_rounds.append(jnp.sum(remaining * onehot_t, axis=0))
+            # place within the expert's group: rows of earlier rounds,
+            # then the earlier tokens of this round (a counting sort)
+            before = jnp.cumsum(onehot_t, axis=1) - onehot_t
+            pos_rounds.append(jnp.sum((before + counts[:, None]) * onehot_t,
+                                      axis=0).astype(jnp.int32))
+            counts = counts + jnp.sum(onehot_t, axis=1)
+            expert_rounds.append(idx)
+            remaining = remaining * (1.0 - onehot_t)
+        balance = e * jnp.sum((counts / t) * jnp.mean(probs_t, axis=1))
+        group_sizes = counts.astype(jnp.int32)                   # [E]
+        starts = jnp.cumsum(group_sizes) - group_sizes
+        row_of_token = jnp.stack(
+            [starts[expert_rounds[k]] + pos_rounds[k]
+             for k in range(top_k)])                             # [K, T]
+        # the inverse map: a permutation, so the int32 scatter is exact
+        flat = row_of_token.reshape(n)
+        token_of_row = jnp.zeros((n,), jnp.int32).at[flat].set(
+            jnp.tile(jnp.arange(t, dtype=jnp.int32), top_k),
+            unique_indices=True)
+        round_of_row = jnp.zeros((n,), jnp.int32).at[flat].set(
+            jnp.repeat(jnp.arange(top_k, dtype=jnp.int32), t),
+            unique_indices=True)
+        gates = jnp.stack(gate_rounds)                           # [K, T]
+    every = jnp.ones((top_k, t), bool)
+    with _annotate("moe/dispatch"):
+        xs = _dispatch_gather(x, token_of_row, row_of_token, every)
+    with _annotate("moe/experts"):
+        mid = jax.nn.silu(jax.lax.ragged_dot(
+            xs, w_gate.astype(x.dtype), group_sizes)) * \
+            jax.lax.ragged_dot(xs, w_up.astype(x.dtype), group_sizes)
+        ys = jax.lax.ragged_dot(mid, w_down.astype(x.dtype), group_sizes)
+    with _annotate("moe/combine"):
+        y = _combine_gather(ys, gates, row_of_token, every, token_of_row,
+                            round_of_row, jnp.ones((n,), bool))
+    return (y, balance.astype(jnp.float32), z.astype(jnp.float32),
+            group_sizes)
+
+
+class DroplessMoEMLP(nn.Layer):
+    """The drop-less MoE FFN of a transformer block (``dropless_moe``).
+
+    forward(x [B, S, H]) -> [B, S, H]; the two auxiliary terms of the last
+    forward are ``balance_loss`` and ``z_loss`` (unweighted scalars; the
+    block weighs them, models/gpt.py GPTBlock). ``stats`` are its counts,
+    float32 so that they can be summed along the trainer's auxiliary carry
+    and leave the step as outputs: ``moe/rows`` [E] the assignments each
+    expert was given, ``moe/load_max`` the fullest expert's,
+    ``moe/assigned`` all there were (tokens x ``top_k``).
+    ``publish_expert_load`` turns them into the registry's gauges on the
+    host, after the step: nothing in the program talks to the host."""
+
+    def __init__(self, hidden_size: int, expert_width: int,
+                 num_experts: int, top_k: int,
+                 initializer_range: float = 0.02,
+                 out_initializer_range: float = 0.02):
+        super().__init__()
+        init = I.Normal(0.0, initializer_range)
+        e, h, f = num_experts, hidden_size, expert_width
+        self.num_experts = e
+        self.top_k = top_k
+        self.gate = self.create_parameter([h, e], default_initializer=init)
+        self.w_gate = self.create_parameter([e, h, f],
+                                            default_initializer=init)
+        self.w_up = self.create_parameter([e, h, f],
+                                          default_initializer=init)
+        self.w_down = self.create_parameter(
+            [e, f, h],
+            default_initializer=I.Normal(0.0, out_initializer_range))
+        # expert dim sharded over 'ep', as MoEMLP declares it
+        self.param_shardings = {
+            "gate": P(), "w_gate": P("ep", None, None),
+            "w_up": P("ep", None, None), "w_down": P("ep", None, None)}
+        self.balance_loss = Tensor(jnp.zeros((), jnp.float32))
+        self.z_loss = Tensor(jnp.zeros((), jnp.float32))
+        self.stats = {}
+
+    def forward(self, x):
+        b, s, h = x.shape[0], x.shape[1], x.shape[2]
+
+        def f(xv, gw, wg, wu, wd):
+            y, balance, z, rows = dropless_moe(xv.reshape(b * s, h), gw, wg,
+                                               wu, wd, self.top_k)
+            rows = rows.astype(jnp.float32)
+            return (y.reshape(b, s, h), balance, z, rows, jnp.max(rows),
+                    jnp.float32(b * s * self.top_k))
+
+        y, self.balance_loss, self.z_loss, rows, load_max, assigned = apply(
+            f, x, self.gate, self.w_gate, self.w_up, self.w_down,
+            name="dropless_moe")
+        self.stats = {"moe/rows": rows, "moe/load_max": load_max,
+                      "moe/assigned": assigned}
+        return y
 
 
 class MoEMLP(nn.Layer):

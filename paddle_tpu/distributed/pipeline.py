@@ -69,12 +69,13 @@ def pipeline_apply(mesh: Mesh, stage_fn: Callable, stacked_params: Any,
     region (see module docstring); returns the scalar instead of the
     activations.
 
-    stage_aux: when True, stage_fn returns ``(y_mb, aux_scalar)`` — a
+    stage_aux: when True, stage_fn returns ``(y_mb, aux)`` — a
     per-microbatch auxiliary scalar (e.g. the MoE load-balance loss of the
-    stage's blocks). Aux values are accumulated over the ticks where the
-    stage holds REAL data (fill/drain garbage ticks masked out), psum'd
-    over 'pp' so every stage's layers contribute, and averaged over
-    microbatches. pipeline_apply then returns ``(out, aux)``.
+    stage's blocks) or a pytree of float32 arrays (that loss and the
+    stage's counts beside it). Aux values are accumulated over the ticks
+    where the stage holds REAL data (fill/drain garbage ticks masked out),
+    psum'd over 'pp' so every stage's layers contribute, and averaged over
+    microbatches, leaf by leaf. pipeline_apply then returns ``(out, aux)``.
 
     sp_axis: when set (sequence parallelism composed with pipeline), the
     shard_map is manual over BOTH axes — x's seq dim (dim 1) stays sharded
@@ -106,7 +107,9 @@ def pipeline_apply(mesh: Mesh, stage_fn: Callable, stacked_params: Any,
         out = jax.lax.map(one_mb, mbs)
         if stage_aux:
             out, auxs = out
-            aux = jnp.sum(auxs.astype(jnp.float32)) / n_micro
+            aux = jax.tree_util.tree_map(
+                lambda a: jnp.sum(a.astype(jnp.float32), axis=0) / n_micro,
+                auxs)
         full = _from_microbatches(out, x.shape)
         res = head_fn(full, *head_args) if head_fn is not None else full
         return (res, aux) if stage_aux else res
@@ -162,6 +165,14 @@ def pipeline_apply(mesh: Mesh, stage_fn: Callable, stacked_params: Any,
         # circuit-return buffer (interleaved: finished circuits wait here
         # until stage 0 re-injects them); unused for v == 1
         ret0 = jnp.zeros(xs.shape, carry_dtype)
+        aux0 = jnp.zeros((), jnp.float32)
+        if stage_aux:
+            # the shapes of the stage's aux, from an abstract trace
+            aux0 = jax.tree_util.tree_map(
+                lambda a: jnp.zeros(a.shape, jnp.float32), jax.eval_shape(
+                    stage_fn, local if v == 1 else jax.tree_util.tree_map(
+                        lambda a: a[0], local),
+                    state0.astype(compute_dtype))[1])
 
         def tick(carry, t):
             prev_out, ret, outputs, aux_acc = carry
@@ -211,8 +222,9 @@ def pipeline_apply(mesh: Mesh, stage_fn: Callable, stacked_params: Any,
                 # fill/drain ticks run on garbage zeros — mask their aux.
                 # stage s holds real data from tick s to s + v*n_micro - 1.
                 busy = (t >= stage) & (t < stage + v * n_micro)
-                aux_acc = aux_acc + jnp.where(
-                    busy, aux.astype(jnp.float32), 0.0)
+                aux_acc = jax.tree_util.tree_map(
+                    lambda acc, a: acc + jnp.where(
+                        busy, a.astype(jnp.float32), 0.0), aux_acc, aux)
             out = out.astype(carry_dtype)
             # the last stage finishing the LAST circuit produces output
             done_t = t - (pp - 1) - (v - 1) * n_micro
@@ -226,10 +238,10 @@ def pipeline_apply(mesh: Mesh, stage_fn: Callable, stacked_params: Any,
             return (out, ret, outputs, aux_acc), None
 
         (last, _, outputs, aux_acc), _ = jax.lax.scan(
-            tick, (state0, ret0, outputs0, jnp.zeros((), jnp.float32)),
-            jnp.arange(n_ticks))
+            tick, (state0, ret0, outputs0, aux0), jnp.arange(n_ticks))
         # every stage's layers contribute their own aux; per-microbatch mean
-        aux_total = jax.lax.psum(aux_acc, pp_axis) / n_micro \
+        aux_total = jax.tree_util.tree_map(
+            lambda a: a / n_micro, jax.lax.psum(aux_acc, pp_axis)) \
             if stage_aux else None
         if sp_axis is not None and stage_aux:
             # local routing groups per sp shard: average their aux

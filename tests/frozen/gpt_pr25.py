@@ -1,3 +1,10 @@
+# FROZEN: paddle_tpu/models/gpt.py as it stood at PR 25, before PR 26 gave the
+# block its architecture fields. tests/test_olmoe.py loads it in the place of
+# models/gpt.py and lowers the dense GPT's step and tick from both in one
+# process: equal text is the proof that the fields, at GPT's values, leave the
+# dense programs as they were. Nothing else uses it; it is not to be kept up to
+# date. A PR that changes the dense GPT's step or tick on purpose deletes this
+# file with the two tests that read it.
 """GPT model family — the flagship for the hybrid-parallel north star
 (BASELINE.md: GPT-3 1.3B/13B, TP×PP×sharding, ≥45% MFU target).
 
@@ -53,36 +60,10 @@ class GPTConfig:
     moe_top_k: int = 1
     moe_capacity_factor: float = 1.25
     moe_aux_weight: float = 0.01
-    # The architecture of the block. These describe a model, none is a
-    # policy; at the values below the traced program is the GPT-3 one.
-    norm: str = "layernorm"           # | "rmsnorm" (no bias, HF OlmoeRMSNorm)
-    position: str = "learned"         # | "rope": no wpe, q and k rotated
-    rope_theta: float = 10000.0
-    qk_norm: bool = False             # RMSNorm of q and of k, all heads wide
-    bias: bool = True                 # biases on the block's projections
-    ffn: str = "gelu"                 # | "swiglu": down(silu(gate x) * up x)
-    moe_expert_width: int = 0         # one expert's width; 0: ffn_hidden_size
-    moe_dropless: bool = False        # token choice, no capacity, no drops
-    moe_z_weight: float = 0.0         # router z-loss (drop-less path)
 
     def __post_init__(self):
         if not self.ffn_hidden_size:
             self.ffn_hidden_size = 4 * self.hidden_size
-        if self.norm not in ("layernorm", "rmsnorm") or \
-                self.position not in ("learned", "rope") or \
-                self.ffn not in ("gelu", "swiglu"):
-            raise ValueError(f"unknown block kind: norm {self.norm!r}, "
-                             f"position {self.position!r}, ffn {self.ffn!r}")
-        if self.moe_dropless and (self.ffn != "swiglu" or self.bias):
-            raise ValueError("moe_dropless experts are bias-free SwiGLU "
-                             "(distributed/moe.py dropless_moe)")
-
-    def is_gpt3_block(self) -> bool:
-        """Whether every architecture field has GPT-3's value: what the
-        serving forwards (gpt_block_body and its callers) implement."""
-        return (self.norm == "layernorm" and self.position == "learned"
-                and not self.qk_norm and self.bias and self.ffn == "gelu"
-                and not self.moe_dropless)
 
     # presets from the reference north-star table (BASELINE.md)
     @staticmethod
@@ -113,53 +94,20 @@ class GPTConfig:
         return GPTConfig(hidden_size=5120, num_layers=40, num_heads=40,
                          max_seq_len=2048)
 
-    @staticmethod
-    def olmoe_1b_7b():
-        """OLMoE-1B-7B (huggingface.co/allenai/OLMoE-1B-7B-0125-Instruct
-        config.json; loss weights and "drop-less" from arXiv:2409.02060):
-        64 experts of width 1024, 8 a token, unrenormalised."""
-        return GPTConfig(
-            vocab_size=50304, hidden_size=2048, num_layers=16, num_heads=16,
-            max_seq_len=4096, ffn_hidden_size=8192, layer_norm_eps=1e-5,
-            tie_word_embeddings=False, norm="rmsnorm", position="rope",
-            rope_theta=10000.0, qk_norm=True, bias=False, ffn="swiglu",
-            moe_num_experts=64, moe_top_k=8, moe_expert_width=1024,
-            moe_dropless=True, moe_aux_weight=0.01, moe_z_weight=0.001)
-
     def num_params(self) -> int:
         h, L, v = self.hidden_size, self.num_layers, self.vocab_size
-        f = self.moe_expert_width or self.ffn_hidden_size
-        mats = 3 if self.ffn == "swiglu" else 2   # matrices of one FFN
-        e = max(self.moe_num_experts, 1)          # E expert FFNs + router
-        norm = h * (1 if self.norm == "rmsnorm" else 2)
-        biases = (4 * h + e * (f * (mats - 1) + h)) if self.bias else 0
-        per_block = 4 * h * h + e * mats * h * f + biases \
-            + (2 + 2 * self.qk_norm) * norm \
+        e = max(self.moe_num_experts, 1)     # E expert FFNs + router
+        ffn = 2 * h * self.ffn_hidden_size * e \
+            + (e - 1) * (self.ffn_hidden_size + h) \
             + (h * e if self.moe_num_experts else 0)
-        return v * h * (1 if self.tie_word_embeddings else 2) \
-            + (self.max_seq_len * h if self.position == "learned" else 0) \
-            + L * per_block + norm
+        per_block = 4 * h * h + ffn + 13 * h
+        return v * h + self.max_seq_len * h + L * per_block + 2 * h
 
     def flops_per_token(self, seq_len=None) -> float:
         """Training FLOPs/token ≈ 6N + 12·L·h·s (attention term)."""
         s = seq_len or self.max_seq_len
         return 6.0 * self.num_params() + 12.0 * self.num_layers * \
             self.hidden_size * s
-
-
-def rope_rotate(x, theta: float):
-    """Rotary position embedding of ``x`` [b, s, heads, d] at positions
-    0..s-1, in the HF rotate-half convention: the angle of pair
-    ``(i, i + d/2)`` at position ``p`` is ``p / theta**(2i/d)``. Computed in
-    float32 and cast back."""
-    s, d = x.shape[1], x.shape[-1]
-    inv = 1.0 / theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d)
-    ang = jnp.arange(s, dtype=jnp.float32)[:, None] * inv[None, :]
-    cos = jnp.concatenate([jnp.cos(ang)] * 2, -1)[None, :, None, :]
-    sin = jnp.concatenate([jnp.sin(ang)] * 2, -1)[None, :, None, :]
-    xf = x.astype(jnp.float32)
-    half = jnp.concatenate([-xf[..., d // 2:], xf[..., :d // 2]], -1)
-    return (xf * cos + half * sin).astype(x.dtype)
 
 
 class GPTAttention(nn.Layer):
@@ -173,19 +121,10 @@ class GPTAttention(nn.Layer):
         self.head_dim = c.hidden_size // c.num_heads
         self.qkv_proj = ColumnParallelLinear(
             c.hidden_size, 3 * c.hidden_size, weight_attr=init,
-            has_bias=c.bias, gather_output=False)
+            gather_output=False)
         self.out_proj = RowParallelLinear(
-            c.hidden_size, c.hidden_size, weight_attr=out_init,
-            has_bias=c.bias)
+            c.hidden_size, c.hidden_size, weight_attr=out_init)
         self.dropout = c.dropout
-        self.rope_theta = c.rope_theta if c.position == "rope" else None
-        if c.qk_norm:
-            # over the whole projection, before the heads are split (HF
-            # OlmoeAttention.q_norm/k_norm)
-            self.q_norm = nn.RMSNorm(c.hidden_size, epsilon=c.layer_norm_eps)
-            self.k_norm = nn.RMSNorm(c.hidden_size, epsilon=c.layer_norm_eps)
-        else:
-            self.q_norm = self.k_norm = None
         # qkv weight columns interleave q|k|v: shard on out dim stays valid
         self.qkv_proj.param_shardings = {"weight": P(None, "tp"),
                                          "bias": P("tp")}
@@ -198,29 +137,10 @@ class GPTAttention(nn.Layer):
             qkv = self.qkv_proj(x)
             qkv = qkv.reshape([b, s, 3, self.num_heads, self.head_dim])
             q, k, v = qkv.unbind(2)
-            if self.q_norm is not None:
-                heads = [b, s, self.num_heads, self.head_dim]
-                q = self.q_norm(q.reshape([b, s, h])).reshape(heads)
-                k = self.k_norm(k.reshape([b, s, h])).reshape(heads)
-            if self.rope_theta is not None:
-                q, k = self._rotate(q, k)
         with annotate("blk/attn"):
             out = self._attend(q, k, v)
         with annotate("blk/attn_out"):
             return self.out_proj(out.reshape([b, s, h]))
-
-    def _rotate(self, q, k):
-        sp = _dctx.current_sequence_parallel()
-        if sp is not None and sp[2]:
-            raise NotImplementedError(
-                "RoPE inside a manual sequence-parallel region needs the "
-                "shard's position offset; use sp_degree=1 or pp=1")
-        from ..tensor._helper import apply
-
-        theta = self.rope_theta
-        return apply(lambda q_, k_: (rope_rotate(q_, theta),
-                                     rope_rotate(k_, theta)),
-                     q, k, name="rope")
 
     def _attend(self, q, k, v):
         sp = _dctx.current_sequence_parallel()
@@ -266,31 +186,16 @@ class GPTMLP(nn.Layer):
         out_init = I.Normal(0.0, c.initializer_range /
                             math.sqrt(2 * c.num_layers))
         self.fc_in = ColumnParallelLinear(c.hidden_size, c.ffn_hidden_size,
-                                          weight_attr=init, has_bias=c.bias,
+                                          weight_attr=init,
                                           gather_output=False)
-        # SwiGLU: fc_in is the "up" projection, fc_gate goes through silu
-        self.fc_gate = ColumnParallelLinear(
-            c.hidden_size, c.ffn_hidden_size, weight_attr=init,
-            has_bias=c.bias, gather_output=False) \
-            if c.ffn == "swiglu" else None
         self.fc_out = RowParallelLinear(c.ffn_hidden_size, c.hidden_size,
-                                        weight_attr=out_init,
-                                        has_bias=c.bias)
+                                        weight_attr=out_init)
         self.dropout = c.dropout
 
     def forward(self, x):
-        if self.fc_gate is not None:
-            x = F.silu(self.fc_gate(x)) * self.fc_in(x)
-        else:
-            x = F.gelu(self.fc_in(x), approximate=True)
+        x = F.gelu(self.fc_in(x), approximate=True)
         x = self.fc_out(x)
         return F.dropout(x, self.dropout, training=self.training)
-
-
-def _norm(config: GPTConfig):
-    if config.norm == "rmsnorm":
-        return nn.RMSNorm(config.hidden_size, epsilon=config.layer_norm_eps)
-    return nn.LayerNorm(config.hidden_size, epsilon=config.layer_norm_eps)
 
 
 class GPTBlock(nn.Layer):
@@ -298,21 +203,12 @@ class GPTBlock(nn.Layer):
 
     def __init__(self, config: GPTConfig):
         super().__init__()
-        self.config = config            # read at forward time
-        self.ln_1 = _norm(config)
+        self.ln_1 = nn.LayerNorm(config.hidden_size,
+                                 epsilon=config.layer_norm_eps)
         self.attn = GPTAttention(config)
-        self.ln_2 = _norm(config)
-        if config.moe_dropless:
-            from ..distributed.moe import DroplessMoEMLP
-
-            self.mlp = DroplessMoEMLP(
-                config.hidden_size,
-                config.moe_expert_width or config.ffn_hidden_size,
-                config.moe_num_experts, top_k=config.moe_top_k,
-                initializer_range=config.initializer_range,
-                out_initializer_range=config.initializer_range /
-                math.sqrt(2 * config.num_layers))
-        elif config.moe_num_experts > 0:
+        self.ln_2 = nn.LayerNorm(config.hidden_size,
+                                 epsilon=config.layer_norm_eps)
+        if config.moe_num_experts > 0:
             from ..distributed.moe import MoEMLP
 
             self.mlp = MoEMLP(config.hidden_size, config.ffn_hidden_size,
@@ -322,14 +218,6 @@ class GPTBlock(nn.Layer):
                               initializer_range=config.initializer_range)
         else:
             self.mlp = GPTMLP(config)
-        #: the block's auxiliary loss of its last forward, already
-        #: weighted: what GPT.loss adds and what the pipeline's
-        #: ``stage_aux`` carries (distributed/hybrid.py); None when dense
-        self.aux_loss = None
-        #: counts of the last forward that ride the same carry and leave
-        #: the trainer's step as ``aux_stats`` (the drop-less layer's
-        #: ``stats``; none otherwise)
-        self.aux_stats = {}
 
     def forward(self, x):
         with annotate("blk/qkv"):
@@ -338,18 +226,7 @@ class GPTBlock(nn.Layer):
         with annotate("blk/attn_out"):
             x = x + h
         with annotate("blk/ffn"):
-            out = x + self.mlp(self.ln_2(x))
-        c = self.config
-        if c.moe_dropless:
-            # both terms are means over the layers, as HF's pooled
-            # load-balance term and megablocks' batched losses are
-            self.aux_loss = (
-                c.moe_aux_weight * self.mlp.balance_loss
-                + c.moe_z_weight * self.mlp.z_loss) * (1.0 / c.num_layers)
-            self.aux_stats = self.mlp.stats
-        elif c.moe_num_experts > 0:
-            self.aux_loss = c.moe_aux_weight * self.mlp.aux_loss
-        return out
+            return x + self.mlp(self.ln_2(x))
 
 
 class GPTEmbeddings(nn.Layer):
@@ -359,20 +236,15 @@ class GPTEmbeddings(nn.Layer):
         self.wte = VocabParallelEmbedding(
             c.vocab_size, c.hidden_size,
             weight_attr=I.Normal(0.0, c.initializer_range))
-        # under RoPE the positions live in the attention; there is no wpe
         self.wpe = nn.Embedding(
             c.max_seq_len, c.hidden_size,
-            weight_attr=I.Normal(0.0, c.initializer_range)) \
-            if c.position == "learned" else None
+            weight_attr=I.Normal(0.0, c.initializer_range))
         self.dropout = c.dropout
 
     def forward(self, tokens):
-        if self.wpe is None:
-            x = self.wte(tokens)
-        else:
-            s = tokens.shape[1]
-            pos = arange(0, s, dtype="int64").unsqueeze(0)
-            x = self.wte(tokens) + self.wpe(pos)
+        s = tokens.shape[1]
+        pos = arange(0, s, dtype="int64").unsqueeze(0)
+        x = self.wte(tokens) + self.wpe(pos)
         return F.dropout(x, self.dropout, training=self.training)
 
 
@@ -386,7 +258,8 @@ class GPT(nn.Layer):
         self.embeddings = GPTEmbeddings(config)
         self.blocks = nn.LayerList([GPTBlock(config)
                                     for _ in range(config.num_layers)])
-        self.ln_f = _norm(config)
+        self.ln_f = nn.LayerNorm(config.hidden_size,
+                                 epsilon=config.layer_norm_eps)
         if not config.tie_word_embeddings:
             self.lm_head = ColumnParallelLinear(
                 config.hidden_size, config.vocab_size, has_bias=False,
@@ -618,13 +491,6 @@ class GPT(nn.Layer):
         self.__dict__["_gen_state"] = (token, stacked, other)
         return stacked, other
 
-    def publish_aux_stats(self, stats):
-        """A training step's ``aux_stats`` (host values) into the
-        profiler's registry: the trainer calls it after a profiled step."""
-        from ..distributed.moe import publish_expert_load
-
-        publish_expert_load(stats)
-
     def loss(self, tokens, labels=None):
         """Next-token LM loss (+ MoE load-balance aux when configured).
         labels default: tokens shifted left.
@@ -639,21 +505,8 @@ class GPT(nn.Layer):
         loss = self.pipeline_head(x, tokens, labels=labels)
         if self.config.moe_num_experts > 0:
             for blk in self.blocks:
-                loss = loss + blk.aux_loss
+                loss = loss + self.config.moe_aux_weight * blk.mlp.aux_loss
         return loss
-
-
-def _require_gpt3_block(cfg: GPTConfig):
-    """The serving forwards below (gpt_block_body and what calls it:
-    generate(), ServingEngine) implement the GPT-3 block only."""
-    if not cfg.is_gpt3_block():
-        raise NotImplementedError(
-            "serving and generate() implement the GPT-3 block (LayerNorm, "
-            "learned positions, biases, GELU FFN); this model's block "
-            f"(norm {cfg.norm}, position {cfg.position}, qk_norm "
-            f"{cfg.qk_norm}, bias {cfg.bias}, ffn {cfg.ffn}, drop-less MoE "
-            f"{cfg.moe_dropless}) is supported for training only "
-            "(ROADMAP R1 'serve', after D1/S3)")
 
 
 def _ln(x, w, b, eps):
@@ -708,7 +561,6 @@ def gpt_cached_apply(cfg: GPTConfig, stacked, other, ck, cv, tokens, pos0,
     Parity with GPT.forward is pinned by
     tests/test_generation.py::test_cached_prefill_matches_forward.
     """
-    _require_gpt3_block(cfg)
     n, t = tokens.shape
     h = cfg.hidden_size
     nh = cfg.num_heads
@@ -835,7 +687,6 @@ def gpt_ragged_apply(cfg: GPTConfig, stacked, other, kpool, vpool,
     from ..ops.paged_attention import (paged_kv_scatter,
                                       ragged_paged_attention)
 
-    _require_gpt3_block(cfg)
     nt = tokens.shape[0]
     nd = decode_rows
     base = nd * (1 + spec_k)
@@ -961,7 +812,6 @@ def _gpt_decode_state(model: "GPT"):
     eager model, for gpt_cached_apply."""
     from ..static.functional import state_tensors
 
-    _require_gpt3_block(model.config)
     if model.config.moe_num_experts:
         raise NotImplementedError(
             "generate() supports dense GPT; MoE decode needs expert "

@@ -48,6 +48,34 @@ def test_kernels_rehearsal():
     chip_smoke.check_ragged(41, 4, 2, 64, 8, 1, True)
 
 
+@pytest.mark.parametrize("forced", [False, True])
+def test_experts_rehearsal(forced):
+    r = chip_smoke.check_dropless(256, 64, 32, 8, 2, jnp.bfloat16, forced)
+    assert (r["load"] >= 4.0) == forced     # 4: every token's first choice
+
+
+def test_experts_check_catches_a_capacity(monkeypatch):
+    """A drop-less layer that loses rows fails the phase: here the fullest
+    expert's group is cut to twice the mean, as a capacity would."""
+    import jax
+    from paddle_tpu.distributed import moe
+
+    real = jax.lax.ragged_dot
+
+    def capped(lhs, rhs, group_sizes, **kw):
+        cap = 2 * lhs.shape[0] // group_sizes.shape[0]
+        rows = jnp.arange(lhs.shape[0])
+        start = jnp.cumsum(group_sizes) - group_sizes
+        group = jnp.searchsorted(jnp.cumsum(group_sizes), rows, side="right")
+        keep = rows - start[group] < cap
+        return jnp.where(keep[:, None], real(lhs, rhs, group_sizes, **kw), 0)
+
+    monkeypatch.setattr(moe.jax.lax, "ragged_dot", capped)
+    chip_smoke.check_dropless(256, 64, 32, 8, 2, jnp.bfloat16, False)
+    with pytest.raises(chip_smoke.SmokeFailure):
+        chip_smoke.check_dropless(256, 64, 32, 8, 2, jnp.bfloat16, True)
+
+
 def test_kernel_check_catches_a_wrong_kernel(monkeypatch):
     from paddle_tpu.ops import paged_attention as pa
 
