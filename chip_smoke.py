@@ -14,7 +14,9 @@ finds, and checks what comes out by the repo's own means:
   1b experts  the drop-less expert layer at OLMoE's widths (64 experts of
               1024, 8 a token, 4096 tokens), forward and gradients against
               the float32 reference, balanced and with one expert forced
-              to over four times its share: nothing is dropped
+              to over four times its share: nothing is dropped, and the
+              grouped products took the path production takes here, the
+              Pallas kernels moe_gmm/moe_tgmm
   2 serve     ServingEngine.submit/step/run, ten requests over ~150 ticks
   3 train     HybridPipelineTrainer.step, bench.py's headline knobs
   4 multichip the same trainer on dp2 x tp2 and pp2 x tp2, and the ZeRO /
@@ -323,12 +325,22 @@ def check_dropless(t: int, h: int, f: int, e: int, k: int, dtype,
     """``dropless_moe`` forward and gradients on ``t`` tokens against the
     float32 reference on the same (dtype-rounded) seeded inputs.
     ``forced``: the tokens share a direction that the router's column 0
-    is aligned with, so expert 0 is nearly every token's choice."""
+    is aligned with, so expert 0 is nearly every token's choice.
+    ``path`` in the result is what the layer counted for its grouped
+    products while it was traced: ``pallas`` or ``xla``."""
     import jax
     import jax.numpy as jnp
 
     from paddle_tpu.distributed.moe import dropless_moe
     from paddle_tpu.models import olmoe_reference as ref
+    from paddle_tpu.profiler import metrics
+
+    def counted():
+        reg = metrics.registry()
+        return {p: reg.counter("moe/grouped_matmul_calls{path=%s}" % p).value
+                for p in ("pallas", "xla")}
+
+    before = counted()
 
     ks = jax.random.split(jax.random.PRNGKey(1 + forced), 6)
     x = jax.random.normal(ks[0], (t, h), jnp.float32)
@@ -382,7 +394,9 @@ def check_dropless(t: int, h: int, f: int, e: int, k: int, dtype,
     check(max(errs[n] for n in ("dx", "drouter", "dw_gate", "dw_up",
                                 "dw_down")) <= TOL_EXPERTS_BWD,
           f"experts bwd error {errs}")
-    return {**errs, "load": load}
+    paths = [p for p, n in counted().items() if n > before[p]]
+    check(len(paths) == 1, f"experts: grouped products counted on {paths}")
+    return {**errs, "load": load, "path": paths[0]}
 
 
 def phase_experts(t: int, h: int, f: int, e: int, k: int) -> None:
@@ -391,8 +405,12 @@ def phase_experts(t: int, h: int, f: int, e: int, k: int) -> None:
     t0 = time.perf_counter()
     for forced in (False, True):
         r = check_dropless(t, h, f, e, k, jnp.bfloat16, forced)
+        path = r.pop("path")
+        check(path == "pallas", f"experts: the grouped products took the "
+              f"{path} path on one TPU device at widths that tile")
         say("experts", f"dropless_moe T={t} h={h} {e} experts of {f}, "
-            f"top-{k}, {'one expert forced' if forced else 'balanced'}: "
+            f"top-{k}, {'one expert forced' if forced else 'balanced'}, "
+            f"grouped products through {path}: "
             f"load max/mean {r.pop('load'):.2f}, dropped 0; vs float32 "
             "reference " + " ".join(f"{n}={v:.2e}" for n, v in r.items())
             + f" (tol {TOL_EXPERTS_FWD}/{TOL_EXPERTS_BWD})")

@@ -24,8 +24,10 @@ TPU-first per the GShard/Switch pattern:
 publish it (OLMoE, arXiv:2409.02060): every token keeps all its ``top_k``
 experts whatever the imbalance. There is no capacity: the ``T x k``
 assignments are ordered by expert and the experts run as grouped matrix
-multiplications over ragged groups (``jax.lax.ragged_dot``, which XLA:TPU
-compiles to its own Mosaic kernel with exactly the needed operations,
+multiplications over ragged groups (``ops.grouped_matmul``: the Pallas
+kernels ``moe_gmm``/``moe_tgmm`` on one TPU device, ``jax.lax.ragged_dot``
+on the CPU, under a multi-device mesh, which GSPMD partitions it over, and
+for shapes that do not tile; either way exactly the needed operations,
 forward and both backward products).
 
 Composes with dp/tp/ep through the strategy compiler
@@ -46,6 +48,7 @@ from .. import nn
 from ..framework.tensor import Tensor
 from ..nn import functional as F
 from ..nn import initializer as I
+from ..ops import grouped_matmul as _gmm
 from ..profiler.trace import annotate as _annotate
 from ..tensor._helper import apply
 
@@ -254,6 +257,18 @@ def publish_expert_load(stats) -> None:
         float(stats["moe/load_max"]) * rows.size / max(assigned, 1.0))
 
 
+def _expert_product(rows, w, group_sizes):
+    """One of the three grouped products, counted at trace time under the
+    path it takes (``moe/grouped_matmul_calls{path=pallas|xla}`` in the
+    profiler's registry: a count on the host, nothing in the program)."""
+    from ..profiler import metrics
+
+    path = _gmm.kernel_path(rows.shape[0], rows.shape[1], w.shape[2])
+    metrics.registry().counter(
+        "moe/grouped_matmul_calls{path=%s}" % path).add(1)
+    return _gmm.grouped_matmul(rows, w.astype(rows.dtype), group_sizes)
+
+
 def dropless_moe(x, router_w, w_gate, w_up, w_down, top_k):
     """Token-choice MoE FFN with no token dropped. x: [T, H]; router_w:
     [H, E], no bias; SiLU-gated experts stacked w_gate, w_up [E, H, F] and
@@ -320,10 +335,9 @@ def dropless_moe(x, router_w, w_gate, w_up, w_down, top_k):
     with _annotate("moe/dispatch"):
         xs = _dispatch_gather(x, token_of_row, row_of_token, every)
     with _annotate("moe/experts"):
-        mid = jax.nn.silu(jax.lax.ragged_dot(
-            xs, w_gate.astype(x.dtype), group_sizes)) * \
-            jax.lax.ragged_dot(xs, w_up.astype(x.dtype), group_sizes)
-        ys = jax.lax.ragged_dot(mid, w_down.astype(x.dtype), group_sizes)
+        mid = jax.nn.silu(_expert_product(xs, w_gate, group_sizes)) * \
+            _expert_product(xs, w_up, group_sizes)
+        ys = _expert_product(mid, w_down, group_sizes)
     with _annotate("moe/combine"):
         y = _combine_gather(ys, gates, row_of_token, every, token_of_row,
                             round_of_row, jnp.ones((n,), bool))

@@ -52,6 +52,7 @@ def test_kernels_rehearsal():
 def test_experts_rehearsal(forced):
     r = chip_smoke.check_dropless(256, 64, 32, 8, 2, jnp.bfloat16, forced)
     assert (r["load"] >= 4.0) == forced     # 4: every token's first choice
+    assert r["path"] == "xla"               # the CPU's; the chip's is pallas
 
 
 def test_experts_check_catches_a_capacity(monkeypatch):

@@ -249,32 +249,100 @@ def test_tpu_compile_serving_tick(monkeypatch):
     assert ma.alias_size_in_bytes > 0, "the donated page pools are not aliased"
 
 
-def test_tpu_compile_dropless_moe_at_olmoe_widths(monkeypatch):
+@pytest.mark.parametrize("mesh_devices", [1, 2])
+def test_tpu_compile_dropless_moe_at_olmoe_widths(monkeypatch, mesh_devices):
     """One drop-less expert layer at OLMoE's widths (4,096 tokens, 64
     experts of 1,024, 8 a token), forward and gradients, compiles for the
-    v5e with XLA:TPU's own ragged-dot kernel for all three products and
-    their six backward products, at the operations needed and no more: a
-    dense fallback over the groups would count 64 times as many."""
+    v5e at the operations needed and no more (a dense fallback over the
+    groups would count 64 times as many). On one device all three products
+    and their six backward products are the Pallas calls ``moe_gmm`` and
+    ``moe_tgmm`` and XLA's ragged-dot kernel is gone; under a two-device
+    auto mesh, the experts over ``ep``, they are XLA:TPU's own ragged-dot
+    kernel, which GSPMD partitions (it refuses a Mosaic call there)."""
     import jax.numpy as jnp
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+    from paddle_tpu.distributed import context as dctx
     from paddle_tpu.distributed.moe import dropless_moe
 
-    dev = _tpu_topology_devices()[0]
     monkeypatch.setenv("PADDLE_TPU_TARGET_PLATFORM", "tpu")
     t, h, f, e, k = 4096, 2048, 1024, 64, 8
-    args = [_on_tpu(dev, s, jnp.bfloat16) for s in
-            ((t, h), (h, e), (e, h, f), (e, h, f), (e, f, h))]
+    shapes = ((t, h), (h, e), (e, h, f), (e, h, f), (e, f, h))
+    mesh = Mesh(np.array(_tpu_topology_devices()[:mesh_devices]), ("ep",))
+    specs = (P(), P()) + (P("ep"),) * 3
+    args = [jax.ShapeDtypeStruct(s, jnp.bfloat16,
+                                 sharding=NamedSharding(mesh, spec))
+            for s, spec in zip(shapes, specs)]
 
     def loss(*a):
         y, balance, z, _ = dropless_moe(*a, top_k=k)
         return jnp.sum(y.astype(jnp.float32)) + balance + z
 
-    # conftest asks for "highest" everywhere; with bf16 operands XLA's
-    # kernel is then refused by Mosaic ("Bad lhs type"), and bf16 products
+    # conftest asks for "highest" everywhere; with bf16 operands both
+    # kernels are then refused by Mosaic ("Bad lhs type"), and bf16 products
     # are exact in the float32 accumulator at the default anyway
-    with jax.default_matmul_precision("default"):
+    with jax.default_matmul_precision("default"), dctx.kernel_scope(mesh):
         compiled = jax.jit(jax.grad(loss, range(5))).lower(*args).compile()
     text = compiled.as_text()
-    assert len(set(re.findall(r"%(ragged-dot[\w.\-]*) = ", text))) >= 9
+    calls = re.findall(
+        r"%([\w.\-]+) = (\w+\[[\d,]*\])\S* custom-call\([^\n]*"
+        r"\"tpu_custom_call\"", text)
+    ragged = set(re.findall(r"%(ragged-dot-none[\w.\-]*) = ", text))
+    if mesh_devices == 1:
+        assert not ragged
+        ours = sorted((name.split(".")[0], shape) for name, shape in calls
+                      if name.startswith("moe_"))
+        rows = f"bf16[{t * k},"
+        assert ours == sorted(
+            [("moe_gmm", f"{rows}{f}]")] * 3            # gate, up, d mid
+            + [("moe_gmm", f"{rows}{h}]")] * 3          # down, 2 x d xs
+            + [("moe_tgmm", f"bf16[{e},{h},{f}]")] * 2
+            + [("moe_tgmm", f"bf16[{e},{f},{h}]")]), calls
+    else:
+        assert len(ragged) >= 9 and not any(
+            name.startswith("moe_") for name, _ in calls)
+    # a Pallas call reports its cost estimate: 2 m k n each
     needed = 3 * 3 * 2.0 * t * k * h * f
     assert needed <= compiled.cost_analysis()["flops"] < 1.1 * needed
+
+
+def test_tpu_compile_olmoe_step_of_the_cell(monkeypatch):
+    """The step of ``train-olmoe-1chip-4k`` (OLMoE at published widths,
+    depth 2, 8 micro-batches of one sequence of 4,096, bf16 parameters and
+    moments, full recomputation) compiles for one v5e inside its 15.75 GB,
+    with the experts' products as the Pallas calls: three forward, three
+    recomputed and six backward in the layer scan's body."""
+    import dataclasses
+
+    import paddle_tpu as paddle
+    from paddle_tpu.distributed.fleet.distributed_strategy import \
+        DistributedStrategy
+    from paddle_tpu.distributed.hybrid import HybridPipelineTrainer
+    from paddle_tpu.distributed.mesh import create_mesh
+    from paddle_tpu.models import GPT, GPTConfig
+
+    devices = _tpu_topology_devices()
+    monkeypatch.setenv("PADDLE_TPU_TARGET_PLATFORM", "tpu")
+    cfg = dataclasses.replace(GPTConfig.olmoe_1b_7b(), num_layers=2)
+    with paddle.LazyGuard():
+        model = GPT(cfg)
+    opt = paddle.optimizer.AdamW(1e-4, parameters=model.parameters())
+    s = DistributedStrategy()
+    s.amp = True
+    s.recompute = True
+    mesh = create_mesh({"dp": 1, "tp": 1, "pp": 1, "sp": 1},
+                       np.array(devices)[:1])
+    tr = HybridPipelineTrainer(model, opt, s, mesh, n_micro=8,
+                               param_dtype="bfloat16",
+                               moment_dtype="bfloat16")
+    with jax.default_matmul_precision("default"):
+        compiled = tr.aot_lower(
+            jax.ShapeDtypeStruct((8, 4096), np.int32)).compile()
+    ma = compiled.memory_analysis()
+    total = (ma.argument_size_in_bytes + ma.output_size_in_bytes
+             + ma.temp_size_in_bytes - ma.alias_size_in_bytes)
+    assert total < 15.75e9, ma
+    text = compiled.as_text()
+    assert len(re.findall(r"%moe_gmm[\w.\-]* = ", text)) == 9
+    assert len(re.findall(r"%moe_tgmm[\w.\-]* = ", text)) == 3
+    assert not re.findall(r"%ragged-dot-none[\w.\-]* = ", text)
