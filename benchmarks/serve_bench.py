@@ -19,15 +19,9 @@ prefills only each request's suffix, so first tokens arrive without
 re-running the system prompt per request. The profiler block carries
 ``serving/prefix_hit_tokens`` as the direct evidence.
 
-``--attention-kernel {ragged-xla,ragged-pallas,legacy}`` selects the
-engine's attention/dispatch path for either workload (default: the
-unified mixed-row tick on the XLA gather spelling).
-``--kernel-matrix`` instead runs BOTH workloads under every kernel and
-reports unified-vs-legacy throughput + TTFT — the dispatch-collapse
-evidence (BENCH_SERVE_r08.json holds a full run). Engines are compared
-against each other (same weights, all warm); greedy outputs are
-bitwise-equal across ragged-xla and legacy, so the delta is pure
-dispatch/compute structure.
+``--attention-kernel {ragged-xla,ragged-pallas}`` selects the
+attention spelling of the engine's one mixed-row tick for either
+workload (default: the XLA gather spelling).
 
 The baseline is exactly what a naive deployment of this repo would run
 today, warmed so the comparison is decode-vs-decode, not
@@ -39,8 +33,7 @@ The Poisson and --prefix-cache blocks carry the full registry
 snapshot, the per-request latency-breakdown table + rolling TTFT/TPOT
 p50/p90/p95/p99 (profiler event timelines), the compiled-program
 inventory (compile wall-time + cost-analysis FLOPs/bytes per dispatch
-site), and the measured event-log overhead on the decode hot loop
-(--kernel-matrix cells stay lean: throughput + TTFT per kernel).
+site), and the measured event-log overhead on the decode hot loop.
 ``--sink-dir`` additionally streams everything to disk (metrics.jsonl
 + events.jsonl + metrics.prom — the ISSUE 8 persistent-sink artifact;
 tools/check_sink_schema.py validates it in CI).
@@ -62,7 +55,6 @@ full runs of both.
 
     python benchmarks/serve_bench.py                 # Poisson, 8 slots
     python benchmarks/serve_bench.py --prefix-cache  # shared-prefix TTFT
-    python benchmarks/serve_bench.py --kernel-matrix # unified vs legacy
     python benchmarks/serve_bench.py --sched-matrix  # fifo/sjf/aged-sjf
     python benchmarks/serve_bench.py --adaptive-k    # adaptive spec-k
     python benchmarks/serve_bench.py --elastic       # kill-one redispatch
@@ -277,8 +269,7 @@ def bench_poisson(args, tiny):
     # the per-tick overhead surface. Single-run wall clocks on this
     # box swing far more than the effect being measured, so both arms
     # run ``reps`` times INTERLEAVED (drift hits both equally) and the
-    # comparison is best-of-reps per arm — the kernel-matrix
-    # noise-floor precedent.
+    # comparison is best-of-reps per arm.
     from paddle_tpu.profiler import events as _pevents
 
     reps = max(2, args.reps)
@@ -374,8 +365,7 @@ def bench_poisson(args, tiny):
                         "baseline_p95": round(pct(bl_ttft, 95), 2)},
             # per-request latency breakdowns + rolling TTFT/TPOT
             # percentiles from the event timelines, the full registry
-            # snapshot, and the compiled-program inventory (ISSUE 8:
-            # kernel-matrix runs carry percentiles, not just means)
+            # snapshot, and the compiled-program inventory (ISSUE 8)
             "request_latency": lat_stats,
             "latency_table": lat_rows,
             "registry": summ["metrics"],
@@ -435,8 +425,8 @@ def bench_shared_prefix(args, tiny):
         # off the clock, then flush results + cached pages so the
         # measured run starts cold
         run_concurrent(eng, reqs)
-        eng.pool.k, eng.pool.v = eng._copy(
-            eng.pool.k, eng.pool.v, np.int32(0), np.int32(0))
+        eng.pool.pools = eng._copy(eng.pool.pools, np.int32(0),
+                                   np.int32(0))
         eng.pool.drop_prefix_cache()
         eng.reset_results()
         return eng
@@ -520,10 +510,7 @@ def bench_shared_prefix(args, tiny):
 def _pool_bytes(eng):
     """Device bytes of an engine's page pool, scale arrays included —
     the honest denominator of the residency claim."""
-    b = eng.pool.k.nbytes + eng.pool.v.nbytes
-    if eng.pool.quantized:
-        b += eng.pool.k_scale.nbytes + eng.pool.v_scale.nbytes
-    return b
+    return sum(a.nbytes for a in eng.pool.pools.arrays().values())
 
 
 def _continuation_nll(net, prompt, cont):
@@ -759,7 +746,7 @@ def bench_spec(args, tiny):
     compute), so the margin there comes only from BLAS batching
     efficiency and shrinks toward (or below) 1x as the draft deepens —
     the measured draft-depth sensitivity is stated in the note. Best-of
-    ``--reps`` per arm per cell (kernel-matrix noise-floor precedent).
+    ``--reps`` per arm per cell.
     """
     import paddle_tpu as paddle
     import paddle_tpu.profiler as profiler
@@ -1104,95 +1091,6 @@ def bench_spec_sampling(args, tiny):
                      "allocated pages — draft KV now lives on the "
                      "shared PagePool allocator, priced by the same "
                      "residency ledger as target bytes"),
-        },
-    }
-
-
-def bench_kernel_matrix(args, tiny):
-    """Unified-tick vs legacy two-dispatch (vs the Pallas ragged
-    kernel) on BOTH workloads: the mixed Poisson arrival trace and the
-    shared-system-prompt concurrent burst. Engines only — the dense
-    baseline is bench_poisson's job; here the delta under test is
-    dispatch/compute structure at identical outputs (ragged-xla and
-    legacy are bitwise-equal greedy). Each cell is best-of ``--reps``
-    (this box's CPU timings are noisy; best-of measures the program,
-    not the scheduler jitter)."""
-    if args.reps < 1:
-        raise SystemExit("--reps must be >= 1")
-    kernels = ["legacy", "ragged-xla", "ragged-pallas"]
-    n_req = 6 if tiny else args.requests
-    max_new = 8 if tiny else args.max_new
-    slots = 4 if tiny else args.slots
-    prompt_lens = (8, 16) if tiny else (16, 32, 64)
-    page_size = 8 if tiny else 16
-    pages_per_slot = -(-(max(prompt_lens) + max_new) // page_size)
-    sys_len = 16 if tiny else 64
-    sfx_len = 8
-    shared_pps = -(-(sys_len + sfx_len + max_new) // page_size)
-
-    net = build_model(tiny)
-    trace = make_trace(n_req, prompt_lens, max_new, args.rate)
-    reqs = make_shared_prefix_requests(slots, sys_len, sfx_len, max_new)
-
-    def measure(kernel):
-        mixed_eng = build_engine(net, slots, page_size, pages_per_slot,
-                                 attention_kernel=kernel)
-        warm = make_trace(max(2, slots), prompt_lens, max_new, 1e9,
-                          seed=1)
-        run_engine(mixed_eng, [(0.0, p, m) for _, p, m in warm])
-        shared_eng = build_engine(net, slots, page_size, shared_pps,
-                                  prefill_chunk=2 * page_size,
-                                  attention_kernel=kernel)
-        run_concurrent(shared_eng, reqs)
-        best = {"mixed_tokens_per_sec": 0.0,
-                "shared_tokens_per_sec": 0.0}
-        for _ in range(args.reps):
-            mixed_eng.pool.drop_prefix_cache()
-            toks, wall, ttfts, _, _ = run_engine(mixed_eng, trace)
-            if toks / wall > best["mixed_tokens_per_sec"]:
-                best["mixed_tokens_per_sec"] = toks / wall
-                best["mixed_ttft_p50_ms"] = pct(ttfts, 50)
-                best["mixed_ttft_p95_ms"] = pct(ttfts, 95)
-            shared_eng.pool.drop_prefix_cache()
-            toks, wall, ttfts = run_concurrent(shared_eng, reqs)
-            if toks / wall > best["shared_tokens_per_sec"]:
-                best["shared_tokens_per_sec"] = toks / wall
-                best["shared_ttft_mean_ms"] = float(np.mean(ttfts))
-        return {k: round(v, 2) for k, v in best.items()}
-
-    cells = {k: measure(k) for k in kernels}
-    speedup = cells["ragged-xla"]["mixed_tokens_per_sec"] / \
-        max(cells["legacy"]["mixed_tokens_per_sec"], 1e-9)
-    return {
-        "metric": "serving_unified_tick_speedup",
-        "value": round(speedup, 4),
-        "unit": "x tokens/s, unified mixed-row tick vs legacy "
-                "two-dispatch (mixed Poisson workload)",
-        "extra": {
-            "mode": "tiny" if tiny else "full",
-            "model": {"hidden": net.config.hidden_size,
-                      "layers": net.config.num_layers,
-                      "vocab": net.config.vocab_size},
-            "kernels": cells,
-            "shared_prefix_ttft_speedup": round(
-                cells["legacy"]["shared_ttft_mean_ms"]
-                / max(cells["ragged-xla"]["shared_ttft_mean_ms"], 1e-9),
-                4),
-            "requests": n_req, "slots": slots,
-            "prompt_lens": list(prompt_lens), "max_new": max_new,
-            "page_size": page_size, "reps": args.reps,
-            "note": ("one jitted mixed-row tick (decode rows + prefill-"
-                     "chunk rows as ragged rows of one program, with a "
-                     "compiled decode-only fast path via lax.cond) vs "
-                     "the pre-unification decode-tick + separate "
-                     "prefill-program pair; greedy outputs bitwise-"
-                     "equal between ragged-xla and legacy. ragged-"
-                     "pallas runs the Pallas kernel in INTERPRET mode "
-                     "on this CPU backend — it lowers to per-grid-step "
-                     "XLA ops, so its numbers here measure interpret "
-                     "overhead, not the kernel (real-TPU measurement "
-                     "pending, ROADMAP); best-of-reps per cell since "
-                     "this box's CPU timings are noisy"),
         },
     }
 
@@ -2264,10 +2162,6 @@ def main():
     ap.add_argument("--prefix-cache", action="store_true",
                     help="shared-system-prompt workload: prefix-cache-on"
                          " vs -off TTFT comparison")
-    ap.add_argument("--kernel-matrix", action="store_true",
-                    help="unified-tick vs legacy two-dispatch (and the "
-                         "interpret-mode Pallas kernel) on both "
-                         "workloads")
     ap.add_argument("--spec-decode", action="store_true",
                     help="speculative decoding: spec engine (early-"
                          "exit draft, greedy acceptance) vs the plain "
@@ -2303,9 +2197,9 @@ def main():
                          "requests co-resident); combines with "
                          "--sched-policy")
     ap.add_argument("--attention-kernel", default="ragged-xla",
-                    choices=["ragged-xla", "ragged-pallas", "legacy"],
-                    help="engine attention/dispatch path for the "
-                         "single-workload modes")
+                    choices=["ragged-xla", "ragged-pallas"],
+                    help="attention spelling of the engine's tick for "
+                         "the single-workload modes")
     ap.add_argument("--kv-dtype", default="f32",
                     choices=["f32", "bf16", "int8"],
                     help="page-pool storage dtype. 'f32' runs the "
@@ -2346,7 +2240,7 @@ def main():
     ap.add_argument("--requests", type=int, default=24)
     ap.add_argument("--max-new", type=int, default=48)
     ap.add_argument("--reps", type=int, default=3,
-                    help="repetitions per kernel-matrix cell (best-of)")
+                    help="repetitions per arm of a comparison (best-of)")
     ap.add_argument("--rate", type=float, default=200.0,
                     help="Poisson arrival rate (req/s)")
     ap.add_argument("--sink-dir", default=None,
@@ -2376,47 +2270,35 @@ def main():
                          "trace_summary.json (Poisson and "
                          "--prefix-cache modes)")
     args = ap.parse_args()
-    if args.spec_decode and args.attention_kernel == "legacy":
-        ap.error("--spec-decode needs the unified tick; "
-                 "--attention-kernel legacy has no verify-row path")
-    if args.sched_policy != "fifo" and args.attention_kernel == \
-            "legacy":
-        ap.error("--sched-policy needs the unified tick; "
-                 "--attention-kernel legacy keeps fifo selection")
     if args.sampling and not args.spec_decode:
         ap.error("--sampling qualifies --spec-decode (the sampled "
                  "rejection-acceptance cell); the plain Poisson mode "
                  "is greedy-only")
-    if args.trace_window and (args.kernel_matrix or args.spec_decode
-                              or args.sched_matrix or args.adaptive_k):
+    if args.trace_window and (args.spec_decode or args.sched_matrix
+                              or args.adaptive_k):
         ap.error("--trace-window rides the Poisson or --prefix-cache "
                  "modes (the matrix/spec cells stay lean)")
-    if args.kv_dtype != "f32" and (args.kernel_matrix or
-                                   args.spec_decode or
+    if args.kv_dtype != "f32" and (args.spec_decode or
                                    args.prefix_cache or
                                    args.trace_window or
                                    args.sched_matrix or
                                    args.adaptive_k):
         ap.error("--kv-dtype bf16/int8 is its own comparison mode "
                  "(residency + quality proxy vs the f32 engine)")
-    if args.sched_matrix and (args.kernel_matrix or args.spec_decode
-                              or args.prefix_cache or
-                              args.adaptive_k):
+    if args.sched_matrix and (args.spec_decode or args.prefix_cache
+                              or args.adaptive_k):
         ap.error("--sched-matrix is its own comparison mode")
-    if args.adaptive_k and (args.kernel_matrix or args.spec_decode
-                            or args.prefix_cache):
+    if args.adaptive_k and (args.spec_decode or args.prefix_cache):
         ap.error("--adaptive-k is its own comparison mode (the "
                  "static-vs-adaptive spec engines are built inside)")
-    if args.elastic and (args.kernel_matrix or args.spec_decode or
-                         args.prefix_cache or args.sched_matrix or
-                         args.adaptive_k or args.kv_dtype != "f32" or
+    if args.elastic and (args.spec_decode or args.prefix_cache or
+                         args.sched_matrix or args.adaptive_k or args.kv_dtype != "f32" or
                          args.hosts > 1 or args.trace_window or
                          args.sink_dir or args.live_status):
         ap.error("--elastic is its own comparison mode (real "
                  "processes; per-cell sinks live in the cell dirs)")
     if args.prefix_routing and (
-            args.kernel_matrix or args.spec_decode or
-            args.prefix_cache or args.sched_matrix or
+            args.spec_decode or args.prefix_cache or args.sched_matrix or
             args.adaptive_k or args.kv_dtype != "f32" or
             args.hosts > 1 or args.elastic or args.trace_window or
             args.live_status):
@@ -2459,15 +2341,12 @@ def main():
     elif args.prefix_routing:
         out = bench_prefix_routing(args, args.tiny)
     elif args.hosts > 1:
-        if args.kernel_matrix or args.spec_decode or \
-                args.prefix_cache or args.kv_dtype != "f32" or \
+        if args.spec_decode or args.prefix_cache or args.kv_dtype != "f32" or \
                 args.sched_matrix or args.adaptive_k:
             ap.error("--hosts N is its own comparison mode")
         out = bench_multihost(args, args.tiny)
     elif args.kv_dtype != "f32":
         out = bench_kv_quant(args, args.tiny)
-    elif args.kernel_matrix:
-        out = bench_kernel_matrix(args, args.tiny)
     elif args.spec_decode:
         out = (bench_spec_sampling(args, args.tiny) if args.sampling
                else bench_spec(args, args.tiny))

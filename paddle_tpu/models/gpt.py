@@ -665,7 +665,7 @@ def _ln(x, w, b, eps):
 def gpt_block_body(xc, p, eps, nh, hd, attend):
     """One pre-norm transformer block over stacked decode params ``p``,
     shared by the dense cached path (gpt_cached_apply) and the paged
-    serving tick (serving/engine.py) — the two must stay BITWISE
+    serving tick (gpt_ragged_apply) — the two must stay BITWISE
     identical, so the block math lives in exactly one place and only the
     cache handling differs: ``attend(q, kk, vv) -> (o [n,t,nh,hd],
     extra)`` writes this layer's KV into its cache and attends."""
@@ -755,16 +755,18 @@ def gpt_cached_apply(cfg: GPTConfig, stacked, other, ck, cv, tokens, pos0,
     return logits, jnp.swapaxes(ckl, 0, 1), jnp.swapaxes(cvl, 0, 1)
 
 
-def gpt_ragged_apply(cfg: GPTConfig, stacked, other, kpool, vpool,
-                     tokens, tok_pos, tok_limit, row_tab, row_pos0,
-                     row_len, sample_ix, decode_rows: int,
-                     chunk_width: int, impl: str = "xla",
-                     spec_k: int = 0, kscale=None, vscale=None):
+def gpt_ragged_apply(cfg: GPTConfig, stacked, other, pools, tokens,
+                     tok_pos, tok_limit, row_tab, row_pos0, row_len,
+                     sample_ix, decode_rows: int, chunk_width: int,
+                     impl: str = "xla", spec_k: int = 0):
     """Mixed prefill/decode forward over the PAGED cache: every token
-    in flight rides one program. ``tokens`` [NT] is the flat token
-    buffer of one serving tick — ``decode_rows`` resident decode
-    tokens followed by the prefill chunks, ``chunk_width`` tokens
-    each; which is which is *only* metadata:
+    in flight rides one program. ``pools`` is the page pools
+    (``serving.paged_cache.Pools``, stacked over layers) — this forward
+    knows nothing of their format: each layer writes through
+    ``pools.scatter`` and reads through ``pools.attend``. ``tokens``
+    [NT] is the flat token buffer of one serving tick — ``decode_rows``
+    resident decode tokens followed by the prefill chunks,
+    ``chunk_width`` tokens each; which is which is *only* metadata:
 
     tok_pos    [NT] int32   absolute cache position of each token
     tok_limit  [NT] int32   first non-writable position of the token's
@@ -790,13 +792,13 @@ def gpt_ragged_apply(cfg: GPTConfig, stacked, other, kpool, vpool,
     ``ragged_paged_attention`` entry point, with rows grouped by their
     static query width — decode rows as ``[decode_rows, 1]`` and chunk
     rows as ``[num_chunks, chunk_width]`` — so a decode-only tick pays
-    the pre-unification decode gather cost, not ``chunk_width×`` pad
+    the decode gather cost, not ``chunk_width×`` pad
     queries ("Ragged Paged Attention", PAPERS.md: per-row
     ``(pos0, true_len)`` metadata; the width grouping is the XLA-
     friendly layout of the same raggedness, and the Pallas kernel
     underneath handles either width in one grid). All metadata may be
     traced: one compiled program serves every mix of resident decodes
-    and prompt chunks. Returns (logits [S, V], kpool, vpool).
+    and prompt chunks. Returns (logits [S, V], pools).
 
     ``spec_k > 0`` (speculative decoding, serving/spec.py) widens each
     of the ``decode_rows`` slot rows into a **verify row** of
@@ -815,26 +817,21 @@ def gpt_ragged_apply(cfg: GPTConfig, stacked, other, kpool, vpool,
     program — hidden/head contractions are row-independent, LN/GELU
     are elementwise, and attention always reduces over the full slot
     capacity with exact-zero masked weights (``ops/paged_attention._
-    gather_attend``, the one shared spelling) — so a decode row here
-    equals the old dedicated decode tick and a chunk row equals the
-    old suffix-prefill program, token for token, bit for bit; a verify
-    position equals the decode row the non-speculative engine would
-    have run at that position.
+    gather_attend``, the one shared spelling) — so a decode row equals
+    a chunk row of length 1 at the same position, token for token, bit
+    for bit, and a verify position equals the decode row the
+    non-speculative engine would have run at that position.
 
-    ``kscale``/``vscale`` [L, P, NH] (ISSUE 12): per-page per-head
-    scales of an int8 pool. When given, every token's KV write routes
-    through ``ops/paged_attention.paged_kv_scatter`` (quantize at the
-    page's running-max scale, re-quantizing resident content when it
-    grows) and the attention gather dequantizes with the same scales —
-    the whole int8 story lives in those two shared helpers, so both
-    attention impls and every delegating spelling inherit it. The
-    return grows to (logits, kpool, vpool, kscale, vscale); numerics
-    are tolerance, not bitwise, vs the unquantized pool (the engine
-    only asserts bitwise between two int8 engines).
+    With int8 pools (ISSUE 12) every token's KV write quantizes at the
+    page's running-max scale and the attention gather dequantizes with
+    the same scales (``ops/paged_attention.paged_kv_scatter`` and
+    ``_gather_attend``, behind ``pools``); numerics are then tolerance,
+    not bitwise, vs the unquantized pool (the engine only asserts
+    bitwise between two int8 engines).
+
+    How the pools travel — ``lax.scan`` xs -> ys, one layer's slice a
+    step — is decided here and nowhere else (ROADMAP S3).
     """
-    from ..ops.paged_attention import (paged_kv_scatter,
-                                      ragged_paged_attention)
-
     _require_gpt3_block(cfg)
     nt = tokens.shape[0]
     nd = decode_rows
@@ -843,7 +840,7 @@ def gpt_ragged_apply(cfg: GPTConfig, stacked, other, kpool, vpool,
     nh = cfg.num_heads
     hd = cfg.hidden_size // nh
     eps = cfg.layer_norm_eps
-    ps = kpool.shape[2]
+    ps = pools.page_size
     nps = row_tab.shape[1]
     wte = other["embeddings.wte.weight"]
     wpe = other["embeddings.wpe.weight"]
@@ -867,21 +864,12 @@ def gpt_ragged_apply(cfg: GPTConfig, stacked, other, kpool, vpool,
         0)
     off = tok_pos % ps
 
-    quantized = kscale is not None
-
     def block(xc, inp):
-        if quantized:
-            p, kpl0, vpl0, ksl0, vsl0 = inp
-        else:
-            p, kpl0, vpl0 = inp
-            ksl0 = vsl0 = None
+        p, pl0 = inp
 
         def attend(q, kk, vv):
             with annotate("blk/kv_scatter"):
-                kpl, ksl = paged_kv_scatter(kpl0, ksl0, page, off,
-                                            kk[:, 0])
-                vpl, vsl = paged_kv_scatter(vpl0, vsl0, page, off,
-                                            vv[:, 0])
+                pl = pl0.scatter(page, off, kk, vv)
             outs = []
             if nd and spec_k:
                 # verify grouping [nd, 1 + spec_k]: each slot's last
@@ -890,34 +878,25 @@ def gpt_ragged_apply(cfg: GPTConfig, stacked, other, kpool, vpool,
                 qv = jnp.concatenate(
                     [q[:nd], q[nd:base, 0].reshape(nd, spec_k, nh, hd)],
                     axis=1)
-                ov = ragged_paged_attention(
-                    qv, kpl, vpl, row_tab[:nd], row_pos0[:nd],
-                    row_len[:nd], impl=impl, k_scale=ksl, v_scale=vsl)
+                ov = pl.attend(qv, row_tab[:nd], row_pos0[:nd],
+                               row_len[:nd], impl)
                 outs.append(ov[:, :1])
                 outs.append(ov[:, 1:].reshape(nd * spec_k, 1, nh, hd))
             elif nd:
-                outs.append(ragged_paged_attention(
-                    q[:nd], kpl, vpl, row_tab[:nd], row_pos0[:nd],
-                    row_len[:nd], impl=impl, k_scale=ksl, v_scale=vsl))
+                outs.append(pl.attend(q[:nd], row_tab[:nd], row_pos0[:nd],
+                                      row_len[:nd], impl))
             if nch:
                 qp = q[base:, 0].reshape(nch, chunk_width, nh, hd)
-                op = ragged_paged_attention(
-                    qp, kpl, vpl, row_tab[nd:], row_pos0[nd:],
-                    row_len[nd:], impl=impl, k_scale=ksl, v_scale=vsl)
+                op = pl.attend(qp, row_tab[nd:], row_pos0[nd:],
+                               row_len[nd:], impl)
                 outs.append(op.reshape(nch * chunk_width, 1, nh, hd))
             o = outs[0] if len(outs) == 1 else \
                 jnp.concatenate(outs, axis=0)
-            return (o, (kpl, vpl, ksl, vsl)) if quantized \
-                else (o, (kpl, vpl))
+            return o, pl
 
         return gpt_block_body(xc, p, eps, nh, hd, attend)
 
-    if quantized:
-        x, (kpool, vpool, kscale, vscale) = jax.lax.scan(
-            block, x, (stacked, kpool, vpool, kscale, vscale))
-    else:
-        x, (kpool, vpool) = jax.lax.scan(block, x,
-                                         (stacked, kpool, vpool))
+    x, pools = jax.lax.scan(block, x, (stacked, pools))
     with annotate("tick/head"):
         x = _ln(x, other["ln_f.weight"], other["ln_f.bias"], eps)
         last = x[sample_ix, 0]                          # [S, h]
@@ -925,35 +904,7 @@ def gpt_ragged_apply(cfg: GPTConfig, stacked, other, kpool, vpool,
             logits = last @ other["lm_head.weight"]
         else:
             logits = last @ wte.T
-    if quantized:
-        return logits, kpool, vpool, kscale, vscale
-    return logits, kpool, vpool
-
-
-def gpt_paged_suffix_apply(cfg: GPTConfig, stacked, other, kpool, vpool,
-                           tokens, pos0, true_len, page_row,
-                           logits_index, kscale=None, vscale=None):
-    """Suffix-prefill forward over the PAGED cache: one prompt chunk
-    ``tokens`` [1, T] at positions pos0..pos0+T-1 of the slot whose
-    page-table row is ``page_row`` [NPs]. Retired into the unified
-    ragged call — each chunk position becomes one ragged row of
-    ``gpt_ragged_apply`` (bitwise-identical per position, see its
-    contract); kept as the legacy two-dispatch engine mode's prefill
-    program and as the documented single-slot chunk surface.
-    ``pos0``/``true_len``/``logits_index`` may be traced. Returns
-    (logits at chunk index ``logits_index`` [1, V], kpool, vpool).
-    """
-    t = tokens.shape[1]
-    tok_pos = pos0 + jnp.arange(t)
-    tok_limit = jnp.broadcast_to(true_len, (t,))
-    sample_ix = jnp.asarray(logits_index, jnp.int32)[None]
-    return gpt_ragged_apply(cfg, stacked, other, kpool, vpool,
-                            tokens[0], tok_pos, tok_limit,
-                            page_row[None],
-                            jnp.asarray(pos0, jnp.int32)[None],
-                            jnp.full((1,), t, jnp.int32), sample_ix,
-                            decode_rows=0, chunk_width=t,
-                            kscale=kscale, vscale=vscale)
+    return logits, pools
 
 
 def _gpt_decode_state(model: "GPT"):

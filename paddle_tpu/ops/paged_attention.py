@@ -13,9 +13,7 @@ pos0, true_len)`` — a decode step is simply a row with
 chunk width, and both kinds share one program, one grid, one softmax
 spelling. The engine's mixed prefill/decode tick flattens every token
 in flight into rows of this one call (``models/gpt.py::
-gpt_ragged_apply``); the pre-unification entry points
-(``paged_decode_attention``, ``paged_prefill_attention``) survive as
-thin delegations for the legacy two-dispatch engine mode and tests.
+gpt_ragged_apply``, through ``serving.paged_cache.Pools.attend``).
 
 Two implementations behind the one entry point, following the
 ``ops/int8_matmul.py`` precedent (kernel built and gated; the XLA
@@ -25,8 +23,8 @@ spelling is the measured default until the kernel wins on hardware):
   ``[R, S_cap, NH, D]`` view and run exactly the dense-cache attention
   expression from ``models/gpt.py::gpt_cached_apply`` — same einsum
   contractions, same mask constant, same f32 softmax — via the ONE
-  shared helper ``_gather_attend`` (decode, suffix prefill and the
-  ragged path all route here, so "same expression" is enforced by
+  shared helper ``_gather_attend`` (decode rows, chunk rows and
+  verify rows all route here, so "same expression" is enforced by
   code, not by a verbatim-copy comment). This is what makes greedy
   paged decode **bitwise** equal to the dense ``generate`` path
   (tests/test_serving.py): XLA fuses the gather into the attention so
@@ -62,10 +60,9 @@ scale grows (``round(q·s_old/s_new)`` — an exact no-op while the
 scale is unchanged, which is the steady state), and the new token is
 quantized at the final scale; the null page's scale contribution is
 masked so it stays 0 forever. The read side dequantizes inside
-``_gather_attend`` — so the XLA spelling, both delegating entry
-points, AND the Pallas kernel (which DMAs each page's scale rows by
-the page's own index map and dequantizes in VMEM before the online
-softmax) all inherit it from the one shared helper. The f32 path is
+``_gather_attend`` for the XLA spelling, and in VMEM for the Pallas
+kernel (which DMAs each page's scale rows by the page's own index map
+and dequantizes before the online softmax). The f32 path is
 bit-for-bit untouched (no cast, no extra ops) — the engine's bitwise
 parity contract only ever applied to unquantized pools, and still
 does.
@@ -80,8 +77,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-__all__ = ["ragged_paged_attention", "paged_decode_attention",
-           "paged_prefill_attention", "paged_kv_scatter"]
+__all__ = ["ragged_paged_attention", "paged_kv_scatter"]
 
 _NEG_INF = -1e9     # same masking constant as gpt_cached_apply
 
@@ -95,9 +91,9 @@ def _interpret() -> bool:
 def _gather_attend(q, k_pool, v_pool, page_table, qpos,
                    k_scale=None, v_scale=None):
     """THE dense paged-attention expression — the single spelling of
-    gather + mask + f32 softmax shared by every XLA entry point in this
-    module (and, transitively, the spelling ``gpt_cached_apply`` uses
-    on the dense cache: same contraction order, same mask constant,
+    gather + mask + f32 softmax behind ``impl="xla"`` (and,
+    transitively, the spelling ``gpt_cached_apply`` uses on the dense
+    cache: same contraction order, same mask constant,
     same softmax dtype — which is what the engine's bitwise greedy
     parity contract rests on).
 
@@ -187,50 +183,10 @@ def ragged_paged_attention(q, k_pool, v_pool, page_table, pos0, true_len,
     raise ValueError(f"unknown paged attention impl {impl!r}")
 
 
-def paged_decode_attention(q, k_pool, v_pool, page_table, attend_pos,
-                           impl: str = "xla", k_scale=None,
-                           v_scale=None):
-    """One decode step of attention over paged KV: a ragged call where
-    every row is a single query at its slot's write position.
-
-    q           [B, 1, NH, D]  single-position queries
-    page_table  [B, NPs] int32 page ids per slot (0 = null page)
-    attend_pos  [B] int32      last attendable position per slot
-
-    Returns [B, 1, NH, D].
-    """
-    # validate before touching any argument: a bad impl must raise
-    # ValueError even with placeholder args (ones_like would TypeError
-    # first otherwise), and the delegation builds true_len eagerly
-    if impl not in ("xla", "pallas"):
-        raise ValueError(f"unknown paged attention impl {impl!r}")
-    ones = jnp.ones_like(attend_pos)
-    return ragged_paged_attention(q, k_pool, v_pool, page_table,
-                                  attend_pos, ones, impl=impl,
-                                  k_scale=k_scale, v_scale=v_scale)
-
-
-def paged_prefill_attention(q, k_pool, v_pool, page_table, pos0,
-                            k_scale=None, v_scale=None):
-    """Suffix-prefill (chunked) attention over paged KV: a ragged call
-    where each batch row is a T-query chunk starting at the shared
-    scalar position ``pos0`` (query i attends positions <= pos0 + i).
-    The chunk's own KV must already be scattered into the pool.
-    Returns [B, T, NH, D].
-    """
-    b, t = q.shape[0], q.shape[1]
-    row_pos0 = jnp.broadcast_to(jnp.asarray(pos0, jnp.int32), (b,))
-    return ragged_paged_attention(q, k_pool, v_pool, page_table,
-                                  row_pos0,
-                                  jnp.full((b,), t, jnp.int32),
-                                  k_scale=k_scale, v_scale=v_scale)
-
-
 def paged_kv_scatter(pool, scale, page, off, vals):
     """Write one tick's per-token KV into the page pool — the single
-    write-side spelling shared by the unified tick, the spec verify
-    tick and the legacy suffix-prefill program (via
-    ``gpt_ragged_apply``).
+    write-side spelling shared by the unified tick and the spec verify
+    tick (via ``gpt_ragged_apply``).
 
     pool   [P, ps, NH, D]  per-layer page pool (f32/bf16/int8)
     scale  [P, NH] f32     per-page per-head scales (int8 pools; None

@@ -75,8 +75,7 @@ last unified tick). Per-shape executable caches (``GPT.generate``'s
 jit cache, the Predictor's bucket executables, the paged-engine cache)
 report LRU evictions as ``cache_evict/<name>``. The engine's ONE
 hot-path program surfaces at the ``serving.tick#N`` recompile site and
-must stay at one trace (``ServingEngine.compiled_sites``; the legacy
-benchmarking mode adds ``serving.prefill#N``).
+must stay at one trace (``ServingEngine.compiled_sites``).
 
 Quick use::
 

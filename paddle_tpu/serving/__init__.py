@@ -19,8 +19,7 @@ resident decodes, as ragged rows of one
 ("Ragged Paged Attention": per-row ``(pos0, true_len)`` metadata; a
 decode row is simply ``true_len == 1``). XLA gather spelling is the
 default; a Pallas ragged kernel is opt-in (correct on the chip, its
-speed not measured); ``attention_kernel="legacy"`` keeps
-the pre-unification two-dispatch engine for benchmarking. Speculative
+speed not measured). Speculative
 decoding (``ServingConfig.spec`` = ``SpecConfig(draft_model, k)``,
 ``spec.py``) amortizes the target over k drafted tokens per verify
 tick with greedy acceptance — spec greedy output stays BITWISE equal
@@ -78,13 +77,13 @@ from .disagg import (DisaggServer, HandoffChannel, MeshSpec,  # noqa: F401
                      route_requests)
 from .engine import Request, ServingConfig, ServingEngine  # noqa: F401
 from .paged_cache import (NULL_PAGE, PageAllocator, PagePool,  # noqa: F401
-                          PrefixCache)
+                          Pools, PrefixCache)
 from .sched import (SCHED_POLICIES, ChunkScheduler,  # noqa: F401
                     SpecKController)
 from .spec import DraftRunner, SpecConfig  # noqa: F401
 
 __all__ = ["ServingEngine", "ServingConfig", "Request", "SpecConfig",
-           "DraftRunner", "PagePool", "PageAllocator", "PrefixCache",
+           "DraftRunner", "PagePool", "Pools", "PageAllocator", "PrefixCache",
            "NULL_PAGE", "DisaggServer", "MeshSpec", "HandoffChannel",
            "route_requests", "SCHED_POLICIES", "ChunkScheduler",
            "SpecKController"]

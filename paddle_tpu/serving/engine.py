@@ -15,16 +15,13 @@ fill:
   rows) execute in the same program, through one
   ``ops/paged_attention.ragged_paged_attention`` call per layer over
   per-row ``(pos0, true_len)`` metadata ("Ragged Paged Attention",
-  PAPERS.md). The pre-unification design's TWO dispatch sites (a
-  decode tick plus a separate suffix-prefill program alternating on
-  the hot path) collapse to one; the program shape never depends on
+  PAPERS.md). The program shape never depends on
   the prefill/decode mix, so it traces exactly once (asserted via
   ``profiler.recompile`` telemetry). Per-request sampling params ride
   as ``[num_slots]`` arrays — no retrace per parameter combination.
-  ``attention_kernel="legacy"`` keeps the old two-dispatch engine as
-  an explicit benchmarking fallback (`serve_bench.py
-  --attention-kernel`); its math routes through the same shared
-  attention helper, so outputs stay bitwise-equal across modes.
+  The page pools travel through it as ONE argument
+  (``paged_cache.Pools``, donated and stored back); what a pool is —
+  two arrays, or four with int8 scales — is that module's business.
 - **Chunked prefill** (Sarathi-style piggybacking). A prompt is
   prefilled in fixed-size chunks riding the unified tick, at most
   ``prefill_chunks_per_tick`` per scheduler step, each attending over
@@ -67,8 +64,7 @@ fill:
   one mixed-row tick, not a new dispatch site. Greedy parity vs the
   f32 engine becomes a measured token-match rate (``serve_bench
   --kv-dtype``); two int8 engines still agree bitwise. ``"bf16"``
-  halves the pool with a plain cast; legacy mode keeps the model
-  dtype.
+  halves the pool with a plain cast.
 - **Speculative decoding** (``ServingConfig.spec``; serving/spec.py):
   a draft model runs ``k`` tokens ahead per slot, ONE verify/mixed
   tick scores every slot's ``(1+k)``-token row (a verify row is a
@@ -86,10 +82,9 @@ exactly — zero-tail padding is not bitwise-neutral). The unified tick
 preserves this: per-token results are independent of which other rows
 share the program (see ``gpt_ragged_apply``'s contract), and prefix
 caching preserves it too (aliased pages hold KV that is identical by
-construction), so the cached engine, the uncached engine, the legacy
-two-dispatch engine and the dense path all agree —
-tests/test_serving.py pins cached-vs-uncached-vs-legacy across
-admission orders.
+construction), so the cached engine, the uncached engine and the
+dense path all agree — tests/test_serving.py pins cached-vs-uncached
+across admission orders.
 
 Profiler signals: ``serving/queue_depth``, ``serving/active_slots``,
 ``serving/page_util``, ``serving/ttft_ms`` (histogram),
@@ -152,7 +147,7 @@ from ..profiler import events as _events
 from ..profiler import recompile as _recompile
 from ..profiler import registry as _registry
 from ..profiler import trace as _ptrace
-from .paged_cache import PagePool
+from .paged_cache import PagePool, Pools
 from .sched import SCHED_POLICIES, ChunkScheduler, SpecKController
 from .spec import SpecConfig
 
@@ -197,13 +192,10 @@ def _proc_index() -> int:
     return _detect_rank()
 
 #: attention_kernel values: the unified mixed-row tick on the XLA
-#: gather spelling (the default), the unified tick on the Pallas
-#: ragged kernel (compiles with Mosaic and matches the XLA spelling on
-#: a v5e for bf16 and int8 pools — chip_smoke.py phase 1; its speed is
-#: not measured), and the pre-unification two-dispatch
-#: engine (decode tick + separate prefill program) kept for
-#: benchmarking the dispatch collapse.
-ATTENTION_KERNELS = ("ragged-xla", "ragged-pallas", "legacy")
+#: gather spelling (the default), or on the Pallas ragged kernel
+#: (compiles with Mosaic and matches the XLA spelling on a v5e for bf16
+#: and int8 pools — chip_smoke.py phase 1; its speed is not measured).
+ATTENTION_KERNELS = ("ragged-xla", "ragged-pallas")
 
 
 @contextmanager
@@ -262,8 +254,6 @@ class ServingConfig:
     #: 'int8' quantizes pages on write with per-page per-head scales —
     #: 4x tokens per pool byte vs f32, greedy parity becomes a measured
     #: token-match rate (serve_bench --kv-dtype) instead of bitwise.
-    #: Unified tick + both ragged kernels only (legacy is the
-    #: pre-unification bench baseline and stays at the model dtype).
     kv_dtype: Optional[str] = None   # None | 'f32' | 'bf16' | 'int8'
     temperature: float = 1.0         # sampling defaults; per-request
     top_k: int = 0                   #   overrides ride submit()
@@ -271,9 +261,8 @@ class ServingConfig:
     eos_token_id: Optional[int] = None
     seed: int = 0
     attention_kernel: str = "ragged-xla"   # see ATTENTION_KERNELS
-    attention_impl: Optional[str] = None   # deprecated alias: 'xla'|'pallas'
     #: speculative decoding (serving/spec.py SpecConfig: draft model +
-    #: k). Greedy-only, unified tick only; the engine gains a second
+    #: k). The engine gains a second
     #: compiled site (the draft tick) and syncs each verify tick —
     #: acceptance decides the next tick's positions, so the deferred
     #: window cannot stay open across it (max_inflight is ignored).
@@ -335,24 +324,6 @@ class _Inflight:
 _Chunk = Tuple[int, int, int, int, int]   # (slot, rid, start, end, t0)
 
 
-def _copy_pages(kpool, vpool, src, dst):
-    """Copy-on-write: duplicate page ``src`` into ``dst`` across all
-    layers (one compiled program, pools donated)."""
-    return (kpool.at[:, dst].set(kpool[:, src]),
-            vpool.at[:, dst].set(vpool[:, src]))
-
-
-def _copy_pages_q(kpool, vpool, kscale, vscale, src, dst):
-    """COW for quantized pools: the donor page's per-head scales travel
-    with its content (dequantizing the copied int8 values needs the
-    SAME scales; the engine un-lists ``dst`` from the fresh-page reset
-    so the next tick cannot zero them)."""
-    return (kpool.at[:, dst].set(kpool[:, src]),
-            vpool.at[:, dst].set(vpool[:, src]),
-            kscale.at[:, dst].set(kscale[:, src]),
-            vscale.at[:, dst].set(vscale[:, src]))
-
-
 class ServingEngine:
     """Continuous-batching serving runtime for a dense ``GPT`` model.
 
@@ -375,29 +346,12 @@ class ServingEngine:
                 f"unknown scheduler {cfg.scheduler!r}; expected one of "
                 f"{SCHED_POLICIES}")
         kernel = cfg.attention_kernel
-        if cfg.attention_impl is not None:
-            if kernel != "ragged-xla":
-                raise ValueError(
-                    "attention_impl (deprecated) and attention_kernel "
-                    "are both set — drop attention_impl")
-            # pre-unification spelling: impl named only the attention
-            # implementation, the dispatch structure was fixed
-            kernel = {"xla": "ragged-xla",
-                      "pallas": "ragged-pallas"}.get(cfg.attention_impl)
-            if kernel is None:
-                raise ValueError(
-                    f"unknown attention impl {cfg.attention_impl!r}")
         if kernel not in ATTENTION_KERNELS:
             raise ValueError(
                 f"unknown attention kernel {kernel!r}; expected one of "
                 f"{ATTENTION_KERNELS}")
         self._spec = cfg.spec
         if self._spec is not None:
-            if kernel == "legacy":
-                raise ValueError(
-                    "speculative decoding needs the unified mixed-row "
-                    "tick; attention_kernel='legacy' has no verify row "
-                    "path")
             if getattr(self._spec, "overlap", False) and \
                     cfg.decode != "sampling":
                 raise ValueError(
@@ -406,12 +360,6 @@ class ServingEngine:
                     "has no chained draft build — use decode='sampling'")
             if self._spec.k < 1:
                 raise ValueError("spec.k must be >= 1")
-        self._legacy = kernel == "legacy"
-        if self._legacy and cfg.scheduler != "fifo":
-            raise ValueError(
-                "scheduler policies need the unified tick; "
-                "attention_kernel='legacy' is the pre-unification "
-                "bench baseline and keeps fifo chunk selection")
         self._impl = "pallas" if kernel.endswith("pallas") else "xla"
         self.attention_kernel = kernel
         # process index folded in: ids stay unique when rank-tagged
@@ -431,13 +379,6 @@ class ServingEngine:
             raise ValueError(
                 f"unknown kv_dtype {cfg.kv_dtype!r}; expected one of "
                 "None (model dtype), 'f32', 'bf16', 'int8'")
-        kv_jnp = jnp.dtype(kv_map[cfg.kv_dtype])
-        if self._legacy and kv_jnp != jnp.dtype(self._dtype):
-            raise ValueError(
-                f"kv_dtype={cfg.kv_dtype!r} needs the unified tick; "
-                "attention_kernel='legacy' is the pre-unification "
-                "bench baseline and keeps the model-dtype pool")
-        self._quantized = kv_jnp == jnp.dtype(jnp.int8)
         nh = mcfg.num_heads
         hd = mcfg.hidden_size // nh
         ps = cfg.page_size
@@ -445,7 +386,7 @@ class ServingEngine:
         num_pages = cfg.num_pages or cfg.num_slots * pages_per_slot + 1
         self.pool = PagePool(mcfg.num_layers, num_pages, ps, nh, hd,
                              cfg.num_slots, pages_per_slot,
-                             dtype=kv_jnp,
+                             dtype=kv_map[cfg.kv_dtype],
                              prefix_cache=cfg.prefix_cache)
         self.prefill_chunk = int(cfg.prefill_chunk) or 2 * ps
         if self.prefill_chunk < 1:
@@ -488,8 +429,6 @@ class ServingEngine:
         #: held requests whose first token has materialized — ready for
         #: export_held() (disaggregated prefill group, ISSUE 13)
         self._held_ready: set = set()
-        self._import_fn = None       # lazy jitted KV-import scatter
-        self._export_fn = None       # lazy jitted KV-export gather
         self.max_inflight_seen = 0
         # device state
         self._last_tok = jnp.zeros((b_slots,), jnp.int32)
@@ -503,18 +442,11 @@ class ServingEngine:
         self._base_key = jax.device_put(jax.random.PRNGKey(cfg.seed),
                                         _host_device())
         _fold_key(self._base_key, np.uint32(0))
-        # compiled programs. Unified (default): ONE mixed-row tick site
-        # serving decodes AND prefill chunks, asserted single-trace.
-        # Legacy: the pre-unification pair (decode tick + suffix-prefill
-        # chunk program), kept for the dispatch-collapse benchmark.
+        # compiled programs: ONE mixed-row tick site serving decodes
+        # AND prefill chunks, asserted single-trace (spec decoding: the
+        # verify tick, plus the draft tick's site)
         self._tick_site = _recompile.unique_site("serving.tick")
-        if self._legacy:
-            self._prefill_site = _recompile.unique_site("serving.prefill")
-            self._tick = jax.jit(self._make_legacy_tick(),
-                                 donate_argnums=(2, 3))
-            self._prefill = jax.jit(self._make_prefill_chunk(),
-                                    donate_argnums=(2, 3))
-        elif self._spec is not None:
+        if self._spec is not None:
             from .spec import DraftRunner, make_spec_tick
 
             dcfg = self._spec.draft_model.config
@@ -559,8 +491,7 @@ class ServingEngine:
             self._draft = DraftRunner(
                 self._spec.draft_model, b_slots,
                 self.pool.slot_capacity, self._spec_k,
-                self.prefill_chunk, self.pool,
-                sampling=self._spec_sampling)
+                self.prefill_chunk, self.pool)
             #: per-admission-cycle lifecycle-event latches
             self._spec_started = [False] * b_slots
             self._spec_verifying = [False] * b_slots
@@ -572,48 +503,41 @@ class ServingEngine:
                 self._zero_probs = np.zeros(
                     (b_slots, self._spec_k, mcfg.vocab_size),
                     np.float32)
-            self._tick = jax.jit(
-                make_spec_tick(mcfg, b_slots, self._spec_k,
-                               self.prefill_chunk, self._impl,
-                               self._tick_site,
-                               quantized=self._quantized,
-                               sampling=self._spec_sampling),
-                donate_argnums=(2, 3, 4, 5) if self._quantized
-                else (2, 3))
+            tick = make_spec_tick(mcfg, b_slots, self._spec_k,
+                                  self.prefill_chunk, self._impl,
+                                  self._tick_site)
         else:
-            self._tick = jax.jit(self._make_unified_tick(),
-                                 donate_argnums=(2, 3, 4, 5)
-                                 if self._quantized else (2, 3))
-        if self._quantized:
-            self._copy = jax.jit(_copy_pages_q,
-                                 donate_argnums=(0, 1, 2, 3))
-            # fixed-size fresh-page reset vector folded into every tick
-            # (paged_cache.take_fresh): sized past the worst case one
-            # scheduler step can allocate — decode growth (<= 1 page
-            # per slot), speculation growth, and the selected chunks'
-            # pages — so the eager-reset overflow path never triggers
-            # in normal operation (it stays correct if it does).
-            # +1 covers draft-page rewind churn: freed draft pages
-            # re-enter the fresh list via the allocator's on_zero hook
-            spec_extra = (self._spec_k // ps + 3) \
-                if self._spec is not None else 0
-            self._fresh_cap = (
-                b_slots * (1 + spec_extra)
-                + cfg.prefill_chunks_per_tick
-                * (self.prefill_chunk // ps + 2) + 8)
-        else:
-            self._copy = jax.jit(_copy_pages, donate_argnums=(0, 1))
+            tick = self._make_unified_tick()
+        # the pools (argument 2 of either tick) are donated and the
+        # tick's first output is stored back; so with the page-granular
+        # maintenance programs: COW copy, handoff import (export only
+        # reads). None is a hot-path dispatch site
+        self._tick = jax.jit(tick, donate_argnums=2)
+        self._copy = jax.jit(Pools.copy_page, donate_argnums=0)
+        self._import_fn = jax.jit(Pools.write_pages, donate_argnums=0)
+        self._export_fn = jax.jit(Pools.gather_pages)
+        # size of the fresh-page reset vector folded into every tick of
+        # pools with scales (paged_cache.take_fresh): past the worst
+        # case one scheduler step can allocate — decode growth (<= 1
+        # page per slot), speculation growth, and the selected chunks'
+        # pages — so the eager-reset overflow path never triggers in
+        # normal operation (it stays correct if it does). +1 covers
+        # draft-page rewind churn: freed draft pages re-enter the fresh
+        # list via the allocator's on_zero hook
+        spec_extra = (self._spec_k // ps + 3) \
+            if self._spec is not None else 0
+        self._fresh_cap = (
+            b_slots * (1 + spec_extra)
+            + cfg.prefill_chunks_per_tick
+            * (self.prefill_chunk // ps + 2) + 8)
 
     @property
     def compiled_sites(self) -> Tuple[str, ...]:
         """Recompile-telemetry site names of this engine's hot-path
-        dispatch programs — the unified engine has exactly ONE (the
+        dispatch programs — the engine has exactly ONE (the
         mixed-row tick); a spec-decoding engine has exactly TWO (the
-        draft tick + the verify/mixed tick); only the legacy mode has
-        a separate prefill program. Tests assert this, so silently
-        re-growing a dispatch site is a visible regression."""
-        if self._legacy:
-            return (self._tick_site, self._prefill_site)
+        draft tick + the verify/mixed tick). Tests assert this, so
+        silently re-growing a dispatch site is a visible regression."""
         if self._spec is not None:
             return (self._tick_site, self._draft.site)
         return (self._tick_site,)
@@ -625,27 +549,15 @@ class ServingEngine:
         _events.emit(kind, rid=rid, eng=self._eng_id, **attrs)
 
     def _pool_args(self) -> tuple:
-        """The pool's device-state args for a tick dispatch (shared by
-        the unified and spec sites). Order matters in int8 mode:
-        ``take_fresh`` runs BEFORE the scale arrays are captured —
-        its overflow path eagerly rewrites them, and capturing first
-        would dispatch the stale arrays and then clobber the reset
-        with the tick's output."""
-        if not self._quantized:
-            return (self.pool.k, self.pool.v)
+        """``(pools, fresh)`` of a tick dispatch (shared by the unified
+        and spec sites): the device state and the pages whose scales
+        the tick resets first (None without scales). Order matters:
+        ``take_fresh`` runs BEFORE ``pools`` is captured — its overflow
+        path eagerly rewrites the scales, and capturing first would
+        dispatch the stale arrays and then clobber the reset with the
+        tick's output."""
         fresh = self.pool.take_fresh(self._fresh_cap)
-        return (self.pool.k, self.pool.v, self.pool.k_scale,
-                self.pool.v_scale, fresh)
-
-    def _store_pools(self, outs: tuple) -> tuple:
-        """Store a tick's donated pool outputs back on the pool;
-        returns the remaining (per-mode) outputs."""
-        if self._quantized:
-            (self.pool.k, self.pool.v, self.pool.k_scale,
-             self.pool.v_scale) = outs[:4]
-            return outs[4:]
-        self.pool.k, self.pool.v = outs[:2]
-        return outs[2:]
+        return (self.pool.pools, fresh)
 
     def _note_avals(self, site: str, fn, args: tuple) -> None:
         """Remember a dispatch site's argument avals (shape/dtype only
@@ -803,8 +715,8 @@ class ServingEngine:
         into free slots, select up to ``prefill_chunks_per_tick``
         prompt chunks, grow pages (preempting on exhaustion), dispatch
         ONE unified tick carrying the selected chunks plus every
-        resident decode (legacy mode: the old chunk-then-tick dispatch
-        pair). Returns whether any device work was dispatched."""
+        resident decode. Returns whether any device work was
+        dispatched."""
         self._sched.on_tick()
         self._drain(self.config.max_inflight)
         # host phases of the tick about to be dispatched, on the
@@ -812,19 +724,13 @@ class ServingEngine:
         n = self._tick_no
         with _ptrace.scope("step/admit", tick=n):
             self._admit()
-        if self._legacy:
-            dispatched = self._prefill_chunks()
-            with _ptrace.scope("step/grow", tick=n):
-                self._grow_pages()
-            dispatched = self._dispatch_legacy_tick() or dispatched
-        else:
-            with _ptrace.scope("step/chunks", tick=n):
-                chunks = self._collect_chunks()
-            with _ptrace.scope("step/grow", tick=n):
-                self._grow_pages()
-            dispatched = self._dispatch_spec(chunks) \
-                if self._spec is not None \
-                else self._dispatch_unified(chunks)
+        with _ptrace.scope("step/chunks", tick=n):
+            chunks = self._collect_chunks()
+        with _ptrace.scope("step/grow", tick=n):
+            self._grow_pages()
+        dispatched = self._dispatch_spec(chunks) \
+            if self._spec is not None \
+            else self._dispatch_unified(chunks)
         reg = _registry()
         reg.gauge("serving/queue_depth").set(float(len(self._queue)))
         reg.gauge("serving/active_slots").set(
@@ -921,9 +827,10 @@ class ServingEngine:
     # ------------------------------------------------------------------
     # KV handoff (ISSUE 13, serving/disagg.py): a prefill-group engine
     # exports a held request's pages; a decode-group engine imports
-    # them. Pages move as raw pool bytes — int8 pools hand off int8
-    # values + their per-page scales, so the PR 12 byte cut applies to
-    # the transfer for free. The import writer is a jitted fixed-shape
+    # them. Pages move as raw pool bytes, keyed by the pools' field
+    # names (``Pools.arrays``) — int8 pools hand off int8 values +
+    # their per-page scales, so the PR 12 byte cut applies to the
+    # transfer for free. The import writer is a jitted fixed-shape
     # maintenance op like the COW copy (self._copy): it is NOT a
     # hot-path dispatch site, so ``compiled_sites`` is unchanged and
     # the decode group's tick keeps its decode-only fast path.
@@ -961,9 +868,10 @@ class ServingEngine:
             # int8 bytes dequantize only with their scales, and f32
             # bytes are garbage reinterpreted as int8
             "kv_dtype": str(np.dtype(self.pool.k.dtype)),
-            "k": np.asarray(self.pool.k[:, idx]),
-            "v": np.asarray(self.pool.v[:, idx]),
         }
+        content = {name: np.asarray(a[:, idx])
+                   for name, a in self.pool.pools.arrays().items()}
+        payload.update(content)
         # per-request sampling overrides travel with the request (only
         # when set — absent keys mean "decode rank's engine defaults",
         # exactly like a local submit with None overrides)
@@ -973,16 +881,11 @@ class ServingEngine:
             payload["top_k"] = int(req.top_k)
         if req.top_p is not None:
             payload["top_p"] = float(req.top_p)
-        if self._quantized:
-            payload["k_scale"] = np.asarray(self.pool.k_scale[:, idx])
-            payload["v_scale"] = np.asarray(self.pool.v_scale[:, idx])
         if req.trace_id is not None:
             # the cross-host join key rides the payload: the decode
             # rank's request (and all its events) joins this trace
             payload["trace_id"] = req.trace_id
-        nbytes = sum(payload[k].nbytes for k in
-                     ("k", "v") + (("k_scale", "v_scale")
-                                   if self._quantized else ()))
+        nbytes = sum(a.nbytes for a in content.values())
         reg = _registry()
         reg.counter("serving/handoffs_out").add(1)
         reg.counter("serving/handoff_bytes_out").add(nbytes)
@@ -1081,11 +984,10 @@ class ServingEngine:
             else payload["top_k"]
         self._topps[slot] = c.top_p if payload.get("top_p") is None \
             else payload["top_p"]
-        self._write_imported_pages(slot, payload)
+        self._write_pages(self.pool._held[slot], payload)
         self._last_tok = self._last_tok.at[slot].set(first_tok)
-        nbytes = sum(payload[k].nbytes for k in
-                     ("k", "v") + (("k_scale", "v_scale")
-                                   if self._quantized else ()))
+        nbytes = sum(payload[name].nbytes
+                     for name in self.pool.pools.arrays())
         reg = _registry()
         reg.counter("serving/handoffs_in").add(1)
         reg.counter("serving/handoff_bytes_in").add(nbytes)
@@ -1101,9 +1003,6 @@ class ServingEngine:
             self._finish(slot, rid, reason="max_new")
         return rid
 
-    def _write_imported_pages(self, slot: int, payload: dict) -> None:
-        self._write_pages(self.pool._held[slot], payload)
-
     def _write_pages(self, pages, payload: dict) -> None:
         """One fixed-shape jitted scatter (padded to ``pages_per_slot``
         with the null page, whose content is always masked and whose
@@ -1117,47 +1016,18 @@ class ServingEngine:
         n = len(pages)
         dst = np.zeros(pps, np.int32)
         dst[:n] = pages
-        shape = (pool.num_layers, pps, pool.page_size, pool.num_heads,
-                 pool.head_dim)
-        kbuf = np.zeros(shape, pool.k.dtype)
-        vbuf = np.zeros(shape, pool.v.dtype)
-        kbuf[:, :n] = payload["k"]
-        vbuf[:, :n] = payload["v"]
-        if self._import_fn is None:
-            if self._quantized:
-                def imp(kpool, vpool, kscale, vscale, kp, vp, ks, vs,
-                        d):
-                    return (kpool.at[:, d].set(kp),
-                            vpool.at[:, d].set(vp),
-                            kscale.at[:, d].set(ks),
-                            vscale.at[:, d].set(vs))
-
-                self._import_fn = jax.jit(imp,
-                                          donate_argnums=(0, 1, 2, 3))
-            else:
-                def imp(kpool, vpool, kp, vp, d):
-                    return (kpool.at[:, d].set(kp),
-                            vpool.at[:, d].set(vp))
-
-                self._import_fn = jax.jit(imp, donate_argnums=(0, 1))
+        content = {}
+        for name, a in pool.pools.arrays().items():
+            buf = np.zeros((a.shape[0], pps) + a.shape[2:], a.dtype)
+            buf[:, :n] = payload[name]
+            content[name] = buf
         with _quiet_donation():
-            if self._quantized:
-                sshape = (pool.num_layers, pps, pool.num_heads)
-                ksbuf = np.zeros(sshape, np.float32)
-                vsbuf = np.zeros(sshape, np.float32)
-                ksbuf[:, :n] = payload["k_scale"]
-                vsbuf[:, :n] = payload["v_scale"]
-                (pool.k, pool.v, pool.k_scale, pool.v_scale) = \
-                    self._import_fn(pool.k, pool.v, pool.k_scale,
-                                    pool.v_scale, kbuf, vbuf, ksbuf,
-                                    vsbuf, dst)
-                # the scale rows were just written by the import — the
-                # next tick's fresh-page reset must not zero them
-                for pg in pages:
-                    pool.claim_fresh(int(pg))
-            else:
-                pool.k, pool.v = self._import_fn(pool.k, pool.v, kbuf,
-                                                 vbuf, dst)
+            pool.pools = self._import_fn(pool.pools, dst,
+                                         Pools(**content))
+        # scale rows the import just wrote: the next tick's fresh-page
+        # reset must not zero them
+        for pg in pages:
+            pool.claim_fresh(int(pg))
 
     # ------------------------------------------------------------------
     # hot prefix-chain migration (ISSUE 18). Host-side policy on the
@@ -1191,29 +1061,13 @@ class ServingEngine:
         # migration never pays a compile (the mirror of _write_pages)
         src = np.zeros(pool.pages_per_slot, np.int32)
         src[:n] = pages
-        if self._export_fn is None:
-            if self._quantized:
-                def gat(kp, vp, ks, vs, s):
-                    return kp[:, s], vp[:, s], ks[:, s], vs[:, s]
-            else:
-                def gat(kp, vp, s):
-                    return kp[:, s], vp[:, s]
-            self._export_fn = jax.jit(gat)
         payload = {
             "tokens": toks[:n_tok],
             "n_tokens": n_tok,
             "kv_dtype": str(np.dtype(pool.k.dtype)),
         }
-        if self._quantized:
-            k, v, ks, vs = self._export_fn(pool.k, pool.v,
-                                           pool.k_scale, pool.v_scale,
-                                           src)
-            payload["k_scale"] = np.asarray(ks)[:, :n]
-            payload["v_scale"] = np.asarray(vs)[:, :n]
-        else:
-            k, v = self._export_fn(pool.k, pool.v, src)
-        payload["k"] = np.asarray(k)[:, :n]
-        payload["v"] = np.asarray(v)[:, :n]
+        for name, a in self._export_fn(pool.pools, src).arrays().items():
+            payload[name] = np.asarray(a)[:, :n]
         return payload
 
     def import_prefix_chain(self, payload: dict) -> int:
@@ -1243,8 +1097,9 @@ class ServingEngine:
                 n_pages * ps != toks.shape[0] or \
                 payload["k"].shape != payload["v"].shape:
             raise ValueError("inconsistent migrated chain payload")
-        if self._quantized and "k_scale" not in payload:
-            raise ValueError("quantized chain without scales")
+        missing = [f for f in pool.pools.arrays() if f not in payload]
+        if missing:
+            raise ValueError(f"migrated chain without {missing}")
         # plain free-list alloc, deliberately NOT pool._alloc: a
         # speculative import must never evict committed local cache
         # entries to make room for itself
@@ -1408,7 +1263,7 @@ class ServingEngine:
             self._topps[slot] = c.top_p if req.top_p is None else req.top_p
 
     # ------------------------------------------------------------------
-    # chunk selection + prefix cache (shared by both engine modes)
+    # chunk selection + prefix cache
     # ------------------------------------------------------------------
     def _next_prefill_slot(self, pend: Dict[int, int]) -> Optional[int]:
         """The slot that opens the next prefill chunk, per the
@@ -1462,22 +1317,11 @@ class ServingEngine:
                     dst = self.pool.tables[slot,
                                            self.pool.slot_pages(slot) - 1]
                     with _quiet_donation():
-                        if self._quantized:
-                            # scales travel with the page; un-list dst
-                            # from the fresh reset or the next tick
-                            # would zero the copied scales
-                            (self.pool.k, self.pool.v,
-                             self.pool.k_scale, self.pool.v_scale) = \
-                                self._copy(
-                                    self.pool.k, self.pool.v,
-                                    self.pool.k_scale,
-                                    self.pool.v_scale,
-                                    np.int32(src), np.int32(dst))
-                            self.pool.claim_fresh(int(dst))
-                        else:
-                            self.pool.k, self.pool.v = self._copy(
-                                self.pool.k, self.pool.v,
-                                np.int32(src), np.int32(dst))
+                        self.pool.pools = self._copy(
+                            self.pool.pools, np.int32(src), np.int32(dst))
+                    # scales travel with the page; un-list dst from the
+                    # fresh reset or the next tick would zero them
+                    self.pool.claim_fresh(int(dst))
                     hit += lcp
                     _registry().counter("cache_share/cow_copies").add(1)
                     self._emit("cow_copy", req.rid, slot=slot, tokens=lcp)
@@ -1718,7 +1562,7 @@ class ServingEngine:
     def _dispatch_unified(self, chunks: List[_Chunk]) -> bool:
         """Assemble and dispatch the mixed-row tick: one decode row per
         slot (inactive slots write to the null page through their
-        zeroed table rows, exactly like the pre-unification tick) plus
+        zeroed table rows) plus
         one ``prefill_chunk``-token row block per selected chunk. A
         chunk whose slot lost its request between selection and here
         (decode growth preempted it) is dropped — its acquired pages
@@ -1732,7 +1576,7 @@ class ServingEngine:
         self._note_avals(self._tick_site, self._tick, args)
         with _ptrace.scope("step/dispatch", tick=self._tick_no), \
                 _quiet_donation():
-            tok, self._last_tok = self._store_pools(self._tick(*args))
+            self.pool.pools, tok, self._last_tok = self._tick(*args)
         meta = [(s, s, self._slot_rid[s]) for s in ticking]
         meta += [(s, s, rid) for s, rid in finishers]
         if meta:
@@ -1842,30 +1686,25 @@ class ServingEngine:
         impl = self._impl
         ns = self.config.num_slots
         w = self.prefill_chunk
-        quantized = self._quantized
 
         from ..models.gpt import gpt_ragged_apply
 
-        def core(stacked, other, pools, last_tok, pf_toks, tok_pos,
-                 tok_limit, row_tab, row_pos0, row_len, sample_ix,
-                 has_chunks):
+        def tick(stacked, other, pools, fresh, last_tok, pf_toks,
+                 tok_pos, tok_limit, row_tab, row_pos0, row_len,
+                 sample_ix, sample_pos, emit, has_chunks, keys, temps,
+                 top_ks, top_ps):
+            _recompile.mark_trace(site, pools.k, row_tab, tok_pos,
+                                  last_tok)
+            # recycled pages restart their running-max scale at 0
+            # (fresh pads with the null page, whose scale is 0)
+            pools = pools.reset_scales(fresh)
             tokens = jnp.concatenate([last_tok, pf_toks])
 
             def run(pl_, toks_, pos_, lim_, tab_, p0_, len_):
-                if quantized:
-                    kp, vp, ks, vs = pl_
-                    lg, kp, vp, ks, vs = gpt_ragged_apply(
-                        mcfg, stacked, other, kp, vp, toks_, pos_,
-                        lim_, tab_, p0_, len_, sample_ix,
-                        decode_rows=ns, chunk_width=w, impl=impl,
-                        kscale=ks, vscale=vs)
-                    return lg, (kp, vp, ks, vs)
-                kp, vp = pl_
-                lg, kp, vp = gpt_ragged_apply(
-                    mcfg, stacked, other, kp, vp, toks_, pos_, lim_,
-                    tab_, p0_, len_, sample_ix, decode_rows=ns,
-                    chunk_width=w, impl=impl)
-                return lg, (kp, vp)
+                return gpt_ragged_apply(
+                    mcfg, stacked, other, pl_, toks_, pos_, lim_, tab_,
+                    p0_, len_, sample_ix, decode_rows=ns, chunk_width=w,
+                    impl=impl)
 
             # ONE program, data-dependent prefill piggyback: both
             # branches trace into this single executable (the site
@@ -1876,55 +1715,21 @@ class ServingEngine:
             # every tick, which on the XLA path is real FLOPs, not
             # skipped blocks.
             def mixed(pl_):
-                lg, pl_ = run(pl_, tokens, tok_pos, tok_limit,
-                              row_tab, row_pos0, row_len)
-                return (lg,) + pl_
+                return run(pl_, tokens, tok_pos, tok_limit, row_tab,
+                           row_pos0, row_len)
 
             def decode_only(pl_):
-                lg, pl_ = run(pl_, tokens[:ns], tok_pos[:ns],
-                              tok_limit[:ns], row_tab[:ns],
-                              row_pos0[:ns], row_len[:ns])
-                return (lg,) + pl_
+                return run(pl_, tokens[:ns], tok_pos[:ns],
+                           tok_limit[:ns], row_tab[:ns], row_pos0[:ns],
+                           row_len[:ns])
 
-            out = jax.lax.cond(has_chunks, mixed, decode_only, pools)
-            return out[0], out[1:]
-
-        if quantized:
-            def tick(stacked, other, kpool, vpool, kscale, vscale,
-                     fresh, last_tok, pf_toks, tok_pos, tok_limit,
-                     row_tab, row_pos0, row_len, sample_ix, sample_pos,
-                     emit, has_chunks, keys, temps, top_ks, top_ps):
-                _recompile.mark_trace(site, kpool, row_tab, tok_pos,
-                                      last_tok)
-                # recycled pages restart their running-max scale at 0
-                # (fresh pads with the null page, whose scale is 0)
-                kscale = kscale.at[:, fresh].set(0.0)
-                vscale = vscale.at[:, fresh].set(0.0)
-                logits, (kpool, vpool, kscale, vscale) = core(
-                    stacked, other, (kpool, vpool, kscale, vscale),
-                    last_tok, pf_toks, tok_pos, tok_limit, row_tab,
-                    row_pos0, row_len, sample_ix, has_chunks)
-                with _ptrace.annotate("tick/sample"):
-                    nxt = self._sample_tok(logits, keys, sample_pos,
-                                           temps, top_ks, top_ps)
-                    new_last = jnp.where(emit, nxt, last_tok)
-                return kpool, vpool, kscale, vscale, nxt, new_last
-        else:
-            def tick(stacked, other, kpool, vpool, last_tok, pf_toks,
-                     tok_pos, tok_limit, row_tab, row_pos0, row_len,
-                     sample_ix, sample_pos, emit, has_chunks, keys,
-                     temps, top_ks, top_ps):
-                _recompile.mark_trace(site, kpool, row_tab, tok_pos,
-                                      last_tok)
-                logits, (kpool, vpool) = core(
-                    stacked, other, (kpool, vpool), last_tok, pf_toks,
-                    tok_pos, tok_limit, row_tab, row_pos0, row_len,
-                    sample_ix, has_chunks)
-                with _ptrace.annotate("tick/sample"):
-                    nxt = self._sample_tok(logits, keys, sample_pos,
-                                           temps, top_ks, top_ps)
-                    new_last = jnp.where(emit, nxt, last_tok)
-                return kpool, vpool, nxt, new_last
+            logits, pools = jax.lax.cond(has_chunks, mixed, decode_only,
+                                         pools)
+            with _ptrace.annotate("tick/sample"):
+                nxt = self._sample_tok(logits, keys, sample_pos, temps,
+                                       top_ks, top_ps)
+                new_last = jnp.where(emit, nxt, last_tok)
+            return pools, nxt, new_last
 
         return tick
 
@@ -2052,31 +1857,23 @@ class ServingEngine:
         drafts = dprobs = None
         if any_feed or gen_slots:
             dtab = np.ascontiguousarray(dr.aux.tables)
+            zi = np.zeros(ns, np.int32)
+            # no chain rides a catch-up tick: a zero chain, all masked
+            dsample = (np.ascontiguousarray(self._keys),
+                       np.ascontiguousarray(self._temps),
+                       np.ascontiguousarray(self._topks),
+                       np.ascontiguousarray(self._topps),
+                       np.zeros((ns, 1 + k), np.int32), zi, zi,
+                       np.zeros(ns, bool)) if sampling else None
+            dargs = (dr.stacked, dr.other, dr.kc, dr.vc, dtab,
+                     feed_toks, feed_pos0, feed_len, gen_tok, gen_pos,
+                     dsample, np.bool_(any_feed),
+                     np.bool_(len(gen_slots) > 0))
+            self._note_avals(dr.site, dr.tick, dargs)
+            with _quiet_donation():
+                dr.kc, dr.vc, drafts, *probs = dr.tick(*dargs)
             if sampling:
-                zc = np.zeros((ns, 1 + k), np.int32)
-                zi = np.zeros(ns, np.int32)
-                dargs = (dr.stacked, dr.other, dr.kc, dr.vc, dtab,
-                         feed_toks, feed_pos0, feed_len, gen_tok,
-                         gen_pos,
-                         np.ascontiguousarray(self._keys),
-                         np.ascontiguousarray(self._temps),
-                         np.ascontiguousarray(self._topks),
-                         np.ascontiguousarray(self._topps),
-                         zc, zi, zi, np.zeros(ns, bool),
-                         np.bool_(any_feed),
-                         np.bool_(len(gen_slots) > 0))
-                self._note_avals(dr.site, dr.tick, dargs)
-                with _quiet_donation():
-                    dr.kc, dr.vc, drafts, dprobs = dr.tick(*dargs)
-                dprobs_m = dprobs
-            else:
-                dargs = (dr.stacked, dr.other, dr.kc, dr.vc, dtab,
-                         feed_toks, feed_pos0, feed_len, gen_tok,
-                         gen_pos, np.bool_(any_feed),
-                         np.bool_(len(gen_slots) > 0))
-                self._note_avals(dr.site, dr.tick, dargs)
-                with _quiet_donation():
-                    dr.kc, dr.vc, drafts = dr.tick(*dargs)
+                dprobs = dprobs_m = probs[0]
             draft_flat = drafts.reshape(-1)
             dr.len += feed_len
             reg.counter("serving/spec_draft_ticks").add(1)
@@ -2173,25 +1970,19 @@ class ServingEngine:
                 finishers.append((s, rid))
                 sample[s, 0] = coff + (t0 - 1 - start)
                 sample_pos[s] = t0
-        if sampling:
-            tail = (last_tok, draft_flat, pf_toks, tok_pos, tok_limit,
-                    row_tab, row_pos0, row_len, sample.reshape(-1),
-                    k_arr,
-                    np.ascontiguousarray(self._keys), sample_pos,
-                    np.ascontiguousarray(self._temps),
-                    np.ascontiguousarray(self._topks),
-                    np.ascontiguousarray(self._topps), dprobs_m,
-                    np.bool_(len(chunks) > 0), np.bool_(has_drafts))
-        else:
-            tail = (last_tok, draft_flat, pf_toks, tok_pos, tok_limit,
-                    row_tab, row_pos0, row_len, sample.reshape(-1),
-                    k_arr,
-                    np.bool_(len(chunks) > 0), np.bool_(has_drafts))
+        vsample = (np.ascontiguousarray(self._keys), sample_pos,
+                   np.ascontiguousarray(self._temps),
+                   np.ascontiguousarray(self._topks),
+                   np.ascontiguousarray(self._topps),
+                   dprobs_m) if sampling else None
+        tail = (last_tok, draft_flat, pf_toks, tok_pos, tok_limit,
+                row_tab, row_pos0, row_len, sample.reshape(-1), k_arr,
+                vsample, np.bool_(len(chunks) > 0), np.bool_(has_drafts))
         args = (self._stacked, self._other) + self._pool_args() + tail
         self._note_avals(self._tick_site, self._tick, args)
         dispatch_t = time.perf_counter()
         with _quiet_donation():
-            tok_m, acc = self._store_pools(self._tick(*args))
+            self.pool.pools, tok_m, acc = self._tick(*args)
 
         # ---- overlap: chain draft tick N+1 on the un-materialized
         # verify outputs, BEFORE the host sync below — the sync then
@@ -2221,11 +2012,11 @@ class ServingEngine:
                 dargs2 = (dr.stacked, dr.other, dr.kc, dr.vc, dtab2,
                           np.zeros((ns, w), np.int32), zi2, zi2, zi2,
                           np.full(ns, cap, np.int32),
-                          np.ascontiguousarray(self._keys),
-                          np.ascontiguousarray(self._temps),
-                          np.ascontiguousarray(self._topks),
-                          np.ascontiguousarray(self._topps),
-                          tok_m, acc, ch_pos0, cm2,
+                          (np.ascontiguousarray(self._keys),
+                           np.ascontiguousarray(self._temps),
+                           np.ascontiguousarray(self._topks),
+                           np.ascontiguousarray(self._topps),
+                           tok_m, acc, ch_pos0, cm2),
                           np.bool_(False), np.bool_(True))
                 self._note_avals(dr.site, dr.tick, dargs2)
                 with _quiet_donation():
@@ -2366,91 +2157,6 @@ class ServingEngine:
         return True
 
     # ------------------------------------------------------------------
-    # legacy two-dispatch mode (attention_kernel="legacy"): the
-    # pre-unification engine — a dedicated decode tick plus a separate
-    # suffix-prefill program alternating on the hot path. Kept ONLY so
-    # serve_bench.py can measure what the dispatch collapse buys;
-    # outputs are bitwise-equal to the unified tick (same shared
-    # attention spelling underneath).
-    # ------------------------------------------------------------------
-    def _prefill_chunks(self) -> bool:
-        """Advance prefilling slots by up to ``prefill_chunks_per_tick``
-        immediately-dispatched chunks, oldest admission first."""
-        any_dispatch = False
-        for _ in range(self.config.prefill_chunks_per_tick):
-            s = self._next_prefill_slot({})
-            if s is None:
-                break
-            chunk = self._open_chunk(s, {})
-            if chunk is None:
-                break
-            self._dispatch_prefill_chunk(*chunk)
-            any_dispatch = True
-        return any_dispatch
-
-    def _dispatch_prefill_chunk(self, s: int, rid: int, start: int,
-                                end: int, t0: int) -> None:
-        req = self._requests[rid]
-        chunk = self.prefill_chunk
-        toks = np.zeros((1, chunk), np.int32)
-        toks[0, :end - start] = req.prompt[start:end]
-        # copies, as in ``_dispatch_legacy_tick``
-        page_row = self.pool.tables[s].copy()
-        args = (self._stacked, self._other, self.pool.k, self.pool.v,
-                toks, np.int32(start), np.int32(t0), page_row, req.key,
-                self._temps[s:s + 1].copy(), self._topks[s:s + 1].copy(),
-                self._topps[s:s + 1].copy())
-        self._note_avals(self._prefill_site, self._prefill, args)
-        with _quiet_donation():
-            self.pool.k, self.pool.v, tok0 = self._prefill(*args)
-        _registry().counter("serving/prefill_chunks").add(1)
-        self._emit("chunk", rid, slot=s, start=start, end=end,
-                   final=bool(end >= t0))
-        if end >= t0:                # final chunk: tok0 is real
-            self._last_tok = self._last_tok.at[s].set(tok0[0])
-            self._inflight.append(_Inflight(tok0, [(0, s, req.rid)],
-                                            self._tick_no))
-            self.max_inflight_seen = max(self.max_inflight_seen,
-                                         len(self._inflight))
-            self._slot_dispatched[s] = 1
-            self._slot_len[s] = t0
-            _registry().counter("serving/prefills").add(1)
-        else:
-            self._slot_len[s] = end
-        # publish the pages this chunk completed (progressively: a long
-        # shared prompt becomes hittable page-by-page, mid-prefill)
-        self._insert_prefix(s, req.prompt, int(self._slot_len[s]))
-
-    def _dispatch_legacy_tick(self) -> bool:
-        ticking = self._ticking_slots()
-        if not ticking:
-            return False
-        # copies: the CPU backend may read a numpy argument in place, after
-        # this method has moved on and written the next tick's state there
-        args = (self._stacked, self._other, self.pool.k, self.pool.v,
-                self.pool.tables.copy(), self._slot_len.copy(),
-                self._last_tok, self._keys.copy(), self._temps.copy(),
-                self._topks.copy(), self._topps.copy())
-        self._note_avals(self._tick_site, self._tick, args)
-        with _quiet_donation():
-            self.pool.k, self.pool.v, tok = self._tick(*args)
-        self._last_tok = tok
-        meta = [(s, s, self._slot_rid[s]) for s in ticking]
-        self._inflight.append(_Inflight(tok, meta, self._tick_no))
-        self._tick_no += 1
-        self.max_inflight_seen = max(self.max_inflight_seen,
-                                     len(self._inflight))
-        for s in ticking:
-            self._slot_len[s] += 1
-            self._slot_dispatched[s] += 1
-        _registry().counter("serving/ticks").add(1)
-        reg = _registry()
-        reg.gauge("serving/mixed_rows").set(float(len(ticking)))
-        reg.gauge("serving/mixed_rows_decode").set(float(len(ticking)))
-        reg.gauge("serving/mixed_rows_prefill").set(0.0)
-        return True
-
-    # ------------------------------------------------------------------
     # compiled program bodies
     # ------------------------------------------------------------------
     def _sample_tok(self, logits, keys, positions, temps, top_ks, top_ps):
@@ -2474,91 +2180,3 @@ class ServingEngine:
             return jax.random.categorical(jax.random.fold_in(key, pos), row)
 
         return jax.vmap(one)(keys, positions, lp).astype(jnp.int32)
-
-    def _make_legacy_tick(self):
-        mcfg = self.model_config
-        ps = self.pool.page_size
-        nh = mcfg.num_heads
-        hd = mcfg.hidden_size // nh
-        eps = mcfg.layer_norm_eps
-        nslots = self.config.num_slots
-        impl = self._impl
-        site = self._tick_site
-
-        from ..models.gpt import _ln, gpt_block_body
-        from ..ops.paged_attention import paged_decode_attention
-
-        nps = self.pool.pages_per_slot
-        cap = nps * ps
-
-        def tick(stacked, other, kpool, vpool, tab, pos, tok, keys,
-                 temps, top_ks, top_ps):
-            _recompile.mark_trace(site, kpool, tab, pos, tok)
-            wte = other["embeddings.wte.weight"]
-            wpe = other["embeddings.wpe.weight"]
-            x = wte[tok[:, None]] + wpe[pos[:, None]]        # [B, 1, h]
-            # a slot that finished at EXACT capacity keeps riding the
-            # fixed-shape tick until its tokens drain, with pos == cap;
-            # clamping that gather would silently stomp the slot's LAST
-            # page (absolute position cap - page_size) — which _finish
-            # is about to publish into the prefix index. Route every
-            # out-of-range write to the null page instead, like the
-            # prefill pad path.
-            page = jnp.where(
-                pos < cap,
-                tab[jnp.arange(nslots), jnp.minimum(pos // ps, nps - 1)],
-                0)
-            off = pos % ps
-
-            def block(xc, inp):
-                p, kpl0, vpl0 = inp
-
-                def attend(q, kk, vv):
-                    kpl = kpl0.at[page, off].set(kk[:, 0])
-                    vpl = vpl0.at[page, off].set(vv[:, 0])
-                    o = paged_decode_attention(q, kpl, vpl, tab, pos,
-                                               impl=impl)
-                    return o, (kpl, vpl)
-
-                return gpt_block_body(xc, p, eps, nh, hd, attend)
-
-            x, (kpool, vpool) = jax.lax.scan(
-                block, x, (stacked, kpool, vpool))
-            x = _ln(x, other["ln_f.weight"], other["ln_f.bias"], eps)
-            last = x[:, -1]
-            if "lm_head.weight" in other:
-                logits = last @ other["lm_head.weight"]
-            else:
-                logits = last @ wte.T
-            nxt = self._sample_tok(logits, keys, pos + 1, temps,
-                                   top_ks, top_ps)
-            return kpool, vpool, nxt
-
-        return tick
-
-    def _make_prefill_chunk(self):
-        """Legacy mode's second compiled program: one fixed-shape
-        suffix-prefill over ``gpt_paged_suffix_apply`` (itself now a
-        delegation into the unified ragged forward). The chunk start /
-        true prompt length ride as traced scalars, so every chunk of
-        every prompt shares this one compiled program. The sampled
-        token is only meaningful on the final chunk (the host ignores
-        it otherwise)."""
-        mcfg = self.model_config
-        site = self._prefill_site
-        chunk = self.prefill_chunk
-
-        from ..models.gpt import gpt_paged_suffix_apply
-
-        def prefill(stacked, other, kpool, vpool, tokens, pos0, true_len,
-                    page_row, key, temp, top_k, top_p):
-            _recompile.mark_trace(site, tokens, kpool, pos0)
-            li = jnp.clip(true_len - 1 - pos0, 0, chunk - 1)
-            logits, kpool, vpool = gpt_paged_suffix_apply(
-                mcfg, stacked, other, kpool, vpool, tokens, pos0,
-                true_len, page_row, li)
-            tok0 = self._sample_tok(logits, key[None], true_len[None],
-                                    temp, top_k, top_p)
-            return kpool, vpool, tok0
-
-        return prefill

@@ -10,10 +10,14 @@ immediately — the allocation granularity that makes continuous
 batching admission-feasible mid-flight ("Ragged Paged Attention",
 PAPERS.md).
 
-Device state (``PagePool``): per-layer key/value pools stacked
-``[L, num_pages, page_size, NH, D]``. One page id addresses the same
-page row in every layer, so the allocator hands out a single id per
-page regardless of depth.
+Device state (``Pools``, held by ``PagePool.pools``): per-layer
+key/value pools stacked ``[L, num_pages, page_size, NH, D]``, plus
+per-page per-head dequant scales ``[L, num_pages, NH]`` when the pools
+are int8. One page id addresses the same page row in every layer, so
+the allocator hands out a single id per page regardless of depth.
+``Pools`` is the ONE place that knows this format: the tick, the spec
+verify tick, the COW copy and the KV handoff pass it whole and reach
+its content through its methods.
 
 Host state (``PageAllocator``): a LIFO free list over ids
 ``1..num_pages-1`` with a **refcount per allocated page**. ``alloc``
@@ -49,11 +53,14 @@ from __future__ import annotations
 
 import hashlib
 import heapq
-from typing import Callable, Dict, List, Optional, Tuple
+from typing import Callable, Dict, List, NamedTuple, Optional, Tuple
 
 import numpy as np
 
+import jax
 import jax.numpy as jnp
+
+from ..ops.paged_attention import paged_kv_scatter, ragged_paged_attention
 
 NULL_PAGE = 0
 
@@ -441,6 +448,98 @@ class PrefixCache:
         return dropped
 
 
+class Pools(NamedTuple):
+    """The page pools as the device holds them, and the only code that
+    knows their format. A pytree: a program takes and returns it as ONE
+    argument, and a ``None`` leaf contributes no parameter, so the
+    un-quantized tick's parameters are ``k`` and ``v`` alone.
+
+    k, v              ``[L, P, ps, NH, D]`` page pools (f32/bf16/int8)
+    k_scale, v_scale  ``[L, P, NH]`` f32 per-page per-head dequant
+                      scales of int8 pools (ISSUE 12); ``None`` otherwise
+
+    Every array indexes layers on axis 0 and pages on axis 1, so the
+    page-granular programs (copy, gather, write) are one ``tree.map``
+    and ``lax.scan`` hands a block the same tuple one layer down, where
+    ``scatter`` and ``attend`` are the cache's write and read sides.
+    Page 0 (null) keeps scale 0 forever (masked contributions). Page
+    CONTENT is deliberately never cleared on free (LIFO dirty reuse is
+    a feature), but a recycled page's STALE SCALE would poison the
+    running-max of its next tenant: ``PagePool`` lists fresh pages and
+    the tick resets them first (``reset_scales``)."""
+
+    k: jax.Array
+    v: jax.Array
+    k_scale: Optional[jax.Array] = None
+    v_scale: Optional[jax.Array] = None
+
+    @classmethod
+    def zeros(cls, num_layers: int, num_pages: int, page_size: int,
+              num_heads: int, head_dim: int, dtype) -> "Pools":
+        shape = (num_layers, num_pages, page_size, num_heads, head_dim)
+        pools = cls(jnp.zeros(shape, dtype), jnp.zeros(shape, dtype))
+        if jnp.dtype(dtype) != jnp.int8:
+            return pools
+        sshape = (num_layers, num_pages, num_heads)
+        return pools._replace(k_scale=jnp.zeros(sshape, jnp.float32),
+                              v_scale=jnp.zeros(sshape, jnp.float32))
+
+    @property
+    def quantized(self) -> bool:
+        return self.k_scale is not None
+
+    @property
+    def page_size(self) -> int:
+        return self.k.shape[-3]
+
+    def arrays(self) -> Dict[str, jax.Array]:
+        """The arrays present, by field name (the KV handoff's keys)."""
+        return {n: a for n, a in self._asdict().items() if a is not None}
+
+    def reset_scales(self, pages) -> "Pools":
+        """Restart the running-max scale of ``pages`` at 0 (pads with
+        the null page, whose scale is 0 anyway). The identity without
+        scales, or with ``pages`` None."""
+        if pages is None or not self.quantized:
+            return self
+        return self._replace(k_scale=self.k_scale.at[:, pages].set(0.0),
+                             v_scale=self.v_scale.at[:, pages].set(0.0))
+
+    def copy_page(self, src, dst) -> "Pools":
+        """Copy-on-write: duplicate page ``src`` into ``dst`` across all
+        layers. The donor's scales travel with its content (dequantizing
+        the copied int8 values needs the SAME scales; the engine
+        un-lists ``dst`` from the fresh-page reset so the next tick
+        cannot zero them)."""
+        return jax.tree.map(lambda a: a.at[:, dst].set(a[:, src]), self)
+
+    def gather_pages(self, pages) -> "Pools":
+        """Pages ``pages`` of every layer: the export side of a handoff."""
+        return jax.tree.map(lambda a: a[:, pages], self)
+
+    def write_pages(self, pages, content: "Pools") -> "Pools":
+        """``content`` (as ``gather_pages`` gave it) written at ``pages``."""
+        return jax.tree.map(lambda a, c: a.at[:, pages].set(c), self,
+                            content)
+
+    # -- one layer's slice, inside the tick's scan over layers ---------
+    def scatter(self, page, off, kk, vv) -> "Pools":
+        """Write each token's key and value (``kk``/``vv`` [NT, 1, NH,
+        D], the block's flat one-position rows) at its ``(page, off)``,
+        quantizing on write where there are scales."""
+        k, k_scale = paged_kv_scatter(self.k, self.k_scale, page, off,
+                                      kk[:, 0])
+        v, v_scale = paged_kv_scatter(self.v, self.v_scale, page, off,
+                                      vv[:, 0])
+        return Pools(k, v, k_scale, v_scale)
+
+    def attend(self, q, page_table, pos0, true_len, impl: str = "xla"):
+        """``ragged_paged_attention`` of ``q`` over this layer's pages."""
+        return ragged_paged_attention(
+            q, self.k, self.v, page_table, pos0, true_len, impl=impl,
+            k_scale=self.k_scale, v_scale=self.v_scale)
+
+
 class PagePool:
     """Device page pools for all layers + host page tables for all slots."""
 
@@ -455,27 +554,14 @@ class PagePool:
         self.head_dim = head_dim
         self.num_slots = num_slots
         self.pages_per_slot = pages_per_slot
-        shape = (num_layers, num_pages, page_size, num_heads, head_dim)
-        self.k = jnp.zeros(shape, dtype)
-        self.v = jnp.zeros(shape, dtype)
-        # int8 pools (ISSUE 12): per-page per-head dequant scales ride
-        # as device state next to the pools — quantize-on-write updates
-        # them inside the tick (ops/paged_attention.paged_kv_scatter),
-        # so they are donated/returned per dispatch exactly like k/v.
-        # Page 0 (null) keeps scale 0 forever (masked contributions).
-        # Page CONTENT is deliberately never cleared on free (LIFO
-        # dirty reuse is a feature), but a recycled page's STALE SCALE
-        # would poison the running-max of its next tenant — so fresh
-        # allocations are tracked host-side and the engine folds a
-        # scale reset for them into the next tick's arguments.
-        self.quantized = jnp.dtype(dtype) == jnp.int8
+        #: the device state, donated to and stored back from every
+        #: dispatch that writes it (tick, COW copy, import)
+        self.pools = Pools.zeros(num_layers, num_pages, page_size,
+                                 num_heads, head_dim, dtype)
         self.allocator = PageAllocator(num_pages)
-        if self.quantized:
-            self.k_scale = jnp.zeros((num_layers, num_pages, num_heads),
-                                     jnp.float32)
-            self.v_scale = jnp.zeros((num_layers, num_pages, num_heads),
-                                     jnp.float32)
-            self._fresh: List[int] = []
+        # pages allocated or zero-freed since the last tick, whose
+        # scales that tick resets (``take_fresh``); scales only
+        self._fresh: List[int] = []
         self.allocator.on_zero = self._on_zero_free
         # pages that arrived via cross-rank chain migration (ISSUE 18):
         # host-side provenance so a prefix hit on one can be counted as
@@ -493,6 +579,13 @@ class PagePool:
         # the SAME allocator: registered so check_consistency can
         # account for their holds (ISSUE 20)
         self._aux: List["AuxPageTable"] = []
+
+    # read-only views of the device state; a writer replaces ``pools``
+    k = property(lambda self: self.pools.k)
+    v = property(lambda self: self.pools.v)
+    k_scale = property(lambda self: self.pools.k_scale)
+    v_scale = property(lambda self: self.pools.v_scale)
+    quantized = property(lambda self: self.pools.quantized)
 
     def register_aux(self, aux: "AuxPageTable") -> None:
         """Register an auxiliary table whose pages come from this
@@ -536,30 +629,25 @@ class PagePool:
         return got
 
     # -- int8 scale lifecycle (quantized pools only) -------------------
-    def take_fresh(self, cap: int) -> np.ndarray:
+    def take_fresh(self, cap: int) -> Optional[np.ndarray]:
         """Drain the freshly-allocated-page list into a fixed-size
         int32 vector (padded with the null page, whose scale is 0
-        anyway) for the next tick's in-program scale reset. Allocations
+        anyway) for the next tick's in-program scale reset; None where
+        there are no scales to reset. Allocations
         beyond ``cap`` — which a correctly-sized cap never produces —
         are reset eagerly here instead of silently dropped (a dropped
         reset would leave a stale running-max scale on a recycled
         page)."""
+        if not self.quantized:
+            return None
         fresh, self._fresh = self._fresh, []
         if len(fresh) > cap:
-            self.reset_scales(fresh[cap:])
+            self.pools = self.pools.reset_scales(
+                np.asarray(fresh[cap:], np.int32))
             fresh = fresh[:cap]
         out = np.zeros(cap, np.int32)
         out[:len(fresh)] = fresh
         return out
-
-    def reset_scales(self, pages) -> None:
-        """Eagerly zero the scale rows of ``pages`` (rare overflow path
-        of :meth:`take_fresh`; the hot path resets inside the tick)."""
-        idx = np.asarray(list(pages), np.int32)
-        if idx.size == 0:
-            return
-        self.k_scale = self.k_scale.at[:, idx].set(0.0)
-        self.v_scale = self.v_scale.at[:, idx].set(0.0)
 
     def claim_fresh(self, page: int) -> None:
         """Remove ``page`` from the pending-reset list — its scale was

@@ -143,13 +143,12 @@ class DraftRunner:
     this class owns the state and the compiled program."""
 
     def __init__(self, draft_model, num_slots: int, capacity: int,
-                 k: int, feed_width: int, pool, sampling: bool = False):
+                 k: int, feed_width: int, pool):
         cfg = draft_model.config
         self.config = cfg
         self.k = int(k)
         self.capacity = int(capacity)
         self.feed_width = int(feed_width)
-        self.sampling = bool(sampling)
         self.pool = pool
         self.aux = AuxPageTable(pool, num_slots)
         self.stacked, self.other = draft_model._decode_state()
@@ -164,7 +163,7 @@ class DraftRunner:
         self.site = _recompile.unique_site("serving.draft")
         self.tick = jax.jit(
             make_draft_tick(cfg, num_slots, capacity, k, feed_width,
-                            self.site, ps, sampling=sampling),
+                            self.site, ps),
             donate_argnums=(2, 3))
 
     def held_tokens(self, slot: int) -> int:
@@ -231,8 +230,7 @@ def _sample_rows(logits, keys, pos, temps, top_ks, top_ps):
 
 
 def make_draft_tick(cfg, num_slots: int, capacity: int, k: int,
-                    feed_width: int, site: str, page_size: int,
-                    sampling: bool = False):
+                    feed_width: int, site: str, page_size: int):
     """Build the draft tick body (jitted by DraftRunner; pools
     donated). The draft KV is PAGED (ISSUE 20): per-layer pools
     ``[num_pages, page_size, NH, D]`` indexed through the slot's draft
@@ -242,7 +240,7 @@ def make_draft_tick(cfg, num_slots: int, capacity: int, k: int,
     ``pool[dtab].reshape(ns, -1, NH, D)`` under the causal mask (null
     entries past the frontier are masked, contributing exactly 0).
 
-    Greedy args (fixed-shape; one trace covers every scheduler state):
+    Args (fixed-shape; one trace covers every scheduler state):
       stacked/other   draft decode params
       kc/vc           [L, num_pages, ps, NH, D] paged pools
       dtab            [ns, pages_per_slot] int32 draft page tables
@@ -255,6 +253,9 @@ def make_draft_tick(cfg, num_slots: int, capacity: int, k: int,
                               not generating (their writes route to
                               the null page and their drafts are
                               garbage the engine never offers)
+      sample_args     None for greedy; the sampling build's tuple,
+                              below (the build is chosen by which of
+                              the two the engine passes, at trace time)
       has_feed        bool    lax.cond fast path: steady-state ticks
                               skip the feed stage's compute entirely
       has_gen         bool    the symmetric fast path: feed-only ticks
@@ -262,10 +263,10 @@ def make_draft_tick(cfg, num_slots: int, capacity: int, k: int,
 
     Greedy returns (kc, vc, drafts [ns, k]).
 
-    The sampling build inserts per-request ``keys [ns, 2] uint32``,
-    ``temps``/``top_ks``/``top_ps`` [ns] and the chain args
+    The sampling build's ``sample_args`` are per-request ``keys [ns, 2]
+    uint32``, ``temps``/``top_ks``/``top_ps`` [ns] and the chain args
     ``chain_tok_m [ns, 1+k]``, ``chain_acc [ns]``, ``chain_pos0 [ns]``,
-    ``chain_mask [ns] bool`` after ``gen_pos``; chained rows override
+    ``chain_mask [ns] bool``; chained rows override
     the seed with ``tok_m[s, acc]`` at ``pos0 + acc + 1`` on device
     (the overlap arm feeds the verify tick's un-materialized outputs
     straight in). Its generate scan runs ``k + 1`` steps — step 0
@@ -287,10 +288,10 @@ def make_draft_tick(cfg, num_slots: int, capacity: int, k: int,
 
     from ..models.gpt import _ln, gpt_block_body
 
-    def body(stacked, other, kc, vc, dtab, feed_toks, feed_pos0,
-             feed_len, gen_tok, gen_pos, has_feed, has_gen,
-             sample_args):
+    def tick(stacked, other, kc, vc, dtab, feed_toks, feed_pos0,
+             feed_len, gen_tok, gen_pos, sample_args, has_feed, has_gen):
         _recompile.mark_trace(site, kc, feed_toks, gen_tok)
+        sampling = sample_args is not None
         wte = other["embeddings.wte.weight"]
         wpe = other["embeddings.wpe.weight"]
         rows = jnp.arange(ns)
@@ -423,36 +424,17 @@ def make_draft_tick(cfg, num_slots: int, capacity: int, k: int,
 
         return jax.lax.cond(has_gen, generate, skip, kc, vc)
 
-    if sampling:
-        def tick(stacked, other, kc, vc, dtab, feed_toks, feed_pos0,
-                 feed_len, gen_tok, gen_pos, keys, temps, top_ks,
-                 top_ps, chain_tok_m, chain_acc, chain_pos0,
-                 chain_mask, has_feed, has_gen):
-            return body(stacked, other, kc, vc, dtab, feed_toks,
-                        feed_pos0, feed_len, gen_tok, gen_pos,
-                        has_feed, has_gen,
-                        (keys, temps, top_ks, top_ps, chain_tok_m,
-                         chain_acc, chain_pos0, chain_mask))
-    else:
-        def tick(stacked, other, kc, vc, dtab, feed_toks, feed_pos0,
-                 feed_len, gen_tok, gen_pos, has_feed, has_gen):
-            return body(stacked, other, kc, vc, dtab, feed_toks,
-                        feed_pos0, feed_len, gen_tok, gen_pos,
-                        has_feed, has_gen, None)
-
     return tick
 
 
 def make_spec_tick(mcfg, num_slots: int, k: int, chunk_width: int,
-                   impl: str, site: str, quantized: bool = False,
-                   sampling: bool = False):
+                   impl: str, site: str):
     """Build the spec engine's verify/mixed tick body (jitted by the
     engine; pools donated). This IS the unified mixed-row tick with a
-    draft section — same site name, same single-trace contract.
-    ``quantized`` (int8 KV pools, ISSUE 12) widens the signature with
-    the per-page per-head scale arrays + the fresh-page reset vector,
-    exactly like the plain unified tick; the draft model's paged cache
-    stays at its own model dtype either way.
+    draft section — same site name, same single-trace contract, the
+    same ``pools`` and ``fresh`` arguments (``paged_cache.Pools``; the
+    draft model's paged cache stays at its own model dtype whatever
+    the pools store).
 
     Flat token layout: ``[ns last_tok | ns*k drafts | npf*w chunks]``.
     ``sample_ix`` is ``[ns * (1+k)]`` in that layout,
@@ -469,13 +451,13 @@ def make_spec_tick(mcfg, num_slots: int, k: int, chunk_width: int,
     them into the fixed-shape output); with no chunks aboard the
     prefill capacity is skipped as before.
 
-    The greedy build (``sampling=False``) is unchanged from PR 9/15:
-    returns (pools..., tokens [ns, 1+k] — the target's greedy argmax
-    at every verify position, accepted [ns]). The sampling build adds
-    ``keys [ns, 2] uint32``, ``sample_pos [ns]`` (column-0 emission
-    positions), ``temps``/``top_ks``/``top_ps`` [ns] and
-    ``draft_probs [ns, k, V]`` (the draft tick's filtered
-    distributions); its spec branches run
+    Returns (pools, tokens [ns, 1+k], accepted [ns]). With
+    ``sample_args`` None (greedy) the tokens are the target's greedy
+    argmax at every verify position. The sampling build is chosen, at
+    trace time, by passing ``sample_args = (keys [ns, 2] uint32,
+    sample_pos [ns] (column-0 emission positions),
+    temps/top_ks/top_ps [ns], draft_probs [ns, k, V] (the draft tick's
+    filtered distributions))``; its spec branches run
     ``ops/decoding.spec_rejection_sample`` and its plain branches the
     per-row sampling law — acceptance must live INSIDE the branches
     there because it consumes the uniform draws.
@@ -487,10 +469,13 @@ def make_spec_tick(mcfg, num_slots: int, k: int, chunk_width: int,
     from ..models.gpt import gpt_ragged_apply
     from ..ops.decoding import spec_accept_length, spec_rejection_sample
 
-    def core(stacked, other, pools, last_tok, draft_toks,
-             pf_toks, tok_pos, tok_limit, row_tab, row_pos0, row_len,
-             sample_ix, n_draft, has_chunks, has_drafts,
-             sample_args=None):
+    def tick(stacked, other, pools, fresh, last_tok, draft_toks, pf_toks,
+             tok_pos, tok_limit, row_tab, row_pos0, row_len, sample_ix,
+             n_draft, sample_args, has_chunks, has_drafts):
+        _recompile.mark_trace(site, pools.k, row_tab, tok_pos, last_tok)
+        # recycled pages start their running-max scale at 0 (the
+        # engine lists pages allocated since the last dispatch)
+        pools = pools.reset_scales(fresh)
         tokens = jnp.concatenate([last_tok, draft_toks, pf_toks])
         # the no-draft branches run the exact non-speculative layout:
         # the draft section sliced out of every metadata vector
@@ -515,22 +500,12 @@ def make_spec_tick(mcfg, num_slots: int, k: int, chunk_width: int,
             return out.at[jnp.arange(ns) * (1 + k)].set(tok_ns)
 
         def run(pl_, toks_, pos_, lim_, tab_, p0_, len_, six_, sk):
-            if quantized:
-                kp, vp, ks, vs = pl_
-                lg, kp, vp, ks, vs = gpt_ragged_apply(
-                    mcfg, stacked, other, kp, vp, toks_, pos_, lim_,
-                    tab_, p0_, len_, six_, decode_rows=ns,
-                    chunk_width=w, impl=impl, spec_k=sk,
-                    kscale=ks, vscale=vs)
-                return lg, (kp, vp, ks, vs)
-            kp, vp = pl_
-            lg, kp, vp = gpt_ragged_apply(
-                mcfg, stacked, other, kp, vp, toks_, pos_, lim_,
-                tab_, p0_, len_, six_, decode_rows=ns,
-                chunk_width=w, impl=impl, spec_k=sk)
-            return lg, (kp, vp)
+            return gpt_ragged_apply(
+                mcfg, stacked, other, pl_, toks_, pos_, lim_, tab_, p0_,
+                len_, six_, decode_rows=ns, chunk_width=w, impl=impl,
+                spec_k=sk)
 
-        if sampling:
+        if sample_args is not None:
             keys, sample_pos, temps, top_ks, top_ps, draft_probs = \
                 sample_args
 
@@ -559,103 +534,36 @@ def make_spec_tick(mcfg, num_slots: int, k: int, chunk_width: int,
         def spec_mixed(pl_):
             lg, pl_ = run(pl_, tokens, tok_pos, tok_limit, row_tab,
                           row_pos0, row_len, sample_ix, k)
-            return accept(lg) + pl_
+            return accept(lg) + (pl_,)
 
         def spec_only(pl_):
             lg, pl_ = run(pl_, tokens[:base], tok_pos[:base],
                           tok_limit[:base], row_tab[:ns], row_pos0[:ns],
                           row_len[:ns], sample_ix, k)
-            return accept(lg) + pl_
+            return accept(lg) + (pl_,)
 
         def plain_mixed(pl_):
             lg, pl_ = run(pl_, tokens_plain, pos_plain, lim_plain,
                           row_tab, row_pos0, row_len, primary_ix, 0)
-            return plain(lg) + pl_
+            return plain(lg) + (pl_,)
 
         def plain_only(pl_):
             lg, pl_ = run(pl_, tokens_plain[:ns], pos_plain[:ns],
                           lim_plain[:ns], row_tab[:ns], row_pos0[:ns],
                           row_len[:ns], primary_ix, 0)
-            return plain(lg) + pl_
+            return plain(lg) + (pl_,)
 
-        out = jax.lax.cond(
+        toks, acc, pools = jax.lax.cond(
             has_drafts,
             lambda pl_: jax.lax.cond(has_chunks, spec_mixed,
                                      spec_only, pl_),
             lambda pl_: jax.lax.cond(has_chunks, plain_mixed,
                                      plain_only, pl_),
             pools)
-        toks, acc_b, pools = out[0], out[1], out[2:]
         tok_m = toks.reshape(ns, 1 + k)
-        if sampling:
-            acc = acc_b
-        else:
+        if sample_args is None:
             acc = spec_accept_length(draft_toks.reshape(ns, k),
                                      tok_m[:, :k], n_draft)
         return pools, tok_m, acc
-
-    if sampling:
-        if quantized:
-            def tick(stacked, other, kpool, vpool, kscale, vscale,
-                     fresh, last_tok, draft_toks, pf_toks, tok_pos,
-                     tok_limit, row_tab, row_pos0, row_len, sample_ix,
-                     n_draft, keys, sample_pos, temps, top_ks, top_ps,
-                     draft_probs, has_chunks, has_drafts):
-                _recompile.mark_trace(site, kpool, row_tab, tok_pos,
-                                      last_tok)
-                kscale = kscale.at[:, fresh].set(0.0)
-                vscale = vscale.at[:, fresh].set(0.0)
-                (kpool, vpool, kscale, vscale), tok_m, acc = core(
-                    stacked, other, (kpool, vpool, kscale, vscale),
-                    last_tok, draft_toks, pf_toks, tok_pos, tok_limit,
-                    row_tab, row_pos0, row_len, sample_ix, n_draft,
-                    has_chunks, has_drafts,
-                    (keys, sample_pos, temps, top_ks, top_ps,
-                     draft_probs))
-                return kpool, vpool, kscale, vscale, tok_m, acc
-        else:
-            def tick(stacked, other, kpool, vpool, last_tok,
-                     draft_toks, pf_toks, tok_pos, tok_limit, row_tab,
-                     row_pos0, row_len, sample_ix, n_draft, keys,
-                     sample_pos, temps, top_ks, top_ps, draft_probs,
-                     has_chunks, has_drafts):
-                _recompile.mark_trace(site, kpool, row_tab, tok_pos,
-                                      last_tok)
-                (kpool, vpool), tok_m, acc = core(
-                    stacked, other, (kpool, vpool), last_tok,
-                    draft_toks, pf_toks, tok_pos, tok_limit, row_tab,
-                    row_pos0, row_len, sample_ix, n_draft, has_chunks,
-                    has_drafts,
-                    (keys, sample_pos, temps, top_ks, top_ps,
-                     draft_probs))
-                return kpool, vpool, tok_m, acc
-    elif quantized:
-        def tick(stacked, other, kpool, vpool, kscale, vscale, fresh,
-                 last_tok, draft_toks, pf_toks, tok_pos, tok_limit,
-                 row_tab, row_pos0, row_len, sample_ix, n_draft,
-                 has_chunks, has_drafts):
-            _recompile.mark_trace(site, kpool, row_tab, tok_pos,
-                                  last_tok)
-            # recycled pages start their running-max scale at 0 (the
-            # engine lists pages allocated since the last dispatch)
-            kscale = kscale.at[:, fresh].set(0.0)
-            vscale = vscale.at[:, fresh].set(0.0)
-            (kpool, vpool, kscale, vscale), tok_m, acc = core(
-                stacked, other, (kpool, vpool, kscale, vscale),
-                last_tok, draft_toks, pf_toks, tok_pos, tok_limit,
-                row_tab, row_pos0, row_len, sample_ix, n_draft,
-                has_chunks, has_drafts)
-            return kpool, vpool, kscale, vscale, tok_m, acc
-    else:
-        def tick(stacked, other, kpool, vpool, last_tok, draft_toks,
-                 pf_toks, tok_pos, tok_limit, row_tab, row_pos0,
-                 row_len, sample_ix, n_draft, has_chunks, has_drafts):
-            _recompile.mark_trace(site, kpool, row_tab, tok_pos,
-                                  last_tok)
-            (kpool, vpool), tok_m, acc = core(
-                stacked, other, (kpool, vpool), last_tok, draft_toks,
-                pf_toks, tok_pos, tok_limit, row_tab, row_pos0,
-                row_len, sample_ix, n_draft, has_chunks, has_drafts)
-            return kpool, vpool, tok_m, acc
 
     return tick

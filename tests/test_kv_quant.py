@@ -317,8 +317,9 @@ class TestEngineInt8:
             num_slots=2, page_size=8, pages_per_slot=pps,
             kv_dtype="int8"))
         free = np.asarray(sorted(eng.pool.allocator._free), np.int32)
-        eng.pool.k_scale = eng.pool.k_scale.at[:, free].set(1e6)
-        eng.pool.v_scale = eng.pool.v_scale.at[:, free].set(1e6)
+        eng.pool.pools = eng.pool.pools._replace(
+            k_scale=eng.pool.k_scale.at[:, free].set(1e6),
+            v_scale=eng.pool.v_scale.at[:, free].set(1e6))
         rids = [eng.submit(p, 12) for p in prompts]
         res = eng.run()
         poisoned = [res[r] for r in rids]
@@ -328,7 +329,7 @@ class TestEngineInt8:
     def test_pool_args_sees_overflow_reset(self, small_net):
         # regression (review): the tick args must capture the scale
         # arrays AFTER take_fresh ran — its overflow path eagerly
-        # rewrites pool.k_scale/v_scale, and capturing first would
+        # rewrites the pools' scales, and capturing first would
         # dispatch the stale (un-reset) arrays and then clobber the
         # reset with the tick's output
         eng = ServingEngine(small_net, ServingConfig(
@@ -337,15 +338,17 @@ class TestEngineInt8:
         eng._fresh_cap = 1
         eng.pool._fresh = [1, 2, 3]
         poison = np.array([1, 2, 3], np.int32)
-        eng.pool.k_scale = eng.pool.k_scale.at[:, poison].set(7.0)
-        eng.pool.v_scale = eng.pool.v_scale.at[:, poison].set(7.0)
-        k, v, ks, vs, fresh = eng._pool_args()
+        eng.pool.pools = eng.pool.pools._replace(
+            k_scale=eng.pool.k_scale.at[:, poison].set(7.0),
+            v_scale=eng.pool.v_scale.at[:, poison].set(7.0))
+        pools, fresh = eng._pool_args()
         assert np.asarray(fresh).tolist() == [1]
         # the overflow pages (2, 3) were reset eagerly, and the
         # CAPTURED arrays already reflect it
-        assert np.all(np.asarray(ks)[:, 2:4] == 0.0)
-        assert np.all(np.asarray(vs)[:, 2:4] == 0.0)
-        assert np.all(np.asarray(ks)[:, 1] == 7.0)  # in-tick reset's job
+        assert np.all(np.asarray(pools.k_scale)[:, 2:4] == 0.0)
+        assert np.all(np.asarray(pools.v_scale)[:, 2:4] == 0.0)
+        # in-tick reset's job
+        assert np.all(np.asarray(pools.k_scale)[:, 1] == 7.0)
 
     def test_claim_fresh_drops_duplicates(self):
         # regression (review): an alloc → preempt-release → realloc
@@ -382,15 +385,15 @@ class TestEngineInt8:
             assert np.array_equal(a, b)
 
     def test_cow_copy_carries_scales(self):
-        from paddle_tpu.serving.engine import _copy_pages_q
+        from paddle_tpu.serving.paged_cache import Pools
         k = jnp.arange(2 * 4 * 2 * 2 * 2, dtype=jnp.int8).reshape(
             2, 4, 2, 2, 2)
         s = jnp.arange(2 * 4 * 2, dtype=jnp.float32).reshape(2, 4, 2)
-        k2, v2, ks2, vs2 = _copy_pages_q(k, k, s, s * 2,
-                                         jnp.int32(1), jnp.int32(3))
-        assert np.array_equal(np.asarray(k2)[:, 3], np.asarray(k)[:, 1])
-        assert np.array_equal(np.asarray(ks2)[:, 3], np.asarray(s)[:, 1])
-        assert np.array_equal(np.asarray(vs2)[:, 3],
+        out = Pools(k, k, s, s * 2).copy_page(jnp.int32(1), jnp.int32(3))
+        assert np.array_equal(np.asarray(out.k)[:, 3], np.asarray(k)[:, 1])
+        assert np.array_equal(np.asarray(out.k_scale)[:, 3],
+                              np.asarray(s)[:, 1])
+        assert np.array_equal(np.asarray(out.v_scale)[:, 3],
                               np.asarray(s * 2)[:, 1])
 
     def test_bf16_pool(self, small_net):
@@ -428,18 +431,6 @@ class TestValidation:
     def test_unknown_kv_dtype(self, small_net):
         with pytest.raises(ValueError, match="kv_dtype"):
             ServingEngine(small_net, ServingConfig(kv_dtype="fp4"))
-
-    def test_legacy_rejects_quantized(self, small_net):
-        with pytest.raises(ValueError, match="legacy"):
-            ServingEngine(small_net, ServingConfig(
-                kv_dtype="int8", attention_kernel="legacy"))
-        with pytest.raises(ValueError, match="legacy"):
-            ServingEngine(small_net, ServingConfig(
-                kv_dtype="bf16", attention_kernel="legacy"))
-        # explicit f32 on an f32 model is the model dtype: allowed
-        ServingEngine(small_net, ServingConfig(
-            kv_dtype="f32", attention_kernel="legacy", num_slots=1,
-            page_size=8, pages_per_slot=2))
 
     def test_dense_generate_rejects_kv_dtype(self, small_net):
         with pytest.raises(ValueError, match="paged"):

@@ -383,7 +383,7 @@ def test_serving_entry_points_refuse_the_new_block_kinds(small):
         gpt_mod.gpt_cached_apply(model.config, {}, {}, None, None,
                                  jnp.zeros((1, 1), jnp.int32), 0)
     with pytest.raises(NotImplementedError, match="training only"):
-        gpt_mod.gpt_ragged_apply(model.config, {}, {}, None, None,
+        gpt_mod.gpt_ragged_apply(model.config, {}, {}, None,
                                  *[None] * 7, decode_rows=0, chunk_width=1)
     from paddle_tpu.serving import ServingConfig, ServingEngine
     with pytest.raises(NotImplementedError, match="training only"):
@@ -416,6 +416,16 @@ def frozen_gpt():
         os.path.dirname(__file__), "frozen", "gpt_pr25.py"))
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
+    # PR 28 handed the tick its page pools as one argument
+    # (``paged_cache.Pools``); the frozen forward takes and returns them
+    # apart, so it is called through that signature here
+    apart = mod.gpt_ragged_apply
+
+    def gpt_ragged_apply(cfg, stacked, other, pools, *a, **kw):
+        logits, k, v = apart(cfg, stacked, other, pools.k, pools.v, *a, **kw)
+        return logits, pools._replace(k=k, v=v)
+
+    mod.gpt_ragged_apply = gpt_ragged_apply
     live = sys.modules[name]
     sys.modules[name] = models.gpt = mod
     try:

@@ -599,7 +599,7 @@ class TestPageReuse:
 
 class TestPagedAttentionKernel:
     def test_pallas_kernel_matches_xla_reference(self):
-        from paddle_tpu.ops.paged_attention import paged_decode_attention
+        from paddle_tpu.ops.paged_attention import ragged_paged_attention
 
         B, NPs, P, ps, NH, Dh = 3, 4, 9, 8, 4, 16
         r = np.random.RandomState(0)
@@ -608,36 +608,43 @@ class TestPagedAttentionKernel:
         q = jnp.asarray(r.randn(B, 1, NH, Dh).astype(np.float32))
         tab = jnp.asarray(r.randint(1, P, (B, NPs)).astype(np.int32))
         pos = jnp.asarray(np.array([5, 17, 30], np.int32))
-        ref = paged_decode_attention(q, kpool, vpool, tab, pos,
+        one = jnp.ones((B,), jnp.int32)       # decode rows
+        ref = ragged_paged_attention(q, kpool, vpool, tab, pos, one,
                                      impl="xla")
-        ker = paged_decode_attention(q, kpool, vpool, tab, pos,
+        ker = ragged_paged_attention(q, kpool, vpool, tab, pos, one,
                                      impl="pallas")
         np.testing.assert_allclose(np.asarray(ker), np.asarray(ref),
                                    rtol=2e-5, atol=2e-5)
 
-    def test_prefill_attention_t1_matches_decode(self):
-        """The suffix-prefill read at chunk length 1 is the decode read
-        (same gather, same mask, same reduction) — the two spellings
-        must agree exactly on identical inputs."""
-        from paddle_tpu.ops.paged_attention import (
-            paged_decode_attention, paged_prefill_attention)
+    def test_chunk_row_of_length_1_matches_decode_row(self):
+        """A chunk row one query wide is the decode row at the same
+        position (same gather, same mask, same reduction), alone or
+        beside other rows: the reads agree exactly."""
+        from paddle_tpu.ops.paged_attention import ragged_paged_attention
 
         r = np.random.RandomState(1)
         kpool = jnp.asarray(r.randn(6, 8, 4, 16).astype(np.float32))
         vpool = jnp.asarray(r.randn(6, 8, 4, 16).astype(np.float32))
-        q = jnp.asarray(r.randn(1, 1, 4, 16).astype(np.float32))
-        tab = jnp.asarray(np.array([[2, 5, 1]], np.int32))
-        pos = jnp.asarray(np.array([13], np.int32))
-        dec = paged_decode_attention(q, kpool, vpool, tab, pos)
-        pre = paged_prefill_attention(q, kpool, vpool, tab,
-                                      jnp.int32(13))
-        np.testing.assert_array_equal(np.asarray(dec), np.asarray(pre))
+        q = jnp.asarray(r.randn(3, 1, 4, 16).astype(np.float32))
+        tab = jnp.asarray(np.array([[2, 5, 1], [3, 4, 0], [1, 2, 5]],
+                                   np.int32))
+        pos = jnp.asarray(np.array([13, 9, 20], np.int32))
+        dec = ragged_paged_attention(q, kpool, vpool, tab, pos,
+                                     jnp.ones((3,), jnp.int32))
+        # as the chunk group spells its metadata, one row at a time
+        for i in range(3):
+            pre = ragged_paged_attention(
+                q[i:i + 1], kpool, vpool, tab[i:i + 1],
+                jnp.broadcast_to(pos[i], (1,)),
+                jnp.full((1,), q.shape[1], jnp.int32))
+            np.testing.assert_array_equal(np.asarray(dec)[i:i + 1],
+                                          np.asarray(pre))
 
     def test_unknown_impl_raises(self):
-        from paddle_tpu.ops.paged_attention import paged_decode_attention
+        from paddle_tpu.ops.paged_attention import ragged_paged_attention
 
         with pytest.raises(ValueError):
-            paged_decode_attention(None, None, None, None, None,
+            ragged_paged_attention(None, None, None, None, None, None,
                                    impl="cuda")
 
 
@@ -652,33 +659,6 @@ class TestRaggedAttention:
         k = jnp.asarray(r.randn(pages, ps, nh, hd).astype(np.float32))
         v = jnp.asarray(r.randn(pages, ps, nh, hd).astype(np.float32))
         return r, k, v
-
-    def test_ragged_rows_match_legacy_spellings_bitwise(self):
-        """A decode call IS a ragged call with true_len == 1 rows; a
-        chunk call IS a ragged call with chunk-width rows — all three
-        entry points route through the one shared gather/mask/softmax
-        helper, so the equality must be bitwise (this is what the
-        engine's greedy parity contract rests on)."""
-        from paddle_tpu.ops.paged_attention import (
-            paged_decode_attention, paged_prefill_attention,
-            ragged_paged_attention)
-
-        r, kpool, vpool = self._pools()
-        tab = jnp.asarray(r.randint(1, 9, (3, 4)).astype(np.int32))
-        pos = jnp.asarray(np.array([5, 17, 31], np.int32))
-        q1 = jnp.asarray(r.randn(3, 1, 4, 16).astype(np.float32))
-        dec = paged_decode_attention(q1, kpool, vpool, tab, pos)
-        rag = ragged_paged_attention(q1, kpool, vpool, tab, pos,
-                                     jnp.ones((3,), jnp.int32))
-        np.testing.assert_array_equal(np.asarray(dec), np.asarray(rag))
-        qc = jnp.asarray(r.randn(2, 8, 4, 16).astype(np.float32))
-        tabc = tab[:2]
-        pre = paged_prefill_attention(qc, kpool, vpool, tabc,
-                                      jnp.int32(9))
-        ragc = ragged_paged_attention(
-            qc, kpool, vpool, tabc, jnp.full((2,), 9, jnp.int32),
-            jnp.full((2,), 8, jnp.int32))
-        np.testing.assert_array_equal(np.asarray(pre), np.asarray(ragc))
 
     def test_pallas_matches_xla_mixed_rows(self):
         """Interpret-mode Pallas vs XLA allclose over one metadata
@@ -730,36 +710,7 @@ class TestRaggedAttention:
                                        rtol=2e-5, atol=2e-5)
 
 
-class TestUnifiedVsLegacy:
-    def test_legacy_two_dispatch_matches_unified_bitwise(self):
-        """attention_kernel='legacy' keeps the pre-unification engine
-        (decode tick + separate prefill program) for the dispatch-
-        collapse benchmark. Outputs must stay bitwise-equal to the
-        unified engine — the math is the same shared helper, only the
-        dispatch structure differs: ONE site (traced once) unified,
-        TWO sites legacy."""
-        from paddle_tpu.profiler import recompile
-
-        net = _net()
-        cfgkw = dict(num_slots=2, page_size=8, pages_per_slot=4,
-                     prefill_chunk=8)
-        rng = np.random.RandomState(21)
-        prompts = [rng.randint(0, 128, (t,)).astype(np.int32)
-                   for t in (8, 16, 12)]
-        uni = ServingEngine(net, ServingConfig(**cfgkw))
-        leg = ServingEngine(net, ServingConfig(
-            attention_kernel="legacy", **cfgkw))
-        u_rids = [uni.submit(p, 8) for p in prompts]
-        l_rids = [leg.submit(p, 8) for p in prompts]
-        u_out, l_out = uni.run(), leg.run()
-        for ur, lr in zip(u_rids, l_rids):
-            np.testing.assert_array_equal(u_out[ur], l_out[lr])
-        assert len(uni.compiled_sites) == 1
-        assert len(leg.compiled_sites) == 2
-        counts = recompile.trace_counts()
-        assert all(counts[site] == 1 for site in uni.compiled_sites)
-        assert all(counts[site] == 1 for site in leg.compiled_sites)
-
+class TestUnifiedTick:
     def test_program_inventory_covers_every_dispatched_site(self):
         """ISSUE 8 regression: record_program_stats() must return one
         inventory entry per compiled_sites program that dispatched —
@@ -780,20 +731,22 @@ class TestUnifiedVsLegacy:
             assert {"flops", "bytes_accessed", "cost_available"} \
                 <= set(rec)
 
-    def test_kernel_selection_and_deprecated_alias(self):
+    def test_kernel_selection(self):
         net = _net()
         cfgkw = dict(num_slots=1, page_size=8, pages_per_slot=2)
         eng = ServingEngine(net, ServingConfig(
-            attention_impl="pallas", **cfgkw))
+            attention_kernel="ragged-pallas", **cfgkw))
         assert eng.attention_kernel == "ragged-pallas"
         assert ServingEngine(net, ServingConfig(
             **cfgkw)).attention_kernel == "ragged-xla"
-        with pytest.raises(ValueError):
-            ServingEngine(net, ServingConfig(
-                attention_kernel="cuda", **cfgkw))
-        with pytest.raises(ValueError):
-            ServingEngine(net, ServingConfig(
-                attention_impl="cuda", **cfgkw))
+        # the two-dispatch engine and the alias that predates
+        # attention_kernel are gone (PR 28)
+        for gone in ("cuda", "legacy"):
+            with pytest.raises(ValueError, match="unknown attention"):
+                ServingEngine(net, ServingConfig(
+                    attention_kernel=gone, **cfgkw))
+        with pytest.raises(TypeError, match="attention_impl"):
+            ServingConfig(attention_impl="pallas", **cfgkw)
 
 
 @pytest.mark.slow

@@ -311,7 +311,7 @@ class TestSpecObservability:
 
 
 class TestSpecConfigValidation:
-    def test_rejects_legacy_and_mismatches(self):
+    def test_rejects_mismatches(self):
         net = _net()
         twin = _net()
         base = dict(num_slots=1, page_size=8, pages_per_slot=2)
@@ -323,10 +323,6 @@ class TestSpecConfigValidation:
                 decode="greedy",
                 spec=SpecConfig(draft_model=twin, k=2, overlap=True),
                 **base))
-        with pytest.raises(ValueError):
-            ServingEngine(net, ServingConfig(
-                attention_kernel="legacy",
-                spec=SpecConfig(draft_model=twin, k=2), **base))
         with pytest.raises(ValueError):
             ServingEngine(net, ServingConfig(
                 spec=SpecConfig(draft_model=twin, k=0), **base))
