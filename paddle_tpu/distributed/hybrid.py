@@ -19,6 +19,27 @@ stage, shards batch dim 0 over 'dp' (+ seq dim 1 over 'sp'), applies ZeRO
 1/2/3 by adding a 'dp' axis to opt-state/param shardings, bf16-casts under
 amp, and wraps blocks in jax.checkpoint under recompute — all in one jitted
 step XLA can schedule globally.
+
+Which loop is outside is decided by the mesh, for every model
+(``_forward_loss``):
+
+  pp == 1: the layer scan outside, the micro-batch loop inside
+      (``blocks_one_stage``). The scan hands one layer's parameters to
+      its body and the block is mapped over the micro-batches there, so
+      forward and backward each slice a layer out of the stack once a
+      step, the backward sums ONE layer's gradient over the micro-batches
+      and writes it into the stack once. With micro-batches outside,
+      ``value_and_grad`` sliced every layer per micro-batch, built the
+      whole stack's gradient per micro-batch and added stacks: a quarter
+      of the OLMoE step, whose experts are 0.8 GB a layer (PERF.md,
+      PR 29).
+  pp > 1: the micro-batch loop outside — it is the pipeline's clock
+      (pipeline.py's tick scan), a stage's layers inside each tick.
+
+Both orders apply the same block to the same micro-batch with the same
+weights, recompute the block of one micro-batch at a time, save x of every
+(layer, micro-batch) between forward and backward, and sum in the same
+order.
 """
 from __future__ import annotations
 
@@ -41,7 +62,8 @@ from ..profiler import trace as _ptrace
 from ..profiler.metrics import registry as _preg
 from ..static.functional import _swapped_state, state_tensors
 from .fleet.distributed_strategy import DistributedStrategy
-from .pipeline import pipeline_apply
+from .pipeline import (_from_microbatches, _to_microbatches,
+                       pipeline_apply)
 from .strategy_compiler import (_add_axis, _local_check_shape,
                                 build_mesh_from_strategy,
                                 resolve_param_specs)
@@ -125,7 +147,9 @@ class HybridPipelineTrainer:
             fetches on the layer's gradient: no fetch overlaps
             forward/backward, trading the overlap for a smaller peak
             (the 1.9B fit knob).
-        unroll_layers: unroll the per-stage layer loop. Default: unroll
+        unroll_layers: unroll the layer loop: the outer loop at pp == 1
+            (the micro-batch loop inside it stays a loop), a stage's
+            layers inside each tick at pp > 1. Default: unroll
             on TPU without remat (removes the scan's dynamic-slice
             bookkeeping), scan under remat — unrolling a rematerialized
             backward lets the latency-hiding scheduler hoist every
@@ -706,58 +730,96 @@ class HybridPipelineTrainer:
 
         moe = self.moe
 
-        def split_aux(aux):
-            # the carry holds means over the micro-batches: right for the
-            # loss; the counts are sums
-            stats = {k: v * self.n_micro for k, v in aux.items()
-                     if k != "loss"}
-            return aux["loss"], stats
-
-        def block_apply(stage_local, x):
-            """Apply one stage's lps blocks (lax.scan over layers).
-            MoE models: returns (out, sums over the stage's blocks of
-            their weighted auxiliary loss and of their counts) — the
-            pipeline's stage_aux contract."""
-            def one_block(h, layer_params):
-                vals = [layer_params[s] for s in self.block_suffixes]
-                # kernel_scope: Pallas kernels nest a shard_map over
-                # whatever mesh axes are still GSPMD-auto here — all of
-                # them at pp == 1 in a plain jit, the non-manual ones
-                # inside the pipeline's region, none inside qcomm's
-                # all-manual dp wrap (XLA does not partition a Mosaic
-                # call)
-                with _swapped_state(blk0_tensors, vals), \
-                        dctx.kernel_scope(self.mesh):
-                    if manual_sp:
-                        with dctx.manual_sequence_parallel_scope():
-                            out = block0(Tensor(h))._value
-                    else:
+        def one_block(h, layer_params):
+            vals = [layer_params[s] for s in self.block_suffixes]
+            # kernel_scope: Pallas kernels nest a shard_map over
+            # whatever mesh axes are still GSPMD-auto here — all of
+            # them at pp == 1 in a plain jit, the non-manual ones
+            # inside the pipeline's region, none inside qcomm's
+            # all-manual dp wrap (XLA does not partition a Mosaic
+            # call)
+            with _swapped_state(blk0_tensors, vals), \
+                    dctx.kernel_scope(self.mesh):
+                if manual_sp:
+                    with dctx.manual_sequence_parallel_scope():
                         out = block0(Tensor(h))._value
-                    aux = {"loss": block0.aux_loss._value,
-                           **{k: v._value for k, v in
-                              block0.aux_stats.items()}} if moe else None
-                return (out, aux) if moe else out
-
-            if self.remat:
-                if self.remat_policy == "dots":
-                    one_block = jax.checkpoint(
-                        one_block,
-                        policy=jax.checkpoint_policies
-                        .dots_with_no_batch_dims_saveable)
                 else:
-                    one_block = jax.checkpoint(one_block)
+                    out = block0(Tensor(h))._value
+                aux = {"loss": block0.aux_loss._value,
+                       **{k: v._value for k, v in
+                          block0.aux_stats.items()}} if moe else None
+            return (out, aux) if moe else out
 
+        # recomputation wraps the block of ONE micro-batch on every mesh,
+        # never a loop over them: what is saved between forward and
+        # backward is x of every (layer, micro-batch) in both loop orders
+        if self.remat:
+            if self.remat_policy == "dots":
+                one_block = jax.checkpoint(
+                    one_block,
+                    policy=jax.checkpoint_policies
+                    .dots_with_no_batch_dims_saveable)
+            else:
+                one_block = jax.checkpoint(one_block)
+
+        def layer_scan(apply_layer, x, stage_local):
+            """``lax.scan`` of ``apply_layer(x, layer_params)`` over the
+            stacked layers; MoE models: also the blocks' auxiliary values
+            (float32), stacked ``[layers, ...]``."""
             def body(h, layer_params):
-                out = one_block(h, layer_params)
+                out = apply_layer(h, layer_params)
                 return out if moe else (out, None)
 
             unroll = self.unroll_layers if self.unroll_layers is not None \
                 else (_target_platform() != "cpu" and not self.remat)
             out, auxs = jax.lax.scan(body, x, stage_local, unroll=unroll)
+            return out, jax.tree_util.tree_map(
+                lambda a: a.astype(jnp.float32), auxs)
+
+        def block_apply(stage_local, x):
+            """pp > 1, the pipeline's ``stage_fn``: one stage's lps blocks
+            on one micro-batch (the micro-batch loop is the schedule's
+            clock, outside). MoE models: returns (out, sums over the
+            stage's blocks of their weighted auxiliary loss and of their
+            counts) — the pipeline's stage_aux contract."""
+            out, auxs = layer_scan(one_block, x, stage_local)
             if moe:
                 return out, jax.tree_util.tree_map(
-                    lambda a: jnp.sum(a.astype(jnp.float32), axis=0), auxs)
+                    lambda a: jnp.sum(a, axis=0), auxs)
             return out
+
+        def split_aux(aux):
+            # pipeline_apply's carry holds means over the micro-batches:
+            # right for the loss; the counts are sums
+            stats = {k: v * self.n_micro for k, v in aux.items()
+                     if k != "loss"}
+            return aux["loss"], stats
+
+        def blocks_one_stage(stacked, x):
+            """pp == 1: every block on every micro-batch, layers outside
+            and micro-batches inside (the module docstring says why): the
+            scan's body gets one layer's parameters and maps the block
+            over ``x`` as ``[n_micro, micro, seq, h]``. Returns the
+            activations and, MoE models, (mean over micro-batches of the
+            blocks' summed auxiliary loss, their counts summed over
+            layers and micro-batches)."""
+            # [1, L, ...] or, interleaved, [1, v, L/v, ...]: the chunks'
+            # layers in order
+            layers = jax.tree_util.tree_map(
+                lambda a: a[0].reshape((-1,) + tuple(a.shape[3:]))
+                if self.v > 1 else a[0], stacked)
+            mbs, auxs = layer_scan(
+                lambda hs, layer_params: jax.lax.map(
+                    lambda h: one_block(h, layer_params), hs),
+                _to_microbatches(x, self.n_micro), layers)
+            out = _from_microbatches(mbs, x.shape)
+            if not moe:
+                return out, None, {}
+            # [L, n_micro] a leaf: over the layers first, as a stage's
+            # aux is summed under pp > 1
+            sums = jax.tree_util.tree_map(
+                lambda a: jnp.sum(jnp.sum(a, axis=0), axis=0), auxs)
+            return out, sums.pop("loss") / self.n_micro, sums
 
         batch_tensors = [Tensor(b) for b in batch]
         # loss-inside-pipeline: the head runs in the manual region and only
@@ -801,14 +863,18 @@ class HybridPipelineTrainer:
                         return (loss_v + aux).astype(jnp.float32), stats
                     return loss_v.astype(jnp.float32), {}
                 with _ptrace.annotate("fwd/blocks"):
-                    x = pipeline_apply(self.mesh, block_apply, block_cast,
-                                       x, self.n_micro, v_virtual=self.v,
-                                       sp_axis="sp" if manual_sp else None,
-                                       stage_aux=moe)
-                aux, stats = None, {}
-                if moe:
-                    x, aux = x
-                    aux, stats = split_aux(aux)
+                    if self.pp == 1:
+                        x, aux, stats = blocks_one_stage(block_cast, x)
+                    else:
+                        x = pipeline_apply(
+                            self.mesh, block_apply, block_cast, x,
+                            self.n_micro, v_virtual=self.v,
+                            sp_axis="sp" if manual_sp else None,
+                            stage_aux=moe)
+                        aux, stats = None, {}
+                        if moe:
+                            x, aux = x
+                            aux, stats = split_aux(aux)
                 with _ptrace.annotate("fwd/head"):
                     x = Tensor(seq_constraint(x))
                     loss = model.pipeline_head(x, *batch_tensors)

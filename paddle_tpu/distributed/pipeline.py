@@ -5,6 +5,14 @@ TPU-native replacement for the reference pipeline stack
 program by op_device + send_v2/recv_v2 ops; PipelineTrainer/SectionWorker
 section_worker.cc:34 F-then-B thread-per-stage schedule).
 
+This module is the schedule of a mesh whose 'pp' axis is larger than 1:
+there the micro-batch loop (the tick scan) is OUTSIDE and a stage's layers
+are inside each tick, because the micro-batches are the pipeline's clock. A
+one-stage mesh has no schedule and is refused here: HybridPipelineTrainer
+scans the layers on the outside and maps the micro-batches inside
+(hybrid.py ``blocks_one_stage``), so that a layer's weights are sliced out
+of the stack once a step and not once a micro-batch.
+
 Here the whole pipeline is ONE compiled SPMD computation:
   - transformer blocks' params are stacked into [pp, layers_per_stage, ...]
     (or [pp, v, layers_per_virtual, ...] when interleaved) with the stage
@@ -95,25 +103,11 @@ def pipeline_apply(mesh: Mesh, stage_fn: Callable, stacked_params: Any,
         raise ValueError(
             f"interleaved schedule needs n_micro >= pp ({n_micro} < {pp})")
     if pp == 1:
-        sliced = jax.tree_util.tree_map(
-            lambda a: a[0] if v == 1 else a[0].reshape(
-                (-1,) + tuple(a.shape[3:])), stacked_params)
-        mbs = _to_microbatches(x, n_micro)
-
-        def one_mb(mb):
-            with _annotate("pp/stage"):
-                return stage_fn(sliced, mb)
-
-        out = jax.lax.map(one_mb, mbs)
-        if stage_aux:
-            out, auxs = out
-            aux = jax.tree_util.tree_map(
-                lambda a: jnp.sum(a.astype(jnp.float32), axis=0) / n_micro,
-                auxs)
-        full = _from_microbatches(out, x.shape)
-        res = head_fn(full, *head_args) if head_fn is not None else full
-        return (res, aux) if stage_aux else res
-
+        raise ValueError(
+            f"pipeline_apply schedules micro-batches over mesh axis "
+            f"{pp_axis!r} of size > 1; a one-stage mesh has no schedule "
+            f"(HybridPipelineTrainer scans the layers there, micro-batches "
+            f"inside: hybrid.py blocks_one_stage)")
     compute_dtype = x.dtype
     # XLA:CPU's AllReducePromotion pass crashes on bf16 all-reduce; the
     # shard_map TRANSPOSE of a replicated input inserts exactly that (psum

@@ -311,7 +311,9 @@ def test_tpu_compile_olmoe_step_of_the_cell(monkeypatch):
     depth 2, 8 micro-batches of one sequence of 4,096, bf16 parameters and
     moments, full recomputation) compiles for one v5e inside its 15.75 GB,
     with the experts' products as the Pallas calls: three forward, three
-    recomputed and six backward in the layer scan's body."""
+    recomputed and six backward in the micro-batch loops' bodies, which
+    sit inside the layer scans (PR 29: 14.38 GB in all where micro-batches
+    outside needed 14.96, and no sum over the whole stack's gradient)."""
     import dataclasses
 
     import paddle_tpu as paddle
@@ -341,8 +343,14 @@ def test_tpu_compile_olmoe_step_of_the_cell(monkeypatch):
     ma = compiled.memory_analysis()
     total = (ma.argument_size_in_bytes + ma.output_size_in_bytes
              + ma.temp_size_in_bytes - ma.alias_size_in_bytes)
-    assert total < 15.75e9, ma
+    print(f"compiled step: {total / 1e9:.2f} GB in all "
+          f"({ma.argument_size_in_bytes / 1e9:.2f} arguments, "
+          f"{ma.temp_size_in_bytes / 1e9:.2f} temporaries)")
+    assert total < 14.6e9 < 15.75e9, ma
     text = compiled.as_text()
+    # the running sum over the micro-batches is one layer's gradient
+    assert re.findall(r"%select_add_fusion[\w.\-]* = bf16\[64,", text)
+    assert not re.findall(r"%select_add_fusion[\w.\-]* = bf16\[2,64,", text)
     assert len(re.findall(r"%moe_gmm[\w.\-]* = ", text)) == 9
     assert len(re.findall(r"%moe_tgmm[\w.\-]* = ", text)) == 3
     assert not re.findall(r"%ragged-dot-none[\w.\-]* = ", text)
