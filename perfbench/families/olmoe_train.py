@@ -1,18 +1,38 @@
-"""OLMoE trained by ``HybridPipelineTrainer``, the program's normal entry
-point for compiled training and the one ``families/gpt_train.py`` uses: the
-model is ``paddle_tpu.models.GPT`` with the block's architecture fields at
-OLMoE's values (``GPTConfig.olmoe_1b_7b()``), its sizes from the
-configuration file under the keys of HF's ``config.json``. Every other knob of the trainer stays at the program's
-default. A program without those fields (the parent of the PR that brought
-them) fails in ``build`` at once, before any weight is made.
+"""OLMoE trained by ``HybridPipelineTrainer``, as ``families/gpt_train.py``
+trains GPT: the model is ``paddle_tpu.models.GPT`` with the block's
+architecture fields at OLMoE's values (``GPTConfig.olmoe_1b_7b()``), its
+sizes from the configuration file under the keys of HF's ``config.json``.
+A program without those fields (the parent of the PR that brought them)
+fails in ``build`` at once, before any weight is made.
 """
 from __future__ import annotations
 
-import time
+import functools
 
-import numpy as np
+from perfbench import train_loop
 
-from perfbench import loader
+
+def check_widths(c: dict) -> None:
+    """OLMoE's own: full multi-head attention over the hidden size, and
+    ``ffn_hidden_size`` the width one token passes through."""
+    if c["num_attention_heads"] * c["head_dim"] != c["hidden_size"]:
+        raise ValueError(
+            f"num_attention_heads {c['num_attention_heads']} x head_dim "
+            f"{c['head_dim']} is not hidden_size {c['hidden_size']}")
+    if c["num_key_value_heads"] != c["num_attention_heads"]:
+        raise ValueError(
+            f"num_key_value_heads {c['num_key_value_heads']} is not "
+            f"num_attention_heads {c['num_attention_heads']}")
+    if c["num_experts_per_tok"] * c["intermediate_size"] \
+            != c["ffn_hidden_size"]:
+        raise ValueError(
+            f"num_experts_per_tok {c['num_experts_per_tok']} x "
+            f"intermediate_size {c['intermediate_size']} is not "
+            f"ffn_hidden_size {c['ffn_hidden_size']}")
+    if c["num_heads"] != c["num_attention_heads"]:
+        raise ValueError(f"num_heads {c['num_heads']} is not "
+                         f"num_attention_heads {c['num_attention_heads']}")
+
 
 def model_config(c: dict):
     from paddle_tpu.models import GPTConfig
@@ -43,109 +63,29 @@ def model_config(c: dict):
 
 def build(ctx, n_micro: int):
     import paddle_tpu as paddle
-    from paddle_tpu.distributed.fleet import DistributedStrategy
-    from paddle_tpu.distributed.hybrid import HybridPipelineTrainer
-    from paddle_tpu.distributed.mesh import create_mesh
     from paddle_tpu.models import GPT
 
-    t = ctx.config["trainer"]
     cfg = model_config(ctx.config)
     paddle.seed(ctx.seed31)
-    model = GPT(cfg)
-    opt = paddle.optimizer.AdamW(t["learning_rate"],
-                                 parameters=model.parameters())
-    s = DistributedStrategy()
-    s.amp = t["amp"]
-    s.recompute = t["recompute"]
-    axes = {"dp": 1, "pp": 1, "tp": 1, "sp": 1, **t["mesh"]}
-    if int(np.prod(list(axes.values()))) != len(ctx.devices):
-        raise ValueError(f"mesh {t['mesh']} is not the cell's "
-                         f"{len(ctx.devices)} chips")
-    mesh = create_mesh(axes, list(ctx.devices))
-    tr = HybridPipelineTrainer(model, opt, s, mesh, n_micro=n_micro,
-                               param_dtype=t["param_dtype"],
-                               moment_dtype=t["moment_dtype"],
-                               free_eager=t["free_eager"])
-    return tr, opt
+    return train_loop.hybrid_trainer(ctx, GPT(cfg), n_micro)
 
 
-def run(ctx) -> dict:
+def limits(c: dict) -> dict:
+    return {"vocab_size": c["vocab_size"],
+            "max_seq_len": c["max_position_embeddings"]}
+
+
+def facts_after(ctx, tr) -> dict:
+    """What the window's last step routed, outputs of that step: the
+    fullest expert's rows over the mean expert's, averaged over the
+    step's expert-layer calls."""
     import jax
 
-    gen = loader.load_module("generators", ctx.traffic["generator"])
-    work = gen.generate(ctx.traffic, ctx.seed, ctx.seconds,
-                        {"vocab_size": ctx.config["vocab_size"],
-                         "max_seq_len":
-                         ctx.config["max_position_embeddings"]})
-    tr, opt = build(ctx, work["n_micro"])
-    built_peak = max(int((d.memory_stats() or {})
-                         .get("peak_bytes_in_use", 0)) for d in ctx.devices)
-
-    step_no = 0
-
-    def one_step():
-        nonlocal step_no
-        with ctx.span("batch"):
-            tokens = work["batch"](step_no)
-        step_no += 1
-        with ctx.span("step"):
-            return float(jax.block_until_ready(tr.step(tokens)))
-
-    warm = [one_step() for _ in range(ctx.traffic["warm_steps"])]
-
-    t_open = ctx.open_window()
-    losses, ends, first = [], [], 0
-    traced = ctx.traffic["traced_steps"] if ctx.trace else 0
-    if traced:
-        ctx.start_trace()
-    while time.perf_counter() - t_open < ctx.seconds:
-        losses.append(one_step())
-        ends.append(time.perf_counter())
-        if traced and len(ends) == traced:
-            # a traced step is slower, and stopping the profiler takes
-            # time that is no step's: the rate is taken from here on
-            ctx.stop_trace()
-            traced, first, t_open = 0, len(ends), time.perf_counter()
-    t_close = ends[-1]
-    # what the window's last step routed, outputs of that step: the
-    # fullest expert's rows over the mean expert's, averaged over the
-    # step's expert-layer calls
     routed = jax.device_get(tr.aux_stats)
-    stats = [d.memory_stats() or {} for d in ctx.devices]
-    live = max(int(s.get("bytes_in_use", 0)) for s in stats)
-    peak = max(int(s.get("peak_bytes_in_use", 0)) for s in stats)
-    n_steps = len(ends) - first
-    step_s = np.diff([t_open] + ends[first:])
-    tokens_per_s = n_steps * work["tokens_per_step"] / (t_close - t_open)
-
-    check = loader.load_module("checks", ctx.config["family"])
-    verdict = check.check(ctx, tr, opt, work, warm + losses)
-    compiles = ctx.compiles_in(ctx.t_open, t_close)
-    facts = {"tokens_per_s": tokens_per_s, "steps": n_steps,
-             "tokens_per_step": work["tokens_per_step"],
-             "seq": work["seq"], "micro": work["micro"],
-             "n_micro": work["n_micro"],
-             "step_s_p50": float(np.median(step_s)),
-             "step_s_max": float(np.max(step_s)),
-             "traced_steps": ctx.traffic["traced_steps"],
-             "compiles_in_window": compiles,
-             "built_peak_bytes": built_peak, "peak_bytes": peak,
-             "live_bytes": live}
-    facts["moe_expert_load_max_over_mean"] = float(
+    return {"moe_expert_load_max_over_mean": float(
         routed["moe/load_max"] * ctx.config["num_experts"]
-        / routed["moe/assigned"])
-    return {
-        "correct": verdict["ok"] and compiles == 0,
-        "attempted": len(ends), "failed": 0,
-        "end_to_end": {"train_tokens_per_s_per_chip":
-                       tokens_per_s / len(ctx.devices)},
-        "facts": facts,
-        "notes": [f"losses {warm[0]:.4f} -> {losses[-1]:.4f} over "
-                  f"{len(warm) + len(losses)} steps; step p50 "
-                  f"{np.median(step_s) * 1e3:.1f} ms, longest "
-                  f"{np.max(step_s) * 1e3:.1f} (step "
-                  f"{int(np.argmax(step_s))} of {n_steps}); built peak "
-                  f"{built_peak / 1e9:.2f} GB, peak after the window "
-                  f"{peak / 1e9:.2f} GB, in use between steps "
-                  f"{live / 1e9:.2f} GB; {verdict['note']}"],
-    }
+        / routed["moe/assigned"])}
+
+
+run = functools.partial(train_loop.run, build=build, limits=limits,
+                        facts_after=facts_after)

@@ -4,6 +4,10 @@ Nothing is registered anywhere: ``configs/<name>.json`` names a ``family``
 found as ``families/<family>.py``, ``traffic/<name>.json`` names a
 ``generator`` found as ``generators/<generator>.py``, a per-layer metric is
 ``layer_metrics/<name>.py`` and a family's check is ``checks/<family>.py``.
+A family says what its model is and what its sizes must satisfy: ``build``,
+``limits``, ``check_widths(config)`` and what else its loop asks, and
+``run``, that loop bound to them. The loops, the window and the reductions
+are ``serve_loop.py``'s and ``train_loop.py``'s, once.
 A later PR adds files and ``BENCHMARK.json`` entries and edits nothing here.
 """
 from __future__ import annotations

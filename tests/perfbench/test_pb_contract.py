@@ -1,7 +1,12 @@
 """BENCHMARK.json against the rules its readers hold it to, and against the
 files its names must find."""
+import ast
+import contextlib
 import os
 import re
+import shutil
+import subprocess
+import sys
 
 import pytest
 
@@ -81,17 +86,28 @@ def test_metrics_fit_their_cells(bench):
         assert any(c in cells_of(m) for m in bench["per_layer"])
 
 
+def config_file_is_sound(entry: dict, cfg: dict) -> None:
+    """What every configuration file is held to: it is the entry's, it
+    says what was cut and assumed, its family, check and reference are
+    found, and its sizes hang together **as its family says they must**
+    (``check_widths`` raises with the key's name). No width identity
+    lives here: which widths multiply out to which is the architecture's
+    business (the tests at the end of this file)."""
+    assert cfg["name"] == entry["name"] and cfg["source"]
+    assert cfg["reduced"] == entry["reduced"] and "assumed" in cfg
+    family = loader.load_module("families", cfg["family"])
+    loader.load_module("checks", cfg["family"])
+    loader.load_module("references", cfg["reference"])
+    assert callable(getattr(family, "check_widths", None)), \
+        f"families/{cfg['family']}.py exports no check_widths(config)"
+    assert callable(family.run)
+    family.check_widths(cfg)
+
+
 def test_every_name_finds_its_file(bench):
     for c in bench["configs"]:
         assert c["file"].startswith("perfbench/configs/")
-        cfg = loader.load_json(loader.root_file(c["file"]))
-        assert cfg["name"] == c["name"] and cfg["source"]
-        assert cfg["reduced"] == c["reduced"] and "assumed" in cfg
-        loader.load_module("families", cfg["family"])
-        loader.load_module("checks", cfg["family"])
-        loader.load_module("references", cfg["reference"])
-        assert cfg["hidden_size"] == cfg["num_heads"] * cfg["head_dim"]
-        assert cfg["ffn_hidden_size"] == 4 * cfg["hidden_size"]
+        config_file_is_sound(c, loader.load_json(loader.root_file(c["file"])))
     files = [c["file"] for c in bench["configs"]]
     assert len(files) == len(set(files))
     used = {w["config"] for w in bench["workloads"]}
@@ -140,3 +156,182 @@ def test_a_name_with_no_file_is_an_error_that_names_the_directory():
         loader.load_data("traffic", "no-such-mix")
     with pytest.raises(loader.NotFound, match=r"BENCHMARK.json"):
         loader.load_cell("no-such-cell")
+
+
+# --- what a family is: its model and what its sizes must satisfy; the
+# --- loops, the window and the reductions are the harness's, once
+
+HERE = os.path.dirname(os.path.abspath(__file__))
+#: the three the benchmark shipped with when the door was opened: what is
+#: said of *them* is said by name, so that a fourth changes nothing here
+SHIPPED = ("gpt_serve", "gpt_train", "olmoe_train")
+#: whatever is there now: only what every family must hold is asked of it
+FAMILIES = sorted(f[:-3] for f in os.listdir(os.path.join(
+    loader.HERE, "families")) if f.endswith(".py"))
+GPT_KEYS = ("hidden_size", "num_heads", "head_dim", "ffn_hidden_size")
+OLMOE_KEYS = ("hidden_size", "num_attention_heads", "head_dim",
+              "num_key_value_heads", "num_experts_per_tok",
+              "intermediate_size", "ffn_hidden_size", "num_heads")
+WIDTH_KEYS = [(name, key) for name, keys in (
+    ("gpt3-1.3b-train", GPT_KEYS), ("gpt3-1.3b-serve", GPT_KEYS),
+    ("gpt3-6.7b-train", GPT_KEYS), ("olmoe-1b-7b-train", OLMOE_KEYS))
+    for key in keys]
+
+
+def toy_gqa():
+    return loader.load_json(os.path.join(HERE, "toy_gqa", "configs",
+                                         "toy-gqa.json"))
+
+
+def copy_with_toy_gqa(dst: str) -> None:
+    """``perfbench/`` copied to ``dst`` with the toy family's files added
+    beside the shipped ones: a family arrives as files, with no edit to
+    one that is there."""
+    shutil.copytree(loader.HERE, dst,
+                    ignore=shutil.ignore_patterns("__pycache__"))
+    for kind in ("families", "checks"):
+        shutil.copy(os.path.join(HERE, "toy_gqa", kind, "toy_gqa.py"),
+                    os.path.join(dst, kind, "toy_gqa.py"))
+
+
+@contextlib.contextmanager
+def loader_at_a_copy_with_toy_gqa(tmp_path):
+    """The loader pointed at such a copy. What it loads from there is
+    forgotten on the way out: ``load_module`` keeps what it loaded in
+    ``sys.modules``, and a later test must not be handed a module of a
+    copy that is gone."""
+    dst, real = str(tmp_path / "perfbench"), loader.HERE
+    copy_with_toy_gqa(dst)
+    loader.HERE = dst
+    try:
+        yield dst
+    finally:
+        loader.HERE = real
+        for name in loaded_from(dst):
+            del sys.modules[name]
+
+
+def loaded_from(directory: str) -> list:
+    return [name for name, mod in list(sys.modules.items())
+            if name.startswith("perfbench.")
+            and (getattr(mod, "__file__", None) or "").startswith(directory)]
+
+
+@pytest.fixture
+def with_toy_gqa(tmp_path):
+    with loader_at_a_copy_with_toy_gqa(tmp_path) as dst:
+        yield dst
+
+
+def test_what_a_copy_loaded_is_forgotten_with_it(tmp_path):
+    with loader_at_a_copy_with_toy_gqa(tmp_path) as dst:
+        family = loader.load_module("families", "toy_gqa")
+        loader.load_module("checks", "toy_gqa")
+        assert family.__file__.startswith(dst) and loaded_from(dst)
+    assert not loaded_from(dst) and loader.HERE != dst
+
+
+def test_widths_that_are_not_gpts_pass_given_a_family_that_takes_them(
+        with_toy_gqa):
+    """Query heads x head size = 2 x hidden, fewer key/value heads than
+    query heads, a gated FFN that is not 4 x hidden, a few of several
+    experts a token: Solar-Open2's published 4,096 / 64 x 128 / 8 /
+    8 of 320 x 1,280 are of this shape."""
+    cfg = toy_gqa()
+    assert cfg["num_attention_heads"] * cfg["head_dim"] == \
+        2 * cfg["hidden_size"]
+    assert cfg["num_key_value_heads"] < cfg["num_attention_heads"]
+    assert cfg["intermediate_size"] != 4 * cfg["hidden_size"]
+    config_file_is_sound({"name": "toy-gqa", "reduced": []}, cfg)
+    for key, value in (("num_key_value_heads", 3),
+                       ("num_experts_per_tok", 9)):
+        with pytest.raises(ValueError, match=key):
+            config_file_is_sound({"name": "toy-gqa", "reduced": []},
+                                 {**cfg, key: value})
+
+
+@pytest.mark.parametrize("family", SHIPPED)
+def test_the_shipped_families_refuse_those_widths(family):
+    """A family's identities are its own architecture's: each of the
+    three refuses the toy's file, by a key's name or for the lack of a
+    key it reads. Said of those three by name: a fourth family may well
+    take the toy."""
+    check = loader.load_module("families", family).check_widths
+    with pytest.raises((ValueError, KeyError),
+                       match="hidden_size|num_heads|ffn_hidden_size"):
+        check(toy_gqa())
+
+
+@pytest.mark.parametrize("name,key", WIDTH_KEYS)
+def test_a_changed_width_is_refused_with_its_key_in_the_message(
+        bench, name, key):
+    """Nothing was loosened for a file that is in the benchmark: one width
+    changed, whichever, and its family refuses the file."""
+    entry = next(c for c in bench["configs"] if c["name"] == name)
+    cfg = loader.load_json(loader.root_file(entry["file"]))
+    config_file_is_sound(entry, cfg)
+    with pytest.raises(ValueError, match=rf"\b{key}\b"):
+        config_file_is_sound(entry, {**cfg, key: cfg[key] * 2})
+
+
+def family_is_only_a_model(path: str) -> None:
+    """What every file under ``families/`` is held to, a later PR's too:
+    no loop over ticks or steps, no window, no profiler, no clock, and
+    nothing read of the program whose name begins with ``_``."""
+    with open(path, encoding="utf-8") as f:
+        tree = ast.parse(f.read())
+    assert not [n.lineno for n in ast.walk(tree)
+                if isinstance(n, ast.While)], "a loop over ticks or steps"
+    attrs = {n.attr for n in ast.walk(tree) if isinstance(n, ast.Attribute)}
+    assert not attrs & {"open_window", "start_trace", "stop_trace",
+                        "read_trace", "perf_counter"}
+    private = {a for a in attrs
+               if a.startswith("_") and not a.startswith("__")}
+    assert not private, f"reads insides of the program: {sorted(private)}"
+
+
+@pytest.mark.parametrize("family", FAMILIES)
+def test_a_family_holds_no_loop_opens_no_window_and_reads_no_insides(family):
+    family_is_only_a_model(os.path.join(loader.HERE, "families",
+                                        family + ".py"))
+    mod = loader.load_module("families", family)
+    assert callable(mod.check_widths) and callable(mod.run)
+
+
+def test_the_windows_edges_are_defined_in_the_two_loops_alone():
+    opens = []
+    for d, dirs, files in os.walk(loader.HERE):
+        dirs[:] = [x for x in dirs if x != "__pycache__"]
+        for f in files:
+            if not f.endswith(".py"):
+                continue
+            with open(os.path.join(d, f), encoding="utf-8") as src:
+                on_ctx = {n.attr for n in ast.walk(ast.parse(src.read()))
+                          if isinstance(n, ast.Attribute)
+                          and isinstance(n.value, ast.Name)
+                          and n.value.id == "ctx"}
+            if on_ctx & {"open_window", "start_trace"}:
+                opens.append(os.path.relpath(os.path.join(d, f),
+                                             loader.HERE))
+    assert sorted(opens) == ["serve_loop.py", "train_loop.py"]
+
+
+def test_a_fourth_family_dropped_beside_the_three_leaves_this_file_green(
+        tmp_path):
+    """The next ``model_config`` PR adds files and edits none: the whole
+    of this file run in a copy of the benchmark that holds the toy family
+    beside the shipped ones: the cases here and the toy's own under
+    ``test_a_family_holds_no_loop...``, all passing."""
+    copy_with_toy_gqa(str(tmp_path / "perfbench"))
+    shutil.copy(loader.root_file("BENCHMARK.json"), tmp_path)
+    shutil.copytree(HERE, tmp_path / "tests" / "perfbench",
+                    ignore=shutil.ignore_patterns("__pycache__"))
+    done = subprocess.run(
+        [sys.executable, "-m", "pytest", "tests/perfbench/" +
+         os.path.basename(__file__), "-v", "-p", "no:cacheprovider",
+         "-p", "no:randomly", "-k", "not dropped_beside"],
+        cwd=tmp_path, capture_output=True, text=True, timeout=300,
+        env={**os.environ, "JAX_PLATFORMS": "cpu"})
+    assert done.returncode == 0, done.stdout[-4000:] + done.stderr[-2000:]
+    assert re.search(r"reads_no_insides\[toy_gqa\] PASSED", done.stdout)
+    assert " failed" not in done.stdout.splitlines()[-1]

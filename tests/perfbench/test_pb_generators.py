@@ -120,3 +120,34 @@ def test_quantile_draws():
     assert sorted(draws.fixed_order(xs, 3)) == xs
     assert draws.fixed_order(xs, 3) == draws.fixed_order(xs, 3) != xs
     assert draws.turned([1, 2, 3, 4], 6) == [3, 4, 1, 2]
+
+
+def head_digest(requests) -> str:
+    import hashlib
+
+    h = hashlib.sha256()
+    for r in requests:
+        h.update(np.int64([len(r["prompt"]), r["max_new"]]).tobytes())
+        h.update(np.asarray(r["prompt"], np.int32).tobytes())
+    return h.hexdigest()
+
+
+@pytest.mark.parametrize("seed,digest", [
+    (1, "ecb56dd54a3650b056647023979c9d4fd12a5568a67e5f70a0c5cd21974a01f8"),
+    (2500000033,
+     "6bfe42064e646c8148e69bc2ef584770877ea415be576f8ea612308288819a1c"),
+    (2 ** 31 + 7,
+     "692b6217cfdf36ca82bc2ecb38a3e74dd775a7f544540b831844fe914694cb9a"),
+])
+def test_the_deeper_backlog_starts_with_the_50_requests_it_had(seed, digest):
+    """PR 30 raised ``requests`` from 50 to 250 so that a faster engine
+    does not run dry. The digests are of the 50 requests (lengths,
+    ``max_new``, tokens) that PR 29's tree queued, computed on that tree:
+    what a window reaches first is what it reached before."""
+    params, p = plan("longprompt-backlog", seed)
+    assert params["requests"] == 250 == len(p["requests"])
+    assert head_digest(p["requests"][:50]) == digest
+    # 335 k tokens over the ~64 s a run drives the engine: it runs dry
+    # only above ~5,200 tokens/s
+    assert sum(len(r["prompt"]) + r["max_new"]
+               for r in p["requests"]) == 5 * 67065

@@ -5,7 +5,12 @@ import time
 import numpy as np
 import pytest
 
-from perfbench import harness, loader
+from perfbench import harness, serve_loop as serve
+
+
+def NOTHING_ON_A_DEVICE(engine):
+    """The family's ``device_state``: what ``Drive.mark`` waits for."""
+    return ()
 
 
 class SlowEngine:
@@ -44,12 +49,14 @@ class SlowEngine:
     def drain(self, target=0):
         pass
 
+    def tokens_so_far(self, rid):
+        return tuple(self._requests[rid].out)
+
     def idle(self):
         return all(len(r.out) >= r.max_new for r in self._requests.values())
 
 
 def test_ttft_runs_from_the_due_time_when_the_loop_submits_late():
-    serve = loader.load_module("families", "gpt_serve")
     step_s = 0.05
     plan = {"mode": "open", "warm_in_s": 0.0, "drain_limit_s": 5.0,
             "requests": [
@@ -59,7 +66,7 @@ def test_ttft_runs_from_the_due_time_when_the_loop_submits_late():
                  "prompt": np.zeros(8, np.int32)}]}
     cell = {"cell": {}, "config": {}, "traffic": {}}
     ctx = harness.Context(cell, seed=1, seconds=1.0, trace=False, devices=[])
-    drive = serve.Drive(ctx, SlowEngine(step_s), plan)
+    drive = serve.Drive(ctx, SlowEngine(step_s), plan, NOTHING_ON_A_DEVICE)
     t0 = time.perf_counter()
     drive.run(t0, lambda now: False)
     r = serve.reduce(plan, drive, t0, t0, 1.0, 8)
@@ -83,14 +90,13 @@ def test_ttft_runs_from_the_due_time_when_the_loop_submits_late():
 
 
 def test_closed_backlog_judges_what_left_the_engine_in_the_window():
-    serve = loader.load_module("families", "gpt_serve")
     plan = {"mode": "closed", "warm_in_s": 0.0, "drain_limit_s": 0.0,
             "requests": [{"due_s": 0.0, "max_new": n,
                           "prompt": np.zeros(4, np.int32)}
                          for n in (2, 4, 40)]}
     cell = {"cell": {}, "config": {}, "traffic": {}}
     ctx = harness.Context(cell, seed=1, seconds=0.2, trace=False, devices=[])
-    drive = serve.Drive(ctx, SlowEngine(0.01), plan)
+    drive = serve.Drive(ctx, SlowEngine(0.01), plan, NOTHING_ON_A_DEVICE)
     t0 = time.perf_counter()
     drive.run(t0, lambda now: now >= 0.2)
     assert serve.reduce(plan, drive, t0, t0, 0.2, 8)["mine"] == [0, 1]
