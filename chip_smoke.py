@@ -17,6 +17,12 @@ finds, and checks what comes out by the repo's own means:
               to over four times its share: nothing is dropped, and the
               grouped products took the path production takes here, the
               Pallas kernels moe_gmm/moe_tgmm
+  1c solar    one step of the small Solar-Open2 preset (a period of one
+              gated grouped-query softmax layer and three gated delta-rule
+              layers, 16 experts of which 8 held) through the trainer in
+              bf16: the loss against the float32 reference, and the scan
+              took the path production takes here, the Pallas kernels
+              kda_fwd/kda_bwd_*
   2 serve     ServingEngine.submit/step/run, ten requests over ~150 ticks
   3 train     HybridPipelineTrainer.step, bench.py's headline knobs
   4 multichip the same trainer on dp2 x tp2 and pp2 x tp2, and the ZeRO /
@@ -716,6 +722,117 @@ def phase_multichip(cfg, micro: int, n_micro: int, loss0: float,
 
 
 # ---------------------------------------------------------------------------
+# phase 1c: the small Solar-Open2 through the trainer
+# ---------------------------------------------------------------------------
+# the trainer's bf16 loss against models/solar_open2_reference.loss in f32
+# on the same weights: a mean over a few hundred positions of a model whose
+# products take bf16 operands (seen on the v5e, PR 31: 2e-4)
+TOL_SOLAR_LOSS = 2e-3
+# the scan alone, bf16 operands, output and the five gradients against
+# autodiff of the token-by-token recurrence in float32, ||difference|| /
+# ||reference|| (seen on the v5e, PR 31: 4.6e-3 to 4.9e-3; the decay's
+# gradient, a difference of large terms, 2.0e-2)
+TOL_SOLAR_SCAN = {"o": 2e-2, "dq": 2e-2, "dk": 2e-2, "dv": 2e-2, "dg": 6e-2,
+                  "dbeta": 2e-2}
+
+
+def scan_against_recurrence(seq: int) -> dict:
+    """``kda_attention`` forward and backward (on the chip: ``kda_fwd``,
+    ``kda_bwd_states``, ``kda_bwd_grads``) with decays from none down to
+    the floor ``G_MIN``, against ``jax.vjp`` of ``kda_recurrent``."""
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.ops import kda
+
+    ks = jax.random.split(jax.random.PRNGKey(3), 6)
+    shape = (1, seq, 4, 128)
+    unit = lambda a: a / jnp.linalg.norm(a, axis=-1, keepdims=True)
+    q, k = (unit(jax.random.normal(ks[i], shape)) for i in (0, 1))
+    v, do = (jax.random.normal(ks[i], shape) for i in (2, 3))
+    g = jax.random.uniform(ks[4], shape, minval=kda.G_MIN, maxval=-1e-3)
+    beta = 2 * jax.nn.sigmoid(jax.random.normal(ks[5], shape[:3]))
+    bf = lambda a: a.astype(jnp.bfloat16)
+    got, vjp = jax.vjp(kda.kda_attention, bf(q), bf(k), bf(v), g, beta)
+    with jax.default_matmul_precision("highest"):
+        want, want_vjp = jax.vjp(kda.kda_recurrent, q, k, v, g, beta)
+        wants = (want,) + want_vjp(do)
+    rel = {n: float(jnp.linalg.norm(a.astype(jnp.float32) - w)
+                    / jnp.linalg.norm(w))
+           for n, a, w in zip(TOL_SOLAR_SCAN, (got,) + vjp(bf(do)), wants)}
+    for n, r in rel.items():
+        check(r <= TOL_SOLAR_SCAN[n],
+              f"solar: the scan's {n} is {r:.2e} from the recurrence's "
+              f"(tol {TOL_SOLAR_SCAN[n]})")
+    return rel
+
+
+def phase_solar(seq: int, scan_path: str) -> dict:
+    """One step at learning rate 0 of ``SolarOpen2Config.tiny`` (heads of
+    128, so the Pallas scan takes them) with experts 4..11 of 16 held."""
+    import jax
+
+    import paddle_tpu as paddle
+    from paddle_tpu import profiler
+    from paddle_tpu.distributed.fleet import DistributedStrategy
+    from paddle_tpu.distributed.hybrid import HybridPipelineTrainer
+    from paddle_tpu.distributed.mesh import create_mesh
+    from paddle_tpu.models import solar_open2_reference as ref
+    from paddle_tpu.models.solar_open2 import SolarOpen2, SolarOpen2Config
+    from paddle_tpu.static.functional import state_tensors
+
+    held = (4, 8)
+    paddle.seed(7)
+    cfg = SolarOpen2Config.tiny(experts_held=held)
+    model = SolarOpen2(cfg)
+    layers = [{k: np.asarray(v._value) for k, v in
+               zip(*state_tensors(layer)[:2])}
+              for period in model.periods for layer in period.layers]
+    names, tensors = state_tensors(model)[:2]
+    other = {n: np.asarray(t._value) for n, t in zip(names, tensors)
+             if not n.startswith("periods.")}
+    tokens = np.random.default_rng(0).integers(0, cfg.vocab_size, (2, seq),
+                                               dtype=np.int32)
+    rc = dict(heads=cfg.num_attention_heads,
+              kv_heads=cfg.num_key_value_heads, head_dim=cfg.head_dim,
+              linear_heads=cfg.linear_attn_num_heads,
+              linear_head_dim=cfg.linear_attn_head_dim,
+              top_k=cfg.num_experts_per_tok, eps=cfg.rms_norm_eps)
+    with jax.default_matmul_precision("highest"):
+        want = float(np.mean([ref.loss(layers, other, tokens[i:i + 1], rc,
+                                       held) for i in range(2)]))
+    s = DistributedStrategy()
+    s.amp = True
+    mesh = create_mesh({"dp": 1, "pp": 1, "tp": 1, "sp": 1},
+                       jax.devices()[:1])
+    profiler.reset()
+    tr = HybridPipelineTrainer(
+        model, paddle.optimizer.SGD(0.0, parameters=model.parameters()), s,
+        mesh, n_micro=2, param_dtype="bfloat16")
+    got = float(tr.step(tokens))
+    stats = jax.device_get(tr.aux_stats)
+    calls = {k: v["value"] for k, v in profiler.summary()["metrics"].items()
+             if k.startswith("kda/scan_calls")}
+    rel = abs(got - want) / abs(want)
+    check(rel <= TOL_SOLAR_LOSS, f"solar: the trainer's loss {got:.5f} is "
+          f"{rel:.2e} from the reference's {want:.5f}")
+    check(set(calls) == {"kda/scan_calls{path=%s}" % scan_path},
+          f"solar: the scan took {sorted(calls)}, not the {scan_path} path")
+    check(stats["moe/routed"] == tokens.size * cfg.num_experts_per_tok * 4
+          and stats["moe/assigned"] == stats["moe/rows"].sum(),
+          f"solar: the step's counts do not add up: {stats}")
+    say("solar", f"tiny Solar-Open2, 2 x {seq} tokens, bf16: loss {got:.5f}"
+        f" vs float32 reference {want:.5f} (rel {rel:.2e}, tol "
+        f"{TOL_SOLAR_LOSS}); scan through {scan_path}; rows held "
+        f"{int(stats['moe/assigned'])} of {int(stats['moe/routed'])} routed")
+    scan = scan_against_recurrence(seq)
+    say("solar", "the scan forward and backward against the recurrence, g "
+        "down to its floor: " + ", ".join(
+            f"{n} {r:.1e}" for n, r in scan.items()))
+    return {"loss": got, "rel": rel, "scan": scan}
+
+
+# ---------------------------------------------------------------------------
 def main() -> int:
     from paddle_tpu.utils.compile_cache import (cache_entries,
                                                 enable_compile_cache)
@@ -753,6 +870,7 @@ def main() -> int:
     run("experts", lambda: phase_experts(
         olmoe.max_seq_len, olmoe.hidden_size, olmoe.moe_expert_width,
         olmoe.moe_num_experts, olmoe.moe_top_k))
+    run("solar", lambda: phase_solar(256, "pallas"))
     run("serve", lambda: phase_serve(cfg, slots, page, SERVE_REQUESTS))
     run("train", lambda: phase_train(cfg, micro=2, n_micro=6, steps=4))
     if len(jax.devices()) >= 4 and "train" not in failed:

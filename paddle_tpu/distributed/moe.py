@@ -39,6 +39,8 @@ psum over 'pp', per-microbatch mean) — see distributed/hybrid.py.
 """
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -269,16 +271,167 @@ def _expert_product(rows, w, group_sizes):
     return _gmm.grouped_matmul(rows, w.astype(rows.dtype), group_sizes)
 
 
+def _swiglu_rows(xs, gate_of_row, w_gate, w_up, w_down, sizes, live):
+    """The held experts on one window of sorted rows, each row times its
+    routing weight; rows past the groups' end are zeros, whatever the
+    kernels left there."""
+    mid = jax.nn.silu(_expert_product(xs, w_gate, sizes)) * \
+        _expert_product(xs, w_up, sizes)
+    ys = _expert_product(mid, w_down, sizes)
+    return jnp.where(live, ys * gate_of_row.astype(ys.dtype)[:, None], 0)
+
+
+def _held_window(token_of_row, gate_of_row, group_sizes, p, width):
+    """Window ``p`` of the sorted rows: the tokens and weights of its
+    ``width`` rows, the part of every group that lies in it, and which of
+    its rows exist."""
+    lo = p * width
+    ends = jnp.cumsum(group_sizes)
+    starts = ends - group_sizes
+    sizes = jnp.clip(ends - lo, 0, width) - jnp.clip(starts - lo, 0, width)
+    live = (lo + jnp.arange(width, dtype=jnp.int32) < ends[-1])[:, None]
+    return (jax.lax.dynamic_slice(token_of_row, (lo,), (width,)),
+            jax.lax.dynamic_slice(gate_of_row, (lo,), (width,)),
+            sizes.astype(jnp.int32), live)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(7,))
+def _held_experts(x, gate_of_row, w_gate, w_up, w_down, token_of_row,
+                  group_sizes, width):
+    """``y[t] = sum over the rows r of token t of gate_of_row[r] *
+    expert(r)(x[t])`` for the assignments this chip holds, sorted by
+    expert: ``token_of_row`` and ``gate_of_row`` [n_max] (``n_max`` a
+    multiple of ``width``), ``group_sizes`` [held] the rows of each.
+
+    Exact for any routing at the cost of the rows there are: the rows go
+    through in windows of ``width`` (gather, three grouped products,
+    scatter-add), the first always, the others while rows are left, so
+    the buffers are ``[width, H]`` and a routing that sends everything
+    here takes ``n_max / width`` rounds, not more memory. The backward
+    pass walks the same windows and recomputes each."""
+    def add(p, y):
+        tok, gt, sizes, live = _held_window(token_of_row, gate_of_row,
+                                            group_sizes, p, width)
+        with _annotate("moe/dispatch"):
+            xs = jnp.where(live, x[tok], 0)
+        with _annotate("moe/experts"):
+            rows = _swiglu_rows(xs, gt, w_gate, w_up, w_down, sizes, live)
+        with _annotate("moe/combine"):
+            return y.at[tok].add(rows)
+
+    windows = (jnp.sum(group_sizes) + width - 1) // width
+    return jax.lax.fori_loop(1, windows, add, add(0, jnp.zeros_like(x)))
+
+
+def _held_fwd(x, gate_of_row, w_gate, w_up, w_down, token_of_row,
+              group_sizes, width):
+    y = _held_experts(x, gate_of_row, w_gate, w_up, w_down, token_of_row,
+                      group_sizes, width)
+    return y, (x, gate_of_row, w_gate, w_up, w_down, token_of_row,
+               group_sizes)
+
+
+def _held_bwd(width, res, dy):
+    x, gate_of_row, w_gate, w_up, w_down, token_of_row, group_sizes = res
+
+    def add(p, acc):
+        dx, dgate, dwg, dwu, dwd = acc
+        tok, gt, sizes, live = _held_window(token_of_row, gate_of_row,
+                                            group_sizes, p, width)
+        with _annotate("moe/dispatch"):
+            xs = jnp.where(live, x[tok], 0)
+        with _annotate("moe/combine"):
+            drows = dy[tok]
+        with _annotate("moe/experts"):
+            _, vjp = jax.vjp(
+                lambda xs_, gt_, wg_, wu_, wd_: _swiglu_rows(
+                    xs_, gt_, wg_, wu_, wd_, sizes, live),
+                xs, gt, w_gate, w_up, w_down)
+            dxs, dgt, g1, g2, g3 = vjp(drows)
+        with _annotate("moe/dispatch"):
+            dx = dx.at[tok].add(jnp.where(live, dxs, 0))
+        return (dx, jax.lax.dynamic_update_slice(dgate, dgt, (p * width,)),
+                dwg + g1, dwu + g2, dwd + g3)
+
+    windows = (jnp.sum(group_sizes) + width - 1) // width
+    zeros = (jnp.zeros_like(x), jnp.zeros_like(gate_of_row),
+             jnp.zeros_like(w_gate), jnp.zeros_like(w_up),
+             jnp.zeros_like(w_down))
+    dx, dgate, dwg, dwu, dwd = jax.lax.fori_loop(1, windows, add,
+                                                 add(0, zeros))
+    return dx, dgate, dwg, dwu, dwd, None, None
+
+
+_held_experts.defvjp(_held_fwd, _held_bwd)
+
+
+def held_window_rows(t: int, top_k: int, held: int, e: int) -> int:
+    """Rows a window of ``_held_experts`` takes: half above the ``t *
+    top_k * held / e`` a balanced router sends to ``held`` of ``e``
+    experts, in the grouped kernels' 128-row tiles, never more than there
+    can be."""
+    most = -(-t * min(top_k, held) // 128) * 128
+    fair = -(-3 * t * top_k * held // (2 * e))
+    return min(most, -(-fair // 128) * 128)
+
+
+def _route(x, router_w, top_k, scoring="softmax", select_bias=None,
+           held=None):
+    """The routing both drop-less layers share, in the transposed [E, T]
+    layout, T on the lanes, and ``top_k`` as rounds of argmax, as
+    ``switch_moe`` does and for its reasons: the scores, the experts and
+    gates of each round and, **a counting sort**, every assignment's place
+    within its expert's group (rows of earlier rounds, then the earlier
+    tokens of this round), kept for the experts ``held=(first, count)``
+    alone where that is given. Returns ``(probs_t [E, T], z, expert_rounds,
+    gate_rounds, pos_rounds, counts)``, ``counts`` [E] or [count] float32
+    the rows given out."""
+    e = router_w.shape[1]
+    softmax = scoring == "softmax"
+    held_rows = (lambda a: a) if held is None \
+        else (lambda a: a[held[0]:held[0] + held[1]])
+    logits_t = jnp.dot(router_w.astype(x.dtype).T, x.T,
+                       preferred_element_type=jnp.float32)       # [E, T]
+    if softmax:
+        lse = jax.nn.logsumexp(logits_t, axis=0)                 # [T]
+        probs_t = jnp.exp(logits_t - lse[None, :])
+        z = jnp.mean(jnp.square(lse))
+        remaining = probs_t
+    else:
+        probs_t = jax.nn.sigmoid(logits_t)
+        z = jnp.zeros((), jnp.float32)
+        remaining = probs_t if select_bias is None else probs_t + \
+            jax.lax.stop_gradient(select_bias).astype(jnp.float32)[:, None]
+    rows_e = jnp.arange(e, dtype=jnp.int32)[:, None]
+    counts = jnp.zeros((e if held is None else held[1],), jnp.float32)
+    expert_rounds, gate_rounds, pos_rounds = [], [], []
+    for _ in range(top_k):
+        idx = jnp.argmax(remaining, axis=0).astype(jnp.int32)    # [T]
+        onehot_t = (rows_e == idx[None, :]).astype(jnp.float32)
+        gate_rounds.append(jnp.sum(
+            (remaining if softmax else probs_t) * onehot_t, axis=0))
+        mine = held_rows(onehot_t)
+        before = jnp.cumsum(mine, axis=1) - mine
+        pos_rounds.append(jnp.sum((before + counts[:, None]) * mine,
+                                  axis=0).astype(jnp.int32))
+        counts = counts + jnp.sum(mine, axis=1)
+        expert_rounds.append(idx)
+        remaining = remaining * (1.0 - onehot_t) if softmax \
+            else jnp.where(onehot_t > 0, -jnp.inf, remaining)
+    return probs_t, z, expert_rounds, gate_rounds, pos_rounds, counts
+
+
 def dropless_moe(x, router_w, w_gate, w_up, w_down, top_k):
     """Token-choice MoE FFN with no token dropped. x: [T, H]; router_w:
     [H, E], no bias; SiLU-gated experts stacked w_gate, w_up [E, H, F] and
     w_down [E, F, H], no biases.
 
-    Router logits (float32 accumulation) and softmax in float32 over all
-    E; the ``top_k`` largest probabilities weigh their experts
-    **unrenormalised**; the ``T*top_k`` assignments are ordered by expert,
+    Softmax over all E in float32, ``top_k`` experts a token by rounds of
+    argmax, the chosen probabilities as weights **unrenormalised**. Then a
+    counting sort of the ``T * top_k`` assignments by expert (``_route``),
     rows gathered to ``[T*top_k, H]``, three grouped matmuls over the [E]
     group sizes, gate-weighted un-sort and sum over ``top_k``.
+    ``held_moe`` is the layer that holds a share of its experts.
 
     Returns ``(y [T, H], balance, z, rows)``: ``rows`` [E] int32 are the
     assignments each expert was given, what the grouped matmuls are told
@@ -292,30 +445,9 @@ def dropless_moe(x, router_w, w_gate, w_up, w_down, top_k):
     t, h = x.shape
     e = router_w.shape[1]
     n = t * top_k
-    # routing in the transposed [E, T] layout, T on the lanes, and top_k
-    # as rounds of argmax, as switch_moe does and for its reasons
     with _annotate("moe/route"):
-        logits_t = jnp.dot(router_w.astype(x.dtype).T, x.T,
-                           preferred_element_type=jnp.float32)   # [E, T]
-        lse = jax.nn.logsumexp(logits_t, axis=0)                 # [T]
-        probs_t = jnp.exp(logits_t - lse[None, :])
-        z = jnp.mean(jnp.square(lse))
-        rows_e = jnp.arange(e, dtype=jnp.int32)[:, None]
-        remaining = probs_t
-        counts = jnp.zeros((e,), jnp.float32)    # rows given out so far
-        expert_rounds, gate_rounds, pos_rounds = [], [], []
-        for _ in range(top_k):
-            idx = jnp.argmax(remaining, axis=0).astype(jnp.int32)  # [T]
-            onehot_t = (rows_e == idx[None, :]).astype(jnp.float32)
-            gate_rounds.append(jnp.sum(remaining * onehot_t, axis=0))
-            # place within the expert's group: rows of earlier rounds,
-            # then the earlier tokens of this round (a counting sort)
-            before = jnp.cumsum(onehot_t, axis=1) - onehot_t
-            pos_rounds.append(jnp.sum((before + counts[:, None]) * onehot_t,
-                                      axis=0).astype(jnp.int32))
-            counts = counts + jnp.sum(onehot_t, axis=1)
-            expert_rounds.append(idx)
-            remaining = remaining * (1.0 - onehot_t)
+        probs_t, z, expert_rounds, gate_rounds, pos_rounds, counts = \
+            _route(x, router_w, top_k)
         balance = e * jnp.sum((counts / t) * jnp.mean(probs_t, axis=1))
         group_sizes = counts.astype(jnp.int32)                   # [E]
         starts = jnp.cumsum(group_sizes) - group_sizes
@@ -343,6 +475,69 @@ def dropless_moe(x, router_w, w_gate, w_up, w_down, top_k):
                             round_of_row, jnp.ones((n,), bool))
     return (y, balance.astype(jnp.float32), z.astype(jnp.float32),
             group_sizes)
+
+
+def held_moe(x, router_w, w_gate, w_up, w_down, top_k, held,
+             scoring="sigmoid", select_bias=None, shared=None):
+    """``dropless_moe``'s sibling that **holds a share of its experts**, as
+    one rank of an expert-parallel layout does: experts ``first : first +
+    count`` of the router's E, ``held=(first, count)``, weights ``[count,
+    H, F]``. It shares the routing (``_route``, over all E) and nothing
+    after it, because what follows must cost what the rows held cost and
+    not ``T * top_k``: the held assignments' rows, sorted by expert, go
+    through ``_held_experts`` in windows (gather, the three grouped
+    products, scatter-add), where the full layer's gather-only dispatch and
+    combine read every assignment's row. Every assignment to a held expert
+    is computed, whatever share of the ``T * top_k`` falls here; what the
+    absent experts would add is left out.
+
+    ``scoring="sigmoid"``: the scores are ``sigmoid(logits)``, the
+    ``top_k`` largest of ``score + select_bias`` [E] are chosen (the bias
+    selects only and takes no gradient) and the chosen scores weigh their
+    experts **renormalised** over all ``top_k`` chosen, held or not;
+    ``"softmax"``: as ``dropless_moe``, unrenormalised.
+    ``shared=(w_gate, w_up, w_down)`` [H, F], [H, F], [F, H] adds one
+    SwiGLU expert every token passes.
+
+    Returns ``(y [T, H], rows)``, ``rows`` [count] int32 the assignments
+    each held expert was given and computed. No auxiliary term: the
+    lineage that routes so balances by the selection bias."""
+    t, h = x.shape
+    e = router_w.shape[1]
+    n = t * top_k
+    first, count = held
+    with _annotate("moe/route"):
+        _, _, expert_rounds, gate_rounds, pos_rounds, counts = _route(
+            x, router_w, top_k, scoring, select_bias, held)
+        group_sizes = counts.astype(jnp.int32)                   # [count]
+        starts = jnp.cumsum(group_sizes) - group_sizes
+        gates = jnp.stack(gate_rounds)                           # [K, T]
+        if scoring != "softmax":
+            gates = gates / jnp.sum(gates, axis=0, keepdims=True)
+        # the held assignments' rows, sorted by expert; the others land
+        # past the end, one place each, and are cut off
+        width = held_window_rows(t, top_k, count, e)
+        n_max = -(-t * min(top_k, count) // width) * width
+        local = jnp.stack(expert_rounds) - first                 # [K, T]
+        here = (local >= 0) & (local < count)
+        flat = jnp.where(
+            here, starts[jnp.clip(local, 0, count - 1)]
+            + jnp.stack(pos_rounds),
+            n_max + jnp.arange(n, dtype=jnp.int32).reshape(top_k, t)
+        ).reshape(n)
+        token_of_row = jnp.zeros((n_max + n,), jnp.int32).at[flat].set(
+            jnp.tile(jnp.arange(t, dtype=jnp.int32), top_k),
+            unique_indices=True)[:n_max]
+        gate_of_row = jnp.zeros((n_max + n,), jnp.float32).at[flat].set(
+            gates.reshape(n), unique_indices=True)[:n_max]
+    y = _held_experts(x, gate_of_row, w_gate, w_up, w_down, token_of_row,
+                      group_sizes, width)
+    if shared is not None:
+        with _annotate("moe/shared"):
+            s_gate, s_up, s_down = (w.astype(x.dtype) for w in shared)
+            y = y + jnp.dot(jax.nn.silu(jnp.dot(x, s_gate))
+                            * jnp.dot(x, s_up), s_down)
+    return y, group_sizes
 
 
 class DroplessMoEMLP(nn.Layer):
@@ -398,6 +593,86 @@ class DroplessMoEMLP(nn.Layer):
             name="dropless_moe")
         self.stats = {"moe/rows": rows, "moe/load_max": load_max,
                       "moe/assigned": assigned}
+        return y
+
+
+class HeldMoEMLP(nn.Layer):
+    """One rank's share of an expert-parallel drop-less layer
+    (``held_moe``): the router keeps ``num_experts`` outputs, the stacked
+    weights are the ``count`` experts of ``held=(first, count)``, a shared
+    SwiGLU expert of ``shared_width`` every token passes, and, under
+    ``scoring="sigmoid"``, the selection bias ``select_bias`` [E], which
+    the step never changes.
+
+    ``stats`` as ``DroplessMoEMLP``'s, of the experts held: ``moe/rows``
+    [count], ``moe/load_max``, ``moe/assigned`` (the assignments to the
+    experts held here, as there: all of this layer's rows when none is
+    dropped) and ``moe/routed``, all that were routed (tokens x
+    ``top_k``), most of them to experts elsewhere."""
+
+    def __init__(self, hidden_size: int, expert_width: int,
+                 num_experts: int, top_k: int, held,
+                 initializer_range: float = 0.02,
+                 out_initializer_range: float = 0.02,
+                 scoring: str = "sigmoid", select_bias_range: float = 0.0,
+                 shared_width: int = 0):
+        super().__init__()
+        init = I.Normal(0.0, initializer_range)
+        out_init = I.Normal(0.0, out_initializer_range)
+        e, h, f = num_experts, hidden_size, expert_width
+        self.num_experts, self.top_k = e, top_k
+        self.held, self.scoring = tuple(held), scoring
+
+        def new(name, shape, initializer=init):
+            setattr(self, name, self.create_parameter(
+                shape, default_initializer=initializer))
+
+        new("gate", [h, e])
+        new("w_gate", [held[1], h, f])
+        new("w_up", [held[1], h, f])
+        new("w_down", [held[1], f, h], out_init)
+        self.param_shardings = {
+            "gate": P(), "w_gate": P("ep", None, None),
+            "w_up": P("ep", None, None), "w_down": P("ep", None, None)}
+        self.shared = bool(shared_width)
+        if shared_width:
+            new("shared_gate", [h, shared_width])
+            new("shared_up", [h, shared_width])
+            new("shared_down", [shared_width, h], out_init)
+        self.select_bias = None
+        if scoring == "sigmoid":
+            new("select_bias", [e], I.Normal(0.0, select_bias_range))
+            # a parameter, so that the trainer stacks it a block like the
+            # rest; at learning rate 0 neither update nor decay moves it
+            self.select_bias.optimize_attr["learning_rate"] = 0.0
+        self.stats = {}
+
+    def options(self, weights: dict) -> dict:
+        """What ``held_moe`` is given beyond the stacked experts, from
+        this layer's values by their names."""
+        return {"held": self.held, "scoring": self.scoring,
+                "select_bias": weights.get("select_bias"),
+                "shared": tuple(weights["shared_" + k]
+                                for k in ("gate", "up", "down"))
+                if self.shared else None}
+
+    def forward(self, x):
+        b, s, h = x.shape[0], x.shape[1], x.shape[2]
+        names, tensors = zip(*self.named_parameters())
+
+        def f(xv, *values):
+            w = dict(zip(names, values))
+            y, rows = held_moe(
+                xv.reshape(b * s, h), w["gate"], w["w_gate"], w["w_up"],
+                w["w_down"], self.top_k, **self.options(w))
+            rows = rows.astype(jnp.float32)
+            return (y.reshape(b, s, h), rows, jnp.max(rows), jnp.sum(rows),
+                    jnp.float32(b * s * self.top_k))
+
+        y, rows, load_max, assigned, routed = apply(
+            f, x, *tensors, name="held_moe")
+        self.stats = {"moe/rows": rows, "moe/load_max": load_max,
+                      "moe/assigned": assigned, "moe/routed": routed}
         return y
 
 

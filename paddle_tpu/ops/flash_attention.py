@@ -95,11 +95,16 @@ def _pick_block(seq, cap):
     return None
 
 
-def supported(q_shape, attn_mask, dropout_p, kv_seq=None) -> bool:
-    """True when the Pallas kernel handles this case; else jnp path."""
+def supported(q_shape, attn_mask, dropout_p, kv_seq=None,
+              kv_heads=None) -> bool:
+    """True when the Pallas kernel handles this case; else jnp path.
+    ``kv_heads``: the key/value heads where they are fewer than the
+    query heads (grouped-query attention); they must divide them."""
     if attn_mask is not None or dropout_p:
         return False
     if len(q_shape) != 4:
+        return False
+    if kv_heads is not None and (kv_heads < 1 or q_shape[2] % kv_heads):
         return False
     if _pick_block(q_shape[1], _BLOCK_Q) is None:
         return False
@@ -165,20 +170,31 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
         lse_ref[0] = (m_ref[:] + jnp.log2(l_safe)) * _LN2   # [bq, 1]
 
 
+def _kv_index_maps(causal, group):
+    """Index maps of the K/V blocks on a grid (query head, q block, k
+    block): query head ``b`` reads key/value head ``b // group`` (heads
+    are flattened batch-major, so the quotient is right across batches)."""
+    if causal:
+        # dead tiles (j past the diagonal) re-reference the diagonal block;
+        # an unchanged block index between grid steps elides the DMA
+        if group == 1:
+            return lambda b, i, j: (b, jax.lax.min(j, i), 0)
+        return lambda b, i, j: (b // group, jax.lax.min(j, i), 0)
+    if group == 1:
+        return lambda b, i, j: (b, j, 0)
+    return lambda b, i, j: (b // group, j, 0)
+
+
 def _fwd(q3, k3, v3, scale, causal, block_q, block_k):
-    """q3/k3/v3: [BH, S, D] -> (o [BH, S, D], lse [BH, S, 1] f32)."""
+    """q3: [BH, S, D], k3/v3: [BH / group, S, D] -> (o [BH, S, D], lse
+    [BH, S, 1] f32)."""
     bh, sq, d = q3.shape
     sk = k3.shape[1]
     nq, nk = sq // block_q, sk // block_k
     grid = (bh, nq, nk)
     kern = functools.partial(_fwd_kernel, scale=scale, causal=causal,
                              block_q=block_q, block_k=block_k)
-    if causal:
-        # dead tiles (j past the diagonal) re-reference the diagonal block;
-        # an unchanged block index between grid steps elides the DMA
-        kv_idx = lambda b, i, j: (b, jax.lax.min(j, i), 0)
-    else:
-        kv_idx = lambda b, i, j: (b, j, 0)
+    kv_idx = _kv_index_maps(causal, bh // k3.shape[0])
     o, lse = pl.pallas_call(
         kern,
         name="flash_fwd",
@@ -262,11 +278,14 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                     dk_ref, dv_ref, dk_acc, dv_acc,
-                    *, scale, causal, block_q, block_k):
-    ik, iq = pl.program_id(1), pl.program_id(2)
-    nq = pl.num_programs(2)
+                    *, scale, causal, block_q, block_k, group=1):
+    # the innermost axis walks the q blocks of every query head of the
+    # group in turn: dK and dV sum over the group in the accumulators
+    ik, step = pl.program_id(1), pl.program_id(2)
+    last = pl.num_programs(2)
+    iq = step if group == 1 else step % (last // group)
 
-    @pl.when(iq == 0)
+    @pl.when(step == 0)
     def _init():
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
@@ -303,7 +322,7 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     else:
         _tile(False)
 
-    @pl.when(iq == nq - 1)
+    @pl.when(step == last - 1)
     def _finish():
         dk_ref[0] = dk_acc[:].astype(dk_ref.dtype)
         dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
@@ -399,16 +418,24 @@ def _bwd(scale, causal, block_q, block_k, res, do3, delta=None,
     dk_dtype = out_dtype or k3.dtype
     dv_dtype = out_dtype or v3.dtype
 
-    if nq == 1 and nk == 1:
+    group = bh // k3.shape[0]
+    if nq == 1 and nk == 1 and group == 1:
         return _bwd_single_tile(scale, causal, (q3, k3, v3, lse), do3,
                                 delta, (dq_dtype, dk_dtype, dv_dtype))
 
-    if causal:
-        # same dead-tile DMA elision as the forward (see module docstring)
-        kv_idx = lambda b, i, j: (b, jax.lax.min(j, i), 0)
+    # same dead-tile DMA elision as the forward (see module docstring)
+    kv_idx = _kv_index_maps(causal, group)
+    if group > 1:
+        # grid (key/value head, k block, group x q blocks)
+        head = lambda b, i: b * group + i // nq
+        if causal:
+            q_row_idx = lambda b, j, i: (head(b, i),
+                                         jax.lax.max(i % nq, j), 0)
+        else:
+            q_row_idx = lambda b, j, i: (head(b, i), i % nq, 0)
+    elif causal:
         q_row_idx = lambda b, j, i: (b, jax.lax.max(i, j), 0)
     else:
-        kv_idx = lambda b, i, j: (b, j, 0)
         q_row_idx = lambda b, j, i: (b, i, 0)
 
     dq_kern = functools.partial(_bwd_dq_kernel, scale=scale, causal=causal,
@@ -433,12 +460,13 @@ def _bwd(scale, causal, block_q, block_k, res, do3, delta=None,
         interpret=_interpret(),
     )(q3, k3, v3, do3, lse, delta)[0]
 
-    dkv_kern = functools.partial(_bwd_dkv_kernel, scale=scale, causal=causal,
-                                 block_q=block_q, block_k=block_k)
+    dkv_kern = functools.partial(
+        _bwd_dkv_kernel, scale=scale, causal=causal, block_q=block_q,
+        block_k=block_k, **({"group": group} if group > 1 else {}))
     dk, dv = pl.pallas_call(
         dkv_kern,
         name="flash_bwd_dkv",
-        grid=(bh, nk, nq),
+        grid=(bh // group, nk, group * nq),
         in_specs=[
             pl.BlockSpec((1, block_q, d), q_row_idx),
             pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0)),
@@ -452,8 +480,8 @@ def _bwd(scale, causal, block_q, block_k, res, do3, delta=None,
             pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((bh, sk, d), dk_dtype),
-            jax.ShapeDtypeStruct((bh, sk, d), dv_dtype),
+            jax.ShapeDtypeStruct(k3.shape, dk_dtype),
+            jax.ShapeDtypeStruct(v3.shape, dv_dtype),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_k, d), jnp.float32),
@@ -509,8 +537,9 @@ def _flash_mha_bwd(causal, scale, res, do):
     q3, k3, v3, o3, lse, b, h, s_val, bq, bk = res
     do3 = _reshape_in(do)
     dq3, dk3, dv3 = _bwd(s_val, causal, bq, bk, (q3, k3, v3, o3, lse), do3)
-    return (_reshape_out(dq3, b, h), _reshape_out(dk3, b, h),
-            _reshape_out(dv3, b, h))
+    h_kv = k3.shape[0] // b
+    return (_reshape_out(dq3, b, h), _reshape_out(dk3, b, h_kv),
+            _reshape_out(dv3, b, h_kv))
 
 
 _flash_mha.defvjp(_flash_fwd_res, _flash_mha_bwd)
@@ -555,18 +584,27 @@ def flash_attention(query, key, value, causal=False, scale=None, name=None):
     Tape-level entry (Tensor in/out). ``_flash_mha`` is the pure-jax kernel
     entry used by jitted functional paths (distributed/hybrid_gpt.py).
     """
-    def f(q, k, v):
-        nested = _nested_shard(q.shape, causal, scale)
-        if nested is not None:
-            return nested(q, k, v)
-        return _flash_mha(q, k, v, causal, scale)
+    return apply(lambda q, k, v: flash_mha(q, k, v, causal, scale),
+                 query, key, value, name="flash_attention")
 
-    return apply(f, query, key, value, name="flash_attention")
+
+def flash_mha(q, k, v, causal=False, scale=None):
+    """``flash_attention`` on arrays: the kernel, inside a shard_map where
+    a multi-device auto mesh is open. k and v may have fewer heads than q,
+    a divisor of them: query head ``i`` reads key/value head ``i // group``."""
+    nested = _nested_shard(q.shape, causal, scale)
+    if nested is not None:
+        return nested(q, k, v)
+    return _flash_mha(q, k, v, causal, scale)
 
 
 def mha_reference(q, k, v, causal=False, scale=None):
-    """Unfused reference (tests compare the kernel against this)."""
+    """Unfused reference (tests compare the kernel against this). Fewer
+    key/value heads than query heads: each is repeated over its group."""
     d = q.shape[-1]
+    if k.shape[2] != q.shape[2]:
+        group = q.shape[2] // k.shape[2]
+        k, v = jnp.repeat(k, group, axis=2), jnp.repeat(v, group, axis=2)
     s = scale if scale is not None else 1.0 / (d ** 0.5)
     qt = jnp.swapaxes(q, 1, 2)
     kt = jnp.swapaxes(k, 1, 2)

@@ -93,6 +93,36 @@ SERVE_REQUESTS = ((0, 6, 8, 0), (0, 40, 8, 16), (0, 120, 6, 0), (2, 9, 10, 0),
                   (30, 48, 8, 16), (34, 5, 6, 0))
 
 
+def test_solar_rehearsal():
+    """The small Solar-Open2's step against its reference; on the CPU the
+    scan takes the ``jax.numpy`` path, and the phase says which it saw."""
+    out = chip_smoke.phase_solar(128, "xla")
+    assert out["rel"] <= chip_smoke.TOL_SOLAR_LOSS
+    assert all(out["scan"][n] <= tol
+               for n, tol in chip_smoke.TOL_SOLAR_SCAN.items()), out["scan"]
+    with pytest.raises(AssertionError, match="not the pallas path"):
+        chip_smoke.phase_solar(128, "pallas")
+
+
+@pytest.mark.parametrize("at,name", [(1, "dk"), (3, "dg"), (4, "dbeta")])
+def test_the_scan_check_sees_a_fault_in_the_backward_pass(monkeypatch, at,
+                                                          name):
+    """One of ``_chunk_bwd``'s gradients a tenth short: the phase's
+    comparison with the recurrence names it."""
+    from paddle_tpu.ops import kda
+
+    real = kda._chunk_bwd
+
+    def planted(*a, **k):
+        out = list(real(*a, **k))
+        out[at] = 0.9 * out[at]
+        return tuple(out)
+
+    monkeypatch.setattr(kda, "_chunk_bwd", planted)
+    with pytest.raises(AssertionError, match=f"the scan's {name} "):
+        chip_smoke.scan_against_recurrence(128)
+
+
 def test_serve_rehearsal():
     # served from the second CPU device, as the chip serves beside the
     # host's: on the first one the key's fold queues behind the toy ticks.
