@@ -437,6 +437,20 @@ def check_submits(submits, limit_ms: float) -> None:
           f"each may take {limit_ms}")
 
 
+def check_tick_memory(temp: float, alias: float, pool_bytes) -> None:
+    """The pools stay where they are (ROADMAP S3): the compiled tick's
+    temporaries (gauge ``serving/tick_temp_bytes``) are under ONE pool's
+    bytes and it aliases all of the donated pools
+    (``serving/tick_alias_bytes``). A pool-sized temporary is a copy of a
+    whole pool every tick. ``pool_bytes``: the bytes of each pool array."""
+    check(0 <= temp < max(pool_bytes),
+          f"the compiled tick holds {_gb(temp)} of temporaries, a whole "
+          f"page pool ({_gb(max(pool_bytes))}) or more: the pools are copied")
+    check(alias >= sum(pool_bytes),
+          f"the compiled tick aliases {_gb(alias)}, not all of the donated "
+          f"pools ({_gb(sum(pool_bytes))})")
+
+
 def phase_serve(cfg, num_slots: int, page_size: int, requests,
                 submit_limit_ms: float = SUBMIT_LIMIT_MS) -> dict:
     import jax
@@ -511,6 +525,14 @@ def phase_serve(cfg, num_slots: int, page_size: int, requests,
                 if "donated buffers were not usable" in str(w.message)]
     check(not donation, f"the tick's pool donation was declined: "
           f"{donation[:1]}")
+    tick_temp = reg.gauge("serving/tick_temp_bytes").value
+    tick_alias = reg.gauge("serving/tick_alias_bytes").value
+    pool_bytes = [a.nbytes for a in eng.pool.pools.arrays().values()]
+    if jax.default_backend() != "cpu":
+        # the CPU's XLA (the rehearsal in tests/test_chip_smoke.py) widens
+        # a whole bf16 pool to float32 around every scatter; what the CPU
+        # can say, it says on float32 pools in tests/test_pools.py
+        check_tick_memory(tick_temp, tick_alias, pool_bytes)
 
     for i, (_, _, want, _) in enumerate(requests):
         out = results.get(rids[i])
@@ -552,7 +574,8 @@ def phase_serve(cfg, num_slots: int, page_size: int, requests,
                                  - base["prefill_chunks"]),
            "prefix_hit_tokens": int(hits), "tick_kinds": kinds,
            "dense_match": f"{match}/{len(paged)}", "dense_s": dense_s,
-           "submits": submits, "peak_bytes": peak_bytes()}
+           "submits": submits, "peak_bytes": peak_bytes(),
+           "tick_temp_bytes": tick_temp, "tick_alias_bytes": tick_alias}
     say("serve", f"{len(requests)} requests on {num_slots} slots, all "
         f"finished; set-up {setup_s:.1f} s, first tick (compile) "
         f"{first_tick_s:.1f} s, run {run_s:.1f} s wall to "
@@ -561,6 +584,10 @@ def phase_serve(cfg, num_slots: int, page_size: int, requests,
         f"{out['prefill_chunks']} tokens={out['tokens']} "
         f"prefix_hit_tokens={out['prefix_hit_tokens']} sites="
         f"{eng.compiled_sites} traced once, no donation warning")
+    say("serve", f"the compiled tick: serving/tick_temp_bytes "
+        f"{tick_temp:.0f} ({_gb(tick_temp)}), serving/tick_alias_bytes "
+        f"{tick_alias:.0f} ({_gb(tick_alias)}); the pools "
+        f"{' + '.join(_gb(b) for b in pool_bytes)}")
     say("serve", "submit() host ms (ticks in flight): "
         + ", ".join(f"{ms:.3f} ({inflight})" for inflight, ms in submits))
     say("serve", f"greedy paged vs dense generate() (prompt "

@@ -758,12 +758,13 @@ def gpt_cached_apply(cfg: GPTConfig, stacked, other, ck, cv, tokens, pos0,
 def gpt_ragged_apply(cfg: GPTConfig, stacked, other, pools, tokens,
                      tok_pos, tok_limit, row_tab, row_pos0, row_len,
                      sample_ix, decode_rows: int, chunk_width: int,
-                     impl: str = "xla", spec_k: int = 0):
+                     impl: str = "xla", spec_k: int = 0, has_chunks=None):
     """Mixed prefill/decode forward over the PAGED cache: every token
     in flight rides one program. ``pools`` is the page pools
     (``serving.paged_cache.Pools``, stacked over layers) — this forward
     knows nothing of their format: each layer writes through
-    ``pools.scatter`` and reads through ``pools.attend``. ``tokens``
+    ``pools.scatter(layer, ...)`` and reads through
+    ``pools.attend(layer, ...)``. ``tokens``
     [NT] is the flat token buffer of one serving tick — ``decode_rows``
     resident decode tokens followed by the prefill chunks,
     ``chunk_width`` tokens each; which is which is *only* metadata:
@@ -785,13 +786,24 @@ def gpt_ragged_apply(cfg: GPTConfig, stacked, other, pools, tokens,
     row_len    [R] int32    real queries per row (decode rows: 1)
     sample_ix  [S] int32    flat indices whose final hidden states
                             feed the logits head (one per emitter)
+    has_chunks bool scalar  optional: False on a tick none of whose
+                            chunk rows is real (all pad). The chunk
+                            rows' attention then runs under a ``cond``
+                            INSIDE the block that only reads the pools
+                            and returns ``[nch, w, NH, D]`` (zeros when
+                            skipped: nothing samples a pad row). On the
+                            v5e such a tick is 0.8 ms of 17.7 shorter
+                            and the compiled tick still holds no
+                            pool-sized temporary (PERF.md section 6,
+                            PR 32). Everything else of the tick is one
+                            body whatever the mix
 
     Hidden-state compute (embeddings, LN, QKV/MLP matmuls) runs once
     over the flat buffer; each token's KV is scattered to its own
     page/offset; attention routes through the ONE
     ``ragged_paged_attention`` entry point, with rows grouped by their
     static query width — decode rows as ``[decode_rows, 1]`` and chunk
-    rows as ``[num_chunks, chunk_width]`` — so a decode-only tick pays
+    rows as ``[num_chunks, chunk_width]`` — so the decode rows pay
     the decode gather cost, not ``chunk_width×`` pad
     queries ("Ragged Paged Attention", PAPERS.md: per-row
     ``(pos0, true_len)`` metadata; the width grouping is the XLA-
@@ -829,8 +841,13 @@ def gpt_ragged_apply(cfg: GPTConfig, stacked, other, pools, tokens,
     not bitwise, vs the unquantized pool (the engine only asserts
     bitwise between two int8 engines).
 
-    How the pools travel — ``lax.scan`` xs -> ys, one layer's slice a
-    step — is decided here and nowhere else (ROADMAP S3).
+    How the pools travel is decided here and nowhere else (ROADMAP
+    S3): they are the CARRY of the ``lax.scan`` over ``(stacked,
+    arange(L))``, beside ``x``; a step hands the block the whole stacks
+    and its layer's index, no step slices a layer out or writes one
+    back, and XLA updates the (donated) stacks in place. Every token's
+    write lands before its layer's read: the block's ``attend`` scatters
+    first and reads the stacks the scatter returned.
     """
     _require_gpt3_block(cfg)
     nt = tokens.shape[0]
@@ -864,12 +881,13 @@ def gpt_ragged_apply(cfg: GPTConfig, stacked, other, pools, tokens,
         0)
     off = tok_pos % ps
 
-    def block(xc, inp):
-        p, pl0 = inp
+    def block(carry, inp):
+        xc, pl0 = carry
+        p, layer = inp
 
         def attend(q, kk, vv):
             with annotate("blk/kv_scatter"):
-                pl = pl0.scatter(page, off, kk, vv)
+                pl = pl0.scatter(layer, page, off, kk, vv)
             outs = []
             if nd and spec_k:
                 # verify grouping [nd, 1 + spec_k]: each slot's last
@@ -878,25 +896,34 @@ def gpt_ragged_apply(cfg: GPTConfig, stacked, other, pools, tokens,
                 qv = jnp.concatenate(
                     [q[:nd], q[nd:base, 0].reshape(nd, spec_k, nh, hd)],
                     axis=1)
-                ov = pl.attend(qv, row_tab[:nd], row_pos0[:nd],
+                ov = pl.attend(layer, qv, row_tab[:nd], row_pos0[:nd],
                                row_len[:nd], impl)
                 outs.append(ov[:, :1])
                 outs.append(ov[:, 1:].reshape(nd * spec_k, 1, nh, hd))
             elif nd:
-                outs.append(pl.attend(q[:nd], row_tab[:nd], row_pos0[:nd],
-                                      row_len[:nd], impl))
+                outs.append(pl.attend(layer, q[:nd], row_tab[:nd],
+                                      row_pos0[:nd], row_len[:nd], impl))
             if nch:
                 qp = q[base:, 0].reshape(nch, chunk_width, nh, hd)
-                op = pl.attend(qp, row_tab[nd:], row_pos0[nd:],
-                               row_len[nd:], impl)
+
+                def chunk_rows():
+                    return pl.attend(layer, qp, row_tab[nd:], row_pos0[nd:],
+                                     row_len[nd:], impl)
+
+                if has_chunks is None:
+                    op = chunk_rows()
+                else:
+                    op = jax.lax.cond(has_chunks, chunk_rows,
+                                      lambda: jnp.zeros_like(qp))
                 outs.append(op.reshape(nch * chunk_width, 1, nh, hd))
             o = outs[0] if len(outs) == 1 else \
                 jnp.concatenate(outs, axis=0)
             return o, pl
 
-        return gpt_block_body(xc, p, eps, nh, hd, attend)
+        return gpt_block_body(xc, p, eps, nh, hd, attend), None
 
-    x, pools = jax.lax.scan(block, x, (stacked, pools))
+    layers = jnp.arange(cfg.num_layers, dtype=jnp.int32)
+    (x, pools), _ = jax.lax.scan(block, (x, pools), (stacked, layers))
     with annotate("tick/head"):
         x = _ln(x, other["ln_f.weight"], other["ln_f.bias"], eps)
         last = x[sample_ix, 0]                          # [S, h]

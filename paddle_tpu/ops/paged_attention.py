@@ -48,7 +48,15 @@ spelling is the measured default until the kernel wins on hardware):
 
 Layout note: pools are ``[num_pages, page_size, NH, D]`` per layer;
 page 0 is the null page (writes of inactive rows land there, gathers
-of unallocated table entries read it and are masked).
+of unallocated table entries read it and are masked). Every entry point
+also takes the pools STACKED over layers, ``[L, num_pages, page_size,
+NH, D]`` (scales ``[L, P, NH]``), with ``layer=`` an index that may be
+traced: the layer then rides inside the scatter's and the gather's own
+indices (``pool.at[layer, page, off]``, ``pool[layer, page_table]``), so
+no operation's result is a layer of the pool and a ``lax.scan`` that
+carries the stack updates it in place (ROADMAP S3;
+``serving.paged_cache.Pools``). The arithmetic after the gather is the
+same statements either way.
 
 Quantized pools (ISSUE 12): with ``kv_dtype="int8"`` the pools store
 int8 values plus per-page **per-head** f32 scales ``[P, NH]`` per
@@ -88,8 +96,14 @@ def _interpret() -> bool:
     return target_platform() == "cpu"
 
 
+def _at(layer, *index):
+    """``index`` into one layer's pool, or ``(layer,) + index`` into the
+    stack: the one place that says where the layer axis is."""
+    return index if layer is None else (layer,) + index
+
+
 def _gather_attend(q, k_pool, v_pool, page_table, qpos,
-                   k_scale=None, v_scale=None):
+                   k_scale=None, v_scale=None, layer=None):
     """THE dense paged-attention expression — the single spelling of
     gather + mask + f32 softmax behind ``impl="xla"`` (and,
     transitively, the spelling ``gpt_cached_apply`` uses on the dense
@@ -105,6 +119,9 @@ def _gather_attend(q, k_pool, v_pool, page_table, qpos,
     k_scale     [P, NH] f32    per-page per-head dequant scales (int8
     v_scale     [P, NH]        pools only; None leaves the math — and
                                the f32 parity contract — untouched)
+    layer       int32 scalar   None: the pools are one layer's; else
+                               they are stacked ``[L, ...]`` and the
+                               layer is one more index of the gathers
 
     Every reduction runs at the full slot capacity ``NPs * ps`` with
     exact-zero weights behind the mask, so results are independent of
@@ -115,19 +132,18 @@ def _gather_attend(q, k_pool, v_pool, page_table, qpos,
     regardless of storage dtype. Returns [R, T, NH, D].
     """
     r = q.shape[0]
-    nps, ps = page_table.shape[1], k_pool.shape[1]
-    nh, hd = k_pool.shape[2], k_pool.shape[3]
+    ps, nh, hd = k_pool.shape[-3:]
+    nps = page_table.shape[1]
     s_cap = nps * ps
-    k_c = k_pool[page_table]                # [R, NPs, ps, NH, D]
-    v_c = v_pool[page_table]
+    at = _at(layer, page_table)
+    k_c = k_pool[at]                        # [R, NPs, ps, NH, D]
+    v_c = v_pool[at]
     if k_scale is not None:
         # int8 pools: dequant with the gathered per-page per-head
         # scales (null pages carry scale 0, so their garbage reads as
         # exact zeros even before the mask)
-        k_c = k_c.astype(q.dtype) * k_scale[page_table][:, :, None, :,
-                                                        None]
-        v_c = v_c.astype(q.dtype) * v_scale[page_table][:, :, None, :,
-                                                        None]
+        k_c = k_c.astype(q.dtype) * k_scale[at][:, :, None, :, None]
+        v_c = v_c.astype(q.dtype) * v_scale[at][:, :, None, :, None]
     elif k_pool.dtype != q.dtype:
         # mixed storage/compute dtypes: contract at the WIDER of the
         # two — upcasting a bf16 pool under an f32 model is free, and
@@ -152,7 +168,7 @@ def _gather_attend(q, k_pool, v_pool, page_table, qpos,
 
 def ragged_paged_attention(q, k_pool, v_pool, page_table, pos0, true_len,
                            impl: str = "xla", k_scale=None,
-                           v_scale=None):
+                           v_scale=None, layer=None):
     """One attention call over ragged rows of the page pool.
 
     q           [R, T, NH, D]  per-row query blocks (T static)
@@ -163,6 +179,9 @@ def ragged_paged_attention(q, k_pool, v_pool, page_table, pos0, true_len,
     true_len    [R] int32      real queries in the row (1 = decode row)
     k_scale     [P, NH] f32    dequant scales for int8 pools (both
     v_scale     [P, NH]        impls; None = unquantized pools)
+    layer       int32 scalar   with it the pools (and scales) are the
+                               stacks ``[L, ...]`` and this layer of
+                               them is read, by index (both impls)
 
     Query ``i`` of row ``r`` attends cache positions
     ``<= pos0[r] + i``. Rows are fixed-shape: queries at
@@ -175,15 +194,17 @@ def ragged_paged_attention(q, k_pool, v_pool, page_table, pos0, true_len,
         t = q.shape[1]
         qpos = pos0[:, None] + jnp.arange(t, dtype=pos0.dtype)[None, :]
         return _gather_attend(q, k_pool, v_pool, page_table, qpos,
-                              k_scale=k_scale, v_scale=v_scale)
+                              k_scale=k_scale, v_scale=v_scale,
+                              layer=layer)
     if impl == "pallas":
         return _ragged_attention_pallas(q, k_pool, v_pool, page_table,
                                         pos0, true_len,
-                                        k_scale=k_scale, v_scale=v_scale)
+                                        k_scale=k_scale, v_scale=v_scale,
+                                        layer=layer)
     raise ValueError(f"unknown paged attention impl {impl!r}")
 
 
-def paged_kv_scatter(pool, scale, page, off, vals):
+def paged_kv_scatter(pool, scale, page, off, vals, layer=None):
     """Write one tick's per-token KV into the page pool — the single
     write-side spelling shared by the unified tick and the spec verify
     tick (via ``gpt_ragged_apply``).
@@ -194,6 +215,10 @@ def paged_kv_scatter(pool, scale, page, off, vals):
     page   [NT] int32      target page per token (0 = null page)
     off    [NT] int32      offset within the page
     vals   [NT, NH, D]     the token KV (model dtype)
+    layer  int32 scalar    None: ``pool``/``scale`` are one layer's;
+                           else they are the stacks ``[L, ...]`` and
+                           every index below gains the layer, so the
+                           stack is written in place and returned
 
     Unquantized pools: one scatter (cast to the pool dtype). int8
     pools quantize-on-write with RUNNING per-page scales:
@@ -224,21 +249,22 @@ def paged_kv_scatter(pool, scale, page, off, vals):
     if scale is None:
         vals = vals if vals.dtype == pool.dtype \
             else vals.astype(pool.dtype)
-        return pool.at[page, off].set(vals), None
+        return pool.at[_at(layer, page, off)].set(vals), None
+    pages = _at(layer, page)
     a = jnp.max(jnp.abs(vals.astype(jnp.float32)), axis=-1) / 127.0
     a = jnp.where((page > 0)[:, None], a, 0.0)          # [NT, NH]
-    s_old = scale[page]                                 # [NT, NH]
-    scale = scale.at[page].max(a)
-    s_new = scale[page]
+    s_old = scale[pages]                                # [NT, NH]
+    scale = scale.at[pages].max(a)
+    s_new = scale[pages]
     ratio = jnp.where(s_new > 0.0,
                       s_old / jnp.maximum(s_new, 1e-30), 0.0)
-    pg = pool[page].astype(jnp.float32)                 # [NT, ps, NH, D]
+    pg = pool[pages].astype(jnp.float32)                # [NT, ps, NH, D]
     pg = jnp.round(pg * ratio[:, None, :, None])
-    pool = pool.at[page].set(pg.astype(jnp.int8))
+    pool = pool.at[pages].set(pg.astype(jnp.int8))
     q = jnp.round(vals.astype(jnp.float32)
                   / jnp.maximum(s_new, 1e-30)[:, :, None])
     q = jnp.clip(q, -127.0, 127.0)
-    pool = pool.at[page, off].set(q.astype(jnp.int8))
+    pool = pool.at[_at(layer, page, off)].set(q.astype(jnp.int8))
     return pool, scale
 
 
@@ -246,11 +272,13 @@ def paged_kv_scatter(pool, scale, page, off, vals):
 # Pallas ragged kernel
 # --------------------------------------------------------------------------
 
-def _ragged_kernel(pt_ref, pos0_ref, tl_ref, q_ref, k_ref, v_ref, *rest,
-                   page_size: int, n_pages: int):
-    """Grid (r, j): row r consumes its j-th page. Page table, pos0 and
-    true_len are scalar-prefetched, so the BlockSpec index map DMAs
-    page ``pt[r, j]`` straight from the pool — the gathered
+def _ragged_kernel(pt_ref, pos0_ref, tl_ref, layer_ref, q_ref, k_ref, v_ref,
+                   *rest, page_size: int, n_pages: int):
+    """Grid (r, j): row r consumes its j-th page. Page table, pos0,
+    true_len and the layer are scalar-prefetched, so the BlockSpec index
+    map DMAs page ``pt[r, j]`` of layer ``layer[0]`` straight from the
+    stacked pool (the layer axis is squeezed out of the block: the body
+    sees one page) — the gathered
     [R, S_cap] intermediate of the XLA path never exists — and routes
     fully-masked blocks (``j*ps > pos0 + true_len - 1``, where nothing
     in the page is attendable by any real query of the row) to the
@@ -319,45 +347,55 @@ def _ragged_kernel(pt_ref, pos0_ref, tl_ref, q_ref, k_ref, v_ref, *rest,
 
 
 def _ragged_attention_pallas(q, k_pool, v_pool, page_table, pos0,
-                             true_len, k_scale=None, v_scale=None):
+                             true_len, k_scale=None, v_scale=None,
+                             layer=None):
     r, t, nh, hd = q.shape
-    ps = k_pool.shape[1]
+    ps = k_pool.shape[-3]
     nps = page_table.shape[1]
+    if layer is None:
+        # one layer's pools are a stack of one (a reshape): the kernel
+        # has one spelling, the stacked one
+        layer = 0
+        k_pool, v_pool = k_pool[None], v_pool[None]
+        if k_scale is not None:
+            k_scale, v_scale = k_scale[None], v_scale[None]
+    layer = jnp.asarray(layer, jnp.int32).reshape(1)
 
-    def _kv_index(i, j, pt, p0, tl):
+    def _page(i, j, pt, p0, tl):
         # fully-masked block: fetch the (hot, tiny) null page instead
         # of a live pool page the row will only mask away
-        return (jnp.where(j * ps <= p0[i] + tl[i] - 1, pt[i, j], 0),
-                0, 0, 0)
+        return jnp.where(j * ps <= p0[i] + tl[i] - 1, pt[i, j], 0)
 
-    def _scale_index(i, j, pt, p0, tl):
+    def _kv_index(i, j, pt, p0, tl, ly):
+        return (ly[0], _page(i, j, pt, p0, tl), 0, 0, 0)
+
+    def _scale_index(i, j, pt, p0, tl, ly):
         # the scale row rides the same page choice as the page itself
-        return (jnp.where(j * ps <= p0[i] + tl[i] - 1, pt[i, j], 0),
-                0, 0)
+        return (ly[0], _page(i, j, pt, p0, tl), 0, 0)
 
     in_specs = [
         pl.BlockSpec((1, t, nh, hd),
-                     lambda i, j, pt, p0, tl: (i, 0, 0, 0)),
-        pl.BlockSpec((1, ps, nh, hd), _kv_index),
-        pl.BlockSpec((1, ps, nh, hd), _kv_index),
+                     lambda i, j, pt, p0, tl, ly: (i, 0, 0, 0)),
+        pl.BlockSpec((None, 1, ps, nh, hd), _kv_index),
+        pl.BlockSpec((None, 1, ps, nh, hd), _kv_index),
     ]
-    args = (page_table, pos0, true_len, q, k_pool, v_pool)
+    args = (page_table, pos0, true_len, layer, q, k_pool, v_pool)
     if k_scale is not None:
-        # scales enter as [P, NH, 1]: a (1, NH) block of the [P, NH]
-        # array breaks Mosaic's rule that a block's last two dims be
+        # scales enter as [L, P, NH, 1]: a (1, NH) block of the [P, NH]
+        # rows breaks Mosaic's rule that a block's last two dims be
         # (8, 128)-divisible or the array's own, and [NH, 1] is already
         # the page tile's layout (heads on sublanes, broadcast along
         # the head_dim lanes)
-        in_specs += [pl.BlockSpec((1, nh, 1), _scale_index),
-                     pl.BlockSpec((1, nh, 1), _scale_index)]
-        args += (k_scale[:, :, None], v_scale[:, :, None])
+        in_specs += [pl.BlockSpec((None, 1, nh, 1), _scale_index),
+                     pl.BlockSpec((None, 1, nh, 1), _scale_index)]
+        args += (k_scale[..., None], v_scale[..., None])
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
+        num_scalar_prefetch=4,
         grid=(r, nps),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, t, nh, hd),
-                               lambda i, j, pt, p0, tl: (i, 0, 0, 0)),
+                               lambda i, j, pt, p0, tl, ly: (i, 0, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((nh, t, 1), jnp.float32),
             pltpu.VMEM((nh, t, 1), jnp.float32),

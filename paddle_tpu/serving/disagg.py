@@ -16,10 +16,11 @@ table exists). Ranks are split into two slot groups:
   A prefill engine's tick therefore only ever carries chunk rows.
 - the **decode group** (everyone else): imports arrive decode-ready
   (``ServingEngine.admit_prefilled`` seeds the slot exactly where a
-  local prefill finisher would have left it), so the decode tick takes
-  its compiled decode-only ``lax.cond`` fast path whenever no local
-  prefill is in flight — short prompts still prefill locally, long
-  ones never touch this group's tick as chunk rows at all.
+  local prefill finisher would have left it), so the decode tick's
+  chunk rows ride as pad rows (one body for every mix; only their
+  attention is skipped) whenever no local prefill is in flight — short
+  prompts still prefill locally, long ones never touch this group's
+  tick as chunk rows at all.
 
 ``MeshSpec(prefill_ranks=())`` is the **symmetric** scale-out
 topology: every rank decodes its own admissions, no handoffs — the

@@ -22,6 +22,13 @@ fill:
   The page pools travel through it as ONE argument
   (``paged_cache.Pools``, donated and stored back); what a pool is —
   two arrays, or four with int8 scales — is that module's business.
+  Inside, the tick is ONE body for every mix (no decode-only branch):
+  the pools are the carry of the forward's scan over layers, written
+  and read by ``(layer, page[, offset])`` and updated in place, so the
+  compiled tick holds no pool-sized temporary (the gauges
+  ``serving/tick_temp_bytes`` / ``serving/tick_alias_bytes`` say so at
+  the first dispatch); on a tick without a chunk the chunk rows ride as
+  pad rows and only their attention is skipped.
 - **Chunked prefill** (Sarathi-style piggybacking). A prompt is
   prefilled in fixed-size chunks riding the unified tick, at most
   ``prefill_chunks_per_tick`` per scheduler step, each attending over
@@ -94,6 +101,9 @@ preempt → re-prefill start — requeue cycles used to fold back into the
 submit-anchored wait, conflating scheduler delay with preemption
 cost), ``serving/tokens_generated``,
 ``serving/prefills``, ``serving/prefill_chunks``, ``serving/ticks``,
+``serving/tick_temp_bytes`` / ``serving/tick_alias_bytes`` (gauges, set
+once when the tick is first compiled: its ``memory_analysis()``; in
+place means temporaries far under one pool and every pool aliased),
 ``serving/preemptions``, ``serving/requests_finished``,
 ``serving/drain_waited`` / ``serving/drain_ready`` (drained ticks whose
 tokens the host had to wait for / found ready),
@@ -575,6 +585,33 @@ class ServingEngine:
         self._program_args[site] = (
             fn, jax.tree_util.tree_map(aval, args))
 
+    def _run_tick(self, args: tuple):
+        """Dispatch the tick (unified, or the spec engine's verify tick).
+        Its first dispatch compiles it, and says once how the pools
+        travel through the compiled program: the gauges
+        ``serving/tick_temp_bytes`` and ``serving/tick_alias_bytes``
+        (``memory_analysis()``). Updated in place the tick's temporaries
+        are far under one pool and it aliases all of the donated pools;
+        a pool-sized temporary is a whole-pool copy every tick (ROADMAP
+        S3; chip_smoke.py fails on it). Tracing, lowering and compiling
+        still happen once: the call finds what ``lower`` made, and
+        ``compile`` finds the call's executable."""
+        if self._tick_site in self._program_args:
+            with _quiet_donation():
+                return self._tick(*args)
+        self._note_avals(self._tick_site, self._tick, args)
+        with _quiet_donation():
+            lowered = self._tick.lower(*args)
+            out = self._tick(*args)
+            memory = lowered.compile().memory_analysis()
+        if memory is not None:
+            reg = _registry()
+            reg.gauge("serving/tick_temp_bytes").set(
+                float(memory.temp_size_in_bytes))
+            reg.gauge("serving/tick_alias_bytes").set(
+                float(memory.alias_size_in_bytes))
+        return out
+
     def record_program_stats(self) -> Dict[str, dict]:
         """Fold compile wall-time + ``cost_analysis()`` FLOPs/bytes of
         every hot-path program that has dispatched at least once into
@@ -833,7 +870,7 @@ class ServingEngine:
     # transfer for free. The import writer is a jitted fixed-shape
     # maintenance op like the COW copy (self._copy): it is NOT a
     # hot-path dispatch site, so ``compiled_sites`` is unchanged and
-    # the decode group's tick keeps its decode-only fast path.
+    # the decode group's tick stays the one tick it was.
     # ------------------------------------------------------------------
     def held_ready(self) -> Tuple[int, ...]:
         """rids submitted with ``hold_after_prefill`` whose prompt is
@@ -1573,10 +1610,8 @@ class ServingEngine:
             return False
         with _ptrace.scope("step/build", tick=self._tick_no):
             args, finishers = self._build_unified(chunks, ticking)
-        self._note_avals(self._tick_site, self._tick, args)
-        with _ptrace.scope("step/dispatch", tick=self._tick_no), \
-                _quiet_donation():
-            self.pool.pools, tok, self._last_tok = self._tick(*args)
+        with _ptrace.scope("step/dispatch", tick=self._tick_no):
+            self.pool.pools, tok, self._last_tok = self._run_tick(args)
         meta = [(s, s, self._slot_rid[s]) for s in ticking]
         meta += [(s, s, rid) for s, rid in finishers]
         if meta:
@@ -1675,7 +1710,9 @@ class ServingEngine:
         rows of a single ``gpt_ragged_apply`` forward. All metadata is
         fixed-shape (pad prefill rows ride with limit 0), so the
         program traces exactly once across any prefill/decode mix,
-        admission order, or per-request sampling params. Decode token
+        admission order, or per-request sampling params, and is one
+        body for all of them: the pools are never a ``cond``'s operand
+        or result here (ROADMAP S3). Decode token
         values come from the DEVICE-side ``last_tok`` (the deferred
         sync never materializes them on the host); the final chunk of
         a prompt emits its slot's first token via ``sample_ix``, and
@@ -1699,32 +1736,19 @@ class ServingEngine:
             # (fresh pads with the null page, whose scale is 0)
             pools = pools.reset_scales(fresh)
             tokens = jnp.concatenate([last_tok, pf_toks])
-
-            def run(pl_, toks_, pos_, lim_, tab_, p0_, len_):
-                return gpt_ragged_apply(
-                    mcfg, stacked, other, pl_, toks_, pos_, lim_, tab_,
-                    p0_, len_, sample_ix, decode_rows=ns, chunk_width=w,
-                    impl=impl)
-
-            # ONE program, data-dependent prefill piggyback: both
-            # branches trace into this single executable (the site
-            # still traces exactly once); at runtime a decode-only
-            # tick takes the ns-token branch, so the prefill-row
-            # capacity costs nothing while nothing is prefilling —
-            # a fixed-shape program otherwise pays its worst-case mix
-            # every tick, which on the XLA path is real FLOPs, not
-            # skipped blocks.
-            def mixed(pl_):
-                return run(pl_, tokens, tok_pos, tok_limit, row_tab,
-                           row_pos0, row_len)
-
-            def decode_only(pl_):
-                return run(pl_, tokens[:ns], tok_pos[:ns],
-                           tok_limit[:ns], row_tab[:ns], row_pos0[:ns],
-                           row_len[:ns])
-
-            logits, pools = jax.lax.cond(has_chunks, mixed, decode_only,
-                                         pools)
+            # ONE body for every mix. The pools are updated in place as
+            # the carry of the forward's layer scan (ROADMAP S3); a
+            # ``cond`` that took and returned them cost two whole-pool
+            # copies a tick, on both branches, to save a tick without a
+            # chunk 1 ms. On such a tick the chunk rows ride as the pad
+            # rows the program already knows (``tok_limit`` 0: their
+            # writes land on the null page; all-null tables), and
+            # ``has_chunks`` only lets the block skip their attention,
+            # which reads the pools and returns ``[nch, w, NH, D]``.
+            logits, pools = gpt_ragged_apply(
+                mcfg, stacked, other, pools, tokens, tok_pos, tok_limit,
+                row_tab, row_pos0, row_len, sample_ix, decode_rows=ns,
+                chunk_width=w, impl=impl, has_chunks=has_chunks)
             with _ptrace.annotate("tick/sample"):
                 nxt = self._sample_tok(logits, keys, sample_pos, temps,
                                        top_ks, top_ps)
@@ -1979,10 +2003,8 @@ class ServingEngine:
                 row_tab, row_pos0, row_len, sample.reshape(-1), k_arr,
                 vsample, np.bool_(len(chunks) > 0), np.bool_(has_drafts))
         args = (self._stacked, self._other) + self._pool_args() + tail
-        self._note_avals(self._tick_site, self._tick, args)
         dispatch_t = time.perf_counter()
-        with _quiet_donation():
-            self.pool.pools, tok_m, acc = self._tick(*args)
+        self.pool.pools, tok_m, acc = self._run_tick(args)
 
         # ---- overlap: chain draft tick N+1 on the un-materialized
         # verify outputs, BEFORE the host sync below — the sync then

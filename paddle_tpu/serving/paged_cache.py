@@ -459,9 +459,14 @@ class Pools(NamedTuple):
                       scales of int8 pools (ISSUE 12); ``None`` otherwise
 
     Every array indexes layers on axis 0 and pages on axis 1, so the
-    page-granular programs (copy, gather, write) are one ``tree.map``
-    and ``lax.scan`` hands a block the same tuple one layer down, where
-    ``scatter`` and ``attend`` are the cache's write and read sides.
+    page-granular programs (copy, gather, write) are one ``tree.map``.
+    The tick's ``lax.scan`` over layers CARRIES the whole tuple and its
+    block calls ``scatter(layer, ...)`` and ``attend(layer, ...)``, the
+    cache's write and read sides: the layer is one more index of their
+    one scatter and one gather, no operation's result is a layer of a
+    pool, and XLA updates the donated stacks in place (ROADMAP S3: as
+    ``xs -> ys`` every step sliced 2 x a layer out and wrote 2 x a layer
+    into a second stack).
     Page 0 (null) keeps scale 0 forever (masked contributions). Page
     CONTENT is deliberately never cleared on free (LIFO dirty reuse is
     a feature), but a recycled page's STALE SCALE would poison the
@@ -522,22 +527,24 @@ class Pools(NamedTuple):
         return jax.tree.map(lambda a, c: a.at[:, pages].set(c), self,
                             content)
 
-    # -- one layer's slice, inside the tick's scan over layers ---------
-    def scatter(self, page, off, kk, vv) -> "Pools":
+    # -- one layer of the stacks, by index, inside the tick's scan ------
+    def scatter(self, layer, page, off, kk, vv) -> "Pools":
         """Write each token's key and value (``kk``/``vv`` [NT, 1, NH,
-        D], the block's flat one-position rows) at its ``(page, off)``,
-        quantizing on write where there are scales."""
+        D], the block's flat one-position rows) at its ``(layer, page,
+        off)``, quantizing on write where there are scales. ``layer``
+        may be traced; the result is the whole stacks."""
         k, k_scale = paged_kv_scatter(self.k, self.k_scale, page, off,
-                                      kk[:, 0])
+                                      kk[:, 0], layer=layer)
         v, v_scale = paged_kv_scatter(self.v, self.v_scale, page, off,
-                                      vv[:, 0])
+                                      vv[:, 0], layer=layer)
         return Pools(k, v, k_scale, v_scale)
 
-    def attend(self, q, page_table, pos0, true_len, impl: str = "xla"):
-        """``ragged_paged_attention`` of ``q`` over this layer's pages."""
+    def attend(self, layer, q, page_table, pos0, true_len,
+               impl: str = "xla"):
+        """``ragged_paged_attention`` of ``q`` over ``layer``'s pages."""
         return ragged_paged_attention(
             q, self.k, self.v, page_table, pos0, true_len, impl=impl,
-            k_scale=self.k_scale, v_scale=self.v_scale)
+            k_scale=self.k_scale, v_scale=self.v_scale, layer=layer)
 
 
 class PagePool:

@@ -134,6 +134,7 @@ def test_serve_rehearsal():
     assert out["prefix_hit_tokens"] >= 16
     assert out["tokens"] == 8 + 8 + 6 + 10 + 8 + 6
     assert out["tick_kinds"]["mixed"] and out["tick_kinds"]["decode_only"]
+    assert out["tick_temp_bytes"] > 0 and out["tick_alias_bytes"] > 0
     assert len(out["submits"]) == 6
     assert sum(1 for inflight, _ in out["submits"] if inflight) >= 2
 
@@ -145,6 +146,19 @@ def test_a_submit_behind_the_ticks_in_flight_fails_the_serve_phase():
     with pytest.raises(chip_smoke.SmokeFailure, match="submit"):
         chip_smoke.check_submits([(0, 0.1), (3, 0.2), (2, 1.1 * limit),
                                   (3, 0.1)], limit)
+
+
+@pytest.mark.parametrize("temp,alias,what", [
+    (2.4e9, 4.8e9, "temporaries"),      # one whole pool copied
+    (4.9e9, 4.8e9, "temporaries"),      # both, as the whole-tick cond did
+    (1e6, 2.4e9, "aliases"),            # one pool not updated in place
+    (1e6, 0.0, "aliases")])
+def test_a_copied_pool_fails_the_serve_phase(temp, alias, what):
+    pools = [2.4e9, 2.4e9]
+    chip_smoke.check_tick_memory(1e6, 4.8e9, pools)
+    chip_smoke.check_tick_memory(0.0, 4.9e9, pools + [1e6, 1e6])
+    with pytest.raises(chip_smoke.SmokeFailure, match=what):
+        chip_smoke.check_tick_memory(temp, alias, pools)
 
 
 def test_train_and_multichip_rehearsal():

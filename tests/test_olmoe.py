@@ -418,10 +418,12 @@ def frozen_gpt():
     spec.loader.exec_module(mod)
     # PR 28 handed the tick its page pools as one argument
     # (``paged_cache.Pools``); the frozen forward takes and returns them
-    # apart, so it is called through that signature here
+    # apart, so it is called through that signature here. It knows no
+    # ``has_chunks`` (PR 32): it runs every row's attention every tick
     apart = mod.gpt_ragged_apply
 
-    def gpt_ragged_apply(cfg, stacked, other, pools, *a, **kw):
+    def gpt_ragged_apply(cfg, stacked, other, pools, *a, has_chunks=None,
+                         **kw):
         logits, k, v = apart(cfg, stacked, other, pools.k, pools.v, *a, **kw)
         return logits, pools._replace(k=k, v=v)
 
@@ -448,7 +450,18 @@ def dense_gpt_step_text(gpt):
     return tr.aot_lower(jax.ShapeDtypeStruct((4, 32), np.int32)).as_text()
 
 
-def dense_gpt_tick_text(gpt):
+def test_the_new_fields_leave_the_dense_gpt_step_as_it_was():
+    import paddle_tpu.models.gpt as live
+
+    with frozen_gpt() as frozen:
+        was = dense_gpt_step_text(frozen)
+    _same_text(dense_gpt_step_text(live), was)
+
+
+def dense_gpt_served(gpt):
+    """Three requests through two slots (chunks, decodes, ticks with and
+    without a chunk, a slot reused): each request's tokens and the pools
+    at the end."""
     from paddle_tpu.serving import ServingConfig, ServingEngine
 
     paddle.seed(0)
@@ -457,18 +470,31 @@ def dense_gpt_tick_text(gpt):
     model.eval()
     eng = ServingEngine(model, ServingConfig(num_slots=2, page_size=4,
                                              pages_per_slot=8))
-    eng.submit(np.arange(5, dtype=np.int32), 3)
-    eng.step()
-    eng.drain(0)
-    fn, avals = eng._program_args[eng.compiled_sites[0]]
-    return fn.lower(*avals).as_text()
+    rng = np.random.default_rng(5)
+    rids = [eng.submit(rng.integers(0, 128, n, dtype=np.int32), 6)
+            for n in (5, 19, 9)]
+    out = eng.run()
+    return [out[r] for r in rids], eng.pool.pools
 
 
-@pytest.mark.parametrize("text_of", [dense_gpt_step_text,
-                                     dense_gpt_tick_text])
-def test_the_new_fields_leave_the_dense_gpt_programs_as_they_were(text_of):
+@pytest.mark.parametrize("what", ["tokens", "k", "v"])
+def test_the_carried_pools_serve_what_the_sliced_pools_served(what):
+    """ISSUE 32 moved the tick's pools from the layer scan's xs -> ys (a
+    layer sliced out and written back a step, under a whole-tick ``cond``)
+    to its carry, indexed by layer in place. The frozen copy still holds the
+    old forward: the same requests get the same tokens and leave the same
+    keys and values in every page but the null page (which a tick without a
+    chunk now writes its pad rows to), bit for bit."""
     import paddle_tpu.models.gpt as live
 
     with frozen_gpt() as frozen:
-        was = text_of(frozen)
-    _same_text(text_of(live), was)
+        was_tokens, was_pools = dense_gpt_served(frozen)
+    tokens, pools = dense_gpt_served(live)
+    if what == "tokens":
+        for got, want in zip(tokens, was_tokens):
+            np.testing.assert_array_equal(got, want)
+    else:
+        got, want = (np.asarray(getattr(p, what))[:, 1:]
+                     for p in (pools, was_pools))
+        assert want.any()
+        np.testing.assert_array_equal(got, want)
