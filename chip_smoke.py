@@ -40,6 +40,7 @@ rehearse them at toy sizes on the CPU mesh.
 """
 from __future__ import annotations
 
+import dataclasses
 import gc
 import json
 import os
@@ -81,6 +82,21 @@ SERVE_REQUESTS = (
     (0, 16, 32, 0), (0, 300, 48, 256), (0, 1500, 32, 0), (3, 64, 64, 0),
     (5, 130, 40, 0), (8, 700, 32, 0), (12, 37, 33, 0), (90, 356, 48, 256),
     (100, 20, 32, 0), (110, 512, 32, 0))
+
+
+#: the looped pass of phase ``serve`` against models/ouro_reference.py (one
+#: full float32 forward over prompt and output): how far below its
+#: position's largest logit an emitted token's may lie, and how far a
+#: request's mean expected exit step from the reference's. For the 12
+#: cache layers of this pass (3 layers x 4 steps): bf16 moves a logit by
+#: about 0.015 rms there and doubles with the depth (PERF.md section 6), so
+#: these are not the limits of perfbench/checks/ouro_serve.py, which holds
+#: the whole model's 192 to 2.0 and 0.04.
+TOL_LOOP_SHORTFALL = 0.15
+TOL_LOOP_EXIT = 0.02
+#: (prompt length, new tokens) of the looped pass: chunked prefill (32 a
+#: tick), mixed and decode-only ticks, three requests on two slots
+LOOP_REQUESTS = ((40, 24), (100, 16), (17, 32))
 
 
 class SmokeFailure(AssertionError):
@@ -597,6 +613,97 @@ def phase_serve(cfg, num_slots: int, page_size: int, requests,
     return out
 
 
+def phase_serve_looped(cfg, num_slots: int, page_size: int,
+                       pages_per_slot: int, requests=LOOP_REQUESTS) -> dict:
+    """One pass of a looped model (``cfg.loop_steps`` > 1: sandwich norms,
+    RoPE, SwiGLU, an exit gate) through the engine: a ``LazyGuard`` model's
+    weights are drawn on the device once, into the engine's stacks
+    (``serving/weights_bytes`` is one copy in the model's type), the tick over ``loop_steps * num_layers``
+    cache layers holds no pool-sized temporary, and what it emitted is the
+    float32 reference's (models/ouro_reference.py), tokens and exit
+    steps."""
+    import jax
+
+    import paddle_tpu as paddle
+    from paddle_tpu.models import GPT
+    from paddle_tpu.models import ouro_reference as ref
+    from paddle_tpu.profiler import registry
+    from paddle_tpu.serving import ServingConfig, ServingEngine
+
+    reg = registry()
+    t_setup = time.perf_counter()
+    paddle.seed(0)
+    with paddle.LazyGuard():
+        net = GPT(cfg)
+    net.eval()
+    net.bfloat16()
+    eng = ServingEngine(net, ServingConfig(
+        num_slots=num_slots, page_size=page_size,
+        pages_per_slot=pages_per_slot))
+    stacked, other = eng.served_weights()
+    on_default_platform((stacked, other, eng.pool.k), "looped serving state")
+    setup_s = time.perf_counter() - t_setup
+    weights = reg.gauge("serving/weights_bytes").value
+    check(weights == 2 * cfg.num_params(),
+          f"serving/weights_bytes {weights:.0f} is not one bf16 copy of "
+          f"{cfg.num_params()} parameters")
+    layers = cfg.loop_steps * cfg.num_layers
+    check(reg.gauge("serving/cache_layers").value == layers
+          == eng.pool.k.shape[0], f"the pools are not {layers} layers deep")
+
+    rng = np.random.RandomState(11)
+    prompts = [rng.randint(0, cfg.vocab_size, n).astype(np.int32)
+               for n, _ in requests]
+    rids = [eng.submit(p, new) for p, (_, new) in zip(prompts, requests)]
+    t_run = time.perf_counter()
+    results = eng.run()
+    jax.block_until_ready(eng.pool.k)
+    run_s = time.perf_counter() - t_run
+    tick_temp = reg.gauge("serving/tick_temp_bytes").value
+    tick_alias = reg.gauge("serving/tick_alias_bytes").value
+    pool_bytes = [a.nbytes for a in eng.pool.pools.arrays().values()]
+    if jax.default_backend() != "cpu":      # as in phase_serve
+        check_tick_memory(tick_temp, tick_alias, pool_bytes)
+
+    def layer_weights():
+        for i in range(cfg.num_layers):
+            yield {k: v[i] for k, v in stacked.items()}
+
+    worst = gap = 0.0
+    for rid, prompt in zip(rids, prompts):
+        out = results[rid]
+        seq = np.concatenate([prompt, out[:-1]])[None]
+        at = np.arange(len(prompt) - 1, len(prompt) - 1 + len(out))
+        targets = np.zeros(seq.shape, np.int32)
+        mask = np.zeros(seq.shape, bool)
+        targets[0, at], mask[0, at] = out, True
+        got = ref.forward(layer_weights, other, seq, cfg.num_heads,
+                          cfg.loop_steps, cfg.exit_threshold,
+                          cfg.layer_norm_eps, cfg.rope_theta)
+        worst = max(worst, float(
+            ref.shortfall(got["state"], other, targets, mask).max()))
+        gap = max(gap, abs(eng.exit_steps(rid)[0] - float(
+            np.asarray(got["expected"])[0, at].mean())))
+    check(worst <= TOL_LOOP_SHORTFALL,
+          f"an emitted token's logit lies {worst:.4f} below the float32 "
+          f"reference's largest, allowed {TOL_LOOP_SHORTFALL}")
+    check(gap <= TOL_LOOP_EXIT,
+          f"a request's mean expected exit step is {gap:.5f} from the "
+          f"reference's, allowed {TOL_LOOP_EXIT}")
+    say("serve", f"looped pass ({cfg.loop_steps} steps x {cfg.num_layers} "
+        f"layers = {layers} cache layers): set-up {setup_s:.1f} s, run "
+        f"{run_s:.1f} s; serving/weights_bytes {weights:.0f} "
+        f"({_gb(weights)}, one bf16 copy); tick_temp_bytes "
+        f"{tick_temp:.0f} ({_gb(tick_temp)}) beside pools of "
+        f"{' + '.join(_gb(b) for b in pool_bytes)}; worst shortfall "
+        f"{worst:.4f} (allowed {TOL_LOOP_SHORTFALL}), exit step within "
+        f"{gap:.5f} (allowed {TOL_LOOP_EXIT}), mean expected exit step "
+        f"{eng.exit_steps()[0]:.3f}")
+    return {"worst": worst, "exit_gap": gap, "weights_bytes": weights,
+            "tick_temp_bytes": tick_temp, "tick_alias_bytes": tick_alias,
+            "cache_layers": layers}
+
+
 # ---------------------------------------------------------------------------
 # phases 3 and 4: the hybrid trainer
 # ---------------------------------------------------------------------------
@@ -899,6 +1006,9 @@ def main() -> int:
         olmoe.moe_num_experts, olmoe.moe_top_k))
     run("solar", lambda: phase_solar(256, "pallas"))
     run("serve", lambda: phase_serve(cfg, slots, page, SERVE_REQUESTS))
+    # the looped model at its published widths, 3 of its 48 layers
+    looped = dataclasses.replace(GPTConfig.ouro_2_6b(), num_layers=3)
+    run("serve-looped", lambda: phase_serve_looped(looped, 4, page, 16))
     run("train", lambda: phase_train(cfg, micro=2, n_micro=6, steps=4))
     if len(jax.devices()) >= 4 and "train" not in failed:
         run("multichip", lambda: phase_multichip(

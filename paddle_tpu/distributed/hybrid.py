@@ -208,6 +208,12 @@ class HybridPipelineTrainer:
             picture — per-scope spans, counters, tokens/sec + steps/sec
             over the enabled window, phases, retraces."""
         _check_protocol(model)
+        if getattr(getattr(model, "config", None), "loop_steps", 1) > 1:
+            raise NotImplementedError(
+                "HybridPipelineTrainer trains one pass of the stack: a "
+                "model with loop_steps > 1 needs a scan over the steps "
+                "around the layer scan, gradients summed over the steps and "
+                "the exit-weighted loss (ROADMAP R1)")
         # MoE composes with pp: a block leaves its auxiliary loss, already
         # weighted, in ``block.aux_loss`` and its counts in
         # ``block.aux_stats``; pipeline_apply carries both across the

@@ -64,6 +64,13 @@ class GPTConfig:
     moe_expert_width: int = 0         # one expert's width; 0: ffn_hidden_size
     moe_dropless: bool = False        # token choice, no capacity, no drops
     moe_z_weight: float = 0.0         # router z-loss (drop-less path)
+    sandwich_norm: bool = False       # a norm after each sub-layer as well
+    # a looped stack (LoopLM, arXiv:2510.25741): the same num_layers run
+    # loop_steps times, the final norm and an exit gate after each, and a
+    # cache for every (step, layer). exit_threshold: the cumulative exit
+    # probability at which a position's head stops reading later steps
+    loop_steps: int = 1
+    exit_threshold: float = 1.0
 
     def __post_init__(self):
         if not self.ffn_hidden_size:
@@ -76,13 +83,17 @@ class GPTConfig:
         if self.moe_dropless and (self.ffn != "swiglu" or self.bias):
             raise ValueError("moe_dropless experts are bias-free SwiGLU "
                              "(distributed/moe.py dropless_moe)")
+        if self.loop_steps < 1:
+            raise ValueError(f"loop_steps {self.loop_steps} must be >= 1")
 
     def is_gpt3_block(self) -> bool:
-        """Whether every architecture field has GPT-3's value: what the
-        serving forwards (gpt_block_body and its callers) implement."""
+        """Whether every architecture field has GPT-3's value: the block
+        whose served program is pinned, and the only one the speculative
+        draft tick (serving/spec.py) runs."""
         return (self.norm == "layernorm" and self.position == "learned"
                 and not self.qk_norm and self.bias and self.ffn == "gelu"
-                and not self.moe_dropless)
+                and not self.moe_dropless and not self.sandwich_norm
+                and self.loop_steps == 1)
 
     # presets from the reference north-star table (BASELINE.md)
     @staticmethod
@@ -126,6 +137,18 @@ class GPTConfig:
             moe_num_experts=64, moe_top_k=8, moe_expert_width=1024,
             moe_dropless=True, moe_aux_weight=0.01, moe_z_weight=0.001)
 
+    @staticmethod
+    def ouro_2_6b():
+        """Ouro-2.6B (huggingface.co/ByteDance/Ouro-2.6B config.json; the
+        loop, the sandwich norms and the exit gate from arXiv:2510.25741
+        and the published modeling code): 48 shared layers run 4 times."""
+        return GPTConfig(
+            vocab_size=49152, hidden_size=2048, num_layers=48, num_heads=16,
+            max_seq_len=65536, ffn_hidden_size=5632, layer_norm_eps=1e-6,
+            tie_word_embeddings=False, norm="rmsnorm", position="rope",
+            rope_theta=1000000.0, bias=False, ffn="swiglu",
+            sandwich_norm=True, loop_steps=4, exit_threshold=1.0)
+
     def num_params(self) -> int:
         h, L, v = self.hidden_size, self.num_layers, self.vocab_size
         f = self.moe_expert_width or self.ffn_hidden_size
@@ -134,11 +157,12 @@ class GPTConfig:
         norm = h * (1 if self.norm == "rmsnorm" else 2)
         biases = (4 * h + e * (f * (mats - 1) + h)) if self.bias else 0
         per_block = 4 * h * h + e * mats * h * f + biases \
-            + (2 + 2 * self.qk_norm) * norm \
+            + (2 + 2 * self.qk_norm + 2 * self.sandwich_norm) * norm \
             + (h * e if self.moe_num_experts else 0)
         return v * h * (1 if self.tie_word_embeddings else 2) \
             + (self.max_seq_len * h if self.position == "learned" else 0) \
-            + L * per_block + norm
+            + L * per_block + norm \
+            + (h + 1 if self.loop_steps > 1 else 0)     # the exit gate
 
     def flops_per_token(self, seq_len=None) -> float:
         """Training FLOPs/token ≈ 6N + 12·L·h·s (attention term)."""
@@ -160,6 +184,48 @@ def rope_rotate(x, theta: float):
     xf = x.astype(jnp.float32)
     half = jnp.concatenate([-xf[..., d // 2:], xf[..., :d // 2]], -1)
     return (xf * cos + half * sin).astype(x.dtype)
+
+
+def rope_at(x, pos, theta: float):
+    """``rope_rotate``'s rotation at given positions: ``x`` [..., t, heads,
+    d], ``pos`` (int, may be traced) broadcastable to ``x.shape[:-2]``.
+    The serving forwards rotate q and k by each token's own cache position,
+    so the cache holds rotated keys."""
+    d = x.shape[-1]
+    inv = 1.0 / theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d)
+    ang = jnp.broadcast_to(pos, x.shape[:-2]).astype(
+        jnp.float32)[..., None, None] * inv
+    cos = jnp.concatenate([jnp.cos(ang)] * 2, -1)
+    sin = jnp.concatenate([jnp.sin(ang)] * 2, -1)
+    xf = x.astype(jnp.float32)
+    half = jnp.concatenate([-xf[..., d // 2:], xf[..., :d // 2]], -1)
+    return (xf * cos + half * sin).astype(x.dtype)
+
+
+def loop_exit(states, gates, threshold: float):
+    """The looped stack's exit rule, per position. ``states`` [T, ..., h]
+    are the final norm's outputs after each loop step, ``gates`` [T, ...]
+    (float32) the exit gate's sigmoid there. ``p_t = g_t prod_{j<t}(1 -
+    g_j)`` for ``t < T`` and ``p_T`` the remainder; a position exits at the
+    first step whose cumulative ``p`` reaches ``threshold``, else at ``T``.
+    Returns (the chosen step's state [..., h], the expected exit step
+    ``sum_t t p_t`` and the chosen step, both float32 [...], steps counted
+    from 1). Every step has run by then: later tokens attend to every
+    step's keys, so the rule picks what the head reads, not what runs."""
+    steps = states.shape[0]
+    stay = jnp.cumprod(1.0 - gates[:-1], axis=0)            # [T-1, ...]
+    before = jnp.concatenate([jnp.ones_like(gates[:1]), stay[:-1]], 0)
+    p = jnp.concatenate([gates[:-1] * before, stay[-1:]], 0)   # [T, ...]
+    t = jnp.arange(1, steps + 1, dtype=jnp.float32).reshape(
+        (steps,) + (1,) * (gates.ndim - 1))
+    expected = jnp.sum(t * p, axis=0)
+    reached = jnp.cumsum(p[:-1], axis=0) >= threshold
+    chosen = jnp.where(reached.any(0), jnp.argmax(reached, axis=0),
+                       steps - 1)
+    out = states[steps - 1]
+    for i in range(steps - 2, -1, -1):      # a where over the T states
+        out = jnp.where((chosen == i)[..., None], states[i], out)
+    return out, expected, (chosen + 1).astype(jnp.float32)
 
 
 class GPTAttention(nn.Layer):
@@ -322,6 +388,10 @@ class GPTBlock(nn.Layer):
                               initializer_range=config.initializer_range)
         else:
             self.mlp = GPTMLP(config)
+        # sandwich norms: each sub-layer's output is normed before it
+        # joins the residual stream
+        self.post_attn_norm = _norm(config) if config.sandwich_norm else None
+        self.post_ffn_norm = _norm(config) if config.sandwich_norm else None
         #: the block's auxiliary loss of its last forward, already
         #: weighted: what GPT.loss adds and what the pipeline's
         #: ``stage_aux`` carries (distributed/hybrid.py); None when dense
@@ -336,9 +406,14 @@ class GPTBlock(nn.Layer):
             h = self.ln_1(x)
         h = self.attn(h)
         with annotate("blk/attn_out"):
+            if self.post_attn_norm is not None:
+                h = self.post_attn_norm(h)
             x = x + h
         with annotate("blk/ffn"):
-            out = x + self.mlp(self.ln_2(x))
+            if self.post_ffn_norm is not None:
+                out = x + self.post_ffn_norm(self.mlp(self.ln_2(x)))
+            else:
+                out = x + self.mlp(self.ln_2(x))
         c = self.config
         if c.moe_dropless:
             # both terms are means over the layers, as HF's pooled
@@ -392,17 +467,52 @@ class GPT(nn.Layer):
                 config.hidden_size, config.vocab_size, has_bias=False,
                 weight_attr=I.Normal(0.0, config.initializer_range),
                 gather_output=True)
+        if config.loop_steps > 1:
+            # one Linear(h, 1) for all loop steps, on the final norm's output
+            self.exit_gate = nn.Linear(
+                config.hidden_size, 1,
+                weight_attr=I.Normal(0.0, config.initializer_range))
 
     def forward(self, tokens):
         x = self.embeddings(tokens)
-        for blk in self.blocks:
-            x = blk(x)
-        x = self.ln_f(x)
+        if self.config.loop_steps > 1:
+            x = self._looped(x)[0]
+        else:
+            for blk in self.blocks:
+                x = blk(x)
+            x = self.ln_f(x)
         if self.config.tie_word_embeddings:
             from ..tensor import matmul
 
             return matmul(x, self.embeddings.wte.weight, transpose_y=True)
         return self.lm_head(x)
+
+    def _looped(self, x):
+        """The stack run ``loop_steps`` times: the final norm after every
+        step (its output starts the next), the exit gate there, and the
+        exit rule's choice among the steps' states. Returns (the chosen
+        state, the expected exit step, the chosen step)."""
+        from ..tensor._helper import apply
+
+        states, gates = [], []
+        for _ in range(self.config.loop_steps):
+            for blk in self.blocks:
+                x = blk(x)
+            x = self.ln_f(x)
+            states.append(x)
+            gates.append(self.exit_gate(x))
+        n, thr = len(states), self.config.exit_threshold
+
+        def rule(*sg):
+            g = jax.nn.sigmoid(jnp.stack(sg[n:])[..., 0].astype(jnp.float32))
+            return loop_exit(jnp.stack(sg[:n]), g, thr)
+
+        return apply(rule, *states, *gates, name="loop_exit")
+
+    def exit_steps(self, tokens):
+        """(expected exit step, chosen exit step) [b, s] of a looped
+        model's full forward over ``tokens``."""
+        return self._looped(self.embeddings(tokens))[1:]
 
     # --- pipeline protocol (distributed/hybrid.py) -----------------------
     def pipeline_stem(self, tokens):
@@ -493,7 +603,7 @@ class GPT(nn.Layer):
         stacked, other = self._decode_state()
         cfg = self.config
         nh, hd = cfg.num_heads, cfg.hidden_size // cfg.num_heads
-        L = cfg.num_layers
+        L = cfg.num_layers * cfg.loop_steps     # a cache a (step, layer)
         dt = other["embeddings.wte.weight"].dtype
 
         # jit cache: retracing the whole prefill+scan program per call
@@ -633,6 +743,11 @@ class GPT(nn.Layer):
         pipeline_head): the [B, S, V] logits never materialize — the
         unfused forward()+cross_entropy spelling cost ~20% of the MoE
         bench step in f32 logit traffic (round-5 ablation)."""
+        if self.config.loop_steps > 1:
+            raise NotImplementedError(
+                "training a looped stack (loop_steps > 1: a scan over steps "
+                "around the layers, gradients summed over the steps, the "
+                "exit-weighted loss) is not implemented; ROADMAP R1")
         x = self.embeddings(tokens)
         for blk in self.blocks:
             x = blk(x)
@@ -643,17 +758,23 @@ class GPT(nn.Layer):
         return loss
 
 
-def _require_gpt3_block(cfg: GPTConfig):
+def _require_served_block(cfg: GPTConfig):
     """The serving forwards below (gpt_block_body and what calls it:
-    generate(), ServingEngine) implement the GPT-3 block only."""
-    if not cfg.is_gpt3_block():
+    generate(), ServingEngine) run every dense block GPTBlock trains; what
+    they still lack is refused here, by what it is."""
+    lacks = []
+    if cfg.moe_num_experts:
+        lacks.append("experts inside the tick (an expert layer under "
+                     "blk/ffn of the serving forwards)")
+    if cfg.qk_norm:
+        lacks.append("QK-norm in the served block")
+    if lacks:
         raise NotImplementedError(
-            "serving and generate() implement the GPT-3 block (LayerNorm, "
-            "learned positions, biases, GELU FFN); this model's block "
-            f"(norm {cfg.norm}, position {cfg.position}, qk_norm "
-            f"{cfg.qk_norm}, bias {cfg.bias}, ffn {cfg.ffn}, drop-less MoE "
-            f"{cfg.moe_dropless}) is supported for training only "
-            "(ROADMAP R1 'serve', after D1/S3)")
+            "serving and generate() run dense blocks (LayerNorm or RMSNorm, "
+            "learned positions or RoPE, with or without biases, GELU or "
+            "SwiGLU, sandwich norms, a looped stack); this model needs "
+            + " and ".join(lacks) + ", supported for training only "
+            "(ROADMAP R1)")
 
 
 def _ln(x, w, b, eps):
@@ -662,33 +783,88 @@ def _ln(x, w, b, eps):
     return (x - m) / jnp.sqrt(var + eps) * w + b
 
 
-def gpt_block_body(xc, p, eps, nh, hd, attend):
+def _rms(x, w, eps):
+    """``F.rms_norm``: float32 statistics and scale, cast back."""
+    xf = x.astype(jnp.float32)
+    ms = jnp.mean(jnp.square(xf), axis=-1, keepdims=True)
+    return (xf / jnp.sqrt(ms + eps) * w.astype(jnp.float32)).astype(x.dtype)
+
+
+def _served_norm(cfg: GPTConfig, x, p, name: str):
+    """The norm ``name`` of the parameters ``p``, of the model's kind."""
+    if cfg.norm == "rmsnorm":
+        return _rms(x, p[name + ".weight"], cfg.layer_norm_eps)
+    return _ln(x, p[name + ".weight"], p[name + ".bias"],
+               cfg.layer_norm_eps)
+
+
+def _linear(cfg: GPTConfig, x, p, name: str):
+    y = x @ p[name + ".weight"]
+    return y + p[name + ".bias"] if cfg.bias else y
+
+
+def _residual(cfg: GPTConfig, xc, x, p, proj: str, post: str):
+    """``xc`` plus the sub-layer's output projection of ``x``, through the
+    sandwich norm ``post`` where the model has one. Without one the sums
+    keep GPT-3's order, ``(xc + x W) + b``: its served program is pinned."""
+    if cfg.sandwich_norm:
+        return xc + _served_norm(cfg, _linear(cfg, x, p, proj), p, post)
+    y = xc + x @ p[proj + ".weight"]
+    return y + p[proj + ".bias"] if cfg.bias else y
+
+
+def _served_head(cfg: GPTConfig, last, other):
+    if cfg.tie_word_embeddings:
+        return last @ other["embeddings.wte.weight"].T
+    return last @ other["lm_head.weight"]
+
+
+def _exit_gate(x, other):
+    """The exit gate's sigmoid at ``x`` [..., h], float32 [...]."""
+    w = other["exit_gate.weight"].astype(jnp.float32)[:, 0]
+    b = other["exit_gate.bias"].astype(jnp.float32)[0]
+    return jax.nn.sigmoid(x.astype(jnp.float32) @ w + b)
+
+
+def gpt_block_body(cfg: GPTConfig, xc, p, attend, pos=None):
     """One pre-norm transformer block over stacked decode params ``p``,
     shared by the dense cached path (gpt_cached_apply) and the paged
     serving tick (gpt_ragged_apply) — the two must stay BITWISE
     identical, so the block math lives in exactly one place and only the
     cache handling differs: ``attend(q, kk, vv) -> (o [n,t,nh,hd],
-    extra)`` writes this layer's KV into its cache and attends."""
+    extra)`` writes this layer's KV into its cache and attends. Of ``cfg``
+    it takes the block's kind (norm, position, bias, ffn, sandwich_norm)
+    and sizes; ``pos`` (RoPE only) is each token's cache position,
+    broadcastable to ``[n, t]``: q and k are rotated before ``attend``, so
+    the cache holds rotated keys."""
     n, t = xc.shape[0], xc.shape[1]
-    h = nh * hd
+    nh = cfg.num_heads
+    h = cfg.hidden_size
+    hd = h // nh
     # blk/* scope names (metadata only): one vocabulary with GPTBlock's
     # forward, read by the traced run's per-part metrics (PERF.md)
     with annotate("blk/qkv"):
-        hn = _ln(xc, p["ln_1.weight"], p["ln_1.bias"], eps)
-        qkv = hn @ p["attn.qkv_proj.weight"] + p["attn.qkv_proj.bias"]
+        hn = _served_norm(cfg, xc, p, "ln_1")
+        qkv = _linear(cfg, hn, p, "attn.qkv_proj")
         qkv = qkv.reshape(n, t, 3, nh, hd)
         q, kk, vv = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
+        if cfg.position == "rope":
+            q = rope_at(q, pos, cfg.rope_theta)
+            kk = rope_at(kk, pos, cfg.rope_theta)
     with annotate("blk/attn"):
         o, extra = attend(q, kk, vv)
     with annotate("blk/attn_out"):
-        o = o.reshape(n, t, h)
-        xc = xc + o @ p["attn.out_proj.weight"] + p["attn.out_proj.bias"]
+        xc = _residual(cfg, xc, o.reshape(n, t, h), p, "attn.out_proj",
+                       "post_attn_norm")
     with annotate("blk/ffn"):
-        h2 = _ln(xc, p["ln_2.weight"], p["ln_2.bias"], eps)
-        mid = jax.nn.gelu(
-            h2 @ p["mlp.fc_in.weight"] + p["mlp.fc_in.bias"],
-            approximate=True)
-        xc = xc + mid @ p["mlp.fc_out.weight"] + p["mlp.fc_out.bias"]
+        h2 = _served_norm(cfg, xc, p, "ln_2")
+        if cfg.ffn == "swiglu":
+            mid = jax.nn.silu(_linear(cfg, h2, p, "mlp.fc_gate")) \
+                * _linear(cfg, h2, p, "mlp.fc_in")
+        else:
+            mid = jax.nn.gelu(_linear(cfg, h2, p, "mlp.fc_in"),
+                              approximate=True)
+        xc = _residual(cfg, xc, mid, p, "mlp.fc_out", "post_ffn_norm")
     return xc, extra
 
 
@@ -707,24 +883,27 @@ def gpt_cached_apply(cfg: GPTConfig, stacked, other, ck, cv, tokens, pos0,
 
     Parity with GPT.forward is pinned by
     tests/test_generation.py::test_cached_prefill_matches_forward.
+
+    A looped model (``cfg.loop_steps`` T > 1) keeps a cache for every
+    (step, layer): ``ck``/``cv`` hold ``T * L`` cache layers, step-major,
+    and the logits are read from the exit rule's step (``loop_exit``).
     """
-    _require_gpt3_block(cfg)
+    _require_served_block(cfg)
     n, t = tokens.shape
-    h = cfg.hidden_size
-    nh = cfg.num_heads
-    hd = h // nh
-    eps = cfg.layer_norm_eps
+    hd = cfg.hidden_size // cfg.num_heads
+    steps, nl = cfg.loop_steps, cfg.num_layers
     wte = other["embeddings.wte.weight"]
-    wpe = other["embeddings.wpe.weight"]
     pos = pos0 + jnp.arange(t)
-    x = wte[tokens] + wpe[pos][None]
+    x = wte[tokens]
+    if cfg.position == "learned":
+        x = x + other["embeddings.wpe.weight"][pos][None]
     smax = ck.shape[2]
     key_pos = jnp.arange(smax)
     # causal-with-cache mask: query i sees cache positions <= pos0 + i
     mask = key_pos[None, None, None, :] <= \
         (pos0 + jnp.arange(t))[None, None, :, None]
 
-    ckl = jnp.swapaxes(ck, 0, 1)            # [L, N, S, NH, D]
+    ckl = jnp.swapaxes(ck, 0, 1)            # [T * L, N, S, NH, D]
     cvl = jnp.swapaxes(cv, 0, 1)
 
     def block(xc, inp):
@@ -739,19 +918,34 @@ def gpt_cached_apply(cfg: GPTConfig, stacked, other, ck, cv, tokens, pos0,
                                axis=-1).astype(xc.dtype)
             return jnp.einsum("bnts,bsnd->btnd", w, v_c), (k_c, v_c)
 
-        return gpt_block_body(xc, p, eps, nh, hd, attend)
+        return gpt_block_body(cfg, xc, p, attend, pos)
 
-    x, (ckl, cvl) = jax.lax.scan(block, x, (stacked, ckl, cvl))
-    x = _ln(x, other["ln_f.weight"], other["ln_f.bias"], eps)
-    if logits_index is None:
-        last = x[:, -1]
-    else:
-        last = jax.lax.dynamic_index_in_dim(x, logits_index, axis=1,
+    def sampled(x):
+        if logits_index is None:
+            return x[:, -1]
+        return jax.lax.dynamic_index_in_dim(x, logits_index, axis=1,
                                             keepdims=False)
-    if "lm_head.weight" in other:
-        logits = last @ other["lm_head.weight"]
+
+    if steps == 1:
+        x, (ckl, cvl) = jax.lax.scan(block, x, (stacked, ckl, cvl))
+        last = sampled(_served_norm(cfg, x, other, "ln_f"))
     else:
-        logits = last @ wte.T
+        def loop_step(xc, caches):
+            xc, caches = jax.lax.scan(block, xc, (stacked,) + caches)
+            with annotate("loop/exit"):
+                xc = _served_norm(cfg, xc, other, "ln_f")
+                state = sampled(xc)
+            return xc, (caches, state)
+
+        by_step = (steps, nl) + ckl.shape[1:]
+        _, ((ckl, cvl), states) = jax.lax.scan(
+            loop_step, x, (ckl.reshape(by_step), cvl.reshape(by_step)))
+        ckl, cvl = (c.reshape((steps * nl,) + c.shape[2:])
+                    for c in (ckl, cvl))
+        with annotate("loop/exit"):
+            last = loop_exit(states, _exit_gate(states, other),
+                             cfg.exit_threshold)[0]
+    logits = _served_head(cfg, last, other)
     return logits, jnp.swapaxes(ckl, 0, 1), jnp.swapaxes(cvl, 0, 1)
 
 
@@ -848,21 +1042,31 @@ def gpt_ragged_apply(cfg: GPTConfig, stacked, other, pools, tokens,
     back, and XLA updates the (donated) stacks in place. Every token's
     write lands before its layer's read: the block's ``attend`` scatters
     first and reads the stacks the scatter returned.
+
+    A looped model (``cfg.loop_steps`` T > 1, ``pools`` of ``T * L`` cache
+    layers) runs that scan inside a scan over ``arange(T)`` with ``(x,
+    pools)`` as its carry too: step ``t``'s layer ``l`` writes and reads
+    cache layer ``t * L + l``, the weights are closed over once, each step
+    ends in the final norm (its output starts the next) and hands out its
+    state at the sampled rows. The head reads the exit rule's step
+    (``loop_exit``), and the forward returns a third value: the expected and
+    the chosen exit step of each sampled row, float32 ``[2, S]``.
     """
-    _require_gpt3_block(cfg)
+    _require_served_block(cfg)
     nt = tokens.shape[0]
     nd = decode_rows
     base = nd * (1 + spec_k)
     nch = (nt - base) // chunk_width if chunk_width else 0
     nh = cfg.num_heads
     hd = cfg.hidden_size // nh
-    eps = cfg.layer_norm_eps
+    steps, nl = cfg.loop_steps, cfg.num_layers
     ps = pools.page_size
     nps = row_tab.shape[1]
     wte = other["embeddings.wte.weight"]
-    wpe = other["embeddings.wpe.weight"]
     with annotate("tick/embed"):
-        x = wte[tokens[:, None]] + wpe[tok_pos[:, None]]    # [NT, 1, h]
+        x = wte[tokens[:, None]]                            # [NT, 1, h]
+        if cfg.position == "learned":
+            x = x + other["embeddings.wpe.weight"][tok_pos[:, None]]
     # token -> ragged row (static: the flat layout never changes);
     # draft tokens share their slot's row (same page table)
     parts = [jnp.arange(nd, dtype=jnp.int32)]
@@ -881,78 +1085,129 @@ def gpt_ragged_apply(cfg: GPTConfig, stacked, other, pools, tokens,
         0)
     off = tok_pos % ps
 
-    def block(carry, inp):
-        xc, pl0 = carry
-        p, layer = inp
+    def stack(x, pools, first):
+        """The ``L`` layers once over ``(x, pools)``, layer ``l`` on cache
+        layer ``first + l``."""
 
-        def attend(q, kk, vv):
-            with annotate("blk/kv_scatter"):
-                pl = pl0.scatter(layer, page, off, kk, vv)
-            outs = []
-            if nd and spec_k:
-                # verify grouping [nd, 1 + spec_k]: each slot's last
-                # token plus its drafts as one chunk-shaped row; the
-                # outputs un-interleave back into flat-buffer order
-                qv = jnp.concatenate(
-                    [q[:nd], q[nd:base, 0].reshape(nd, spec_k, nh, hd)],
-                    axis=1)
-                ov = pl.attend(layer, qv, row_tab[:nd], row_pos0[:nd],
-                               row_len[:nd], impl)
-                outs.append(ov[:, :1])
-                outs.append(ov[:, 1:].reshape(nd * spec_k, 1, nh, hd))
-            elif nd:
-                outs.append(pl.attend(layer, q[:nd], row_tab[:nd],
-                                      row_pos0[:nd], row_len[:nd], impl))
-            if nch:
-                qp = q[base:, 0].reshape(nch, chunk_width, nh, hd)
+        def block(carry, inp):
+            xc, pl0 = carry
+            p, layer = inp
 
-                def chunk_rows():
-                    return pl.attend(layer, qp, row_tab[nd:], row_pos0[nd:],
-                                     row_len[nd:], impl)
+            def attend(q, kk, vv):
+                with annotate("blk/kv_scatter"):
+                    pl = pl0.scatter(layer, page, off, kk, vv)
+                outs = []
+                if nd and spec_k:
+                    # verify grouping [nd, 1 + spec_k]: each slot's last
+                    # token plus its drafts as one chunk-shaped row; the
+                    # outputs un-interleave back into flat-buffer order
+                    qv = jnp.concatenate(
+                        [q[:nd], q[nd:base, 0].reshape(nd, spec_k, nh, hd)],
+                        axis=1)
+                    ov = pl.attend(layer, qv, row_tab[:nd], row_pos0[:nd],
+                                   row_len[:nd], impl)
+                    outs.append(ov[:, :1])
+                    outs.append(ov[:, 1:].reshape(nd * spec_k, 1, nh, hd))
+                elif nd:
+                    outs.append(pl.attend(layer, q[:nd], row_tab[:nd],
+                                          row_pos0[:nd], row_len[:nd], impl))
+                if nch:
+                    qp = q[base:, 0].reshape(nch, chunk_width, nh, hd)
 
-                if has_chunks is None:
-                    op = chunk_rows()
-                else:
-                    op = jax.lax.cond(has_chunks, chunk_rows,
-                                      lambda: jnp.zeros_like(qp))
-                outs.append(op.reshape(nch * chunk_width, 1, nh, hd))
-            o = outs[0] if len(outs) == 1 else \
-                jnp.concatenate(outs, axis=0)
-            return o, pl
+                    def chunk_rows():
+                        return pl.attend(layer, qp, row_tab[nd:],
+                                         row_pos0[nd:], row_len[nd:], impl)
 
-        return gpt_block_body(xc, p, eps, nh, hd, attend), None
+                    if has_chunks is None:
+                        op = chunk_rows()
+                    else:
+                        op = jax.lax.cond(has_chunks, chunk_rows,
+                                          lambda: jnp.zeros_like(qp))
+                    outs.append(op.reshape(nch * chunk_width, 1, nh, hd))
+                o = outs[0] if len(outs) == 1 else \
+                    jnp.concatenate(outs, axis=0)
+                return o, pl
 
-    layers = jnp.arange(cfg.num_layers, dtype=jnp.int32)
-    (x, pools), _ = jax.lax.scan(block, (x, pools), (stacked, layers))
+            return gpt_block_body(cfg, xc, p, attend, tok_pos[:, None]), None
+
+        layers = jnp.arange(nl, dtype=jnp.int32)
+        if first is not None:   # None: one pass, the pinned GPT-3 program
+            layers = first + layers
+        return jax.lax.scan(block, (x, pools), (stacked, layers))[0]
+
+    if steps == 1:
+        x, pools = stack(x, pools, None)
+        with annotate("tick/head"):
+            x = _served_norm(cfg, x, other, "ln_f")
+            logits = _served_head(cfg, x[sample_ix, 0], other)  # [S, V]
+        return logits, pools
+
+    def loop_step(carry, step):
+        xc, pl = stack(*carry, step * nl)
+        with annotate("loop/exit"):
+            xc = _served_norm(cfg, xc, other, "ln_f")
+            state = xc[sample_ix, 0]                        # [S, h]
+        return (xc, pl), state
+
+    (x, pools), states = jax.lax.scan(
+        loop_step, (x, pools), jnp.arange(steps, dtype=jnp.int32))
+    with annotate("loop/exit"):
+        last, expected, chosen = loop_exit(
+            states, _exit_gate(states, other), cfg.exit_threshold)
     with annotate("tick/head"):
-        x = _ln(x, other["ln_f.weight"], other["ln_f.bias"], eps)
-        last = x[sample_ix, 0]                          # [S, h]
-        if "lm_head.weight" in other:
-            logits = last @ other["lm_head.weight"]
-        else:
-            logits = last @ wte.T
-    return logits, pools
+        logits = _served_head(cfg, last, other)
+    return logits, pools, jnp.stack([expected, chosen])
 
 
 def _gpt_decode_state(model: "GPT"):
     """(stacked {sfx: [L, ...]}, other {name: val}) jnp dicts from the
-    eager model, for gpt_cached_apply."""
+    eager model, for gpt_cached_apply. A model built under ``LazyGuard``
+    has no weights yet: its state is drawn here, once, straight into the
+    stacks (``_decode_state_drawer``)."""
+    from ..framework.lazy import is_abstract
     from ..static.functional import state_tensors
 
-    _require_gpt3_block(model.config)
-    if model.config.moe_num_experts:
-        raise NotImplementedError(
-            "generate() supports dense GPT; MoE decode needs expert "
-            "routing in the cached path")
+    _require_served_block(model.config)
     blocks = list(model.blocks)
     sfx, t0 = state_tensors(blocks[0])[:2]
     per_block = [state_tensors(b)[1] for b in blocks]   # one walk per block
-    stacked = {s: jnp.stack([pb[j]._value for pb in per_block], 0)
-               for j, s in enumerate(sfx)}
     pn, pt, _, _ = state_tensors(model)
     block_ids = {id(x) for pb in per_block for x in pb}
-    other = {n: p._value for n, p in zip(pn, pt) if id(p) not in block_ids}
-    return stacked, other
+    rest = [(n, p) for n, p in zip(pn, pt) if id(p) not in block_ids]
+    if any(is_abstract(p) for p in pt):
+        from ..core import rng
+
+        # one jitted call, seeded by the global generator's next key
+        return jax.jit(_decode_state_drawer(sfx, t0, len(blocks), rest))(
+            rng.next_key())
+    stacked = {s: jnp.stack([pb[j]._value for pb in per_block], 0)
+               for j, s in enumerate(sfx)}
+    return stacked, {n: p._value for n, p in rest}
+
+
+def _decode_state_drawer(sfx, block_params, num_layers: int, rest):
+    """``key -> (stacked, other)`` for a model whose parameters are
+    ``LazyGuard``'s placeholders: a scan over the layers draws each from its
+    parameters' recorded initializers, in their own type, into its row of
+    the stacks, so under ``jit`` the device never holds more than the state
+    and one layer's float32 staging, and the model itself stays abstract
+    (ROADMAP S16's serving half). ``block_params`` are one block's
+    parameters: the blocks of a stack are built alike."""
+    def draw(params, key):
+        return [p._lazy_initializer(p._value.shape, p._value.dtype,
+                                    jax.random.fold_in(key, j))
+                for j, p in enumerate(params)]
+
+    def drawer(key):
+        layers_key, rest_key = jax.random.split(key)
+        _, stacks = jax.lax.scan(
+            lambda c, k: (c, draw(block_params, k)), None,
+            jax.random.split(layers_key, num_layers))
+        return dict(zip(sfx, stacks)), \
+            dict(zip([n for n, _ in rest],
+                     draw([p for _, p in rest], rest_key)))
+
+    return drawer
 
 
 class GPTForGeneration(nn.Layer):

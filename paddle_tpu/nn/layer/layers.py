@@ -16,6 +16,7 @@ from __future__ import annotations
 from collections import OrderedDict
 from typing import Iterator, Optional, Tuple
 
+import jax
 import numpy as np
 
 from ...core import dtype as dtype_mod
@@ -236,7 +237,11 @@ class Layer:
         if dtype is not None:
             d = dtype_mod.convert_dtype(dtype)
             for p in self.parameters():
-                p._value = p._value.astype(d)
+                if isinstance(p._value, jax.ShapeDtypeStruct):
+                    # LazyGuard's placeholder: the type it will be drawn in
+                    p._value = jax.ShapeDtypeStruct(p._value.shape, d)
+                else:
+                    p._value = p._value.astype(d)
             for b in self.buffers():
                 import jax.numpy as jnp
 

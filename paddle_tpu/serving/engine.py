@@ -104,6 +104,13 @@ cost), ``serving/tokens_generated``,
 ``serving/tick_temp_bytes`` / ``serving/tick_alias_bytes`` (gauges, set
 once when the tick is first compiled: its ``memory_analysis()``; in
 place means temporaries far under one pool and every pool aliased),
+``serving/cache_layers`` / ``serving/weights_bytes`` (gauges, set once:
+the pools' depth, ``num_layers`` x the model's ``loop_steps``, and the
+bytes of weights the engine holds on the device), of a looped model
+``loop/steps_run`` (counter: loop steps x ticks) and
+``loop/expected_exit_step`` / ``loop/chosen_exit_step`` (gauges: mean over
+the rows sampled since ``exit_steps()`` last read them, from the tick's
+own outputs, set when a tick is drained),
 ``serving/preemptions``, ``serving/requests_finished``,
 ``serving/drain_waited`` / ``serving/drain_ready`` (drained ticks whose
 tokens the host had to wait for / found ready),
@@ -312,6 +319,10 @@ class Request:
     #: ``done`` is True so the scheduler forgets it, but it must never
     #: surface as a served output (``run()``/coordinators skip it)
     canceled: bool = False
+    #: a looped model's exit statistics over the tokens drained so far:
+    #: sums of the expected and of the chosen exit step, one term a token
+    exit_expected: float = 0.0
+    exit_chosen: float = 0.0
 
     @property
     def ttft_from(self) -> float:
@@ -321,12 +332,15 @@ class Request:
 
 
 class _Inflight:
-    __slots__ = ("tok", "meta", "tick", "dispatch_t")
+    __slots__ = ("tok", "meta", "tick", "dispatch_t", "exit")
 
-    def __init__(self, tok, meta, tick):
+    def __init__(self, tok, meta, tick, exit_steps=None):
         self.tok = tok               # device int32 array
         self.meta = meta             # [(index_into_tok, slot, rid)]
         self.tick = tick             # the engine's tick that computes it
+        #: a looped model's exit statistics of the sampled rows, device
+        #: float32 [2, num_slots]: expected and chosen exit step
+        self.exit = exit_steps
         self.dispatch_t = time.perf_counter()
 
 
@@ -382,6 +396,15 @@ class ServingEngine:
         self.model_config = mcfg
         self._stacked, self._other = model._decode_state()
         self._dtype = self._other["embeddings.wte.weight"].dtype
+        #: a looped model runs its layers ``loop_steps`` times a tick and
+        #: keeps a cache for every (step, layer); a configuration without
+        #: the field (any model of the pipeline protocol's shape) runs once
+        self._loop_steps = getattr(mcfg, "loop_steps", 1)
+        if self._loop_steps > 1 and self._spec is not None:
+            raise NotImplementedError(
+                "speculative decoding of a looped model: the verify tick "
+                "(serving/spec.py make_spec_tick) reads no exit step and "
+                "the draft runner's pools are the draft's num_layers deep")
         # page-pool storage dtype (ISSUE 12): None follows the model
         kv_map = {None: self._dtype, "f32": jnp.float32,
                   "bf16": jnp.bfloat16, "int8": jnp.int8}
@@ -394,10 +417,17 @@ class ServingEngine:
         ps = cfg.page_size
         pages_per_slot = cfg.pages_per_slot or -(-mcfg.max_seq_len // ps)
         num_pages = cfg.num_pages or cfg.num_slots * pages_per_slot + 1
-        self.pool = PagePool(mcfg.num_layers, num_pages, ps, nh, hd,
-                             cfg.num_slots, pages_per_slot,
+        self.pool = PagePool(mcfg.num_layers * self._loop_steps, num_pages,
+                             ps, nh, hd, cfg.num_slots, pages_per_slot,
                              dtype=kv_map[cfg.kv_dtype],
                              prefix_cache=cfg.prefix_cache)
+        # set once: how deep the pools are, and what the engine holds on
+        # the device for the model (one copy of the weights, as served)
+        _registry().gauge("serving/cache_layers").set(
+            float(self.pool.num_layers))
+        _registry().gauge("serving/weights_bytes").set(float(sum(
+            a.nbytes for a in jax.tree_util.tree_leaves(
+                (self._stacked, self._other)))))
         self.prefill_chunk = int(cfg.prefill_chunk) or 2 * ps
         if self.prefill_chunk < 1:
             raise ValueError("prefill_chunk must be >= 1")
@@ -440,6 +470,8 @@ class ServingEngine:
         #: export_held() (disaggregated prefill group, ISSUE 13)
         self._held_ready: set = set()
         self.max_inflight_seen = 0
+        # exit steps of the rows sampled since ``exit_steps()`` last read
+        self._exit_sums, self._exit_rows = np.zeros(2), 0
         # device state
         self._last_tok = jnp.zeros((b_slots,), jnp.int32)
         self._keys = np.zeros((b_slots, 2), np.uint32)
@@ -818,6 +850,22 @@ class ServingEngine:
         name — what ``models.gpt.gpt_ragged_apply`` takes."""
         return self._stacked, self._other
 
+    def exit_steps(self, rid: Optional[int] = None) -> Tuple[float, float,
+                                                             int]:
+        """A looped model's exit statistics: (mean expected exit step,
+        mean chosen exit step, tokens) over the tokens drained so far, of
+        request ``rid`` or, with none given, of every row sampled since
+        this was last read that way (the gauges ``loop/expected_exit_step``
+        and ``loop/chosen_exit_step`` hold the same means)."""
+        if rid is not None:
+            req = self._requests[rid]
+            n = len(req.out)
+            return (req.exit_expected / max(n, 1),
+                    req.exit_chosen / max(n, 1), n)
+        sums, n = self._exit_sums, self._exit_rows
+        self._exit_sums, self._exit_rows = np.zeros(2), 0
+        return float(sums[0]) / max(n, 1), float(sums[1]) / max(n, 1), n
+
     def reset_results(self) -> None:
         """Forget finished requests (long-running host keeps memory flat)."""
         self._requests = {rid: r for rid, r in self._requests.items()
@@ -1170,6 +1218,7 @@ class ServingEngine:
             with _ptrace.scope("step/drain", tick=ent.tick,
                                waited=int(waited)):
                 toks = np.asarray(ent.tok)
+                exits = None if ent.exit is None else np.asarray(ent.exit)
                 now = time.perf_counter()
                 for idx, slot, rid in ent.meta:
                     req = self._requests[rid]
@@ -1177,6 +1226,11 @@ class ServingEngine:
                         continue    # EOS discovered while in flight
                     tok = int(toks[idx])
                     req.out.append(tok)
+                    if exits is not None:
+                        req.exit_expected += float(exits[0, idx])
+                        req.exit_chosen += float(exits[1, idx])
+                        self._exit_sums += exits[:, idx]
+                        self._exit_rows += 1
                     _registry().counter("serving/tokens_generated").add(1)
                     if req.first_token_t is None:
                         req.first_token_t = now
@@ -1199,6 +1253,11 @@ class ServingEngine:
                     elif len(req.out) >= req.max_new:
                         self._finish(slot, rid, reason="max_new")
             reg = _registry()
+            if exits is not None and self._exit_rows:
+                reg.gauge("loop/expected_exit_step").set(
+                    self._exit_sums[0] / self._exit_rows)
+                reg.gauge("loop/chosen_exit_step").set(
+                    self._exit_sums[1] / self._exit_rows)
             reg.counter("serving/drain_waited" if waited
                         else "serving/drain_ready").add(1)
             reg.histogram("serving/tick_turnaround_ms").observe(
@@ -1611,14 +1670,16 @@ class ServingEngine:
         with _ptrace.scope("step/build", tick=self._tick_no):
             args, finishers = self._build_unified(chunks, ticking)
         with _ptrace.scope("step/dispatch", tick=self._tick_no):
-            self.pool.pools, tok, self._last_tok = self._run_tick(args)
+            self.pool.pools, tok, self._last_tok, *exit_steps = \
+                self._run_tick(args)
         meta = [(s, s, self._slot_rid[s]) for s in ticking]
         meta += [(s, s, rid) for s, rid in finishers]
         if meta:
             # chunk-only ticks (no decodes, no finishers) emit nothing
             # worth syncing — queueing them would stall the host on a
             # token vector nobody reads once the window fills
-            self._inflight.append(_Inflight(tok, meta, self._tick_no))
+            self._inflight.append(_Inflight(tok, meta, self._tick_no,
+                                            *exit_steps))
         self._tick_no += 1
         self.max_inflight_seen = max(self.max_inflight_seen,
                                      len(self._inflight))
@@ -1637,6 +1698,8 @@ class ServingEngine:
             self._insert_prefix(s, self._requests[rid].prompt, end)
         reg = _registry()
         reg.counter("serving/ticks").add(1)
+        if self._loop_steps > 1:
+            reg.counter("loop/steps_run").add(self._loop_steps)
         if chunks:
             reg.counter("serving/prefill_chunks").add(len(chunks))
         reg.gauge("serving/mixed_rows").set(float(len(ticking)
@@ -1745,7 +1808,9 @@ class ServingEngine:
             # writes land on the null page; all-null tables), and
             # ``has_chunks`` only lets the block skip their attention,
             # which reads the pools and returns ``[nch, w, NH, D]``.
-            logits, pools = gpt_ragged_apply(
+            # a looped model's forward also hands out its exit statistics,
+            # which leave the tick as one more output (no callback)
+            logits, pools, *exit_steps = gpt_ragged_apply(
                 mcfg, stacked, other, pools, tokens, tok_pos, tok_limit,
                 row_tab, row_pos0, row_len, sample_ix, decode_rows=ns,
                 chunk_width=w, impl=impl, has_chunks=has_chunks)
@@ -1753,7 +1818,7 @@ class ServingEngine:
                 nxt = self._sample_tok(logits, keys, sample_pos, temps,
                                        top_ks, top_ps)
                 new_last = jnp.where(emit, nxt, last_tok)
-            return pools, nxt, new_last
+            return (pools, nxt, new_last, *exit_steps)
 
         return tick
 

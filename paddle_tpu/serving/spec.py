@@ -145,6 +145,11 @@ class DraftRunner:
     def __init__(self, draft_model, num_slots: int, capacity: int,
                  k: int, feed_width: int, pool):
         cfg = draft_model.config
+        if not cfg.is_gpt3_block():
+            raise NotImplementedError(
+                "the draft tick embeds learned positions and keeps its own "
+                "pools of num_layers: it runs GPT-3's block only, not RoPE, "
+                "sandwich norms or a looped stack")
         self.config = cfg
         self.k = int(k)
         self.capacity = int(capacity)
@@ -328,7 +333,7 @@ def make_draft_tick(cfg, num_slots: int, capacity: int, k: int,
                     return jnp.einsum("bnts,bsnd->btnd", w, vw), \
                         (kcl, vcl)
 
-                return gpt_block_body(xc, p, eps, nh, hd, attend)
+                return gpt_block_body(cfg, xc, p, attend)
 
             _, (kc, vc) = jax.lax.scan(block, x, (stacked, kc, vc))
             return kc, vc
@@ -390,7 +395,7 @@ def make_draft_tick(cfg, num_slots: int, capacity: int, k: int,
                     return jnp.einsum("bnts,bsnd->btnd", w, vw), \
                         (kcl, vcl)
 
-                return gpt_block_body(xc, pp, eps, nh, hd, attend)
+                return gpt_block_body(cfg, xc, pp, attend)
 
             x, (kc, vc) = jax.lax.scan(block, x, (stacked, kc, vc))
             x = _ln(x, other["ln_f.weight"], other["ln_f.bias"], eps)

@@ -2,6 +2,7 @@
 calls, at toy sizes on the virtual CPU mesh (Pallas kernels interpreted),
 plus the script's refusals — no accelerator, no backend at import, and
 where the compile cache goes."""
+import dataclasses
 import os
 import subprocess
 import sys
@@ -137,6 +138,19 @@ def test_serve_rehearsal():
     assert out["tick_temp_bytes"] > 0 and out["tick_alias_bytes"] > 0
     assert len(out["submits"]) == 6
     assert sum(1 for inflight, _ in out["submits"] if inflight) >= 2
+
+
+def test_serve_looped_rehearsal():
+    """The looped pass at a toy width: 2 layers run 4 times, 8 cache
+    layers, tokens and exit steps against the float32 reference."""
+    cfg = dataclasses.replace(
+        GPTConfig.ouro_2_6b(), vocab_size=256, hidden_size=64, num_heads=4,
+        num_layers=2, ffn_hidden_size=96, max_seq_len=128)
+    out = chip_smoke.phase_serve_looped(cfg, 2, 4, 32)
+    assert out["cache_layers"] == 8
+    assert out["weights_bytes"] == 2 * cfg.num_params()
+    assert out["worst"] <= chip_smoke.TOL_LOOP_SHORTFALL
+    assert out["exit_gap"] <= chip_smoke.TOL_LOOP_EXIT
 
 
 def test_a_submit_behind_the_ticks_in_flight_fails_the_serve_phase():

@@ -268,6 +268,82 @@ def test_tpu_compile_serving_tick(monkeypatch):
         f"{ma.temp_size_in_bytes} bytes of temporaries: a pool is copied"
 
 
+def test_tpu_compile_looped_serving_tick(monkeypatch):
+    """ISSUE 33: a looped model's tick (4 loop steps over 2 layers: pools 8
+    cache layers deep, the carry of the scan over steps and of the layer
+    scan inside it; RoPE, sandwich RMSNorm, SwiGLU) compiles for the v5e
+    and updates its bf16 pools in place there: no pool-sized temporary,
+    both pools aliased."""
+    import paddle_tpu as paddle
+    from paddle_tpu.models import GPT, GPTConfig
+    from paddle_tpu.serving import ServingConfig, ServingEngine
+
+    dev = _tpu_topology_devices()[0]
+    paddle.seed(0)
+    net = GPT(GPTConfig(
+        vocab_size=256, hidden_size=256, num_layers=2, num_heads=2,
+        max_seq_len=128, ffn_hidden_size=384, layer_norm_eps=1e-6,
+        tie_word_embeddings=False, norm="rmsnorm", position="rope",
+        rope_theta=1e6, bias=False, ffn="swiglu", sandwich_norm=True,
+        loop_steps=4))
+    net.eval()
+    net.bfloat16()
+    eng = ServingEngine(net, ServingConfig(num_slots=2, page_size=16,
+                                           num_pages=1025))
+    eng.submit(np.arange(5, dtype=np.int32), 2)
+    eng.step()
+    eng.drain(0)
+    fn, avals = eng._program_args[eng.compiled_sites[0]]
+    avals = jax.tree_util.tree_map(
+        lambda a: _on_tpu(dev, a.shape, a.dtype), avals)
+    monkeypatch.setenv("PADDLE_TPU_TARGET_PLATFORM", "tpu")
+    ma = fn.lower(*avals).compile().memory_analysis()
+    one_pool = eng.pool.k.nbytes
+    assert eng.pool.k.shape[0] == 8 and one_pool > 30e6
+    assert eng.pool.k.dtype == jax.numpy.bfloat16
+    assert ma.alias_size_in_bytes >= 2 * one_pool, \
+        "the donated page pools are not aliased"
+    assert ma.temp_size_in_bytes < one_pool, \
+        f"{ma.temp_size_in_bytes} bytes of temporaries: a pool is copied"
+
+
+def test_tpu_compile_a_lazy_models_state_draw(monkeypatch):
+    """A ``LazyGuard`` model's served state is drawn on the v5e straight
+    into the bf16 stacks: beside them the compiled draw holds less than one
+    layer's float32 (models/gpt._decode_state_drawer; at Ouro-2.6B's sizes
+    it compiles to 0 B of temporaries for 5.34 GB of state, PERF.md)."""
+    import jax.numpy as jnp
+
+    import paddle_tpu as paddle
+    from paddle_tpu.models import GPT, GPTConfig
+    from paddle_tpu.models import gpt as gpt_mod
+    from paddle_tpu.static.functional import state_tensors
+
+    dev = _tpu_topology_devices()[0]
+    cfg = GPTConfig(
+        vocab_size=512, hidden_size=512, num_layers=6, num_heads=4,
+        max_seq_len=128, ffn_hidden_size=1408, layer_norm_eps=1e-6,
+        tie_word_embeddings=False, norm="rmsnorm", position="rope",
+        rope_theta=1e6, bias=False, ffn="swiglu", sandwich_norm=True,
+        loop_steps=4)
+    with paddle.LazyGuard():
+        net = GPT(cfg)
+    net.bfloat16()
+    blocks = list(net.blocks)
+    sfx, t0 = state_tensors(blocks[0])[:2]
+    in_blocks = {id(p) for b in blocks for p in b.parameters()}
+    rest = [(n, p) for n, p in net.named_parameters()
+            if id(p) not in in_blocks]
+    drawer = gpt_mod._decode_state_drawer(sfx, t0, len(blocks), rest)
+    monkeypatch.setenv("PADDLE_TPU_TARGET_PLATFORM", "tpu")
+    ma = jax.jit(drawer).lower(
+        _on_tpu(dev, (2,), jnp.uint32)).compile().memory_analysis()
+    assert ma.output_size_in_bytes >= 2 * cfg.num_params()
+    layer_f32 = 4 * sum(int(np.prod(p._value.shape)) for p in t0)
+    assert ma.temp_size_in_bytes < layer_f32, \
+        f"{ma.temp_size_in_bytes} bytes of temporaries beside the stacks"
+
+
 @pytest.mark.parametrize("mesh_devices", [1, 2])
 def test_tpu_compile_dropless_moe_at_olmoe_widths(monkeypatch, mesh_devices):
     """One drop-less expert layer at OLMoE's widths (4,096 tokens, 64
