@@ -10,7 +10,9 @@ finds, and checks what comes out by the repo's own means:
   0 device    a TPU that the one peak table knows; memory_stats reports;
               every Pallas gate reads "compile with Mosaic"
   1 kernels   flash fwd+bwd and the ragged paged kernel (bf16 and int8
-              pools), compiled by Mosaic, against their references
+              pools, at the serving cells' row shapes: 12 rows over 128
+              pages a slot, 10 over 32), compiled by Mosaic, against their
+              references
   1b experts  the drop-less expert layer at OLMoE's widths (64 experts of
               1024, 8 a token, 4096 tokens), forward and gradients against
               the float32 reference, balanced and with one expert forced
@@ -23,7 +25,8 @@ finds, and checks what comes out by the repo's own means:
               bf16: the loss against the float32 reference, and the scan
               took the path production takes here, the Pallas kernels
               kda_fwd/kda_bwd_*
-  2 serve     ServingEngine.submit/step/run, ten requests over ~150 ticks
+  2 serve     ServingEngine.submit/step/run, ten requests over ~150 ticks;
+              the tick took its attention through the ragged kernel
   3 train     HybridPipelineTrainer.step, bench.py's headline knobs
   4 multichip the same trainer on dp2 x tp2 and pp2 x tp2, and the ZeRO /
               int8-ring arms of compile_train_step on dp=4 (only with
@@ -258,11 +261,12 @@ def check_flash(b: int, s: int, h: int, d: int, dtype) -> dict:
 
 
 def check_ragged(num_pages: int, ps: int, nh: int, hd: int, nps: int,
-                 t: int, int8: bool) -> float:
+                 t: int, int8: bool, rows: int = 4) -> float:
     """``ragged_paged_attention(impl="pallas")`` against ``impl="xla"``
-    in f32 on the same pools: four rows of ``t`` queries with ragged
-    pos0/true_len, null-page table entries behind each row's frontier,
-    and one row that ends exactly at the slot capacity ``nps * ps``.
+    in f32 on the same pools: ``rows`` rows of ``t`` queries with ragged
+    pos0/true_len (four patterns, each later round of them a third of the
+    capacity further on), null-page table entries behind each row's
+    frontier, and rows that end exactly at the slot capacity ``nps * ps``.
     Only real queries (index < true_len) are compared."""
     import jax
     import jax.numpy as jnp
@@ -271,10 +275,11 @@ def check_ragged(num_pages: int, ps: int, nh: int, hd: int, nps: int,
 
     cap = nps * ps
     rng = np.random.RandomState(1)
-    true_len = np.array([t, max(1, t // 2 + 1), 1, t], np.int32)
-    pos0 = np.array([cap - t, ps + 3, 0, 5 * ps - 1], np.int32)
+    r = rows
+    true_len = np.resize([t, max(1, t // 2 + 1), 1, t], r).astype(np.int32)
+    pos0 = np.resize([cap - t, ps + 3, 0, 5 * ps - 1], r) \
+        + np.arange(r) // 4 * (cap // 3 + 1)
     pos0 = np.minimum(pos0, cap - true_len).astype(np.int32)
-    r = len(pos0)
     table = np.zeros((r, nps), np.int32)          # 0 = null page
     free = rng.permutation(np.arange(1, num_pages))
     used = 0
@@ -324,7 +329,14 @@ def check_ragged(num_pages: int, ps: int, nh: int, hd: int, nps: int,
     return err
 
 
-def phase_kernels(flash_shape, pool_shape, nps: int, chunk: int) -> None:
+#: (rows, pages a slot, (T, int8) of each check): the serving cells' row
+#: shapes. gpt3-1.3b-serve: 12 decode rows and a chunk of 32 over 128 pages
+#: a slot; ouro-2.6b-serve: 10 rows over 32
+RAGGED_ROWS = ((12, 128, ((1, False), (32, False), (1, True), (32, True))),
+               (10, 32, ((1, False),)))
+
+
+def phase_kernels(flash_shape, page: int, heads: int, head_dim: int) -> None:
     import jax.numpy as jnp
 
     t0 = time.perf_counter()
@@ -332,11 +344,12 @@ def phase_kernels(flash_shape, pool_shape, nps: int, chunk: int) -> None:
     say("kernels", f"flash fwd+bwd {flash_shape} bf16 vs mha_reference: " +
         " ".join(f"{k}={v:.2e}" for k, v in errs.items()) +
         f" (tol {TOL_FLASH_FWD}/{TOL_FLASH_BWD})")
-    for int8 in (False, True):
-        for t in (1, chunk):
-            err = check_ragged(*pool_shape, nps, t, int8)
+    for rows, nps, kinds in RAGGED_ROWS:
+        pool_shape = (rows * nps + 1, page, heads, head_dim)
+        for t, int8 in kinds:
+            err = check_ragged(*pool_shape, nps, t, int8, rows=rows)
             say("kernels", f"ragged pallas vs xla pools={pool_shape} "
-                f"{'int8+scales' if int8 else 'bf16'} T={t}: "
+                f"{rows} rows {'int8+scales' if int8 else 'bf16'} T={t}: "
                 f"err={err:.2e} (tol {TOL_RAGGED})")
     say("kernels", f"{time.perf_counter() - t0:.1f} s, "
         f"peak so far {_gb(peak_bytes())}")
@@ -453,6 +466,28 @@ def check_submits(submits, limit_ms: float) -> None:
           f"each may take {limit_ms}")
 
 
+def attn_calls() -> dict:
+    """``serving/attn_calls{path=}``: attention calls by the path they
+    took, counted on the host while a tick is traced."""
+    from paddle_tpu.profiler import registry
+
+    return {p: registry().counter("serving/attn_calls{path=%s}" % p).value
+            for p in ("pallas", "xla")}
+
+
+def check_attn_path(before: dict) -> str:
+    """The ticks traced since ``before`` took the platform's attention and
+    no other: on the chip the ragged kernel (the CPU's rehearsal runs the
+    XLA spelling)."""
+    import jax
+
+    want = "xla" if jax.default_backend() == "cpu" else "pallas"
+    took = {p: n - before[p] for p, n in attn_calls().items()}
+    check(0 < took[want] == sum(took.values()),
+          f"the engine's tick took its attention by {took}, not by {want}")
+    return want
+
+
 def check_tick_memory(temp: float, alias: float, pool_bytes) -> None:
     """The pools stay where they are (ROADMAP S3): the compiled tick's
     temporaries (gauge ``serving/tick_temp_bytes``) are under ONE pool's
@@ -483,6 +518,7 @@ def phase_serve(cfg, num_slots: int, page_size: int, requests,
         return reg.counter("serving/" + name).value
 
     t_setup = time.perf_counter()
+    attn_before = attn_calls()
     paddle.seed(0)
     net = GPT(cfg)
     net.eval()
@@ -549,6 +585,7 @@ def phase_serve(cfg, num_slots: int, page_size: int, requests,
         # a whole bf16 pool to float32 around every scatter; what the CPU
         # can say, it says on float32 pools in tests/test_pools.py
         check_tick_memory(tick_temp, tick_alias, pool_bytes)
+    attn_path = check_attn_path(attn_before)
 
     for i, (_, _, want, _) in enumerate(requests):
         out = results.get(rids[i])
@@ -599,7 +636,8 @@ def phase_serve(cfg, num_slots: int, page_size: int, requests,
     say("serve", f"ticks={out['ticks']} {kinds} chunks="
         f"{out['prefill_chunks']} tokens={out['tokens']} "
         f"prefix_hit_tokens={out['prefix_hit_tokens']} sites="
-        f"{eng.compiled_sites} traced once, no donation warning")
+        f"{eng.compiled_sites} traced once, attention through "
+        f"{attn_path}, no donation warning")
     say("serve", f"the compiled tick: serving/tick_temp_bytes "
         f"{tick_temp:.0f} ({_gb(tick_temp)}), serving/tick_alias_bytes "
         f"{tick_alias:.0f} ({_gb(tick_alias)}); the pools "
@@ -632,6 +670,7 @@ def phase_serve_looped(cfg, num_slots: int, page_size: int,
 
     reg = registry()
     t_setup = time.perf_counter()
+    attn_before = attn_calls()
     paddle.seed(0)
     with paddle.LazyGuard():
         net = GPT(cfg)
@@ -664,6 +703,7 @@ def phase_serve_looped(cfg, num_slots: int, page_size: int,
     pool_bytes = [a.nbytes for a in eng.pool.pools.arrays().values()]
     if jax.default_backend() != "cpu":      # as in phase_serve
         check_tick_memory(tick_temp, tick_alias, pool_bytes)
+    check_attn_path(attn_before)
 
     def layer_weights():
         for i in range(cfg.num_layers):
@@ -982,7 +1022,6 @@ def main() -> int:
     cfg = GPTConfig.gpt3_1_3b()
     heads, head_dim = cfg.num_heads, cfg.hidden_size // cfg.num_heads
     page, slots = 16, 8
-    nps = cfg.max_seq_len // page
     failed, seen = [], {}
 
     def run(name, fn):
@@ -998,8 +1037,7 @@ def main() -> int:
         say(name, f"phase wall {time.perf_counter() - t0:.1f} s")
 
     run("kernels", lambda: phase_kernels(
-        (2, cfg.max_seq_len, heads, head_dim),
-        (slots * nps + 1, page, heads, head_dim), nps, chunk=2 * page))
+        (2, cfg.max_seq_len, heads, head_dim), page, heads, head_dim))
     olmoe = GPTConfig.olmoe_1b_7b()
     run("experts", lambda: phase_experts(
         olmoe.max_seq_len, olmoe.hidden_size, olmoe.moe_expert_width,

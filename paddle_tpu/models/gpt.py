@@ -952,7 +952,7 @@ def gpt_cached_apply(cfg: GPTConfig, stacked, other, ck, cv, tokens, pos0,
 def gpt_ragged_apply(cfg: GPTConfig, stacked, other, pools, tokens,
                      tok_pos, tok_limit, row_tab, row_pos0, row_len,
                      sample_ix, decode_rows: int, chunk_width: int,
-                     impl: str = "xla", spec_k: int = 0, has_chunks=None):
+                     impl=None, spec_k: int = 0, has_chunks=None):
     """Mixed prefill/decode forward over the PAGED cache: every token
     in flight rides one program. ``pools`` is the page pools
     (``serving.paged_cache.Pools``, stacked over layers) — this forward

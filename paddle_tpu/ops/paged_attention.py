@@ -15,36 +15,41 @@ spelling. The engine's mixed prefill/decode tick flattens every token
 in flight into rows of this one call (``models/gpt.py::
 gpt_ragged_apply``, through ``serving.paged_cache.Pools.attend``).
 
-Two implementations behind the one entry point, following the
-``ops/int8_matmul.py`` precedent (kernel built and gated; the XLA
-spelling is the measured default until the kernel wins on hardware):
+Two implementations behind the one entry point, picked where the
+program is traced (``resolve_impl``: ``core.place.target_platform()``,
+as ``ops/flash_attention.py`` picks Mosaic or interpret mode), unless
+the caller names one:
 
-- ``impl="xla"`` (default): gather each row's pages into a contiguous
-  ``[R, S_cap, NH, D]`` view and run exactly the dense-cache attention
-  expression from ``models/gpt.py::gpt_cached_apply`` — same einsum
-  contractions, same mask constant, same f32 softmax — via the ONE
-  shared helper ``_gather_attend`` (decode rows, chunk rows and
-  verify rows all route here, so "same expression" is enforced by
-  code, not by a verbatim-copy comment). This is what makes greedy
-  paged decode **bitwise** equal to the dense ``generate`` path
-  (tests/test_serving.py): XLA fuses the gather into the attention so
-  the page indirection costs index arithmetic, not a second cache.
-- ``impl="pallas"``: the ragged Pallas kernel — grid
-  ``(rows, pages_per_slot)``, page table / pos0 / true_len
-  scalar-prefetched so each grid step DMAs one page directly from the
-  pool (no materialized gather), online-softmax accumulation in VMEM
-  scratch across the page axis, and **fully-masked page blocks
-  skipped**: a block whose first position exceeds the row's last
-  attendable position (``pos0 + true_len - 1``) contributes nothing,
-  so its compute is predicated off and its DMA is routed to the null
-  page by the index map (the grid still visits the step — the win is
-  skipped FLOPs + a cached null-page fetch, stated honestly). Gated
-  behind the same TPU guard as ``ops/flash_attention.py`` (interpret
-  mode on CPU). Numerics are allclose, not bitwise, vs the XLA path
-  (online softmax reassociates the reduction), so the serving engine
-  only selects it on explicit request. On a v5e it compiles and agrees
-  with the XLA spelling for bf16 and int8 pools (chip_smoke.py phase
-  1); a default flip waits for a speed measurement (ROADMAP S6).
+- ``impl="xla"`` (the reference, and what anything but a TPU runs):
+  gather each row's pages into a contiguous ``[R, S_cap, NH, D]`` view
+  and run exactly the dense-cache attention expression from
+  ``models/gpt.py::gpt_cached_apply`` — same einsum contractions, same
+  mask constant, same f32 softmax — via the ONE shared helper
+  ``_gather_attend`` (decode rows, chunk rows and verify rows all route
+  here, so "same expression" is enforced by code, not by a
+  verbatim-copy comment). This is what makes greedy paged decode
+  **bitwise** equal to the dense ``generate`` path
+  (tests/test_serving.py, which run on the CPU). Its cost is the slot's
+  capacity whatever is live: on a v5e 14.3 ms for the 24 layers of 12
+  decode rows of 2,048 (PERF.md section 6, PR 34).
+- ``impl="pallas"`` (the chip's serving kernel, ISSUE 34): the ragged
+  kernel ``ragged_paged_attn`` — grid ``(rows,)``; the pools stay in
+  HBM, stacked, and the KV axis is a loop INSIDE the kernel over blocks
+  of ``_BLOCK_TOKENS`` positions whose trip count is the row's own
+  ``ceil((pos0 + true_len) / block)``, read from the scalar-prefetched
+  metadata. A block's live pages are fetched by page id with the
+  kernel's own asynchronous copies into one of two VMEM buffers, the
+  next block (or the next row's first) in flight while this one is
+  multiplied, so **a page past a row's last live position is never
+  read** (tests/test_ragged_kernel.py fills them with NaN) and a row of
+  length 0 costs a grid step and no copy. bf16 pages meet the MXU as
+  bf16, float32 accumulated, under a float32 online softmax; int8 pages
+  are widened in VMEM and their scale rows applied to the block's
+  scores and weights. On a v5e it reads a long row's live K and V at
+  690-740 GB/s (PERF.md section 6, PR 34). Numerics are allclose, not
+  bitwise, vs the XLA path (online softmax reassociates the reduction):
+  on the chip the engine is held by the benchmark's check against the
+  float32 reference and by ``chip_smoke.py``'s kernel-against-XLA phase.
 
 Layout note: pools are ``[num_pages, page_size, NH, D]`` per layer;
 page 0 is the null page (writes of inactive rows land there, gathers
@@ -68,9 +73,10 @@ scale grows (``round(q·s_old/s_new)`` — an exact no-op while the
 scale is unchanged, which is the steady state), and the new token is
 quantized at the final scale; the null page's scale contribution is
 masked so it stays 0 forever. The read side dequantizes inside
-``_gather_attend`` for the XLA spelling, and in VMEM for the Pallas
-kernel (which DMAs each page's scale rows by the page's own index map
-and dequantizes before the online softmax). The f32 path is
+``_gather_attend`` for the XLA spelling; the Pallas kernel widens the
+int8 pages in VMEM (exactly) and multiplies the block's scores by the
+K pages' scales and its weights by the V pages' (the same product, with
+one rounding fewer). The f32 path is
 bit-for-bit untouched (no cast, no extra ops) — the engine's bitwise
 parity contract only ever applied to unquantized pools, and still
 does.
@@ -82,6 +88,7 @@ import math
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -94,6 +101,17 @@ def _interpret() -> bool:
     from ..core.place import target_platform
 
     return target_platform() == "cpu"
+
+
+def resolve_impl(impl=None) -> str:
+    """``"pallas"`` or ``"xla"``: ``impl`` itself when given, else the
+    platform's, where the program is being traced (the kernel where it
+    is compiled for a TPU, the XLA spelling anywhere else)."""
+    if impl is not None:
+        return impl
+    from ..core.place import target_platform
+
+    return "pallas" if target_platform() == "tpu" else "xla"
 
 
 def _at(layer, *index):
@@ -167,7 +185,7 @@ def _gather_attend(q, k_pool, v_pool, page_table, qpos,
 
 
 def ragged_paged_attention(q, k_pool, v_pool, page_table, pos0, true_len,
-                           impl: str = "xla", k_scale=None,
+                           impl=None, k_scale=None,
                            v_scale=None, layer=None):
     """One attention call over ragged rows of the page pool.
 
@@ -182,6 +200,10 @@ def ragged_paged_attention(q, k_pool, v_pool, page_table, pos0, true_len,
     layer       int32 scalar   with it the pools (and scales) are the
                                stacks ``[L, ...]`` and this layer of
                                them is read, by index (both impls)
+    impl        None           the platform's (``resolve_impl``), or
+                               ``"xla"`` / ``"pallas"``; counted, while
+                               the program is traced, in
+                               ``serving/attn_calls{path=}``
 
     Query ``i`` of row ``r`` attends cache positions
     ``<= pos0[r] + i``. Rows are fixed-shape: queries at
@@ -190,18 +212,22 @@ def ragged_paged_attention(q, k_pool, v_pool, page_table, pos0, true_len,
     are additionally skipped, so the garbage differs between impls —
     never compare pad queries). Returns [R, T, NH, D].
     """
+    from ..profiler import metrics
+
+    impl = resolve_impl(impl)
+    if impl not in ("xla", "pallas"):
+        raise ValueError(f"unknown paged attention impl {impl!r}")
+    metrics.registry().counter(
+        "serving/attn_calls{path=%s}" % impl).add(1)
     if impl == "xla":
         t = q.shape[1]
         qpos = pos0[:, None] + jnp.arange(t, dtype=pos0.dtype)[None, :]
         return _gather_attend(q, k_pool, v_pool, page_table, qpos,
                               k_scale=k_scale, v_scale=v_scale,
                               layer=layer)
-    if impl == "pallas":
-        return _ragged_attention_pallas(q, k_pool, v_pool, page_table,
-                                        pos0, true_len,
-                                        k_scale=k_scale, v_scale=v_scale,
-                                        layer=layer)
-    raise ValueError(f"unknown paged attention impl {impl!r}")
+    return _ragged_attention_pallas(q, k_pool, v_pool, page_table, pos0,
+                                    true_len, k_scale=k_scale,
+                                    v_scale=v_scale, layer=layer)
 
 
 def paged_kv_scatter(pool, scale, page, off, vals, layer=None):
@@ -272,78 +298,211 @@ def paged_kv_scatter(pool, scale, page, off, vals, layer=None):
 # Pallas ragged kernel
 # --------------------------------------------------------------------------
 
-def _ragged_kernel(pt_ref, pos0_ref, tl_ref, layer_ref, q_ref, k_ref, v_ref,
-                   *rest, page_size: int, n_pages: int):
-    """Grid (r, j): row r consumes its j-th page. Page table, pos0,
-    true_len and the layer are scalar-prefetched, so the BlockSpec index
-    map DMAs page ``pt[r, j]`` of layer ``layer[0]`` straight from the
-    stacked pool (the layer axis is squeezed out of the block: the body
-    sees one page) — the gathered
-    [R, S_cap] intermediate of the XLA path never exists — and routes
-    fully-masked blocks (``j*ps > pos0 + true_len - 1``, where nothing
-    in the page is attendable by any real query of the row) to the
-    null page with their compute predicated off. Running max /
-    denominator / accumulator live in VMEM scratch across the page
-    axis (online softmax). Quantized pools add two inputs — the
-    per-page per-head scale rows, DMA'd by the SAME index map as the
-    page itself — and dequantize in VMEM right after the (int8) page
-    loads, before anything touches the MXU."""
-    if len(rest) == 6:
-        ks_ref, vs_ref, o_ref, m_ref, l_ref, acc_ref = rest
-    else:
-        o_ref, m_ref, l_ref, acc_ref = rest
-        ks_ref = vs_ref = None
+#: tokens of one KV block: what one trip of the kernel's loop fetches
+#: and multiplies (several pages; a v5e moves it in about 2.5 us)
+_BLOCK_TOKENS = 256
+
+
+def kv_block_pages(page_size: int, pages_per_slot: int) -> int:
+    """Pages in one KV block of the kernel, from the static shapes."""
+    return max(1, min(pages_per_slot, _BLOCK_TOKENS // page_size))
+
+
+def live_block_share(pos0, true_len, page_size: int,
+                     pages_per_slot: int) -> float:
+    """Blocks the kernel's loops visit for rows ``(pos0, true_len)``
+    (host arrays) over the blocks of the same rows at capacity."""
+    bt = kv_block_pages(page_size, pages_per_slot) * page_size
+    cap = page_size * pages_per_slot
+    pos0, true_len = np.asarray(pos0), np.asarray(true_len)
+    live = np.where(true_len > 0, np.minimum(pos0 + true_len, cap), 0)
+    return float((-(-live // bt)).sum()) / (len(live) * -(-cap // bt))
+
+
+def _rows_per_word(dtype) -> int:
+    return 4 // jnp.dtype(dtype).itemsize
+
+
+def _heads(ref, nh: int):
+    """Every head of ``ref`` ``[..., NH, D]`` (a VMEM ref whose heads fill
+    whole tiles), each as ``[rows, D]``: head ``h`` is row ``t * NH + h``
+    of the flat view for every ``t``, one strided load. 16-bit rows come
+    two to a 32-bit word, so a load brings two heads, taken apart with a
+    shift and a mask."""
+    hd = ref.shape[-1]
+    rows = math.prod(ref.shape[:-1]) // nh
+    flat = ref.reshape(rows * nh, hd)
+    w = _rows_per_word(ref.dtype)
+    if w == 1:
+        return [flat[pl.ds(h, rows, stride=nh), :] for h in range(nh)]
+    words = flat.bitcast(jnp.uint32)
+    out = []
+    for h in range(nh // w):
+        pair = words[pl.ds(h, rows, stride=nh // w), :]
+        out += [pltpu.bitcast(x, jnp.float32).astype(ref.dtype)
+                for x in (pair << 16, pair & jnp.uint32(0xFFFF0000))]
+    return out
+
+
+def _dot(a, b, dims):
+    """A product of the kernel, float32 accumulated. 16-bit operands go to
+    the MXU as they are whatever ``jax_default_matmul_precision`` says
+    (Mosaic refuses a bf16 product at ``highest``)."""
+    precision = jax.lax.Precision.DEFAULT if a.dtype.itemsize == 2 else None
+    return jax.lax.dot_general(a, b, dims, precision=precision,
+                               preferred_element_type=jnp.float32)
+
+
+def _ragged_kernel(pt_ref, pos0_ref, tl_ref, layer_ref, q_ref, k_hbm, v_hbm,
+                   *rest, t: int, quant: bool, split: bool):
+    """Grid (r,): row ``r``. The pools stay in HBM; the KV axis is a
+    loop inside the kernel over blocks of ``bp`` pages whose trip count
+    is the row's own ``ceil(kv_len / block)``, so a page past the row's
+    last attendable position is never visited, and a row of length 0
+    costs the grid step alone. Each block's live pages are fetched by
+    page id with the kernel's own asynchronous copies into one of two
+    buffers; the next block, or the next row's first, is in flight
+    while this one is multiplied (the buffer in turn is carried from
+    row to row in SMEM, so the grid axis is sequential).
+
+    ``split`` (the heads fill whole tiles) reads every head of q, K and
+    V as its own ``[rows, D]`` by a strided load and runs two plain
+    products a head on the MXU in the pools' type, float32 accumulated;
+    otherwise (small head counts) the block is one batched product in
+    float32. The online softmax is float32 either way. Pages narrower
+    than the products' type are widened in VMEM (exactly); int8 pages'
+    scale rows multiply the block's scores and weights:
+    ``ks_ref``/``vs_ref`` hold the row's scale at every position."""
+    rest = list(rest)
+    ks_ref, vs_ref = (rest.pop(0), rest.pop(0)) if quant else (None, None)
+    o_ref, kbuf, vbuf = rest[:3]
+    kwide, vwide = rest[3:-5] or (None, None)
+    sem, slot_ref, m_ref, l_ref, acc_ref = rest[-5:]
+    streams = ((k_hbm, kbuf), (v_hbm, vbuf))
+    _, bp, ps, nh, hd = kbuf.shape
+    tp = q_ref.shape[1]
+    nps = pt_ref.shape[1]
+    bt = bp * ps
     r = pl.program_id(0)
-    j = pl.program_id(1)
+    last_row = r + 1 == pl.num_programs(0)
+    layer = layer_ref[0]
 
-    @pl.when(j == 0)
-    def _init():
-        m_ref[:] = jnp.full_like(m_ref, _NEG_INF)
-        l_ref[:] = jnp.zeros_like(l_ref)
-        acc_ref[:] = jnp.zeros_like(acc_ref)
+    def kv_len(row):
+        n = jnp.minimum(pos0_ref[row] + tl_ref[row], nps * ps)
+        return jnp.where(tl_ref[row] > 0, n, 0)
 
-    last_attendable = pos0_ref[r] + tl_ref[r] - 1
+    def copies(row, blk, slot, act):
+        """``act`` (start or wait) on the copy of every live page of
+        block ``blk`` of ``row`` into buffer ``slot``."""
+        first = blk * bp
+        count = jnp.minimum(pl.cdiv(kv_len(row), ps) - first, bp)
 
-    @pl.when(j * page_size <= last_attendable)
-    def _compute():
-        q = q_ref[0].astype(jnp.float32)                # [T, NH, D]
-        k = k_ref[0].astype(jnp.float32)                # [ps, NH, D]
-        v = v_ref[0].astype(jnp.float32)
-        if ks_ref is not None:
-            # in-VMEM dequant: page values × this page's [NH, 1] scales
-            k = k * ks_ref[0][None]
-            v = v * vs_ref[0][None]
-        hd = q.shape[-1]
-        # s[n, t, p] = q[t, n] · k[p, n] / sqrt(D)
-        s = jax.lax.dot_general(
-            q, k, (((2,), (2,)), ((1,), (1,))),
-            preferred_element_type=jnp.float32) / math.sqrt(hd)
-        # query t attends global position <= pos0 + t
-        gpos = j * page_size + jax.lax.broadcasted_iota(
-            jnp.int32, s.shape, 2)
-        qpos = pos0_ref[r] + jax.lax.broadcasted_iota(
-            jnp.int32, s.shape, 1)
-        s = jnp.where(gpos <= qpos, s, _NEG_INF)
-        m_prev = m_ref[:]                               # [NH, T, 1]
+        def one(i, carry):
+            page = pt_ref[row, first + i]
+            for hbm, buf in streams:
+                act(pltpu.make_async_copy(hbm.at[layer, page],
+                                          buf.at[slot, i], sem.at[slot]))
+            return carry
+
+        jax.lax.fori_loop(0, count, one, 0)
+
+    start = lambda c: c.start()
+    wait = lambda c: c.wait()
+    n_live = kv_len(r)
+    nblk = pl.cdiv(n_live, bt)
+
+    @pl.when(r == 0)
+    def _first():
+        slot_ref[0] = 0
+        copies(r, 0, 0, start)
+
+    slot0 = slot_ref[0]
+    m_ref[:] = jnp.full_like(m_ref, _NEG_INF)
+    l_ref[:] = jnp.zeros_like(l_ref)
+    acc_ref[:] = jnp.zeros_like(acc_ref)
+
+    def block(b, carry):
+        slot = (slot0 + b) % 2
+
+        @pl.when(b + 1 < nblk)
+        def _next_block():
+            copies(r, b + 1, 1 - slot, start)
+
+        @pl.when(jnp.logical_and(b + 1 == nblk, jnp.logical_not(last_row)))
+        def _next_row():
+            copies(r + 1, 0, 1 - slot, start)
+
+        copies(r, b, slot, wait)
+        ksrc, vsrc = kbuf.at[slot], vbuf.at[slot]
+        if kwide is not None:
+            # pages narrower than the products' type are widened here,
+            # exactly (int8's scales go onto the scores and the weights)
+            kwide[...] = ksrc[...].astype(jnp.float32).astype(kwide.dtype)
+            vwide[...] = vsrc[...].astype(jnp.float32).astype(vwide.dtype)
+            ksrc, vsrc = kwide, vwide
+        if quant:       # the row's scales at this block, [NH, 1, bt]
+            at = (0, slice(None), slice(None),
+                  pl.ds(pl.multiple_of(b * bt, bt), bt))
+        left = n_live - b * bt              # live positions of this block
+        if split:
+            s = jnp.stack([
+                _dot(qh, kh, (((1,), (1,)), ((), ())))
+                for qh, kh in zip(_heads(q_ref.at[0], nh),
+                                  _heads(ksrc, nh))])
+        else:
+            q = q_ref[0].astype(jnp.float32)            # [Tp, NH, D]
+            k = ksrc[...].astype(jnp.float32).reshape(bt, nh, hd)
+            s = _dot(q, k, (((2,), (2,)), ((1,), (1,))))  # [NH, Tp, bt]
+        s = s / math.sqrt(hd)
+        if quant:
+            s = s * ks_ref[at]
+        # query i attends positions <= pos0 + i, and none past the row's
+        # last live one (a pad query would read what no copy fetched)
+        kpos = b * bt + jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
+        qpos = pos0_ref[r] + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        keep = kpos <= jnp.minimum(qpos, n_live - 1)
+        s = jnp.where(keep, s, _NEG_INF)
+        m_prev = m_ref[:]                               # [NH, Tp, 1]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=2, keepdims=True))
-        p = jnp.exp(s - m_new)                          # [NH, T, ps]
-        corr = jnp.exp(m_prev - m_new)                  # [NH, T, 1]
+        p = jnp.exp(s - m_new)                          # [NH, Tp, bt]
+        corr = jnp.exp(m_prev - m_new)
         l_ref[:] = corr * l_ref[:] + jnp.sum(p, axis=2, keepdims=True)
-        # acc[n, t, d] += sum_p p[n, t, p] * v[p, n, d]
-        pv = jax.lax.dot_general(
-            p, v, (((2,), (0,)), ((0,), (1,))),
-            preferred_element_type=jnp.float32)         # [NH, T, D]
-        acc_ref[:] = corr * acc_ref[:] + pv
         m_ref[:] = m_new
+        if quant:
+            p = jnp.where(keep, p * vs_ref[at], 0.0)
+        # rows past the live ones hold what an earlier block left there,
+        # or nothing at all: 0 x NaN must not reach the accumulator
+        if split:
+            dead = jax.lax.broadcasted_iota(jnp.int32, (bt, hd), 0) >= left
+            pv = jnp.stack([
+                _dot(p[h].astype(vh.dtype),
+                     jnp.where(dead, jnp.zeros_like(vh), vh),
+                     (((1,), (0,)), ((), ())))
+                for h, vh in enumerate(_heads(vsrc, nh))])
+        else:
+            v = vsrc[...].astype(jnp.float32).reshape(bt, nh, hd)
+            dead = jax.lax.broadcasted_iota(jnp.int32, v.shape, 0) >= left
+            pv = _dot(p, jnp.where(dead, 0.0, v),
+                      (((2,), (0,)), ((0,), (1,))))     # [NH, Tp, D]
+        acc_ref[:] = corr * acc_ref[:] + pv
+        return carry
 
-    @pl.when(j == n_pages - 1)
-    def _finish():
-        # rows whose every block was skipped (degenerate metadata) get
-        # zeros, not 0/0 NaN — they are never read, but NaN would trip
-        # debug_nans and pollute allclose diagnostics
-        l_safe = jnp.where(l_ref[:] == 0.0, 1.0, l_ref[:])
-        o_ref[0] = jnp.transpose(acc_ref[:] / l_safe,
-                                 (1, 0, 2)).astype(o_ref.dtype)
+    jax.lax.fori_loop(0, nblk, block, 0)
+
+    @pl.when(jnp.logical_and(nblk == 0, jnp.logical_not(last_row)))
+    def _next_row_of_an_empty_one():
+        copies(r + 1, 0, slot0, start)
+
+    slot_ref[0] = (slot0 + nblk) % 2
+    # a row of length 0 gets zeros, not 0/0: it is never read, but NaN
+    # would trip debug_nans and pollute allclose diagnostics
+    l = l_ref[:]
+    out = acc_ref[:] / jnp.where(l == 0.0, 1.0, l)      # [NH, Tp, D]
+    if split:
+        for h in range(nh):     # o_ref [1, T * NH, D]: row t * NH + h
+            o_ref[0, pl.ds(h, t, stride=nh), :] = out[h, :t]
+    else:
+        o_ref[0] = jnp.transpose(out, (1, 0, 2))[:t].astype(o_ref.dtype)
 
 
 def _ragged_attention_pallas(q, k_pool, v_pool, page_table, pos0,
@@ -352,62 +511,73 @@ def _ragged_attention_pallas(q, k_pool, v_pool, page_table, pos0,
     r, t, nh, hd = q.shape
     ps = k_pool.shape[-3]
     nps = page_table.shape[1]
+    bp = kv_block_pages(ps, nps)
+    quant = k_scale is not None
     if layer is None:
         # one layer's pools are a stack of one (a reshape): the kernel
         # has one spelling, the stacked one
         layer = 0
         k_pool, v_pool = k_pool[None], v_pool[None]
-        if k_scale is not None:
+        if quant:
             k_scale, v_scale = k_scale[None], v_scale[None]
     layer = jnp.asarray(layer, jnp.int32).reshape(1)
+    # what the products run in: the pools' type, int8 as the queries'
+    # (mixed float types at the wider of the two, as the XLA spelling)
+    kv_dtype = q.dtype if quant else \
+        jnp.promote_types(k_pool.dtype, q.dtype)
+    rows = 8 * _rows_per_word(kv_dtype)     # of one tile of that type
+    split = nh % rows == 0
+    # the queries ride at a whole tile's rows: a one-row product is not
+    # the MXU's, and the pad rows cost it nothing
+    tp = -(-t // rows) * rows if split else t
+    qk = q.astype(kv_dtype)
+    if tp != t:
+        qk = jnp.pad(qk, ((0, 0), (0, tp - t), (0, 0), (0, 0)))
 
-    def _page(i, j, pt, p0, tl):
-        # fully-masked block: fetch the (hot, tiny) null page instead
-        # of a live pool page the row will only mask away
-        return jnp.where(j * ps <= p0[i] + tl[i] - 1, pt[i, j], 0)
-
-    def _kv_index(i, j, pt, p0, tl, ly):
-        return (ly[0], _page(i, j, pt, p0, tl), 0, 0, 0)
-
-    def _scale_index(i, j, pt, p0, tl, ly):
-        # the scale row rides the same page choice as the page itself
-        return (ly[0], _page(i, j, pt, p0, tl), 0, 0)
-
-    in_specs = [
-        pl.BlockSpec((1, t, nh, hd),
-                     lambda i, j, pt, p0, tl, ly: (i, 0, 0, 0)),
-        pl.BlockSpec((None, 1, ps, nh, hd), _kv_index),
-        pl.BlockSpec((None, 1, ps, nh, hd), _kv_index),
-    ]
-    args = (page_table, pos0, true_len, layer, q, k_pool, v_pool)
-    if k_scale is not None:
-        # scales enter as [L, P, NH, 1]: a (1, NH) block of the [P, NH]
-        # rows breaks Mosaic's rule that a block's last two dims be
-        # (8, 128)-divisible or the array's own, and [NH, 1] is already
-        # the page tile's layout (heads on sublanes, broadcast along
-        # the head_dim lanes)
-        in_specs += [pl.BlockSpec((None, 1, nh, 1), _scale_index),
-                     pl.BlockSpec((None, 1, nh, 1), _scale_index)]
-        args += (k_scale[..., None], v_scale[..., None])
+    # the split kernel writes head h of query i at row i * NH + h
+    o_shape = (t * nh, hd) if split else (t, nh, hd)
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
+    in_specs = [pl.BlockSpec((1, tp, nh, hd),
+                             lambda i, pt, p0, tl, ly: (i, 0, 0, 0)),
+                hbm, hbm]
+    args = (page_table, pos0, true_len, layer, qk, k_pool, v_pool)
+    scratch = [pltpu.VMEM((2, bp, ps, nh, hd), k_pool.dtype),
+               pltpu.VMEM((2, bp, ps, nh, hd), v_pool.dtype)]
+    if quant:
+        # the scale of every position of every row, [R, NH, 1, blocks *
+        # bt] (a gather of R * NPs rows of NH numbers, not of pages): a
+        # row's block of them multiplies its scores and its weights
+        pt = jnp.pad(page_table, ((0, 0), (0, -nps % bp)))
+        in_specs += [pl.BlockSpec(
+            (1, nh, 1, pt.shape[1] * ps),
+            lambda i, pt, p0, tl, ly: (i, 0, 0, 0))] * 2
+        args += tuple(
+            jnp.repeat(jnp.swapaxes(sc[layer[0], pt], 1, 2), ps,
+                       axis=2)[:, :, None] for sc in (k_scale, v_scale))
+    if k_pool.dtype != kv_dtype:
+        scratch += [pltpu.VMEM((bp, ps, nh, hd), kv_dtype)] * 2
+    scratch += [pltpu.SemaphoreType.DMA((2,)),
+                pltpu.SMEM((1,), jnp.int32),
+                pltpu.VMEM((nh, tp, 1), jnp.float32),
+                pltpu.VMEM((nh, tp, 1), jnp.float32),
+                pltpu.VMEM((nh, tp, hd), jnp.float32)]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,
-        grid=(r, nps),
+        grid=(r,),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, t, nh, hd),
-                               lambda i, j, pt, p0, tl, ly: (i, 0, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((nh, t, 1), jnp.float32),
-            pltpu.VMEM((nh, t, 1), jnp.float32),
-            pltpu.VMEM((nh, t, hd), jnp.float32),
-        ],
+        out_specs=pl.BlockSpec(
+            (1,) + o_shape,
+            lambda i, pt, p0, tl, ly: (i,) + (0,) * len(o_shape)),
+        scratch_shapes=scratch,
     )
-    return pl.pallas_call(
-        functools.partial(_ragged_kernel, page_size=ps, n_pages=nps),
+    out = pl.pallas_call(
+        functools.partial(_ragged_kernel, t=t, quant=quant, split=split),
         name="ragged_paged_attn",
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((r, t, nh, hd), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((r,) + o_shape, jnp.float32),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
+            dimension_semantics=("arbitrary",)),
         interpret=_interpret(),
     )(*args)
+    return out.reshape(q.shape).astype(q.dtype)

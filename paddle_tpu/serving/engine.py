@@ -160,6 +160,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from ..ops.paged_attention import live_block_share, resolve_impl
 from ..profiler import events as _events
 from ..profiler import recompile as _recompile
 from ..profiler import registry as _registry
@@ -208,10 +209,12 @@ def _proc_index() -> int:
 
     return _detect_rank()
 
-#: attention_kernel values: the unified mixed-row tick on the XLA
-#: gather spelling (the default), or on the Pallas ragged kernel
-#: (compiles with Mosaic and matches the XLA spelling on a v5e for bf16
-#: and int8 pools — chip_smoke.py phase 1; its speed is not measured).
+#: explicit attention_kernel values: the unified mixed-row tick on the
+#: XLA gather spelling (the reference: what the CPU runs and the bitwise
+#: pins are written for), or on the Pallas ragged kernel (the chip's
+#: serving kernel: its work follows each row's live length). The
+#: default, None, is the platform's, resolved where the tick is traced
+#: (``ops/paged_attention.resolve_impl``).
 ATTENTION_KERNELS = ("ragged-xla", "ragged-pallas")
 
 
@@ -277,7 +280,7 @@ class ServingConfig:
     top_p: float = 1.0
     eos_token_id: Optional[int] = None
     seed: int = 0
-    attention_kernel: str = "ragged-xla"   # see ATTENTION_KERNELS
+    attention_kernel: Optional[str] = None  # see ATTENTION_KERNELS
     #: speculative decoding (serving/spec.py SpecConfig: draft model +
     #: k). The engine gains a second
     #: compiled site (the draft tick) and syncs each verify tick —
@@ -370,10 +373,10 @@ class ServingEngine:
                 f"unknown scheduler {cfg.scheduler!r}; expected one of "
                 f"{SCHED_POLICIES}")
         kernel = cfg.attention_kernel
-        if kernel not in ATTENTION_KERNELS:
+        if kernel is not None and kernel not in ATTENTION_KERNELS:
             raise ValueError(
-                f"unknown attention kernel {kernel!r}; expected one of "
-                f"{ATTENTION_KERNELS}")
+                f"unknown attention kernel {kernel!r}; expected None "
+                f"(the platform's) or one of {ATTENTION_KERNELS}")
         self._spec = cfg.spec
         if self._spec is not None:
             if getattr(self._spec, "overlap", False) and \
@@ -384,8 +387,7 @@ class ServingEngine:
                     "has no chained draft build — use decode='sampling'")
             if self._spec.k < 1:
                 raise ValueError("spec.k must be >= 1")
-        self._impl = "pallas" if kernel.endswith("pallas") else "xla"
-        self.attention_kernel = kernel
+        self._impl = kernel and kernel.removeprefix("ragged-")
         # process index folded in: ids stay unique when rank-tagged
         # event streams from N processes are merged (ISSUE 13)
         self._eng_id = (_proc_index() << 20) | next(_ENGINE_SEQ)
@@ -572,6 +574,12 @@ class ServingEngine:
             b_slots * (1 + spec_extra)
             + cfg.prefill_chunks_per_tick
             * (self.prefill_chunk // ps + 2) + 8)
+
+    @property
+    def attention_kernel(self) -> str:
+        """The tick's attention, by its ATTENTION_KERNELS name: the
+        configured one, else the one a tick traced now would take."""
+        return "ragged-" + resolve_impl(self._impl)
 
     @property
     def compiled_sites(self) -> Tuple[str, ...]:
@@ -1724,12 +1732,13 @@ class ServingEngine:
         tok_pos[:ns] = self._slot_len
         tok_limit[:ns] = cap
         # ragged row metadata: ns decode rows, then npf chunk rows (pad
-        # chunk rows keep an all-null table and attend one masked key)
+        # chunk rows keep an all-null table)
         row_tab = np.zeros((ns + npf, nps), np.int32)
         row_tab[:ns] = self.pool.tables
         row_pos0 = np.zeros(ns + npf, np.int32)
         row_pos0[:ns] = self._slot_len
         row_len = np.ones(ns + npf, np.int32)
+        row_len[ns:] = 0          # a pad chunk row: nothing to fetch
         sample_ix = np.zeros(ns, np.int32)
         sample_pos = np.zeros(ns, np.int32)
         emit = np.zeros(ns, bool)
@@ -1757,6 +1766,10 @@ class ServingEngine:
                 sample_ix[s] = base + (t0 - 1 - start)
                 sample_pos[s] = t0
                 emit[s] = True
+        # blocks the attention kernel's loops visit this tick over blocks
+        # at capacity
+        _registry().gauge("serving/attn_live_block_share").set(
+            live_block_share(row_pos0, row_len, self.pool.page_size, nps))
         tail = (self._last_tok, pf_toks, tok_pos, tok_limit, row_tab,
                 row_pos0, row_len, sample_ix, sample_pos, emit,
                 np.bool_(len(chunks) > 0),

@@ -539,8 +539,7 @@ class Pools(NamedTuple):
                                       vv[:, 0], layer=layer)
         return Pools(k, v, k_scale, v_scale)
 
-    def attend(self, layer, q, page_table, pos0, true_len,
-               impl: str = "xla"):
+    def attend(self, layer, q, page_table, pos0, true_len, impl=None):
         """``ragged_paged_attention`` of ``q`` over ``layer``'s pages."""
         return ragged_paged_attention(
             q, self.k, self.v, page_table, pos0, true_len, impl=impl,

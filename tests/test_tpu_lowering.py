@@ -194,22 +194,38 @@ def _on_tpu(device, shape, dtype):
                                 sharding=SingleDeviceSharding(device))
 
 
-@pytest.mark.parametrize("layers", [None, 3])
-@pytest.mark.parametrize("int8", [False, True])
-@pytest.mark.parametrize("t", [1, 32])
-def test_tpu_compile_ragged_pallas(monkeypatch, int8, t, layers):
+#: (pages, heads, pages a slot, rows) of a toy pool, whose 4 heads do not
+#: fill a tile (the kernel's batched product), and of the cells' own row
+#: shapes (16 heads: a strided load a head): gpt3-1.3b-serve's 12 decode
+#: rows and its one chunk row over 128 pages a slot, ouro-2.6b-serve's 10
+#: rows over 32 pages
+_TOY, _GPT3_ROWS, _GPT3_CHUNK, _OURO_ROWS = (
+    (33, 4, 8, 4), (1537, 16, 128, 12), (1537, 16, 128, 1),
+    (321, 16, 32, 10))
+
+
+@pytest.mark.parametrize("shape,int8,t,layers", [
+    (_TOY, int8, t, layers) for layers in (None, 3)
+    for int8 in (False, True) for t in (1, 32)] + [
+    (_GPT3_ROWS, False, 1, 24), (_GPT3_CHUNK, False, 32, 24),
+    (_GPT3_ROWS, True, 1, 24), (_GPT3_ROWS, False, 5, 24),
+    (_OURO_ROWS, False, 1, 192)])
+def test_tpu_compile_ragged_pallas(monkeypatch, shape, int8, t, layers):
     """The ragged paged kernel at head_dim 128 compiles with Mosaic for
     decode rows and chunk rows, bf16 pools and int8 pools with scales
     (the [P, NH] scale rows once broke the (8, 128) block rule), over one
     layer's pools and over the stack with the layer a traced scalar that
-    the page index maps read from scalar prefetch (ISSUE 32)."""
+    the kernel's own page copies read from scalar prefetch (ISSUE 32), at
+    a toy pool's shapes and at the serving cells' (ISSUE 34: decode,
+    verify and chunk rows of gpt3-1.3b-serve, the 192 cache layers of
+    ouro-2.6b-serve)."""
     import jax.numpy as jnp
 
     from paddle_tpu.ops.paged_attention import ragged_paged_attention
 
     dev = _tpu_topology_devices()[0]
     monkeypatch.setenv("PADDLE_TPU_TARGET_PLATFORM", "tpu")
-    pages, ps, nh, hd, nps, r = 33, 16, 4, 128, 8, 4
+    (pages, nh, nps, r), ps, hd = shape, 16, 128
     stack = () if layers is None else (layers,)
     pool = _on_tpu(dev, stack + (pages, ps, nh, hd),
                    jnp.int8 if int8 else jnp.bfloat16)
@@ -233,25 +249,22 @@ def test_tpu_compile_ragged_pallas(monkeypatch, int8, t, layers):
                              % (pages, ps, nh, hd), text)
 
 
-def test_tpu_compile_serving_tick(monkeypatch):
-    """The unified serving tick, re-lowered from the avals an engine
-    captured at its first dispatch (engine._note_avals) with TPU
-    shardings, compiles for the v5e, and updates its bf16 pools in place
-    there (ISSUE 32; the CPU's XLA widens a bf16 pool around a scatter, so
-    only this target speaks for bf16): pools that dwarf the toy model, no
-    pool-sized temporary, both pools aliased."""
+def _compiled_tick(monkeypatch, model_cfg, serving_cfg):
+    """(engine, compiled tick): an engine built and ticked once on the CPU,
+    its tick re-lowered from the avals captured at that dispatch
+    (engine._note_avals) with TPU shardings, as a program traced for the
+    TPU: the tick the chip runs, the attention kernel inside."""
     import paddle_tpu as paddle
-    from paddle_tpu.models import GPT, GPTConfig
-    from paddle_tpu.serving import ServingConfig, ServingEngine
+    from paddle_tpu.models import GPT
+    from paddle_tpu.profiler import metrics
+    from paddle_tpu.serving import ServingEngine
 
     dev = _tpu_topology_devices()[0]
     paddle.seed(0)
-    net = GPT(GPTConfig(vocab_size=256, hidden_size=256, num_layers=2,
-                        num_heads=2, max_seq_len=128))
+    net = GPT(model_cfg)
     net.eval()
     net.bfloat16()
-    eng = ServingEngine(net, ServingConfig(num_slots=2, page_size=16,
-                                           num_pages=4097))
+    eng = ServingEngine(net, serving_cfg)
     eng.submit(np.arange(5, dtype=np.int32), 2)
     eng.step()
     eng.drain(0)
@@ -259,7 +272,18 @@ def test_tpu_compile_serving_tick(monkeypatch):
     avals = jax.tree_util.tree_map(
         lambda a: _on_tpu(dev, a.shape, a.dtype), avals)
     monkeypatch.setenv("PADDLE_TPU_TARGET_PLATFORM", "tpu")
-    ma = fn.lower(*avals).compile().memory_analysis()
+    calls = metrics.registry().counter("serving/attn_calls{path=pallas}")
+    before = calls.value
+    # the CPU's trace of these avals took the XLA spelling: trace anew
+    jax.clear_caches()
+    compiled = fn.lower(*avals).compile()
+    assert calls.value > before and "tpu_custom_call" in compiled.as_text(), \
+        "the tick compiled for the TPU does not hold the attention kernel"
+    return eng, compiled
+
+
+def _updated_in_place(eng, compiled):
+    ma = compiled.memory_analysis()
     one_pool = eng.pool.k.nbytes
     assert eng.pool.k.dtype == jax.numpy.bfloat16 and one_pool > 30e6
     assert ma.alias_size_in_bytes >= 2 * one_pool, \
@@ -268,43 +292,63 @@ def test_tpu_compile_serving_tick(monkeypatch):
         f"{ma.temp_size_in_bytes} bytes of temporaries: a pool is copied"
 
 
-def test_tpu_compile_looped_serving_tick(monkeypatch):
+#: a toy model under pools that dwarf it (2 heads: the kernel's batched
+#: product), and gpt3-1.3b-serve's rows: 12 slots of 128 pages, 16 heads of
+#: 128 (a strided load a head), decode rows and a chunk row of 32
+_TICKS = {
+    "toy": (dict(hidden_size=256, num_heads=2, max_seq_len=128),
+            dict(num_slots=2, page_size=16, num_pages=4097)),
+    "gpt3-rows": (dict(hidden_size=2048, num_heads=16, max_seq_len=2048,
+                       ffn_hidden_size=256),
+                  dict(num_slots=12, page_size=16)),
+}
+
+
+@pytest.mark.parametrize("sizes", list(_TICKS))
+def test_tpu_compile_serving_tick(monkeypatch, sizes):
+    """The unified serving tick compiles for the v5e with the ragged
+    kernel inside (ISSUE 34), and updates its bf16 pools in place there
+    (ISSUE 32; the CPU's XLA widens a bf16 pool around a scatter, so only
+    this target speaks for bf16): no pool-sized temporary, both pools
+    aliased."""
+    from paddle_tpu.models import GPTConfig
+    from paddle_tpu.serving import ServingConfig
+
+    model, serving = _TICKS[sizes]
+    eng, compiled = _compiled_tick(
+        monkeypatch, GPTConfig(vocab_size=256, num_layers=2, **model),
+        ServingConfig(**serving))
+    _updated_in_place(eng, compiled)
+
+
+#: the same two, for a looped model: ouro-2.6b-serve's rows are 10 slots
+#: of 32 pages
+_LOOPED_TICKS = {
+    "toy": _TICKS["toy"][:1] + (dict(num_slots=2, page_size=16,
+                                     num_pages=1025),),
+    "ouro-rows": (dict(hidden_size=2048, num_heads=16, max_seq_len=512),
+                  dict(num_slots=10, page_size=16)),
+}
+
+
+@pytest.mark.parametrize("sizes", list(_LOOPED_TICKS))
+def test_tpu_compile_looped_serving_tick(monkeypatch, sizes):
     """ISSUE 33: a looped model's tick (4 loop steps over 2 layers: pools 8
     cache layers deep, the carry of the scan over steps and of the layer
     scan inside it; RoPE, sandwich RMSNorm, SwiGLU) compiles for the v5e
-    and updates its bf16 pools in place there: no pool-sized temporary,
-    both pools aliased."""
-    import paddle_tpu as paddle
-    from paddle_tpu.models import GPT, GPTConfig
-    from paddle_tpu.serving import ServingConfig, ServingEngine
+    with the ragged kernel inside and updates its bf16 pools in place
+    there: no pool-sized temporary, both pools aliased."""
+    from paddle_tpu.models import GPTConfig
+    from paddle_tpu.serving import ServingConfig
 
-    dev = _tpu_topology_devices()[0]
-    paddle.seed(0)
-    net = GPT(GPTConfig(
-        vocab_size=256, hidden_size=256, num_layers=2, num_heads=2,
-        max_seq_len=128, ffn_hidden_size=384, layer_norm_eps=1e-6,
-        tie_word_embeddings=False, norm="rmsnorm", position="rope",
-        rope_theta=1e6, bias=False, ffn="swiglu", sandwich_norm=True,
-        loop_steps=4))
-    net.eval()
-    net.bfloat16()
-    eng = ServingEngine(net, ServingConfig(num_slots=2, page_size=16,
-                                           num_pages=1025))
-    eng.submit(np.arange(5, dtype=np.int32), 2)
-    eng.step()
-    eng.drain(0)
-    fn, avals = eng._program_args[eng.compiled_sites[0]]
-    avals = jax.tree_util.tree_map(
-        lambda a: _on_tpu(dev, a.shape, a.dtype), avals)
-    monkeypatch.setenv("PADDLE_TPU_TARGET_PLATFORM", "tpu")
-    ma = fn.lower(*avals).compile().memory_analysis()
-    one_pool = eng.pool.k.nbytes
-    assert eng.pool.k.shape[0] == 8 and one_pool > 30e6
-    assert eng.pool.k.dtype == jax.numpy.bfloat16
-    assert ma.alias_size_in_bytes >= 2 * one_pool, \
-        "the donated page pools are not aliased"
-    assert ma.temp_size_in_bytes < one_pool, \
-        f"{ma.temp_size_in_bytes} bytes of temporaries: a pool is copied"
+    model, serving = _LOOPED_TICKS[sizes]
+    eng, compiled = _compiled_tick(monkeypatch, GPTConfig(
+        vocab_size=256, num_layers=2, ffn_hidden_size=384,
+        layer_norm_eps=1e-6, tie_word_embeddings=False, norm="rmsnorm",
+        position="rope", rope_theta=1e6, bias=False, ffn="swiglu",
+        sandwich_norm=True, loop_steps=4, **model), ServingConfig(**serving))
+    assert eng.pool.k.shape[0] == 8
+    _updated_in_place(eng, compiled)
 
 
 def test_tpu_compile_a_lazy_models_state_draw(monkeypatch):
