@@ -10,7 +10,19 @@ Top-level namespace mirrors `paddle.*` so reference users can switch.
 """
 from __future__ import annotations
 
+import time as _time
+
+_import_t0_ns = _time.perf_counter_ns()     # the package's first line
+
 __version__ = "0.1.0"
+
+# The program's set-up begins here (profiler/trace.py, ``phase``): the
+# process's age now is what lay before the program (the interpreter, jax,
+# the device runtime's start, the caller's own files), and the package's
+# own import is the first phase.
+from .profiler import trace as _trace  # noqa: E402
+
+_import_phase = _trace.import_began(_import_t0_ns)
 
 from . import autograd, compat, core, framework  # noqa: F401
 from .autograd import enable_grad, grad, no_grad, set_grad_enabled  # noqa: F401
@@ -119,3 +131,7 @@ def flops(net, input_size, custom_ops=None, print_detail=False):
     from .hapi.model_summary import flops as _flops
 
     return _flops(net, input_size, custom_ops, print_detail)
+
+
+_import_phase.end()
+del _import_phase

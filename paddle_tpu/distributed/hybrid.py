@@ -46,6 +46,7 @@ from __future__ import annotations
 from typing import Dict, List, Optional
 
 import contextlib
+import functools
 import os
 import time
 
@@ -84,9 +85,25 @@ def _check_protocol(model):
                 f"protocol ({m}); see distributed/hybrid.py docstring")
 
 
+def _setup_phase(init):
+    """The constructor inside phase ``setup/trainer`` [``site``], on the
+    always-on record (profiler/trace.py ``phase``): its children are what
+    it does, in order; ``site`` is the step program's dispatch site, the
+    one its ``setup/first_call`` carries."""
+    @functools.wraps(init)
+    def __init__(self, *args, **kwargs):
+        # recompilation telemetry: every (re)trace of this trainer's step
+        # program is reported to profiler.recompile under this site
+        self._prof_site = _precomp.unique_site("hybrid.step")
+        with _ptrace.phase("setup/trainer", site=self._prof_site):
+            init(self, *args, **kwargs)
+    return __init__
+
+
 class HybridPipelineTrainer:
     """Compiled hybrid-parallel trainer for any pipeline-protocol model."""
 
+    @_setup_phase
     def __init__(self, model, optimizer,
                  strategy: Optional[DistributedStrategy] = None,
                  mesh: Optional[Mesh] = None, n_micro: Optional[int] = None,
@@ -416,6 +433,7 @@ class HybridPipelineTrainer:
         # stacked block params: [pp, lps, ...] (GPipe) or
         # [pp, v, lps/v, ...] (interleaved: stage s circuit c owns layers
         # (c·pp + s)·lps_v .. +lps_v — the circular assignment)
+        part = _ptrace.phase("setup/trainer/stack_blocks").begin()
         self.block_vals: Dict[str, jax.Array] = {}
         self.block_specs: Dict[str, P] = {}
         # stream_layers: per-layer piece specs [pp, ...] and, with
@@ -511,7 +529,9 @@ class HybridPipelineTrainer:
                     stacked = stacked.astype(dt)
                 self.block_vals[sfx] = jax.device_put(
                     stacked, self._param_ns(spec))
+        part.end()
 
+        part = _ptrace.phase("setup/trainer/place_others").begin()
         self.other_vals: List[jax.Array] = []
         self.other_specs: List[P] = []
         for n in self.other_names:
@@ -546,8 +566,10 @@ class HybridPipelineTrainer:
                         v.astype(cdt), NamedSharding(self.mesh, spec)))
                 self.other_vals.append(jax.device_put(
                     v, self._param_ns(spec)))
+        part.end()
 
         # --- optimizer state ----------------------------------------------
+        part = _ptrace.phase("setup/trainer/opt_state").begin()
         def opt_state_spec(spec, shape, ndim):
             if self.zero >= 1:
                 local = _local_check_shape(shape, spec, self.mesh)
@@ -654,8 +676,10 @@ class HybridPipelineTrainer:
             s = init_opt_state(v, sp)
             self.other_opt.append(s)
             self.other_opt_specs.append({k: sp for k in s})
+        part.end()
 
         if free_eager and not self.abstract:
+            part = _ptrace.phase("setup/trainer/free_eager").begin()
             # device_put may return a NEW Array sharing the SAME buffer
             # when dtype+sharding are unchanged, so aliasing cannot be
             # detected by identity. Delete only buffers that are
@@ -675,6 +699,7 @@ class HybridPipelineTrainer:
                 if t._value.dtype != v.dtype:
                     t._value.delete()
                 t._value = None
+            part.end()
 
         self.guard_bad_steps = bool(guard_bad_steps)
         if self.guard_bad_steps and (offload_params or stream_layers):
@@ -696,9 +721,6 @@ class HybridPipelineTrainer:
         #: the blocks' ``aux_stats`` of the last step, summed over its
         #: layers and micro-batches (device arrays; {} for a dense model)
         self.aux_stats = {}
-        # recompilation telemetry: every (re)trace of this trainer's step
-        # program is reported to profiler.recompile under this site
-        self._prof_site = _precomp.unique_site("hybrid.step")
 
     # ---------------------------------------------------------------------
     def _forward_loss(self, block_params, other_params, batch, key):
@@ -1225,6 +1247,7 @@ class HybridPipelineTrainer:
             step_fn, in_shardings=in_sh, out_shardings=out_sh,
             donate_argnums=(0, 1, 2, 3))
         self._n_batch_args = n_batch_args
+        self._first_call_due = True
 
     def _build_stream(self, n_batch_args: int):
         """stream_layers step: per-layer host↔HBM streaming update.
@@ -1431,6 +1454,7 @@ class HybridPipelineTrainer:
                            oth_c_sh, blk_o_sh, oth_o_sh, ns(P())),
             donate_argnums=(0, 1, 2, 3, 4, 5))
         self._n_batch_args = n_batch_args
+        self._first_call_due = True
 
     def _state_args(self):
         if self.stream_layers:
@@ -1438,6 +1462,15 @@ class HybridPipelineTrainer:
                     self.other_comp, self.block_opt, self.other_opt)
         return (self.block_vals, self.other_vals, self.block_opt,
                 self.other_opt)
+
+    def _first_call(self, *args):
+        """The step program's first call, phase ``setup/first_call``
+        [``site``]: it is traced, lowered and compiled or fetched from
+        the cache in here, and recompile.py's listener charges those
+        seconds to the site."""
+        with _ptrace.phase(_precomp.FIRST_CALL, site=self._prof_site):
+            self._first_call_due = False
+            return self._step_fn(*args)
 
     def step(self, *batch) -> jax.Array:
         from ..core import rng as rng_mod
@@ -1455,6 +1488,7 @@ class HybridPipelineTrainer:
         # instrumented branch additionally SYNCS on the loss (a host value
         # fetch), so the enabled step_ms histogram measures execution,
         # not dispatch.
+        call = self._first_call if self._first_call_due else self._step_fn
         prof = _ptrace.is_enabled()
         sync = prof and getattr(self, "profiled_step_sync", True)
         t0 = time.perf_counter_ns() if prof else 0
@@ -1480,7 +1514,7 @@ class HybridPipelineTrainer:
             # honest hybrid/sync_wait span instead; the histogram is
             # then named hybrid/dispatch_ms, because that is what it is.
             with _ptrace.scope("hybrid/step"):
-                out = self._step_fn(*args)
+                out = call(*args)
                 if sync:
                     # truthful sync; the inner span isolates how much of
                     # the step was execution the host WAITED on vs
@@ -1495,7 +1529,7 @@ class HybridPipelineTrainer:
                           else "hybrid/dispatch_ms").observe(dt_ms)
             _pinstr.record_memory_high_water()
         else:
-            out = self._step_fn(*args)
+            out = call(*args)
         if self.guard_bad_steps:
             self._last_ok_dev = out[1]
             out = (out[0],) + out[2:]

@@ -17,9 +17,12 @@ metadata, zero bytes materialized. A lazily-built model can be:
 from __future__ import annotations
 
 import threading
+import time
 
 import jax
 import jax.numpy as jnp
+
+from ..profiler import trace as _ptrace
 
 _state = threading.local()
 
@@ -55,9 +58,12 @@ def materialize(layer, key=None):
         if p is not None and is_abstract(p):
             init = getattr(p, "_lazy_initializer", None)
             spec = p._value
+            t0 = time.perf_counter()
             if init is None:
                 p._value = jnp.zeros(spec.shape, spec.dtype)
             else:
                 p._value = jnp.asarray(
                     init(list(spec.shape), spec.dtype), spec.dtype)
+            _ptrace.charge_setup("weights", time.perf_counter() - t0,
+                                 p._value.nbytes, where="host")
     return layer

@@ -15,6 +15,7 @@ TPU-first:
 from __future__ import annotations
 
 import math
+import time
 from dataclasses import dataclass, field
 
 import numpy as np
@@ -30,6 +31,7 @@ from ..distributed.parallel_layers import (ColumnParallelLinear,
                                            VocabParallelEmbedding)
 from ..nn import functional as F
 from ..nn import initializer as I
+from ..profiler import trace as _ptrace
 from ..profiler.trace import annotate
 from ..tensor import arange
 
@@ -1178,8 +1180,16 @@ def _gpt_decode_state(model: "GPT"):
         from ..core import rng
 
         # one jitted call, seeded by the global generator's next key
-        return jax.jit(_decode_state_drawer(sfx, t0, len(blocks), rest))(
+        t_draw = time.perf_counter()
+        state = jax.jit(_decode_state_drawer(sfx, t0, len(blocks), rest))(
             rng.next_key())
+        # the host's seconds: tracing, compiling and queueing the draw;
+        # the device's are waited for by whoever first needs the weights
+        _ptrace.charge_setup(
+            "weights", time.perf_counter() - t_draw,
+            sum(a.nbytes for a in jax.tree_util.tree_leaves(state)),
+            where="device")
+        return state
     stacked = {s: jnp.stack([pb[j]._value for pb in per_block], 0)
                for j, s in enumerate(sfx)}
     return stacked, {n: p._value for n, p in rest}

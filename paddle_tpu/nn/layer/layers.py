@@ -13,6 +13,7 @@ whole AST-translation subsystem (dygraph_to_static) for this.
 """
 from __future__ import annotations
 
+import time
 from collections import OrderedDict
 from typing import Iterator, Optional, Tuple
 
@@ -22,6 +23,7 @@ import numpy as np
 from ...core import dtype as dtype_mod
 from ...framework.param_attr import ParamAttr
 from ...framework.tensor import Parameter, Tensor
+from ...profiler import trace as _ptrace
 from .. import initializer as I
 
 _name_counters = {}
@@ -235,6 +237,7 @@ class Layer:
 
     def to(self, device=None, dtype=None, blocking=None):
         if dtype is not None:
+            t0 = time.perf_counter()
             d = dtype_mod.convert_dtype(dtype)
             for p in self.parameters():
                 if isinstance(p._value, jax.ShapeDtypeStruct):
@@ -248,6 +251,7 @@ class Layer:
                 if jnp.issubdtype(b._value.dtype, jnp.floating):
                     b._value = b._value.astype(d)
             self._dtype = d
+            _ptrace.charge_setup("cast", time.perf_counter() - t0)
         return self
 
     def astype(self, dtype):
@@ -325,7 +329,11 @@ def build_parameter(shape, attr=None, dtype=None, is_bias=False,
                       _unique_name("param"), trainable=attr.trainable)
         p._lazy_initializer = init
     else:
+        t0 = time.perf_counter()
         value = init(shape, dtype)
+        # the eager draw, one parameter at a time as the host dispatches it
+        _ptrace.charge_setup("weights", time.perf_counter() - t0,
+                             getattr(value, "nbytes", None), where="host")
         p = Parameter(value, name=name or attr.name or
                       _unique_name("param"), trainable=attr.trainable)
     p.optimize_attr["learning_rate"] = attr.learning_rate

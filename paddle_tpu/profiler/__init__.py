@@ -20,6 +20,23 @@ with the byte accounting), a measured compute∩comm overlap fraction
 ``profile_step_phases(trace_window=k)``,
 ``ServingEngine.trace_window()`` and ``serve_bench --trace-window``.
 
+**Set-up, on the program's own clock** (ISSUE 35; always on, like the
+event log it writes to): ``profiler.phase("setup/...")`` records the
+edges of set-up as ``phase`` events whether or not ``enable()`` is on
+(trace.py): the package's import (``setup/import``; the gauge
+``proc/age_at_import_s`` is the process's age before it), the trainer's
+and the engine's constructors with their children (``setup/trainer``,
+``setup/engine``), every dispatch site's first call
+(``setup/first_call`` [``site``]). Parameters are too many for a phase
+each: their making adds to the counters ``setup/weights_s{where=}``,
+``setup/weights_bytes{where=}`` and ``setup/cast_s``
+(``trace.charge_setup``). What each compilation cost is heard from
+``jax.monitoring`` (recompile.py) and charged to the site whose first
+call is open, else to ``eager``: the inventory's ``trace_s`` /
+``lower_s`` / ``backend_s`` / ``cache_fetch_s`` / ``cache_hit``, the
+counters ``compile/*`` and one ``compile`` event a program. Nothing of
+it runs on a tick or a step.
+
 Three pillars, one switch (``profiler.enable()``):
 
 1. **Tracing** (``trace.py``): ``profiler.scope("name")`` /
@@ -113,14 +130,14 @@ from .recompile import (mark_trace, retraces, suppressed,  # noqa: F401
 from .sink import (MetricsSink, active_sink, disable_sink,  # noqa: F401
                    enable_sink, flush_active, prometheus_text)
 from .trace import (RecordEvent, annotate, chrome_trace,  # noqa: F401
-                    export_chrome_trace, is_enabled, live_spans, scope,
-                    scope_summary)
+                    export_chrome_trace, is_enabled, live_spans, phase,
+                    scope, scope_summary)
 from .xla_stats import program_inventory, record_compiled  # noqa: F401
 from .xla_stats import record_lowered  # noqa: F401
 
 __all__ = [
     "enable", "disable", "is_enabled", "reset",
-    "scope", "RecordEvent", "annotate",
+    "scope", "phase", "RecordEvent", "annotate",
     "scope_summary", "chrome_trace", "export_chrome_trace", "live_spans",
     "registry", "Counter", "Gauge", "Histogram", "MetricsRegistry",
     "mark_trace", "watch", "retraces", "trace_counts", "suppressed",

@@ -98,6 +98,13 @@ __all__ = [
 #: (the engine abandoned a request without a result — orphan
 #: bookkeeping when a re-dispatched gid's stale local work is torn
 #: down; attr ``reason``).
+#: Set-up (ISSUE 35) adds two process-level kinds, neither per tick nor
+#: per step: ``phase`` (a set-up phase ended, trace.py ``phase`` — attrs
+#: ``name``/``t0_ns``/``t1_ns``/``id``/``parent``/``tid`` and the
+#: phase's ids, a few dozen a process) and ``compile`` (a program was
+#: compiled or fetched from the persistent cache, recompile.py's
+#: listener — attrs ``site``/``trace_s``/``lower_s``/``backend_s``/
+#: ``cache_fetch_s``/``cache_hit``; one a compilation).
 EVENT_KINDS = (
     "submit", "admit", "prefix_hit", "cow_copy", "chunk",
     "first_token", "draft", "verify", "accept",
@@ -106,6 +113,7 @@ EVENT_KINDS = (
     "vote_window_expiry",
     "member_join", "member_leave", "redispatch", "cancel",
     "preempt", "requeue", "finish", "rollback", "alert",
+    "phase", "compile",
 )
 
 

@@ -14,17 +14,30 @@ no jax internals. The watcher keeps each site's abstract signature
 (shape/dtype of every leaf); any trace after a site's first is a
 **retrace** and is recorded with the shapes that triggered it, diffed
 against the previous signature.
+
+What a compilation COST is jax's to say: one ``jax.monitoring`` listener,
+registered here at import, hears the seconds each program took to trace,
+to lower and to compile or to fetch from the persistent cache. They are
+charged to the dispatch site whose ``setup/first_call`` phase
+(``trace.phase``) is open on the compiling thread, else to the site
+``eager`` (the small programs of eager operations), in three places: the
+site's record in the inventory (``xla_stats.charge_compile``), the
+process's counters ``compile/*`` and one event of kind ``compile`` in the
+always-on event log, so that a reader can say what the counters stood at
+when a window opened. The listener runs at compilations only.
 """
 from __future__ import annotations
 
 import itertools
 import logging
 import threading
+import time
 from contextlib import contextmanager
 from typing import Any, Dict, List
 
 import jax
 
+from . import events as _events
 from . import trace as _trace
 from .metrics import registry
 
@@ -143,3 +156,69 @@ def reset() -> None:
     with _lock:
         _sites.clear()
         _retraces.clear()
+
+
+# ---------------------------------------------------------------------------
+# what each compilation cost, by site
+# ---------------------------------------------------------------------------
+#: the phase a dispatch site opens around its first call, with ``site=``
+FIRST_CALL = "setup/first_call"
+#: the site of every compilation outside such a phase
+EAGER = "eager"
+
+_TRACE_EVENT = "/jax/core/compile/jaxpr_trace_duration"
+_LOWER_EVENT = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+#: around the whole of ``compile_or_get_cached``: the last event of a
+#: program, built by the backend or fetched
+_BACKEND_EVENT = "/jax/core/compile/backend_compile_duration"
+_FETCH_EVENT = "/jax/compilation_cache/cache_retrieval_time_sec"
+
+
+class _Pending(threading.local):
+    """What this thread's next program has cost so far."""
+
+    def __init__(self):
+        self.traces: List[tuple] = []    # (start, end), outermost only
+        self.lower_s = 0.0
+        self.fetched = False
+
+
+_pending = _Pending()
+
+
+def _on_compile_event(event: str, duration: float, **_) -> None:
+    p = _pending
+    if event == _TRACE_EVENT:
+        # a jitted function traced inside another reports too, before
+        # the one that holds it: only the outermost is time of its own
+        end = time.perf_counter()
+        start = end - duration
+        p.traces = [t for t in p.traces if t[0] < start] + [(start, end)]
+    elif event == _LOWER_EVENT:
+        p.lower_s += duration
+    elif event == _FETCH_EVENT:
+        p.fetched = True
+    elif event == _BACKEND_EVENT:
+        cost = {"trace_s": sum(e - s for s, e in p.traces),
+                "lower_s": p.lower_s,
+                "backend_s": 0.0 if p.fetched else duration,
+                # key, read and deserialization: what the hit cost
+                "cache_fetch_s": duration if p.fetched else 0.0,
+                "cache_hit": p.fetched}
+        p.traces, p.lower_s, p.fetched = [], 0.0, False
+        site = next((ph.ids.get("site", EAGER)
+                     for ph in reversed(_trace.open_phases())
+                     if ph.name == FIRST_CALL), EAGER)
+        reg = registry()
+        for k in ("trace_s", "lower_s", "backend_s", "cache_fetch_s"):
+            reg.counter("compile/" + k).add(cost[k])
+        reg.counter("compile/cache_hits" if cost["cache_hit"]
+                    else "compile/cache_misses").add(1)
+        reg.counter("compile/programs").add(1)
+        _events.emit("compile", site=site, **cost)
+        from . import xla_stats    # it imports this module
+
+        xla_stats.charge_compile(site, **cost)
+
+
+jax.monitoring.register_event_duration_secs_listener(_on_compile_event)

@@ -22,16 +22,27 @@ Disabled mode is the fast path: with ``enable()`` off and no profiler
 session live (``TraceMe.is_enabled``, the test a TraceMe makes of itself)
 ``scope()`` hands back one shared no-op context — no allocation, no lock,
 no event.
+
+**Set-up phases** (``phase``) are the one exception to the switch: a span
+for an edge that happens a few dozen times a process (the package's
+import, a trainer's or an engine's constructor, a dispatch site's first
+call), recorded whether or not ``enable()`` is on, as one event of kind
+``phase`` in the always-on event log. Never on a tick or a step.
 """
 from __future__ import annotations
 
 import contextlib
+import itertools
 import json
+import os
 import threading
 import time
 from typing import Dict, List, Optional
 
 import jax
+
+from . import events as _lifecycle
+from .metrics import registry as _registry
 
 try:  # private jax API with a public-behavior contract (moe.py precedent)
     from jax._src.core import trace_state_clean as _trace_state_clean
@@ -255,6 +266,124 @@ class RecordEvent(_Span):
 
     def end(self):
         self.__exit__(None, None, None)
+
+
+class _PhaseTLS(threading.local):
+    def __init__(self):
+        self.stack: List["_Phase"] = []   # this thread's open phases
+
+
+_phases = _PhaseTLS()
+_phase_seq = itertools.count()
+
+
+class _Phase(RecordEvent):
+    """What ``phase`` opens: a span on the event log's clock
+    (``perf_counter_ns``, the host's: it says where the host was, so the
+    phases of one thread partition its wall time, and device work a phase
+    queues is charged to the phase that first waits for it)."""
+
+    __slots__ = ("id", "ids", "parent", "start_ns")
+
+    def __init__(self, name: str, start_ns: Optional[int] = None, **ids):
+        # a TraceMe's name ends where its stats begin, at a "#": the
+        # annotation says "serving.tick:0" for the site "serving.tick#0"
+        super().__init__(name, **{k: str(v).replace("#", ":")
+                                  for k, v in ids.items()})
+        self.ids = ids
+        self.id = next(_phase_seq)
+        self.parent: Optional[_Phase] = None
+        self.start_ns = start_ns
+
+    def __enter__(self):
+        stack = _phases.stack
+        self.parent = stack[-1] if stack else None
+        stack.append(self)
+        if _enabled or _session_live():
+            super().__enter__()          # ``pt:<name>``, as every scope
+        if self.start_ns is None:
+            self.start_ns = time.perf_counter_ns()
+        return self
+
+    def __exit__(self, *exc):
+        end_ns = time.perf_counter_ns()
+        if self._mode:
+            super().__exit__(*exc)
+        # a child left open (a ``begin()`` whose ``end()`` an exception
+        # skipped) goes with its parent
+        stack = _phases.stack
+        while stack and stack.pop() is not self:
+            pass
+        _lifecycle.emit(
+            "phase", name=self.name, t0_ns=self.start_ns, t1_ns=end_ns,
+            id=self.id, parent=self.parent and self.parent.id,
+            tid=threading.get_ident(), **self.ids)
+        return False
+
+
+def phase(name: str, start_ns: Optional[int] = None, **ids) -> _Phase:
+    """``with profiler.phase("setup/engine", site=...):`` - a set-up
+    phase: recorded **whether or not** ``enable()`` is on. At its end it
+    emits one event of kind ``phase`` into ``events.log()``: ``name``,
+    ``t0_ns`` and ``t1_ns`` on the log's clock, its ``id``, the ``id`` of
+    the phase that encloses it on this thread (``parent``, None at the
+    top), ``tid`` and the keyword ids. As every scope it opens
+    ``pt:<name>`` while a profiler session is live. ``begin()`` /
+    ``end()`` as ``RecordEvent``. ``start_ns``: for the one phase that
+    began before this module could be imported (the package's import).
+
+    For edges that happen a few dozen times a process. A plain
+    ``scope()`` keeps its no-op fast path; nothing on a tick or a step
+    may open a phase (tests/test_setup_phases.py counts them)."""
+    return _Phase(name, start_ns, **ids)
+
+
+def import_began(t0_ns: int) -> _Phase:
+    """For the first lines of ``paddle_tpu/__init__.py``, which read the
+    clock before anything could be imported: sets the gauge
+    ``proc/age_at_import_s``, the process's age at ``t0_ns`` (what lay
+    before the program), and returns the phase ``setup/import``, begun
+    then; the package's last line ends it."""
+    _registry().gauge("proc/age_at_import_s").set(
+        process_age_s() - (time.perf_counter_ns() - t0_ns) / 1e9)
+    return phase("setup/import", start_ns=t0_ns).begin()
+
+
+def open_phases() -> List[_Phase]:
+    """This thread's open phases, outermost first."""
+    return list(_phases.stack)
+
+
+def charge_setup(kind: str, seconds: float, nbytes: Optional[int] = None,
+                 **labels) -> None:
+    """Set-up work too fine for a phase each (a parameter drawn, a layer
+    cast): ``seconds`` more on the counter ``setup/<kind>_s{labels}``, and
+    ``nbytes`` more on ``setup/<kind>_bytes{labels}``. The innermost open
+    phase, if any, is the last label (``phase=setup/engine/decode_state``),
+    so that a reader can take these seconds out of that phase's."""
+    if _phases.stack:
+        labels["phase"] = _phases.stack[-1].name
+    label = "{%s}" % ",".join(f"{k}={v}" for k, v in labels.items()) \
+        if labels else ""
+    reg = _registry()
+    reg.counter(f"setup/{kind}_s{label}").add(seconds)
+    if nbytes is not None:
+        reg.counter(f"setup/{kind}_bytes{label}").add(nbytes)
+
+
+def process_age_s() -> float:
+    """Seconds since this process was started, from /proc where there is
+    one (0.0 where there is none): what lies before the package's first
+    line is the interpreter, jax, the device runtime's start and the
+    caller's own files."""
+    try:
+        with open("/proc/self/stat", encoding="ascii") as f:
+            start_ticks = float(f.read().rsplit(")", 1)[1].split()[19])
+        with open("/proc/uptime", encoding="ascii") as f:
+            uptime = float(f.read().split()[0])
+        return uptime - start_ticks / os.sysconf("SC_CLK_TCK")
+    except (OSError, ValueError, IndexError):
+        return 0.0
 
 
 def annotate(name: str):

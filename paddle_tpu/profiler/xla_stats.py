@@ -21,6 +21,17 @@ cache hit, which is the cost the caller actually paid) and folds the
 cost analysis into the registry as ``xla/<site>/compile_ms`` /
 ``.../flops`` / ``.../bytes_accessed`` gauges plus the inventory.
 
+What the PROCESS paid for a site's program is measured where it was paid:
+a site that opens a ``setup/first_call`` phase around its first call gets
+that call's seconds of tracing, lowering, backend compilation and cache
+retrieval, and whether the cache held it (``charge_compile``, fed by
+recompile.py's ``jax.monitoring`` listener; gauges ``xla/<site>/trace_s``
+... ``cache_hit``). Its ``compile_ms`` is then that call's backend
+compilation or cache fetch, and a later ``record_lowered`` adds the cost
+analysis and leaves the timing alone. Compilations outside any first call
+are charged to the bucket ``eager``: ``get("eager")``, not a dispatch
+site and not in ``inventory()``.
+
 CPU caveat (documented, not hidden): the CPU backend's cost analysis
 reports ``flops``/``bytes accessed`` from the optimized HLO but no
 per-op timing model; on some backends/versions ``cost_analysis()``
@@ -38,6 +49,7 @@ from . import recompile as _recompile
 from .metrics import registry
 
 __all__ = ["ProgramStats", "record_lowered", "record_compiled",
+           "charge_compile",
            "normalize_cost", "inventory", "program_inventory", "get",
            "reset", "category_breakdown", "module_sites",
            "ambiguous_modules", "register_module_site"]
@@ -60,7 +72,9 @@ class ProgramStats:
 
     __slots__ = ("site", "compile_ms", "flops", "bytes_accessed",
                  "cost", "recorded_unix", "module", "categories",
-                 "collectives", "flops_unattributed")
+                 "collectives", "flops_unattributed", "programs",
+                 "trace_s", "lower_s", "backend_s", "cache_fetch_s",
+                 "cache_hit")
 
     def __init__(self, site: str, compile_ms: Optional[float],
                  flops: Optional[float], bytes_accessed: Optional[float],
@@ -78,6 +92,12 @@ class ProgramStats:
         self.collectives = collectives or {}
         self.flops_unattributed = flops_unattributed
         self.recorded_unix = time.time()
+        #: compilations the process made for this site (``charge_compile``)
+        #: and their seconds; ``cache_hit``: the cache held every one
+        self.programs = 0
+        self.trace_s = self.lower_s = 0.0
+        self.backend_s = self.cache_fetch_s = 0.0
+        self.cache_hit: Optional[bool] = None
 
     def to_dict(self) -> dict:
         return {
@@ -91,6 +111,11 @@ class ProgramStats:
             "categories": self.categories,
             "collectives": self.collectives,
             "flops_unattributed": self.flops_unattributed,
+            "programs": self.programs,
+            "trace_s": self.trace_s, "lower_s": self.lower_s,
+            "backend_s": self.backend_s,
+            "cache_fetch_s": self.cache_fetch_s,
+            "cache_hit": self.cache_hit,
         }
 
 
@@ -332,6 +357,13 @@ def record_compiled(site: str, compiled,
                          collectives=collectives,
                          flops_unattributed=unattrib)
     with _lock:
+        prior = _programs.get(site)
+        if prior is not None and prior.programs:
+            # the process's own compilation of this site was measured:
+            # that is what it paid, not this diagnostic compile
+            for k in ("compile_ms", "programs", "trace_s", "lower_s",
+                      "backend_s", "cache_fetch_s", "cache_hit"):
+                setattr(stats, k, getattr(prior, k))
         _programs[site] = stats
     reg = registry()
     if stats.compile_ms is not None:
@@ -355,6 +387,34 @@ def record_lowered(site: str, lowered) -> ProgramStats:
     return record_compiled(site, compiled, compile_s=dt)
 
 
+def charge_compile(site: str, trace_s: float, lower_s: float,
+                   backend_s: float, cache_fetch_s: float,
+                   cache_hit: bool) -> None:
+    """One compilation the process made for ``site`` (recompile.py's
+    listener calls this): its seconds are added to the site's record,
+    made here if this is the first the inventory hears of the site, and
+    the gauges ``xla/<site>/...`` follow."""
+    with _lock:
+        st = _programs.get(site)
+        if st is None:
+            st = _programs[site] = ProgramStats(site, 0.0, None, None, {})
+        elif not st.programs:
+            st.compile_ms = 0.0          # a diagnostic timing gives way
+        st.programs += 1
+        st.trace_s += trace_s
+        st.lower_s += lower_s
+        st.backend_s += backend_s
+        st.cache_fetch_s += cache_fetch_s
+        st.cache_hit = cache_hit and st.cache_hit is not False
+        st.compile_ms += (backend_s + cache_fetch_s) * 1e3
+        vals = {k: getattr(st, k) for k in
+                ("compile_ms", "trace_s", "lower_s", "backend_s",
+                 "cache_fetch_s", "cache_hit")}
+    reg = registry()
+    for k, v in vals.items():
+        reg.gauge(f"xla/{site}/{k}").set(v)
+
+
 def get(site: str) -> Optional[ProgramStats]:
     with _lock:
         return _programs.get(site)
@@ -362,9 +422,10 @@ def get(site: str) -> Optional[ProgramStats]:
 
 def inventory() -> Dict[str, dict]:
     """JSON-ready {site: stats} — what bench blocks and the sink
-    embed."""
+    embed. Dispatch sites only: the ``eager`` bucket is ``get("eager")``."""
     with _lock:
-        return {site: s.to_dict() for site, s in sorted(_programs.items())}
+        return {site: s.to_dict() for site, s in sorted(_programs.items())
+                if site != _recompile.EAGER}
 
 
 #: package-level spelling (``profiler.program_inventory()``) — the

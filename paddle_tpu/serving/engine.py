@@ -151,7 +151,7 @@ from __future__ import annotations
 import time
 import warnings
 from collections import deque
-from contextlib import contextmanager
+from contextlib import contextmanager, nullcontext
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Tuple
 
@@ -174,6 +174,7 @@ __all__ = ["ServingConfig", "ServingEngine", "Request", "SpecConfig"]
 #: engine ids stamped on every event (``eng`` attr) so co-resident
 #: engines' timelines don't alias in the process-global log
 _ENGINE_SEQ = iter(range(1 << 20))
+_NOTHING = nullcontext()
 
 
 #: a request's default sampling key: the request id folded into the
@@ -362,7 +363,15 @@ class ServingEngine:
     """
 
     def __init__(self, model, config: Optional[ServingConfig] = None):
-        cfg = config or ServingConfig()
+        # compiled programs: ONE mixed-row tick site serving decodes
+        # AND prefill chunks, asserted single-trace (spec decoding: the
+        # verify tick, plus the draft tick's site)
+        self._tick_site = _recompile.unique_site("serving.tick")
+        # set-up on the always-on record (profiler/trace.py ``phase``)
+        with _ptrace.phase("setup/engine", site=self._tick_site):
+            self._construct(model, config or ServingConfig())
+
+    def _construct(self, model, cfg: ServingConfig):
         mcfg = model.config
         if cfg.decode not in ("greedy", "sampling"):
             raise ValueError(f"unknown decode mode {cfg.decode!r}")
@@ -396,7 +405,10 @@ class ServingEngine:
         self._program_args: Dict[str, tuple] = {}
         self.config = cfg
         self.model_config = mcfg
-        self._stacked, self._other = model._decode_state()
+        # the second, stacked copy of the weights (under ``LazyGuard``
+        # the only one: drawn here)
+        with _ptrace.phase("setup/engine/decode_state"):
+            self._stacked, self._other = model._decode_state()
         self._dtype = self._other["embeddings.wte.weight"].dtype
         #: a looped model runs its layers ``loop_steps`` times a tick and
         #: keeps a cache for every (step, layer); a configuration without
@@ -419,10 +431,11 @@ class ServingEngine:
         ps = cfg.page_size
         pages_per_slot = cfg.pages_per_slot or -(-mcfg.max_seq_len // ps)
         num_pages = cfg.num_pages or cfg.num_slots * pages_per_slot + 1
-        self.pool = PagePool(mcfg.num_layers * self._loop_steps, num_pages,
-                             ps, nh, hd, cfg.num_slots, pages_per_slot,
-                             dtype=kv_map[cfg.kv_dtype],
-                             prefix_cache=cfg.prefix_cache)
+        with _ptrace.phase("setup/engine/pools"):
+            self.pool = PagePool(
+                mcfg.num_layers * self._loop_steps, num_pages, ps, nh, hd,
+                cfg.num_slots, pages_per_slot, dtype=kv_map[cfg.kv_dtype],
+                prefix_cache=cfg.prefix_cache)
         # set once: how deep the pools are, and what the engine holds on
         # the device for the model (one copy of the weights, as served)
         _registry().gauge("serving/cache_layers").set(
@@ -486,10 +499,6 @@ class ServingEngine:
         self._base_key = jax.device_put(jax.random.PRNGKey(cfg.seed),
                                         _host_device())
         _fold_key(self._base_key, np.uint32(0))
-        # compiled programs: ONE mixed-row tick site serving decodes
-        # AND prefill chunks, asserted single-trace (spec decoding: the
-        # verify tick, plus the draft tick's site)
-        self._tick_site = _recompile.unique_site("serving.tick")
         if self._spec is not None:
             from .spec import DraftRunner, make_spec_tick
 
@@ -558,6 +567,9 @@ class ServingEngine:
         # reads). None is a hot-path dispatch site
         self._tick = jax.jit(tick, donate_argnums=2)
         self._copy = jax.jit(Pools.copy_page, donate_argnums=0)
+        #: the page copy's first call is a phase of its own site
+        self._copy_site = self._tick_site.replace("tick", "copy_page")
+        self._copy_called = False
         self._import_fn = jax.jit(Pools.write_pages, donate_argnums=0)
         self._export_fn = jax.jit(Pools.gather_pages)
         # size of the fresh-page reset vector folded into every tick of
@@ -609,12 +621,15 @@ class ServingEngine:
         fresh = self.pool.take_fresh(self._fresh_cap)
         return (self.pool.pools, fresh)
 
-    def _note_avals(self, site: str, fn, args: tuple) -> None:
+    def _first_call(self, site: str, fn, args: tuple):
         """Remember a dispatch site's argument avals (shape/dtype only
         — captured BEFORE dispatch, since donation invalidates the pool
-        buffers) the first time it dispatches."""
+        buffers) the first time it dispatches. Returns what that dispatch
+        runs under: phase ``setup/first_call`` [``site``] the first time
+        (recompile.py's listener charges the compilation's seconds to
+        the site), nothing after."""
         if site in self._program_args:
-            return
+            return _NOTHING
 
         def aval(a):
             if hasattr(a, "shape") and hasattr(a, "dtype"):
@@ -624,6 +639,7 @@ class ServingEngine:
 
         self._program_args[site] = (
             fn, jax.tree_util.tree_map(aval, args))
+        return _ptrace.phase(_recompile.FIRST_CALL, site=site)
 
     def _run_tick(self, args: tuple):
         """Dispatch the tick (unified, or the spec engine's verify tick).
@@ -635,12 +651,13 @@ class ServingEngine:
         a pool-sized temporary is a whole-pool copy every tick (ROADMAP
         S3; chip_smoke.py fails on it). Tracing, lowering and compiling
         still happen once: the call finds what ``lower`` made, and
-        ``compile`` finds the call's executable."""
+        ``compile`` finds the call's executable. All of it is inside
+        the site's ``setup/first_call`` phase."""
         if self._tick_site in self._program_args:
             with _quiet_donation():
                 return self._tick(*args)
-        self._note_avals(self._tick_site, self._tick, args)
-        with _quiet_donation():
+        with _quiet_donation(), \
+                self._first_call(self._tick_site, self._tick, args):
             lowered = self._tick.lower(*args)
             out = self._tick(*args)
             memory = lowered.compile().memory_analysis()
@@ -1420,7 +1437,11 @@ class ServingEngine:
                 if self.pool.grow_slot(slot, 1):
                     dst = self.pool.tables[slot,
                                            self.pool.slot_pages(slot) - 1]
-                    with _quiet_donation():
+                    first = _NOTHING if self._copy_called else \
+                        _ptrace.phase(_recompile.FIRST_CALL,
+                                      site=self._copy_site)
+                    self._copy_called = True
+                    with _quiet_donation(), first:
                         self.pool.pools = self._copy(
                             self.pool.pools, np.int32(src), np.int32(dst))
                     # scales travel with the page; un-list dst from the
@@ -1971,8 +1992,8 @@ class ServingEngine:
                      feed_toks, feed_pos0, feed_len, gen_tok, gen_pos,
                      dsample, np.bool_(any_feed),
                      np.bool_(len(gen_slots) > 0))
-            self._note_avals(dr.site, dr.tick, dargs)
-            with _quiet_donation():
+            with _quiet_donation(), \
+                    self._first_call(dr.site, dr.tick, dargs):
                 dr.kc, dr.vc, drafts, *probs = dr.tick(*dargs)
             if sampling:
                 dprobs = dprobs_m = probs[0]
@@ -2118,8 +2139,8 @@ class ServingEngine:
                            np.ascontiguousarray(self._topps),
                            tok_m, acc, ch_pos0, cm2),
                           np.bool_(False), np.bool_(True))
-                self._note_avals(dr.site, dr.tick, dargs2)
-                with _quiet_donation():
+                with _quiet_donation(), \
+                        self._first_call(dr.site, dr.tick, dargs2):
                     dr.kc, dr.vc, ch_drafts, ch_probs = \
                         dr.tick(*dargs2)
                 pend_new = {"drafts": ch_drafts, "probs": ch_probs,

@@ -252,7 +252,7 @@ def test_tpu_compile_ragged_pallas(monkeypatch, shape, int8, t, layers):
 def _compiled_tick(monkeypatch, model_cfg, serving_cfg):
     """(engine, compiled tick): an engine built and ticked once on the CPU,
     its tick re-lowered from the avals captured at that dispatch
-    (engine._note_avals) with TPU shardings, as a program traced for the
+    (engine._first_call) with TPU shardings, as a program traced for the
     TPU: the tick the chip runs, the attention kernel inside."""
     import paddle_tpu as paddle
     from paddle_tpu.models import GPT
