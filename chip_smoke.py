@@ -25,12 +25,20 @@ finds, and checks what comes out by the repo's own means:
               bf16: the loss against the float32 reference, and the scan
               took the path production takes here, the Pallas kernels
               kda_fwd/kda_bwd_*
+  1d head     the fused lm-head + cross-entropy at the four training
+              cells' shapes (tied [V, H] and Linear [H, V], a vocabulary
+              shard of 25152 as 6.7B's): loss and both gradients against
+              autodifferentiation of the float32 reference, and the
+              program traced the rule that makes the gradients in the
+              forward pass
   2 serve     ServingEngine.submit/step/run, ten requests over ~150 ticks;
               the tick took its attention through the ragged kernel
   3 train     HybridPipelineTrainer.step, bench.py's headline knobs
-  4 multichip the same trainer on dp2 x tp2 and pp2 x tp2, and the ZeRO /
-              int8-ring arms of compile_train_step on dp=4 (only with
-              >= 4 devices)
+  4 multichip the same trainer on dp2 x tp2 and pp2 x tp2 (and the head
+              alone as that arm runs it: inside a region manual over pp,
+              its vocabulary over tp, gradients against the reference),
+              and the ZeRO / int8-ring arms of compile_train_step on dp=4
+              (only with >= 4 devices)
 
 There is no CPU mode: with no accelerator it exits non-zero and prints no
 result. A failed phase does not stop the later ones, but the exit code is
@@ -70,6 +78,21 @@ TOL_RAGGED = 2e-2
 # an eighth of its output) is not expected and not allowed for.
 TOL_EXPERTS_FWD = 2e-2
 TOL_EXPERTS_BWD = 3e-2
+
+# the fused head against autodifferentiation of the float32 reference at
+# "highest" on the same bf16-rounded inputs. The loss is float32 sums of
+# exact products either way. ``dx`` is rounded to bf16 once; ``dW`` is a
+# bf16 running sum over the sequence's chunks of 256 (8 to 32 of them), one
+# rounding an add, as the transposed scan's was before the gradients moved
+# into the forward pass. Seen on the v5e, PR 36, at the four cells' shapes:
+# loss 3e-8 to 1e-7, dx 2.4e-3 to 5.0e-3, dW 6.8e-3 to 9.2e-3.
+TOL_HEAD_LOSS = 1e-5
+TOL_HEAD_GRAD = 3e-2
+#: (rows, sequence, hidden, vocabulary, weight is [V, H]) of the head in
+#: the four training cells: 1.3B, OLMoE, Solar-Open2, and one chip's
+#: vocabulary shard of 6.7B on pp2 x tp2
+HEAD_SHAPES = ((12, 2048, 2048, 50304, True), (8, 4096, 2048, 50304, False),
+               (2, 8192, 4096, 24576, False), (48, 2048, 4096, 25152, True))
 
 #: the most a ``submit()`` may take while ticks are in flight, ms: it is
 #: tens of microseconds, and was two to three ticks (137 ms at 1.3B) while
@@ -454,6 +477,120 @@ def phase_experts(t: int, h: int, f: int, e: int, k: int) -> None:
 
 
 # ---------------------------------------------------------------------------
+# phase 1d: the fused head's loss and gradients
+# ---------------------------------------------------------------------------
+def check_head(b: int, s: int, h: int, v: int, w_is_vh: bool, dtype,
+               mesh=None) -> dict:
+    """The fused lm-head + cross-entropy on ``[b, s, h]`` states and a
+    ``v``-word vocabulary, next-token labels, chunks of 256 as the models
+    call it: loss, ``dx`` and ``dW`` against autodifferentiation of the
+    plain float32 reference at highest precision on the same
+    (dtype-rounded) inputs, a row at a time (its ``[b, s, v]`` float32
+    logits are what the head exists not to hold). With ``mesh`` (axes
+    ``pp`` and ``tp``) the head runs as the pipeline's ``head_fn`` does:
+    inside a region manual over ``pp`` alone, the last stage's loss kept,
+    the vocabulary sharded over ``tp``."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from paddle_tpu.ops.fused_ce import (IGNORE,
+                                         fused_linear_cross_entropy_fn,
+                                         shifted_labels)
+    from paddle_tpu.profiler import metrics
+
+    def traced():
+        reg = metrics.registry()
+        return {r: reg.counter("head/fused_ce_traces{rule=%s}" % r).value
+                for r in ("grad_in_forward", "loss_only")}
+
+    ks = jax.random.split(jax.random.PRNGKey(v + h), 3)
+    x = jax.random.normal(ks[0], (b, s, h), jnp.float32).astype(dtype)
+    w = (0.02 * jax.random.normal(ks[1], (v, h) if w_is_vh else (h, v),
+                                  jnp.float32)).astype(dtype)
+    labels = shifted_labels(jax.random.randint(ks[2], (b, s), 0, v))
+    n = int(jnp.sum(labels != IGNORE))
+
+    def head(x_, w_, labels_):
+        return fused_linear_cross_entropy_fn(x_, w_, labels_, chunk=256,
+                                             transpose_w=not w_is_vh)
+
+    program, args = head, (x, w, labels)
+    if mesh is not None:
+        last = mesh.shape["pp"] - 1
+
+        def program(x_, w_, labels_):
+            @jax.shard_map(mesh=mesh, in_specs=(P(), P(), P()),
+                           out_specs=P(), check_vma=False,
+                           axis_names=frozenset({"pp"}))
+            def region(x_, w_, labels_):
+                out = head(x_, w_, labels_)
+                return jax.lax.psum(jnp.where(
+                    jax.lax.axis_index("pp") == last, out, 0.0), "pp")
+            return region(x_, w_, labels_)
+
+        w_spec = P("tp", None) if w_is_vh else P(None, "tp")
+        args = tuple(jax.device_put(a, NamedSharding(mesh, spec))
+                     for a, spec in zip(args, (P(), w_spec, P())))
+
+    @jax.jit
+    def reference_row(x_, w_, labels_):
+        def row_loss(x_, w_):
+            logits = jnp.einsum("sh,vh->sv" if w_is_vh else "sh,hv->sv",
+                                x_, w_)
+            logp = jax.nn.log_softmax(logits, axis=-1)
+            gold = jnp.take_along_axis(
+                logp, jnp.clip(labels_, 0)[:, None], axis=-1)[:, 0]
+            return -jnp.sum(jnp.where(labels_ != IGNORE, gold, 0.0)) / n
+        return jax.value_and_grad(row_loss, (0, 1))(x_, w_)
+
+    before = traced()
+    loss, (dx, dw) = jax.jit(jax.value_and_grad(program, (0, 1)))(*args)
+    after = traced()
+    check(after["grad_in_forward"] == before["grad_in_forward"] + 1
+          and after["loss_only"] == before["loss_only"],
+          f"head: the differentiated program traced {after} after {before}, "
+          "not the rule that makes the gradients in the forward pass once")
+    w32 = w.astype(jnp.float32)
+    want, want_dx, want_dw = 0.0, [], jnp.zeros(w.shape, jnp.float32)
+    with jax.default_matmul_precision("highest"):
+        for i in range(b):
+            row, (row_dx, row_dw) = reference_row(
+                x[i].astype(jnp.float32), w32, labels[i])
+            want, want_dw = want + float(row), want_dw + row_dw
+            want_dx.append(np.asarray(row_dx))
+    errs = {"loss": abs(float(loss) - want) / abs(want),
+            "dx": _nerr(dx, np.stack(want_dx)), "dW": _nerr(dw, want_dw)}
+    check(dx.dtype == x.dtype and dw.dtype == w.dtype,
+          f"head: gradients in {dx.dtype}, {dw.dtype}")
+    check(np.isfinite(list(errs.values())).all(), f"head non-finite {errs}")
+    check(errs["loss"] <= TOL_HEAD_LOSS, f"head loss error {errs}")
+    for name in ("dx", "dW"):
+        check(errs[name] <= TOL_HEAD_GRAD, f"head {name} error {errs}")
+    return errs
+
+
+def _head_errs(errs: dict) -> str:
+    return ("vs float32 autodiff: "
+            + " ".join(f"{k}={e:.2e}" for k, e in errs.items())
+            + f" (tol {TOL_HEAD_LOSS}/{TOL_HEAD_GRAD})")
+
+
+def phase_head(shapes) -> None:
+    import jax.numpy as jnp
+
+    t0 = time.perf_counter()
+    for b, s, h, v, w_is_vh in shapes:
+        errs = check_head(b, s, h, v, w_is_vh, jnp.bfloat16)
+        say("head", f"fused lm-head + CE x[{b}, {s}, {h}] "
+            f"W[{'V, H' if w_is_vh else 'H, V'}] V={v} bf16, gradients made "
+            "in the forward pass, " + _head_errs(errs))
+        release_device_memory()
+    say("head", f"{time.perf_counter() - t0:.1f} s, "
+        f"peak so far {_gb(peak_bytes())}")
+
+
+# ---------------------------------------------------------------------------
 # phase 2: serve
 # ---------------------------------------------------------------------------
 def check_submits(submits, limit_ms: float) -> None:
@@ -831,8 +968,10 @@ def _distinct_devices(tree) -> int:
 
 
 def phase_multichip(cfg, micro: int, n_micro: int, loss0: float,
-                    zero_cfg, zero_batch: int) -> None:
-    """Four chips: the trainer on dp2 x tp2 (pp = 1) and pp2 x tp2, then
+                    zero_cfg, zero_batch: int, head) -> None:
+    """Four chips: the trainer on dp2 x tp2 (pp = 1) and pp2 x tp2, the
+    head alone (``head``: ``check_head``'s shape and dtype) as the
+    pp2 x tp2 arm runs it, then
     compile_train_step on dp=4 replicated / ZeRO-1 f32 ring / ZeRO-2 int8
     ring."""
     import jax
@@ -860,6 +999,11 @@ def phase_multichip(cfg, micro: int, n_micro: int, loss0: float,
             f"and optimizer shards on {n_p} devices")
         del r, params, opt_state
         release_device_memory()
+
+    errs = check_head(*head, mesh=create_mesh({"pp": 2, "tp": 2}, devs))
+    say("multichip", f"head {head[:5]} inside a region manual over pp, "
+        "vocabulary over tp, " + _head_errs(errs))
+    release_device_memory()
 
     tokens = np.random.RandomState(0).randint(
         0, zero_cfg.vocab_size,
@@ -1043,6 +1187,7 @@ def main() -> int:
         olmoe.max_seq_len, olmoe.hidden_size, olmoe.moe_expert_width,
         olmoe.moe_num_experts, olmoe.moe_top_k))
     run("solar", lambda: phase_solar(256, "pallas"))
+    run("head", lambda: phase_head(HEAD_SHAPES))
     run("serve", lambda: phase_serve(cfg, slots, page, SERVE_REQUESTS))
     # the looped model at its published widths, 3 of its 48 layers
     looped = dataclasses.replace(GPTConfig.ouro_2_6b(), num_layers=3)
@@ -1052,7 +1197,9 @@ def main() -> int:
         run("multichip", lambda: phase_multichip(
             cfg, 2, 6, seen["train"]["loss0"],
             GPTConfig(vocab_size=512, hidden_size=512, num_layers=4,
-                      num_heads=4, max_seq_len=128), zero_batch=8))
+                      num_heads=4, max_seq_len=128), zero_batch=8,
+            # 6.7B's head on pp2 x tp2: the whole vocabulary, two shards
+            head=(48, 2048, 4096, 50304, True, jax.numpy.bfloat16)))
     else:
         say("multichip", f"{len(jax.devices())} device, not run"
             if len(jax.devices()) < 4 else "not run: train failed")

@@ -88,6 +88,30 @@ def test_kernel_check_catches_a_wrong_kernel(monkeypatch):
         chip_smoke.check_ragged(41, 4, 2, 64, 8, 8, False)
 
 
+@pytest.mark.parametrize("w_is_vh", [True, False])
+def test_head_rehearsal(w_is_vh):
+    errs = chip_smoke.check_head(2, 512, 32, 96, w_is_vh, jnp.bfloat16)
+    assert set(errs) == {"loss", "dx", "dW"}
+
+
+@pytest.mark.parametrize("at,name", [(0, "dx"), (1, "dW")])
+def test_the_head_check_sees_a_fault_in_a_gradient(monkeypatch, at, name):
+    """One of the saved gradients a tenth short: the check names it."""
+    from paddle_tpu.ops import fused_ce
+
+    real = fused_ce._fused_ce_bwd
+
+    def planted(*a):
+        out = list(real(*a))
+        out[at] = (0.9 * out[at]).astype(out[at].dtype)
+        return tuple(out)
+
+    # the custom_vjp reads its ``bwd`` at each call
+    monkeypatch.setattr(fused_ce._fused_ce, "bwd", planted)
+    with pytest.raises(chip_smoke.SmokeFailure, match=f"head {name} error"):
+        chip_smoke.check_head(2, 512, 32, 96, True, jnp.bfloat16)
+
+
 SERVE_TOY = GPTConfig(vocab_size=128, hidden_size=64, num_layers=2,
                       num_heads=2, max_seq_len=256)
 SERVE_REQUESTS = ((0, 6, 8, 0), (0, 40, 8, 16), (0, 120, 6, 0), (2, 9, 10, 0),
@@ -178,7 +202,10 @@ def test_a_copied_pool_fails_the_serve_phase(temp, alias, what):
 def test_train_and_multichip_rehearsal():
     loss0 = chip_smoke.phase_train(TOY, micro=2, n_micro=2,
                                    steps=3)["loss0"]
-    chip_smoke.phase_multichip(TOY, 2, 2, loss0, TOY, zero_batch=8)
+    # float32: XLA:CPU aborts on the bf16 all-reduce of the head's dx over
+    # tp (the trainer keeps its head outside the region on CPU + amp)
+    chip_smoke.phase_multichip(TOY, 2, 2, loss0, TOY, zero_batch=8,
+                               head=(4, 512, 32, 96, True, jnp.float32))
 
 
 def test_import_starts_no_backend():

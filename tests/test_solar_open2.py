@@ -525,12 +525,17 @@ def small_olmoe_step_text():
     return tr.aot_lower(jax.ShapeDtypeStruct((4, 32), np.int32)).as_text()
 
 
-#: sha256 of the small OLMoE step's lowered text, computed on the parent of
-#: the PR that gave ``dropless_moe`` its held range (PR 31) and on that PR's
-#: tree in one environment: equal. A change to that program on purpose
-#: recomputes it and says so.
+#: sha256 of the small OLMoE step's lowered text. PR 31 (which gave
+#: ``dropless_moe`` its held range) computed it on its parent and on its own
+#: tree in one environment: equal. PR 36 changed the program on purpose and
+#: recomputed it: the head's scan makes each chunk's gradients from the tile
+#: its loss was made from (``ops/fused_ce.py``), and between the two texts
+#: the only ``dot_general``s that differ are the head's, five by the
+#: vocabulary before (the tile twice, the one-hot's outer product, ``dW``,
+#: ``dx``) and three after. A change to that program on purpose recomputes
+#: it and says so.
 OLMOE_STEP_SHA256 = \
-    "ada2e9ca270666e6681752a3393c0e69c7f04c63c463186b745afc973403b9a8"
+    "7e29db9d420bcf8442baf98fa38aaf12bd31c585a7c102b7f0e912b337099a9b"
 
 
 def test_the_small_olmoe_step_is_the_parents_program():
