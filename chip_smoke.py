@@ -123,6 +123,20 @@ TOL_LOOP_EXIT = 0.02
 #: (prompt length, new tokens) of the looped pass: chunked prefill (32 a
 #: tick), mixed and decode-only ticks, three requests on two slots
 LOOP_REQUESTS = ((40, 24), (100, 16), (17, 32))
+# the latent-attention pass (ISSUE 37; models/dots3.py): the read sides of
+# the latent pools against plain float32 spellings (bf16 products, float32
+# accumulated), and a small model's emitted tokens against the float32
+# reference: the shortfall of an emitted token's logit below the reference's
+# largest, and the share of a selected set that differs from the reference's
+TOL_LATENT_OPS = 2e-2
+TOL_LATENT_SHORTFALL = 0.25
+#: ... of the median emitted token; the worst may lie this far below: the
+#: small model selects 8 keys a query, so one near-tie that bf16 indexer keys
+#: order otherwise moves an eighth of a query's attention (2.13 read on the
+#: chip, PR 37; perfbench/checks/dots3_serve.py has the published widths')
+TOL_LATENT_WORST = 4.0
+TOL_LATENT_SELECTED = 0.3
+LATENT_REQUESTS = ((23, 12), (41, 10), (7, 16))
 
 
 class SmokeFailure(AssertionError):
@@ -881,6 +895,164 @@ def phase_serve_looped(cfg, num_slots: int, page_size: int,
             "cache_layers": layers}
 
 
+def check_latent_ops(rows: int, t: int, heads: int, width: int, c: int,
+                     page: int, pages: int, topk: int, window: int,
+                     dtype) -> dict:
+    """``ops/paged_attention``'s latent-pool reads at the given row shape
+    (``rows`` rows of ``t`` queries over ``pages`` pages of ``page``
+    tokens, half of them live) against float32 spellings over the whole
+    rows: the indexer's scores, the selection (threshold against
+    ``top_k``), attention over the selection and over the window."""
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.ops import paged_attention as pa
+
+    rng = np.random.default_rng(5)
+    cap = pages * page
+    pool = jnp.asarray(rng.normal(size=(1, rows * pages + 1, width, page)),
+                       dtype)
+    table = 1 + np.arange(rows * pages, dtype=np.int32).reshape(rows, pages)
+    pos0 = (cap // 2 + np.arange(rows) * 3 - t).astype(np.int32)
+    true_len = np.full(rows, t, np.int32)
+    q = jnp.asarray(rng.normal(size=(rows, t, heads, width)) * .3, dtype)
+    q_i = jnp.asarray(rng.normal(size=(rows, t, 4, width)), dtype)
+    w_i = jnp.asarray(rng.normal(size=(rows, t, 4)), jnp.float32)
+    flat = jnp.swapaxes(pool[0, table], 2, 3).reshape(
+        rows, cap, width).astype(jnp.float32)               # [R, S, W]
+    qpos = pos0[:, None] + np.arange(t)[None]
+    seen = np.arange(cap)[None, None] <= qpos[..., None]    # [R, T, S]
+
+    score = jax.jit(pa.index_scores, static_argnums=3)(
+        q_i, w_i, pool, 0, table, pos0, true_len)
+    want = jnp.einsum("rtj,rtjs->rts", w_i, jax.nn.relu(jnp.einsum(
+        "rtjd,rsd->rtjs", q_i.astype(jnp.float32), flat)))
+    errs = {"index": _nerr(jnp.where(seen, score, 0.0),
+                           jnp.where(seen, want, 0.0))}
+    check(bool(jnp.all(jnp.isneginf(jnp.where(seen, -jnp.inf, score)))),
+          "an invisible position has a score")
+    keys, thr, ties = pa.select_threshold(score.reshape(rows * t, cap), topk)
+    mask = pa.selection_mask(keys, thr, ties).reshape(rows, t, cap) & seen
+    idx, valid = pa.select_topk(score.reshape(rows * t, cap), topk)
+    mine = np.asarray(mask.reshape(rows * t, cap))
+    same = all(set(np.flatnonzero(mine[i])) == set(
+        np.asarray(idx[i])[np.asarray(valid[i])]) for i in range(0, rows * t,
+                                                               max(t // 4, 1)))
+    check(same, "the threshold's selection is not top_k's")
+
+    def dense(keep, scale):
+        sc = jnp.einsum("rtnc,rsc->rtns", q.astype(jnp.float32), flat) \
+            * scale
+        pr = jax.nn.softmax(jnp.where(keep[:, :, None], sc, -jnp.inf), -1)
+        return jnp.einsum("rtns,rsc->rtnc", pr, flat[..., :c])
+
+    got = jax.jit(pa.selected_latent_attention, static_argnums=(2, 9, 10))(
+        q, pool, 0, table, pos0, true_len, keys.reshape(rows, t, cap),
+        thr.reshape(rows, t), ties.reshape(rows, t), c, 0.06)
+    errs["selected"] = _nerr(got, dense(mask, 0.06))
+    got, _ = jax.jit(pa.window_latent_attention, static_argnums=(2, 6, 7, 8))(
+        q, pool, 0, table, pos0, true_len, window, c, 0.06)
+    inside = seen & (np.arange(cap)[None, None] > qpos[..., None] - window)
+    errs["window"] = _nerr(got, dense(jnp.asarray(inside), 0.06))
+    for name, err in errs.items():
+        check(err <= TOL_LATENT_OPS,
+              f"latent {name} read off by {err:.2e}, allowed {TOL_LATENT_OPS}")
+    return errs
+
+
+def phase_latent(cfg, num_slots: int, page_size: int, pages_per_slot: int,
+                 ops_shape, requests=LATENT_REQUESTS) -> dict:
+    """The latent-attention pass (models/dots3.py): the latent pools' read
+    sides against plain spellings at ``ops_shape``, then a small model
+    through the engine (a ``LazyGuard`` model drawn on the device, latent,
+    indexer-key and windowed pools, the held experts inside the tick): what
+    it emitted is the float32 reference's (models/dots3_reference.py), the
+    sets its indexer selected are the reference's but for near-ties, and the
+    windowed layers' pages behind the window went back."""
+    import dataclasses as dc
+
+    import jax
+
+    import paddle_tpu as paddle
+    from paddle_tpu.models import dots3_reference as ref
+    from paddle_tpu.models.dots3 import Dots3
+    from paddle_tpu.profiler import registry
+    from paddle_tpu.serving import ServingConfig, ServingEngine
+
+    errs = check_latent_ops(*ops_shape)
+    say("latent", "reads against float32 spellings: " + ", ".join(
+        f"{k} {v:.2e}" for k, v in errs.items())
+        + f" (allowed {TOL_LATENT_OPS})")
+    reg = registry()
+    freed0 = reg.counter("serving/window_pages_freed").value
+    paddle.seed(0)
+    with paddle.LazyGuard():
+        net = Dots3(cfg)
+    net.eval()
+    net.bfloat16()
+    eng = ServingEngine(net, ServingConfig(
+        num_slots=num_slots, page_size=page_size,
+        pages_per_slot=pages_per_slot, prefix_cache=False))
+    layers, other = eng.served_weights()
+    on_default_platform((layers, other, eng.pool.pools),
+                        "latent serving state")
+    weights = reg.gauge("serving/weights_bytes").value
+    check(weights == 2 * cfg.num_params(),
+          f"serving/weights_bytes {weights:.0f} is not one bf16 copy of "
+          f"{cfg.num_params()} parameters")
+    rng = np.random.RandomState(13)
+    prompts = [rng.randint(0, cfg.vocab_size, n).astype(np.int32)
+               for n, _ in requests]
+    rids = [eng.submit(p, new) for p, (_, new) in zip(prompts, requests)]
+    results = eng.run()
+    jax.block_until_ready(eng.pool.pools)
+    freed = reg.counter("serving/window_pages_freed").value - freed0
+    check(freed > 0, "no page of a windowed layer went back")
+    check(eng.pool.check_consistency() == [], "the pools' books disagree")
+    config = dc.asdict(cfg)
+
+    def layer_weights():
+        for i, kind in enumerate(cfg.layer_types):
+            yield kind, cfg.is_moe(i), layers[f"layer{i}"]
+
+    differ, shorts = 0.0, []
+    for rid, prompt in zip(rids, prompts):
+        out = results[rid]
+        seq = np.concatenate([prompt, out[:-1]])
+        got = ref.forward(layer_weights(), other, seq, config, cfg.held)
+        at = np.arange(len(prompt) - 1, len(seq))
+        shorts.append(ref.shortfall(np.asarray(got["state"])[at], other,
+                                    out)[0])
+        for pos, sets in eng.tick_record.selected_sets(rid):
+            for layer, mine in enumerate(sets):
+                theirs = np.asarray(got["selected"][layer][pos])
+                a, b = set(mine.tolist()), set(theirs[theirs >= 0].tolist())
+                differ = max(differ, len(a ^ b) / max(2 * len(b), 1))
+    shorts = np.concatenate(shorts)
+    worst, median = float(shorts.max()), float(np.median(shorts))
+    check(median <= TOL_LATENT_SHORTFALL and worst <= TOL_LATENT_WORST,
+          f"an emitted token's logit lies {median:.4f} below the float32 "
+          f"reference's largest at the median (allowed "
+          f"{TOL_LATENT_SHORTFALL}) and {worst:.4f} at the worst (allowed "
+          f"{TOL_LATENT_WORST})")
+    check(differ <= TOL_LATENT_SELECTED,
+          f"a selected set differs from the reference's in {differ:.3f} of "
+          f"it, allowed {TOL_LATENT_SELECTED}")
+    paths = {k: v for k, v in reg.snapshot().items()
+             if k.startswith("moe/grouped_matmul_calls")} \
+        if hasattr(reg, "snapshot") else {}
+    say("latent", f"{len(rids)} requests through latent, indexer-key and "
+        f"windowed pools ({cfg.num_hidden_layers} layers): shortfall "
+        f"{median:.4f} at the median (allowed {TOL_LATENT_SHORTFALL}), "
+        f"{worst:.4f} at the worst (allowed {TOL_LATENT_WORST}), a selected "
+        f"set "
+        f"differs by at most {differ:.3f} (allowed {TOL_LATENT_SELECTED}), "
+        f"{freed:.0f} windowed pages given back; weights {_gb(weights)}"
+        + (f"; {paths}" if paths else ""))
+    return {"worst": worst, "median": median, "differ": differ,
+            "freed": freed, "weights_bytes": weights, **errs}
+
+
 # ---------------------------------------------------------------------------
 # phases 3 and 4: the hybrid trainer
 # ---------------------------------------------------------------------------
@@ -1192,6 +1364,14 @@ def main() -> int:
     # the looped model at its published widths, 3 of its 48 layers
     looped = dataclasses.replace(GPTConfig.ouro_2_6b(), num_layers=3)
     run("serve-looped", lambda: phase_serve_looped(looped, 4, page, 16))
+    # the latent-attention model: its pools' reads at the cell's row shape
+    # (128 heads over latents of 576, pages of 128), and a small model of
+    # its kinds of layer through the engine
+    from paddle_tpu.models.dots3 import Dots3Config
+
+    run("latent", lambda: phase_latent(
+        Dots3Config.tiny(hidden_size=256, experts_held=(0, 4)), 3, 4, 24,
+        (3, 16, 128, 576, 512, 128, 24, 512, 513, jax.numpy.bfloat16)))
     run("train", lambda: phase_train(cfg, micro=2, n_micro=6, steps=4))
     if len(jax.devices()) >= 4 and "train" not in failed:
         run("multichip", lambda: phase_multichip(

@@ -719,6 +719,14 @@ class GPT(nn.Layer):
         eng.reset_results()
         return _T(jnp.asarray(out)), _T(jnp.zeros((b,), jnp.float32))
 
+    def cache_spec(self) -> dict:
+        """What caches serving keeps for this model (``ServingEngine``
+        reads them from the model): K and V a layer, and a loop step."""
+        c = self.config
+        return {"kind": "kv", "layers": c.num_layers * c.loop_steps,
+                "heads": c.num_heads,
+                "head_dim": c.hidden_size // c.num_heads}
+
     def _decode_state(self):
         """Cached (stacked, other) decode params; rebuilt only when the
         underlying param values changed (training step replaces them)."""
@@ -763,20 +771,26 @@ class GPT(nn.Layer):
 def _require_served_block(cfg: GPTConfig):
     """The serving forwards below (gpt_block_body and what calls it:
     generate(), ServingEngine) run every dense block GPTBlock trains; what
-    they still lack is refused here, by what it is."""
+    they still lack is refused here, by what it is. Experts inside a
+    serving tick exist since ISSUE 37, in a model that brings its own
+    forward (``models/dots3.py``: ``held_moe`` under ``blk/ffn``); *this*
+    file's forwards still run dense FFNs alone."""
     lacks = []
     if cfg.moe_num_experts:
-        lacks.append("experts inside the tick (an expert layer under "
-                     "blk/ffn of the serving forwards)")
+        lacks.append("an expert layer under blk/ffn of gpt_block_body "
+                     "(dropless_moe or switch_moe in place of the dense "
+                     "FFN, and the pools' page accounting unchanged)")
     if cfg.qk_norm:
         lacks.append("QK-norm in the served block")
     if lacks:
         raise NotImplementedError(
-            "serving and generate() run dense blocks (LayerNorm or RMSNorm, "
-            "learned positions or RoPE, with or without biases, GELU or "
-            "SwiGLU, sandwich norms, a looped stack); this model needs "
-            + " and ".join(lacks) + ", supported for training only "
-            "(ROADMAP R1)")
+            "models/gpt.py's serving forwards and generate() run dense "
+            "blocks (LayerNorm or RMSNorm, learned positions or RoPE, with "
+            "or without biases, GELU or SwiGLU, sandwich norms, a looped "
+            "stack); this model needs " + " and ".join(lacks)
+            + ". A model that brings its own tick forward is served with "
+            "its experts (models/dots3.py: held_moe inside the tick); "
+            "GPTBlock's experts are not yet (ROADMAP R1)")
 
 
 def _ln(x, w, b, eps):

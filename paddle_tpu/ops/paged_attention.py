@@ -92,7 +92,10 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-__all__ = ["ragged_paged_attention", "paged_kv_scatter"]
+__all__ = ["ragged_paged_attention", "paged_kv_scatter", "latent_scatter",
+           "index_scores", "select_topk", "select_threshold",
+           "selection_mask", "selected_latent_attention",
+           "window_latent_attention"]
 
 _NEG_INF = -1e9     # same masking constant as gpt_cached_apply
 
@@ -581,3 +584,284 @@ def _ragged_attention_pallas(q, k_pool, v_pool, page_table, pos0,
         interpret=_interpret(),
     )(*args)
     return out.reshape(q.shape).astype(q.dtype)
+
+
+# --------------------------------------------------------------------------
+# latent pools (ISSUE 37): one vector a token a layer in place of K and V
+# --------------------------------------------------------------------------
+# A latent-attention model (MLA, arXiv:2405.04434) caches ``(c_kv,
+# k_rope)``, one row of ``C + R`` numbers a token a layer that every head
+# shares; attention runs in the absorbed form (the queries carried into
+# the latent space, the values carried out of it afterwards), so a pool has
+# no head axis. It is ``[L, P, W, ps]``, a page's tokens along the *last*
+# axis, which is how the reads below want it (keys on the lanes of their
+# products). A write of one token's row is then a column of its page, and
+# XLA:TPU re-lays the whole pool for a scatter of columns and back (three
+# copies of the 0.93 GB latent pool a tick, 9 ms of 44 on a v5e, with either
+# order of the two axes; PERF.md section 6, PR 37). So the writes go a page
+# at a time (``latent_scatter``): the pages a tick touches are read, given
+# their new columns and written back whole, which is the pool's own layout.
+# The functions below are the read and write sides of such pools, in
+# ``jax.numpy``: the same walk over a row's own pages as the ragged
+# kernel's, other contents. Every shape is fixed, every trip count is the
+# rows' own.
+
+#: pages of one block of ``index_scores``' walk over a row's indexer keys
+_INDEX_BLOCK_PAGES = 8
+
+
+def latent_scatter(pool, page, off, vals, layer, touched=None):
+    """Each token's row ``vals`` [NT, W] written at its ``(layer, page,
+    off)`` of ``pool`` [L, P, W, ps] (null page 0 for rows that write
+    nothing). ``touched`` [n] names every page a token writes to, in any
+    order, as often as it likes and padded with the null page (a tick knows
+    them: a decode row's page and the few a chunk spans; left out: every
+    token's own). Those pages are read, each takes the columns of *all* the
+    tokens that write to it (so a page named twice is written twice with the
+    same contents) and goes back whole. The stack is written in place and
+    returned."""
+    ps = pool.shape[-1]
+    vals = vals if vals.dtype == pool.dtype else vals.astype(pool.dtype)
+    touched = page if touched is None else touched
+    hit = (page[None, None, :] == touched[:, None, None]) \
+        & (off[None, None, :] == jnp.arange(ps, dtype=off.dtype)[None, :, None])
+    # one token at most writes a column: a sum over one term, exact
+    new = _einsum_f32("tw,qot->qwo", vals, hit.astype(vals.dtype))
+    old = pool[layer, touched]                              # [n, W, ps]
+    return pool.at[layer, touched].set(
+        jnp.where(jnp.any(hit, axis=-1)[:, None, :], new.astype(pool.dtype),
+                  old))
+
+
+def _einsum_f32(spec: str, a, b):
+    """``einsum`` accumulated and returned in float32. The CPU's dot has
+    no 16-bit x 16-bit -> float32 form for every contraction, so there the
+    operands are widened first (the same numbers: a product of two bf16
+    values is exact in float32)."""
+    if a.dtype.itemsize == 2 and _interpret():
+        a, b = a.astype(jnp.float32), b.astype(jnp.float32)
+    return jnp.einsum(spec, a, b, preferred_element_type=jnp.float32)
+
+
+def _block_of_pages(pool, layer, pages):
+    """Pages ``pages`` [R, n] of ``pool`` as ``[R, W, n * ps]``: the rows'
+    tokens side by side along the last axis."""
+    got = pool[layer, pages]                            # [R, n, W, ps]
+    r, n, width, ps = got.shape
+    return jnp.swapaxes(got, 1, 2).reshape(r, width, n * ps)
+
+
+def index_scores(q_i, w_i, k_pool, layer, page_table, pos0, true_len):
+    """The sparse indexer's scores of every query against its row's live
+    keys: ``I(t, s) = sum_j w_j(t) relu(q_j(t) . k(s))`` (DeepSeek-V3.2's
+    lightning indexer).
+
+    q_i         [R, T, J, D]   index queries (T static, J index heads)
+    w_i         [R, T, J]      each query's head weights
+    k_pool      [L, P, D, ps]  the indexer keys' page pool
+    page_table  [R, NPs]       page ids per row
+    pos0, true_len [R]         as ``ragged_paged_attention``
+
+    Returns float32 ``[R, T, NPs * ps]``: ``-inf`` at every position query
+    ``i`` may not see (past ``pos0 + i``, or past the row's last live
+    one). The keys are walked in blocks of ``_INDEX_BLOCK_PAGES`` pages
+    and only as far as the longest row's live positions reach: a page
+    past them is never read."""
+    r, t = q_i.shape[:2]
+    ps = k_pool.shape[-1]
+    nps = page_table.shape[1]
+    bp = min(_INDEX_BLOCK_PAGES, nps)
+    blocks = -(-nps // bp)
+    bt = bp * ps
+    table = jnp.pad(page_table, ((0, 0), (0, blocks * bp - nps)))
+    live = jnp.where(true_len > 0, jnp.minimum(pos0 + true_len, nps * ps), 0)
+    qpos = pos0[:, None] + jnp.arange(t, dtype=pos0.dtype)[None, :]
+    last = jnp.minimum(qpos, live[:, None] - 1)             # [R, T]
+    w_f = w_i.astype(jnp.float32)
+
+    def block(b, out):
+        pages = jax.lax.dynamic_slice(table, (0, b * bp), (r, bp))
+        kpos = b * bt + jnp.arange(bt, dtype=pos0.dtype)
+        k = jnp.where((kpos[None, :] < live[:, None])[:, None, :],
+                      _block_of_pages(k_pool, layer, pages), 0)
+        s = _einsum_f32("rtjd,rds->rtjs", q_i, k.astype(q_i.dtype))
+        s = jnp.sum(jax.nn.relu(s) * w_f[..., None], axis=2)  # [R, T, bt]
+        s = jnp.where(kpos[None, None, :] <= last[:, :, None], s, -jnp.inf)
+        return jax.lax.dynamic_update_slice(out, s, (0, 0, b * bt))
+
+    out = jnp.full((r, t, blocks * bt), -jnp.inf, jnp.float32)
+    n_live = jnp.minimum(-(-jnp.max(live) // bt), blocks)
+    out = jax.lax.fori_loop(0, n_live, block, out)
+    return out[:, :, :nps * ps]
+
+
+def select_topk(scores, k: int):
+    """The ``k`` largest of each row of ``scores`` [N, S] (``-inf``: not
+    visible): ``(idx [N, k] int32, valid [N, k])``, fixed-shape, ``valid``
+    false where fewer than ``k`` are visible. Exact (``lax.top_k``, which
+    the TPU compiles to a sort of the whole row: 9.8 ms for ``[268,
+    33792]`` on a v5e, PERF.md section 6, PR 37), so no tick calls it:
+    every row's selection is ``select_threshold``'s, and this is what the
+    tests and ``chip_smoke.py`` hold that to."""
+    val, idx = jax.lax.top_k(scores, min(k, scores.shape[-1]))
+    return idx.astype(jnp.int32), val > -jnp.inf
+
+
+def _ordered_bits(scores):
+    """float32 ``scores`` as uint32 that compare as the floats do
+    (``-inf`` lowest)."""
+    bits = jax.lax.bitcast_convert_type(scores, jnp.int32)
+    key = jnp.where(bits < 0, bits ^ jnp.int32(0x7FFFFFFF), bits)
+    return jax.lax.bitcast_convert_type(key, jnp.uint32) \
+        ^ jnp.uint32(0x80000000)
+
+
+def select_threshold(scores, k: int):
+    """Each row's selection as a threshold: ``(keys, thr, ties)`` with
+    ``keys`` uint32 ``[N, S]`` (the scores, order kept), ``thr`` uint32
+    ``[N]`` the row's ``k``-th largest (0 where fewer than ``k`` are
+    visible) and ``ties`` int32 ``[N]``: the row's selection is every
+    position with ``keys > thr`` and the first ``ties`` positions with
+    ``keys == thr``, which is ``lax.top_k``'s set, its ties broken towards
+    the lower position too. No sort and no gather: the ``k``-th largest is
+    found a bit at a time, 32 passes of a comparison and a count over the
+    scores."""
+    keys = _ordered_bits(scores)
+
+    def bit(i, thr):
+        cand = thr | (jnp.uint32(1) << (jnp.uint32(31) - i.astype(
+            jnp.uint32)))
+        enough = jnp.sum(keys >= cand[:, None], axis=1) >= k
+        return jnp.where(enough, cand, thr)
+
+    thr = jax.lax.fori_loop(0, 32, bit,
+                            jnp.zeros((scores.shape[0],), jnp.uint32))
+    above = jnp.sum(keys > thr[:, None], axis=1).astype(jnp.int32)
+    return keys, thr, k - above
+
+
+def selection_mask(keys, thr, ties):
+    """``select_threshold``'s selection as a mask ``[N, S]`` (over whole
+    rows: the walk of ``selected_latent_attention`` does the same a block
+    at a time)."""
+    tie = keys == thr[:, None]
+    first = jnp.cumsum(tie, axis=1) <= ties[:, None]
+    return (keys > thr[:, None]) | (tie & first)
+
+
+#: pages of one block of ``selected_latent_attention``'s walk
+_ATTN_BLOCK_PAGES = 16
+
+
+def selected_latent_attention(q, pool, layer, page_table, pos0, true_len,
+                              keys, thr, ties, c_width: int, scale: float):
+    """Absorbed (multi-query) attention of ragged rows over a latent pool,
+    each query over its own *selection* of its row's live positions.
+
+    q           [R, T, NH, W]  queries in the latent space: ``q_nope
+                               W_kvb^K`` (``c_width`` wide) beside the
+                               rotated ``q_rope``
+    pool        [L, P, W, ps]  latents ``(c_kv, k_rope)``
+    page_table  [R, NPs]       page ids per row
+    keys        [R, T, S]      ``select_threshold``'s, with ``thr`` and
+    thr, ties   [R, T]         ``ties``: which positions query ``i`` of row
+                               ``r`` selected (of those ``<= pos0[r] + i``
+                               within the row's live positions)
+
+    The row's live pages are walked once, in blocks of
+    ``_ATTN_BLOCK_PAGES`` pages, every head of every query of the row
+    scoring a block's latents in one product under the selection's mask
+    and a float32 online softmax: the selected latents are never gathered
+    a query at a time (549 k rows of 1,152 B took 9.8 ms a layer on a v5e,
+    and their page ids 5.6 more), at the price of scoring what is not
+    selected. Returns ``[R, T, NH, c_width]`` (the values are carried out
+    of the latent space by the caller); a query with nothing to attend
+    gets zeros."""
+    r, t, nh = q.shape[:3]
+    ps = pool.shape[-1]
+    nps = page_table.shape[1]
+    bp = min(_ATTN_BLOCK_PAGES, nps)
+    blocks = -(-nps // bp)
+    bt = bp * ps
+    table = jnp.pad(page_table, ((0, 0), (0, blocks * bp - nps)))
+    keys = jnp.pad(keys, ((0, 0), (0, 0), (0, blocks * bt - keys.shape[2])))
+    live = jnp.where(true_len > 0, jnp.minimum(pos0 + true_len, nps * ps), 0)
+    qpos = pos0[:, None] + jnp.arange(t, dtype=pos0.dtype)[None, :]
+    last = jnp.minimum(qpos, live[:, None] - 1)             # [R, T]
+
+    def block(b, carry):
+        m, l, acc, left = carry
+        pages = jax.lax.dynamic_slice(table, (0, b * bp), (r, bp))
+        kpos = b * bt + jnp.arange(bt, dtype=pos0.dtype)
+        # what lies past the row's live positions is whatever was there:
+        # zeros, so that a weight of 0 cannot meet a NaN
+        lat = jnp.where((kpos[None, :] < live[:, None])[:, None, :],
+                        _block_of_pages(pool, layer, pages), 0)
+        lat = lat if lat.dtype == q.dtype else lat.astype(q.dtype)
+        s = _einsum_f32("rtnc,rcs->rtns", q, lat) * scale
+        mine = jax.lax.dynamic_slice(keys, (0, 0, b * bt), (r, t, bt))
+        seen = kpos[None, None, :] <= last[:, :, None]
+        tie = seen & (mine == thr[:, :, None])
+        taken = tie & (jnp.cumsum(tie, axis=-1) <= left[:, :, None])
+        keep = ((seen & (mine > thr[:, :, None])) | taken)[:, :, None, :]
+        s = jnp.where(keep, s, _NEG_INF)
+        m_new = jnp.maximum(m, jnp.max(s, axis=-1))
+        p = jnp.where(keep, jnp.exp(s - m_new[..., None]), 0.0)
+        corr = jnp.exp(m - m_new)
+        l = corr * l + jnp.sum(p, axis=-1)
+        acc = corr[..., None] * acc + _einsum_f32(
+            "rtns,rcs->rtnc", p.astype(q.dtype), lat[:, :c_width])
+        return m_new, l, acc, left - jnp.sum(tie, axis=-1).astype(left.dtype)
+
+    n_live = jnp.minimum(-(-jnp.max(live) // bt), blocks)
+    _, l, acc, _ = jax.lax.fori_loop(0, n_live, block, (
+        jnp.full((r, t, nh), _NEG_INF, jnp.float32),
+        jnp.zeros((r, t, nh), jnp.float32),
+        jnp.zeros((r, t, nh, c_width), jnp.float32),
+        ties.astype(jnp.int32)))
+    return (acc / jnp.where(l == 0.0, 1.0, l)[..., None]).astype(q.dtype)
+
+
+def window_latent_attention(q, pool, layer, page_table, pos0, true_len,
+                            window: int, c_width: int, scale: float):
+    """Absorbed attention of ragged rows over the last ``window`` positions
+    of a latent pool: query ``i`` of row ``r`` sees ``pos0[r] + i - window
+    < s <= pos0[r] + i``.
+
+    q           [R, T, NH, W]  per-row query blocks (T static)
+    pool        [L, P, W, ps]  the windowed layers' latents
+    page_table  [R, NPs]       page ids per row; entries behind the window
+                               may be null (their pages were given back)
+
+    Only the pages that can hold a visible position are fetched: ``ceil((
+    window - 1 + T) / ps) + 1`` from the page of the first query's oldest
+    visible position on. Returns ``([R, T, NH, c_width], lse [R, T])``:
+    ``lse`` float32, the log of the sum of a query's exponentiated scores,
+    mean over its heads (it grows with the log of the keys a query sees,
+    which tells a window from a longer one)."""
+    r, t = q.shape[:2]
+    ps = pool.shape[-1]
+    nps = page_table.shape[1]
+    wp = min(nps, -(-(window - 1 + t) // ps) + 1)
+    first = jnp.maximum(pos0 - (window - 1), 0) // ps       # [R]
+    cols = first[:, None] + jnp.arange(wp, dtype=pos0.dtype)[None, :]
+    pages = jnp.where(
+        cols < nps,
+        jnp.take_along_axis(page_table, jnp.minimum(cols, nps - 1), axis=1),
+        0)
+    kpos = first[:, None] * ps + jnp.arange(wp * ps, dtype=pos0.dtype)
+    qpos = pos0[:, None] + jnp.arange(t, dtype=pos0.dtype)[None, :]
+    live = jnp.where(true_len > 0, pos0 + true_len, 0)
+    # pages behind the window may be gone, positions past the live ones
+    # hold whatever was there: zeros, so that a weight of 0 meets no NaN
+    held = (kpos < live[:, None]) & (kpos > pos0[:, None] - window)
+    lat = jnp.where(held[:, None, :], _block_of_pages(pool, layer, pages), 0)
+    lat = lat if lat.dtype == q.dtype else lat.astype(q.dtype)
+    k3, q3 = kpos[:, None, :], qpos[:, :, None]
+    keep = (k3 <= q3) & (k3 > q3 - window) & (k3 < live[:, None, None])
+    s = _einsum_f32("rtnc,rcs->rnts", q, lat) * scale
+    s = jnp.where(keep[:, None], s, _NEG_INF)
+    lse = jax.nn.logsumexp(s, axis=-1)                      # [R, NH, T]
+    p = jnp.exp(s - lse[..., None]).astype(q.dtype)
+    return (jnp.einsum("rnts,rcs->rtnc", p, lat[:, :c_width]),
+            jnp.mean(lse, axis=1))

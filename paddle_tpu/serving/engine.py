@@ -148,6 +148,7 @@ profiler's program inventory, keyed by ``compiled_sites``.
 """
 from __future__ import annotations
 
+import functools
 import time
 import warnings
 from collections import deque
@@ -165,7 +166,7 @@ from ..profiler import events as _events
 from ..profiler import recompile as _recompile
 from ..profiler import registry as _registry
 from ..profiler import trace as _ptrace
-from .paged_cache import PagePool, Pools
+from .paged_cache import LatentPagePool, PagePool, Pools
 from .sched import SCHED_POLICIES, ChunkScheduler, SpecKController
 from .spec import SpecConfig
 
@@ -336,15 +337,22 @@ class Request:
 
 
 class _Inflight:
-    __slots__ = ("tok", "meta", "tick", "dispatch_t", "exit")
+    __slots__ = ("tok", "meta", "tick", "dispatch_t", "exit", "aux",
+                 "positions")
 
-    def __init__(self, tok, meta, tick, exit_steps=None):
+    def __init__(self, tok, meta, tick, exit_steps=None, aux=None,
+                 positions=None):
         self.tok = tok               # device int32 array
         self.meta = meta             # [(index_into_tok, slot, rid)]
         self.tick = tick             # the engine's tick that computes it
         #: a looped model's exit statistics of the sampled rows, device
         #: float32 [2, num_slots]: expected and chosen exit step
         self.exit = exit_steps
+        #: what a tick reports of itself beside its tokens, for the
+        #: model's own record (``cache_spec()["tick_record"]``), and the
+        #: cache position each sampled row's query stood at
+        self.aux = aux
+        self.positions = positions
         self.dispatch_t = time.perf_counter()
 
 
@@ -426,16 +434,42 @@ class ServingEngine:
             raise ValueError(
                 f"unknown kv_dtype {cfg.kv_dtype!r}; expected one of "
                 "None (model dtype), 'f32', 'bf16', 'int8'")
-        nh = mcfg.num_heads
-        hd = mcfg.hidden_size // nh
         ps = cfg.page_size
         pages_per_slot = cfg.pages_per_slot or -(-mcfg.max_seq_len // ps)
         num_pages = cfg.num_pages or cfg.num_slots * pages_per_slot + 1
+        self.prefill_chunk = int(cfg.prefill_chunk) or 2 * ps
+        if self.prefill_chunk < 1:
+            raise ValueError("prefill_chunk must be >= 1")
+        #: what caches the model keeps, from the model: K and V a layer
+        #: (and loop step) of ``heads x head_dim``, or a latent-attention
+        #: model's latent, indexer-key and windowed pools
+        #: (``models/dots3.py``), which bring the tick's forward with them
+        spec_of = getattr(model, "cache_spec", None)
+        self._caches = spec_of() if spec_of is not None else {
+            "kind": "kv", "layers": mcfg.num_layers * self._loop_steps,
+            "heads": mcfg.num_heads,
+            "head_dim": mcfg.hidden_size // mcfg.num_heads}
+        self._latent = self._caches["kind"] == "latent"
+        self._apply = getattr(model, "ragged_apply", None)
+        #: the model's record of what its ticks report of themselves
+        #: (``models/dots3.TickRecord``); None: its ticks report nothing
+        record = self._caches.get("tick_record")
+        self.tick_record = record() if record is not None else None
         with _ptrace.phase("setup/engine/pools"):
-            self.pool = PagePool(
-                mcfg.num_layers * self._loop_steps, num_pages, ps, nh, hd,
-                cfg.num_slots, pages_per_slot, dtype=kv_map[cfg.kv_dtype],
-                prefix_cache=cfg.prefix_cache)
+            if self._latent:
+                self._refuse_over_latent_pools(cfg)
+                self.pool = LatentPagePool(
+                    self._caches, num_pages, ps, cfg.num_slots,
+                    pages_per_slot, self.prefill_chunk,
+                    dtype=kv_map[cfg.kv_dtype],
+                    prefix_cache=cfg.prefix_cache)
+            else:
+                self.pool = PagePool(
+                    self._caches["layers"], num_pages, ps,
+                    self._caches["heads"], self._caches["head_dim"],
+                    cfg.num_slots, pages_per_slot,
+                    dtype=kv_map[cfg.kv_dtype],
+                    prefix_cache=cfg.prefix_cache)
         # set once: how deep the pools are, and what the engine holds on
         # the device for the model (one copy of the weights, as served)
         _registry().gauge("serving/cache_layers").set(
@@ -443,9 +477,6 @@ class ServingEngine:
         _registry().gauge("serving/weights_bytes").set(float(sum(
             a.nbytes for a in jax.tree_util.tree_leaves(
                 (self._stacked, self._other)))))
-        self.prefill_chunk = int(cfg.prefill_chunk) or 2 * ps
-        if self.prefill_chunk < 1:
-            raise ValueError("prefill_chunk must be >= 1")
         b_slots = cfg.num_slots
         # chunk-selection + budget policy (ISSUE 15; serving/sched.py)
         # — host-side only: picks which slot opens the next prefill
@@ -586,6 +617,28 @@ class ServingEngine:
             b_slots * (1 + spec_extra)
             + cfg.prefill_chunks_per_tick
             * (self.prefill_chunk // ps + 2) + 8)
+
+    def _refuse_over_latent_pools(self, cfg: ServingConfig) -> None:
+        """What the engine cannot do for a model whose caches are not K
+        and V, each by what it lacks."""
+        if cfg.spec is not None:
+            raise NotImplementedError(
+                "speculative decoding over latent and windowed pools: the "
+                "verify tick (serving/spec.py make_spec_tick) and the draft "
+                "runner carry Pools of K and V, and a rejected draft would "
+                "have to rewind pages a window has already given back")
+        if cfg.kv_dtype == "int8":
+            raise NotImplementedError(
+                "int8 latent pools: the per-page per-head scales are K's "
+                "and V's; a latent row has no head axis")
+
+    def _require_kv_pools(self, what: str) -> None:
+        if self._latent:
+            raise NotImplementedError(
+                f"{what} over latent and windowed pools: a handoff moves "
+                "Pools of K and V by page (serving/disagg.py), and a "
+                "windowed layer's pages behind the window no longer exist "
+                "to be moved")
 
     @property
     def attention_kernel(self) -> str:
@@ -764,6 +817,8 @@ class ServingEngine:
         never ride decode ticks, so a prefill-group engine's tick only
         ever carries chunk rows."""
         began = time.perf_counter()
+        if hold_after_prefill:
+            self._require_kv_pools("hold_after_prefill (a prefill group)")
         p = np.asarray(prompt_ids, np.int32).reshape(-1)
         t0 = p.shape[0]
         cap = self.pool.slot_capacity
@@ -895,6 +950,8 @@ class ServingEngine:
         """Forget finished requests (long-running host keeps memory flat)."""
         self._requests = {rid: r for rid, r in self._requests.items()
                           if not r.done}
+        if self.tick_record is not None:
+            self.tick_record.forget(self._requests)
 
     def cancel(self, rid: int, reason: str = "redispatch") -> bool:
         """Abandon a request wherever it stands — queued, resident
@@ -957,6 +1014,7 @@ class ServingEngine:
         ``ceil(t0 / page_size)`` pages holding the prompt's KV. The
         slot stays resident until ``release_exported`` — export is
         read-only, so a failed send can simply retry."""
+        self._require_kv_pools("export_held (a KV handoff)")
         if rid not in self._held_ready:
             raise ValueError(f"request {rid} is not held-ready")
         t_span = time.perf_counter()
@@ -1033,6 +1091,7 @@ class ServingEngine:
         local rid, or None when no slot/pages are free right now (the
         caller retries; imports never preempt residents — a transfer
         must not evict committed decode work)."""
+        self._require_kv_pools("admit_prefilled (a KV handoff)")
         t_span = time.perf_counter()
         p = np.asarray(payload["prompt"], np.int32).reshape(-1)
         t0 = p.shape[0]
@@ -1155,6 +1214,7 @@ class ServingEngine:
         when nothing is cached — the chain may have been evicted since
         it was published, and a missed migration is a perf event, not
         an error."""
+        self._require_kv_pools("export_prefix_chain (prefix migration)")
         pool = self.pool
         if pool.prefix is None:
             return None
@@ -1190,6 +1250,7 @@ class ServingEngine:
         indexed (0 = pool full right now, or nothing new — both
         perf-only). Raises ValueError on a payload this pool must not
         store (dtype/shape mismatch)."""
+        self._require_kv_pools("import_prefix_chain (prefix migration)")
         pool = self.pool
         if pool.prefix is None:
             return 0
@@ -1244,6 +1305,10 @@ class ServingEngine:
                                waited=int(waited)):
                 toks = np.asarray(ent.tok)
                 exits = None if ent.exit is None else np.asarray(ent.exit)
+                note = None
+                if ent.aux is not None:
+                    note = self.tick_record.tick(
+                        ent.aux, ent.positions, [m[2] for m in ent.meta])
                 now = time.perf_counter()
                 for idx, slot, rid in ent.meta:
                     req = self._requests[rid]
@@ -1251,6 +1316,8 @@ class ServingEngine:
                         continue    # EOS discovered while in flight
                     tok = int(toks[idx])
                     req.out.append(tok)
+                    if note is not None:
+                        note(rid, idx)
                     if exits is not None:
                         req.exit_expected += float(exits[0, idx])
                         req.exit_chosen += float(exits[1, idx])
@@ -1699,7 +1766,7 @@ class ServingEngine:
         with _ptrace.scope("step/build", tick=self._tick_no):
             args, finishers = self._build_unified(chunks, ticking)
         with _ptrace.scope("step/dispatch", tick=self._tick_no):
-            self.pool.pools, tok, self._last_tok, *exit_steps = \
+            self.pool.pools, tok, self._last_tok, *extra = \
                 self._run_tick(args)
         meta = [(s, s, self._slot_rid[s]) for s in ticking]
         meta += [(s, s, rid) for s, rid in finishers]
@@ -1707,8 +1774,19 @@ class ServingEngine:
             # chunk-only ticks (no decodes, no finishers) emit nothing
             # worth syncing — queueing them would stall the host on a
             # token vector nobody reads once the window fills
-            self._inflight.append(_Inflight(tok, meta, self._tick_no,
-                                            *exit_steps))
+            if self.tick_record is not None:
+                # where each emitting row's query stood: a decode row at
+                # its slot's length, a finished prompt's at its last token
+                positions = self._slot_len.copy()
+                for s, _, _, end, t0 in chunks:
+                    if end >= t0:
+                        positions[s] = t0 - 1
+                self._inflight.append(_Inflight(
+                    tok, meta, self._tick_no, aux=extra[0],
+                    positions=positions))
+            else:
+                self._inflight.append(_Inflight(tok, meta, self._tick_no,
+                                                *extra))
         self._tick_no += 1
         self.max_inflight_seen = max(self.max_inflight_seen,
                                      len(self._inflight))
@@ -1727,6 +1805,14 @@ class ServingEngine:
             self._insert_prefix(s, self._requests[rid].prompt, end)
         reg = _registry()
         reg.counter("serving/ticks").add(1)
+        if self._latent:
+            # pages of the windowed layers that no later query can see go
+            # back now: every tick that reads them is already dispatched
+            freed = sum(self.pool.free_behind(s, int(self._slot_len[s]))
+                        for s in set(ticking) | {c[0] for c in chunks})
+            reg.counter("serving/window_pages_freed").add(freed)
+        for kind, share in self.pool.live_shares().items():
+            reg.gauge("serving/live_pages{pool=%s}" % kind).set(share)
         if self._loop_steps > 1:
             reg.counter("loop/steps_run").add(self._loop_steps)
         if chunks:
@@ -1754,8 +1840,9 @@ class ServingEngine:
         tok_limit[:ns] = cap
         # ragged row metadata: ns decode rows, then npf chunk rows (pad
         # chunk rows keep an all-null table)
-        row_tab = np.zeros((ns + npf, nps), np.int32)
-        row_tab[:ns] = self.pool.tables
+        rows = list(range(ns)) + [c[0] for c in chunks] \
+            + [None] * (npf - len(chunks))
+        row_tab = self.pool.row_tables(rows)
         row_pos0 = np.zeros(ns + npf, np.int32)
         row_pos0[:ns] = self._slot_len
         row_len = np.ones(ns + npf, np.int32)
@@ -1774,7 +1861,6 @@ class ServingEngine:
             pf_toks[c * w:c * w + (end - start)] = req.prompt[start:end]
             tok_pos[base:base + w] = start + np.arange(w)
             tok_limit[base:base + w] = t0
-            row_tab[ns + c] = self.pool.tables[s]
             row_pos0[ns + c] = start
             row_len[ns + c] = end - start
             # the slot's decode row must sit at the post-chunk frontier
@@ -1823,12 +1909,16 @@ class ServingEngine:
 
         from ..models.gpt import gpt_ragged_apply
 
+        # a model that keeps other caches than K and V brings its forward
+        # (same arguments, ``row_tab`` its pools' tables); GPT's is as ever
+        apply = self._apply or functools.partial(gpt_ragged_apply, mcfg)
+
         def tick(stacked, other, pools, fresh, last_tok, pf_toks,
                  tok_pos, tok_limit, row_tab, row_pos0, row_len,
                  sample_ix, sample_pos, emit, has_chunks, keys, temps,
                  top_ks, top_ps):
-            _recompile.mark_trace(site, pools.k, row_tab, tok_pos,
-                                  last_tok)
+            _recompile.mark_trace(site, jax.tree_util.tree_leaves(pools)[0],
+                                  row_tab, tok_pos, last_tok)
             # recycled pages restart their running-max scale at 0
             # (fresh pads with the null page, whose scale is 0)
             pools = pools.reset_scales(fresh)
@@ -1844,8 +1934,8 @@ class ServingEngine:
             # which reads the pools and returns ``[nch, w, NH, D]``.
             # a looped model's forward also hands out its exit statistics,
             # which leave the tick as one more output (no callback)
-            logits, pools, *exit_steps = gpt_ragged_apply(
-                mcfg, stacked, other, pools, tokens, tok_pos, tok_limit,
+            logits, pools, *exit_steps = apply(
+                stacked, other, pools, tokens, tok_pos, tok_limit,
                 row_tab, row_pos0, row_len, sample_ix, decode_rows=ns,
                 chunk_width=w, impl=impl, has_chunks=has_chunks)
             with _ptrace.annotate("tick/sample"):
@@ -2063,8 +2153,9 @@ class ServingEngine:
             .astype(np.int32).reshape(-1)
         tok_limit[ns:base] = np.where(dj < k_arr[:, None], cap, 0) \
             .astype(np.int32).reshape(-1)
-        row_tab = np.zeros((ns + npf, nps), np.int32)
-        row_tab[:ns] = self.pool.tables
+        rows = list(range(ns)) + [c[0] for c in chunks] \
+            + [None] * (npf - len(chunks))
+        row_tab = self.pool.row_tables(rows)
         row_pos0 = np.zeros(ns + npf, np.int32)
         row_pos0[:ns] = self._slot_len
         row_len = np.ones(ns + npf, np.int32)
@@ -2084,7 +2175,6 @@ class ServingEngine:
             pf_toks[c * w:c * w + (end - start)] = req.prompt[start:end]
             tok_pos[coff:coff + w] = start + np.arange(w)
             tok_limit[coff:coff + w] = t0
-            row_tab[ns + c] = self.pool.tables[s]
             row_pos0[ns + c] = start
             row_len[ns + c] = end - start
             tok_pos[s] = end

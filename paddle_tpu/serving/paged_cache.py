@@ -546,13 +546,21 @@ class Pools(NamedTuple):
             k_scale=self.k_scale, v_scale=self.v_scale, layer=layer)
 
 
+def _rows_of(tables: np.ndarray, rows) -> np.ndarray:
+    out = np.zeros((len(rows), tables.shape[1]), np.int32)
+    for i, slot in enumerate(rows):
+        if slot is not None:
+            out[i] = tables[slot]
+    return out
+
+
 class PagePool:
     """Device page pools for all layers + host page tables for all slots."""
 
     def __init__(self, num_layers: int, num_pages: int, page_size: int,
                  num_heads: int, head_dim: int, num_slots: int,
                  pages_per_slot: int, dtype=jnp.float32,
-                 prefix_cache: bool = False):
+                 prefix_cache: bool = False, pools=None):
         self.num_layers = num_layers
         self.num_pages = num_pages
         self.page_size = page_size
@@ -562,8 +570,8 @@ class PagePool:
         self.pages_per_slot = pages_per_slot
         #: the device state, donated to and stored back from every
         #: dispatch that writes it (tick, COW copy, import)
-        self.pools = Pools.zeros(num_layers, num_pages, page_size,
-                                 num_heads, head_dim, dtype)
+        self.pools = pools if pools is not None else Pools.zeros(
+            num_layers, num_pages, page_size, num_heads, head_dim, dtype)
         self.allocator = PageAllocator(num_pages)
         # pages allocated or zero-freed since the last tick, whose
         # scales that tick resets (``take_fresh``); scales only
@@ -592,6 +600,16 @@ class PagePool:
     k_scale = property(lambda self: self.pools.k_scale)
     v_scale = property(lambda self: self.pools.v_scale)
     quantized = property(lambda self: self.pools.quantized)
+
+    def live_shares(self) -> Dict[str, float]:
+        """Allocated share of each kind of pool's pages (the engine's
+        ``serving/live_pages{pool=}`` gauges)."""
+        return {"kv": self.allocator.utilization()}
+
+    def row_tables(self, rows) -> np.ndarray:
+        """The page-table rows of a tick: one per entry of ``rows``, a
+        slot or ``None`` (an all-null row)."""
+        return _rows_of(self.tables, rows)
 
     def register_aux(self, aux: "AuxPageTable") -> None:
         """Register an auxiliary table whose pages come from this
@@ -897,3 +915,187 @@ class AuxPageTable:
         self._held[slot] = []
         self.tables[slot, :] = NULL_PAGE
         return n
+
+
+class LatentPools(NamedTuple):
+    """The pools of a latent-attention model with a sparse indexer in its
+    full layers and windowed layers between them (ISSUE 37;
+    ``models/dots3.py``), as the device holds them. None is K or V:
+
+    latent   ``[Lf, P, C + R, ps]``   a full layer's ``(c_kv, k_rope)``
+    index_k  ``[Lf, P, D, ps]``       its indexer's keys
+    window   ``[Lw, Pw, Cw + R, ps]`` a windowed layer's latents, in a
+                                      page space of its own that holds only
+                                      the window (``LatentPagePool``)
+
+    A page's tokens lie along the last axis (``ops/paged_attention`` says
+    why).
+
+    A pytree like ``Pools``: the tick takes and returns it as one donated
+    argument and its layer scans carry it. The writes and reads are
+    ``ops/paged_attention``'s ``latent_scatter``, ``index_scores``,
+    ``selected_latent_attention`` and ``window_latent_attention``."""
+
+    latent: jax.Array
+    index_k: jax.Array
+    window: jax.Array
+
+    quantized = False
+
+    @classmethod
+    def zeros(cls, full_layers: int, num_pages: int, window_layers: int,
+              window_pages: int, page_size: int, latent_width: int,
+              index_width: int, window_width: int, dtype) -> "LatentPools":
+        return cls(
+            jnp.zeros((full_layers, num_pages, latent_width, page_size),
+                      dtype),
+            jnp.zeros((full_layers, num_pages, index_width, page_size),
+                      dtype),
+            jnp.zeros((window_layers, window_pages, window_width,
+                       page_size), dtype))
+
+    @property
+    def page_size(self) -> int:
+        return self.latent.shape[-1]
+
+    def arrays(self) -> Dict[str, jax.Array]:
+        return self._asdict()
+
+    def reset_scales(self, pages) -> "LatentPools":
+        return self
+
+
+class LatentPagePool(PagePool):
+    """``PagePool`` for ``LatentPools``: the full layers' pages are the
+    pool's own (``allocator``, ``tables``: a page a ``page_size`` tokens of
+    a slot, for as long as the slot lives), and the windowed layers' pages
+    come from a second page space (``window_allocator``,
+    ``window_tables``, indexed by the same logical page of the slot) that
+    holds **only the window**: ``free_behind(slot, frontier)`` gives back
+    every page that no query at or past ``frontier`` can see, and the
+    engine calls it as it dispatches. The window space is sized for every
+    slot's worst case (``ceil((window - 1 + chunk) / page_size) + 2`` pages
+    a slot), so it never binds: admission, exhaustion and preemption are
+    decided by the full layers' pages alone.
+
+    What it lacks is refused by name: a prefix cache (a cached page would
+    have to say which layers it serves: a windowed layer's page is gone
+    once the window has passed), speculative rewinds (``shrink_slot``) and
+    auxiliary tables."""
+
+    #: False keeps every windowed page for the slot's life (the window
+    #: space is then as large as the full layers'): what the tests compare
+    #: a freeing run with
+    FREE_BEHIND = True
+
+    def __init__(self, caches: dict, num_pages: int, page_size: int,
+                 num_slots: int, pages_per_slot: int, chunk: int,
+                 dtype=jnp.float32, prefix_cache: bool = False):
+        if prefix_cache:
+            raise NotImplementedError(
+                "prefix_cache=True with windowed layers: PrefixCache shares "
+                "a page into every layer's pool, and a windowed layer's "
+                "page is given back once the window has passed it; pass "
+                "prefix_cache=False (ROADMAP R4)")
+        self.window = int(caches["window"])
+        held = -(-(self.window - 1 + chunk) // page_size) + 2
+        self.window_pages_per_slot = min(pages_per_slot, held) \
+            if self.FREE_BEHIND else pages_per_slot
+        window_pages = num_slots * self.window_pages_per_slot + 1
+        pools = LatentPools.zeros(
+            caches["full_layers"], num_pages, caches["window_layers"],
+            window_pages, page_size, caches["latent_width"],
+            caches["index_width"], caches["window_width"], dtype)
+        super().__init__(caches["full_layers"] + caches["window_layers"],
+                         num_pages, page_size, 1, caches["latent_width"],
+                         num_slots, pages_per_slot, dtype=dtype, pools=pools)
+        self.window_allocator = PageAllocator(window_pages)
+        self.window_tables = np.zeros((num_slots, pages_per_slot), np.int32)
+        #: logical page -> page id of the window space, per slot
+        self._window_held: List[Dict[int, int]] = [
+            {} for _ in range(num_slots)]
+
+    def live_shares(self) -> Dict[str, float]:
+        return {"latent": self.allocator.utilization(),
+                "window": self.window_allocator.utilization()}
+
+    def row_tables(self, rows):
+        return (_rows_of(self.tables, rows),
+                _rows_of(self.window_tables, rows))
+
+    def slot_window_pages(self, slot: int) -> int:
+        return len(self._window_held[slot])
+
+    def grow_slot(self, slot: int, n_pages: int) -> bool:
+        """Both page spaces or neither: ``n_pages`` more logical pages of
+        the slot, each with a page of the full layers and one of the
+        windowed layers."""
+        if n_pages <= 0:
+            return True
+        first = len(self._held[slot])
+        if self.window_allocator.num_free < n_pages \
+                or not super().grow_slot(slot, n_pages):
+            return False
+        got = self.window_allocator.alloc(n_pages)
+        self.window_tables[slot, first:first + n_pages] = got
+        self._window_held[slot].update(zip(range(first, first + n_pages),
+                                           got))
+        return True
+
+    def free_behind(self, slot: int, frontier: int) -> int:
+        """Give back the slot's windowed pages that lie wholly behind the
+        window of every query at or past position ``frontier`` (such a
+        query sees positions ``> frontier - window``). Returns how many."""
+        if not self.FREE_BEHIND:
+            return 0
+        oldest = max(frontier - self.window + 1, 0) // self.page_size
+        held = self._window_held[slot]
+        gone = [i for i in held if i < oldest]
+        if gone:
+            self.window_allocator.free([held.pop(i) for i in gone])
+            self.window_tables[slot, gone] = NULL_PAGE
+        return len(gone)
+
+    def release_slot(self, slot: int) -> int:
+        held = self._window_held[slot]
+        if held:
+            self.window_allocator.free(list(held.values()))
+        self._window_held[slot] = {}
+        self.window_tables[slot, :] = NULL_PAGE
+        return super().release_slot(slot)
+
+    def share_into_slot(self, slot: int, pages) -> None:
+        raise NotImplementedError(
+            "sharing pages into a slot of latent and windowed pools")
+
+    def shrink_slot(self, slot: int, keep_pages: int) -> int:
+        raise NotImplementedError(
+            "rewinding a slot of latent and windowed pools")
+
+    def register_aux(self, aux) -> None:
+        raise NotImplementedError(
+            "an auxiliary page table over latent and windowed pools")
+
+    def check_consistency(self) -> List[str]:
+        out = super().check_consistency()
+        alloc = self.window_allocator
+        seen: Dict[int, int] = {}
+        for slot, held in enumerate(self._window_held):
+            row = self.window_tables[slot]
+            for i, pg in held.items():
+                seen[pg] = seen.get(pg, 0) + 1
+                if int(row[i]) != pg:
+                    out.append(f"slot {slot} window table[{i}]="
+                               f"{int(row[i])} != held page {pg}")
+            for i in np.flatnonzero(row):
+                if int(i) not in held:
+                    out.append(f"slot {slot} window table[{int(i)}]="
+                               f"{int(row[i])} is not held")
+        for pg, n in seen.items():
+            if n != 1 or alloc.refcount(pg) != 1:
+                out.append(f"window page {pg} held {n} times, refcount "
+                           f"{alloc.refcount(pg)}")
+        if alloc.num_allocated != len(seen):
+            out.append(f"window pages allocated {alloc.num_allocated} != "
+                       f"held {len(seen)}")
+        return out

@@ -177,6 +177,24 @@ def test_serve_looped_rehearsal():
     assert out["exit_gap"] <= chip_smoke.TOL_LOOP_EXIT
 
 
+def test_latent_rehearsal():
+    """The latent-attention pass at toy sizes: the pools' reads against
+    float32 spellings, a small model's tokens and selections against the
+    float32 reference, the windowed pages given back."""
+    import jax.numpy as jnp
+
+    from paddle_tpu.models.dots3 import Dots3Config
+
+    out = chip_smoke.phase_latent(
+        Dots3Config.tiny(experts_held=(0, 4)), 3, 4, 24,
+        (2, 5, 3, 12, 8, 4, 10, 6, 5, jnp.bfloat16))
+    assert out["median"] <= out["worst"] <= chip_smoke.TOL_LATENT_SHORTFALL
+    assert out["differ"] <= chip_smoke.TOL_LATENT_SELECTED
+    assert out["freed"] > 0
+    assert max(out["index"], out["selected"], out["window"]) \
+        <= chip_smoke.TOL_LATENT_OPS
+
+
 def test_a_submit_behind_the_ticks_in_flight_fails_the_serve_phase():
     limit = chip_smoke.SUBMIT_LIMIT_MS
     # with nothing in flight a slow submit proves nothing

@@ -389,7 +389,7 @@ def test_what_a_looped_model_is_refused():
             spec=SpecConfig(draft_model=_net(1), k=2)))
     with pytest.raises(NotImplementedError, match="QK-norm"):
         _net(1, qk_norm=True)._decode_state()
-    with pytest.raises(NotImplementedError, match="experts inside the tick"):
+    with pytest.raises(NotImplementedError, match="an expert layer under blk/ffn"):
         GPT(dataclasses.replace(GPTConfig.olmoe_1b_7b(), num_layers=1,
                                 hidden_size=64, num_heads=4, vocab_size=128,
                                 ffn_hidden_size=64, moe_num_experts=4,

@@ -493,3 +493,59 @@ def test_tpu_compile_olmoe_step_of_the_cell(monkeypatch):
     assert len(re.findall(r"%moe_gmm[\w.\-]* = ", text)) == 9
     assert len(re.findall(r"%moe_tgmm[\w.\-]* = ", text)) == 3
     assert not re.findall(r"%ragged-dot-none[\w.\-]* = ", text)
+
+
+def test_tpu_compile_the_latent_models_forward(monkeypatch):
+    """ISSUE 37: the tick's forward of a latent-attention model
+    (``models/dots3.dots3_ragged_apply``: latent, indexer-key and windowed
+    pools, the threshold selection, the walks over a row's live pages, the
+    held experts' grouped matmuls) compiles for the v5e from a ``LazyGuard``
+    model, with the Pallas grouped matmul inside and the donated pools
+    aliased. Published head counts and latent widths over a small hidden
+    size and two experts of the held share."""
+    import jax.numpy as jnp
+
+    import paddle_tpu as paddle
+    from paddle_tpu.models.dots3 import (FULL, SLIDING, Dots3, Dots3Config,
+                                         dots3_ragged_apply, state_drawer)
+    from paddle_tpu.serving.paged_cache import LatentPools
+
+    dev = _tpu_topology_devices()[0]
+    cfg = Dots3Config(
+        vocab_size=512, hidden_size=256, intermediate_size=512,
+        moe_intermediate_size=128, num_hidden_layers=3,
+        layer_types=(FULL, FULL, SLIDING), n_routed_experts=16,
+        experts_held=(0, 2), q_lora_rank=128, swa_q_lora_rank=128)
+    with paddle.LazyGuard():
+        net = Dots3(cfg)
+    net.bfloat16()
+    state = jax.eval_shape(state_drawer(net),
+                           jax.ShapeDtypeStruct((2,), jnp.uint32))
+    ns, ps, nps, w = 4, 128, 24, 256
+    spec = net.cache_spec()
+    pools = jax.eval_shape(lambda: LatentPools.zeros(
+        spec["full_layers"], ns * nps + 1, spec["window_layers"],
+        ns * 8 + 1, ps, spec["latent_width"], spec["index_width"],
+        spec["window_width"], jnp.bfloat16))
+    nt = ns + w
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32)
+
+    args = (state[0], state[1], pools, i32(nt), i32(nt), i32(nt),
+            (i32(ns + 1, nps), i32(ns + 1, nps)), i32(ns + 1), i32(ns + 1),
+            i32(ns))
+    args = jax.tree_util.tree_map(
+        lambda a: _on_tpu(dev, a.shape, a.dtype), args)
+
+    def forward(*a):
+        return dots3_ragged_apply(cfg, *a, decode_rows=ns, chunk_width=w)
+
+    monkeypatch.setenv("PADDLE_TPU_TARGET_PLATFORM", "tpu")
+    with jax.default_matmul_precision("default"):
+        compiled = jax.jit(forward, donate_argnums=2).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()        # moe_gmm
+    ma = compiled.memory_analysis()
+    pool_bytes = sum(int(np.prod(p.shape)) * 2 for p in pools)
+    assert ma.alias_size_in_bytes >= pool_bytes, \
+        "the donated latent pools are not aliased"
