@@ -617,12 +617,13 @@ def check_submits(submits, limit_ms: float) -> None:
           f"each may take {limit_ms}")
 
 
-def attn_calls() -> dict:
+def attn_calls(counter: str = "serving/attn_calls") -> dict:
     """``serving/attn_calls{path=}``: attention calls by the path they
-    took, counted on the host while a tick is traced."""
+    took, counted on the host while a tick is traced (``counter``:
+    ``serving/latent_attn_calls`` for the selected latent attention's)."""
     from paddle_tpu.profiler import registry
 
-    return {p: registry().counter("serving/attn_calls{path=%s}" % p).value
+    return {p: registry().counter("%s{path=%s}" % (counter, p)).value
             for p in ("pallas", "xla")}
 
 
@@ -946,10 +947,19 @@ def check_latent_ops(rows: int, t: int, heads: int, width: int, c: int,
         pr = jax.nn.softmax(jnp.where(keep[:, :, None], sc, -jnp.inf), -1)
         return jnp.einsum("rtns,rsc->rtnc", pr, flat[..., :c])
 
-    got = jax.jit(pa.selected_latent_attention, static_argnums=(2, 9, 10))(
-        q, pool, 0, table, pos0, true_len, keys.reshape(rows, t, cap),
-        thr.reshape(rows, t), ties.reshape(rows, t), c, 0.06)
-    errs["selected"] = _nerr(got, dense(mask, 0.06))
+    def selected(impl):
+        return jax.jit(pa.selected_latent_attention,
+                       static_argnums=(2, 9, 10, 11))(
+            q, pool, 0, table, pos0, true_len, keys.reshape(rows, t, cap),
+            thr.reshape(rows, t), ties.reshape(rows, t), c, 0.06, impl)
+
+    # the spelling the platform and this shape pick (on the chip, at the
+    # cell's row shapes, the Pallas kernel), then the other one
+    path = pa.latent_attention_path(q, pool, c)
+    other = "xla" if path == "pallas" else "pallas"
+    want = dense(mask, 0.06)
+    errs["selected"] = _nerr(selected(None), want)
+    errs["selected_" + other] = _nerr(selected(other), want)
     got, _ = jax.jit(pa.window_latent_attention, static_argnums=(2, 6, 7, 8))(
         q, pool, 0, table, pos0, true_len, window, c, 0.06)
     inside = seen & (np.arange(cap)[None, None] > qpos[..., None] - window)
@@ -957,13 +967,15 @@ def check_latent_ops(rows: int, t: int, heads: int, width: int, c: int,
     for name, err in errs.items():
         check(err <= TOL_LATENT_OPS,
               f"latent {name} read off by {err:.2e}, allowed {TOL_LATENT_OPS}")
-    return errs
+    return {**errs, "path": path}
 
 
 def phase_latent(cfg, num_slots: int, page_size: int, pages_per_slot: int,
-                 ops_shape, requests=LATENT_REQUESTS) -> dict:
+                 ops_shapes, requests=LATENT_REQUESTS) -> dict:
     """The latent-attention pass (models/dots3.py): the latent pools' read
-    sides against plain spellings at ``ops_shape``, then a small model
+    sides against plain spellings at each of ``ops_shapes`` (the selected
+    attention through both of its spellings; the one these shapes pick
+    must be the platform's: on the chip the kernel), then a small model
     through the engine (a ``LazyGuard`` model drawn on the device, latent,
     indexer-key and windowed pools, the held experts inside the tick): what
     it emitted is the float32 reference's (models/dots3_reference.py), the
@@ -979,11 +991,24 @@ def phase_latent(cfg, num_slots: int, page_size: int, pages_per_slot: int,
     from paddle_tpu.profiler import registry
     from paddle_tpu.serving import ServingConfig, ServingEngine
 
-    errs = check_latent_ops(*ops_shape)
-    say("latent", "reads against float32 spellings: " + ", ".join(
-        f"{k} {v:.2e}" for k, v in errs.items())
-        + f" (allowed {TOL_LATENT_OPS})")
+    from paddle_tpu.ops.paged_attention import resolve_impl
+
+    errs = {}
+    for shape in ops_shapes:
+        got = check_latent_ops(*shape)
+        path = got.pop("path")
+        rows, t, heads, width = shape[:4]
+        say("latent", f"{rows} rows of {t} queries, {heads} heads over "
+            f"{width}: reads against float32 spellings: " + ", ".join(
+                f"{k} {v:.2e}" for k, v in got.items())
+            + f" (allowed {TOL_LATENT_OPS}); the selected attention's own "
+            f"path here: {path}")
+        check(path == resolve_impl(None),
+              f"the selected latent attention of {heads} heads over {width} "
+              f"went by {path} where the platform's is {resolve_impl(None)}")
+        errs = {k: max(v, errs.get(k, 0.0)) for k, v in got.items()}
     reg = registry()
+    calls0 = attn_calls("serving/latent_attn_calls")
     freed0 = reg.counter("serving/window_pages_freed").value
     paddle.seed(0)
     with paddle.LazyGuard():
@@ -1041,6 +1066,12 @@ def phase_latent(cfg, num_slots: int, page_size: int, pages_per_slot: int,
     paths = {k: v for k, v in reg.snapshot().items()
              if k.startswith("moe/grouped_matmul_calls")} \
         if hasattr(reg, "snapshot") else {}
+    tick = {p: n - calls0[p] for p, n in
+            attn_calls("serving/latent_attn_calls").items() if n > calls0[p]}
+    check(tick, "no tick counted its selected latent attention")
+    say("latent", f"the engine's ticks compiled their selected attention by "
+        f"{tick} (serving/latent_attn_calls; the kernel where Mosaic tiles "
+        f"the model's heads, latents and pages)")
     say("latent", f"{len(rids)} requests through latent, indexer-key and "
         f"windowed pools ({cfg.num_hidden_layers} layers): shortfall "
         f"{median:.4f} at the median (allowed {TOL_LATENT_SHORTFALL}), "
@@ -1050,7 +1081,8 @@ def phase_latent(cfg, num_slots: int, page_size: int, pages_per_slot: int,
         f"{freed:.0f} windowed pages given back; weights {_gb(weights)}"
         + (f"; {paths}" if paths else ""))
     return {"worst": worst, "median": median, "differ": differ,
-            "freed": freed, "weights_bytes": weights, **errs}
+            "freed": freed, "weights_bytes": weights, "tick_paths": tick,
+            **errs}
 
 
 # ---------------------------------------------------------------------------
@@ -1364,14 +1396,16 @@ def main() -> int:
     # the looped model at its published widths, 3 of its 48 layers
     looped = dataclasses.replace(GPTConfig.ouro_2_6b(), num_layers=3)
     run("serve-looped", lambda: phase_serve_looped(looped, 4, page, 16))
-    # the latent-attention model: its pools' reads at the cell's row shape
-    # (128 heads over latents of 576, pages of 128), and a small model of
-    # its kinds of layer through the engine
+    # the latent-attention model: its pools' reads at the cell's row shapes
+    # (128 heads over latents of 576, pages of 128: a chunk row of 256 and
+    # twelve decode rows), and a small model of its kinds of layer through
+    # the engine
     from paddle_tpu.models.dots3 import Dots3Config
 
+    widths = (128, 576, 512, 128, 24, 512, 513, jax.numpy.bfloat16)
     run("latent", lambda: phase_latent(
         Dots3Config.tiny(hidden_size=256, experts_held=(0, 4)), 3, 4, 24,
-        (3, 16, 128, 576, 512, 128, 24, 512, 513, jax.numpy.bfloat16)))
+        [(1, 256) + widths, (12, 1) + widths]))
     run("train", lambda: phase_train(cfg, micro=2, n_micro=6, steps=4))
     if len(jax.devices()) >= 4 and "train" not in failed:
         run("multichip", lambda: phase_multichip(

@@ -544,9 +544,12 @@ def dots3_ragged_apply(c: Dots3Config, stacked, other, pools, tokens,
     arguments of ``gpt_ragged_apply``, with ``pools`` a ``LatentPools`` and
     ``row_tab`` the pair ``(tables of the full layers' pages, tables of the
     windowed layers' pages)``, both ``[R, NPs]``, ``stacked`` the layers'
-    own weights (``{"layer<i>": {...}}``). ``impl`` and
-    ``has_chunks`` are taken and not used: there is one spelling, and one
-    body whatever the mix.
+    own weights (``{"layer<i>": {...}}``). ``impl`` names the spelling of
+    the full layers' attention (``ops/paged_attention.
+    selected_latent_attention``: ``None`` for the one the platform and the
+    shapes pick, the Pallas kernel on the chip at the published widths;
+    ``"xla"`` / ``"pallas"``); every other read has one spelling.
+    ``has_chunks`` is taken and not used: one body whatever the mix.
 
     Returns ``(logits [S, V], pools, aux)``: ``aux["stats"]`` float32
     ``[len(TICK_STATS)]`` (the mean share of its visible keys a live query
@@ -559,7 +562,7 @@ def dots3_ragged_apply(c: Dots3Config, stacked, other, pools, tokens,
     ``aux["window_lse"]`` ``[sliding layers, S]`` float32 the log of the sum
     of each sampled row's exponentiated scores in a sliding layer, mean over
     its heads (``ops/paged_attention.window_latent_attention``)."""
-    del impl, has_chunks
+    del has_chunks
     tab, wtab = row_tab
     nt, nd, w = tokens.shape[0], decode_rows, chunk_width
     nch = (nt - nd) // w if w else 0
@@ -663,7 +666,8 @@ def dots3_ragged_apply(c: Dots3Config, stacked, other, pools, tokens,
                     layer, tab[rows], row_pos0[rows], row_len[rows],
                     keys[lo:hi].reshape(n, t, -1), thr[lo:hi].reshape(n, t),
                     ties[lo:hi].reshape(n, t),
-                    w_["kv_rank"], 1.0 / math.sqrt(w_["nope"] + w_["rope"])
+                    w_["kv_rank"], 1.0 / math.sqrt(w_["nope"] + w_["rope"]),
+                    impl=impl
                 ).reshape((n * t,) + q.shape[1:2] + (w_["kv_rank"],))
 
             o_lat = groups(attend)

@@ -80,6 +80,21 @@ one rounding fewer). The f32 path is
 bit-for-bit untouched (no cast, no extra ops) — the engine's bitwise
 parity contract only ever applied to unquantized pools, and still
 does.
+
+Latent pools (ISSUE 37; the second half of this file): a latent-attention
+model caches one row a token a layer, ``[L, P, W, ps]``, and its reads
+(``index_scores``, ``select_threshold``, ``selected_latent_attention``,
+``window_latent_attention``) walk a row's own pages as the ragged kernel
+does. ``selected_latent_attention`` has the same two spellings behind one
+entry point as ``ragged_paged_attention`` (ISSUE 39), picked where the
+program is traced, by platform and shapes (``latent_attention_path``):
+the XLA walk, whose float32 score blocks ``[256, 128, 2048]`` go through
+HBM five times a block (on a v5e 6.8 ms for one layer's chunk of 256
+queries with 8,960 positions behind it, 2.6 ms for twelve decode rows of
+which six are live at 13-21 k: every row walks as far as the longest), and
+the Pallas kernel ``selected_latent_attn``, whose scores stay in VMEM
+(4.2 ms and 0.44 ms; 16.4 -> 8.6 ms of the dots3 cell's tick; PERF.md
+section 6, PR 39).
 """
 from __future__ import annotations
 
@@ -601,10 +616,13 @@ def _ragged_attention_pallas(q, k_pool, v_pool, page_table, pos0,
 # order of the two axes; PERF.md section 6, PR 37). So the writes go a page
 # at a time (``latent_scatter``): the pages a tick touches are read, given
 # their new columns and written back whole, which is the pool's own layout.
-# The functions below are the read and write sides of such pools, in
-# ``jax.numpy``: the same walk over a row's own pages as the ragged
-# kernel's, other contents. Every shape is fixed, every trip count is the
-# rows' own.
+# The functions below are the read and write sides of such pools: the same
+# walk over a row's own pages as the ragged kernel's, other contents. Every
+# shape is fixed, every trip count is the rows' own. All are ``jax.numpy``
+# but the full layers' attention, ``selected_latent_attention``, which on
+# the chip is the Pallas kernel at the end of this file (ISSUE 39): a page
+# ``[W, ps]`` is what ``q @ page`` wants as its right-hand side, so the
+# kernel fetches pages by id as they lie and nothing is re-laid.
 
 #: pages of one block of ``index_scores``' walk over a row's indexer keys
 _INDEX_BLOCK_PAGES = 8
@@ -749,12 +767,32 @@ def selection_mask(keys, thr, ties):
     return (keys > thr[:, None]) | (tie & first)
 
 
-#: pages of one block of ``selected_latent_attention``'s walk
+#: pages of one block of the XLA spelling's walk
 _ATTN_BLOCK_PAGES = 16
 
 
+def latent_attention_path(q, pool, c_width: int, impl=None) -> str:
+    """``"pallas"`` or ``"xla"`` for ``selected_latent_attention``:
+    ``impl`` itself when given; else the kernel where the program is traced
+    for a TPU (``resolve_impl``) *and* the shapes are ones Mosaic tiles
+    (pages of whole lanes, heads and widths of whole sublane tiles, a tile
+    of queries that fits), the XLA spelling anywhere else. Nothing else
+    selects the path."""
+    if impl is not None:
+        return impl
+    if resolve_impl(None) == "xla":
+        return "xla"
+    t, nh, width = q.shape[1:]
+    rows = 8 * _rows_per_word(pool.dtype)
+    tiles = (q.dtype == pool.dtype and pool.shape[-1] % 128 == 0
+             and c_width % 128 == 0 and nh % rows == 0 and width % rows == 0
+             and _latent_tile_queries(t, nh) * nh <= 2 * _LATENT_TILE_ROWS)
+    return "pallas" if tiles else "xla"
+
+
 def selected_latent_attention(q, pool, layer, page_table, pos0, true_len,
-                              keys, thr, ties, c_width: int, scale: float):
+                              keys, thr, ties, c_width: int, scale: float,
+                              impl=None):
     """Absorbed (multi-query) attention of ragged rows over a latent pool,
     each query over its own *selection* of its row's live positions.
 
@@ -767,16 +805,61 @@ def selected_latent_attention(q, pool, layer, page_table, pos0, true_len,
     thr, ties   [R, T]         ``ties``: which positions query ``i`` of row
                                ``r`` selected (of those ``<= pos0[r] + i``
                                within the row's live positions)
+    impl        None           the path ``latent_attention_path`` observes,
+                               or ``"xla"`` / ``"pallas"``; counted, while
+                               the program is traced, in
+                               ``serving/latent_attn_calls{path=}``
 
-    The row's live pages are walked once, in blocks of
-    ``_ATTN_BLOCK_PAGES`` pages, every head of every query of the row
-    scoring a block's latents in one product under the selection's mask
-    and a float32 online softmax: the selected latents are never gathered
-    a query at a time (549 k rows of 1,152 B took 9.8 ms a layer on a v5e,
-    and their page ids 5.6 more), at the price of scoring what is not
-    selected. Returns ``[R, T, NH, c_width]`` (the values are carried out
-    of the latent space by the caller); a query with nothing to attend
-    gets zeros."""
+    The selected latents are never gathered a query at a time (549 k rows
+    of 1,152 B took 9.8 ms a layer on a v5e, and their page ids 5.6 more;
+    PERF.md section 6, PR 37): every head of a tile of queries scores whole
+    pages under the selection's mask and a float32 online softmax, at the
+    price of scoring what is not selected. Two spellings of that walk:
+
+    - ``"xla"`` (the reference, and what anything but a TPU runs):
+      ``_selected_latent_xla``, a ``fori_loop`` over blocks of
+      ``_ATTN_BLOCK_PAGES`` pages as far as the *longest* row's live
+      positions, every block's float32 scores ``[R, T, NH, 2048]`` written
+      to HBM and read back for the mask, the maximum, the exponentials and
+      the second product, and the decode rows' page blocks re-laid by
+      ``_block_of_pages``. On a v5e, one layer: 1.8 / 6.8 / 11.8 ms for a
+      chunk of 256 with 0 / 8,960 / 16,384 positions behind it, 2.6 ms for
+      twelve decode rows, six of them live at 13-21 k (PERF.md section 6,
+      PR 39).
+    - ``"pallas"`` (the chip's, ISSUE 39): the kernel
+      ``selected_latent_attn``. Grid (row, tile of queries); the pool stays
+      in HBM and a block's pages come by page id into one of two VMEM
+      buffers; scores, mask and softmax never leave VMEM; a tile walks only
+      as far as its own last query sees. The same calls: 0.59 / 4.2 /
+      7.3 ms and 0.44 ms, the products at about 160 TFLOP/s of the chip's
+      197 behind a long context; ``mla.attn_ms_per_tick`` of
+      ``serve-dots3-longdoc-backlog`` 16.4 -> 8.6 ms (two layers).
+      Allclose, not bitwise, to the spelling (the blocks differ, so the
+      online softmax reassociates); both read 3-4e-3 of the largest value
+      off a float32 softmax at the cell's shapes.
+
+    Returns ``[R, T, NH, c_width]`` (the values are carried out of the
+    latent space by the caller); a query with nothing to attend gets zeros.
+    Queries at ``i >= true_len[r]`` are computed anyway and hold garbage
+    that differs between the spellings: never compare pad queries."""
+    from ..profiler import metrics
+
+    impl = latent_attention_path(q, pool, c_width, impl)
+    if impl not in ("xla", "pallas"):
+        raise ValueError(f"unknown latent attention impl {impl!r}")
+    metrics.registry().counter(
+        "serving/latent_attn_calls{path=%s}" % impl).add(1)
+    spelling = _selected_latent_xla if impl == "xla" \
+        else _selected_latent_pallas
+    return spelling(q, pool, layer, page_table, pos0, true_len, keys, thr,
+                    ties, c_width, scale)
+
+
+def _selected_latent_xla(q, pool, layer, page_table, pos0, true_len,
+                         keys, thr, ties, c_width: int, scale: float):
+    """``selected_latent_attention`` in ``jax.numpy``: the row's live pages
+    walked once, in blocks of ``_ATTN_BLOCK_PAGES`` pages, every head of
+    every query of the row scoring a block's latents in one product."""
     r, t, nh = q.shape[:3]
     ps = pool.shape[-1]
     nps = page_table.shape[1]
@@ -865,3 +948,247 @@ def window_latent_attention(q, pool, layer, page_table, pos0, true_len,
     p = jnp.exp(s - lse[..., None]).astype(q.dtype)
     return (jnp.einsum("rnts,rcs->rtnc", p, lat[:, :c_width]),
             jnp.mean(lse, axis=1))
+
+
+# --------------------------------------------------------------------------
+# Pallas kernel of the selected latent attention (ISSUE 39)
+# --------------------------------------------------------------------------
+
+#: positions of one block of the kernel's walk: what one trip fetches (four
+#: pages of 128, 590 KB of latents) and scores
+_LATENT_BLOCK_TOKENS = 512
+#: rows of a tile's products, queries x heads (absorbed attention is
+#: multi-query: every head of a query meets the same latents). On a v5e,
+#: one layer's chunk of 256 behind 16,384 positions: 7.34 ms at 2,048 rows
+#: x 512 positions, 7.62 at 1,024 x 512, 7.58 at 2,048 x 1,024, 8.62 at
+#: 1,024 x 256 (PERF.md section 6, PR 39)
+_LATENT_TILE_ROWS = 2048
+
+
+def _latent_tile_queries(t: int, nh: int) -> int:
+    """Queries of one tile of a row of ``t``: a divisor of ``t`` in whole
+    sublane tiles whose ``tq * nh`` rows stay within ``_LATENT_TILE_ROWS``,
+    or all of a short row."""
+    want = max(1, _LATENT_TILE_ROWS // nh)
+    fits = [d for d in range(8, min(t, want) + 1, 8) if t % d == 0]
+    return t if t <= want or not fits else max(fits)
+
+
+def _last_taken_tie(keys, thr, ties, last, group: int):
+    """The position of the last tie each query takes: its selection is the
+    visible positions with ``keys > thr`` and those with ``keys == thr`` up
+    to that position (-1: no tie taken; ``S``: all of them), which is
+    ``selection_mask``'s running count without a cumulative sum over ``S``:
+    one pass counts the ties of every ``group`` positions, the group that
+    holds the ``ties``-th is looked at alone.
+
+    keys [R, T, S] uint32, thr uint32 / ties int32 / last int32 [R, T]
+    (``last``: the last position a query sees), ``S`` a multiple of
+    ``group``. Returns int32 [R, T]."""
+    r, t, s = keys.shape
+    n = s // group
+    grouped = keys.reshape(r, t, n, group)
+    at = jnp.arange(group, dtype=jnp.int32)
+    kpos = (jnp.arange(n, dtype=jnp.int32) * group)[:, None] + at[None, :]
+    tie = (grouped == thr[..., None, None]) \
+        & (kpos <= last[..., None, None])
+    per = jnp.sum(tie, axis=-1, dtype=jnp.int32)                # [R, T, n]
+    cum = jnp.cumsum(per, axis=-1)
+    grp = jnp.sum(cum < ties[..., None], axis=-1, dtype=jnp.int32)
+    g = jnp.minimum(grp, n - 1)[..., None]
+    need = ties - (jnp.take_along_axis(cum, g, -1)
+                   - jnp.take_along_axis(per, g, -1))[..., 0]
+    mine = jnp.take_along_axis(grouped, g[..., None], axis=2)[:, :, 0]
+    pos = g * group + at                                        # [R, T, group]
+    tie = (mine == thr[..., None]) & (pos <= last[..., None])
+    taken = tie & (jnp.cumsum(tie, axis=-1) <= need[..., None])
+    cut = jnp.max(jnp.where(taken, pos, -1), axis=-1)
+    return jnp.where(ties <= 0, -1, jnp.where(grp >= n, s, cut))
+
+
+def _latent_kernel(pt_ref, pos0_ref, tl_ref, layer_ref, q_ref, keys_ref,
+                   thr_ref, cut_ref, pool_hbm, o_ref, buf, sem, slot_ref,
+                   s_ref, p_ref, m_ref, l_ref, corr_ref, acc_ref, *,
+                   scale: float, c_width: int, ps: int):
+    """Grid (r, j): tile ``j`` of ``tq`` consecutive queries of row ``r``,
+    every head of them the rows of its products (``tq * NH`` rows of ``W``).
+    The pool stays in HBM; the key axis is a loop over blocks of ``bp`` of
+    the row's own pages whose trip count is the tile's own: as far as its
+    last real query sees, so a page past that is never read and a tile with
+    no real query (a free slot's row, the pad tiles of a short chunk) costs
+    the grid step alone. A block's pages are fetched by page id, side by
+    side along the lanes of one of two buffers ``[W, bp * ps]``, the next
+    block (or the next tile's first) in flight while this one is multiplied;
+    the buffer in turn is carried from step to step in SMEM, so the grid is
+    sequential. A block's scores ``[tq * NH, bp * ps]`` live in VMEM, in
+    float32; the selection's mask is made once a query from its ``keys``,
+    ``thr`` and last taken tie, and laid over its heads; running maximum,
+    sum and accumulator are float32, the weights meet the latents again in
+    the pool's type."""
+    _, width, bt = buf.shape
+    tq, nh = q_ref.shape[1:3]
+    bp = bt // ps
+    nps = pt_ref.shape[1]
+    rows = tq * nh
+    r, j = pl.program_id(0), pl.program_id(1)
+    tiles = pl.num_programs(1)
+    last_step = jnp.logical_and(r + 1 == pl.num_programs(0), j + 1 == tiles)
+    next_r = jnp.where(j + 1 < tiles, r, r + 1)
+    next_j = jnp.where(j + 1 < tiles, j + 1, 0)
+    layer = layer_ref[0]
+
+    def visible(row, tile):
+        """Positions the real queries of ``tile`` of ``row`` see between
+        them (0: the tile has no real query)."""
+        n = jnp.minimum(pos0_ref[row] + jnp.minimum((tile + 1) * tq,
+                                                    tl_ref[row]), nps * ps)
+        return jnp.where(tile * tq < tl_ref[row], n, 0)
+
+    def copies(row, tile, blk, slot, act):
+        """``act`` (start or wait) on the copy of every page block ``blk``
+        of a tile needs into buffer ``slot``."""
+        first = blk * bp
+        count = jnp.minimum(pl.cdiv(visible(row, tile), ps) - first, bp)
+
+        def one(i, carry):
+            page = pt_ref[row, first + i]
+            act(pltpu.make_async_copy(
+                pool_hbm.at[layer, page],
+                buf.at[slot, :, pl.ds(pl.multiple_of(i * ps, ps), ps)],
+                sem.at[slot]))
+            return carry
+
+        jax.lax.fori_loop(0, count, one, 0)
+
+    start = lambda c: c.start()
+    wait = lambda c: c.wait()
+    n_vis = visible(r, j)
+    nblk = pl.cdiv(n_vis, bt)
+
+    @pl.when(jnp.logical_and(r == 0, j == 0))
+    def _first():
+        slot_ref[0] = 0
+        copies(r, j, 0, 0, start)
+
+    slot0 = slot_ref[0]
+    m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
+    l_ref[...] = jnp.zeros_like(l_ref)
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+    q = q_ref[0].reshape(rows, width)
+    thr, cut = thr_ref[0], cut_ref[0]                           # [tq, 1]
+    qpos = pos0_ref[r] + j * tq + jax.lax.broadcasted_iota(
+        jnp.int32, (tq, bt), 0)
+    last = jnp.minimum(qpos, n_vis - 1)
+
+    def block(b, carry):
+        slot = (slot0 + b) % 2
+
+        @pl.when(b + 1 < nblk)
+        def _next_block():
+            copies(r, j, b + 1, 1 - slot, start)
+
+        @pl.when(jnp.logical_and(b + 1 == nblk, jnp.logical_not(last_step)))
+        def _next_tile():
+            copies(next_r, next_j, 0, 1 - slot, start)
+
+        copies(r, j, b, slot, wait)
+        lat = buf.at[slot]
+        left = n_vis - b * bt
+
+        @pl.when(left < bt)
+        def _dead():
+            # what lies past the tile's last position is whatever the
+            # buffer or the page held: zeros, so that a weight of 0 cannot
+            # meet a NaN
+            at = jax.lax.broadcasted_iota(jnp.int32, (width, bt), 1)
+            lat[...] = jnp.where(at < left, lat[...], jnp.zeros_like(lat))
+
+        s_ref[...] = _dot(q, lat[...].astype(q.dtype),
+                          (((1,), (0,)), ((), ())))             # [rows, bt]
+        mine = keys_ref[0, :, pl.ds(pl.multiple_of(b * bt, bt), bt)]
+        kpos = b * bt + jax.lax.broadcasted_iota(jnp.int32, (tq, bt), 1)
+        keep = (kpos <= last) & ((mine > thr)
+                                 | ((mine == thr) & (kpos <= cut)))
+        bias = jnp.where(keep, 0.0, _NEG_INF)                   # [tq, bt]
+        for i in range(tq):         # a query's heads share its mask
+            at = slice(i * nh, (i + 1) * nh)
+            s = s_ref[at, :] * scale + bias[i:i + 1, :]
+            m_prev = m_ref[at, :]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+            # where nothing was kept yet m is the mask's constant and the
+            # weights are 1: the first kept score's ``corr`` is exactly 0
+            p = jnp.exp(s - m_new)
+            corr_ref[at, :] = jnp.exp(m_prev - m_new)
+            l_ref[at, :] = corr_ref[at, :] * l_ref[at, :] \
+                + jnp.sum(p, axis=1, keepdims=True)
+            m_ref[at, :] = m_new
+            p_ref[at, :] = p.astype(p_ref.dtype)
+        acc_ref[...] = corr_ref[...] * acc_ref[...] + _dot(
+            p_ref[...], lat[:c_width, :].astype(q.dtype),
+            (((1,), (1,)), ((), ())))                           # [rows, C]
+        return carry
+
+    jax.lax.fori_loop(0, nblk, block, 0)
+
+    @pl.when(jnp.logical_and(nblk == 0, jnp.logical_not(last_step)))
+    def _next_tile_of_an_empty_one():
+        copies(next_r, next_j, 0, slot0, start)
+
+    slot_ref[0] = (slot0 + nblk) % 2
+    # a query that kept nothing (its maximum is still the mask's constant)
+    # gets zeros, as a tile that walked nothing does
+    kept = m_ref[...] > _NEG_INF / 2
+    out = acc_ref[...] / jnp.where(kept, l_ref[...], 1.0)
+    o_ref[0] = jnp.where(kept, out, 0.0).reshape(
+        tq, nh, c_width).astype(o_ref.dtype)
+
+
+def _selected_latent_pallas(q, pool, layer, page_table, pos0, true_len,
+                            keys, thr, ties, c_width: int, scale: float):
+    r, t, nh, width = q.shape
+    ps = pool.shape[-1]
+    nps = page_table.shape[1]
+    bp = max(1, min(nps, _LATENT_BLOCK_TOKENS // ps))
+    bt = bp * ps
+    tq = _latent_tile_queries(t, nh)
+    cap = nps * ps
+    live = jnp.where(true_len > 0, jnp.minimum(pos0 + true_len, cap), 0)
+    qpos = pos0[:, None] + jnp.arange(t, dtype=pos0.dtype)[None, :]
+    cut = _last_taken_tie(keys, thr, ties.astype(jnp.int32),
+                          jnp.minimum(qpos, live[:, None] - 1), ps)
+    if cap % bt:        # a block of keys is sliced whole
+        keys = jnp.pad(keys, ((0, 0), (0, 0), (0, -cap % bt)))
+    span = keys.shape[2]
+
+    def tile(*block):
+        return pl.BlockSpec((1, tq) + block, lambda i, j, *_: (i, j)
+                            + (0,) * len(block))
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=4,
+        grid=(r, t // tq),
+        in_specs=[tile(nh, width), tile(span), tile(1), tile(1),
+                  pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=tile(nh, c_width),
+        scratch_shapes=[
+            pltpu.VMEM((2, width, bt), pool.dtype),
+            pltpu.SemaphoreType.DMA((2,)),
+            pltpu.SMEM((1,), jnp.int32),
+            pltpu.VMEM((tq * nh, bt), jnp.float32),     # scores
+            pltpu.VMEM((tq * nh, bt), q.dtype),         # weights
+            pltpu.VMEM((tq * nh, 1), jnp.float32),      # running maximum
+            pltpu.VMEM((tq * nh, 1), jnp.float32),      # running sum
+            pltpu.VMEM((tq * nh, 1), jnp.float32),      # a block's rescale
+            pltpu.VMEM((tq * nh, c_width), jnp.float32)])
+    return pl.pallas_call(
+        functools.partial(_latent_kernel, scale=scale, c_width=c_width,
+                          ps=ps),
+        name="selected_latent_attn",
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((r, t, nh, c_width), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=64 * 2 ** 20),
+        interpret=_interpret(),
+    )(page_table, pos0, true_len, jnp.asarray(layer, jnp.int32).reshape(1),
+      q, keys, thr[..., None], cut[..., None], pool)

@@ -187,7 +187,12 @@ def test_latent_rehearsal():
 
     out = chip_smoke.phase_latent(
         Dots3Config.tiny(experts_held=(0, 4)), 3, 4, 24,
-        (2, 5, 3, 12, 8, 4, 10, 6, 5, jnp.bfloat16))
+        [(2, 5, 3, 12, 8, 4, 10, 6, 5, jnp.bfloat16),
+         (3, 1, 3, 12, 8, 4, 10, 6, 5, jnp.bfloat16)])
+    # off the chip the tick's selected attention is the XLA spelling, and
+    # the kernel ran interpreted beside it
+    assert set(out["tick_paths"]) == {"xla"}
+    assert out["selected_pallas"] <= chip_smoke.TOL_LATENT_OPS
     assert out["median"] <= out["worst"] <= chip_smoke.TOL_LATENT_SHORTFALL
     assert out["differ"] <= chip_smoke.TOL_LATENT_SELECTED
     assert out["freed"] > 0

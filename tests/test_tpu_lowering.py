@@ -544,7 +544,13 @@ def test_tpu_compile_the_latent_models_forward(monkeypatch):
     monkeypatch.setenv("PADDLE_TPU_TARGET_PLATFORM", "tpu")
     with jax.default_matmul_precision("default"):
         compiled = jax.jit(forward, donate_argnums=2).lower(*args).compile()
-    assert "tpu_custom_call" in compiled.as_text()        # moe_gmm
+    text = compiled.as_text()
+    assert re.findall(r"%moe_gmm[\w.\-]* = ", text)
+    # ISSUE 39: the full layers' attention is the Pallas kernel, twice a
+    # layer (decode rows, chunk rows), and no score block with a (heads x
+    # keys) extent is an array of the program
+    assert len(re.findall(r"%selected_latent_attn[\w.\-]* = ", text)) == 4
+    assert not re.findall(r"f32\[\d+,\d+,128,\d{3,}\]", text)
     ma = compiled.memory_analysis()
     pool_bytes = sum(int(np.prod(p.shape)) * 2 for p in pools)
     assert ma.alias_size_in_bytes >= pool_bytes, \
