@@ -33,6 +33,15 @@ finds, and checks what comes out by the repo's own means:
               forward pass
   2 serve     ServingEngine.submit/step/run, ten requests over ~150 ticks;
               the tick took its attention through the ragged kernel
+  2d dsv2     DeepSeek-V2's dense latent attention (ops/paged_attention.
+              latent_attention) at its cell's row shapes (two chunk rows of
+              256, twenty decode rows; 128 heads over latents of 576) through
+              both of its spellings against a float32 softmax, and a small
+              model (YaRN, a router limited to 2 of 4 groups, held experts)
+              through the engine, two chunks a tick, against the float32
+              reference models/deepseek_v2_reference.py; its cell is
+              serve-dsv2-docqa-backlog (5 of 60 layers, 20 of 160 experts,
+              1/8 of the vocabulary)
   3 train     HybridPipelineTrainer.step, bench.py's headline knobs
   4 multichip the same trainer on dp2 x tp2 and pp2 x tp2 (and the head
               alone as that arm runs it: inside a region manual over pp,
@@ -1085,6 +1094,141 @@ def phase_latent(cfg, num_slots: int, page_size: int, pages_per_slot: int,
             **errs}
 
 
+def check_dense_latent(rows: int, t: int, heads: int, width: int, c: int,
+                       page: int, pages: int, dtype) -> dict:
+    """``ops/paged_attention.latent_attention`` at the given row shape
+    (``rows`` rows of ``t`` queries over ``pages`` pages of ``page`` tokens,
+    half of them live) through both spellings against a float32 softmax
+    over the whole rows."""
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.ops import paged_attention as pa
+
+    rng = np.random.default_rng(6)
+    cap = pages * page
+    pool = jnp.asarray(rng.normal(size=(1, rows * pages + 1, width, page)),
+                       dtype)
+    table = 1 + np.arange(rows * pages, dtype=np.int32).reshape(rows, pages)
+    pos0 = (cap // 2 + np.arange(rows) * 3 - t).astype(np.int32)
+    true_len = np.full(rows, t, np.int32)
+    q = jnp.asarray(rng.normal(size=(rows, t, heads, width)) * .3, dtype)
+    flat = jnp.swapaxes(pool[0, table], 2, 3).reshape(
+        rows, cap, width).astype(jnp.float32)               # [R, S, W]
+    qpos = pos0[:, None] + np.arange(t)[None]
+    seen = np.arange(cap)[None, None] <= qpos[..., None]    # [R, T, S]
+    sc = jnp.einsum("rtnc,rsc->rtns", q.astype(jnp.float32), flat) * 0.06
+    pr = jax.nn.softmax(jnp.where(seen[:, :, None], sc, -jnp.inf), -1)
+    want = jnp.einsum("rtns,rsc->rtnc", pr, flat[..., :c])
+
+    def attend(impl):
+        return jax.jit(pa.latent_attention, static_argnums=(2, 6, 7, 8))(
+            q, pool, 0, table, pos0, true_len, c, 0.06, impl)
+
+    path = pa.latent_attention_path(q, pool, c)
+    other = "xla" if path == "pallas" else "pallas"
+    errs = {"dense": _nerr(attend(None), want),
+            "dense_" + other: _nerr(attend(other), want)}
+    for name, err in errs.items():
+        check(err <= TOL_LATENT_OPS,
+              f"latent {name} read off by {err:.2e}, allowed {TOL_LATENT_OPS}")
+    return {**errs, "path": path}
+
+
+def phase_dsv2(cfg, num_slots: int, page_size: int, pages_per_slot: int,
+               ops_shapes, requests=LATENT_REQUESTS) -> dict:
+    """DeepSeek-V2's pass (models/deepseek_v2.py): the dense latent
+    attention through both of its spellings against a float32 softmax at
+    each of ``ops_shapes`` (the one these shapes pick must be the
+    platform's: on the chip the kernel), then a small model through the
+    engine, two prefill chunks a tick (a ``LazyGuard`` model drawn on the
+    device, latent pools alone, the held experts under the group limit
+    inside the tick): what it emitted is the float32 reference's
+    (models/deepseek_v2_reference.py)."""
+    import dataclasses as dc
+
+    import jax
+
+    import paddle_tpu as paddle
+    from paddle_tpu.models import deepseek_v2_reference as ref
+    from paddle_tpu.models.deepseek_v2 import DeepseekV2
+    from paddle_tpu.ops.paged_attention import resolve_impl
+    from paddle_tpu.profiler import registry
+    from paddle_tpu.serving import ServingConfig, ServingEngine
+
+    errs = {}
+    for shape in ops_shapes:
+        got = check_dense_latent(*shape)
+        path = got.pop("path")
+        rows, t, heads, width = shape[:4]
+        say("dsv2", f"{rows} rows of {t} queries, {heads} heads over "
+            f"{width}: dense latent attention against a float32 softmax: "
+            + ", ".join(f"{k} {v:.2e}" for k, v in got.items())
+            + f" (allowed {TOL_LATENT_OPS}); its own path here: {path}")
+        check(path == resolve_impl(None),
+              f"the dense latent attention of {heads} heads over {width} "
+              f"went by {path} where the platform's is {resolve_impl(None)}")
+        errs = {k: max(v, errs.get(k, 0.0)) for k, v in got.items()}
+    reg = registry()
+    counter = "serving/latent_attn_calls{path=%s,kind=dense}"
+    calls0 = {p: reg.counter(counter % p).value for p in ("pallas", "xla")}
+    paddle.seed(0)
+    with paddle.LazyGuard():
+        net = DeepseekV2(cfg)
+    net.eval()
+    net.bfloat16()
+    eng = ServingEngine(net, ServingConfig(
+        num_slots=num_slots, page_size=page_size,
+        pages_per_slot=pages_per_slot, prefix_cache=False,
+        prefill_chunks_per_tick=2))
+    layers, other = eng.served_weights()
+    on_default_platform((layers, other, eng.pool.pools), "dsv2 serving state")
+    weights = reg.gauge("serving/weights_bytes").value
+    check(weights == 2 * cfg.num_params(),
+          f"serving/weights_bytes {weights:.0f} is not one bf16 copy of "
+          f"{cfg.num_params()} parameters")
+    check(eng.pool.pools.index_k.size == 0 and eng.pool.pools.window.size
+          == 0, "pools were made for an indexer or a window it has not")
+    rng = np.random.RandomState(13)
+    prompts = [rng.randint(0, cfg.vocab_size, n).astype(np.int32)
+               for n, _ in requests]
+    rids = [eng.submit(p, new) for p, (_, new) in zip(prompts, requests)]
+    results = eng.run()
+    jax.block_until_ready(eng.pool.pools)
+    check(eng.pool.check_consistency() == [], "the pools' books disagree")
+    config = dc.asdict(cfg)
+    shorts = []
+    for rid, prompt in zip(rids, prompts):
+        out = results[rid]
+        seq = np.concatenate([prompt, out[:-1]])
+        got = ref.forward(((cfg.is_moe(i), layers[f"layer{i}"])
+                           for i in range(cfg.num_hidden_layers)), other,
+                          seq, config, cfg.held)
+        at = np.arange(len(prompt) - 1, len(seq))
+        shorts.append(ref.shortfall(np.asarray(got["state"])[at], other,
+                                    out)[0])
+    shorts = np.concatenate(shorts)
+    worst, median = float(shorts.max()), float(np.median(shorts))
+    check(median <= TOL_LATENT_SHORTFALL and worst <= TOL_LATENT_WORST,
+          f"an emitted token's logit lies {median:.4f} below the float32 "
+          f"reference's largest at the median (allowed "
+          f"{TOL_LATENT_SHORTFALL}) and {worst:.4f} at the worst (allowed "
+          f"{TOL_LATENT_WORST})")
+    tick = {p: reg.counter(counter % p).value - n for p, n in calls0.items()
+            if reg.counter(counter % p).value > n}
+    check(tick, "no tick counted its dense latent attention")
+    say("dsv2", f"the engine's ticks compiled their dense attention by "
+        f"{tick} (serving/latent_attn_calls{{kind=dense}}; the kernel where "
+        f"Mosaic tiles the model's heads, latents and pages)")
+    say("dsv2", f"{len(rids)} requests through latent pools alone "
+        f"({cfg.num_hidden_layers} layers, two chunks a tick): shortfall "
+        f"{median:.4f} at the median (allowed {TOL_LATENT_SHORTFALL}), "
+        f"{worst:.4f} at the worst (allowed {TOL_LATENT_WORST}); weights "
+        f"{_gb(weights)}")
+    return {"worst": worst, "median": median, "weights_bytes": weights,
+            "tick_paths": tick, **errs}
+
+
 # ---------------------------------------------------------------------------
 # phases 3 and 4: the hybrid trainer
 # ---------------------------------------------------------------------------
@@ -1406,6 +1550,15 @@ def main() -> int:
     run("latent", lambda: phase_latent(
         Dots3Config.tiny(hidden_size=256, experts_held=(0, 4)), 3, 4, 24,
         [(1, 256) + widths, (12, 1) + widths]))
+    # DeepSeek-V2: dense latent attention at its cell's row shapes (two
+    # chunk rows of 256, twenty decode rows), and a small model through the
+    # engine, two chunks a tick
+    from paddle_tpu.models.deepseek_v2 import DeepseekV2Config
+
+    dense = (128, 576, 512, 128, 24, jax.numpy.bfloat16)
+    run("dsv2", lambda: phase_dsv2(
+        DeepseekV2Config.tiny(hidden_size=256, experts_held=(4, 4)), 3, 4,
+        24, [(2, 256) + dense, (20, 1) + dense]))
     run("train", lambda: phase_train(cfg, micro=2, n_micro=6, steps=4))
     if len(jax.devices()) >= 4 and "train" not in failed:
         run("multichip", lambda: phase_multichip(

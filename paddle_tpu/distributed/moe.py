@@ -375,19 +375,43 @@ def held_window_rows(t: int, top_k: int, held: int, e: int) -> int:
     return min(most, -(-fair // 128) * 128)
 
 
+def kept_groups(scores_t, n_group: int, topk_group: int):
+    """The group limit of DeepSeek-V2's router (``group_limited_greedy``,
+    arXiv:2405.04434 section 2.2's device-limited routing): the experts
+    ``[E, T]`` are ``n_group`` runs of ``E / n_group``, a group's score is
+    its best expert's, and a token keeps its ``topk_group`` best groups
+    (rounds of argmax, a tie to the lower group, as the experts' rounds).
+    Returns bool ``[n_group, T]``."""
+    e, t = scores_t.shape
+    remaining = jnp.max(scores_t.reshape(n_group, e // n_group, t), axis=1)
+    rows_g = jnp.arange(n_group, dtype=jnp.int32)[:, None]
+    kept = jnp.zeros((n_group, t), bool)
+    for _ in range(topk_group):
+        hit = rows_g == jnp.argmax(remaining, axis=0).astype(jnp.int32)[None]
+        kept = kept | hit
+        remaining = jnp.where(hit, -jnp.inf, remaining)
+    return kept
+
+
 def _route(x, router_w, top_k, scoring="softmax", select_bias=None,
-           held=None):
+           held=None, n_group=1, topk_group=1):
     """The routing both drop-less layers share, in the transposed [E, T]
     layout, T on the lanes, and ``top_k`` as rounds of argmax, as
     ``switch_moe`` does and for its reasons: the scores, the experts and
     gates of each round and, **a counting sort**, every assignment's place
     within its expert's group (rows of earlier rounds, then the earlier
     tokens of this round), kept for the experts ``held=(first, count)``
-    alone where that is given. Returns ``(probs_t [E, T], z, expert_rounds,
-    gate_rounds, pos_rounds, counts)``, ``counts`` [E] or [count] float32
-    the rows given out."""
+    alone where that is given. ``n_group`` > 1 (softmax scores): a token
+    chooses within its ``topk_group`` best of ``n_group`` groups of experts
+    (``kept_groups``), the others' scores set to 0 before the rounds.
+    Returns ``(probs_t [E, T], z, expert_rounds, gate_rounds, pos_rounds,
+    counts)``, ``counts`` [E] or [count] float32 the rows given out."""
     e = router_w.shape[1]
     softmax = scoring == "softmax"
+    if n_group > 1 and not softmax:
+        raise NotImplementedError(
+            "a group limit over sigmoid scores (DeepSeek-V3's, a group's "
+            "score the sum of its two best): only V2's over softmax is here")
     held_rows = (lambda a: a) if held is None \
         else (lambda a: a[held[0]:held[0] + held[1]])
     logits_t = jnp.dot(router_w.astype(x.dtype).T, x.T,
@@ -397,6 +421,10 @@ def _route(x, router_w, top_k, scoring="softmax", select_bias=None,
         probs_t = jnp.exp(logits_t - lse[None, :])
         z = jnp.mean(jnp.square(lse))
         remaining = probs_t
+        if n_group > 1:
+            remaining = jnp.where(jnp.repeat(
+                kept_groups(probs_t, n_group, topk_group), e // n_group,
+                axis=0), probs_t, 0.0)
     else:
         probs_t = jax.nn.sigmoid(logits_t)
         z = jnp.zeros((), jnp.float32)
@@ -478,7 +506,8 @@ def dropless_moe(x, router_w, w_gate, w_up, w_down, top_k):
 
 
 def held_moe(x, router_w, w_gate, w_up, w_down, top_k, held,
-             scoring="sigmoid", select_bias=None, shared=None):
+             scoring="sigmoid", select_bias=None, shared=None,
+             n_group=1, topk_group=1, routed_scaling=1.0):
     """``dropless_moe``'s sibling that **holds a share of its experts**, as
     one rank of an expert-parallel layout does: experts ``first : first +
     count`` of the router's E, ``held=(first, count)``, weights ``[count,
@@ -497,7 +526,11 @@ def held_moe(x, router_w, w_gate, w_up, w_down, top_k, held,
     experts **renormalised** over all ``top_k`` chosen, held or not;
     ``"softmax"``: as ``dropless_moe``, unrenormalised.
     ``shared=(w_gate, w_up, w_down)`` [H, F], [H, F], [F, H] adds one
-    SwiGLU expert every token passes.
+    SwiGLU expert every token passes (two shared experts of 1,536 are one
+    of 3,072). ``n_group``, ``topk_group``: the softmax router's group
+    limit (``_route``); ``routed_scaling`` multiplies the routed experts'
+    weights and not the shared expert. At their defaults the program is
+    what it was without them.
 
     Returns ``(y [T, H], rows)``, ``rows`` [count] int32 the assignments
     each held expert was given and computed. No auxiliary term: the
@@ -508,12 +541,15 @@ def held_moe(x, router_w, w_gate, w_up, w_down, top_k, held,
     first, count = held
     with _annotate("moe/route"):
         _, _, expert_rounds, gate_rounds, pos_rounds, counts = _route(
-            x, router_w, top_k, scoring, select_bias, held)
+            x, router_w, top_k, scoring, select_bias, held, n_group,
+            topk_group)
         group_sizes = counts.astype(jnp.int32)                   # [count]
         starts = jnp.cumsum(group_sizes) - group_sizes
         gates = jnp.stack(gate_rounds)                           # [K, T]
         if scoring != "softmax":
             gates = gates / jnp.sum(gates, axis=0, keepdims=True)
+        if routed_scaling != 1.0:
+            gates = gates * routed_scaling
         # the held assignments' rows, sorted by expert; the others land
         # past the end, one place each, and are cut off
         width = held_window_rows(t, top_k, count, e)
@@ -602,7 +638,8 @@ class HeldMoEMLP(nn.Layer):
     weights are the ``count`` experts of ``held=(first, count)``, a shared
     SwiGLU expert of ``shared_width`` every token passes, and, under
     ``scoring="sigmoid"``, the selection bias ``select_bias`` [E], which
-    the step never changes.
+    the step never changes; ``n_group``, ``topk_group`` and
+    ``routed_scaling`` as ``held_moe`` takes them.
 
     ``stats`` as ``DroplessMoEMLP``'s, of the experts held: ``moe/rows``
     [count], ``moe/load_max``, ``moe/assigned`` (the assignments to the
@@ -615,13 +652,15 @@ class HeldMoEMLP(nn.Layer):
                  initializer_range: float = 0.02,
                  out_initializer_range: float = 0.02,
                  scoring: str = "sigmoid", select_bias_range: float = 0.0,
-                 shared_width: int = 0):
+                 shared_width: int = 0, n_group: int = 1,
+                 topk_group: int = 1, routed_scaling: float = 1.0):
         super().__init__()
         init = I.Normal(0.0, initializer_range)
         out_init = I.Normal(0.0, out_initializer_range)
         e, h, f = num_experts, hidden_size, expert_width
         self.num_experts, self.top_k = e, top_k
         self.held, self.scoring = tuple(held), scoring
+        self.groups = (n_group, topk_group, float(routed_scaling))
 
         def new(name, shape, initializer=init):
             setattr(self, name, self.create_parameter(
@@ -650,7 +689,10 @@ class HeldMoEMLP(nn.Layer):
     def options(self, weights: dict) -> dict:
         """What ``held_moe`` is given beyond the stacked experts, from
         this layer's values by their names."""
+        n_group, topk_group, routed_scaling = self.groups
         return {"held": self.held, "scoring": self.scoring,
+                "n_group": n_group, "topk_group": topk_group,
+                "routed_scaling": routed_scaling,
                 "select_bias": weights.get("select_bias"),
                 "shared": tuple(weights["shared_" + k]
                                 for k in ("gate", "up", "down"))

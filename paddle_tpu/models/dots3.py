@@ -353,6 +353,10 @@ class TickRecord:
     for every tick it drains; a request that nobody watches costs nothing
     beyond the four ``stats``."""
 
+    #: the names of ``aux["stats"]``, in order (a sibling model's record
+    #: names its own)
+    STATS = TICK_STATS
+
     def __init__(self):
         #: ``watch(rid)`` says whether request ``rid`` is recorded
         #: (default: every one; a caller with many requests sets a rule)
@@ -366,7 +370,7 @@ class TickRecord:
         to a request, or None where no watched request is among them."""
         reg = _registry()
         reg.counter("serving/tick_stat_ticks").add(1)
-        for name, value in zip(TICK_STATS, np.asarray(aux["stats"])):
+        for name, value in zip(self.STATS, np.asarray(aux["stats"])):
             reg.counter("serving/tick_stat_sum{stat=%s}" % name).add(
                 float(value))
             reg.gauge("serving/tick_stat{stat=%s}" % name).set(float(value))
@@ -479,6 +483,68 @@ def state_drawer(model: Dots3):
     return drawer
 
 
+class TickRows:
+    """One tick's flat token buffer against its rows, as every latent tick
+    forward reads it (``dots3_ragged_apply`` and ``models/deepseek_v2.py``'s
+    sibling): ``nd`` decode rows of one token, then ``nch`` chunk rows of
+    ``w``; ``ps`` the page size and ``nps`` the pages of a slot's table."""
+
+    def __init__(self, ps: int, nps: int, tok_pos, tok_limit, row_pos0,
+                 nt: int, nd: int, w: int):
+        self.ps, self.nps, self.nd, self.w = ps, nps, nd, w
+        self.nch = nch = (nt - nd) // w if w else 0
+        self.row_pos0 = row_pos0
+        parts = [jnp.arange(nd, dtype=jnp.int32)]
+        if nch:
+            parts.append(jnp.repeat(nd + jnp.arange(nch, dtype=jnp.int32),
+                                    w))
+        #: the row of each flat token
+        self.tok_row = jnp.concatenate(parts)
+        self._slot_page = jnp.minimum(tok_pos // ps, nps - 1)
+        self._writes = tok_pos < tok_limit
+
+    def page_of(self, table):
+        """The page of ``table`` [R, NPs] each token writes to (the null
+        page where it writes nothing)."""
+        return jnp.where(self._writes,
+                         table[self.tok_row, self._slot_page], 0)
+
+    def touched(self, pages, table):
+        """The pages this tick's tokens write to: each decode row's and
+        the ``(w - 1) // ps + 2`` a chunk can span (null where there is
+        none)."""
+        nd, nch, w, ps, nps = self.nd, self.nch, self.w, self.ps, self.nps
+        out = [pages[:nd]]
+        if nch:
+            lp = self.row_pos0[nd:nd + nch, None] // ps + jnp.arange(
+                (w - 1) // ps + 2, dtype=jnp.int32)[None, :]
+            out.append(jnp.where(lp < nps, jnp.take_along_axis(
+                table[nd:nd + nch], jnp.minimum(lp, nps - 1), axis=1),
+                0).reshape(-1))
+        return jnp.concatenate(out)
+
+    def live(self, table, row_len):
+        """A token is live if its row holds it and the row a slot's pages
+        (a free slot's decode row rides along on the null page): it is
+        counted."""
+        tok_ix = jnp.concatenate(
+            [jnp.zeros((self.nd,), jnp.int32)]
+            + [jnp.tile(jnp.arange(self.w, dtype=jnp.int32), self.nch)]
+            * bool(self.nch))
+        return (tok_ix < row_len[self.tok_row]) \
+            & (table[self.tok_row, 0] > 0)
+
+    def groups(self, fn):
+        """``fn(rows, width)`` over the decode rows and the chunk rows,
+        back in flat-token order."""
+        outs = []
+        if self.nd:
+            outs.append(fn(slice(0, self.nd), 1))
+        if self.nch:
+            outs.append(fn(slice(self.nd, self.nd + self.nch), self.w))
+        return jax.tree.map(lambda *a: jnp.concatenate(a, 0), *outs)
+
+
 # --------------------------------------------------------------------------
 # the tick's forward
 # --------------------------------------------------------------------------
@@ -565,53 +631,18 @@ def dots3_ragged_apply(c: Dots3Config, stacked, other, pools, tokens,
     del has_chunks
     tab, wtab = row_tab
     nt, nd, w = tokens.shape[0], decode_rows, chunk_width
-    nch = (nt - nd) // w if w else 0
     ps = pools.page_size
     nps = tab.shape[1]
     eps = c.rms_norm_eps
     topk = min(c.index_topk, nps * ps)
     with annotate("tick/embed"):
         x = other["embeddings.wte.weight"][tokens]              # [NT, h]
-    parts = [jnp.arange(nd, dtype=jnp.int32)]
-    if nch:
-        parts.append(jnp.repeat(nd + jnp.arange(nch, dtype=jnp.int32), w))
-    tok_row = jnp.concatenate(parts)
-    slot_page = jnp.minimum(tok_pos // ps, nps - 1)
-    writes = tok_pos < tok_limit
-    page = jnp.where(writes, tab[tok_row, slot_page], 0)
-    wpage = jnp.where(writes, wtab[tok_row, slot_page], 0)
+    rows_ = TickRows(ps, nps, tok_pos, tok_limit, row_pos0, nt, nd, w)
+    page, wpage = rows_.page_of(tab), rows_.page_of(wtab)
     off = tok_pos % ps
-
-    def touched(pages, table):
-        """The pages this tick's tokens write to: each decode row's and
-        the ``(w - 1) // ps + 2`` a chunk can span (null where there is
-        none)."""
-        out = [pages[:nd]]
-        if nch:
-            lp = row_pos0[nd:nd + nch, None] // ps + jnp.arange(
-                (w - 1) // ps + 2, dtype=jnp.int32)[None, :]
-            out.append(jnp.where(lp < nps, jnp.take_along_axis(
-                table[nd:nd + nch], jnp.minimum(lp, nps - 1), axis=1),
-                0).reshape(-1))
-        return jnp.concatenate(out)
-
-    wrote, wwrote = touched(page, tab), touched(wpage, wtab)
-    # a token is live if its row holds it and the row a slot's pages (a
-    # free slot's decode row rides along on the null page): it is counted
-    tok_ix = jnp.concatenate(
-        [jnp.zeros((nd,), jnp.int32)]
-        + [jnp.tile(jnp.arange(w, dtype=jnp.int32), nch)] * bool(nch))
-    live = (tok_ix < row_len[tok_row]) & (tab[tok_row, 0] > 0)
-
-    def groups(fn):
-        """``fn(rows, width)`` over the decode rows and the chunk rows,
-        back in flat-token order."""
-        outs = []
-        if nd:
-            outs.append(fn(slice(0, nd), 1))
-        if nch:
-            outs.append(fn(slice(nd, nd + nch), w))
-        return jax.tree.map(lambda *a: jnp.concatenate(a, 0), *outs)
+    wrote, wwrote = rows_.touched(page, tab), rows_.touched(wpage, wtab)
+    live = rows_.live(tab, row_len)
+    groups = rows_.groups
 
     def full_attention(x, pl, p, layer):
         with annotate("blk/qkv"):

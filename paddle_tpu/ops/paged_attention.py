@@ -94,7 +94,8 @@ queries with 8,960 positions behind it, 2.6 ms for twelve decode rows of
 which six are live at 13-21 k: every row walks as far as the longest), and
 the Pallas kernel ``selected_latent_attn``, whose scores stay in VMEM
 (4.2 ms and 0.44 ms; 16.4 -> 8.6 ms of the dots3 cell's tick; PERF.md
-section 6, PR 39).
+section 6, PR 39). ``latent_attention`` (ISSUE 40) is the same pair without
+a selection: every visible position of the row, DeepSeek-V2's dense MLA.
 """
 from __future__ import annotations
 
@@ -110,7 +111,7 @@ from jax.experimental.pallas import tpu as pltpu
 __all__ = ["ragged_paged_attention", "paged_kv_scatter", "latent_scatter",
            "index_scores", "select_topk", "select_threshold",
            "selection_mask", "selected_latent_attention",
-           "window_latent_attention"]
+           "latent_attention", "window_latent_attention"]
 
 _NEG_INF = -1e9     # same masking constant as gpt_cached_apply
 
@@ -855,11 +856,49 @@ def selected_latent_attention(q, pool, layer, page_table, pos0, true_len,
                     ties, c_width, scale)
 
 
+def latent_attention(q, pool, layer, page_table, pos0, true_len,
+                     c_width: int, scale: float, impl=None):
+    """Absorbed (multi-query) attention of ragged rows over a latent pool,
+    **dense**: query ``i`` of row ``r`` attends every live position ``s <=
+    pos0[r] + i`` of its row (DeepSeek-V2's MLA, arXiv:2405.04434 section
+    2.1: no indexer, no selection), so its cost grows with the context
+    where ``selected_latent_attention``'s is capped.
+
+    The arguments, the result and the two spellings are
+    ``selected_latent_attention``'s without ``keys``, ``thr`` and ``ties``:
+    the same XLA walk (the reference) and the same Pallas kernel scheme
+    (``latent_attn``: the row's own live pages by page id, two buffers, an
+    online softmax in VMEM, nothing of extent heads x keys in HBM) with the
+    causal mask alone, no selection operand and no tie pass. Chunk rows run
+    absorbed as decode rows do: one kernel for both, at ``2 NH (W + C)``
+    operations a visible pair where expanding ``k_nope`` and ``v`` from the
+    latents would take ``2 NH (192 + 128)`` a pair and ``2 C NH 256`` a
+    visible key a call, 0.56 against 0.73 TFLOP for a chunk of 256 behind
+    10 k: too little to pay for a second kernel and the expanded keys'
+    round trip through HBM (PERF.md section 6, PR 40). The path is
+    ``latent_attention_path``'s and counted in
+    ``serving/latent_attn_calls{path=,kind=dense}``."""
+    from ..profiler import metrics
+
+    impl = latent_attention_path(q, pool, c_width, impl)
+    if impl not in ("xla", "pallas"):
+        raise ValueError(f"unknown latent attention impl {impl!r}")
+    metrics.registry().counter(
+        "serving/latent_attn_calls{path=%s,kind=dense}" % impl).add(1)
+    spelling = _selected_latent_xla if impl == "xla" \
+        else _selected_latent_pallas
+    return spelling(q, pool, layer, page_table, pos0, true_len, None, None,
+                    None, c_width, scale)
+
+
 def _selected_latent_xla(q, pool, layer, page_table, pos0, true_len,
                          keys, thr, ties, c_width: int, scale: float):
     """``selected_latent_attention`` in ``jax.numpy``: the row's live pages
     walked once, in blocks of ``_ATTN_BLOCK_PAGES`` pages, every head of
-    every query of the row scoring a block's latents in one product."""
+    every query of the row scoring a block's latents in one product.
+    ``keys`` None: no selection, every visible position
+    (``latent_attention``)."""
+    dense = keys is None
     r, t, nh = q.shape[:3]
     ps = pool.shape[-1]
     nps = page_table.shape[1]
@@ -867,7 +906,9 @@ def _selected_latent_xla(q, pool, layer, page_table, pos0, true_len,
     blocks = -(-nps // bp)
     bt = bp * ps
     table = jnp.pad(page_table, ((0, 0), (0, blocks * bp - nps)))
-    keys = jnp.pad(keys, ((0, 0), (0, 0), (0, blocks * bt - keys.shape[2])))
+    if not dense:
+        keys = jnp.pad(keys,
+                       ((0, 0), (0, 0), (0, blocks * bt - keys.shape[2])))
     live = jnp.where(true_len > 0, jnp.minimum(pos0 + true_len, nps * ps), 0)
     qpos = pos0[:, None] + jnp.arange(t, dtype=pos0.dtype)[None, :]
     last = jnp.minimum(qpos, live[:, None] - 1)             # [R, T]
@@ -882,11 +923,16 @@ def _selected_latent_xla(q, pool, layer, page_table, pos0, true_len,
                         _block_of_pages(pool, layer, pages), 0)
         lat = lat if lat.dtype == q.dtype else lat.astype(q.dtype)
         s = _einsum_f32("rtnc,rcs->rtns", q, lat) * scale
-        mine = jax.lax.dynamic_slice(keys, (0, 0, b * bt), (r, t, bt))
+        if not dense:
+            mine = jax.lax.dynamic_slice(keys, (0, 0, b * bt), (r, t, bt))
         seen = kpos[None, None, :] <= last[:, :, None]
-        tie = seen & (mine == thr[:, :, None])
-        taken = tie & (jnp.cumsum(tie, axis=-1) <= left[:, :, None])
-        keep = ((seen & (mine > thr[:, :, None])) | taken)[:, :, None, :]
+        if dense:
+            keep = seen[:, :, None, :]
+        else:
+            tie = seen & (mine == thr[:, :, None])
+            taken = tie & (jnp.cumsum(tie, axis=-1) <= left[:, :, None])
+            keep = ((seen & (mine > thr[:, :, None]))
+                    | taken)[:, :, None, :]
         s = jnp.where(keep, s, _NEG_INF)
         m_new = jnp.maximum(m, jnp.max(s, axis=-1))
         p = jnp.where(keep, jnp.exp(s - m_new[..., None]), 0.0)
@@ -894,14 +940,15 @@ def _selected_latent_xla(q, pool, layer, page_table, pos0, true_len,
         l = corr * l + jnp.sum(p, axis=-1)
         acc = corr[..., None] * acc + _einsum_f32(
             "rtns,rcs->rtnc", p.astype(q.dtype), lat[:, :c_width])
-        return m_new, l, acc, left - jnp.sum(tie, axis=-1).astype(left.dtype)
+        return m_new, l, acc, left if dense else \
+            left - jnp.sum(tie, axis=-1).astype(left.dtype)
 
     n_live = jnp.minimum(-(-jnp.max(live) // bt), blocks)
     _, l, acc, _ = jax.lax.fori_loop(0, n_live, block, (
         jnp.full((r, t, nh), _NEG_INF, jnp.float32),
         jnp.zeros((r, t, nh), jnp.float32),
         jnp.zeros((r, t, nh, c_width), jnp.float32),
-        ties.astype(jnp.int32)))
+        jnp.zeros((), jnp.int32) if dense else ties.astype(jnp.int32)))
     return (acc / jnp.where(l == 0.0, 1.0, l)[..., None]).astype(q.dtype)
 
 
@@ -1024,7 +1071,8 @@ def _latent_kernel(pt_ref, pos0_ref, tl_ref, layer_ref, q_ref, keys_ref,
     float32; the selection's mask is made once a query from its ``keys``,
     ``thr`` and last taken tie, and laid over its heads; running maximum,
     sum and accumulator are float32, the weights meet the latents again in
-    the pool's type."""
+    the pool's type. ``keys_ref`` None (``_dense_latent_kernel``): no
+    selection, the causal mask alone."""
     _, width, bt = buf.shape
     tq, nh = q_ref.shape[1:3]
     bp = bt // ps
@@ -1075,7 +1123,8 @@ def _latent_kernel(pt_ref, pos0_ref, tl_ref, layer_ref, q_ref, keys_ref,
     l_ref[...] = jnp.zeros_like(l_ref)
     acc_ref[...] = jnp.zeros_like(acc_ref)
     q = q_ref[0].reshape(rows, width)
-    thr, cut = thr_ref[0], cut_ref[0]                           # [tq, 1]
+    if keys_ref is not None:
+        thr, cut = thr_ref[0], cut_ref[0]                       # [tq, 1]
     qpos = pos0_ref[r] + j * tq + jax.lax.broadcasted_iota(
         jnp.int32, (tq, bt), 0)
     last = jnp.minimum(qpos, n_vis - 1)
@@ -1105,10 +1154,11 @@ def _latent_kernel(pt_ref, pos0_ref, tl_ref, layer_ref, q_ref, keys_ref,
 
         s_ref[...] = _dot(q, lat[...].astype(q.dtype),
                           (((1,), (0,)), ((), ())))             # [rows, bt]
-        mine = keys_ref[0, :, pl.ds(pl.multiple_of(b * bt, bt), bt)]
         kpos = b * bt + jax.lax.broadcasted_iota(jnp.int32, (tq, bt), 1)
-        keep = (kpos <= last) & ((mine > thr)
-                                 | ((mine == thr) & (kpos <= cut)))
+        keep = kpos <= last
+        if keys_ref is not None:
+            mine = keys_ref[0, :, pl.ds(pl.multiple_of(b * bt, bt), bt)]
+            keep = keep & ((mine > thr) | ((mine == thr) & (kpos <= cut)))
         bias = jnp.where(keep, 0.0, _NEG_INF)                   # [tq, bt]
         for i in range(tq):         # a query's heads share its mask
             at = slice(i * nh, (i + 1) * nh)
@@ -1143,8 +1193,18 @@ def _latent_kernel(pt_ref, pos0_ref, tl_ref, layer_ref, q_ref, keys_ref,
         tq, nh, c_width).astype(o_ref.dtype)
 
 
+def _dense_latent_kernel(pt_ref, pos0_ref, tl_ref, layer_ref, q_ref,
+                         pool_hbm, *rest, **sizes):
+    """``_latent_kernel`` with no selection operand."""
+    _latent_kernel(pt_ref, pos0_ref, tl_ref, layer_ref, q_ref, None, None,
+                   None, pool_hbm, *rest, **sizes)
+
+
 def _selected_latent_pallas(q, pool, layer, page_table, pos0, true_len,
                             keys, thr, ties, c_width: int, scale: float):
+    """The kernel's call; ``keys`` None: ``latent_attention``'s, under the
+    name ``latent_attn``, without the three selection operands."""
+    dense = keys is None
     r, t, nh, width = q.shape
     ps = pool.shape[-1]
     nps = page_table.shape[1]
@@ -1152,13 +1212,15 @@ def _selected_latent_pallas(q, pool, layer, page_table, pos0, true_len,
     bt = bp * ps
     tq = _latent_tile_queries(t, nh)
     cap = nps * ps
-    live = jnp.where(true_len > 0, jnp.minimum(pos0 + true_len, cap), 0)
-    qpos = pos0[:, None] + jnp.arange(t, dtype=pos0.dtype)[None, :]
-    cut = _last_taken_tie(keys, thr, ties.astype(jnp.int32),
-                          jnp.minimum(qpos, live[:, None] - 1), ps)
-    if cap % bt:        # a block of keys is sliced whole
-        keys = jnp.pad(keys, ((0, 0), (0, 0), (0, -cap % bt)))
-    span = keys.shape[2]
+    selection = ()
+    if not dense:
+        live = jnp.where(true_len > 0, jnp.minimum(pos0 + true_len, cap), 0)
+        qpos = pos0[:, None] + jnp.arange(t, dtype=pos0.dtype)[None, :]
+        cut = _last_taken_tie(keys, thr, ties.astype(jnp.int32),
+                              jnp.minimum(qpos, live[:, None] - 1), ps)
+        if cap % bt:        # a block of keys is sliced whole
+            keys = jnp.pad(keys, ((0, 0), (0, 0), (0, -cap % bt)))
+        selection = (keys, thr[..., None], cut[..., None])
 
     def tile(*block):
         return pl.BlockSpec((1, tq) + block, lambda i, j, *_: (i, j)
@@ -1167,8 +1229,9 @@ def _selected_latent_pallas(q, pool, layer, page_table, pos0, true_len,
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,
         grid=(r, t // tq),
-        in_specs=[tile(nh, width), tile(span), tile(1), tile(1),
-                  pl.BlockSpec(memory_space=pl.ANY)],
+        in_specs=[tile(nh, width)]
+        + [tile(*x.shape[2:]) for x in selection]
+        + [pl.BlockSpec(memory_space=pl.ANY)],
         out_specs=tile(nh, c_width),
         scratch_shapes=[
             pltpu.VMEM((2, width, bt), pool.dtype),
@@ -1181,9 +1244,9 @@ def _selected_latent_pallas(q, pool, layer, page_table, pos0, true_len,
             pltpu.VMEM((tq * nh, 1), jnp.float32),      # a block's rescale
             pltpu.VMEM((tq * nh, c_width), jnp.float32)])
     return pl.pallas_call(
-        functools.partial(_latent_kernel, scale=scale, c_width=c_width,
-                          ps=ps),
-        name="selected_latent_attn",
+        functools.partial(_dense_latent_kernel if dense else _latent_kernel,
+                          scale=scale, c_width=c_width, ps=ps),
+        name="latent_attn" if dense else "selected_latent_attn",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((r, t, nh, c_width), q.dtype),
         compiler_params=pltpu.CompilerParams(
@@ -1191,4 +1254,4 @@ def _selected_latent_pallas(q, pool, layer, page_table, pos0, true_len,
             vmem_limit_bytes=64 * 2 ** 20),
         interpret=_interpret(),
     )(page_table, pos0, true_len, jnp.asarray(layer, jnp.int32).reshape(1),
-      q, keys, thr[..., None], cut[..., None], pool)
+      q, *selection, pool)

@@ -929,7 +929,9 @@ class LatentPools(NamedTuple):
                                       the window (``LatentPagePool``)
 
     A page's tokens lie along the last axis (``ops/paged_attention`` says
-    why).
+    why). A model with no indexer or no windowed layer (DeepSeek-V2,
+    ``models/deepseek_v2.py``: dense latent attention in every layer) has
+    ``index_k`` or ``window`` of no bytes: width 0, no layers.
 
     A pytree like ``Pools``: the tick takes and returns it as one donated
     argument and its layer scans carry it. The writes and reads are
@@ -978,10 +980,14 @@ class LatentPagePool(PagePool):
     a slot), so it never binds: admission, exhaustion and preemption are
     decided by the full layers' pages alone.
 
+    A model without windowed layers (``window_layers`` 0) has no window
+    space: no page of it is allocated, grown or freed.
+
     What it lacks is refused by name: a prefix cache (a cached page would
     have to say which layers it serves: a windowed layer's page is gone
-    once the window has passed), speculative rewinds (``shrink_slot``) and
-    auxiliary tables."""
+    once the window has passed; and without windowed layers
+    ``share_into_slot`` and ``copy_page`` are still not written over latent
+    pools), speculative rewinds (``shrink_slot``) and auxiliary tables."""
 
     #: False keeps every windowed page for the slot's life (the window
     #: space is then as large as the full layers'): what the tests compare
@@ -991,22 +997,31 @@ class LatentPagePool(PagePool):
     def __init__(self, caches: dict, num_pages: int, page_size: int,
                  num_slots: int, pages_per_slot: int, chunk: int,
                  dtype=jnp.float32, prefix_cache: bool = False):
-        if prefix_cache:
+        windowed = caches.get("window_layers", 0)
+        if prefix_cache and windowed:
             raise NotImplementedError(
                 "prefix_cache=True with windowed layers: PrefixCache shares "
                 "a page into every layer's pool, and a windowed layer's "
                 "page is given back once the window has passed it; pass "
                 "prefix_cache=False (ROADMAP R4)")
-        self.window = int(caches["window"])
+        if prefix_cache:
+            raise NotImplementedError(
+                "prefix_cache=True over latent pools: sharing a cached page "
+                "into a slot (share_into_slot) and copying a shared page "
+                "before a write (LatentPools.copy_page) are not written "
+                "for them; pass prefix_cache=False (ROADMAP R4)")
+        self.window = int(caches.get("window", 0))
         held = -(-(self.window - 1 + chunk) // page_size) + 2
-        self.window_pages_per_slot = min(pages_per_slot, held) \
-            if self.FREE_BEHIND else pages_per_slot
-        window_pages = num_slots * self.window_pages_per_slot + 1
+        self.window_pages_per_slot = 0 if not windowed else min(
+            pages_per_slot, held) if self.FREE_BEHIND else pages_per_slot
+        # (an allocator wants two pages; of no layers they are no bytes)
+        window_pages = max(num_slots * self.window_pages_per_slot + 1, 2)
         pools = LatentPools.zeros(
-            caches["full_layers"], num_pages, caches["window_layers"],
+            caches["full_layers"], num_pages, windowed,
             window_pages, page_size, caches["latent_width"],
-            caches["index_width"], caches["window_width"], dtype)
-        super().__init__(caches["full_layers"] + caches["window_layers"],
+            caches.get("index_width", 0), caches.get("window_width", 0),
+            dtype)
+        super().__init__(caches["full_layers"] + windowed,
                          num_pages, page_size, 1, caches["latent_width"],
                          num_slots, pages_per_slot, dtype=dtype, pools=pools)
         self.window_allocator = PageAllocator(window_pages)
@@ -1016,8 +1031,10 @@ class LatentPagePool(PagePool):
             {} for _ in range(num_slots)]
 
     def live_shares(self) -> Dict[str, float]:
-        return {"latent": self.allocator.utilization(),
-                "window": self.window_allocator.utilization()}
+        shares = {"latent": self.allocator.utilization()}
+        if self.window_pages_per_slot:
+            shares["window"] = self.window_allocator.utilization()
+        return shares
 
     def row_tables(self, rows):
         return (_rows_of(self.tables, rows),
@@ -1032,6 +1049,8 @@ class LatentPagePool(PagePool):
         windowed layers."""
         if n_pages <= 0:
             return True
+        if not self.window_pages_per_slot:
+            return super().grow_slot(slot, n_pages)
         first = len(self._held[slot])
         if self.window_allocator.num_free < n_pages \
                 or not super().grow_slot(slot, n_pages):
@@ -1046,7 +1065,7 @@ class LatentPagePool(PagePool):
         """Give back the slot's windowed pages that lie wholly behind the
         window of every query at or past position ``frontier`` (such a
         query sees positions ``> frontier - window``). Returns how many."""
-        if not self.FREE_BEHIND:
+        if not self.FREE_BEHIND or not self.window_pages_per_slot:
             return 0
         oldest = max(frontier - self.window + 1, 0) // self.page_size
         held = self._window_held[slot]

@@ -200,6 +200,27 @@ def test_latent_rehearsal():
         <= chip_smoke.TOL_LATENT_OPS
 
 
+def test_dsv2_rehearsal():
+    """DeepSeek-V2's pass at toy sizes: the dense latent attention through
+    both spellings against a float32 softmax, a small model's tokens (two
+    chunks a tick) against the float32 reference."""
+    import jax.numpy as jnp
+
+    from paddle_tpu.models.deepseek_v2 import DeepseekV2Config
+
+    out = chip_smoke.phase_dsv2(
+        DeepseekV2Config.tiny(experts_held=(4, 4)), 3, 4, 24,
+        [(2, 5, 3, 12, 8, 4, 10, jnp.bfloat16),
+         (3, 1, 3, 12, 8, 4, 10, jnp.bfloat16)])
+    # off the chip the tick's attention is the XLA walk, and the kernel ran
+    # interpreted beside it
+    assert set(out["tick_paths"]) == {"xla"}
+    assert max(out["dense"], out["dense_pallas"]) <= chip_smoke.TOL_LATENT_OPS
+    assert out["median"] <= out["worst"] <= chip_smoke.TOL_LATENT_SHORTFALL
+    cfg = DeepseekV2Config.tiny(experts_held=(4, 4))
+    assert out["weights_bytes"] == 2 * cfg.num_params()
+
+
 def test_a_submit_behind_the_ticks_in_flight_fails_the_serve_phase():
     limit = chip_smoke.SUBMIT_LIMIT_MS
     # with nothing in flight a slow submit proves nothing

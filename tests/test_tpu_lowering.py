@@ -555,3 +555,63 @@ def test_tpu_compile_the_latent_models_forward(monkeypatch):
     pool_bytes = sum(int(np.prod(p.shape)) * 2 for p in pools)
     assert ma.alias_size_in_bytes >= pool_bytes, \
         "the donated latent pools are not aliased"
+
+
+def test_tpu_compile_the_dense_latent_models_forward(monkeypatch):
+    """ISSUE 40: DeepSeek-V2's tick forward
+    (``models/deepseek_v2.deepseek_v2_ragged_apply``: latent pools alone,
+    dense latent attention, the group-limited router, the held experts'
+    grouped matmuls, two chunk rows) compiles for the v5e from a
+    ``LazyGuard`` model, its attention the Pallas kernel ``latent_attn``
+    twice a layer (decode rows, chunk rows) with no selection operand, no
+    score block of extent heads x keys an array of the program, the donated
+    pool aliased. Published head counts and latent widths over a small
+    hidden size and one group of experts held."""
+    import jax.numpy as jnp
+
+    import paddle_tpu as paddle
+    from paddle_tpu.models.deepseek_v2 import (DeepseekV2, DeepseekV2Config,
+                                               deepseek_v2_ragged_apply)
+    from paddle_tpu.models.dots3 import state_drawer
+    from paddle_tpu.serving.paged_cache import LatentPools
+
+    dev = _tpu_topology_devices()[0]
+    cfg = DeepseekV2Config(
+        vocab_size=512, hidden_size=256, intermediate_size=512,
+        moe_intermediate_size=128, num_hidden_layers=2, n_routed_experts=16,
+        experts_held=(0, 2), q_lora_rank=128)
+    with paddle.LazyGuard():
+        net = DeepseekV2(cfg)
+    net.bfloat16()
+    state = jax.eval_shape(state_drawer(net),
+                           jax.ShapeDtypeStruct((2,), jnp.uint32))
+    ns, ps, nps, w, nch = 4, 128, 24, 256, 2
+    spec = net.cache_spec()
+    pools = jax.eval_shape(lambda: LatentPools.zeros(
+        spec["full_layers"], ns * nps + 1, 0, 2, ps, spec["latent_width"],
+        0, 0, jnp.bfloat16))
+    nt, rows = ns + nch * w, ns + nch
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32)
+
+    args = (state[0], state[1], pools, i32(nt), i32(nt), i32(nt),
+            (i32(rows, nps), i32(rows, nps)), i32(rows), i32(rows), i32(ns))
+    args = jax.tree_util.tree_map(
+        lambda a: _on_tpu(dev, a.shape, a.dtype), args)
+
+    def forward(*a):
+        return deepseek_v2_ragged_apply(cfg, *a, decode_rows=ns,
+                                        chunk_width=w)
+
+    monkeypatch.setenv("PADDLE_TPU_TARGET_PLATFORM", "tpu")
+    with jax.default_matmul_precision("default"):
+        compiled = jax.jit(forward, donate_argnums=2).lower(*args).compile()
+    text = compiled.as_text()
+    assert re.findall(r"%moe_gmm[\w.\-]* = ", text)
+    assert len(re.findall(r"%latent_attn[\w.\-]* = ", text)) == 4
+    assert not re.findall(r"%selected_latent_attn[\w.\-]* = ", text)
+    assert not re.findall(r"f32\[\d+,\d+,128,\d{3,}\]", text)
+    ma = compiled.memory_analysis()
+    assert ma.alias_size_in_bytes >= int(np.prod(pools.latent.shape)) * 2, \
+        "the donated latent pool is not aliased"
