@@ -24,7 +24,9 @@ finds, and checks what comes out by the repo's own means:
               layers, 16 experts of which 8 held) through the trainer in
               bf16: the loss against the float32 reference, and the scan
               took the path production takes here, the Pallas kernels
-              kda_fwd/kda_bwd_*
+              kda_fwd/kda_bwd_*; the chain between the projections and
+              the scan (kda_prep/kda_prep_bwd) at the training cell's
+              [1, 8192, 8192] bf16 against its jax.numpy spelling
   1d head     the fused lm-head + cross-entropy at the four training
               cells' shapes (tied [V, H] and Linear [H, V], a vocabulary
               shard of 25152 as 6.7B's): loss and both gradients against
@@ -1433,9 +1435,64 @@ def scan_against_recurrence(seq: int) -> dict:
     return rel
 
 
-def phase_solar(seq: int, scan_path: str) -> dict:
+# what lies between the projections and the scan (ops/kda_prep.py), bf16,
+# operands and the six gradients against the jax.numpy spelling, ||difference||
+# / ||reference||: the spelling rounds to bf16 after the SiLU and again after
+# the norm, the kernel once (seen on the v5e, PR 41: 2.7e-3 to 2.9e-3)
+TOL_SOLAR_PREP = 1e-2
+
+
+def prep_against_spelling(shape, path: str) -> dict:
+    """``kda_prep`` by the path observed here (on the chip: the Pallas pair
+    ``kda_prep``/``kda_prep_bwd``) against ``xla_kda_prep``, forward and
+    ``jax.vjp`` towards the three projections and the three tap matrices;
+    ``shape`` [b, s, heads * 128]. The worst relative distance."""
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu import profiler
+    from paddle_tpu.ops import kda_prep as kp
+
+    ks = jax.random.split(jax.random.PRNGKey(5), 9)
+    bf = lambda a: a.astype(jnp.bfloat16)
+    ps = tuple(bf(jax.random.normal(k, shape)) for k in ks[:3])
+    ws = tuple(bf(jax.random.uniform(k, (4, shape[2]), minval=-0.5,
+                                     maxval=0.5)) for k in ks[3:6])
+    cs = tuple(bf(jax.random.normal(k, shape)) for k in ks[6:])
+
+    def both_ways(chain):
+        def run(ps, ws, cs):
+            out, vjp = jax.vjp(
+                lambda p, w: chain(p, w, (True, True, False), 128, 1e-6),
+                ps, ws)
+            return out, vjp(cs)
+        return jax.tree.leaves(jax.jit(run)(ps, ws, cs))
+
+    profiler.reset()
+    got = both_ways(kp.kda_prep)
+    took = sorted(k for k in profiler.summary()["metrics"]
+                  if k.startswith("kda/prep_calls"))
+    check(took == ["kda/prep_calls{path=%s}" % path],
+          f"solar: the chain before the scan took {took}, not the {path} "
+          "path")
+    names = ("q", "k", "v", "dp_q", "dp_k", "dp_v", "dconv_q", "dconv_k",
+             "dconv_v")
+    rel = {}
+    for n, a, w in zip(names, got, both_ways(kp.xla_kda_prep)):
+        w = w.astype(jnp.float32)
+        rel[n] = float(jnp.linalg.norm(a.astype(jnp.float32) - w)
+                       / jnp.linalg.norm(w))
+        check(rel[n] <= TOL_SOLAR_PREP,
+              f"solar: the chain's {n} is {rel[n]:.2e} from the spelling's "
+              f"(tol {TOL_SOLAR_PREP})")
+    return rel
+
+
+def phase_solar(seq: int, scan_path: str, prep_shape) -> dict:
     """One step at learning rate 0 of ``SolarOpen2Config.tiny`` (heads of
-    128, so the Pallas scan takes them) with experts 4..11 of 16 held."""
+    128, so the Pallas scan takes them) with experts 4..11 of 16 held;
+    the scan alone against the recurrence; the chain between the
+    projections and the scan at ``prep_shape`` against its spelling."""
     import jax
 
     import paddle_tpu as paddle
@@ -1478,12 +1535,13 @@ def phase_solar(seq: int, scan_path: str) -> dict:
     got = float(tr.step(tokens))
     stats = jax.device_get(tr.aux_stats)
     calls = {k: v["value"] for k, v in profiler.summary()["metrics"].items()
-             if k.startswith("kda/scan_calls")}
+             if k.startswith(("kda/scan_calls", "kda/prep_calls"))}
     rel = abs(got - want) / abs(want)
     check(rel <= TOL_SOLAR_LOSS, f"solar: the trainer's loss {got:.5f} is "
           f"{rel:.2e} from the reference's {want:.5f}")
-    check(set(calls) == {"kda/scan_calls{path=%s}" % scan_path},
-          f"solar: the scan took {sorted(calls)}, not the {scan_path} path")
+    check(set(calls) == {"kda/scan_calls{path=%s}" % scan_path,
+                         "kda/prep_calls{path=%s}" % scan_path},
+          f"solar: the layer took {sorted(calls)}, not the {scan_path} path")
     check(stats["moe/routed"] == tokens.size * cfg.num_experts_per_tok * 4
           and stats["moe/assigned"] == stats["moe/rows"].sum(),
           f"solar: the step's counts do not add up: {stats}")
@@ -1495,7 +1553,11 @@ def phase_solar(seq: int, scan_path: str) -> dict:
     say("solar", "the scan forward and backward against the recurrence, g "
         "down to its floor: " + ", ".join(
             f"{n} {r:.1e}" for n, r in scan.items()))
-    return {"loss": got, "rel": rel, "scan": scan}
+    prep = prep_against_spelling(prep_shape, scan_path)
+    say("solar", f"the chain between the projections and the scan at "
+        f"{list(prep_shape)} bf16 through {scan_path}, against its spelling: "
+        + ", ".join(f"{n} {r:.1e}" for n, r in prep.items()))
+    return {"loss": got, "rel": rel, "scan": scan, "prep": prep}
 
 
 # ---------------------------------------------------------------------------
@@ -1534,7 +1596,8 @@ def main() -> int:
     run("experts", lambda: phase_experts(
         olmoe.max_seq_len, olmoe.hidden_size, olmoe.moe_expert_width,
         olmoe.moe_num_experts, olmoe.moe_top_k))
-    run("solar", lambda: phase_solar(256, "pallas"))
+    # the chain at the training cell's shapes: 8,192 tokens, 64 heads of 128
+    run("solar", lambda: phase_solar(256, "pallas", (1, 8192, 8192)))
     run("head", lambda: phase_head(HEAD_SHAPES))
     run("serve", lambda: phase_serve(cfg, slots, page, SERVE_REQUESTS))
     # the looped model at its published widths, 3 of its 48 layers

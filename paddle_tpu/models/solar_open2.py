@@ -45,6 +45,9 @@ from ..framework.tensor import Tensor
 from ..nn import initializer as I
 from ..ops import flash_attention as _fa
 from ..ops.kda import kda_attention_flat
+from ..ops.kda_prep import causal_conv  # noqa: F401  (tests read it here)
+from ..ops.kda_prep import (head_sums as _head_sums, kda_prep,
+                            over_heads as _over_heads)
 from ..profiler.trace import annotate
 from ..tensor._helper import apply
 
@@ -196,55 +199,19 @@ def rms_norm(x, w, eps):
             * w.astype(_F32)).astype(x.dtype)
 
 
-def causal_conv(x, w):
-    """Depthwise causal convolution over the sequence, no bias: ``y_t =
-    sum_j w[j] x_{t - (taps - 1 - j)}``; x [b, s, c], w [taps, c]. The last
-    tap multiplies the token itself (fla ``ShortConvolution``)."""
-    taps, s = w.shape[0], x.shape[1]
-    xp = jnp.pad(x.astype(_F32), ((0, 0), (taps - 1, 0), (0, 0)))
-    wf = w.astype(_F32)
-    return sum(xp[:, j:j + s] * wf[j] for j in range(taps))
-
-
-def _head_sums(x, heads: int):
-    """Sums over each head's columns of ``x`` [b, s, heads * d] ->
-    [b, s, heads] float32, as a product with a 0/1 matrix: the activations
-    stay ``[b, s, heads * d]`` from the projection to the scan (a view a
-    head ``[b, s, heads, d]`` is another layout on the TPU and costs a
-    copy of every such array)."""
-    d = x.shape[-1] // heads
-    seg = (jnp.arange(heads * d)[:, None] // d
-           == jnp.arange(heads)[None, :]).astype(x.dtype)
-    return jnp.dot(x, seg, preferred_element_type=_F32)
-
-
-def _over_heads(a, d: int):
-    """``a`` [b, s, heads] repeated over each head's ``d`` columns, exactly:
-    the same 0/1 product the other way, at full precision."""
-    heads = a.shape[-1]
-    seg = (jnp.arange(heads)[:, None]
-           == jnp.arange(heads * d)[None, :] // d).astype(_F32)
-    return jnp.dot(a.astype(_F32), seg,
-                   precision=jax.lax.Precision.HIGHEST)
-
-
 def kda_mix(x, w, c: SolarOpen2Config):
-    """The linear-attention half of a layer on the normalised input."""
+    """The linear-attention half of a layer on the normalised input. What
+    turns the three projections into the scan's operands (short
+    convolution, SiLU, q's and k's ``l2norm`` a head) is ``ops/kda_prep.py``:
+    one Pallas pass each way over the raw bf16 projections on the chip, its
+    ``jax.numpy`` spelling elsewhere; the products stay three, and ``g``,
+    ``beta`` and ``gate`` stay here, fused by XLA into their products."""
     heads, d = c.linear_attn_num_heads, c.linear_attn_head_dim
     dt = x.dtype
     with annotate("blk/kda/proj"):
-        def branch(proj, conv):
-            return jax.nn.silu(
-                causal_conv(jnp.dot(x, w[proj]), w[conv])).astype(dt)
-
-        def l2norm(y):
-            yf = y.astype(_F32)
-            inv = jax.lax.rsqrt(_head_sums(yf * yf, heads) + L2_EPS)
-            return (yf * _over_heads(inv, d)).astype(dt)
-
-        q = l2norm(branch("w_q", "conv_q"))
-        k = l2norm(branch("w_k", "conv_k"))
-        v = branch("w_v", "conv_v")
+        q, k, v = kda_prep(
+            [jnp.dot(x, w["w_" + n]) for n in "qkv"],
+            [w["conv_" + n] for n in "qkv"], (True, True, False), d, L2_EPS)
         f = jnp.dot(jnp.dot(x, w["w_f1"]), w["w_f2"]).astype(_F32) \
             + w["dt_bias"].astype(_F32)
         g = -jnp.repeat(jnp.exp(w["A_log"].astype(_F32)), d) \

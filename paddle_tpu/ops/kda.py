@@ -9,7 +9,13 @@ Attention, arXiv:2510.26692; ``fla.ops.kda``), chunked, forward and backward.
     o_t = S_t^T q_t / sqrt(dk)
 
 ``g <= 0`` is the log of the decay, ``beta`` in [0, 2]. Nothing here
-normalises q or k: the layer does (models/solar_open2.py). **The decay has
+normalises q or k: what turns a layer's three projections into this scan's
+operands (short convolution, SiLU, q's and k's ``l2norm`` a head) lives in
+``ops/kda_prep.py``, one Pallas pass each way (``kda_prep``,
+``kda_prep_bwd``: names that start with neither ``kda_fwd`` nor ``kda_bwd``,
+which a trace's reader takes for this file's kernels), called by
+``models/solar_open2.kda_mix`` just before ``kda_attention_flat``. ``g``
+comes here in float32, as the layer makes it. **The decay has
 a floor**: ``g`` is taken as ``max(g, G_MIN)``, ``G_MIN = -9`` a token and
 channel (a decay of 1.2e-4, under what a bf16 operand keeps of the state it
 multiplies; fla's chunked kernel asks ``g >= -5`` of its caller for the same

@@ -121,12 +121,32 @@ SERVE_REQUESTS = ((0, 6, 8, 0), (0, 40, 8, 16), (0, 120, 6, 0), (2, 9, 10, 0),
 def test_solar_rehearsal():
     """The small Solar-Open2's step against its reference; on the CPU the
     scan takes the ``jax.numpy`` path, and the phase says which it saw."""
-    out = chip_smoke.phase_solar(128, "xla")
+    out = chip_smoke.phase_solar(128, "xla", (1, 64, 256))
     assert out["rel"] <= chip_smoke.TOL_SOLAR_LOSS
     assert all(out["scan"][n] <= tol
                for n, tol in chip_smoke.TOL_SOLAR_SCAN.items()), out["scan"]
+    assert len(out["prep"]) == 9 and max(out["prep"].values()) == 0.0
     with pytest.raises(AssertionError, match="not the pallas path"):
-        chip_smoke.phase_solar(128, "pallas")
+        chip_smoke.phase_solar(128, "pallas", (1, 64, 256))
+
+
+def test_the_prep_check_sees_a_fault_in_the_backward_pass(monkeypatch):
+    """The chain's kernels interpreted where the phase compares them with
+    the spelling, one tap's gradient a tenth short: the phase names it."""
+    from paddle_tpu.ops import kda_prep
+
+    real = kda_prep._pallas_bwd
+
+    def planted(*a):
+        dps, dws = real(*a)
+        return dps, (dws[0], 0.9 * dws[1], dws[2])
+
+    monkeypatch.setattr(kda_prep, "prep_path", lambda *a: "pallas")
+    assert max(chip_smoke.prep_against_spelling(
+        (1, 64, 256), "pallas").values()) <= chip_smoke.TOL_SOLAR_PREP
+    monkeypatch.setattr(kda_prep, "_pallas_bwd", planted)
+    with pytest.raises(AssertionError, match="the chain's dconv_k "):
+        chip_smoke.prep_against_spelling((1, 64, 256), "pallas")
 
 
 @pytest.mark.parametrize("at,name", [(1, "dk"), (3, "dg"), (4, "dbeta")])

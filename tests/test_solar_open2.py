@@ -23,6 +23,7 @@ from paddle_tpu.models import solar_open2_reference as ref
 from paddle_tpu.models.solar_open2 import SolarOpen2, SolarOpen2Config
 from paddle_tpu.ops import flash_attention as fa
 from paddle_tpu.ops import kda
+from paddle_tpu.ops import kda_prep
 from paddle_tpu.static.functional import state_tensors
 
 HELD = (4, 8)          # experts 4..11 of the small model's 16
@@ -158,6 +159,150 @@ def test_the_scans_path_is_observed_and_counted():
     seen = profiler.summary()["metrics"]
     assert seen["kda/scan_calls{path=xla}"]["value"] == 1
     assert "kda/scan_calls{path=pallas}" not in seen
+
+
+# --- between the projections and the scan (ops/kda_prep.py) ---------------------
+NORMS = (True, True, False)
+
+
+def prep_inputs(seed, b, s, width, dtype, cot_rows=None):
+    """Three projections, their taps and three cotangents (zero below row
+    ``cot_rows`` on, where given)."""
+    ks = jax.random.split(jax.random.PRNGKey(seed), 9)
+    ps = tuple(jax.random.normal(k, (b, s, width)).astype(dtype)
+               for k in ks[:3])
+    ws = tuple(jax.random.uniform(k, (4, width), minval=-0.5,
+                                  maxval=0.5).astype(dtype)
+               for k in ks[3:6])
+    cs = tuple(jax.random.normal(k, (b, s, width)) for k in ks[6:])
+    if cot_rows is not None:
+        cs = tuple(c.at[:, cot_rows:].set(0.0) for c in cs)
+    return ps, ws, tuple(c.astype(dtype) for c in cs)
+
+
+def prep_both_ways(chain, ps, ws, cs, norms=NORMS):
+    """Outputs, gradients towards the projections and towards the taps."""
+    out, vjp = jax.vjp(lambda p, w: chain(p, w, norms, 128, prog.L2_EPS),
+                       ps, ws)
+    return jax.tree.leaves((out, vjp(cs)))
+
+
+#: (sequence, width, batch, norms, cotangent's rows, rows a block): one row
+#: block and two column tiles; three blocks of 176 rows, so the history
+#: crosses a block's edge forward and the rows after it backward; 17 blocks
+#: of one tile's 16 rows over two sequences (the tap sums over batch and
+#: row blocks); cotangents on the first three rows alone, whose history is
+#: zeros; no branch with a norm
+PREP_CASES = {
+    "one-block": (64, 256, 1, NORMS, None, 2048),
+    "three-blocks": (528, 256, 1, NORMS, None, 176),
+    "blocks-of-a-tile": (272, 128, 2, NORMS, None, 16),
+    "first-three-rows": (512, 256, 1, NORMS, 3, 256),
+    "no-norm": (512, 128, 1, (False, False, False), None, 256),
+}
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("case", sorted(PREP_CASES))
+def test_prep_kernels_equal_the_spelling(monkeypatch, case, dtype):
+    """``kda_prep``/``kda_prep_bwd`` interpreted against the ``jax.numpy``
+    chain: the operands and the gradients towards the three projections
+    and the three tap matrices. float32: the same arithmetic in another
+    order. bf16: the spelling rounds after the SiLU and after the norm and
+    the kernel once, so they differ by the spelling's own rounding."""
+    s, width, b, norms, cot_rows, rows = PREP_CASES[case]
+    monkeypatch.setattr(kda_prep, "_ROWS", rows)    # the most rows a block
+    assert kda_prep._blocks((b, s, width), 128)[:2] == (min(rows, s), 128)
+    ps, ws, cs = prep_inputs(len(case), b, s, width, dtype, cot_rows)
+    got = prep_both_ways(kda_prep.pallas_kda_prep, ps, ws, cs, norms)
+    want = prep_both_ways(kda_prep.xla_kda_prep, ps, ws, cs, norms)
+    assert len(got) == len(want) == 9
+    for a, w in zip(got, want):
+        assert a.shape == w.shape and a.dtype == w.dtype
+        a, w = np.asarray(a, np.float32), np.asarray(w, np.float32)
+        if dtype == "float32":
+            np.testing.assert_allclose(a, w, rtol=0,
+                                       atol=1e-5 * np.abs(w).max())
+        else:
+            assert np.linalg.norm(a - w) <= 6e-3 * np.linalg.norm(w)
+            np.testing.assert_allclose(a, w, rtol=0,
+                                       atol=2.0 ** -6 * np.abs(w).max())
+    if cot_rows is not None:
+        # what the first rows' cotangents reach: themselves and no row after
+        assert all(float(jnp.abs(d[:, cot_rows:]).max()) == 0
+                   for d in got[3:6])
+        assert all(float(jnp.abs(d[:, :cot_rows]).max()) > 0
+                   for d in got[3:6])
+
+
+def kda_layer_weights(c, seed):
+    layer = prog.SolarKDA(c)
+    ks = jax.random.split(jax.random.PRNGKey(seed), 32)
+    return {n: jax.random.normal(k, p.shape) * (0.3 if n.startswith("conv")
+                                               else 0.05)
+            for k, (n, p) in zip(ks, layer.named_parameters())}
+
+
+def test_the_layer_through_the_kernels_is_the_layer_through_the_spelling(
+        monkeypatch):
+    """``kda_mix`` at a small width, output and every gradient, with the
+    chain forced through the interpreted kernels against the path the CPU
+    observes."""
+    c = SolarOpen2Config.tiny()
+    w = kda_layer_weights(c, 3)
+    x = jax.random.normal(jax.random.PRNGKey(4), (2, 64, c.hidden_size))
+    do = jax.random.normal(jax.random.PRNGKey(5), x.shape)
+
+    def both_ways():
+        out, vjp = jax.vjp(lambda x, w: prog.kda_mix(x, w, c), x, w)
+        return out, vjp(do)
+
+    profiler.reset()
+    want = both_ways()
+    monkeypatch.setattr(kda_prep, "prep_path", lambda *a: "pallas")
+    got = both_ways()
+    seen = profiler.summary()["metrics"]
+    assert seen["kda/prep_calls{path=xla}"]["value"] == 1
+    assert seen["kda/prep_calls{path=pallas}"]["value"] == 1
+    flat = lambda t: jax.tree_util.tree_leaves_with_path(t)
+    for (path, a), (_, b) in zip(flat(got), flat(want)):
+        np.testing.assert_allclose(
+            a, b, rtol=0, atol=2e-5 * float(jnp.abs(b).max()),
+            err_msg=jax.tree_util.keystr(path))
+
+
+def test_the_chains_path_is_observed_and_counted():
+    assert kda_prep.prep_path(8192, 128, 4) == "xla"     # the CPU
+    profiler.reset()
+    ps, ws, _ = prep_inputs(0, 1, 32, 128, "float32")
+    jax.jit(lambda p, w: kda_prep.kda_prep(p, w, NORMS, 128, 1e-6))(ps, ws)
+    seen = profiler.summary()["metrics"]
+    assert seen["kda/prep_calls{path=xla}"]["value"] == 1
+    assert "kda/prep_calls{path=pallas}" not in seen
+
+
+def pallas_call_names(jaxpr):
+    names = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            names.append(eqn.params["name"])
+        for v in eqn.params.values():
+            for sub in (v if isinstance(v, (list, tuple)) else [v]):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    names += pallas_call_names(sub)
+    return names
+
+
+def test_the_chains_kernels_are_not_counted_as_the_scan():
+    """perfbench/layer_metrics/_kda_trace.py takes every Mosaic call whose
+    name starts with ``kda_fwd`` or ``kda_bwd`` for the scan."""
+    ps, ws, cs = prep_inputs(0, 1, 32, 128, "float32")
+    names = pallas_call_names(jax.make_jaxpr(
+        lambda p, w, c: prep_both_ways(kda_prep.pallas_kda_prep, p, w, c))(
+            ps, ws, cs).jaxpr)
+    assert sorted(names) == ["kda_prep", "kda_prep_bwd"]
+    assert not [n for n in names if n.startswith(("kda_fwd", "kda_bwd"))]
 
 
 # --- grouped-query flash attention ---------------------------------------------

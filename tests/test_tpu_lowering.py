@@ -495,6 +495,58 @@ def test_tpu_compile_olmoe_step_of_the_cell(monkeypatch):
     assert not re.findall(r"%ragged-dot-none[\w.\-]* = ", text)
 
 
+def test_tpu_compile_a_kda_layers_gradient_at_the_cells_widths(monkeypatch):
+    """ISSUE 41: ``kda_mix`` under ``jax.checkpoint`` and its gradient at
+    Solar-Open2's published widths and the training cell's 8,192 tokens
+    compile for one v5e with the chain between the projections and the
+    scan as the Pallas pair ``kda_prep``/``kda_prep_bwd``
+    (``ops/kda_prep.py``): Mosaic takes the blocks (a head's 128 columns,
+    the 16-row halo view, the unaligned tap loads), both calls sit under
+    ``blk/kda/proj``, where the benchmark's ``kda.proj_ms_per_step`` reads
+    them, neither's name starts like the scan's kernels', and the
+    program's temporaries are 2.6 GB where the ``jax.numpy`` chain's
+    float32 ``[8192, 8192]`` arrays made them 4.3."""
+    import jax.numpy as jnp
+
+    from paddle_tpu.models import solar_open2 as prog
+
+    dev = _tpu_topology_devices()[0]
+    monkeypatch.setenv("PADDLE_TPU_TARGET_PLATFORM", "tpu")
+    c = prog.SolarOpen2Config()
+    heads, d = c.linear_attn_num_heads, c.linear_attn_head_dim
+    bf = lambda *shape: _on_tpu(dev, shape, jnp.bfloat16)
+    w = {n: bf(*p.shape) for n, p in prog.SolarKDA(
+        prog.SolarOpen2Config.tiny()).named_parameters()}
+    sizes = {"w_q": (c.hidden_size, heads * d), "conv_q": (4, heads * d),
+             "w_f1": (c.hidden_size, c.kda_proj_rank),
+             "w_f2": (c.kda_proj_rank, heads * d), "dt_bias": (heads * d,),
+             "A_log": (heads,), "w_b": (c.hidden_size, heads),
+             "o_norm": (d,), "w_o": (heads * d, c.hidden_size)}
+    like = {"w_k": "w_q", "w_v": "w_q", "conv_k": "conv_q",
+            "conv_v": "conv_q", "w_g1": "w_f1", "w_g2": "w_f2",
+            "b_g": "dt_bias"}
+    w = {n: bf(*sizes[like.get(n, n)]) for n in w}
+
+    def loss(x, w):
+        mix = jax.checkpoint(lambda x, w: prog.kda_mix(x, w, c))
+        return jnp.sum(mix(x, w).astype(jnp.float32))
+
+    with jax.default_matmul_precision("default"):
+        compiled = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+            bf(1, 8192, c.hidden_size), w).compile()
+    calls = re.findall(r"%(kda_\w+?)[.\d]* = [^\n]*custom_call_target="
+                       r"\"tpu_custom_call\"[^\n]*op_name=\"([^\"]*)\"",
+                       compiled.as_text())
+    names = sorted(name for name, _ in calls)
+    assert names == ["kda_bwd_grads", "kda_bwd_states", "kda_fwd",
+                     "kda_prep", "kda_prep_bwd"], names
+    for name, scope in calls:
+        part = "blk/kda/proj" if name.startswith("kda_prep") \
+            else "blk/kda/scan"
+        assert part in scope, (name, scope)
+    assert compiled.memory_analysis().temp_size_in_bytes < 3.0e9
+
+
 def test_tpu_compile_the_latent_models_forward(monkeypatch):
     """ISSUE 37: the tick's forward of a latent-attention model
     (``models/dots3.dots3_ragged_apply``: latent, indexer-key and windowed
