@@ -1405,9 +1405,9 @@ TOL_SOLAR_SCAN = {"o": 2e-2, "dq": 2e-2, "dk": 2e-2, "dv": 2e-2, "dg": 6e-2,
 
 
 def scan_against_recurrence(seq: int) -> dict:
-    """``kda_attention`` forward and backward (on the chip: ``kda_fwd``,
-    ``kda_bwd_states``, ``kda_bwd_grads``) with decays from none down to
-    the floor ``G_MIN``, against ``jax.vjp`` of ``kda_recurrent``."""
+    """``kda_attention`` forward and backward (on the chip: the forward
+    rule's ``kda_fwd_states``, then ``kda_bwd_grads``) with decays from none
+    down to the floor ``G_MIN``, against ``jax.vjp`` of ``kda_recurrent``."""
     import jax
     import jax.numpy as jnp
 

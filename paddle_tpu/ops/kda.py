@@ -49,19 +49,32 @@ exact and on the matrix unit:
 * The state and every accumulation are float32; operands of the products
   are of the inputs' type (bf16 in training, float32 under test).
 
-**The backward pass** recomputes the chunk states from the saved inputs
-(``kda_bwd_states``, the forward recurrence again, which writes ``S0`` of
-every chunk), then walks the chunks backwards (``kda_bwd_grads``) with the
-state's cotangent in VMEM; a chunk's gradients are written out by hand
-below (``_chunk_bwd``), checked against autodiff of the token-by-token
-recurrence in tests/test_solar_open2.py.
+**The backward pass** is one sweep. Differentiated, the scan's forward rule
+runs the forward sweep under the name ``kda_fwd_states`` and leaves, beside
+``o``, what the backward sweep would otherwise make again: the state before
+every chunk (float32 ``[b, heads, s / C, dk, dv]``, a copy of the scratch
+the sweep holds anyway) and every chunk's ``(I + M Diag beta)^-1`` (``[b,
+heads, s / C, C, C]`` in the operands' type, which is how every product
+takes it). ``kda_bwd_grads`` then walks the chunks backwards with the
+state's cotangent in VMEM and recomputes nothing but a chunk's own parts
+(decays, pair matrices, pseudo-values: ``_chunk_parts``); a chunk's
+gradients are written out by hand below (``_chunk_bwd``), checked against
+autodiff of the token-by-token recurrence in tests/test_solar_open2.py.
+What it costs the caller: the residuals are the five inputs and those two
+arrays, 537 MB + 134 MB a layer and sequence of 8,192 tokens with 64 heads
+of 128 (a bf16 inverse's 64 columns lie in tiles of 128 lanes). Under a ``jax.checkpoint`` around the layer (models/solar_open2.py
+has one) they are born in the recomputed forward sweep and die in the
+backward sweep right after it; a caller that differentiates a deep stack of
+these layers with no checkpoint holds them a layer until its backward
+pass. Not differentiated, the forward sweep (``kda_fwd``) writes ``o`` alone.
 
 **Two paths, one body.** ``_chunk_fwd`` and ``_chunk_bwd`` are functions of
 one head's one chunk. On one TPU device, with a sequence that is a multiple
 of the chunk and heads of 128, they are the bodies of the Pallas kernels
-``kda_fwd``, ``kda_bwd_states``, ``kda_bwd_grads``; everywhere else (the
+``kda_fwd`` / ``kda_fwd_states`` and ``kda_bwd_grads``; everywhere else (the
 CPU, a multi-device auto mesh, sizes that do not tile) ``lax.scan`` over
-the chunks calls the same functions under ``vmap``. The path is observed
+the chunks calls the same functions under ``vmap``, and its forward rule
+stacks the carry it has. The path is observed
 (``kernel_path``), never chosen: there is no argument, field or variable
 for it. It is counted at trace time in ``kda/scan_calls{path=}``
 (the profiler's registry: a count on the host, nothing in the program).
@@ -214,13 +227,15 @@ def _pair_matrices(qf, kf, dec, dt):
             jnp.where(strict, jnp.concatenate(k_rows), 0.0), blocks)
 
 
-def _chunk_parts(q, k, v, g, beta_row, s0):
-    """Everything one chunk's forward computes, for both passes."""
+def _chunk_parts(q, k, v, g, beta_row, s0, inv=None):
+    """Everything one chunk's forward computes, for both passes; the
+    backward pass hands in the ``inv`` the forward pass left."""
     dt = q.dtype
     qf, kf, vf = q.astype(_F32), k.astype(_F32), v.astype(_F32)
     dec = _decay_parts(g)
     aq, m, blocks = _pair_matrices(qf, kf, dec, dt)
-    inv = _unit_lower_inverse(m * beta_row, dt)         # (I + M Diag b)^-1
+    if inv is None:                                     # (I + M Diag b)^-1
+        inv = _unit_lower_inverse(m * beta_row, dt).astype(dt)
     beta_col = _to_col(beta_row)
     kg, qg = kf * dec["gam"], qf * dec["gam"]
     r = vf - _mm(kg, s0, _NN, dt)
@@ -231,30 +246,24 @@ def _chunk_parts(q, k, v, g, beta_row, s0):
             "qg": qg, "pr": pr, "u": u, "k_end": kf * dec["to_end"]}
 
 
-def _next_state(p, s0):
-    return _to_col(p["dec"]["end"]) * s0 \
-        + _mm(p["k_end"], p["u"], _TN, p["dt"])
-
-
 def _chunk_fwd(q, k, v, g, beta_row, s0, scale):
     """One head's one chunk: q, k [C, dk], v [C, dv], g [C, dk] float32,
     beta_row [1, C] float32, s0 [dk, dv] float32 -> (o [C, dv] float32,
-    s1)."""
+    s1, and the chunk's ``(I + M Diag beta)^-1`` [C, C] as the products
+    take it, in q's type)."""
     p = _chunk_parts(q, k, v, g, beta_row, s0)
     o = scale * (_mm(p["qg"], s0, _NN, p["dt"])
                  + _mm(p["aq"], p["u"], _NN, p["dt"]))
-    return o, _next_state(p, s0)
+    s1 = _to_col(p["dec"]["end"]) * s0 + _mm(p["k_end"], p["u"], _TN, p["dt"])
+    return o, s1, p["inv"]
 
 
-def _chunk_state(q, k, v, g, beta_row, s0):
-    """The state after the chunk alone (the backward pass's first sweep)."""
-    return _next_state(_chunk_parts(q, k, v, g, beta_row, s0), s0)
-
-
-def _chunk_bwd(q, k, v, g, beta_row, s0, do, ds1, scale):
-    """The chunk's gradients from ``do`` [C, dv] and the cotangent ``ds1``
-    of the state after it: (dq, dk, dv, dg, dbeta_row, ds0), float32."""
-    p = _chunk_parts(q, k, v, g, beta_row, s0)
+def _chunk_bwd(q, k, v, g, beta_row, s0, inv, do, ds1, scale):
+    """The chunk's gradients from what the forward pass left (the state
+    ``s0`` before the chunk and ``inv``), ``do`` [C, dv] and the cotangent
+    ``ds1`` of the state after it: (dq, dk, dv, dg, dbeta_row, ds0),
+    float32."""
+    p = _chunk_parts(q, k, v, g, beta_row, s0, inv)
     dt, qf, kf, dec = p["dt"], p["qf"], p["kf"], p["dec"]
     c = qf.shape[0]
     low = _iota((c, c), 0) >= _iota((c, c), 1)
@@ -367,47 +376,37 @@ def _over_heads(fn):
     return jax.vmap(jax.vmap(fn))
 
 
-def _xla_states(qc, kc, vc, gc, bc):
-    b, h, dk, dv = qc.shape[1], qc.shape[2], qc.shape[-1], vc.shape[-1]
-
-    def step(s0, x):
-        return _over_heads(_chunk_state)(*x, s0), s0
-
-    _, states = jax.lax.scan(step, jnp.zeros((b, h, dk, dv), _F32),
-                             (qc, kc, vc, gc, bc))
-    return states                                        # S0 of every chunk
-
-
 def _xla_fwd(q, k, v, g, beta, scale):
+    """-> (o, and what the backward pass reads: the state before every
+    chunk and every chunk's inverse, [n_chunks, b, h, ...], the scan's
+    carry and an output it has anyway)."""
     c = CHUNK
     b, h, dk, dv = q.shape[0], q.shape[2], q.shape[3], v.shape[3]
     xs = (_chunked(q, c), _chunked(k, c), _chunked(v, c), _chunked(g, c),
           _beta_rows(beta, c))
 
     def step(s0, x):
-        o, s1 = _over_heads(
+        o, s1, inv = _over_heads(
             functools.partial(_chunk_fwd, scale=scale))(*x, s0)
-        return s1, o
+        return s1, (o, s0, inv)
 
-    _, o = jax.lax.scan(step, jnp.zeros((b, h, dk, dv), _F32), xs)
-    return _unchunked(o).astype(v.dtype)
+    _, (o, states, invs) = jax.lax.scan(
+        step, jnp.zeros((b, h, dk, dv), _F32), xs)
+    return _unchunked(o).astype(v.dtype), states, invs
 
 
-def _xla_bwd(q, k, v, g, beta, do, scale):
+def _xla_bwd(q, k, v, g, beta, states, invs, do, scale):
     c = CHUNK
     xs = (_chunked(q, c), _chunked(k, c), _chunked(v, c), _chunked(g, c),
-          _beta_rows(beta, c))
-    states = _xla_states(*xs)
+          _beta_rows(beta, c), states, invs, _chunked(do, c))
 
     def step(ds1, x):
-        *ins, s0, do_c = x
         dq, dk, dv, dg, db, ds0 = _over_heads(
-            functools.partial(_chunk_bwd, scale=scale))(*ins, s0, do_c, ds1)
+            functools.partial(_chunk_bwd, scale=scale))(*x, ds1)
         return ds0, (dq, dk, dv, dg, db)
 
     _, (dq, dk, dv, dg, db) = jax.lax.scan(
-        step, jnp.zeros_like(states[0]), xs + (states, _chunked(do, c)),
-        reverse=True)
+        step, jnp.zeros_like(states[0]), xs, reverse=True)
     dbeta = jnp.transpose(db[:, :, :, 0, :], (1, 0, 3, 2)).reshape(
         beta.shape)
     return (_unchunked(dq).astype(q.dtype), _unchunked(dk).astype(k.dtype),
@@ -425,25 +424,12 @@ def _head_cols(ref, h, d):
     return ref[0, :, h * d:(h + 1) * d]
 
 
-def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, o_ref, s_ref, *, hb, dk,
-                dv, scale):
-    c = pl.program_id(2)
-
-    @pl.when(c == 0)
-    def _init():
-        s_ref[...] = jnp.zeros_like(s_ref)
-
-    for h in range(hb):
-        o, s1 = _chunk_fwd(
-            _head_cols(q_ref, h, dk), _head_cols(k_ref, h, dk),
-            _head_cols(v_ref, h, dv), _head_cols(g_ref, h, dk),
-            b_ref[0, h, pl.ds(c, 1), :], s_ref[h], scale)
-        o_ref[0, :, h * dv:(h + 1) * dv] = o.astype(o_ref.dtype)
-        s_ref[h] = s1
-
-
-def _states_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, st_ref, s_ref, *, hb,
-                   dk, dv):
+def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, o_ref, *more, hb, dk, dv,
+                scale):
+    """``more``: the blocks the forward rule leaves the backward pass (the
+    state before the chunk, the chunk's inverse), if it is the forward
+    rule that runs, then the state's scratch."""
+    *left, s_ref = more
     c = pl.program_id(2)
 
     @pl.when(c == 0)
@@ -452,14 +438,17 @@ def _states_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, st_ref, s_ref, *, hb,
 
     for h in range(hb):
         s0 = s_ref[h]
-        st_ref[0, h, 0] = s0
-        s_ref[h] = _chunk_state(
+        o, s1, inv = _chunk_fwd(
             _head_cols(q_ref, h, dk), _head_cols(k_ref, h, dk),
             _head_cols(v_ref, h, dv), _head_cols(g_ref, h, dk),
-            b_ref[0, h, pl.ds(c, 1), :], s0)
+            b_ref[0, h, pl.ds(c, 1), :], s0, scale)
+        o_ref[0, :, h * dv:(h + 1) * dv] = o.astype(o_ref.dtype)
+        s_ref[h] = s1
+        for ref, part in zip(left, (s0, inv)):
+            ref[0, h, 0] = part
 
 
-def _grads_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, do_ref, st_ref,
+def _grads_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, st_ref, inv_ref, do_ref,
                   dq_ref, dk_ref, dv_ref, dg_ref, db_ref, ds_ref, *, hb,
                   dk, dv, scale, n_chunks):
     step = pl.program_id(2)
@@ -473,7 +462,7 @@ def _grads_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, do_ref, st_ref,
         dq, dkk, dvv, dg, db, ds0 = _chunk_bwd(
             _head_cols(q_ref, h, dk), _head_cols(k_ref, h, dk),
             _head_cols(v_ref, h, dv), _head_cols(g_ref, h, dk),
-            b_ref[0, h, pl.ds(c, 1), :], st_ref[0, h, 0],
+            b_ref[0, h, pl.ds(c, 1), :], st_ref[0, h, 0], inv_ref[0, h, 0],
             _head_cols(do_ref, h, dv), ds_ref[h], scale)
         dq_ref[0, :, h * dk:(h + 1) * dk] = dq.astype(dq_ref.dtype)
         dk_ref[0, :, h * dk:(h + 1) * dk] = dkk.astype(dk_ref.dtype)
@@ -488,15 +477,17 @@ def _heads_a_step(heads: int) -> int:
 
 
 def _specs(s, dk, dv, hb, order):
-    """The block specs the three kernels share; ``order(c)`` is the chunk
-    a grid step works on."""
+    """The block specs the kernels share; ``order(c)`` is the chunk a grid
+    step works on. ``cols(d)``: a chunk of ``hb`` heads' columns; ``beta``;
+    ``left``: what the forward rule leaves, a chunk's state and inverse."""
     nc = s // CHUNK
     cols = lambda d: pl.BlockSpec(
         (1, CHUNK, hb * d), lambda i, j, c: (i, order(c), j))
     beta = pl.BlockSpec((1, hb, nc, CHUNK), lambda i, j, c: (i, j, 0, 0))
-    state = pl.BlockSpec((1, hb, 1, dk, dv),
+    left = [pl.BlockSpec((1, hb, 1) + tile,
                          lambda i, j, c: (i, j, order(c), 0, 0))
-    return cols, beta, state
+            for tile in ((dk, dv), (CHUNK, CHUNK))]
+    return cols, beta, left
 
 
 def _beta_blocks(beta):
@@ -511,51 +502,45 @@ def _params():
         vmem_limit_bytes=_VMEM_LIMIT)
 
 
-def _pallas_fwd(q, k, v, g, beta, scale):
+def _pallas_fwd(q, k, v, g, beta, scale, leave):
     """q, k, g [b, s, heads * dk], v [b, s, heads * dv], beta [b, s,
-    heads] -> o [b, s, heads * dv]."""
+    heads] -> o [b, s, heads * dv]; with ``leave`` (the forward rule)
+    -> (o, states float32 [b, heads, n_chunks, dk, dv], inverses [b,
+    heads, n_chunks, C, C] in q's type), from the same sweep."""
     b, s, h = beta.shape
     dk, dv = q.shape[2] // h, v.shape[2] // h
-    hb = _heads_a_step(h)
-    cols, beta_spec, _ = _specs(s, dk, dv, hb, lambda c: c)
-    return pl.pallas_call(
+    hb, nc = _heads_a_step(h), s // CHUNK
+    cols, beta_spec, left = _specs(s, dk, dv, hb, lambda c: c)
+    outs = [(cols(dv), jax.ShapeDtypeStruct(v.shape, v.dtype)),
+            (left[0], jax.ShapeDtypeStruct((b, h, nc, dk, dv), _F32)),
+            (left[1], jax.ShapeDtypeStruct((b, h, nc, CHUNK, CHUNK),
+                                           q.dtype))][:3 if leave else 1]
+    out = pl.pallas_call(
         functools.partial(_fwd_kernel, hb=hb, dk=dk, dv=dv, scale=scale),
-        name="kda_fwd",
-        grid=(b, h // hb, s // CHUNK),
+        name="kda_fwd_states" if leave else "kda_fwd",
+        grid=(b, h // hb, nc),
         in_specs=[cols(dk), cols(dk), cols(dv), cols(dk), beta_spec],
-        out_specs=cols(dv),
-        out_shape=jax.ShapeDtypeStruct(v.shape, v.dtype),
+        out_specs=[spec for spec, _ in outs],
+        out_shape=[shape for _, shape in outs],
         scratch_shapes=[pltpu.VMEM((hb, dk, dv), _F32)],
         compiler_params=_params(),
         interpret=_interpret(),
     )(q, k, v, g, _beta_blocks(beta))
+    return tuple(out) if leave else out[0]
 
 
-def _pallas_bwd(q, k, v, g, beta, do, scale):
+def _pallas_bwd(q, k, v, g, beta, states, invs, do, scale):
     b, s, h = beta.shape
     dk, dv = q.shape[2] // h, v.shape[2] // h
     hb, nc = _heads_a_step(h), s // CHUNK
-    ins = (q, k, v, g, _beta_blocks(beta))
-    cols, beta_spec, state = _specs(s, dk, dv, hb, lambda c: c)
-    states = pl.pallas_call(
-        functools.partial(_states_kernel, hb=hb, dk=dk, dv=dv),
-        name="kda_bwd_states",
-        grid=(b, h // hb, nc),
-        in_specs=[cols(dk), cols(dk), cols(dv), cols(dk), beta_spec],
-        out_specs=state,
-        out_shape=jax.ShapeDtypeStruct((b, h, nc, dk, dv), _F32),
-        scratch_shapes=[pltpu.VMEM((hb, dk, dv), _F32)],
-        compiler_params=_params(),
-        interpret=_interpret(),
-    )(*ins)
-    cols, beta_spec, state = _specs(s, dk, dv, hb, lambda c: nc - 1 - c)
+    cols, beta_spec, left = _specs(s, dk, dv, hb, lambda c: nc - 1 - c)
     dq, dkk, dvv, dg, db = pl.pallas_call(
         functools.partial(_grads_kernel, hb=hb, dk=dk, dv=dv, scale=scale,
                           n_chunks=nc),
         name="kda_bwd_grads",
         grid=(b, h // hb, nc),
-        in_specs=[cols(dk), cols(dk), cols(dv), cols(dk), beta_spec,
-                  cols(dv), state],
+        in_specs=[cols(dk), cols(dk), cols(dv), cols(dk), beta_spec]
+        + left + [cols(dv)],
         out_specs=[cols(dk), cols(dk), cols(dv), cols(dk), beta_spec],
         out_shape=[
             jax.ShapeDtypeStruct(q.shape, q.dtype),
@@ -566,7 +551,7 @@ def _pallas_bwd(q, k, v, g, beta, do, scale):
         scratch_shapes=[pltpu.VMEM((hb, dk, dv), _F32)],
         compiler_params=_params(),
         interpret=_interpret(),
-    )(*ins, do.astype(v.dtype), states)
+    )(q, k, v, g, _beta_blocks(beta), states, invs, do.astype(v.dtype))
     return dq, dkk, dvv, dg, jnp.transpose(db, (0, 2, 3, 1)).reshape(b, s, h)
 
 
@@ -575,13 +560,16 @@ def pallas_kda(q, k, v, g, beta, scale):
     """The kernels themselves, whatever the platform (interpreted on the
     CPU), on ``[b, s, heads * d]`` arrays: what tests/test_solar_open2.py
     compares with the recurrence."""
-    return _pallas_fwd(q, k, v, g, beta, scale)
+    return _pallas_fwd(q, k, v, g, beta, scale, leave=False)
 
 
-pallas_kda.defvjp(
-    lambda q, k, v, g, beta, scale: (
-        _pallas_fwd(q, k, v, g, beta, scale), (q, k, v, g, beta)),
-    lambda scale, res, do: _pallas_bwd(*res, do, scale))
+def _pallas_kda_fwd(q, k, v, g, beta, scale):
+    o, states, invs = _pallas_fwd(q, k, v, g, beta, scale, leave=True)
+    return o, (q, k, v, g, beta, states, invs)
+
+
+pallas_kda.defvjp(_pallas_kda_fwd,
+                  lambda scale, res, do: _pallas_bwd(*res, do, scale))
 
 
 # ---------------------------------------------------------------------------
@@ -605,25 +593,28 @@ def _heads_apart(a, heads):
     return a.reshape(b, s, heads, hd // heads)
 
 
+def _xla_kda_fwd(q, k, v, g, beta, scale):
+    """The ``lax.scan`` path's forward rule, on the kernels' layout."""
+    heads = beta.shape[2]
+    o, states, invs = _xla_fwd(
+        *(_heads_apart(a, heads) for a in (q, k, v, g)), beta, scale)
+    return o.reshape(v.shape), (q, k, v, g, beta, states, invs)
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
 def _xla_kda(q, k, v, g, beta, scale):
-    q, k, v, g = (_heads_apart(a, beta.shape[2]) for a in (q, k, v, g))
-    return _xla_fwd(q, k, v, g, beta, scale).reshape(
-        v.shape[0], v.shape[1], -1)
+    return _xla_kda_fwd(q, k, v, g, beta, scale)[0]
 
 
 def _xla_kda_bwd(scale, res, do):
-    q, k, v, g, beta = res
+    *ins, beta, states, invs = res
     heads = beta.shape[2]
-    grads = _xla_bwd(*(_heads_apart(a, heads) for a in (q, k, v, g)), beta,
-                     _heads_apart(do, heads), scale)
-    return tuple(d.reshape(a.shape) for d, a in zip(grads, res))
+    grads = _xla_bwd(*(_heads_apart(a, heads) for a in ins), beta, states,
+                     invs, _heads_apart(do, heads), scale)
+    return tuple(d.reshape(a.shape) for d, a in zip(grads, res[:5]))
 
 
-_xla_kda.defvjp(
-    lambda q, k, v, g, beta, scale: (
-        _xla_kda(q, k, v, g, beta, scale), (q, k, v, g, beta)),
-    _xla_kda_bwd)
+_xla_kda.defvjp(_xla_kda_fwd, _xla_kda_bwd)
 
 
 def kda_attention_flat(q, k, v, g, beta, scale=None):

@@ -5,6 +5,7 @@ of its experts (distributed/moe.py), and the period and the whole model
 through HybridPipelineTrainer against the plain float32 reference
 (models/solar_open2_reference.py); small sizes on the CPU, float32, seeded
 weights, matmuls at ``highest`` (tests/conftest.py)."""
+import functools
 import hashlib
 
 import numpy as np
@@ -92,12 +93,6 @@ def test_decays_down_to_the_floor_stay_exact(path):
     holds; the kernels and the ``jax.numpy`` path alike."""
     d = 128 if path == "pallas" else 64
     args, do = steep_inputs(13, kda.G_MIN, -1e-3, d)
-    flat = lambda a: a.reshape(a.shape[0], a.shape[1], -1)
-
-    def kernels(q, k, v, g, beta):
-        return kda.pallas_kda(flat(q), flat(k), flat(v), flat(g), beta,
-                              d ** -0.5).reshape(v.shape)
-
     against_the_recurrence(kernels if path == "pallas"
                            else kda.kda_attention, args, do, 2e-5)
 
@@ -138,17 +133,97 @@ def test_neighbouring_keys_alike_and_beta_near_two_stay_exact():
     against_the_recurrence(kda.kda_attention, (q, k, v, g, beta), do, 1e-4)
 
 
+def flat(a):
+    return a.reshape(a.shape[0], a.shape[1], -1)
+
+
+def kernels(q, k, v, g, beta):
+    """``pallas_kda`` (interpreted here) on ``kda_attention``'s layout."""
+    return kda.pallas_kda(flat(q), flat(k), flat(v), flat(g), beta,
+                          q.shape[-1] ** -0.5).reshape(v.shape)
+
+
 def test_pallas_scan_kernels_equal_the_recurrence():
-    """The kernels themselves, interpreted: forward, the states' sweep and
+    """The kernels themselves, interpreted: the forward rule's sweep and
     the backward sweep."""
     args, do = scan_inputs(1, 1, 128, 2, 128, (0.5, 0.999), "spread")
-    flat = lambda a: a.reshape(a.shape[0], a.shape[1], -1)
-
-    def kernels(q, k, v, g, beta):
-        return kda.pallas_kda(flat(q), flat(k), flat(v), flat(g), beta,
-                              128 ** -0.5).reshape(v.shape)
-
     against_the_recurrence(kernels, args, do, 2e-5)
+
+
+def test_the_backward_pass_is_one_sweep():
+    """``jax.grad`` through ``pallas_kda`` holds the forward rule's sweep
+    and one backward kernel: nothing rebuilds the chunks' entry states."""
+    args, do = scan_inputs(2, 1, 192, 2, 128, (0.5, 0.999), "spread")
+    loss = lambda *a: jnp.sum(kernels(*a) * do)
+    names = pallas_call_names(
+        jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2, 3, 4)))(*args).jaxpr)
+    assert names == ["kda_fwd_states", "kda_bwd_grads"], names
+    assert pallas_call_names(jax.make_jaxpr(kernels)(*args).jaxpr) \
+        == ["kda_fwd"]
+
+
+def test_under_a_checkpoint_the_gradients_are_the_same_bits():
+    """The layer's ``jax.checkpoint``: the residuals are born in the
+    recomputed forward sweep; still one backward kernel, and every
+    gradient equals the unchecked one bit for bit."""
+    args, do = scan_inputs(3, 1, 192, 2, 128, (0.5, 0.999), "spread")
+    loss = lambda fn: lambda *a: jnp.sum(fn(*a) * do)
+    grad = lambda fn: jax.grad(loss(fn), argnums=(0, 1, 2, 3, 4))
+    names = pallas_call_names(
+        jax.make_jaxpr(grad(jax.checkpoint(kernels)))(*args).jaxpr)
+    assert [n for n in names if n.startswith("kda_bwd")] \
+        == ["kda_bwd_grads"], names
+    assert "kda_fwd_states" in names
+    for a, b in zip(jax.jit(grad(jax.checkpoint(kernels)))(*args),
+                    jax.jit(grad(kernels))(*args)):
+        assert bool(jnp.isfinite(a).all())
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+def recurrent_states(q, k, v, g, beta):
+    """``kda_recurrent``'s carry before every token, [s, b, h, dk, dv]."""
+    f = lambda a: jnp.moveaxis(a, 1, 0)
+    hi = functools.partial(jnp.einsum, precision=jax.lax.Precision.HIGHEST)
+
+    def step(state, x):
+        kt, vt, gt, bt = x
+        new = state * jnp.exp(gt)[..., None]
+        err = vt - hi("bhk,bhkv->bhv", kt, new)
+        return new + hi("bhk,bhv->bhkv", kt * bt[..., None], err), state
+
+    zero = jnp.zeros(q.shape[:1] + q.shape[2:] + v.shape[-1:], jnp.float32)
+    return jax.lax.scan(step, zero, (f(k), f(v), f(g), f(beta)))[1]
+
+
+@pytest.mark.parametrize("path", ["xla", "pallas"])
+def test_the_forward_rule_leaves_the_recurrences_carry_at_every_chunk_edge(
+        path):
+    """What the backward sweep reads in place of a sweep of its own: the
+    state before every chunk, float32, and (the second residual) each
+    chunk's unit lower-triangular inverse."""
+    args, _ = scan_inputs(5, 1, 256, 2, 128, (0.5, 0.999), "spread")
+    q, k, v, g, beta = args
+    rule = {"xla": kda._xla_kda_fwd, "pallas": kda._pallas_kda_fwd}[path]
+    o, res = rule(flat(q), flat(k), flat(v), flat(g), beta, 128 ** -0.5)
+    states, invs = res[5:]
+    if path == "xla":                   # [chunks, b, h, ...] -> [b, h, chunks]
+        states, invs = (jnp.moveaxis(a, 0, 2) for a in (states, invs))
+    want = jnp.transpose(recurrent_states(*args)[::kda.CHUNK],
+                         (1, 2, 0, 3, 4))
+    assert states.dtype == jnp.float32 and states.shape == want.shape
+    assert float(jnp.abs(want[:, :, 1:]).max()) > 0.1
+    np.testing.assert_allclose(np.asarray(states), np.asarray(want), rtol=0,
+                               atol=2e-5 * float(jnp.abs(want).max()))
+    want_o = kda.kda_recurrent(*args)
+    np.testing.assert_allclose(
+        np.asarray(o.reshape(v.shape)), np.asarray(want_o), rtol=0,
+        atol=2e-5 * float(jnp.abs(want_o).max()))
+    c = kda.CHUNK
+    assert invs.shape == (1, 2, 256 // c, c, c)
+    upper = np.triu(np.ones((c, c), bool), 1)
+    assert float(jnp.abs(jnp.where(upper, invs, 0.0)).max()) == 0
+    np.testing.assert_array_equal(
+        np.asarray(jnp.diagonal(invs, axis1=-2, axis2=-1)), 1.0)
 
 
 def test_the_scans_path_is_observed_and_counted():

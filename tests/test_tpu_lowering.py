@@ -504,8 +504,13 @@ def test_tpu_compile_a_kda_layers_gradient_at_the_cells_widths(monkeypatch):
     the 16-row halo view, the unaligned tap loads), both calls sit under
     ``blk/kda/proj``, where the benchmark's ``kda.proj_ms_per_step`` reads
     them, neither's name starts like the scan's kernels', and the
-    program's temporaries are 2.6 GB where the ``jax.numpy`` chain's
-    float32 ``[8192, 8192]`` arrays made them 4.3."""
+    program's temporaries are 2.7 GB where the ``jax.numpy`` chain's
+    float32 ``[8192, 8192]`` arrays made them 4.3. ISSUE 42: the scan is
+    two kernels here, the forward rule's sweep, which leaves every chunk's
+    entry state and inverse (``kda_fwd_states``; the pass before it is the
+    same call on the same operands and XLA keeps one), and
+    ``kda_bwd_grads``: no third sweep rebuilds the states (the inverses
+    are 0.13 of the 2.7 GB; the states were there before)."""
     import jax.numpy as jnp
 
     from paddle_tpu.models import solar_open2 as prog
@@ -538,8 +543,8 @@ def test_tpu_compile_a_kda_layers_gradient_at_the_cells_widths(monkeypatch):
                        r"\"tpu_custom_call\"[^\n]*op_name=\"([^\"]*)\"",
                        compiled.as_text())
     names = sorted(name for name, _ in calls)
-    assert names == ["kda_bwd_grads", "kda_bwd_states", "kda_fwd",
-                     "kda_prep", "kda_prep_bwd"], names
+    assert names == ["kda_bwd_grads", "kda_fwd_states", "kda_prep",
+                     "kda_prep_bwd"], names
     for name, scope in calls:
         part = "blk/kda/proj" if name.startswith("kda_prep") \
             else "blk/kda/scan"
