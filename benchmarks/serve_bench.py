@@ -19,10 +19,6 @@ prefills only each request's suffix, so first tokens arrive without
 re-running the system prompt per request. The profiler block carries
 ``serving/prefix_hit_tokens`` as the direct evidence.
 
-``--attention-kernel {ragged-xla,ragged-pallas}`` selects the
-attention spelling of the engine's one mixed-row tick for either
-workload (default: the XLA gather spelling).
-
 The baseline is exactly what a naive deployment of this repo would run
 today, warmed so the comparison is decode-vs-decode, not
 compile-vs-decode.
@@ -143,16 +139,14 @@ def run_baseline(net, trace):
 
 
 def build_engine(net, num_slots, page_size, pages_per_slot,
-                 prefill_chunk=0, prefix_cache=True,
-                 attention_kernel="ragged-xla", kv_dtype=None,
+                 prefill_chunk=0, prefix_cache=True, kv_dtype=None,
                  scheduler="fifo", prefill_chunks_per_tick=1):
     from paddle_tpu.serving import ServingConfig, ServingEngine
 
     return ServingEngine(net, ServingConfig(
         num_slots=num_slots, page_size=page_size,
         pages_per_slot=pages_per_slot, prefill_chunk=prefill_chunk,
-        prefix_cache=prefix_cache, attention_kernel=attention_kernel,
-        kv_dtype=kv_dtype, scheduler=scheduler,
+        prefix_cache=prefix_cache, kv_dtype=kv_dtype, scheduler=scheduler,
         prefill_chunks_per_tick=prefill_chunks_per_tick))
 
 
@@ -256,7 +250,6 @@ def bench_poisson(args, tiny):
         p = np.zeros((t0,), np.int32)
         net.generate(paddle.to_tensor(p[None]), max_new_tokens=max_new)
     eng = build_engine(net, slots, page_size, pages_per_slot,
-                       attention_kernel=args.attention_kernel,
                        scheduler=args.sched_policy)
     warm = make_trace(max(2, slots), prompt_lens, max_new, 1e9, seed=1)
     run_engine(eng, [(0.0, p, m) for _, p, m in warm])
@@ -351,7 +344,6 @@ def bench_poisson(args, tiny):
             "requests": n_req, "slots": slots,
             "prompt_lens": list(prompt_lens), "max_new": max_new,
             "arrival_rate_hz": args.rate,
-            "attention_kernel": args.attention_kernel,
             "page_size": page_size, "pages_per_slot": pages_per_slot,
             "engine_tokens_per_sec": round(eng_tps, 2),
             "baseline_tokens_per_sec": round(bl_tps, 2),
@@ -419,7 +411,6 @@ def bench_shared_prefix(args, tiny):
         eng = build_engine(net, slots, page_size, pages_per_slot,
                            prefill_chunk=chunk,
                            prefix_cache=prefix_cache,
-                           attention_kernel=args.attention_kernel,
                            scheduler=args.sched_policy)
         # warm every compiled program (tick, prefill chunk, COW copy)
         # off the clock, then flush results + cached pages so the
@@ -475,7 +466,6 @@ def bench_shared_prefix(args, tiny):
             "requests": n_req, "slots": slots,
             "system_prompt_tokens": sys_len,
             "suffix_tokens": sfx_len, "max_new": max_new,
-            "attention_kernel": args.attention_kernel,
             "page_size": page_size, "pages_per_slot": pages_per_slot,
             "prefill_chunk": chunk,
             "ttft_ms": {
@@ -776,12 +766,10 @@ def bench_spec(args, tiny):
         draft = build_early_exit_draft(net, draft_layers)
         pages_per_slot = -(-(max(prompt_lens) + max_new) // page_size)
         trace = make_trace(n_req, prompt_lens, max_new, rate)
-        plain = build_engine(net, slots, page_size, pages_per_slot,
-                             attention_kernel=args.attention_kernel)
+        plain = build_engine(net, slots, page_size, pages_per_slot)
         spec = ServingEngine(net, ServingConfig(
             num_slots=slots, page_size=page_size,
             pages_per_slot=pages_per_slot,
-            attention_kernel=args.attention_kernel,
             spec=SpecConfig(draft_model=draft, k=cell_k)))
         warm = make_trace(max(2, slots), prompt_lens, max_new, 1e9,
                           seed=1)
@@ -983,7 +971,6 @@ def bench_spec_sampling(args, tiny):
             num_slots=slots, page_size=page_size,
             pages_per_slot=pages_per_slot,
             num_pages=3 * slots * pages_per_slot + 1,
-            attention_kernel=args.attention_kernel,
             decode="sampling", temperature=temperature,
             top_k=top_k, top_p=top_p, spec=spec))
 
@@ -1146,7 +1133,6 @@ def bench_sched_matrix(args, tiny):
                       seed=1)
     for pol in policies:
         eng = build_engine(net, slots, ps, pps, prefill_chunk=ps,
-                           attention_kernel=args.attention_kernel,
                            scheduler=pol)
         run_engine(eng, [(0.0, p, m) for _, p, m in warm])
         eng.pool.drop_prefix_cache()
@@ -1316,7 +1302,6 @@ def bench_adaptive_k(args, tiny):
     def make_eng(adaptive):
         return ServingEngine(net, ServingConfig(
             num_slots=slots, page_size=ps, pages_per_slot=pps,
-            attention_kernel=args.attention_kernel,
             scheduler=args.sched_policy,
             spec=SpecConfig(draft_model=draft, k=k,
                             adaptive=adaptive)))
@@ -2196,10 +2181,6 @@ def main():
                          "draft: twin-accept and ~zero-accept "
                          "requests co-resident); combines with "
                          "--sched-policy")
-    ap.add_argument("--attention-kernel", default="ragged-xla",
-                    choices=["ragged-xla", "ragged-pallas"],
-                    help="attention spelling of the engine's tick for "
-                         "the single-workload modes")
     ap.add_argument("--kv-dtype", default="f32",
                     choices=["f32", "bf16", "int8"],
                     help="page-pool storage dtype. 'f32' runs the "

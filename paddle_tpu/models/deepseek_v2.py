@@ -12,12 +12,12 @@ weights, not renormalised, and ``n_shared_experts`` shared experts, which
 are one SwiGLU of their widths' sum. This model holds a share of the routed
 experts (``distributed/moe.held_moe``).
 
-It is ``models/dots3.py``'s sibling and copies none of it: the weights'
-containers, the state the engine draws, ``TickRows`` (the tick's flat
-tokens against its rows) and ``TickRecord`` are imported from there, the
-pools are ``serving.paged_cache.LatentPools`` with no indexer keys and no
-window space, the reads and writes ``ops/paged_attention``'s
-``latent_scatter`` and ``latent_attention``. What is its own is below: the
+It is ``models/dots3.py``'s sibling: both are served layer by layer
+(``models/tick.py``: the protocol ``ServingEngine`` asks of a model, the
+weights' containers, the state the engine draws, ``TickRows`` and
+``TickRecord``), the pools are ``serving.paged_cache.LatentPools`` with no
+indexer keys and no window space, written and read through their
+``scatter_latent`` and ``attend``. What is its own is below: the
 configuration, YaRN's table, the layer and the tick's forward.
 ``models/deepseek_v2_reference.py`` is the plain float32 reference of the
 same equations; it reads this model's weights by the names given here and
@@ -42,10 +42,10 @@ import numpy as np
 from .. import nn
 from ..distributed.moe import HeldMoEMLP, held_moe, kept_groups
 from ..nn import initializer as I
-from ..ops import paged_attention as _pa
 from ..profiler.trace import annotate
-from . import dots3 as _d3
-from .gpt import _rms
+from . import tick as _tick
+from .tick import (HeldExpertsConfig, LayerwiseLM, SwiGLUMLP, TickRows,
+                   Weight, rms)
 
 #: what one tick reports beside its tokens, in this order (``aux["stats"]``)
 TICK_STATS = ("group_hit_share", "expert_rows", "expert_load_max_over_mean",
@@ -97,7 +97,7 @@ def yarn_table(dim: int, theta: float, scaling: Optional[dict]):
 
 
 @dataclass
-class DeepseekV2Config:
+class DeepseekV2Config(HeldExpertsConfig):
     """Sizes under the names of the model's ``config.json``."""
     vocab_size: int = 102400
     hidden_size: int = 5120
@@ -134,15 +134,6 @@ class DeepseekV2Config:
             raise ValueError(f"topk_group {self.topk_group} of "
                              f"n_group {self.n_group}")
 
-    # the engine's names for what it reads of any served model
-    @property
-    def max_seq_len(self) -> int:
-        return self.max_position_embeddings
-
-    @property
-    def held(self) -> Tuple[int, int]:
-        return self.experts_held or (0, self.n_routed_experts)
-
     @property
     def shared_width(self) -> int:
         return self.n_shared_experts * self.moe_intermediate_size
@@ -153,9 +144,6 @@ class DeepseekV2Config:
         return (self.qk_nope_head_dim + self.qk_rope_head_dim) ** -0.5 \
             * yarn_table(self.qk_rope_head_dim, self.rope_theta,
                          self.rope_scaling)[2]
-
-    def is_moe(self, layer: int) -> bool:
-        return layer >= self.first_k_dense_replace
 
     def attention_params(self) -> int:
         h, nh = self.hidden_size, self.num_attention_heads
@@ -176,11 +164,6 @@ class DeepseekV2Config:
             return n + 3 * h * self.intermediate_size
         return n + h * self.n_routed_experts + 3 * h * (
             self.moe_intermediate_size * self.held[1] + self.shared_width)
-
-    def num_params(self) -> int:
-        return sum(self.layer_params(i)
-                   for i in range(self.num_hidden_layers)) \
-            + 2 * self.vocab_size * self.hidden_size + self.hidden_size
 
     @staticmethod
     def deepseek_v2():
@@ -209,24 +192,23 @@ class DeepseekV2Config:
 
 
 class DeepseekV2Attention(nn.Layer):
-    """The weights of one layer's latent attention, under ``models/
-    dots3.py``'s names for the matrices the two models share."""
+    """The weights of one layer's latent attention, under the names
+    ``models/dots3.py`` gives the matrices the two models share."""
 
     def __init__(self, c: DeepseekV2Config):
         super().__init__()
         h, nh = c.hidden_size, c.num_attention_heads
         init, one = I.Normal(0.0, c.initializer_range), I.Constant(1.0)
-        self.q_a = _d3._Weight([h, c.q_lora_rank], init)
-        self.q_a_norm = _d3._Weight([c.q_lora_rank], one)
-        self.q_b = _d3._Weight(
+        self.q_a = Weight([h, c.q_lora_rank], init)
+        self.q_a_norm = Weight([c.q_lora_rank], one)
+        self.q_b = Weight(
             [c.q_lora_rank,
              nh * (c.qk_nope_head_dim + c.qk_rope_head_dim)], init)
-        self.kv_a = _d3._Weight([h, c.kv_lora_rank + c.qk_rope_head_dim],
-                                init)
-        self.kv_a_norm = _d3._Weight([c.kv_lora_rank], one)
-        self.kv_b = _d3._Weight(
+        self.kv_a = Weight([h, c.kv_lora_rank + c.qk_rope_head_dim], init)
+        self.kv_a_norm = Weight([c.kv_lora_rank], one)
+        self.kv_b = Weight(
             [c.kv_lora_rank, nh * (c.qk_nope_head_dim + c.v_head_dim)], init)
-        self.o = _d3._Weight([nh * c.v_head_dim, h], init)
+        self.o = Weight([nh * c.v_head_dim, h], init)
 
 
 class DeepseekV2Block(nn.Layer):
@@ -234,9 +216,9 @@ class DeepseekV2Block(nn.Layer):
         super().__init__()
         one = I.Constant(1.0)
         self.moe = c.is_moe(layer)
-        self.ln_1 = _d3._Weight([c.hidden_size], one)
+        self.ln_1 = Weight([c.hidden_size], one)
         self.attn = DeepseekV2Attention(c)
-        self.ln_2 = _d3._Weight([c.hidden_size], one)
+        self.ln_2 = Weight([c.hidden_size], one)
         if self.moe:
             self.ffn = HeldMoEMLP(
                 c.hidden_size, c.moe_intermediate_size, c.n_routed_experts,
@@ -247,66 +229,35 @@ class DeepseekV2Block(nn.Layer):
                 n_group=c.n_group, topk_group=c.topk_group,
                 routed_scaling=c.routed_scaling_factor)
         else:
-            self.ffn = _d3.Dots3MLP(c)
+            self.ffn = SwiGLUMLP(c)
 
 
-class TickRecord(_d3.TickRecord):
-    """``models/dots3.TickRecord`` under this model's statistics' names;
-    its ticks hand out no selected set and no window's log-sum."""
+class TickRecord(_tick.TickRecord):
+    """Its ticks hand out no selected set and no window's log-sum."""
     STATS = TICK_STATS
 
 
-class DeepseekV2(nn.Layer):
-    """The served model: weights, what caches it keeps and the tick's
-    forward. ``forward(tokens [s])`` is one prefill of the whole sequence
-    through pools of its own, float logits ``[s, vocab]``: for tests."""
+class DeepseekV2(LayerwiseLM):
+    """The served model: ``LayerwiseLM``'s weights, what caches it keeps and
+    the tick's forward."""
 
     def __init__(self, config: DeepseekV2Config):
-        super().__init__()
-        self.config = config
-        self.embeddings = _d3._Embeddings(config)
-        self.blocks = nn.LayerList([
-            DeepseekV2Block(config, i)
-            for i in range(config.num_hidden_layers)])
-        self.ln_f = _d3._Weight([config.hidden_size], I.Constant(1.0))
-        self.lm_head = _d3._Weight([config.hidden_size, config.vocab_size],
-                                   I.Normal(0.0, config.initializer_range))
+        super().__init__(config, DeepseekV2Block)
 
-    # -- what ServingEngine asks of a model -----------------------------
+    # -- what ServingEngine asks of a model (models/tick.py) -------------
     def cache_spec(self) -> dict:
         c = self.config
         return {"kind": "latent", "full_layers": c.num_hidden_layers,
                 "latent_width": c.kv_lora_rank + c.qk_rope_head_dim,
                 "tick_record": TickRecord}
 
-    _decode_state = _d3.Dots3._decode_state
-
     def ragged_apply(self, stacked, other, pools, tokens, tok_pos, tok_limit,
-                     row_tab, row_pos0, row_len, sample_ix, **kw):
+                     row_tab, row_pos0, row_len, sample_ix, *, decode_rows,
+                     chunk_width, has_chunks=None):
         return deepseek_v2_ragged_apply(
             self.config, stacked, other, pools, tokens, tok_pos, tok_limit,
-            row_tab, row_pos0, row_len, sample_ix, **kw)
-
-    def forward(self, tokens):
-        from ..serving.paged_cache import LatentPools
-
-        toks = jnp.asarray(getattr(tokens, "_value", tokens),
-                           jnp.int32).reshape(-1)
-        s, ps = toks.shape[0], 8
-        pages = -(-s // ps)
-        stacked, other = self._decode_state()
-        spec = self.cache_spec()
-        pools = LatentPools.zeros(
-            spec["full_layers"], pages + 1, 0, 1, ps, spec["latent_width"],
-            0, 0, other["embeddings.wte.weight"].dtype)
-        table = jnp.arange(1, pages + 1, dtype=jnp.int32)[None]
-        pos = jnp.arange(s, dtype=jnp.int32)
-        logits, _, _ = deepseek_v2_ragged_apply(
-            self.config, stacked, other, pools, toks, pos,
-            jnp.full((s,), s, jnp.int32), (table, jnp.zeros_like(table)),
-            jnp.zeros((1,), jnp.int32), jnp.full((1,), s, jnp.int32), pos,
-            decode_rows=0, chunk_width=s)
-        return logits
+            row_tab, row_pos0, row_len, sample_ix, decode_rows, chunk_width,
+            has_chunks=has_chunks)
 
 
 # --------------------------------------------------------------------------
@@ -335,11 +286,11 @@ def _latent_queries(c: DeepseekV2Config, hn, p, pos, rope):
         c.qk_rope_head_dim
     rank, eps = c.kv_lora_rank, c.rms_norm_eps
     inv, cs, _ = rope
-    c_q = _rms(hn @ p["attn.q_a.weight"], p["attn.q_a_norm.weight"], eps)
+    c_q = rms(hn @ p["attn.q_a.weight"], p["attn.q_a_norm.weight"], eps)
     q = (c_q @ p["attn.q_b.weight"]).reshape(-1, nh, nope + rd)
     q_pe = rope_by_table(q[..., nope:], pos, inv, cs)
     kv = hn @ p["attn.kv_a.weight"]
-    c_kv = _rms(kv[:, :rank], p["attn.kv_a_norm.weight"], eps)
+    c_kv = rms(kv[:, :rank], p["attn.kv_a_norm.weight"], eps)
     k_pe = rope_by_table(kv[:, None, rank:], pos, inv, cs)[:, 0]
     w_k = p["attn.kv_b.weight"].reshape(rank, nh,
                                         nope + c.v_head_dim)[..., :nope]
@@ -361,14 +312,14 @@ def _attention_out(c: DeepseekV2Config, x, o_lat, p):
 def deepseek_v2_ragged_apply(c: DeepseekV2Config, stacked, other, pools,
                              tokens, tok_pos, tok_limit, row_tab, row_pos0,
                              row_len, sample_ix, decode_rows: int,
-                             chunk_width: int, impl=None, has_chunks=None):
+                             chunk_width: int, has_chunks=None):
     """Mixed prefill/decode forward over latent pools: the arguments of
     ``models/dots3.dots3_ragged_apply`` (``row_tab`` its pair, of which the
-    windowed layers' tables are not read). ``impl`` names the spelling of
-    ``ops/paged_attention.latent_attention`` (None: the one the platform
-    and the shapes pick). Decode rows and chunk rows attend in the absorbed
-    form, under two scopes (``blk/attn/mla_decode``, ``blk/attn/mla_chunk``)
-    so that a trace tells them apart.
+    windowed layers' tables are not read). Decode rows and chunk rows attend
+    in the absorbed form (``ops/paged_attention.latent_attention``, in the
+    spelling the platform and the shapes pick where this is traced), under
+    two scopes (``blk/attn/mla_decode``, ``blk/attn/mla_chunk``) so that a
+    trace tells them apart.
 
     Returns ``(logits [S, V], pools, aux)``: ``aux["stats"]`` float32
     ``[len(TICK_STATS)]`` (the share of live tokens whose kept groups
@@ -392,7 +343,7 @@ def deepseek_v2_ragged_apply(c: DeepseekV2Config, stacked, other, pools,
     per_group = c.n_routed_experts // c.n_group
     with annotate("tick/embed"):
         x = other["embeddings.wte.weight"][tokens]              # [NT, h]
-    rows_ = _d3.TickRows(ps, nps, tok_pos, tok_limit, row_pos0, nt, nd, w)
+    rows_ = TickRows(ps, nps, tok_pos, tok_limit, row_pos0, nt, nd, w)
     page = rows_.page_of(tab)
     off = tok_pos % ps
     wrote = rows_.touched(page, tab)
@@ -408,22 +359,17 @@ def deepseek_v2_ragged_apply(c: DeepseekV2Config, stacked, other, pools,
 
     def attention(x, pl, p, layer):
         with annotate("blk/qkv"):
-            hn = _rms(x, p["ln_1.weight"], eps)
+            hn = rms(x, p["ln_1.weight"], eps)
             q, row = _latent_queries(c, hn, p, tok_pos, rope)
         with annotate("blk/latent_scatter"):
-            pl = pl._replace(latent=_pa.latent_scatter(
-                pl.latent, page, off, row, layer, wrote))
+            pl = pl.scatter_latent(layer, page, off, row, wrote)
 
-        def attend(rows, t):
-            n = rows.stop - rows.start
-            lo = rows.start if t == 1 else nd
-            with annotate("blk/attn/mla_decode" if t == 1
+        def attend(rows, cut):
+            with annotate("blk/attn/mla_decode" if cut.t == 1
                           else "blk/attn/mla_chunk"):
-                return _pa.latent_attention(
-                    q[lo:lo + n * t].reshape((n, t) + q.shape[1:]),
-                    pl.latent, layer, tab[rows], row_pos0[rows],
-                    row_len[rows], c.kv_lora_rank, scale, impl=impl
-                ).reshape((n * t,) + q.shape[1:2] + (c.kv_lora_rank,))
+                return cut.flat(pl.attend(
+                    layer, cut(q), tab[rows], row_pos0[rows], row_len[rows],
+                    c.kv_lora_rank, scale))
 
         o_lat = rows_.groups(attend)
         with annotate("blk/attn_out"):
@@ -432,7 +378,7 @@ def deepseek_v2_ragged_apply(c: DeepseekV2Config, stacked, other, pools,
 
     def ffn(x, p, moe: bool):
         with annotate("blk/ffn"):
-            h2 = _rms(x, p["ln_2.weight"], eps)
+            h2 = rms(x, p["ln_2.weight"], eps)
             if not moe:
                 mid = jax.nn.silu(h2 @ p["ffn.fc_gate.weight"]) \
                     * (h2 @ p["ffn.fc_in.weight"])
@@ -471,7 +417,7 @@ def deepseek_v2_ragged_apply(c: DeepseekV2Config, stacked, other, pools,
         x, f = ffn(x, p, c.is_moe(i))
         stats_moe.extend(f)
     with annotate("tick/head"):
-        last = _rms(x[sample_ix], other["ln_f.weight"], eps)
+        last = rms(x[sample_ix], other["ln_f.weight"], eps)
         logits = last @ other["lm_head.weight"]                 # [S, V]
         top = jnp.max(logits.astype(jnp.float32), -1)
     n_s = sample_ix.shape[0]

@@ -19,14 +19,14 @@ Two kinds of attention and two kinds of FFN in one stack:
 Text only: the vision and audio towers and the MTP head of the published
 model are no part of this file. There is no training forward.
 
-**Served layer by layer, each layer's weights their own arrays.**
-``ServingEngine`` asks a model what caches it keeps (``cache_spec``), for its
-weights as the tick reads them (``_decode_state``: ``{"layer<i>": {name:
-array}}``, nothing stacked) and for the tick's forward (``ragged_apply``).
-The forward is ``models/gpt.gpt_ragged_apply``'s sibling: the same flat token
-buffer and row metadata, the pools (``serving.paged_cache.LatentPools``,
-stacked by kind of layer and indexed by a static layer) threaded through the
-layers in turn. The layers are unlike, so there is no one block to scan; and
+**Served layer by layer, each layer's weights their own arrays**
+(``models/tick.py``: the protocol ``ServingEngine`` asks of a model, and what
+this file shares with ``models/deepseek_v2.py``). The forward is
+``models/gpt.gpt_ragged_apply``'s sibling: the same flat token buffer and row
+metadata, the pools (``serving.paged_cache.LatentPools``, stacked by kind of
+layer and indexed by a static layer, written and read through their methods)
+threaded through the layers in turn. The layers are unlike, so there is no
+one block to scan; and
 a scan over a run of like layers would slice each layer's held experts out of
 a stack for the Pallas grouped matmul, a copy of 4.6 ms a matrix a tick on a
 v5e (PERF.md section 6, PR 37). ``models/dots3_reference.py`` is the plain
@@ -44,22 +44,21 @@ rotation and fp8 are left out; a query sees ``s > t - window``.
 from __future__ import annotations
 
 import math
-import time
 from dataclasses import dataclass
 from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 from .. import nn
 from ..distributed.moe import HeldMoEMLP, held_moe
 from ..nn import initializer as I
-from ..ops import paged_attention as _pa
-from ..profiler import registry as _registry
-from ..profiler import trace as _ptrace
+from ..ops.paged_attention import select_threshold, selection_mask
 from ..profiler.trace import annotate
-from .gpt import _rms, rope_at
+from . import tick as _tick
+from .gpt import rope_at
+from .tick import (HeldExpertsConfig, LayerwiseLM, SwiGLUMLP, TickRows,
+                   Weight, rms)
 
 FULL, SLIDING = "full_attention", "sliding_attention"
 #: what one tick reports beside its tokens, in this order (``aux["stats"]``)
@@ -70,7 +69,7 @@ INDEX_NORM_EPS = 1e-6
 
 
 @dataclass
-class Dots3Config:
+class Dots3Config(HeldExpertsConfig):
     """Sizes under the names of the model's ``config.json``."""
     vocab_size: int = 152064
     hidden_size: int = 5120
@@ -134,15 +133,6 @@ class Dots3Config:
         if self.qk_rope_head_dim > self.index_head_dim:
             raise ValueError("index_head_dim is under qk_rope_head_dim")
 
-    # the engine's names for what it reads of any served model
-    @property
-    def max_seq_len(self) -> int:
-        return self.max_position_embeddings
-
-    @property
-    def held(self) -> Tuple[int, int]:
-        return self.experts_held or (0, self.n_routed_experts)
-
     def widths(self, kind: str) -> dict:
         pre = "swa_" if kind == SLIDING else ""
         get = lambda k: getattr(self, pre + k)          # noqa: E731
@@ -151,9 +141,6 @@ class Dots3Config:
                 "nope": get("qk_nope_head_dim"),
                 "rope": get("qk_rope_head_dim"), "v": get("v_head_dim"),
                 "theta": float(get("rope_theta"))}
-
-    def is_moe(self, layer: int) -> bool:
-        return layer >= self.first_k_dense_replace
 
     def layer_params(self, layer: int) -> int:
         """Parameters of one layer as held here (the held experts alone)."""
@@ -174,11 +161,6 @@ class Dots3Config:
         f = self.moe_intermediate_size
         return n + h * self.n_routed_experts + self.n_routed_experts \
             + 3 * h * f * (self.held[1] + 1)
-
-    def num_params(self) -> int:
-        return sum(self.layer_params(i)
-                   for i in range(self.num_hidden_layers)) \
-            + 2 * self.vocab_size * self.hidden_size + self.hidden_size
 
     @staticmethod
     def dots3_note_prev():
@@ -206,18 +188,6 @@ class Dots3Config:
         return Dots3Config(**base)
 
 
-class _Weight(nn.Layer):
-    """One matrix ``[rows, cols]`` or vector, ``weight`` (and ``bias``)."""
-
-    def __init__(self, shape, init, bias=False):
-        super().__init__()
-        self.weight = self.create_parameter(list(shape),
-                                            default_initializer=init)
-        if bias:
-            self.bias = self.create_parameter(
-                list(shape), default_initializer=I.Constant(0.0))
-
-
 class Dots3Attention(nn.Layer):
     """The weights of one layer's latent attention, ``kind`` its widths;
     a full layer's carry the indexer's."""
@@ -226,31 +196,22 @@ class Dots3Attention(nn.Layer):
         super().__init__()
         w, h = c.widths(kind), c.hidden_size
         init, one = I.Normal(0.0, c.initializer_range), I.Constant(1.0)
-        self.q_a = _Weight([h, w["q_rank"]], init)
-        self.q_a_norm = _Weight([w["q_rank"]], one)
-        self.q_b = _Weight(
+        self.q_a = Weight([h, w["q_rank"]], init)
+        self.q_a_norm = Weight([w["q_rank"]], one)
+        self.q_b = Weight(
             [w["q_rank"], w["heads"] * (w["nope"] + w["rope"])], init)
-        self.kv_a = _Weight([h, w["kv_rank"] + w["rope"]], init)
-        self.kv_a_norm = _Weight([w["kv_rank"]], one)
-        self.kv_b = _Weight(
+        self.kv_a = Weight([h, w["kv_rank"] + w["rope"]], init)
+        self.kv_a_norm = Weight([w["kv_rank"]], one)
+        self.kv_b = Weight(
             [w["kv_rank"], w["heads"] * (w["nope"] + w["v"])], init)
-        self.o = _Weight([w["heads"] * w["v"], h], init)
-        self.gate = _Weight([h, w["heads"]], init)
+        self.o = Weight([w["heads"] * w["v"], h], init)
+        self.gate = Weight([h, w["heads"]], init)
         if kind == FULL:
-            self.idx_q = _Weight(
+            self.idx_q = Weight(
                 [w["q_rank"], c.index_n_heads * c.index_head_dim], init)
-            self.idx_k = _Weight([h, c.index_head_dim], init)
-            self.idx_k_norm = _Weight([c.index_head_dim], one, bias=True)
-            self.idx_w = _Weight([h, c.index_n_heads], init)
-
-
-class Dots3MLP(nn.Layer):
-    def __init__(self, c: Dots3Config):
-        super().__init__()
-        init = I.Normal(0.0, c.initializer_range)
-        self.fc_gate = _Weight([c.hidden_size, c.intermediate_size], init)
-        self.fc_in = _Weight([c.hidden_size, c.intermediate_size], init)
-        self.fc_out = _Weight([c.intermediate_size, c.hidden_size], init)
+            self.idx_k = Weight([h, c.index_head_dim], init)
+            self.idx_k_norm = Weight([c.index_head_dim], one, bias=True)
+            self.idx_w = Weight([h, c.index_n_heads], init)
 
 
 class Dots3Block(nn.Layer):
@@ -258,9 +219,9 @@ class Dots3Block(nn.Layer):
         super().__init__()
         one = I.Constant(1.0)
         self.kind, self.moe = c.layer_types[layer], c.is_moe(layer)
-        self.ln_1 = _Weight([c.hidden_size], one)
+        self.ln_1 = Weight([c.hidden_size], one)
         self.attn = Dots3Attention(c, self.kind)
-        self.ln_2 = _Weight([c.hidden_size], one)
+        self.ln_2 = Weight([c.hidden_size], one)
         if self.moe:
             self.ffn = HeldMoEMLP(
                 c.hidden_size, c.moe_intermediate_size, c.n_routed_experts,
@@ -270,32 +231,17 @@ class Dots3Block(nn.Layer):
                 scoring="sigmoid", select_bias_range=c.select_bias_range,
                 shared_width=c.moe_intermediate_size)
         else:
-            self.ffn = Dots3MLP(c)
+            self.ffn = SwiGLUMLP(c)
 
 
-class _Embeddings(nn.Layer):
-    def __init__(self, c: Dots3Config):
-        super().__init__()
-        self.wte = _Weight([c.vocab_size, c.hidden_size],
-                           I.Normal(0.0, c.initializer_range))
-
-
-class Dots3(nn.Layer):
-    """The served model: weights, what caches it keeps and the tick's
-    forward. ``forward(tokens [s])`` is one prefill of the whole sequence
-    through pools of its own, float logits ``[s, vocab]``: for tests."""
+class Dots3(LayerwiseLM):
+    """The served model: ``LayerwiseLM``'s weights, what caches it keeps and
+    the tick's forward."""
 
     def __init__(self, config: Dots3Config):
-        super().__init__()
-        self.config = config
-        self.embeddings = _Embeddings(config)
-        self.blocks = nn.LayerList([Dots3Block(config, i)
-                                    for i in range(config.num_hidden_layers)])
-        self.ln_f = _Weight([config.hidden_size], I.Constant(1.0))
-        self.lm_head = _Weight([config.hidden_size, config.vocab_size],
-                               I.Normal(0.0, config.initializer_range))
+        super().__init__(config, Dots3Block)
 
-    # -- what ServingEngine asks of a model -----------------------------
+    # -- what ServingEngine asks of a model (models/tick.py) -------------
     def cache_spec(self) -> dict:
         c = self.config
         n_full = sum(k == FULL for k in c.layer_types)
@@ -306,243 +252,17 @@ class Dots3(nn.Layer):
                 "window_width": c.swa_kv_lora_rank + c.swa_qk_rope_head_dim,
                 "window": c.sliding_window_size, "tick_record": TickRecord}
 
-    def _decode_state(self):
-        token = id(self.embeddings.wte.weight._value)
-        cached = self.__dict__.get("_gen_state")
-        if cached is None or cached[0] != token:
-            cached = (token,) + _decode_state(self)
-            self.__dict__["_gen_state"] = cached
-        return cached[1], cached[2]
-
     def ragged_apply(self, stacked, other, pools, tokens, tok_pos, tok_limit,
-                     row_tab, row_pos0, row_len, sample_ix, **kw):
-        return dots3_ragged_apply(self.config, stacked, other, pools, tokens,
-                                  tok_pos, tok_limit, row_tab, row_pos0,
-                                  row_len, sample_ix, **kw)
-
-    def forward(self, tokens):
-        from ..serving.paged_cache import LatentPools
-
-        toks = jnp.asarray(getattr(tokens, "_value", tokens),
-                           jnp.int32).reshape(-1)
-        s, ps = toks.shape[0], 8
-        pages = -(-s // ps)
-        stacked, other = self._decode_state()
-        spec = self.cache_spec()
-        pools = LatentPools.zeros(
-            spec["full_layers"], pages + 1, spec["window_layers"], pages + 1,
-            ps, spec["latent_width"], spec["index_width"],
-            spec["window_width"], other["embeddings.wte.weight"].dtype)
-        table = jnp.arange(1, pages + 1, dtype=jnp.int32)[None]
-        pos = jnp.arange(s, dtype=jnp.int32)
-        logits, _, _ = dots3_ragged_apply(
-            self.config, stacked, other, pools, toks, pos,
-            jnp.full((s,), s, jnp.int32), (table, table),
-            jnp.zeros((1,), jnp.int32), jnp.full((1,), s, jnp.int32), pos,
-            decode_rows=0, chunk_width=s)
-        return logits
+                     row_tab, row_pos0, row_len, sample_ix, *, decode_rows,
+                     chunk_width, has_chunks=None):
+        return dots3_ragged_apply(
+            self.config, stacked, other, pools, tokens, tok_pos, tok_limit,
+            row_tab, row_pos0, row_len, sample_ix, decode_rows, chunk_width,
+            has_chunks=has_chunks)
 
 
-class TickRecord:
-    """What the ticks said of themselves (``dots3_ragged_apply``'s ``aux``),
-    kept on the host: every drained tick's ``stats`` in the registry
-    (``serving/tick_stat_sum{stat=}`` over ``serving/tick_stat_ticks``, and
-    the latest under ``serving/tick_stat{stat=}``), and, for the requests a
-    caller watches, what the rows that chose their tokens reported.
-    ``ServingEngine`` makes one (``engine.tick_record``) and calls ``tick``
-    for every tick it drains; a request that nobody watches costs nothing
-    beyond the four ``stats``."""
-
-    #: the names of ``aux["stats"]``, in order (a sibling model's record
-    #: names its own)
+class TickRecord(_tick.TickRecord):
     STATS = TICK_STATS
-
-    def __init__(self):
-        #: ``watch(rid)`` says whether request ``rid`` is recorded
-        #: (default: every one; a caller with many requests sets a rule)
-        self.watch = lambda rid: True
-        self._by_rid: dict = {}
-
-    def tick(self, aux: dict, positions, rids):
-        """One drained tick: ``positions`` the cache position each sampled
-        row's query stood at, ``rids`` the requests it emits for. Returns
-        ``note(rid, row)`` for the engine to call for every token it hands
-        to a request, or None where no watched request is among them."""
-        reg = _registry()
-        reg.counter("serving/tick_stat_ticks").add(1)
-        for name, value in zip(self.STATS, np.asarray(aux["stats"])):
-            reg.counter("serving/tick_stat_sum{stat=%s}" % name).add(
-                float(value))
-            reg.gauge("serving/tick_stat{stat=%s}" % name).set(float(value))
-        if not any(self.watch(rid) for rid in rids):
-            return None
-        tops = np.asarray(aux["top_logit"])
-        routed = np.asarray(aux["routed"])
-        wlse = np.asarray(aux["window_lse"])
-
-        def note(rid: int, row: int) -> None:
-            if not self.watch(rid):
-                return
-            rec = self._by_rid.setdefault(rid, {
-                "top": [], "routed": [], "lse": [], "selected": []})
-            rec["top"].append(float(tops[row]))
-            rec["routed"].append(routed[:, row])
-            rec["lse"].append(wlse[:, row])
-            # the first and the latest emitting row's sets, as the tick's
-            # device array: nothing is fetched until ``selected_sets`` asks
-            del rec["selected"][1:]
-            rec["selected"].append((int(positions[row]), aux["selected"],
-                                    row))
-
-        return note
-
-    def forget(self, keep) -> None:
-        """Drops the records of requests not in ``keep``."""
-        self._by_rid = {r: v for r, v in self._by_rid.items() if r in keep}
-
-    def has(self, rid: int) -> bool:
-        return rid in self._by_rid
-
-    def top_logits(self, rid: int) -> Tuple[float, ...]:
-        """The largest logit of the row that chose each token request
-        ``rid`` has been handed."""
-        return tuple(self._by_rid[rid]["top"])
-
-    def selected_sets(self, rid: int) -> list:
-        """``(query position, [the positions selected, ascending, a full
-        layer each])`` of the rows that chose request ``rid``'s first and
-        latest token: the mask its attention applied."""
-        return [(pos, [np.flatnonzero(m) for m in np.asarray(sel[:, row])])
-                for pos, sel, row in self._by_rid[rid]["selected"]]
-
-    def routed_experts(self, rid: int):
-        """``[tokens, expert layers, top_k]`` int32: the experts the row
-        that chose each of request ``rid``'s tokens was routed to."""
-        return np.stack(self._by_rid[rid]["routed"])
-
-    def window_lse(self, rid: int):
-        """``[tokens, sliding layers]`` float32: for the row that chose each
-        of request ``rid``'s tokens, the log of the sum of its
-        exponentiated scores in every sliding layer, mean over the heads."""
-        return np.stack(self._by_rid[rid]["lse"])
-
-
-def _decode_state(model: Dots3):
-    """``(layers, other)``: ``layers["layer<i>"]`` the weights of layer
-    ``i`` by their names within a block, ``other`` the rest by name. A
-    model built under ``LazyGuard`` has no weights yet: they are drawn
-    here, in one jitted call (``state_drawer``)."""
-    from ..framework.lazy import is_abstract
-
-    if any(is_abstract(p) for p in model.parameters()):
-        from ..core import rng
-
-        t_draw = time.perf_counter()
-        state = jax.jit(state_drawer(model))(rng.next_key())
-        _ptrace.charge_setup(
-            "weights", time.perf_counter() - t_draw,
-            sum(a.nbytes for a in jax.tree_util.tree_leaves(state)),
-            where="device")
-        return state
-    per_block, rest = _state_names(model)
-    return ({f"layer{i}": {n: p._value for n, p in zip(names, params)}
-             for i, (names, params) in enumerate(per_block)},
-            {n: p._value for n, p in rest})
-
-
-def _state_names(model: Dots3):
-    """``(names, parameters)`` of every block, and the rest by name."""
-    from ..static.functional import state_tensors
-
-    per_block = [state_tensors(b)[:2] for b in model.blocks]
-    pn, pt, _, _ = state_tensors(model)
-    block_ids = {id(x) for _, ts in per_block for x in ts}
-    return per_block, [(n, p) for n, p in zip(pn, pt)
-                       if id(p) not in block_ids]
-
-
-def state_drawer(model: Dots3):
-    """``key -> (layers, other)`` for a model whose parameters are
-    ``LazyGuard``'s placeholders: every parameter drawn from its recorded
-    initializer, in its own type, as the array the tick will read
-    (``models/gpt._decode_state_drawer``'s sibling, for unlike layers)."""
-    per_block, rest = _state_names(model)
-
-    def draw(params, key):
-        return [p._lazy_initializer(p._value.shape, p._value.dtype,
-                                    jax.random.fold_in(key, j))
-                for j, p in enumerate(params)]
-
-    def drawer(key):
-        keys = jax.random.split(key, len(per_block) + 1)
-        layers = {f"layer{i}": dict(zip(names, draw(params, keys[i])))
-                  for i, (names, params) in enumerate(per_block)}
-        return layers, dict(zip([n for n, _ in rest],
-                                draw([p for _, p in rest], keys[-1])))
-
-    return drawer
-
-
-class TickRows:
-    """One tick's flat token buffer against its rows, as every latent tick
-    forward reads it (``dots3_ragged_apply`` and ``models/deepseek_v2.py``'s
-    sibling): ``nd`` decode rows of one token, then ``nch`` chunk rows of
-    ``w``; ``ps`` the page size and ``nps`` the pages of a slot's table."""
-
-    def __init__(self, ps: int, nps: int, tok_pos, tok_limit, row_pos0,
-                 nt: int, nd: int, w: int):
-        self.ps, self.nps, self.nd, self.w = ps, nps, nd, w
-        self.nch = nch = (nt - nd) // w if w else 0
-        self.row_pos0 = row_pos0
-        parts = [jnp.arange(nd, dtype=jnp.int32)]
-        if nch:
-            parts.append(jnp.repeat(nd + jnp.arange(nch, dtype=jnp.int32),
-                                    w))
-        #: the row of each flat token
-        self.tok_row = jnp.concatenate(parts)
-        self._slot_page = jnp.minimum(tok_pos // ps, nps - 1)
-        self._writes = tok_pos < tok_limit
-
-    def page_of(self, table):
-        """The page of ``table`` [R, NPs] each token writes to (the null
-        page where it writes nothing)."""
-        return jnp.where(self._writes,
-                         table[self.tok_row, self._slot_page], 0)
-
-    def touched(self, pages, table):
-        """The pages this tick's tokens write to: each decode row's and
-        the ``(w - 1) // ps + 2`` a chunk can span (null where there is
-        none)."""
-        nd, nch, w, ps, nps = self.nd, self.nch, self.w, self.ps, self.nps
-        out = [pages[:nd]]
-        if nch:
-            lp = self.row_pos0[nd:nd + nch, None] // ps + jnp.arange(
-                (w - 1) // ps + 2, dtype=jnp.int32)[None, :]
-            out.append(jnp.where(lp < nps, jnp.take_along_axis(
-                table[nd:nd + nch], jnp.minimum(lp, nps - 1), axis=1),
-                0).reshape(-1))
-        return jnp.concatenate(out)
-
-    def live(self, table, row_len):
-        """A token is live if its row holds it and the row a slot's pages
-        (a free slot's decode row rides along on the null page): it is
-        counted."""
-        tok_ix = jnp.concatenate(
-            [jnp.zeros((self.nd,), jnp.int32)]
-            + [jnp.tile(jnp.arange(self.w, dtype=jnp.int32), self.nch)]
-            * bool(self.nch))
-        return (tok_ix < row_len[self.tok_row]) \
-            & (table[self.tok_row, 0] > 0)
-
-    def groups(self, fn):
-        """``fn(rows, width)`` over the decode rows and the chunk rows,
-        back in flat-token order."""
-        outs = []
-        if self.nd:
-            outs.append(fn(slice(0, self.nd), 1))
-        if self.nch:
-            outs.append(fn(slice(self.nd, self.nd + self.nch), self.w))
-        return jax.tree.map(lambda *a: jnp.concatenate(a, 0), *outs)
 
 
 # --------------------------------------------------------------------------
@@ -573,12 +293,12 @@ def _latent_queries(c: Dots3Config, kind: str, hn, p, pos):
     r_kv = math.sqrt(c.hidden_size / w["kv_rank"]) \
         if c.apply_mla_qkv_lora_rescale else 1.0
     eps = c.rms_norm_eps
-    c_q = _rms(hn @ p["attn.q_a.weight"], p["attn.q_a_norm.weight"], eps)
+    c_q = rms(hn @ p["attn.q_a.weight"], p["attn.q_a_norm.weight"], eps)
     c_q = (c_q * r_q).astype(hn.dtype)
     q = (c_q @ p["attn.q_b.weight"]).reshape(-1, nh, nope + rd)
     q_rope = _rope_part(q[..., nope:], pos, w["theta"], 0, rd)
     kv = hn @ p["attn.kv_a.weight"]
-    c_kv = _rms(kv[:, :w["kv_rank"]], p["attn.kv_a_norm.weight"], eps)
+    c_kv = rms(kv[:, :w["kv_rank"]], p["attn.kv_a_norm.weight"], eps)
     c_kv = (c_kv * r_kv).astype(hn.dtype)
     k_rope = _rope_part(kv[:, None, w["kv_rank"]:], pos, w["theta"], 0,
                         rd)[:, 0]
@@ -605,17 +325,16 @@ def _attention_out(c: Dots3Config, kind: str, x, o_lat, gate, p):
 def dots3_ragged_apply(c: Dots3Config, stacked, other, pools, tokens,
                        tok_pos, tok_limit, row_tab, row_pos0, row_len,
                        sample_ix, decode_rows: int, chunk_width: int,
-                       impl=None, has_chunks=None):
+                       has_chunks=None):
     """Mixed prefill/decode forward over latent and windowed pools: the
     arguments of ``gpt_ragged_apply``, with ``pools`` a ``LatentPools`` and
     ``row_tab`` the pair ``(tables of the full layers' pages, tables of the
     windowed layers' pages)``, both ``[R, NPs]``, ``stacked`` the layers'
-    own weights (``{"layer<i>": {...}}``). ``impl`` names the spelling of
-    the full layers' attention (``ops/paged_attention.
-    selected_latent_attention``: ``None`` for the one the platform and the
-    shapes pick, the Pallas kernel on the chip at the published widths;
-    ``"xla"`` / ``"pallas"``); every other read has one spelling.
-    ``has_chunks`` is taken and not used: one body whatever the mix.
+    own weights (``{"layer<i>": {...}}``). The spelling of the full layers'
+    attention is picked where this is traced (``ops/paged_attention.
+    latent_attention_path``: the Pallas kernel on the chip at the published
+    widths); every other read has one spelling. ``has_chunks`` is taken and
+    not used: one body whatever the mix.
 
     Returns ``(logits [S, V], pools, aux)``: ``aux["stats"]`` float32
     ``[len(TICK_STATS)]`` (the mean share of its visible keys a live query
@@ -646,7 +365,7 @@ def dots3_ragged_apply(c: Dots3Config, stacked, other, pools, tokens,
 
     def full_attention(x, pl, p, layer):
         with annotate("blk/qkv"):
-            hn = _rms(x, p["ln_1.weight"], eps)
+            hn = rms(x, p["ln_1.weight"], eps)
             c_q, q, row, gate = _latent_queries(c, FULL, hn, p, tok_pos)
             nj, dj, rd = c.index_n_heads, c.index_head_dim, \
                 c.qk_rope_head_dim
@@ -660,48 +379,26 @@ def dots3_ragged_apply(c: Dots3Config, stacked, other, pools, tokens,
             w_i = (hn @ p["attn.idx_w.weight"]).astype(jnp.float32) \
                 / math.sqrt(nj) / math.sqrt(dj)
         with annotate("blk/latent_scatter"):
-            pl = pl._replace(
-                latent=_pa.latent_scatter(pl.latent, page, off, row, layer,
-                                          wrote),
-                index_k=_pa.latent_scatter(pl.index_k, page, off, k_i,
-                                           layer, wrote))
+            pl = pl.scatter_latent(layer, page, off, row, wrote) \
+                .scatter_index(layer, page, off, k_i, wrote)
         with annotate("blk/index"):
-            def scores(rows, t):
-                n = rows.stop - rows.start
-                lo = rows.start if t == 1 else nd
-                hi = lo + n * t
-                return _pa.index_scores(
-                    q_i[lo:hi].reshape(n, t, nj, dj),
-                    w_i[lo:hi].reshape(n, t, nj), pl.index_k, layer,
-                    tab[rows], row_pos0[rows], row_len[rows]
-                ).reshape(n * t, nps * ps)
-
-            score = groups(scores)                          # [NT, cap]
+            score = groups(lambda rows, cut: cut.flat(pl.index_scores(
+                layer, cut(q_i), cut(w_i), tab[rows], row_pos0[rows],
+                row_len[rows])))                            # [NT, cap]
         with annotate("blk/select"):
-            keys, thr, ties = _pa.select_threshold(score, topk)
+            keys, thr, ties = select_threshold(score, topk)
         # the sampled rows' sets, handed out as the mask the attention
         # applies (the same keys, threshold and ties; a query's visible
         # positions are those with a score)
-        picked = _pa.selection_mask(
+        picked = selection_mask(
             keys[sample_ix], thr[sample_ix], ties[sample_ix]) \
             & (score[sample_ix] > -jnp.inf)
         with annotate("blk/attn/mla"):
             w_ = c.widths(FULL)
-
-            def attend(rows, t):
-                n = rows.stop - rows.start
-                lo = rows.start if t == 1 else nd
-                hi = lo + n * t
-                return _pa.selected_latent_attention(
-                    q[lo:hi].reshape((n, t) + q.shape[1:]), pl.latent,
-                    layer, tab[rows], row_pos0[rows], row_len[rows],
-                    keys[lo:hi].reshape(n, t, -1), thr[lo:hi].reshape(n, t),
-                    ties[lo:hi].reshape(n, t),
-                    w_["kv_rank"], 1.0 / math.sqrt(w_["nope"] + w_["rope"]),
-                    impl=impl
-                ).reshape((n * t,) + q.shape[1:2] + (w_["kv_rank"],))
-
-            o_lat = groups(attend)
+            o_lat = groups(lambda rows, cut: cut.flat(pl.attend_selected(
+                layer, cut(q), tab[rows], row_pos0[rows], row_len[rows],
+                cut(keys), cut(thr), cut(ties), w_["kv_rank"],
+                1.0 / math.sqrt(w_["nope"] + w_["rope"]))))
         with annotate("blk/attn_out"):
             x = _attention_out(c, FULL, x, o_lat, gate, p)
         visible = (tok_pos + 1).astype(jnp.float32)
@@ -712,33 +409,23 @@ def dots3_ragged_apply(c: Dots3Config, stacked, other, pools, tokens,
 
     def sliding_attention(x, pl, p, layer):
         with annotate("blk/qkv"):
-            hn = _rms(x, p["ln_1.weight"], eps)
+            hn = rms(x, p["ln_1.weight"], eps)
             _, q, row, gate = _latent_queries(c, SLIDING, hn, p, tok_pos)
         with annotate("blk/latent_scatter"):
-            pl = pl._replace(window=_pa.latent_scatter(
-                pl.window, wpage, off, row, layer, wwrote))
+            pl = pl.scatter_window(layer, wpage, off, row, wwrote)
         with annotate("blk/attn/swa"):
             w_ = c.widths(SLIDING)
-
-            def attend(rows, t):
-                n = rows.stop - rows.start
-                lo = rows.start if t == 1 else nd
-                o, lse = _pa.window_latent_attention(
-                    q[lo:lo + n * t].reshape((n, t) + q.shape[1:]),
-                    pl.window, layer, wtab[rows], row_pos0[rows],
-                    row_len[rows], c.sliding_window_size, w_["kv_rank"],
-                    1.0 / math.sqrt(w_["nope"] + w_["rope"]))
-                return o.reshape((n * t,) + q.shape[1:2]
-                                 + (w_["kv_rank"],)), lse.reshape(n * t)
-
-            o_lat, lse = groups(attend)
+            o_lat, lse = groups(lambda rows, cut: cut.flat(pl.attend_window(
+                layer, cut(q), wtab[rows], row_pos0[rows], row_len[rows],
+                c.sliding_window_size, w_["kv_rank"],
+                1.0 / math.sqrt(w_["nope"] + w_["rope"]))))
         with annotate("blk/attn_out"):
             x = _attention_out(c, SLIDING, x, o_lat, gate, p)
         return x, pl, lse[sample_ix]
 
     def ffn(x, p, moe: bool):
         with annotate("blk/ffn"):
-            h2 = _rms(x, p["ln_2.weight"], eps)
+            h2 = rms(x, p["ln_2.weight"], eps)
             if not moe:
                 mid = jax.nn.silu(h2 @ p["ffn.fc_gate.weight"]) \
                     * (h2 @ p["ffn.fc_in.weight"])
@@ -776,7 +463,7 @@ def dots3_ragged_apply(c: Dots3Config, stacked, other, pools, tokens,
         x, f = ffn(x, p, c.is_moe(i))
         stats_moe.extend(f)
     with annotate("tick/head"):
-        last = _rms(x[sample_ix], other["ln_f.weight"], eps)
+        last = rms(x[sample_ix], other["ln_f.weight"], eps)
         logits = last @ other["lm_head.weight"]                 # [S, V]
         top = jnp.max(logits.astype(jnp.float32), -1)
     share = jnp.mean(jnp.stack([s for s, _ in stats_sel])) if stats_sel \

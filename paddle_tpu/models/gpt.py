@@ -34,6 +34,7 @@ from ..nn import initializer as I
 from ..profiler import trace as _ptrace
 from ..profiler.trace import annotate
 from ..tensor import arange
+from .tick import LoopRecord, rms
 
 
 @dataclass
@@ -719,13 +720,26 @@ class GPT(nn.Layer):
         eng.reset_results()
         return _T(jnp.asarray(out)), _T(jnp.zeros((b,), jnp.float32))
 
+    # -- what ServingEngine asks of a model (models/tick.py) -------------
     def cache_spec(self) -> dict:
-        """What caches serving keeps for this model (``ServingEngine``
-        reads them from the model): K and V a layer, and a loop step."""
+        """K and V a layer, and a loop step; a looped model's ticks hand out
+        their exit steps, which ``LoopRecord`` keeps."""
         c = self.config
-        return {"kind": "kv", "layers": c.num_layers * c.loop_steps,
+        spec = {"kind": "kv", "layers": c.num_layers * c.loop_steps,
                 "heads": c.num_heads,
-                "head_dim": c.hidden_size // c.num_heads}
+                "head_dim": c.hidden_size // c.num_heads,
+                "loop_steps": c.loop_steps}
+        if c.loop_steps > 1:
+            spec["tick_record"] = LoopRecord
+        return spec
+
+    def ragged_apply(self, stacked, other, pools, tokens, tok_pos, tok_limit,
+                     row_tab, row_pos0, row_len, sample_ix, *, decode_rows,
+                     chunk_width, has_chunks=None):
+        return gpt_ragged_apply(
+            self.config, stacked, other, pools, tokens, tok_pos, tok_limit,
+            row_tab, row_pos0, row_len, sample_ix, decode_rows, chunk_width,
+            has_chunks=has_chunks)
 
     def _decode_state(self):
         """Cached (stacked, other) decode params; rebuilt only when the
@@ -799,17 +813,10 @@ def _ln(x, w, b, eps):
     return (x - m) / jnp.sqrt(var + eps) * w + b
 
 
-def _rms(x, w, eps):
-    """``F.rms_norm``: float32 statistics and scale, cast back."""
-    xf = x.astype(jnp.float32)
-    ms = jnp.mean(jnp.square(xf), axis=-1, keepdims=True)
-    return (xf / jnp.sqrt(ms + eps) * w.astype(jnp.float32)).astype(x.dtype)
-
-
 def _served_norm(cfg: GPTConfig, x, p, name: str):
     """The norm ``name`` of the parameters ``p``, of the model's kind."""
     if cfg.norm == "rmsnorm":
-        return _rms(x, p[name + ".weight"], cfg.layer_norm_eps)
+        return rms(x, p[name + ".weight"], cfg.layer_norm_eps)
     return _ln(x, p[name + ".weight"], p[name + ".bias"],
                cfg.layer_norm_eps)
 
@@ -968,7 +975,7 @@ def gpt_cached_apply(cfg: GPTConfig, stacked, other, ck, cv, tokens, pos0,
 def gpt_ragged_apply(cfg: GPTConfig, stacked, other, pools, tokens,
                      tok_pos, tok_limit, row_tab, row_pos0, row_len,
                      sample_ix, decode_rows: int, chunk_width: int,
-                     impl=None, spec_k: int = 0, has_chunks=None):
+                     spec_k: int = 0, has_chunks=None):
     """Mixed prefill/decode forward over the PAGED cache: every token
     in flight rides one program. ``pools`` is the page pools
     (``serving.paged_cache.Pools``, stacked over layers) — this forward
@@ -1020,7 +1027,9 @@ def gpt_ragged_apply(cfg: GPTConfig, stacked, other, pools, tokens,
     friendly layout of the same raggedness, and the Pallas kernel
     underneath handles either width in one grid). All metadata may be
     traced: one compiled program serves every mix of resident decodes
-    and prompt chunks. Returns (logits [S, V], pools).
+    and prompt chunks. Returns (logits [S, V], pools, aux): ``aux`` what the
+    tick says of itself (``models/tick.py``), of this model nothing, ``{}``,
+    unless it is looped (below).
 
     ``spec_k > 0`` (speculative decoding, serving/spec.py) widens each
     of the ``decode_rows`` slot rows into a **verify row** of
@@ -1065,8 +1074,8 @@ def gpt_ragged_apply(cfg: GPTConfig, stacked, other, pools, tokens,
     cache layer ``t * L + l``, the weights are closed over once, each step
     ends in the final norm (its output starts the next) and hands out its
     state at the sampled rows. The head reads the exit rule's step
-    (``loop_exit``), and the forward returns a third value: the expected and
-    the chosen exit step of each sampled row, float32 ``[2, S]``.
+    (``loop_exit``), and ``aux["exit_steps"]`` is the expected and the
+    chosen exit step of each sampled row, float32 ``[2, S]``.
     """
     _require_served_block(cfg)
     nt = tokens.shape[0]
@@ -1121,18 +1130,18 @@ def gpt_ragged_apply(cfg: GPTConfig, stacked, other, pools, tokens,
                         [q[:nd], q[nd:base, 0].reshape(nd, spec_k, nh, hd)],
                         axis=1)
                     ov = pl.attend(layer, qv, row_tab[:nd], row_pos0[:nd],
-                                   row_len[:nd], impl)
+                                   row_len[:nd])
                     outs.append(ov[:, :1])
                     outs.append(ov[:, 1:].reshape(nd * spec_k, 1, nh, hd))
                 elif nd:
                     outs.append(pl.attend(layer, q[:nd], row_tab[:nd],
-                                          row_pos0[:nd], row_len[:nd], impl))
+                                          row_pos0[:nd], row_len[:nd]))
                 if nch:
                     qp = q[base:, 0].reshape(nch, chunk_width, nh, hd)
 
                     def chunk_rows():
                         return pl.attend(layer, qp, row_tab[nd:],
-                                         row_pos0[nd:], row_len[nd:], impl)
+                                         row_pos0[nd:], row_len[nd:])
 
                     if has_chunks is None:
                         op = chunk_rows()
@@ -1156,7 +1165,7 @@ def gpt_ragged_apply(cfg: GPTConfig, stacked, other, pools, tokens,
         with annotate("tick/head"):
             x = _served_norm(cfg, x, other, "ln_f")
             logits = _served_head(cfg, x[sample_ix, 0], other)  # [S, V]
-        return logits, pools
+        return logits, pools, {}
 
     def loop_step(carry, step):
         xc, pl = stack(*carry, step * nl)
@@ -1172,7 +1181,7 @@ def gpt_ragged_apply(cfg: GPTConfig, stacked, other, pools, tokens,
             states, _exit_gate(states, other), cfg.exit_threshold)
     with annotate("tick/head"):
         logits = _served_head(cfg, last, other)
-    return logits, pools, jnp.stack([expected, chosen])
+    return logits, pools, {"exit_steps": jnp.stack([expected, chosen])}
 
 
 def _gpt_decode_state(model: "GPT"):

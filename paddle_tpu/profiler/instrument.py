@@ -38,6 +38,10 @@ _HLO_COLLECTIVE_RE = re.compile(
     r"(all-reduce|all-gather|all-to-all|collective-permute|"
     r"reduce-scatter|collective-broadcast)(?:-done)?\(")
 _HLO_TYPE_RE = re.compile(r"([a-z][a-z0-9]+)\[([0-9,]*)\]")
+# a tuple of more than five results prints `/*index=5*/` every fifth one:
+# XLA's combiner makes one such all-reduce of a GSPMD step's gradients, and
+# the `=` inside the comment must not end the result type
+_HLO_COMMENT_RE = re.compile(r"/\*.*?\*/")
 
 _DTYPE_BYTES = {
     "f64": 8, "f32": 4, "f16": 2, "bf16": 2, "f8e4m3fn": 1, "f8e5m2": 1,
@@ -106,7 +110,7 @@ def collective_stats(lowered_text: str) -> dict:
         line = lines[i]
         m = _COLLECTIVE_RE.search(line)
         if not m:
-            hm = _HLO_COLLECTIVE_RE.search(line)
+            hm = _HLO_COLLECTIVE_RE.search(_HLO_COMMENT_RE.sub("", line))
             if hm:
                 op = hm.group(2).replace("-", "_")
                 ops[op] = ops.get(op, 0) + 1

@@ -17,9 +17,10 @@ bounded work per scheduler step) and the chunks ride the SAME tick as
 resident decodes, as ragged rows of one
 ``ops/paged_attention.ragged_paged_attention`` call per layer
 ("Ragged Paged Attention": per-row ``(pos0, true_len)`` metadata; a
-decode row is simply ``true_len == 1``). XLA gather spelling is the
-default; a Pallas ragged kernel is opt-in (correct on the chip, its
-speed not measured). Speculative
+decode row is simply ``true_len == 1``), in the spelling the platform
+picks where the tick is traced: the Pallas ragged kernel on a TPU, the
+XLA gather anywhere else. What the engine asks of a model is three
+methods (``models/tick.py``). Speculative
 decoding (``ServingConfig.spec`` = ``SpecConfig(draft_model, k)``,
 ``spec.py``) amortizes the target over k drafted tokens per verify
 tick with greedy acceptance — spec greedy output stays BITWISE equal
@@ -51,23 +52,9 @@ a request never queues behind the ticks in flight on the serving device
 (ISSUE 25). Without it the engine's constructor raises and names the
 setting.
 
-Profiler integration (``paddle_tpu.profiler``): gauges
-``serving/queue_depth``, ``serving/active_slots``,
-``serving/page_util``, ``serving/mixed_rows`` (+ ``_decode`` /
-``_prefill`` split per tick); counters ``serving/tokens_generated``,
-``serving/prefills``, ``serving/prefill_chunks``, ``serving/ticks``,
-``serving/preemptions``, ``serving/requests_finished``,
-``serving/drain_waited``, ``serving/drain_ready``,
-``serving/prefix_lookups``, ``serving/prefix_hit_tokens``,
-``cache_share/*`` (refcount traffic: shares, releases, cow_copies,
-prefix_evictions); histograms ``serving/ttft_ms``,
-``serving/tick_turnaround_ms``, ``serving/submit_ms`` (host time of
-each ``submit()``), ``serving/prefill_queue_wait_ms``,
-``serving/chunk_wait_ms`` (admission -> first chunk open); scheduler
-policy (ISSUE 15, ``sched.py``) counters
-``serving/aged_promotions``/``serving/budget_cuts`` and the
-``serving/spec_k_effective`` gauge. The ONE
-compiled hot-path site (``serving.tick#N``) must stay at ONE trace —
+Profiler integration (``paddle_tpu.profiler``): ``engine.py``'s docstring
+lists every gauge, counter, histogram and event. The ONE compiled
+hot-path site (``serving.tick#N``) must stay at ONE trace —
 ``ServingEngine.compiled_sites`` + the recompile registry make any
 regression assertable (tests do).
 """

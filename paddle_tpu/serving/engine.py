@@ -148,25 +148,24 @@ profiler's program inventory, keyed by ``compiled_sites``.
 """
 from __future__ import annotations
 
-import functools
 import time
 import warnings
 from collections import deque
 from contextlib import contextmanager, nullcontext
 from dataclasses import dataclass, field
-from typing import Dict, List, Optional, Tuple
+from typing import Dict, List, NamedTuple, Optional, Tuple
 
 import numpy as np
 
 import jax
 import jax.numpy as jnp
 
-from ..ops.paged_attention import live_block_share, resolve_impl
+from ..ops.paged_attention import live_block_share
 from ..profiler import events as _events
 from ..profiler import recompile as _recompile
 from ..profiler import registry as _registry
 from ..profiler import trace as _ptrace
-from .paged_cache import LatentPagePool, PagePool, Pools
+from .paged_cache import Pools, page_pool
 from .sched import SCHED_POLICIES, ChunkScheduler, SpecKController
 from .spec import SpecConfig
 
@@ -210,14 +209,6 @@ def _proc_index() -> int:
     from ..profiler.sink import _detect_rank
 
     return _detect_rank()
-
-#: explicit attention_kernel values: the unified mixed-row tick on the
-#: XLA gather spelling (the reference: what the CPU runs and the bitwise
-#: pins are written for), or on the Pallas ragged kernel (the chip's
-#: serving kernel: its work follows each row's live length). The
-#: default, None, is the platform's, resolved where the tick is traced
-#: (``ops/paged_attention.resolve_impl``).
-ATTENTION_KERNELS = ("ragged-xla", "ragged-pallas")
 
 
 @contextmanager
@@ -282,7 +273,6 @@ class ServingConfig:
     top_p: float = 1.0
     eos_token_id: Optional[int] = None
     seed: int = 0
-    attention_kernel: Optional[str] = None  # see ATTENTION_KERNELS
     #: speculative decoding (serving/spec.py SpecConfig: draft model +
     #: k). The engine gains a second
     #: compiled site (the draft tick) and syncs each verify tick —
@@ -324,10 +314,6 @@ class Request:
     #: ``done`` is True so the scheduler forgets it, but it must never
     #: surface as a served output (``run()``/coordinators skip it)
     canceled: bool = False
-    #: a looped model's exit statistics over the tokens drained so far:
-    #: sums of the expected and of the chosen exit step, one term a token
-    exit_expected: float = 0.0
-    exit_chosen: float = 0.0
 
     @property
     def ttft_from(self) -> float:
@@ -336,24 +322,16 @@ class Request:
         return self.submit_t if self.due_t is None else self.due_t
 
 
-class _Inflight:
-    __slots__ = ("tok", "meta", "tick", "dispatch_t", "exit", "aux",
-                 "positions")
-
-    def __init__(self, tok, meta, tick, exit_steps=None, aux=None,
-                 positions=None):
-        self.tok = tok               # device int32 array
-        self.meta = meta             # [(index_into_tok, slot, rid)]
-        self.tick = tick             # the engine's tick that computes it
-        #: a looped model's exit statistics of the sampled rows, device
-        #: float32 [2, num_slots]: expected and chosen exit step
-        self.exit = exit_steps
-        #: what a tick reports of itself beside its tokens, for the
-        #: model's own record (``cache_spec()["tick_record"]``), and the
-        #: cache position each sampled row's query stood at
-        self.aux = aux
-        self.positions = positions
-        self.dispatch_t = time.perf_counter()
+class _Inflight(NamedTuple):
+    tok: jax.Array                   # device int32 array
+    meta: list                       # [(index_into_tok, slot, rid)]
+    tick: int                        # the engine's tick that computes it
+    #: what the tick reports of itself beside its tokens (device arrays by
+    #: name; the model's record reads them) and the cache position each
+    #: sampled row's query stood at
+    aux: dict
+    positions: np.ndarray
+    dispatch_t: float
 
 
 #: one selected-but-not-yet-dispatched prompt chunk of the unified tick
@@ -361,7 +339,9 @@ _Chunk = Tuple[int, int, int, int, int]   # (slot, rid, start, end, t0)
 
 
 class ServingEngine:
-    """Continuous-batching serving runtime for a dense ``GPT`` model.
+    """Continuous-batching serving runtime for a model that gives
+    ``cache_spec()``, ``_decode_state()`` and ``ragged_apply(...)``
+    (``models/tick.py`` states the protocol).
 
     ::
 
@@ -389,22 +369,15 @@ class ServingEngine:
             raise ValueError(
                 f"unknown scheduler {cfg.scheduler!r}; expected one of "
                 f"{SCHED_POLICIES}")
-        kernel = cfg.attention_kernel
-        if kernel is not None and kernel not in ATTENTION_KERNELS:
-            raise ValueError(
-                f"unknown attention kernel {kernel!r}; expected None "
-                f"(the platform's) or one of {ATTENTION_KERNELS}")
         self._spec = cfg.spec
         if self._spec is not None:
-            if getattr(self._spec, "overlap", False) and \
-                    cfg.decode != "sampling":
+            if self._spec.overlap and cfg.decode != "sampling":
                 raise ValueError(
                     "spec.overlap chains the next draft tick on the "
                     "sampled verify tick's device outputs; greedy spec "
                     "has no chained draft build — use decode='sampling'")
             if self._spec.k < 1:
                 raise ValueError("spec.k must be >= 1")
-        self._impl = kernel and kernel.removeprefix("ragged-")
         # process index folded in: ids stay unique when rank-tagged
         # event streams from N processes are merged (ISSUE 13)
         self._eng_id = (_proc_index() << 20) | next(_ENGINE_SEQ)
@@ -418,10 +391,13 @@ class ServingEngine:
         with _ptrace.phase("setup/engine/decode_state"):
             self._stacked, self._other = model._decode_state()
         self._dtype = self._other["embeddings.wte.weight"].dtype
+        #: what caches the model keeps and what its ticks report, from the
+        #: model (``models/tick.py``)
+        caches = model.cache_spec()
+        self._apply = model.ragged_apply
         #: a looped model runs its layers ``loop_steps`` times a tick and
-        #: keeps a cache for every (step, layer); a configuration without
-        #: the field (any model of the pipeline protocol's shape) runs once
-        self._loop_steps = getattr(mcfg, "loop_steps", 1)
+        #: keeps a cache for every (step, layer)
+        self._loop_steps = caches.get("loop_steps", 1)
         if self._loop_steps > 1 and self._spec is not None:
             raise NotImplementedError(
                 "speculative decoding of a looped model: the verify tick "
@@ -440,36 +416,15 @@ class ServingEngine:
         self.prefill_chunk = int(cfg.prefill_chunk) or 2 * ps
         if self.prefill_chunk < 1:
             raise ValueError("prefill_chunk must be >= 1")
-        #: what caches the model keeps, from the model: K and V a layer
-        #: (and loop step) of ``heads x head_dim``, or a latent-attention
-        #: model's latent, indexer-key and windowed pools
-        #: (``models/dots3.py``), which bring the tick's forward with them
-        spec_of = getattr(model, "cache_spec", None)
-        self._caches = spec_of() if spec_of is not None else {
-            "kind": "kv", "layers": mcfg.num_layers * self._loop_steps,
-            "heads": mcfg.num_heads,
-            "head_dim": mcfg.hidden_size // mcfg.num_heads}
-        self._latent = self._caches["kind"] == "latent"
-        self._apply = getattr(model, "ragged_apply", None)
         #: the model's record of what its ticks report of themselves
-        #: (``models/dots3.TickRecord``); None: its ticks report nothing
-        record = self._caches.get("tick_record")
+        #: (``models/tick.py``); None: its ticks report nothing
+        record = caches.get("tick_record")
         self.tick_record = record() if record is not None else None
         with _ptrace.phase("setup/engine/pools"):
-            if self._latent:
-                self._refuse_over_latent_pools(cfg)
-                self.pool = LatentPagePool(
-                    self._caches, num_pages, ps, cfg.num_slots,
-                    pages_per_slot, self.prefill_chunk,
-                    dtype=kv_map[cfg.kv_dtype],
-                    prefix_cache=cfg.prefix_cache)
-            else:
-                self.pool = PagePool(
-                    self._caches["layers"], num_pages, ps,
-                    self._caches["heads"], self._caches["head_dim"],
-                    cfg.num_slots, pages_per_slot,
-                    dtype=kv_map[cfg.kv_dtype],
-                    prefix_cache=cfg.prefix_cache)
+            self.pool = page_pool(
+                caches, num_pages, ps, cfg.num_slots, pages_per_slot,
+                self.prefill_chunk, kv_map[cfg.kv_dtype], cfg.prefix_cache,
+                rewinds=cfg.spec is not None)
         # set once: how deep the pools are, and what the engine holds on
         # the device for the model (one copy of the weights, as served)
         _registry().gauge("serving/cache_layers").set(
@@ -516,8 +471,6 @@ class ServingEngine:
         #: export_held() (disaggregated prefill group, ISSUE 13)
         self._held_ready: set = set()
         self.max_inflight_seen = 0
-        # exit steps of the rows sampled since ``exit_steps()`` last read
-        self._exit_sums, self._exit_rows = np.zeros(2), 0
         # device state
         self._last_tok = jnp.zeros((b_slots,), jnp.int32)
         self._keys = np.zeros((b_slots, 2), np.uint32)
@@ -550,8 +503,7 @@ class ServingEngine:
             self._spec_ctl = (
                 SpecKController(b_slots, self._spec_k,
                                 self._spec.ewma_alpha,
-                                getattr(self._spec,
-                                        "reprobe_every", 0))
+                                self._spec.reprobe_every)
                 if self._spec.adaptive else None)
             #: per-tick cache of tick_depth() results — the probe
             #: state machine advances once per slot per tick even
@@ -566,8 +518,7 @@ class ServingEngine:
             #: draft tick: dispatch the chained draft build against the
             #: verify tick's still-on-device outputs before
             #: materializing them (sampling only)
-            self._spec_overlap = bool(getattr(self._spec, "overlap",
-                                              False))
+            self._spec_overlap = bool(self._spec.overlap)
             #: pending chained draft state: dict with device drafts /
             #: probs plus host validity mask, or None when no chained
             #: tick is in flight
@@ -588,8 +539,7 @@ class ServingEngine:
                     (b_slots, self._spec_k, mcfg.vocab_size),
                     np.float32)
             tick = make_spec_tick(mcfg, b_slots, self._spec_k,
-                                  self.prefill_chunk, self._impl,
-                                  self._tick_site)
+                                  self.prefill_chunk, self._tick_site)
         else:
             tick = self._make_unified_tick()
         # the pools (argument 2 of either tick) are donated and the
@@ -617,34 +567,6 @@ class ServingEngine:
             b_slots * (1 + spec_extra)
             + cfg.prefill_chunks_per_tick
             * (self.prefill_chunk // ps + 2) + 8)
-
-    def _refuse_over_latent_pools(self, cfg: ServingConfig) -> None:
-        """What the engine cannot do for a model whose caches are not K
-        and V, each by what it lacks."""
-        if cfg.spec is not None:
-            raise NotImplementedError(
-                "speculative decoding over latent and windowed pools: the "
-                "verify tick (serving/spec.py make_spec_tick) and the draft "
-                "runner carry Pools of K and V, and a rejected draft would "
-                "have to rewind pages a window has already given back")
-        if cfg.kv_dtype == "int8":
-            raise NotImplementedError(
-                "int8 latent pools: the per-page per-head scales are K's "
-                "and V's; a latent row has no head axis")
-
-    def _require_kv_pools(self, what: str) -> None:
-        if self._latent:
-            raise NotImplementedError(
-                f"{what} over latent and windowed pools: a handoff moves "
-                "Pools of K and V by page (serving/disagg.py), and a "
-                "windowed layer's pages behind the window no longer exist "
-                "to be moved")
-
-    @property
-    def attention_kernel(self) -> str:
-        """The tick's attention, by its ATTENTION_KERNELS name: the
-        configured one, else the one a tick traced now would take."""
-        return "ragged-" + resolve_impl(self._impl)
 
     @property
     def compiled_sites(self) -> Tuple[str, ...]:
@@ -818,7 +740,8 @@ class ServingEngine:
         ever carries chunk rows."""
         began = time.perf_counter()
         if hold_after_prefill:
-            self._require_kv_pools("hold_after_prefill (a prefill group)")
+            self.pool.require("handoff",
+                              "hold_after_prefill (a prefill group)")
         p = np.asarray(prompt_ids, np.int32).reshape(-1)
         t0 = p.shape[0]
         cap = self.pool.slot_capacity
@@ -925,26 +848,20 @@ class ServingEngine:
         return tuple(self._requests[rid].out)
 
     def served_weights(self) -> Tuple[dict, dict]:
-        """The weights as the tick reads them: ``(stacked, other)``,
-        block parameters stacked ``[L, ...]`` by suffix and the rest by
-        name — what ``models.gpt.gpt_ragged_apply`` takes."""
+        """The weights as the tick reads them: ``(stacked, other)``, the
+        model's ``_decode_state()`` (a GPT's block parameters stacked
+        ``[L, ...]`` by suffix and the rest by name)."""
         return self._stacked, self._other
 
     def exit_steps(self, rid: Optional[int] = None) -> Tuple[float, float,
                                                              int]:
-        """A looped model's exit statistics: (mean expected exit step,
-        mean chosen exit step, tokens) over the tokens drained so far, of
-        request ``rid`` or, with none given, of every row sampled since
-        this was last read that way (the gauges ``loop/expected_exit_step``
-        and ``loop/chosen_exit_step`` hold the same means)."""
-        if rid is not None:
-            req = self._requests[rid]
-            n = len(req.out)
-            return (req.exit_expected / max(n, 1),
-                    req.exit_chosen / max(n, 1), n)
-        sums, n = self._exit_sums, self._exit_rows
-        self._exit_sums, self._exit_rows = np.zeros(2), 0
-        return float(sums[0]) / max(n, 1), float(sums[1]) / max(n, 1), n
+        """A looped model's exit statistics, from its record (``models/
+        tick.LoopRecord``): (mean expected exit step, mean chosen exit
+        step, tokens) of request ``rid`` or, with none given, of every row
+        sampled since this was last read that way."""
+        if self._loop_steps > 1:
+            return self.tick_record.exit_steps(rid)
+        return 0.0, 0.0, 0 if rid is None else len(self._requests[rid].out)
 
     def reset_results(self) -> None:
         """Forget finished requests (long-running host keeps memory flat)."""
@@ -1014,7 +931,7 @@ class ServingEngine:
         ``ceil(t0 / page_size)`` pages holding the prompt's KV. The
         slot stays resident until ``release_exported`` — export is
         read-only, so a failed send can simply retry."""
-        self._require_kv_pools("export_held (a KV handoff)")
+        self.pool.require("handoff", "export_held (a KV handoff)")
         if rid not in self._held_ready:
             raise ValueError(f"request {rid} is not held-ready")
         t_span = time.perf_counter()
@@ -1091,7 +1008,7 @@ class ServingEngine:
         local rid, or None when no slot/pages are free right now (the
         caller retries; imports never preempt residents — a transfer
         must not evict committed decode work)."""
-        self._require_kv_pools("admit_prefilled (a KV handoff)")
+        self.pool.require("handoff", "admit_prefilled (a KV handoff)")
         t_span = time.perf_counter()
         p = np.asarray(payload["prompt"], np.int32).reshape(-1)
         t0 = p.shape[0]
@@ -1214,7 +1131,7 @@ class ServingEngine:
         when nothing is cached — the chain may have been evicted since
         it was published, and a missed migration is a perf event, not
         an error."""
-        self._require_kv_pools("export_prefix_chain (prefix migration)")
+        self.pool.require("handoff", "export_prefix_chain (prefix migration)")
         pool = self.pool
         if pool.prefix is None:
             return None
@@ -1250,7 +1167,7 @@ class ServingEngine:
         indexed (0 = pool full right now, or nothing new — both
         perf-only). Raises ValueError on a payload this pool must not
         store (dtype/shape mismatch)."""
-        self._require_kv_pools("import_prefix_chain (prefix migration)")
+        self.pool.require("handoff", "import_prefix_chain (prefix migration)")
         pool = self.pool
         if pool.prefix is None:
             return 0
@@ -1304,9 +1221,8 @@ class ServingEngine:
             with _ptrace.scope("step/drain", tick=ent.tick,
                                waited=int(waited)):
                 toks = np.asarray(ent.tok)
-                exits = None if ent.exit is None else np.asarray(ent.exit)
                 note = None
-                if ent.aux is not None:
+                if self.tick_record is not None:
                     note = self.tick_record.tick(
                         ent.aux, ent.positions, [m[2] for m in ent.meta])
                 now = time.perf_counter()
@@ -1318,11 +1234,6 @@ class ServingEngine:
                     req.out.append(tok)
                     if note is not None:
                         note(rid, idx)
-                    if exits is not None:
-                        req.exit_expected += float(exits[0, idx])
-                        req.exit_chosen += float(exits[1, idx])
-                        self._exit_sums += exits[:, idx]
-                        self._exit_rows += 1
                     _registry().counter("serving/tokens_generated").add(1)
                     if req.first_token_t is None:
                         req.first_token_t = now
@@ -1345,11 +1256,6 @@ class ServingEngine:
                     elif len(req.out) >= req.max_new:
                         self._finish(slot, rid, reason="max_new")
             reg = _registry()
-            if exits is not None and self._exit_rows:
-                reg.gauge("loop/expected_exit_step").set(
-                    self._exit_sums[0] / self._exit_rows)
-                reg.gauge("loop/chosen_exit_step").set(
-                    self._exit_sums[1] / self._exit_rows)
             reg.counter("serving/drain_waited" if waited
                         else "serving/drain_ready").add(1)
             reg.histogram("serving/tick_turnaround_ms").observe(
@@ -1766,7 +1672,7 @@ class ServingEngine:
         with _ptrace.scope("step/build", tick=self._tick_no):
             args, finishers = self._build_unified(chunks, ticking)
         with _ptrace.scope("step/dispatch", tick=self._tick_no):
-            self.pool.pools, tok, self._last_tok, *extra = \
+            self.pool.pools, tok, self._last_tok, aux = \
                 self._run_tick(args)
         meta = [(s, s, self._slot_rid[s]) for s in ticking]
         meta += [(s, s, rid) for s, rid in finishers]
@@ -1774,19 +1680,15 @@ class ServingEngine:
             # chunk-only ticks (no decodes, no finishers) emit nothing
             # worth syncing — queueing them would stall the host on a
             # token vector nobody reads once the window fills
-            if self.tick_record is not None:
-                # where each emitting row's query stood: a decode row at
-                # its slot's length, a finished prompt's at its last token
-                positions = self._slot_len.copy()
-                for s, _, _, end, t0 in chunks:
-                    if end >= t0:
-                        positions[s] = t0 - 1
-                self._inflight.append(_Inflight(
-                    tok, meta, self._tick_no, aux=extra[0],
-                    positions=positions))
-            else:
-                self._inflight.append(_Inflight(tok, meta, self._tick_no,
-                                                *extra))
+            # where each emitting row's query stood: a decode row at its
+            # slot's length, a finished prompt's at its last token
+            positions = self._slot_len.copy()
+            for s, _, _, end, t0 in chunks:
+                if end >= t0:
+                    positions[s] = t0 - 1
+            self._inflight.append(_Inflight(
+                tok, meta, self._tick_no, aux, positions,
+                time.perf_counter()))
         self._tick_no += 1
         self.max_inflight_seen = max(self.max_inflight_seen,
                                      len(self._inflight))
@@ -1805,12 +1707,11 @@ class ServingEngine:
             self._insert_prefix(s, self._requests[rid].prompt, end)
         reg = _registry()
         reg.counter("serving/ticks").add(1)
-        if self._latent:
-            # pages of the windowed layers that no later query can see go
-            # back now: every tick that reads them is already dispatched
-            freed = sum(self.pool.free_behind(s, int(self._slot_len[s]))
-                        for s in set(ticking) | {c[0] for c in chunks})
-            reg.counter("serving/window_pages_freed").add(freed)
+        # pages of windowed layers that no later query can see go back now:
+        # every tick that reads them is already dispatched
+        reg.counter("serving/window_pages_freed").add(sum(
+            self.pool.free_behind(s, int(self._slot_len[s]))
+            for s in set(ticking) | {c[0] for c in chunks}))
         for kind, share in self.pool.live_shares().items():
             reg.gauge("serving/live_pages{pool=%s}" % kind).set(share)
         if self._loop_steps > 1:
@@ -1890,7 +1791,7 @@ class ServingEngine:
     def _make_unified_tick(self):
         """The ONE compiled hot-path program: every resident decode and
         every selected prefill chunk of a scheduler step, as ragged
-        rows of a single ``gpt_ragged_apply`` forward. All metadata is
+        rows of a single forward, the model's ``ragged_apply``. All metadata is
         fixed-shape (pad prefill rows ride with limit 0), so the
         program traces exactly once across any prefill/decode mix,
         admission order, or per-request sampling params, and is one
@@ -1901,17 +1802,10 @@ class ServingEngine:
         a prompt emits its slot's first token via ``sample_ix``, and
         ``emit`` folds emitted tokens back into ``last_tok`` for the
         next tick."""
-        mcfg = self.model_config
         site = self._tick_site
-        impl = self._impl
+        apply = self._apply
         ns = self.config.num_slots
         w = self.prefill_chunk
-
-        from ..models.gpt import gpt_ragged_apply
-
-        # a model that keeps other caches than K and V brings its forward
-        # (same arguments, ``row_tab`` its pools' tables); GPT's is as ever
-        apply = self._apply or functools.partial(gpt_ragged_apply, mcfg)
 
         def tick(stacked, other, pools, fresh, last_tok, pf_toks,
                  tok_pos, tok_limit, row_tab, row_pos0, row_len,
@@ -1932,17 +1826,18 @@ class ServingEngine:
             # writes land on the null page; all-null tables), and
             # ``has_chunks`` only lets the block skip their attention,
             # which reads the pools and returns ``[nch, w, NH, D]``.
-            # a looped model's forward also hands out its exit statistics,
-            # which leave the tick as one more output (no callback)
-            logits, pools, *exit_steps = apply(
+            # what the forward says of itself (``aux``: a looped model's
+            # exit steps, a latent model's statistics) leaves the tick as
+            # outputs beside the tokens, no callback; of no entries, none
+            logits, pools, aux = apply(
                 stacked, other, pools, tokens, tok_pos, tok_limit,
                 row_tab, row_pos0, row_len, sample_ix, decode_rows=ns,
-                chunk_width=w, impl=impl, has_chunks=has_chunks)
+                chunk_width=w, has_chunks=has_chunks)
             with _ptrace.annotate("tick/sample"):
                 nxt = self._sample_tok(logits, keys, sample_pos, temps,
                                        top_ks, top_ps)
                 new_last = jnp.where(emit, nxt, last_tok)
-            return (pools, nxt, new_last, *exit_steps)
+            return pools, nxt, new_last, aux
 
         return tick
 

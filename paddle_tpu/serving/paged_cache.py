@@ -60,7 +60,10 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from ..ops.paged_attention import paged_kv_scatter, ragged_paged_attention
+from ..ops.paged_attention import (
+    index_scores, latent_attention, latent_scatter, paged_kv_scatter,
+    ragged_paged_attention, selected_latent_attention,
+    window_latent_attention)
 
 NULL_PAGE = 0
 
@@ -539,10 +542,11 @@ class Pools(NamedTuple):
                                       vv[:, 0], layer=layer)
         return Pools(k, v, k_scale, v_scale)
 
-    def attend(self, layer, q, page_table, pos0, true_len, impl=None):
-        """``ragged_paged_attention`` of ``q`` over ``layer``'s pages."""
+    def attend(self, layer, q, page_table, pos0, true_len):
+        """``ragged_paged_attention`` of ``q`` over ``layer``'s pages, in the
+        spelling the platform picks where the tick is traced."""
         return ragged_paged_attention(
-            q, self.k, self.v, page_table, pos0, true_len, impl=impl,
+            q, self.k, self.v, page_table, pos0, true_len,
             k_scale=self.k_scale, v_scale=self.v_scale, layer=layer)
 
 
@@ -615,6 +619,23 @@ class PagePool:
         """Register an auxiliary table whose pages come from this
         pool's allocator — its holds join the consistency audit."""
         self._aux.append(aux)
+
+    #: what an engine may ask of its pool that this kind cannot do, each
+    #: with why (``require``): K and V pools do all of it
+    CANNOT: Dict[str, str] = {}
+
+    @classmethod
+    def require(cls, what: str, doing: str) -> None:
+        """Refuses, in this kind of pool's own words, ``doing`` what needs
+        ``what`` of it: ``"rewinds"`` (speculative decoding), ``"int8"``
+        pages, a page ``"handoff"``."""
+        if what in cls.CANNOT:
+            raise NotImplementedError(cls.CANNOT[what].format(doing=doing))
+
+    def free_behind(self, slot: int, frontier: int) -> int:
+        """Pages of ``slot`` that no query at or past ``frontier`` can see,
+        given back: none here, every layer sees its whole slot."""
+        return 0
 
     @property
     def slot_capacity(self) -> int:
@@ -934,9 +955,11 @@ class LatentPools(NamedTuple):
     ``index_k`` or ``window`` of no bytes: width 0, no layers.
 
     A pytree like ``Pools``: the tick takes and returns it as one donated
-    argument and its layer scans carry it. The writes and reads are
-    ``ops/paged_attention``'s ``latent_scatter``, ``index_scores``,
-    ``selected_latent_attention`` and ``window_latent_attention``."""
+    argument and threads it through its layers. Like ``Pools`` it is the one
+    place that knows its format: a forward writes through ``scatter_latent``,
+    ``scatter_index`` and ``scatter_window`` and reads through
+    ``index_scores``, ``attend_selected``, ``attend`` and ``attend_window``,
+    each ``ops/paged_attention``'s function on the field it is for."""
 
     latent: jax.Array
     index_k: jax.Array
@@ -966,6 +989,42 @@ class LatentPools(NamedTuple):
     def reset_scales(self, pages) -> "LatentPools":
         return self
 
+    # -- one layer of a stack, by index, inside the tick: the only code that
+    # -- names the fields. ``page``/``off`` [NT] are each token's target,
+    # -- ``touched`` the pages the tick writes (``latent_scatter``)
+    def scatter_latent(self, layer, page, off, rows, touched):
+        return self._replace(latent=latent_scatter(
+            self.latent, page, off, rows, layer, touched))
+
+    def scatter_index(self, layer, page, off, keys, touched):
+        return self._replace(index_k=latent_scatter(
+            self.index_k, page, off, keys, layer, touched))
+
+    def scatter_window(self, layer, page, off, rows, touched):
+        return self._replace(window=latent_scatter(
+            self.window, page, off, rows, layer, touched))
+
+    def index_scores(self, layer, q_i, w_i, page_table, pos0, true_len):
+        return index_scores(q_i, w_i, self.index_k, layer, page_table, pos0,
+                            true_len)
+
+    def attend_selected(self, layer, q, page_table, pos0, true_len, keys,
+                        thr, ties, c_width: int, scale: float):
+        return selected_latent_attention(
+            q, self.latent, layer, page_table, pos0, true_len, keys, thr,
+            ties, c_width, scale)
+
+    def attend(self, layer, q, page_table, pos0, true_len, c_width: int,
+               scale: float):
+        return latent_attention(q, self.latent, layer, page_table, pos0,
+                                true_len, c_width, scale)
+
+    def attend_window(self, layer, q, page_table, pos0, true_len,
+                      window: int, c_width: int, scale: float):
+        return window_latent_attention(
+            q, self.window, layer, page_table, pos0, true_len, window,
+            c_width, scale)
+
 
 class LatentPagePool(PagePool):
     """``PagePool`` for ``LatentPools``: the full layers' pages are the
@@ -987,16 +1046,34 @@ class LatentPagePool(PagePool):
     have to say which layers it serves: a windowed layer's page is gone
     once the window has passed; and without windowed layers
     ``share_into_slot`` and ``copy_page`` are still not written over latent
-    pools), speculative rewinds (``shrink_slot``) and auxiliary tables."""
+    pools), speculative rewinds (``shrink_slot``), auxiliary tables, int8
+    pages and a page handoff (``CANNOT``)."""
 
     #: False keeps every windowed page for the slot's life (the window
     #: space is then as large as the full layers'): what the tests compare
     #: a freeing run with
     FREE_BEHIND = True
 
+    CANNOT = {
+        "rewinds": (
+            "{doing} over latent and windowed pools: the verify tick "
+            "(serving/spec.py make_spec_tick) and the draft runner carry "
+            "Pools of K and V, and a rejected draft would have to rewind "
+            "pages a window has already given back"),
+        "int8": (
+            "int8 latent pools: the per-page per-head scales are K's and "
+            "V's; a latent row has no head axis"),
+        "handoff": (
+            "{doing} over latent and windowed pools: a handoff moves Pools "
+            "of K and V by page (serving/disagg.py), and a windowed layer's "
+            "pages behind the window no longer exist to be moved"),
+    }
+
     def __init__(self, caches: dict, num_pages: int, page_size: int,
                  num_slots: int, pages_per_slot: int, chunk: int,
                  dtype=jnp.float32, prefix_cache: bool = False):
+        if jnp.dtype(dtype) == jnp.int8:
+            self.require("int8", "kv_dtype='int8'")
         windowed = caches.get("window_layers", 0)
         if prefix_cache and windowed:
             raise NotImplementedError(
@@ -1118,3 +1195,22 @@ class LatentPagePool(PagePool):
             out.append(f"window pages allocated {alloc.num_allocated} != "
                        f"held {len(seen)}")
         return out
+
+
+def page_pool(caches: dict, num_pages: int, page_size: int, num_slots: int,
+              pages_per_slot: int, chunk: int, dtype, prefix_cache: bool,
+              rewinds: bool) -> PagePool:
+    """The pool of a model's ``cache_spec()`` (``models/tick.py``): a
+    ``PagePool`` of K and V or a ``LatentPagePool``, ``chunk`` the tokens of
+    a prefill chunk (a window's pages are sized by it). ``rewinds`` says that
+    the engine will shrink slots (speculative decoding); what a kind of pool
+    cannot do it refuses before anything is allocated."""
+    kind = LatentPagePool if caches["kind"] == "latent" else PagePool
+    if rewinds:
+        kind.require("rewinds", "speculative decoding")
+    if kind is LatentPagePool:
+        return kind(caches, num_pages, page_size, num_slots, pages_per_slot,
+                    chunk, dtype=dtype, prefix_cache=prefix_cache)
+    return kind(caches["layers"], num_pages, page_size, caches["heads"],
+                caches["head_dim"], num_slots, pages_per_slot, dtype=dtype,
+                prefix_cache=prefix_cache)

@@ -433,7 +433,7 @@ def make_draft_tick(cfg, num_slots: int, capacity: int, k: int,
 
 
 def make_spec_tick(mcfg, num_slots: int, k: int, chunk_width: int,
-                   impl: str, site: str):
+                   site: str):
     """Build the spec engine's verify/mixed tick body (jitted by the
     engine; pools donated). This IS the unified mixed-row tick with a
     draft section — same site name, same single-trace contract, the
@@ -505,10 +505,11 @@ def make_spec_tick(mcfg, num_slots: int, k: int, chunk_width: int,
             return out.at[jnp.arange(ns) * (1 + k)].set(tok_ns)
 
         def run(pl_, toks_, pos_, lim_, tab_, p0_, len_, six_, sk):
+            # (a verify tick is K and V alone, of a model that is not
+            # looped, by the engine's refusals: ``aux`` is empty)
             return gpt_ragged_apply(
                 mcfg, stacked, other, pl_, toks_, pos_, lim_, tab_, p0_,
-                len_, six_, decode_rows=ns, chunk_width=w, impl=impl,
-                spec_k=sk)
+                len_, six_, decode_rows=ns, chunk_width=w, spec_k=sk)[:2]
 
         if sample_args is not None:
             keys, sample_pos, temps, top_ks, top_ps, draft_probs = \

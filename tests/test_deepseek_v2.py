@@ -434,7 +434,8 @@ def test_the_dense_kernel_is_the_walk_and_the_softmax(small_blocks, dtype,
     assert not kernel[dead].any()
 
 
-def test_the_dense_path_is_picked_and_counted_where_traced(small_blocks):
+def test_the_dense_path_is_picked_and_counted_where_traced(
+        small_blocks, attention_spelling):
     q, pool, meta = _case((0, 5), (8, 3), 8, jnp.float32)
     reg = metrics.registry()
     names = {p: "serving/latent_attn_calls{path=%s,kind=dense}" % p
@@ -446,14 +447,16 @@ def test_the_dense_path_is_picked_and_counted_where_traced(small_blocks):
     assert reg.counter(names["pallas"]).value == before["pallas"] + 1
     with pytest.raises(ValueError, match="unknown latent attention impl"):
         _attend("mosaic", q, pool, meta)
-    # an engine told to run the kernel runs it in every tick, interpreted
+    # an engine whose ticks are traced with the kernel picked runs it in
+    # every tick, interpreted
     net = build()
-    eng = engine(net, attention_kernel="ragged-pallas")
-    rid = eng.submit(np.arange(11, dtype=np.int32), 4)
-    eng.run()
     plain = engine(net)
     rid2 = plain.submit(np.arange(11, dtype=np.int32), 4)
     plain.run()
+    attention_spelling("pallas")
+    eng = engine(net)
+    rid = eng.submit(np.arange(11, dtype=np.int32), 4)
+    eng.run()
     assert list(eng.tokens_so_far(rid)) == list(plain.tokens_so_far(rid2))
     np.testing.assert_allclose(eng.tick_record.top_logits(rid),
                                plain.tick_record.top_logits(rid2), atol=1e-4)

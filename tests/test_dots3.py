@@ -579,8 +579,11 @@ def test_the_engine_reads_the_caches_from_the_model(net):
                         num_heads=2, max_seq_len=32, loop_steps=2,
                         norm="rmsnorm", position="rope", bias=False,
                         ffn="swiglu", tie_word_embeddings=False))
+    from paddle_tpu.models.tick import LoopRecord
+
     assert gpt.cache_spec() == {"kind": "kv", "layers": 4, "heads": 2,
-                                "head_dim": 16}
+                                "head_dim": 16, "loop_steps": 2,
+                                "tick_record": LoopRecord}
 
 
 def test_a_lazy_model_draws_every_layers_own_weights():

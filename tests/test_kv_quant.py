@@ -48,14 +48,13 @@ def _prompts(net, n, lens, seed=7):
 
 
 def _run(net, prompts, max_new, kv_dtype, *, slots=4, page_size=8,
-         pages_per_slot=None, prefix_cache=True, num_pages=0,
-         attention_kernel="ragged-xla"):
+         pages_per_slot=None, prefix_cache=True, num_pages=0):
     pps = pages_per_slot or -(-(max(len(p) for p in prompts) + max_new)
                               // page_size)
     eng = ServingEngine(net, ServingConfig(
         num_slots=slots, page_size=page_size, pages_per_slot=pps,
         num_pages=num_pages, prefix_cache=prefix_cache,
-        kv_dtype=kv_dtype, attention_kernel=attention_kernel))
+        kv_dtype=kv_dtype))
     rids = [eng.submit(p, max_new) for p in prompts]
     res = eng.run()
     return [res[r] for r in rids], eng

@@ -263,11 +263,13 @@ def test_platform_and_shapes_pick_and_an_explicit_spelling_wins(
 
 
 @pytest.mark.parametrize("plen", [7, 23])
-def test_an_engine_told_to_take_the_kernel_serves_the_same_tokens(plen):
-    """``dots3_ragged_apply`` hands its ``impl`` on: an engine whose
-    configuration names the kernel runs it (interpreted here) in every
-    full layer of every tick, and emits what the XLA spelling's engine
-    emits, the same top logits within float32's reassociation."""
+def test_an_engine_told_to_take_the_kernel_serves_the_same_tokens(
+        plen, attention_spelling):
+    """The full layers' attention is picked where the tick is traced
+    (``latent_attention_path``): an engine whose ticks are traced with the
+    kernel picked runs it (interpreted here) in every full layer of every
+    tick, and emits what the XLA spelling's engine emits, the same top
+    logits within float32's reassociation."""
     from paddle_tpu.models.dots3 import Dots3, Dots3Config
     from paddle_tpu.serving import ServingConfig, ServingEngine
 
@@ -278,10 +280,10 @@ def test_an_engine_told_to_take_the_kernel_serves_the_same_tokens(plen):
     net.eval()
     prompt = np.random.default_rng(0).integers(0, 96, plen).astype(np.int32)
     outs, tops = {}, {}
-    for kernel, path in (("ragged-xla", "xla"), ("ragged-pallas", "pallas")):
+    for path in ("xla", "pallas"):
+        attention_spelling(path)
         eng = ServingEngine(net, ServingConfig(
-            num_slots=3, page_size=4, pages_per_slot=16, prefix_cache=False,
-            attention_kernel=kernel))
+            num_slots=3, page_size=4, pages_per_slot=16, prefix_cache=False))
         before = _calls()
         rid = eng.submit(prompt, 10)
         outs[path] = eng.run()[rid].tolist()

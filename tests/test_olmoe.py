@@ -407,8 +407,9 @@ def test_unknown_block_kinds_are_refused():
 @contextlib.contextmanager
 def frozen_gpt():
     """``paddle_tpu.models.gpt`` answered by the frozen copy: the trainer
-    takes the model it is given and the engine imports the serving forwards
-    by that name when it builds its tick."""
+    and the engine take the model they are given. The frozen ``GPT`` predates
+    the served model's protocol (``models/tick.py``) and is given its two
+    missing methods here, over its own forward."""
     import paddle_tpu.models as models
 
     name = "paddle_tpu.models.gpt"
@@ -422,12 +423,16 @@ def frozen_gpt():
     # ``has_chunks`` (PR 32): it runs every row's attention every tick
     apart = mod.gpt_ragged_apply
 
-    def gpt_ragged_apply(cfg, stacked, other, pools, *a, has_chunks=None,
-                         **kw):
-        logits, k, v = apart(cfg, stacked, other, pools.k, pools.v, *a, **kw)
-        return logits, pools._replace(k=k, v=v)
+    def ragged_apply(self, stacked, other, pools, *a, has_chunks=None, **kw):
+        logits, k, v = apart(self.config, stacked, other, pools.k, pools.v,
+                             *a, **kw)
+        return logits, pools._replace(k=k, v=v), {}
 
-    mod.gpt_ragged_apply = gpt_ragged_apply
+    mod.GPT.ragged_apply = ragged_apply
+    mod.GPT.cache_spec = lambda self: {
+        "kind": "kv", "layers": self.config.num_layers,
+        "heads": self.config.num_heads,
+        "head_dim": self.config.hidden_size // self.config.num_heads}
     live = sys.modules[name]
     sys.modules[name] = models.gpt = mod
     try:

@@ -225,11 +225,14 @@ def _mixed_tick(dtype, seed=11):
 @pytest.mark.parametrize("impl", ["xla", "pallas"])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16, jnp.int8],
                          ids=["float32", "bfloat16", "int8"])
-def test_scatter_and_attend_by_layer_are_the_per_layer_functions(dtype, impl):
+def test_scatter_and_attend_by_layer_are_the_per_layer_functions(
+        dtype, impl, attention_spelling):
     """``Pools.scatter(layer, ...)`` and ``attend(layer, ...)`` on the stacks
     against ``paged_kv_scatter`` and ``ragged_paged_attention`` on
     ``pools.k[layer]``: bit for bit, scales too, the other layers untouched,
-    with the layer traced as the scan hands it over."""
+    with the layer traced as the scan hands it over. ``attend`` names no
+    spelling: it takes the one picked where it is traced."""
+    attention_spelling(impl)
     pools, (page, off, kk, vv), (q, nd, w, tab, pos0, rlen) = \
         _mixed_tick(dtype)
     by_layer = jax.jit(lambda ly: pools.scatter(ly, page, off, kk, vv))
@@ -260,7 +263,7 @@ def test_scatter_and_attend_by_layer_are_the_per_layer_functions(dtype, impl):
                                           err_msg=name)
         assert np.asarray(new.k[layer] != pools.k[layer]).any()
         stacked = jax.jit(lambda ly: rows(
-            lambda *a: new.attend(ly, *a, impl)))(np.int32(layer))
+            lambda *a: new.attend(ly, *a)))(np.int32(layer))
         apart = jax.jit(lambda k, v, ks, vs: rows(
             lambda *a: ragged_paged_attention(
                 a[0], k, v, *a[1:], impl=impl, k_scale=ks, v_scale=vs)))(
@@ -296,7 +299,7 @@ def test_a_tick_without_a_chunk_is_the_tick_with_one(kv_dtype):
     ticking = eng._ticking_slots()
     args, _ = eng._build_unified([], ticking)
     before = eng.pool.pools
-    after, tok, _ = tick(*args)
+    after, tok, _, _ = tick(*args)
 
     ps = eng.pool.page_size
     wrote = {(int(eng.pool.tables[s, eng._slot_len[s] // ps]),
@@ -324,6 +327,6 @@ def test_a_tick_without_a_chunk_is_the_tick_with_one(kv_dtype):
     chunks = eng._collect_chunks()
     assert [c[0] for c in chunks] == [free]
     with_chunk, _ = eng._build_unified(chunks, ticking)
-    _, tok_mixed, _ = tick(*with_chunk)
+    _, tok_mixed, _, _ = tick(*with_chunk)
     np.testing.assert_array_equal(np.asarray(tok)[ticking],
                                   np.asarray(tok_mixed)[ticking])

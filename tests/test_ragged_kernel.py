@@ -11,7 +11,7 @@ import numpy as np
 import pytest
 
 from paddle_tpu.ops import paged_attention as pa
-from paddle_tpu.profiler import metrics
+from paddle_tpu.profiler import metrics, recompile
 
 PS, HD = 4, 32
 BLOCK = pa._BLOCK_TOKENS                 # positions of one KV block
@@ -207,6 +207,10 @@ def test_the_platform_picks_and_an_explicit_spelling_wins(
 
 
 def test_the_engines_default_is_the_platforms(monkeypatch):
+    """An engine names no kernel; its tick takes the platform's where it is
+    traced, and ``serving/attn_calls{path=}`` says which: the XLA spelling
+    when it runs here, the kernel when the same tick is traced for a TPU
+    (one call a group of rows a trace of the layer scan's body)."""
     import paddle_tpu as paddle
     from paddle_tpu.models import GPT, GPTConfig
     from paddle_tpu.serving import ServingConfig, ServingEngine
@@ -215,16 +219,23 @@ def test_the_engines_default_is_the_platforms(monkeypatch):
     net = GPT(GPTConfig(vocab_size=64, hidden_size=32, num_layers=1,
                         num_heads=2, max_seq_len=32))
     net.eval()
-    assert ServingConfig().attention_kernel is None
+    assert "attention_kernel" not in ServingConfig.__dataclass_fields__
+    before = _calls()
     eng = ServingEngine(net, ServingConfig(num_slots=2, page_size=4))
-    assert eng.attention_kernel == "ragged-xla"
+    eng.submit(np.arange(5, dtype=np.int32), 2)
+    eng.run()
+    here = {p: n - before[p] for p, n in _calls().items()}
+    assert here["xla"] > 0 and here["pallas"] == 0
     monkeypatch.setenv("PADDLE_TPU_TARGET_PLATFORM", "tpu")
-    assert eng.attention_kernel == "ragged-pallas"
-    explicit = ServingEngine(net, ServingConfig(
-        num_slots=2, page_size=4, attention_kernel="ragged-xla"))
-    assert explicit.attention_kernel == "ragged-xla"
-    with pytest.raises(ValueError, match="attention kernel"):
-        ServingEngine(net, ServingConfig(attention_kernel="xla"))
+    _, avals = eng._program_args[eng.compiled_sites[0]]
+    # the same tick body under a site of its own: the engine's site keeps
+    # its one trace (other tests count every tick site's traces)
+    eng._tick_site = recompile.unique_site("serving.tick")
+    before = _calls()
+    text = str(jax.make_jaxpr(eng._make_unified_tick())(*avals))
+    there = {p: n - before[p] for p, n in _calls().items()}
+    assert there == {"pallas": here["xla"], "xla": 0}
+    assert "pallas_call" in text
 
 
 def test_live_block_share_is_the_hand_count():

@@ -731,27 +731,42 @@ class TestUnifiedTick:
             assert {"flops", "bytes_accessed", "cost_available"} \
                 <= set(rec)
 
-    def test_kernel_selection(self):
+    def test_kernel_selection(self, attention_spelling):
+        """The tick's attention is picked where the tick is traced
+        (``paged_attention.resolve_impl``) and nowhere else: what an
+        engine's tick took is what ``serving/attn_calls{path=}`` counted
+        while it was traced."""
+        from paddle_tpu.profiler import registry
+
         net = _net()
         cfgkw = dict(num_slots=1, page_size=8, pages_per_slot=2)
-        eng = ServingEngine(net, ServingConfig(
-            attention_kernel="ragged-pallas", **cfgkw))
-        assert eng.attention_kernel == "ragged-pallas"
-        assert ServingEngine(net, ServingConfig(
-            **cfgkw)).attention_kernel == "ragged-xla"
-        # the two-dispatch engine and the alias that predates
-        # attention_kernel are gone (PR 28)
-        for gone in ("cuda", "legacy"):
-            with pytest.raises(ValueError, match="unknown attention"):
-                ServingEngine(net, ServingConfig(
-                    attention_kernel=gone, **cfgkw))
-        with pytest.raises(TypeError, match="attention_impl"):
-            ServingConfig(attention_impl="pallas", **cfgkw)
+
+        def took():
+            calls = {p: registry().counter("serving/attn_calls{path=%s}" % p)
+                     for p in ("xla", "pallas")}
+            before = {p: c.value for p, c in calls.items()}
+            eng = ServingEngine(net, ServingConfig(**cfgkw))
+            eng.submit(np.arange(5, dtype=np.int32), 2)
+            eng.run()
+            return {p: c.value - before[p] for p, c in calls.items()}
+
+        here = took()                       # the CPU: the XLA spelling
+        assert here["xla"] > 0 and here["pallas"] == 0
+        attention_spelling("pallas")
+        kernel = took()
+        assert kernel["pallas"] == here["xla"] and kernel["xla"] == 0
+        # no configuration names a kernel: the option (PR 28's
+        # ``attention_kernel``, and ``attention_impl`` before it) is gone
+        for gone in ("attention_kernel", "attention_impl"):
+            with pytest.raises(TypeError, match=gone):
+                ServingConfig(**{gone: "ragged-pallas"}, **cfgkw)
+        assert not hasattr(ServingEngine, "attention_kernel")
 
 
 @pytest.mark.slow
 class TestRaggedPallasEngine:
-    def test_pallas_engine_greedy_matches_xla_engine(self):
+    def test_pallas_engine_greedy_matches_xla_engine(self,
+                                                     attention_spelling):
         """The unified tick on the Pallas ragged kernel (interpret mode
         on CPU), end to end: mixed prefill/decode rows, slot reuse.
         Online softmax is allclose-not-bitwise vs the XLA gather, so
@@ -765,12 +780,13 @@ class TestRaggedPallasEngine:
         rng = np.random.RandomState(13)
         prompts = [rng.randint(0, 128, (8,)).astype(np.int32)
                    for _ in range(3)]
-        pal = ServingEngine(net, ServingConfig(
-            attention_kernel="ragged-pallas", **cfgkw))
         xla = ServingEngine(net, ServingConfig(**cfgkw))
-        p_rids = [pal.submit(p, 16) for p in prompts]
         x_rids = [xla.submit(p, 16) for p in prompts]
-        p_out, x_out = pal.run(), xla.run()
+        x_out = xla.run()
+        attention_spelling("pallas")        # ticks traced from here on
+        pal = ServingEngine(net, ServingConfig(**cfgkw))
+        p_rids = [pal.submit(p, 16) for p in prompts]
+        p_out = pal.run()
         for pr, xr in zip(p_rids, x_rids):
             np.testing.assert_array_equal(p_out[pr], x_out[xr])
 

@@ -564,7 +564,8 @@ def test_tpu_compile_the_latent_models_forward(monkeypatch):
 
     import paddle_tpu as paddle
     from paddle_tpu.models.dots3 import (FULL, SLIDING, Dots3, Dots3Config,
-                                         dots3_ragged_apply, state_drawer)
+                                         dots3_ragged_apply)
+    from paddle_tpu.models.tick import state_drawer
     from paddle_tpu.serving.paged_cache import LatentPools
 
     dev = _tpu_topology_devices()[0]
@@ -629,7 +630,7 @@ def test_tpu_compile_the_dense_latent_models_forward(monkeypatch):
     import paddle_tpu as paddle
     from paddle_tpu.models.deepseek_v2 import (DeepseekV2, DeepseekV2Config,
                                                deepseek_v2_ragged_apply)
-    from paddle_tpu.models.dots3 import state_drawer
+    from paddle_tpu.models.tick import state_drawer
     from paddle_tpu.serving.paged_cache import LatentPools
 
     dev = _tpu_topology_devices()[0]
