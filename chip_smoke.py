@@ -1232,6 +1232,170 @@ def phase_dsv2(cfg, num_slots: int, page_size: int, pages_per_slot: int,
 
 
 # ---------------------------------------------------------------------------
+# phase 2d: the served gated delta rule and a hybrid model through the engine
+# ---------------------------------------------------------------------------
+# the two kernels of ops/gdn.py against their jax.numpy spellings on the same
+# bf16 operands and float32 states, ||difference|| / ||reference|| of the
+# outputs and of the states they leave (the step is float32 on the vector
+# unit both ways; the chunk's products take bf16 operands both ways and
+# differ by the order of their sums)
+TOL_GDN_OPS = 1e-2
+# a small hybrid model's emitted tokens against the float32 reference: the
+# shortfall of an emitted token's logit below the reference's largest
+TOL_GDN_SHORTFALL = 0.25
+TOL_GDN_WORST = 1.0
+GDN_REQUESTS = ((150, 24), (300, 20), (70, 40))
+
+
+def check_gdn_ops(heads: int, dk: int, dv: int, rows: int, w: int) -> dict:
+    """``gdn_step_rows`` over ``rows`` decode rows and ``gdn_chunk_rows``
+    over one row of ``w`` tokens, from random states, by the path observed
+    here (on the chip: the kernels) against ``xla_step`` and ``xla_chunk``."""
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.ops import gdn
+
+    def operands(seed, n, t):
+        ks = jax.random.split(jax.random.PRNGKey(seed), 6)
+        unit = lambda a: a / jnp.linalg.norm(a, axis=-1, keepdims=True)
+        bf = lambda a: a.astype(jnp.bfloat16)
+        return (bf(unit(jax.random.normal(ks[0], (n, t, heads, dk)))),
+                bf(unit(jax.random.normal(ks[1], (n, t, heads, dk)))),
+                bf(jax.random.normal(ks[2], (n, t, heads, dv))),
+                -jax.nn.softplus(jax.random.normal(ks[3], (n, t, heads))),
+                2 * jax.nn.sigmoid(jax.random.normal(ks[4], (n, t, heads))),
+                jax.random.normal(ks[5], (n, heads, dk, dv)))
+
+    rel = lambda a, b: float(jnp.linalg.norm(                # noqa: E731
+        a.astype(jnp.float32) - b) / jnp.linalg.norm(b))
+    path = gdn.gdn_path(heads, dk, dv)
+    out = {"path": path}
+    # the step: rows 1.. live, one dead row on the null slot
+    q, k, v, g, beta, s0 = operands(1, rows, 1)
+    stack = jnp.zeros((2, rows + 1) + gdn.pack_state(s0).shape[1:],
+                      jnp.float32).at[1, 1:].set(gdn.pack_state(s0))
+    slots = jnp.arange(1, rows + 1).at[1].set(0)
+    args = (q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0])
+    o, after = jax.jit(gdn.gdn_step_rows, static_argnums=6)(
+        *args, stack, 1, slots)
+    want_o, want_s = gdn.xla_step(*args, s0)
+    live = np.asarray(slots) > 0
+    out["step_o"] = rel(o[live], want_o[live])
+    out["step_s"] = rel(gdn.unpack_state(after[1, 1:], heads)[live],
+                        want_s[live])
+    check(bool(jnp.array_equal(after[1, 2], stack[1, 2])),
+          "gdn: a dead row's state moved")
+    # the chunk: a carried state, the last 40 positions pads
+    q, k, v, g, beta, s0 = operands(2, 1, w)
+    stack = jnp.zeros((2, 3) + gdn.pack_state(s0).shape[1:],
+                      jnp.float32).at[1, 2:].set(gdn.pack_state(s0))
+    n_live = jnp.asarray([w - 40])
+    o, after = jax.jit(gdn.gdn_chunk_rows, static_argnums=6)(
+        q, k, v, g, beta, stack, 1, jnp.asarray([2]),
+        jnp.zeros((1,), bool), n_live)
+    want_o, want_s = gdn.xla_chunk(q, k, v, g, beta, s0, n_live)
+    out["chunk_o"] = rel(o[:, :w - 40], want_o[:, :w - 40])
+    out["chunk_s"] = rel(gdn.unpack_state(after[1, 2:], heads), want_s)
+    check(bool(jnp.array_equal(after[1, 1], stack[1, 1])),
+          "gdn: a chunk moved another slot's state")
+    for name in ("step_o", "step_s", "chunk_o", "chunk_s"):
+        check(out[name] <= TOL_GDN_OPS, f"gdn: {name} is {out[name]:.2e} "
+              f"from its jax.numpy spelling (tol {TOL_GDN_OPS})")
+    return out
+
+
+def phase_olmoh(cfg, num_slots: int, page_size: int, pages_per_slot: int,
+                chunk: int, ops_shape, want_path: str,
+                requests=GDN_REQUESTS) -> dict:
+    """Olmo-Hybrid's pass (models/olmo_hybrid.py): the two kernels of the
+    served gated delta rule against their ``jax.numpy`` spellings at
+    ``ops_shape`` (heads, dk, dv, decode rows, chunk tokens), which must go
+    by ``want_path`` (on the chip the kernels'), then a small model of both
+    kinds of layer through the engine (a ``LazyGuard`` model drawn on the
+    device, K/V pages beside a state a slot, prompts of several chunks):
+    what it emitted is the float32 reference's
+    (models/olmo_hybrid_reference.py), and its ticks counted their delta
+    rule by ``want_path``."""
+    import dataclasses as dc
+
+    import jax
+
+    import paddle_tpu as paddle
+    from paddle_tpu.models import olmo_hybrid_reference as ref
+    from paddle_tpu.models.olmo_hybrid import OlmoHybrid
+    from paddle_tpu.profiler import registry
+    from paddle_tpu.serving import ServingConfig, ServingEngine
+
+    errs = check_gdn_ops(*ops_shape)
+    path = errs.pop("path")
+    say("olmoh", f"{ops_shape[0]} heads of {ops_shape[1]} x {ops_shape[2]}, "
+        f"{ops_shape[3]} decode rows and a chunk of {ops_shape[4]}: "
+        + ", ".join(f"{k} {v:.2e}" for k, v in errs.items())
+        + f" from the jax.numpy spellings (allowed {TOL_GDN_OPS}); path "
+        f"here: {path}")
+    check(path == want_path, f"the delta rule of {ops_shape[:3]} went by "
+          f"{path}, not {want_path}")
+    reg = registry()
+    counters = {kind: "gdn/%s_calls{path=%%s}" % kind
+                for kind in ("step", "chunk")}
+    calls0 = {(kind, p): reg.counter(c % p).value
+              for kind, c in counters.items() for p in ("pallas", "xla")}
+    paddle.seed(0)
+    with paddle.LazyGuard():
+        net = OlmoHybrid(cfg)
+    net.eval()
+    net.bfloat16()
+    eng = ServingEngine(net, ServingConfig(
+        num_slots=num_slots, page_size=page_size,
+        pages_per_slot=pages_per_slot, prefill_chunk=chunk,
+        prefix_cache=False))
+    layers, other = eng.served_weights()
+    on_default_platform((layers, other, eng.pool.pools),
+                        "olmoh serving state")
+    weights = reg.gauge("serving/weights_bytes").value
+    check(weights == 2 * cfg.num_params(),
+          f"serving/weights_bytes {weights:.0f} is not one bf16 copy of "
+          f"{cfg.num_params()} parameters")
+    rng = np.random.RandomState(13)
+    prompts = [rng.randint(0, cfg.vocab_size, n).astype(np.int32)
+               for n, _ in requests]
+    rids = [eng.submit(p, new) for p, (_, new) in zip(prompts, requests)]
+    results = eng.run()
+    jax.block_until_ready(eng.pool.pools)
+    check(eng.pool.check_consistency() == [], "the pools' books disagree")
+    config = dc.asdict(cfg)
+    shorts = []
+    for rid, prompt in zip(rids, prompts):
+        out = results[rid]
+        seq = np.concatenate([prompt, out[:-1]])
+        got = ref.forward(((kind, layers[f"layer{i}"])
+                           for i, kind in enumerate(cfg.layer_types)),
+                          other, seq, config)
+        at = np.arange(len(prompt) - 1, len(seq))
+        shorts.append(ref.shortfall(np.asarray(got["state"])[at], other,
+                                    out)[0])
+    shorts = np.concatenate(shorts)
+    worst, median = float(shorts.max()), float(np.median(shorts))
+    check(median <= TOL_GDN_SHORTFALL and worst <= TOL_GDN_WORST,
+          f"an emitted token's logit lies {median:.4f} below the float32 "
+          f"reference's largest at the median (allowed {TOL_GDN_SHORTFALL}) "
+          f"and {worst:.4f} at the worst (allowed {TOL_GDN_WORST})")
+    tick = {kind: sorted(p for p in ("pallas", "xla") if reg.counter(
+        c % p).value > calls0[(kind, p)]) for kind, c in counters.items()}
+    check(tick == {"step": [want_path], "chunk": [want_path]},
+          f"the engine's ticks counted their delta rule by {tick}, not "
+          f"{want_path} (gdn/step_calls, gdn/chunk_calls)")
+    say("olmoh", f"{len(rids)} requests through K/V pages and a state a slot "
+        f"({cfg.num_hidden_layers} layers, chunks of {chunk}): shortfall "
+        f"{median:.4f} at the median (allowed {TOL_GDN_SHORTFALL}), "
+        f"{worst:.4f} at the worst (allowed {TOL_GDN_WORST}); the ticks' "
+        f"delta rule by {tick}; weights {_gb(weights)}")
+    return {"worst": worst, "median": median, "weights_bytes": weights,
+            "tick_paths": tick, **errs}
+
+
+# ---------------------------------------------------------------------------
 # phases 3 and 4: the hybrid trainer
 # ---------------------------------------------------------------------------
 def flash_in_program(tr, tokens, what: str) -> None:
@@ -1622,6 +1786,19 @@ def main() -> int:
     run("dsv2", lambda: phase_dsv2(
         DeepseekV2Config.tiny(hidden_size=256, experts_held=(4, 4)), 3, 4,
         24, [(2, 256) + dense, (20, 1) + dense]))
+    # Olmo-Hybrid: the served delta rule's kernels at its heads (30 of
+    # 96 x 192, the cell's 40 decode rows and chunk of 256), and a small
+    # model of both kinds of layer through the engine, whose heads are of
+    # those sizes too, so that its ticks take the kernels
+    from paddle_tpu.models.olmo_hybrid import OlmoHybridConfig
+
+    run("olmoh", lambda: phase_olmoh(
+        OlmoHybridConfig(
+            vocab_size=1024, hidden_size=512, intermediate_size=1024,
+            num_hidden_layers=4, num_attention_heads=4,
+            num_key_value_heads=4, linear_num_key_heads=4,
+            linear_num_value_heads=4, max_position_embeddings=512),
+        3, 16, 32, 128, (30, 96, 192, 40, 256), "pallas"))
     run("train", lambda: phase_train(cfg, micro=2, n_micro=6, steps=4))
     if len(jax.devices()) >= 4 and "train" not in failed:
         run("multichip", lambda: phase_multichip(

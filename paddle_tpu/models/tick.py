@@ -9,9 +9,13 @@ nothing else of it but ``model.config`` (``vocab_size``, ``max_seq_len``):
     V a cache layer (``paged_cache.Pools``). ``{"kind": "latent",
     "full_layers", "latent_width"}`` and, where the model has them,
     ``"index_width"``, ``"window_layers"``, ``"window_width"``, ``"window"``:
-    latent, indexer-key and windowed pools (``paged_cache.LatentPools``). Two
-    keys are the tick's: ``"loop_steps"`` (the times a tick runs the layers;
-    1 where absent) and ``"tick_record"`` (the class that reads the tick's
+    latent, indexer-key and windowed pools (``paged_cache.LatentPools``).
+    ``{"kind": "state", "layers", "heads", "head_dim", "state_layers",
+    "state_heads", "key_dim", "value_dim", "conv_width", "conv_taps"}``: K and
+    V pages for some layers and, for the others, a recurrent state and a short
+    convolution's history a slot (``paged_cache.StatePools``; ``row_tab`` is
+    then the page tables and each row's state slot). Two keys are the
+    tick's: ``"loop_steps"`` (the times a tick runs the layers; 1 where absent) and ``"tick_record"`` (the class that reads the tick's
     ``aux`` on the host, below; absent: the tick reports nothing).
 ``_decode_state() -> (stacked, other)``
     The weights as the tick reads them, two pytrees of device arrays, cached
@@ -121,8 +125,8 @@ class LayerwiseLM(nn.Layer):
     (``embeddings``, ``blocks`` of ``block(config, i)``, ``ln_f``,
     ``lm_head``) and their state; a model adds ``cache_spec`` and
     ``ragged_apply``. ``forward(tokens [s])`` is one prefill of the whole
-    sequence through latent pools of its own, float logits ``[s, vocab]``:
-    for tests."""
+    sequence through pools of its own (``paged_cache.page_pool`` of its
+    ``cache_spec()``, one slot), float logits ``[s, vocab]``: for tests."""
 
     def __init__(self, config, block):
         super().__init__()
@@ -165,25 +169,26 @@ class LayerwiseLM(nn.Layer):
                 {n: p._value for n, p in rest})
 
     def forward(self, tokens):
-        from ..serving.paged_cache import LatentPools
+        from ..serving.paged_cache import page_pool
 
         toks = jnp.asarray(getattr(tokens, "_value", tokens),
                            jnp.int32).reshape(-1)
         s, ps = toks.shape[0], 8
         pages = -(-s // ps)
+        w = pages * ps                  # the one chunk row: whole pages
         stacked, other = self._decode_state()
-        spec = self.cache_spec()
-        pools = LatentPools.zeros(
-            spec["full_layers"], pages + 1, spec.get("window_layers", 0),
-            pages + 1, ps, spec["latent_width"], spec.get("index_width", 0),
-            spec.get("window_width", 0), other["embeddings.wte.weight"].dtype)
-        table = jnp.arange(1, pages + 1, dtype=jnp.int32)[None]
-        pos = jnp.arange(s, dtype=jnp.int32)
+        pool = page_pool(self.cache_spec(), pages + 1, ps, 1, pages, w,
+                         other["embeddings.wte.weight"].dtype, False, False)
+        pool.grow_slot(0, pages)
+        pos = jnp.arange(w, dtype=jnp.int32)
+        # the tables of a tick of one (empty) decode row and the chunk row,
+        # less the decode row
+        row_tab = jax.tree.map(lambda a: a[1:], pool.row_tables([None, 0]))
         return self.ragged_apply(
-            stacked, other, pools, toks, pos, jnp.full((s,), s, jnp.int32),
-            (table, table), jnp.zeros((1,), jnp.int32),
-            jnp.full((1,), s, jnp.int32), pos, decode_rows=0,
-            chunk_width=s)[0]
+            stacked, other, pool.pools, jnp.pad(toks, (0, w - s)), pos,
+            jnp.full((w,), s, jnp.int32), row_tab,
+            jnp.zeros((1,), jnp.int32), jnp.full((1,), s, jnp.int32),
+            pos[:s], decode_rows=0, chunk_width=w)[0]
 
 
 def _state_names(model):
@@ -302,6 +307,18 @@ class _Cut:
             lambda o: o.reshape((self.n * self.t,) + o.shape[2:]), out)
 
 
+def count_stats(names, stats) -> None:
+    """One drained tick's ``stats`` (a device array, ``names`` in order) into
+    the registry: ``serving/tick_stat_sum{stat=}`` over
+    ``serving/tick_stat_ticks``, and the latest under
+    ``serving/tick_stat{stat=}``."""
+    reg = _registry()
+    reg.counter("serving/tick_stat_ticks").add(1)
+    for name, value in zip(names, np.asarray(stats)):
+        reg.counter("serving/tick_stat_sum{stat=%s}" % name).add(float(value))
+        reg.gauge("serving/tick_stat{stat=%s}" % name).set(float(value))
+
+
 class TickRecord:
     """A latent model's ticks (``aux`` of ``models/dots3.dots3_ragged_apply``
     and its sibling): every drained tick's ``stats`` in the registry
@@ -324,12 +341,7 @@ class TickRecord:
         row's query stood at, ``rids`` the requests it emits for. Returns
         ``note(rid, row)`` for the engine to call for every token it hands
         to a request, or None where no watched request is among them."""
-        reg = _registry()
-        reg.counter("serving/tick_stat_ticks").add(1)
-        for name, value in zip(self.STATS, np.asarray(aux["stats"])):
-            reg.counter("serving/tick_stat_sum{stat=%s}" % name).add(
-                float(value))
-            reg.gauge("serving/tick_stat{stat=%s}" % name).set(float(value))
+        count_stats(self.STATS, aux["stats"])
         if not any(self.watch(rid) for rid in rids):
             return None
         tops = np.asarray(aux["top_logit"])

@@ -424,7 +424,8 @@ class ServingEngine:
             self.pool = page_pool(
                 caches, num_pages, ps, cfg.num_slots, pages_per_slot,
                 self.prefill_chunk, kv_map[cfg.kv_dtype], cfg.prefix_cache,
-                rewinds=cfg.spec is not None)
+                rewinds=cfg.spec is not None,
+                chunk_rows=cfg.prefill_chunks_per_tick)
         # set once: how deep the pools are, and what the engine holds on
         # the device for the model (one copy of the weights, as served)
         _registry().gauge("serving/cache_layers").set(

@@ -60,6 +60,8 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from ..ops.gdn import (gdn_chunk_rows, gdn_step_rows, pack_state,
+                       unpack_state)
 from ..ops.paged_attention import (
     index_scores, latent_attention, latent_scatter, paged_kv_scatter,
     ragged_paged_attention, selected_latent_attention,
@@ -598,6 +600,16 @@ class PagePool:
         # account for their holds (ISSUE 20)
         self._aux: List["AuxPageTable"] = []
 
+    @classmethod
+    def for_spec(cls, caches: dict, num_pages: int, page_size: int,
+                 num_slots: int, pages_per_slot: int, chunk: int, dtype,
+                 prefix_cache: bool) -> "PagePool":
+        """The pool of a ``cache_spec()`` of this kind (``page_pool``)."""
+        del chunk
+        return cls(caches["layers"], num_pages, page_size, caches["heads"],
+                   caches["head_dim"], num_slots, pages_per_slot,
+                   dtype=dtype, prefix_cache=prefix_cache)
+
     # read-only views of the device state; a writer replaces ``pools``
     k = property(lambda self: self.pools.k)
     v = property(lambda self: self.pools.v)
@@ -938,6 +950,11 @@ class AuxPageTable:
         return n
 
 
+def _from_spec(cls, *args, **kw):
+    """``for_spec`` of a pool whose constructor takes the spec itself."""
+    return cls(*args, **kw)
+
+
 class LatentPools(NamedTuple):
     """The pools of a latent-attention model with a sparse indexer in its
     full layers and windowed layers between them (ISSUE 37;
@@ -1069,6 +1086,8 @@ class LatentPagePool(PagePool):
             "pages behind the window no longer exist to be moved"),
     }
 
+    for_spec = classmethod(_from_spec)
+
     def __init__(self, caches: dict, num_pages: int, page_size: int,
                  num_slots: int, pages_per_slot: int, chunk: int,
                  dtype=jnp.float32, prefix_cache: bool = False):
@@ -1197,20 +1216,240 @@ class LatentPagePool(PagePool):
         return out
 
 
+class StatePools(NamedTuple):
+    """The caches of a model whose layers are of two kinds (ISSUE 44;
+    ``models/olmo_hybrid.py``): full attention over K/V pages in some, a
+    gated delta rule's recurrent state in the others, as the device holds
+    them:
+
+    kv     ``Pools``: the full layers' K and V pages, ``[Lf, P, ps, NHr, D]``.
+           ``NHr`` is the heads rounded up to whole tiles of the pools' type
+           (30 heads of bf16 ride at 32, two of zeros: the ragged kernel
+           reads a head by a strided load when the heads fill tiles, and
+           batches a float32 product otherwise)
+    state  ``[Ll, slots + 1, ...]`` float32: one state a linear layer and
+           slot, whatever the context, in ``ops/gdn.pack_state``'s layout
+    conv   ``[Ll, taps - 1, slots + 1, C]``: the positions each linear
+           layer's short convolution looks back on, the oldest first. It is
+           read and written a tap at a time, rows ``[n, C]`` by slot: rows
+           are what a gather or scatter by slot moves as they lie (one
+           scatter over the taps and slots together made XLA:TPU re-lay the
+           whole array around every write, 24 copies of 34 MB a tick, and
+           a slot's taps as one row of ``3 C`` cost more in re-laying the
+           rows than it saved; PERF.md section 6, PR 44)
+
+    Slot 0 of ``state`` and ``conv`` is the null slot, as page 0 is the null
+    page: rows that carry no tenant's token read and write it. A pytree
+    like ``Pools``, and the one place that knows this format: a forward
+    writes and reads K/V through ``scatter`` and ``attend`` as a K/V model
+    does, the convolution's history through ``history`` and
+    ``keep_history``, and the state through ``step`` (decode rows) and
+    ``chunk`` (chunk rows), ``ops/gdn``'s functions on the field they are
+    for. A tenant's first chunk enters at zero (``fresh``): nothing else
+    clears a slot."""
+
+    kv: Pools
+    state: jax.Array
+    conv: jax.Array
+
+    quantized = False
+
+    @classmethod
+    def zeros(cls, caches: dict, num_pages: int, page_size: int,
+              num_slots: int, dtype) -> "StatePools":
+        tile = 8 * (4 // jnp.dtype(dtype).itemsize)
+        one = pack_state(jnp.zeros(
+            (caches["state_heads"], caches["key_dim"], caches["value_dim"]),
+            jnp.float32))
+        return cls(
+            Pools.zeros(caches["layers"], num_pages, page_size,
+                        -(-caches["heads"] // tile) * tile,
+                        caches["head_dim"], dtype),
+            jnp.zeros((caches["state_layers"], num_slots + 1) + one.shape,
+                      jnp.float32),
+            jnp.zeros((caches["state_layers"], caches["conv_taps"] - 1,
+                       num_slots + 1, caches["conv_width"]), dtype))
+
+    @property
+    def page_size(self) -> int:
+        return self.kv.page_size
+
+    def arrays(self) -> Dict[str, jax.Array]:
+        return dict(self.kv.arrays(), state=self.state, conv=self.conv)
+
+    def reset_scales(self, pages) -> "StatePools":
+        return self
+
+    # -- the full layers: K and V pages --------------------------------
+    def _head_rows(self, a):
+        pad = self.kv.k.shape[-2] - a.shape[-2]
+        return jnp.pad(a, ((0, 0),) * (a.ndim - 2) + ((0, pad), (0, 0)))
+
+    def scatter(self, layer, page, off, kk, vv) -> "StatePools":
+        return self._replace(kv=self.kv.scatter(
+            layer, page, off, self._head_rows(kk), self._head_rows(vv)))
+
+    def attend(self, layer, q, page_table, pos0, true_len):
+        return self.kv.attend(layer, self._head_rows(q), page_table, pos0,
+                              true_len)[..., :q.shape[-2], :]
+
+    # -- the linear layers: a state and a history a slot ---------------
+    def history(self, layer, slots, fresh):
+        """``[taps - 1, n, C]``: the positions before each row's first
+        token, zeros where the row is a sequence's first (``fresh``)."""
+        return jnp.where(fresh[None, :, None], 0, jnp.stack(
+            [self.conv[layer, j, slots]
+             for j in range(self.conv.shape[1])]))
+
+    def keep_history(self, layer, slots, hist) -> "StatePools":
+        conv = self.conv
+        for j in range(conv.shape[1]):
+            conv = conv.at[layer, j, slots].set(hist[j].astype(conv.dtype))
+        return self._replace(conv=conv)
+
+    def step(self, layer, slots, q, k, v, g, beta):
+        """``ops/gdn.gdn_step_rows`` at ``layer``'s states."""
+        o, state = gdn_step_rows(q, k, v, g, beta, self.state, layer, slots)
+        return o, self._replace(state=state)
+
+    def chunk(self, layer, slots, fresh, row_len, q, k, v, g, beta):
+        """``ops/gdn.gdn_chunk_rows`` at ``layer``'s states."""
+        o, state = gdn_chunk_rows(q, k, v, g, beta, self.state, layer,
+                                  slots, fresh, row_len)
+        return o, self._replace(state=state)
+
+    def state_of(self, layer, slots, heads: int):
+        """``[n, heads, dk, dv]`` float32: the states a check reads."""
+        return unpack_state(self.state[layer, slots], heads)
+
+
+class StatePagePool(PagePool):
+    """``PagePool`` for ``StatePools``: the full layers' pages are the pool's
+    own, allocated, grown and freed as a K/V model's are; a slot's state and
+    history are slot ``s + 1`` of their arrays for as long as the engine has
+    slot ``s``, so nothing is allocated for them and they never bind.
+
+    ``row_tables`` hands a tick each row's state slot beside its page table.
+    A decode row whose slot has a chunk row in the same tick carries slot 0:
+    the chunk row owns the state that tick. The tick itself tells the other
+    dead decode rows (an empty slot, a slot between two chunks of its
+    prompt) by their page: such a row's token has no page of its slot to be
+    written to, **because a prefill chunk is whole pages** (``chunk %
+    page_size == 0``, required here: a slot between chunks then holds
+    exactly the pages of what it has prefilled).
+
+    What a state cannot do yet is refused by name (``CANNOT``), before
+    anything is allocated."""
+
+    CANNOT = {
+        "prefix": (
+            "{doing} over a recurrent state: a cached K/V page is of no use "
+            "without the linear layers' states at that page's boundary, and "
+            "no snapshot of them is kept (ROADMAP R5)"),
+        "rewinds": (
+            "{doing} over a recurrent state: a rejected draft would have to "
+            "roll the state back, and no snapshot of it is kept; the verify "
+            "tick (serving/spec.py make_spec_tick) carries Pools of K and V"),
+        "int8": (
+            "int8 pages beside a recurrent state: the scales' reset list "
+            "and the quantized ragged kernel are Pools' own, and StatePools "
+            "pads its heads to whole tiles of a float type"),
+        "handoff": (
+            "{doing} over a recurrent state: a handoff moves Pools of K and "
+            "V by page (serving/disagg.py); a slot's state and convolution "
+            "history are not pages and nothing ships them"),
+        "chunk_rows": (
+            "{doing} over a recurrent state: under fifo two chunk rows of a "
+            "tick are one prompt's consecutive chunks, and the second needs "
+            "the state the first leaves; the rows of a tick run side by "
+            "side"),
+    }
+
+    for_spec = classmethod(_from_spec)
+
+    def __init__(self, caches: dict, num_pages: int, page_size: int,
+                 num_slots: int, pages_per_slot: int, chunk: int,
+                 dtype=jnp.float32, prefix_cache: bool = False):
+        if jnp.dtype(dtype) == jnp.int8:
+            self.require("int8", "kv_dtype='int8'")
+        if prefix_cache:
+            self.require("prefix", "prefix_cache=True")
+        if chunk % page_size:
+            raise ValueError(
+                f"prefill_chunk {chunk} is not whole pages of {page_size}: "
+                "a tick tells a slot between two chunks of its prompt by "
+                "the page its decode row's token would need (StatePagePool)")
+        pools = StatePools.zeros(caches, num_pages, page_size, num_slots,
+                                 dtype)
+        super().__init__(caches["layers"], num_pages, page_size,
+                         caches["heads"], caches["head_dim"], num_slots,
+                         pages_per_slot, dtype=dtype, pools=pools)
+        #: slots whose state a chunk row has entered since they were released
+        self._stateful = np.zeros(num_slots, bool)
+        _registry().gauge("serving/state_bytes").set(
+            float(pools.state.nbytes + pools.conv.nbytes))
+
+    def live_shares(self) -> Dict[str, float]:
+        return {"kv": self.allocator.utilization(),
+                "state": float(np.mean(self._stateful))}
+
+    def row_tables(self, rows):
+        """``(page tables [R, NPs], state slots [R])``: the rows are the
+        engine's, a decode row a slot and then the chunk rows."""
+        chunks = [s for s in rows[self.num_slots:] if s is not None]
+        if len(rows) - self.num_slots > 1:
+            self.require("chunk_rows", "prefill_chunks_per_tick > 1")
+        self._stateful[chunks] = True
+        slots = np.asarray(
+            [0 if s is None or (i < self.num_slots and s in chunks)
+             else s + 1 for i, s in enumerate(rows)], np.int32)
+        return _rows_of(self.tables, rows), slots
+
+    def release_slot(self, slot: int) -> int:
+        self._stateful[slot] = False
+        return super().release_slot(slot)
+
+    def share_into_slot(self, slot: int, pages) -> None:
+        self.require("prefix", "sharing pages into a slot")
+
+    def shrink_slot(self, slot: int, keep_pages: int) -> int:
+        self.require("rewinds", "rewinding a slot")
+
+    def register_aux(self, aux) -> None:
+        self.require("rewinds", "an auxiliary page table")
+
+    def check_consistency(self) -> List[str]:
+        out = super().check_consistency()
+        for slot in np.flatnonzero(self._stateful):
+            if not self._held[slot]:
+                out.append(f"slot {int(slot)} holds a state and no page")
+        want = (self.num_slots + 1,)
+        for name, a, axis in (("state", self.pools.state, 1),
+                              ("conv", self.pools.conv, 2)):
+            if a.shape[axis:axis + 1] != want:
+                out.append(f"{name} holds {a.shape[axis]} slots, not "
+                           f"{want[0]} (the null slot and one a slot)")
+        return out
+
+
+#: the pool of each kind of ``cache_spec()`` (``models/tick.py``)
+POOL_KINDS = {"kv": PagePool, "latent": LatentPagePool,
+              "state": StatePagePool}
+
+
 def page_pool(caches: dict, num_pages: int, page_size: int, num_slots: int,
               pages_per_slot: int, chunk: int, dtype, prefix_cache: bool,
-              rewinds: bool) -> PagePool:
-    """The pool of a model's ``cache_spec()`` (``models/tick.py``): a
-    ``PagePool`` of K and V or a ``LatentPagePool``, ``chunk`` the tokens of
-    a prefill chunk (a window's pages are sized by it). ``rewinds`` says that
-    the engine will shrink slots (speculative decoding); what a kind of pool
-    cannot do it refuses before anything is allocated."""
-    kind = LatentPagePool if caches["kind"] == "latent" else PagePool
+              rewinds: bool, chunk_rows: int = 1) -> PagePool:
+    """The pool of a model's ``cache_spec()`` (``models/tick.py``), of the
+    kind it names (``POOL_KINDS``); ``chunk`` the tokens of a prefill chunk
+    (a window's pages are sized by it) and ``chunk_rows`` the chunk rows of
+    a tick. ``rewinds`` says that the engine will shrink slots (speculative
+    decoding); what a kind of pool cannot do it refuses before anything is
+    allocated."""
+    kind = POOL_KINDS[caches["kind"]]
     if rewinds:
         kind.require("rewinds", "speculative decoding")
-    if kind is LatentPagePool:
-        return kind(caches, num_pages, page_size, num_slots, pages_per_slot,
-                    chunk, dtype=dtype, prefix_cache=prefix_cache)
-    return kind(caches["layers"], num_pages, page_size, caches["heads"],
-                caches["head_dim"], num_slots, pages_per_slot, dtype=dtype,
-                prefix_cache=prefix_cache)
+    if chunk_rows > 1:
+        kind.require("chunk_rows", "prefill_chunks_per_tick > 1")
+    return kind.for_spec(caches, num_pages, page_size, num_slots,
+                         pages_per_slot, chunk, dtype, prefix_cache)

@@ -1,0 +1,8 @@
+"""Share of the state pool's slots that hold a tenant's state (the gauge
+``serving/live_pages{pool=state}`` as the run's last tick left it): a state
+is a slot's whatever the context, so this is the share of the 1.1 GB in use."""
+
+
+def read(run):
+    share = run["facts"].get("live_state_share")
+    return None if share is None else 100.0 * share

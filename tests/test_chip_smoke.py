@@ -241,6 +241,24 @@ def test_dsv2_rehearsal():
     assert out["weights_bytes"] == 2 * cfg.num_params()
 
 
+def test_olmoh_rehearsal():
+    """Olmo-Hybrid's pass at toy sizes: the served delta rule against its
+    spellings (off the chip: the spellings themselves), a small model's
+    tokens through K/V pages and a state a slot against the float32
+    reference."""
+    from paddle_tpu.models.olmo_hybrid import OlmoHybridConfig
+
+    cfg = OlmoHybridConfig.tiny(initializer_range=0.05)
+    out = chip_smoke.phase_olmoh(cfg, 3, 4, 24, 8, (6, 24, 48, 5, 128),
+                                 "xla", requests=((23, 12), (41, 10),
+                                                  (7, 16)))
+    assert out["tick_paths"] == {"step": ["xla"], "chunk": ["xla"]}
+    assert max(out["step_o"], out["step_s"], out["chunk_o"],
+               out["chunk_s"]) <= chip_smoke.TOL_GDN_OPS
+    assert out["median"] <= out["worst"] <= chip_smoke.TOL_GDN_WORST
+    assert out["weights_bytes"] == 2 * cfg.num_params()
+
+
 def test_a_submit_behind_the_ticks_in_flight_fails_the_serve_phase():
     limit = chip_smoke.SUBMIT_LIMIT_MS
     # with nothing in flight a slow submit proves nothing

@@ -673,3 +673,64 @@ def test_tpu_compile_the_dense_latent_models_forward(monkeypatch):
     ma = compiled.memory_analysis()
     assert ma.alias_size_in_bytes >= int(np.prod(pools.latent.shape)) * 2, \
         "the donated latent pool is not aliased"
+
+
+def test_tpu_compile_the_hybrid_models_forward(monkeypatch):
+    """ISSUE 44: Olmo-Hybrid's tick forward
+    (``models/olmo_hybrid.olmo_hybrid_ragged_apply``: K/V pages at 32 head
+    rows for 30 heads, a float32 state and a convolution's history a slot)
+    compiles for the v5e from a ``LazyGuard`` model at the published widths
+    of one period (three linear layers and a full one), the cell's 40 decode
+    rows and its chunk row of 256: the two kernels of the served delta rule
+    and the ragged kernel are in the program (a chunk row attends in pieces
+    of 32 queries: 64 of 32 head rows pass the kernel's VMEM), and the
+    donated pools, the 1.1 GB of states among them, are aliased."""
+    import jax.numpy as jnp
+
+    import paddle_tpu as paddle
+    from paddle_tpu.models.olmo_hybrid import (OlmoHybrid, OlmoHybridConfig,
+                                               olmo_hybrid_ragged_apply)
+    from paddle_tpu.models.tick import state_drawer
+    from paddle_tpu.serving.paged_cache import StatePools
+
+    dev = _tpu_topology_devices()[0]
+    cfg = OlmoHybridConfig(num_hidden_layers=4, vocab_size=1024)
+    with paddle.LazyGuard():
+        net = OlmoHybrid(cfg)
+    net.bfloat16()
+    state = jax.eval_shape(state_drawer(net),
+                           jax.ShapeDtypeStruct((2,), jnp.uint32))
+    ns, ps, nps, w = 40, 16, 88, 256
+    pools = jax.eval_shape(lambda: StatePools.zeros(
+        net.cache_spec(), ns * nps + 1, ps, ns, jnp.bfloat16))
+    assert pools.kv.k.shape == (1, ns * nps + 1, ps, 32, 128)
+    assert pools.state.shape == (3, ns + 1, 15, 96, 384)
+    nt = ns + w
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32)
+
+    args = (state[0], state[1], pools, i32(nt), i32(nt), i32(nt),
+            (i32(ns + 1, nps), i32(ns + 1)), i32(ns + 1), i32(ns + 1),
+            i32(ns))
+    args = jax.tree_util.tree_map(
+        lambda a: _on_tpu(dev, a.shape, a.dtype), args)
+
+    def forward(*a):
+        return olmo_hybrid_ragged_apply(cfg, *a, decode_rows=ns,
+                                        chunk_width=w)
+
+    monkeypatch.setenv("PADDLE_TPU_TARGET_PLATFORM", "tpu")
+    with jax.default_matmul_precision("default"):
+        compiled = jax.jit(forward, donate_argnums=2).lower(*args).compile()
+    text = compiled.as_text()
+    assert len(re.findall(r"%gdn_step[\w.\-]* = ", text)) == 3
+    assert len(re.findall(r"%gdn_chunk[\w.\-]* = ", text)) == 3
+    assert len(re.findall(r"%ragged_paged_attn[\w.\-]* = ", text)) == 2
+    ma = compiled.memory_analysis()
+    pool_bytes = sum(a.size * a.dtype.itemsize
+                     for a in jax.tree_util.tree_leaves(pools))
+    assert ma.alias_size_in_bytes >= pool_bytes, \
+        "the donated pools and states are not aliased"
+    assert ma.temp_size_in_bytes < pools.state.size * 4 / 3, \
+        "a layer's states are copied"

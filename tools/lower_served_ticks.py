@@ -1,4 +1,4 @@
-"""The lowered serving ticks of the five served configurations, for a TPU v5e,
+"""The lowered serving ticks of the six served configurations, for a TPU v5e,
 without the chip: the proof that a refactor of the engine <-> model <-> cache
 seam left every cell's program as it was (ISSUE 43).
 
@@ -8,8 +8,9 @@ seam left every cell's program as it was (ISSUE 43).
 Run the first form once from the root of the parent's tree and once from the
 change's (``git archive <commit> | tar -x -C <dir>``), with ``JAX_PLATFORMS=cpu``:
 an engine is built and ticked on the CPU at the rows of ``gpt3-1.3b-serve`` and
-``ouro-2.6b-serve``, at a tiny width with a draft model (both ticks), and for a
-dots3 and a DeepSeek-V2 model at the published head counts and latent widths;
+``ouro-2.6b-serve``, at a tiny width with a draft model (both ticks), for a
+dots3 and a DeepSeek-V2 model at the published head counts and latent widths,
+and for an Olmo-Hybrid model at the published head sizes (PR 44);
 each tick is lowered again from the avals of its first dispatch as a program
 traced for the TPU (the attention kernels inside), and its StableHLO text,
 which carries no locations, is written to ``<out_dir>/<name>.<site>.txt``,
@@ -181,3 +182,28 @@ with paddle.LazyGuard():
         moe_intermediate_size=128, num_hidden_layers=2, n_routed_experts=16,
         experts_held=(0, 2), q_lora_rank=128))
 lower("dsv2", latent(v2, prefill_chunks_per_tick=2))
+
+# a hybrid of linear-attention and full layers at its published head sizes
+# (4 of each kind's heads over a small hidden size): K/V pages beside a
+# state a slot. A tree without the model (before PR 44) writes no file for it.
+try:
+    from paddle_tpu.models.olmo_hybrid import OlmoHybrid, OlmoHybridConfig
+except ImportError:
+    OlmoHybrid = None
+if OlmoHybrid is not None:
+    paddle.seed(0)
+    with paddle.LazyGuard():
+        hybrid = OlmoHybrid(OlmoHybridConfig(
+            vocab_size=512, hidden_size=512, intermediate_size=512,
+            num_hidden_layers=4, num_attention_heads=4,
+            num_key_value_heads=4, linear_num_key_heads=4,
+            linear_num_value_heads=4, max_position_embeddings=2048))
+    hybrid.bfloat16()
+    eng = ServingEngine(hybrid, ServingConfig(
+        num_slots=4, page_size=16, pages_per_slot=88, prefill_chunk=256,
+        prefix_cache=False))
+    eng.submit(np.arange(300, dtype=np.int32) % 512, 2)
+    for _ in range(3):
+        eng.step()
+    eng.drain(0)
+    lower("olmoh", eng)
