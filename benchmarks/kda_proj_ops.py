@@ -12,7 +12,11 @@ summed by instruction name without its number (``multiply_add_fusion`` of
 ``%multiply_add_fusion.12``) and the result's type, milliseconds a step and how
 many ran: which fusions a part is made of, and which float32 ``[.., 8192,
 8192]`` results are among them. It adds up no metric; the metrics are the
-benchmark's.
+benchmark's. A part of any trainer's step as ``train.*_ms_per_step`` cuts it
+(``head``, ``unscoped``, ``dense``, ``opt``, ``flash_fwd``, ``flash_bwd``:
+``_program_trace.step_part``) is listed the same way after a ``--trace 1``
+run of any training cell, each operation with the innermost scope it lies
+under; on several chips the times are the mean of a chip's step.
 """
 import json
 import os
@@ -44,6 +48,8 @@ def main():
     if doc is None:
         raise SystemExit("no trace in .perfbench_trace: run a cell with "
                          "--trace 1 first")
+    part_of, title = (pt.step_part, part) if part in pt.STEP_ORDER \
+        else (kt.kda_part, f"blk/kda/{part}")
     by_name = defaultdict(lambda: {"ms": 0.0, "n": 0})
     steps = 0
     for plane in tracered.device_planes(doc):
@@ -52,11 +58,13 @@ def main():
         inside = tracered.merge(tracered.intervals(runs))
         for ev in tracered.op_events(plane):
             iv = (ev["start_ns"], ev["start_ns"] + ev["dur_ns"])
-            if kt.kda_part(ev) != part \
+            if part_of(ev) != part \
                     or not tracered.intersection_ns([iv], inside):
                 continue
             result = result_type(ev["name"])
             name = re.sub(r"\.\d+$", "", tracered.short_name(ev))
+            if part_of is pt.step_part:
+                name = f"{name} [{pt.scope_name(ev)}]"
             rec = by_name[name, result]
             rec["ms"] += ev["dur_ns"] / 1e6
             rec["n"] += 1
@@ -69,12 +77,12 @@ def main():
     total = sum(r["ms_per_step"] for r in table)
     wide = sum(r["ms_per_step"] for r in table
                if re.search(r"f32\[(\d+,)?8192,8192\]", r["result"]))
-    print(f"blk/kda/{part}: {total:.2f} ms a step over {steps} traced "
+    print(f"{title}: {total:.2f} ms a step over {steps} traced "
           f"step(s), {len(table)} kinds of operation; {wide:.2f} ms in "
           "operations whose result is float32 [.., 8192, 8192]")
     for r in table[:rows]:
         print(f"  {r['ms_per_step']:9.3f} ms  x{r['runs_per_step']:6.1f}  "
-              f"{r['name'][:44]:44s} {r['result']}")
+              f"{r['name'][:56]:56s} {r['result']}")
     if len(sys.argv) > 3:
         with open(sys.argv[3], "w") as f:
             json.dump({"part": part, "steps": steps, "total_ms": total,

@@ -512,9 +512,11 @@ def check_head(b: int, s: int, h: int, v: int, w_is_vh: bool, dtype,
     plain float32 reference at highest precision on the same
     (dtype-rounded) inputs, a row at a time (its ``[b, s, v]`` float32
     logits are what the head exists not to hold). With ``mesh`` (axes
-    ``pp`` and ``tp``) the head runs as the pipeline's ``head_fn`` does:
-    inside a region manual over ``pp`` alone, the last stage's loss kept,
-    the vocabulary sharded over ``tp``."""
+    ``pp`` and ``tp``) the head runs where the pipeline's ``head_fn`` does:
+    inside a region manual over ``pp`` alone, the vocabulary sharded over
+    ``tp`` (every stage on the whole of ``x`` and the last stage's loss
+    kept, the form ``pipeline_apply`` takes where the micro-batches do not
+    divide among the stages)."""
     import jax
     import jax.numpy as jnp
     from jax.sharding import NamedSharding, PartitionSpec as P
@@ -1483,7 +1485,8 @@ def _distinct_devices(tree) -> int:
 
 def phase_multichip(cfg, micro: int, n_micro: int, loss0: float,
                     zero_cfg, zero_batch: int, head) -> None:
-    """Four chips: the trainer on dp2 x tp2 (pp = 1) and pp2 x tp2, the
+    """Four chips: the trainer on dp2 x tp2 (pp = 1) and pp2 x tp2 (whose
+    stages must have shared the loss head: ``head/pp_share_traces``), the
     head alone (``head``: ``check_head``'s shape and dtype) as the
     pp2 x tp2 arm runs it, then
     compile_train_step on dp=4 replicated / ZeRO-1 f32 ring / ZeRO-2 int8
@@ -1495,10 +1498,27 @@ def phase_multichip(cfg, micro: int, n_micro: int, loss0: float,
     from paddle_tpu.distributed.mesh import create_mesh
     from paddle_tpu.distributed.strategy_compiler import compile_train_step
     from paddle_tpu.models import GPT
+    from paddle_tpu.profiler import metrics
+
+    def head_shares():
+        reg = metrics.registry()
+        return {n: reg.counter("head/pp_share_traces{stages=%d}" % n).value
+                for n in (1, 2)}
 
     devs = jax.devices()[:4]
     for axes in ({"dp": 2, "tp": 2}, {"pp": 2, "tp": 2}):
+        before = head_shares()
         r = train_steps(cfg, axes, devs, micro, n_micro, steps=2)
+        shared = {n: v - before[n] for n, v in head_shares().items()}
+        # which head the step compiled (pipeline.py "Loss egress"): no
+        # region at pp = 1, and on the CPU under amp the trainer keeps the
+        # head outside it; on the chip the stages share it, or each runs
+        # all of it where the micro-batches do not divide
+        stages = 0 if "pp" not in axes or devs[0].platform == "cpu" \
+            else 2 if n_micro % 2 == 0 else 1
+        check(all((v > 0) == (n == stages) for n, v in shared.items()),
+              f"train {axes}: the step counted head/pp_share_traces "
+              f"{shared}, expected stages={stages or 'none'}")
         rel = abs(r["losses"][0] - loss0) / abs(loss0)
         check(rel < 0.02, f"train {axes}: step-0 loss {r['losses'][0]} vs "
               f"one chip {loss0} ({rel:.3%})")
@@ -1508,7 +1528,8 @@ def phase_multichip(cfg, micro: int, n_micro: int, loss0: float,
               "devices, expected 4")
         say("multichip", f"{axes}: losses "
             + " ".join(f"{x:.4f}" for x in r["losses"])
-            + f" (step 0 within {rel:.2%} of one chip), first step "
+            + f" (step 0 within {rel:.2%} of one chip), head shared by "
+            f"{stages or 'no'} stages, first step "
             f"{r['times'][0]:.1f} s, second {r['times'][1]:.2f} s, params "
             f"and optimizer shards on {n_p} devices")
         del r, params, opt_state

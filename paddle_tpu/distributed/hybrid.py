@@ -13,6 +13,14 @@ protocol any stacked-block model declares (models/gpt.py, models/bert.py):
   pipeline_blocks()      -> list of identical blocks (stackable params)
   pipeline_head(x, *batch) -> scalar loss     (norm + head + loss)
 
+and, where the loss is a mean over labels some of which are ignored, a
+fourth that says over how many, so that a head run on slices of the batch
+(the pipeline's stages share it, pipeline.py "Loss egress") still gives the
+whole batch's mean:
+
+  pipeline_head_terms(x, *batch) -> ((mean, count), ...) adding up to
+                                    pipeline_head's loss
+
 The trainer stacks block params to [pp, layers_per_stage, ...], shards the
 stage axis over 'pp' (pipeline.py shard_map), scans/unrolls layers within a
 stage, shards batch dim 0 over 'dp' (+ seq dim 1 over 'sp'), applies ZeRO
@@ -850,8 +858,14 @@ class HybridPipelineTrainer:
             return out, sums.pop("loss") / self.n_micro, sums
 
         batch_tensors = [Tensor(b) for b in batch]
-        # loss-inside-pipeline: the head runs in the manual region and only
-        # a SCALAR crosses 'pp' (vs the full activation buffer). Disabled
+        # loss-inside-pipeline: the head runs in the manual region, the
+        # stages sharing it: the last stage deals its finished
+        # micro-batches out over 'pp' (1/pp of the activation buffer to
+        # each other stage, their dx back in the backward pass) and each
+        # runs the head on its own against its rows of the batch; sums
+        # and counts of the loss's terms cross 'pp' back. Where n_micro
+        # is not a multiple of pp every stage runs the whole head and the
+        # last one's value is kept (pipeline.py "Loss egress"). Disabled
         # under manual sp (head must see the sp-sharded output) and under
         # CPU+amp (bf16 cotangent psum trips XLA:CPU). tp>1 is supported:
         # the vocab-sharded head's tp collectives ride GSPMD-auto inside
@@ -871,20 +885,27 @@ class HybridPipelineTrainer:
                 if head_inside:
                     # head params + batch enter the manual region as
                     # explicit inputs; blocks' swapped values are local
-                    def head_fn(full, other_vals, batch_vals):
+                    def head_fn(rows, other_vals, *batch_rows):
+                        # (mean, count) terms where the model gives
+                        # them: a share's count of kept labels may differ
+                        head = getattr(model, "pipeline_head_terms",
+                                       model.pipeline_head)
                         with _swapped_state(other_tensors,
                                             list(other_vals)), \
                                 _ptrace.annotate("fwd/head"):
-                            return model.pipeline_head(
-                                Tensor(full),
-                                *[Tensor(b) for b in batch_vals])._value
+                            out = head(Tensor(rows),
+                                       *[Tensor(b) for b in batch_rows])
+                        if isinstance(out, Tensor):
+                            return out._value
+                        return tuple((m._value, getattr(c, "_value", c))
+                                     for m, c in out)
                     with _ptrace.annotate("fwd/blocks"):
                         loss_v = pipeline_apply(
                             self.mesh, block_apply, block_cast, x,
                             self.n_micro, v_virtual=self.v,
                             head_fn=head_fn,
-                            head_args=(tuple(other_cast), tuple(batch)),
-                            stage_aux=moe)
+                            head_args=(tuple(other_cast),),
+                            head_batch=tuple(batch), stage_aux=moe)
                     if moe:
                         loss_v, aux = loss_v
                         aux, stats = split_aux(aux)

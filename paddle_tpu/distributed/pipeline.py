@@ -36,11 +36,25 @@ Schedules:
     reference's F-then-B. Requires n_micro >= pp.
 
 Loss egress: when ``head_fn`` is given, the loss head runs INSIDE the
-manual region — every stage computes it in SPMD lockstep (no wall-clock
-cost vs one stage computing while the rest idle), the last stage's value
-is selected, and only the SCALAR is psum'd across 'pp'. Without head_fn
-the full activation buffer is shared via masked psum (needed by the
-manual-sp composition, where the head must see the sp-sharded output).
+manual region, and the stages share it. Only the last stage writes its
+buffer of finished micro-batches (the others' stay zeros), so after the
+tick scan it is dealt out along the micro-batch axis by one reduce-scatter
+of the buffers over 'pp' (``1/pp`` of the real one leaves the last stage
+for each other stage), every stage runs the head on its own ``n_micro /
+pp`` micro-batches against the matching rows of ``head_batch``, and two
+scalars a term of the loss cross 'pp' back (sum and count: the loss is the
+whole batch's mean, ``_whole_batch_mean``). Differentiated, the head's
+``dx`` of each share returns by the transpose, an all-gather, of which the
+last stage's ticks take their micro-batch each and the other stages' none,
+and the head weights' cotangent is summed over 'pp' once by the region's
+own transpose of a replicated input, each stage adding its share. Where
+``n_micro`` is not a multiple of ``pp`` the shares would be
+unequal: every stage then runs the head on its whole buffer in lockstep and
+the last stage's value is kept (stage ``pp - 1`` does all of the head's work
+and the others wait for it). Which form a program compiled is counted at
+trace time in ``head/pp_share_traces{stages=<pp or 1>}``. Without head_fn
+the full activation buffer is shared by a psum of the buffers (needed by
+the manual-sp composition, where the head must see the sp-sharded output).
 """
 from __future__ import annotations
 
@@ -65,7 +79,8 @@ def pipeline_apply(mesh: Mesh, stage_fn: Callable, stacked_params: Any,
                    x, n_micro: int, pp_axis: str = "pp",
                    sp_axis: str = None, v_virtual: int = 1,
                    head_fn: Optional[Callable] = None,
-                   head_args: tuple = (), stage_aux: bool = False):
+                   head_args: tuple = (), head_batch: tuple = (),
+                   stage_aux: bool = False):
     """Run x [batch, ...] through the pipelined stacked blocks.
 
     stage_fn(params_one_chunk, x_mb) -> y_mb applies one (virtual) stage's
@@ -73,9 +88,17 @@ def pipeline_apply(mesh: Mesh, stage_fn: Callable, stacked_params: Any,
     v_virtual=1 or [pp, v, ...] for interleaved; x is split into n_micro
     microbatches along dim 0.
 
-    head_fn(full_output) -> scalar: optional loss head computed inside the
-    region (see module docstring); returns the scalar instead of the
-    activations.
+    head_fn(out_rows, *head_args, *batch_rows): optional loss head computed
+    inside the region (see module docstring); pipeline_apply then returns
+    the scalar loss instead of the activations. ``out_rows`` are the
+    finished activations of some whole micro-batches (all of them, or a
+    stage's ``1/pp``), ``batch_rows`` the same rows of every array in
+    ``head_batch`` (leading dimension x's batch: tokens, labels);
+    ``head_args`` (the head's weights) arrive whole. It returns the loss as
+    a scalar, a mean over its rows in which every row weighs the same, or
+    as a sequence of ``(mean, count)`` terms that add up to it, each a mean
+    over ``count`` kept labels: what the whole batch's mean needs where
+    the count differs between rows (an ``ignore_index``).
 
     stage_aux: when True, stage_fn returns ``(y_mb, aux)`` — a
     per-microbatch auxiliary scalar (e.g. the MoE load-balance loss of the
@@ -137,12 +160,24 @@ def pipeline_apply(mesh: Mesh, stage_fn: Callable, stacked_params: Any,
     # head params/batch enter as explicit inputs (replicated over the
     # manual axes; their dp/tp shardings ride the auto axes) — closures
     # over outer-traced sharded values are rejected inside shard_map
-    head_specs = jax.tree_util.tree_map(lambda _: P(), head_args)
+    head_in = (tuple(head_args), tuple(head_batch))
+    head_specs = jax.tree_util.tree_map(lambda _: P(), head_in)
+    # the stages share the head where the micro-batches divide among them
+    shares = pp if head_fn is not None and n_micro % pp == 0 else 1
+    if head_fn is not None:
+        from ..profiler import metrics
+        metrics.registry().counter(
+            "head/pp_share_traces{stages=%d}" % shares).add(1)
+        for a in head_batch:
+            if a.ndim == 0 or a.shape[0] != x.shape[0]:
+                raise ValueError(
+                    f"head_batch holds an array of shape {a.shape}: its "
+                    f"leading dimension is not the batch's {x.shape[0]}")
 
     @partial(jax.shard_map, mesh=mesh,
              in_specs=(param_specs, x_spec, head_specs), out_specs=out_spec,
              check_vma=False, axis_names=manual)
-    def pipelined(params, xs, head_args):
+    def pipelined(params, xs, head_in):
         # params leaves: [1, ...] local slice; xs: [n_micro, mb, ...]
         local = jax.tree_util.tree_map(
             lambda a: a[0].astype(compute_dtype)
@@ -220,11 +255,13 @@ def pipeline_apply(mesh: Mesh, stage_fn: Callable, stacked_params: Any,
                     lambda acc, a: acc + jnp.where(
                         busy, a.astype(jnp.float32), 0.0), aux_acc, aux)
             out = out.astype(carry_dtype)
-            # the last stage finishing the LAST circuit produces output
+            # the last stage finishing the LAST circuit produces output;
+            # every other stage's buffer stays zeros, so a sum over 'pp'
+            # of the buffers (or of parts of them) is the real one
             done_t = t - (pp - 1) - (v - 1) * n_micro
             out_idx = jnp.clip(done_t % n_micro if v > 1 else done_t,
                                0, n_micro - 1)
-            valid = done_t >= 0
+            valid = (done_t >= 0) & (stage == pp - 1)
             cur = jax.lax.dynamic_index_in_dim(outputs, out_idx, 0,
                                                keepdims=False)
             outputs = jax.lax.dynamic_update_index_in_dim(
@@ -241,21 +278,29 @@ def pipeline_apply(mesh: Mesh, stage_fn: Callable, stacked_params: Any,
             # local routing groups per sp shard: average their aux
             aux_total = jax.lax.pmean(aux_total, sp_axis)
         if head_fn is not None:
-            # loss head on every stage in lockstep; only the last stage's
-            # value is real — egress is ONE scalar, not the activations
-            full = outputs.reshape((outputs.shape[0] * outputs.shape[1],)
+            head_args, head_batch = head_in
+            if shares > 1:
+                # stage s takes micro-batches [s, s + 1) * n_micro / pp
+                # and their rows of the batch; every share is real. The
+                # exchange is the head's: under its scope name, so that
+                # a device trace charges the head for the bytes it moves
+                with _annotate("fwd/head"):
+                    outputs = jax.lax.psum_scatter(
+                        outputs, pp_axis, scatter_dimension=0, tiled=True)
+                head_batch = tuple(
+                    jax.lax.dynamic_index_in_dim(
+                        a.reshape((pp, a.shape[0] // pp) + a.shape[1:]),
+                        stage, 0, keepdims=False) for a in head_batch)
+            rows = outputs.reshape((outputs.shape[0] * outputs.shape[1],)
                                    + tuple(outputs.shape[2:]))
             with _annotate("pp/head"):
-                loss = head_fn(full.astype(compute_dtype), *head_args)
-            loss = jnp.where(stage == pp - 1, loss, 0.0)
-            loss = jax.lax.psum(loss.astype(jnp.float32), pp_axis)
+                loss = _whole_batch_mean(
+                    head_fn(rows.astype(compute_dtype), *head_args,
+                            *head_batch),
+                    shares > 1 or stage == pp - 1, pp_axis)
             return (loss, aux_total) if stage_aux else loss
-        # only the last stage's buffer is the real output; share it
-        mask = (stage == pp - 1).astype(outputs.dtype)
-        masked = outputs * mask
-        if boundary_f32:
-            masked = masked.astype(jnp.float32)
-        shared = jax.lax.psum(masked, pp_axis)
+        # share the whole buffer (float32 already where boundary_f32)
+        shared = jax.lax.psum(outputs, pp_axis)
         return (shared, aux_total) if stage_aux else shared
 
     mbs = _to_microbatches(x, n_micro)
@@ -263,13 +308,31 @@ def pipeline_apply(mesh: Mesh, stage_fn: Callable, stacked_params: Any,
         mbs = mbs.astype(jnp.float32)
     if param_f32:
         stacked_params = jax.tree_util.tree_map(_pf, stacked_params)
-    out = pipelined(stacked_params, mbs, head_args)
+    out = pipelined(stacked_params, mbs, head_in)
     aux = None
     if stage_aux:
         out, aux = out
     if head_fn is None:
         out = _from_microbatches(out, x.shape).astype(compute_dtype)
     return (out, aux) if stage_aux else out
+
+
+def _whole_batch_mean(terms, real, pp_axis):
+    """The loss of the whole batch from what ``head_fn`` returned on this
+    stage's rows, or (``real`` false) on a buffer that is not the
+    pipeline's output: each term's means weighed by their counts,
+    ``sum_pp(mean * count) / sum_pp(count)``, so that a label counts the
+    same whichever stage's share it fell into and the gradient's ``1/n``
+    is the whole batch's. A scalar is one term whose every share counts
+    the same."""
+    if not isinstance(terms, (tuple, list)):
+        terms = ((terms, 1.0),)
+    counts = [jnp.where(real, jnp.asarray(c, jnp.float32), 0.0)
+              for _, c in terms]
+    sums = [jnp.where(real, m.astype(jnp.float32) * c, 0.0)
+            for (m, _), c in zip(terms, counts)]
+    sums, counts = jax.lax.psum((sums, counts), pp_axis)
+    return sum(s / jnp.where(c > 0, c, 1.0) for s, c in zip(sums, counts))
 
 
 def _to_microbatches(x, n_micro):

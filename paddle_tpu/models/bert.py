@@ -214,6 +214,17 @@ class BertForPretraining(nn.Layer):
         full-sequence ignore-index CE whenever no row has more than
         max_predictions masked positions (excess positions are dropped,
         mirroring the reference data generator's truncation)."""
+        (mlm, _), (nsp, _) = self.pipeline_head_terms(
+            x, tokens, token_type_ids, mlm_labels, nsp_labels)
+        return mlm + nsp
+
+    def pipeline_head_terms(self, x, tokens, token_type_ids, mlm_labels,
+                            nsp_labels):
+        """``pipeline_head``'s two means, each with the count it is taken
+        over: ``((mlm, masked positions kept), (nsp, rows))``. A caller
+        that runs the head on slices of the batch (the pipeline's stages
+        share it, distributed/pipeline.py) weighs each slice's means by
+        these, since slices differ in their masked positions."""
         from ..distributed import context as _dctx
         from ..ops.fused_ce import fused_linear_cross_entropy
         from ..tensor import take_along_axis, tanh, topk, where
@@ -236,7 +247,8 @@ class BertForPretraining(nn.Layer):
         pooled = tanh(self.bert.pooler(cls))
         nsp = F.cross_entropy(self.nsp_head(pooled).astype("float32"),
                               nsp_labels)
-        return mlm + nsp
+        return ((mlm, (mlm_labels != -100).astype("int32").sum()),
+                (nsp, int(nsp_labels.shape[0])))
 
     def loss(self, tokens, token_type_ids, mlm_labels, nsp_labels):
         """Same objective as pipeline_head (fused tied-decoder CE +

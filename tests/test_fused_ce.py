@@ -2,8 +2,10 @@
 against autodifferentiation of a plain float32 reference, on one device,
 under GSPMD (vocabulary over ``tp``, batch over ``dp``) and inside a
 ``shard_map`` that is manual over ``pp`` alone, as the pipeline's
-``head_fn`` runs; and a count of the products by the vocabulary, so that a
-recomputed pass cannot come back unseen.
+``head_fn`` runs; a count of the products by the vocabulary, so that a
+recomputed pass cannot come back unseen; and the head shared by the
+pipeline's stages (``pipeline_apply(head_fn=...)``) against the head on
+the whole batch.
 
 The rule makes each chunk's gradients from the tile its loss was made from
 (a ``jax.custom_vjp`` whose forward rule saves ``dx`` and ``dW``); nothing
@@ -216,9 +218,11 @@ def test_vocabulary_over_tp_and_batch_over_dp(layout, dtype):
 
 @pytest.mark.parametrize("layout", ["vh", "hv"])
 def test_inside_a_region_manual_over_pp_only(layout):
-    """As ``pipeline_apply`` runs ``head_fn``: every stage computes the
-    head on its own buffer, the last stage's loss is kept and summed over
-    ``pp``; ``tp`` and ``dp`` stay GSPMD's inside the region."""
+    """As ``pipeline_apply`` runs ``head_fn`` where the micro-batches do
+    not divide among the stages: every stage computes the head on its own
+    buffer, the last stage's loss is kept and summed over ``pp``; ``tp``
+    and ``dp`` stay GSPMD's inside the region. (Where they divide the
+    stages share the head: the tests at the end of this file.)"""
     w_is_vh, dtype = layout == "vh", "float32"
     mesh = _mesh(pp=2, dp=2, tp=2)
     x, w, _, labels = draw(dtype, w_is_vh, False, "some", b=4)
@@ -291,11 +295,15 @@ def test_a_step_multiplies_by_the_vocabulary_three_times(layout, bias):
         x, w, v=56) == (1, 0)
 
 
-def _traces():
+def _counted(name, keys):
+    """{key: the counter ``name % key``'s value, 0 before its first add}."""
     snap = metrics.registry().snapshot()
-    return {rule: snap.get("head/fused_ce_traces{rule=%s}" % rule,
-                           {"value": 0})["value"]
-            for rule in ("grad_in_forward", "loss_only")}
+    return {k: snap.get(name % k, {"value": 0})["value"] for k in keys}
+
+
+def _traces():
+    return _counted("head/fused_ce_traces{rule=%s}",
+                    ("grad_in_forward", "loss_only"))
 
 
 def test_the_counter_says_which_rule_a_program_compiled():
@@ -330,3 +338,159 @@ def test_the_counter_says_which_rule_a_program_compiled():
     assert step["grad_in_forward"] - after["grad_in_forward"] == 1
     assert step["loss_only"] == after["loss_only"]
     np.testing.assert_allclose(first, alone, rtol=2e-5)
+
+
+# --- the pipeline's stages share the head (distributed/pipeline.py) ---------
+# pipeline_apply(head_fn=...) deals the last stage's finished micro-batches
+# out over ``pp`` and every stage runs the head on its own. The reference is
+# the head the parent ran, on the whole batch at once: the pipeline's
+# activations leave the region by the egress that has no head (code this
+# form does not touch) and one head call sees every row. A benchmark cell's
+# check cannot stand in for these: it puts one sequence in every row, so a
+# dropped or doubled share reads the same loss.
+
+P_MB, P_S, P_H, P_V = 2, 16, 8, 40
+
+
+def _share_traces():
+    return _counted("head/pp_share_traces{stages=%d}", (1, 2))
+
+
+def _pipeline_problem(mesh, v, n_micro, uneven):
+    """Two stages of ``v`` chunks of one layer, an embedding, a head over
+    ``tp``; every row of the batch its own tokens. ``uneven``: nearly every
+    label of the first half of the batch (stage 0's share) is ignored."""
+    b = n_micro * P_MB
+    k = jax.random.split(jax.random.PRNGKey(7 + n_micro), 6)
+    stack = (2,) + ((v,) if v > 1 else ()) + (1, P_H, P_H)
+    blocks = {"w": 0.4 * jax.random.normal(k[0], stack)}
+    w_head = 0.3 * jax.random.normal(k[1], (P_V, P_H))
+    emb = jax.random.normal(k[2], (P_V, P_H))
+    extra = 0.1 * jax.random.normal(k[3], (b, P_S, P_H))
+    tokens = jax.random.randint(k[4], (b, P_S), 0, P_V)
+    labels = jax.random.randint(k[5], (b, P_S), 0, P_V)
+    if uneven:
+        labels = labels.at[:b // 2, 2:].set(IGNORE).at[-1, :3].set(IGNORE)
+    rows = P("dp") if "dp" in mesh.shape else P()
+    put = lambda a, spec: jax.device_put(a, NamedSharding(mesh, spec))
+    args = ({"w": put(blocks["w"], P("pp"))}, put(w_head, P("tp", None)),
+            put(emb, P()), put(extra, rows))
+    return args, put(tokens, rows), put(labels, rows)
+
+
+def _stage(params, x):
+    for l in range(params["w"].shape[0]):
+        x = x + jnp.tanh(x @ params["w"][l])
+    return x
+
+
+def _pipeline_losses(mesh, v, n_micro, tokens, labels, terms):
+    """(the shared head's loss, the whole-batch head's) as functions of
+    (blocks, head weights, embedding, an addend of x)."""
+    from paddle_tpu.distributed.pipeline import pipeline_apply
+
+    def head(rows, w, lbl):
+        loss = fused_linear_cross_entropy_fn(rows, w, lbl, chunk=8)
+        return ((loss, jnp.sum(lbl != IGNORE)),) if terms else loss
+
+    def shared(blocks, w_head, emb, extra):
+        return pipeline_apply(
+            mesh, _stage, blocks, emb[tokens] + extra, n_micro,
+            v_virtual=v, head_fn=head, head_args=(w_head,),
+            head_batch=(labels,))
+
+    def whole(blocks, w_head, emb, extra):
+        out = pipeline_apply(mesh, _stage, blocks, emb[tokens] + extra,
+                             n_micro, v_virtual=v)
+        return fused_linear_cross_entropy_fn(out, w_head, labels, chunk=8)
+    return shared, whole
+
+
+def _agree_all(got, want):
+    (gl, gg), (wl, wg) = got, want
+    np.testing.assert_allclose(float(gl), float(wl), rtol=LOSS_RTOL)
+    for g, r in zip(jax.tree_util.tree_leaves(gg),
+                    jax.tree_util.tree_leaves(wg)):
+        assert g.shape == r.shape and g.dtype == r.dtype
+        assert rel_err(g, r) <= GRAD_TOL["float32"]
+
+
+@pytest.mark.parametrize("uneven", [False, True], ids=["every", "uneven"])
+@pytest.mark.parametrize("v", [1, 2], ids=["gpipe", "interleaved"])
+@pytest.mark.parametrize("axes", [dict(pp=2, tp=2), dict(pp=2, dp=2, tp=2)],
+                         ids=["pp2tp2", "pp2dp2tp2"])
+def test_the_stages_share_the_head(axes, v, uneven):
+    """Loss and the gradients of blocks, head weights, embedding and ``x``
+    are the whole-batch head's on distinct rows, also where the shares keep
+    different counts of labels (``(mean, count)`` terms); the program
+    counted ``stages=2``."""
+    mesh, n_micro = _mesh(**axes), 4
+    args, tokens, labels = _pipeline_problem(mesh, v, n_micro, uneven)
+    shared, whole = _pipeline_losses(mesh, v, n_micro, tokens, labels,
+                                     terms=uneven)
+    argnums = (0, 1, 2, 3)
+    before = _share_traces()
+    got = jax.jit(jax.value_and_grad(shared, argnums))(*args)
+    after = _share_traces()
+    assert after[2] > before[2] and after[1] == before[1]
+    _agree_all(got, jax.jit(jax.value_and_grad(whole, argnums))(*args))
+    if uneven:
+        # the test tells the two means apart: a head that gives no counts
+        # is weighed a share alike, and is not this batch's mean
+        alike = _pipeline_losses(mesh, v, n_micro, tokens, labels,
+                                 terms=False)[0]
+        assert abs(float(jax.jit(alike)(*args)) - float(got[0])) > 1e-3
+
+
+@pytest.mark.parametrize("v", [1, 2], ids=["gpipe", "interleaved"])
+@pytest.mark.parametrize("uneven", [False, True], ids=["every", "uneven"])
+def test_micro_batches_that_do_not_divide_keep_the_whole_head(v, uneven):
+    """``n_micro % pp != 0``: every stage runs the head on its whole
+    buffer, the last stage's loss is kept, and the program says so."""
+    mesh, n_micro = _mesh(pp=2, tp=2), 3
+    args, tokens, labels = _pipeline_problem(mesh, v, n_micro, uneven)
+    shared, whole = _pipeline_losses(mesh, v, n_micro, tokens, labels,
+                                     terms=uneven)
+    before = _share_traces()
+    got = jax.jit(jax.value_and_grad(shared, (0, 1, 2, 3)))(*args)
+    after = _share_traces()
+    assert after[1] > before[1] and after[2] == before[2]
+    _agree_all(got, jax.jit(jax.value_and_grad(whole, (0, 1, 2, 3)))(*args))
+
+
+def _vocabulary_operands(fn, *args, v):
+    """The shapes of the left operands of the products that contract or
+    make the vocabulary with a sequence chunk's states (the logits tile's
+    ``x`` chunk among them)."""
+    return [eqn.invars[0].aval.shape
+            for eqn, _ in _eqns(jax.make_jaxpr(fn)(*args).jaxpr)
+            if eqn.primitive.name == "dot_general"
+            and not eqn.params["dimension_numbers"][1][0]
+            and v in eqn.outvars[0].aval.shape]
+
+
+@pytest.mark.parametrize("n_micro,rows", [(4, 2 * P_MB), (3, 3 * P_MB)],
+                         ids=["shared", "whole"])
+def test_a_shared_head_sees_its_share_and_multiplies_three_times(n_micro,
+                                                                 rows):
+    """In the step's jaxpr the logits tile is made from ``n_micro / pp``
+    micro-batches' rows (all of them where they do not divide), and a step
+    still multiplies by the vocabulary three times, none in a remat."""
+    mesh = _mesh(pp=2, tp=2)
+    args, tokens, labels = _pipeline_problem(mesh, 1, n_micro, False)
+    shared, _ = _pipeline_losses(mesh, 1, n_micro, tokens, labels, False)
+    grad = jax.grad(shared, (0, 1, 2, 3))
+    assert vocabulary_products(grad, *args, v=P_V) == (3, 0)
+    tiles = _vocabulary_operands(grad, *args, v=P_V)
+    assert tiles and all(t[0] == rows for t in tiles), tiles
+
+
+def test_a_head_batch_that_is_not_the_batchs_rows_is_refused():
+    from paddle_tpu.distributed.pipeline import pipeline_apply
+
+    mesh = _mesh(pp=2, tp=2)
+    args, tokens, labels = _pipeline_problem(mesh, 1, 4, False)
+    with pytest.raises(ValueError, match="leading dimension"):
+        pipeline_apply(mesh, _stage, args[0], args[2][tokens], 4,
+                       head_fn=lambda rows, lbl: jnp.sum(rows),
+                       head_batch=(labels[:3],))

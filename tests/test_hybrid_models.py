@@ -48,6 +48,38 @@ class TestBertHybrid:
         spmd = float(tr.step(*batch))
         assert abs(spmd - eager) < 2e-2, (spmd, eager)
 
+    def test_bert_stages_share_the_head_with_uneven_masks(self):
+        """pp2 x tp2, two micro-batches: each stage runs the head on one of
+        them. Nearly every masked position lies in the first, so the loss
+        is the whole batch's two means (MLM over masked positions, NSP
+        over rows) only if a share's MLM mean is weighed by its count
+        (``pipeline_head_terms``)."""
+        import pytest
+        from paddle_tpu.profiler import metrics
+
+        paddle.seed(9)
+        net = bert_tiny()
+        net.eval()
+        tokens, tt, mlm, nsp = _bert_batch(seed=7)
+        mlm[4:, 1:] = -100                   # the second share keeps 4
+        mlm[4:, 0] = tokens[4:, 0]
+        batch = (tokens, tt, mlm, nsp)
+        eager = float(net.loss(*[paddle.to_tensor(a) for a in batch])
+                      .numpy())
+        halves = [float(net.loss(*[paddle.to_tensor(a[h]) for a in batch])
+                        .numpy()) for h in (slice(0, 4), slice(4, 8))]
+        assert abs(sum(halves) / 2 - eager) > 1e-2   # the test can tell
+        net.train()
+        opt = paddle.optimizer.SGD(0.0, parameters=net.parameters())
+        s = _strategy(hybrid={"mp_degree": 2, "pp_degree": 2})
+        counter = metrics.registry().counter(
+            "head/pp_share_traces{stages=2}")
+        before = counter.value
+        tr = HybridPipelineTrainer(net, opt, s, build_mesh_from_strategy(s),
+                                   n_micro=2)
+        assert float(tr.step(*batch)) == pytest.approx(eager, rel=2e-5)
+        assert counter.value > before
+
     def test_bert_hybrid_training_decreases_loss(self):
         paddle.seed(6)
         net = bert_tiny()

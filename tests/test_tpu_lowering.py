@@ -95,6 +95,25 @@ def test_tpu_lowering_bf16_collective_permute(monkeypatch):
         "no Mosaic custom call in the TPU program — flash kernel lost"
 
 
+def test_tpu_lowering_the_stages_share_the_head(monkeypatch):
+    """pp2 x dp2 x tp2, four micro-batches: the step lowered for the TPU
+    deals the last stage's finished micro-batches out in ONE reduce-scatter
+    of bf16 (two of the four to a stage), brings the head's ``dx`` back in
+    ONE all-gather, and holds no second exchange of the buffer (PR 45;
+    at the 6.7B cell's sizes: PERF.md section 6)."""
+    devices = _tpu_topology_devices()
+    monkeypatch.setenv("PADDLE_TPU_TARGET_PLATFORM", "tpu")
+    tr = _build_abstract_trainer(devices, dp=2, tp=2, pp=2)
+    hlo = tr.aot_lower(jax.ShapeDtypeStruct((8, 128), np.int32)).as_text()
+    scatters = re.findall(r".*stablehlo\.reduce_scatter.*\n?.*", hlo)
+    gathers = re.findall(r".*stablehlo\.all_gather.*", hlo)
+    assert len(scatters) == 1 and len(gathers) == 1, (scatters, gathers)
+    assert re.search(r"tensor<4x2x128x512xbf16>\) -> "
+                     r"tensor<2x2x128x512xbf16>", hlo), scatters
+    assert re.search(r"tensor<2x2x128x512xbf16>\) -> "
+                     r"tensor<4x2x128x512xbf16>", gathers[0]), gathers
+
+
 def test_tpu_topology_compile_and_memory():
     """Full compile for the v5e target: the executable exists and XLA's
     per-chip accounting is within the 16 GB v5e HBM for the tiny model
