@@ -16,7 +16,12 @@ benchmark's. A part of any trainer's step as ``train.*_ms_per_step`` cuts it
 (``head``, ``unscoped``, ``dense``, ``opt``, ``flash_fwd``, ``flash_bwd``:
 ``_program_trace.step_part``) is listed the same way after a ``--trace 1``
 run of any training cell, each operation with the innermost scope it lies
-under; on several chips the times are the mean of a chip's step.
+under; on several chips the times are the mean of a chip's step. A part of
+Olmo-Hybrid's served tick as ``perfbench/layer_metrics/_olmoh_trace.py`` cuts
+it (``gdn_prep``, ``gdn_step``, ``gdn_chunk``, ``attn``, ``head_sample``) is
+listed after a ``--trace 1`` run of ``serve-olmo-hybrid-gen-backlog``,
+milliseconds a tick, each operation with the end of its scope path (PR 46
+read ``gdn_prep`` that way before writing ``ops/gdn.py``'s pass).
 """
 import json
 import os
@@ -48,12 +53,16 @@ def main():
     if doc is None:
         raise SystemExit("no trace in .perfbench_trace: run a cell with "
                          "--trace 1 first")
-    part_of, title = (pt.step_part, part) if part in pt.STEP_ORDER \
+    ot = loader.load_module("layer_metrics", "_olmoh_trace")
+    word = "tick" if any(pt.program_runs(p, "tick")
+                         for p in tracered.device_planes(doc)) else "step"
+    part_of, title = (ot.part, part) if word == "tick" \
+        else (pt.step_part, part) if part in pt.STEP_ORDER \
         else (kt.kda_part, f"blk/kda/{part}")
     by_name = defaultdict(lambda: {"ms": 0.0, "n": 0})
     steps = 0
     for plane in tracered.device_planes(doc):
-        runs = pt.whole_runs(pt.program_runs(plane, "step"))
+        runs = pt.whole_runs(pt.program_runs(plane, word))
         steps += len(runs)
         inside = tracered.merge(tracered.intervals(runs))
         for ev in tracered.op_events(plane):
@@ -65,6 +74,8 @@ def main():
             name = re.sub(r"\.\d+$", "", tracered.short_name(ev))
             if part_of is pt.step_part:
                 name = f"{name} [{pt.scope_name(ev)}]"
+            elif word == "tick":
+                name = f"{name} [{'/'.join(ev.get('scope', '').split('/')[-3:])}]"
             rec = by_name[name, result]
             rec["ms"] += ev["dur_ns"] / 1e6
             rec["n"] += 1

@@ -1236,11 +1236,13 @@ def phase_dsv2(cfg, num_slots: int, page_size: int, pages_per_slot: int,
 # ---------------------------------------------------------------------------
 # phase 2d: the served gated delta rule and a hybrid model through the engine
 # ---------------------------------------------------------------------------
-# the two kernels of ops/gdn.py against their jax.numpy spellings on the same
-# bf16 operands and float32 states, ||difference|| / ||reference|| of the
+# the three kernels of ops/gdn.py against their jax.numpy spellings on the
+# same bf16 operands and float32 states, ||difference|| / ||reference|| of the
 # outputs and of the states they leave (the step is float32 on the vector
 # unit both ways; the chunk's products take bf16 operands both ways and
-# differ by the order of their sums)
+# differ by the order of their sums; the pass between projections and rule
+# rounds to bf16 once where its spelling rounds after the SiLU and after the
+# norm, and leaves the history bit for bit)
 TOL_GDN_OPS = 1e-2
 # a small hybrid model's emitted tokens against the float32 reference: the
 # shortfall of an emitted token's logit below the reference's largest
@@ -1252,7 +1254,9 @@ GDN_REQUESTS = ((150, 24), (300, 20), (70, 40))
 def check_gdn_ops(heads: int, dk: int, dv: int, rows: int, w: int) -> dict:
     """``gdn_step_rows`` over ``rows`` decode rows and ``gdn_chunk_rows``
     over one row of ``w`` tokens, from random states, by the path observed
-    here (on the chip: the kernels) against ``xla_step`` and ``xla_chunk``."""
+    here (on the chip: the kernels) against ``xla_step`` and ``xla_chunk``;
+    before them ``gdn_prep_rows`` over the same two row groups (4 taps, a
+    history of ``rows`` slots) against ``xla_prep``."""
     import jax
     import jax.numpy as jnp
 
@@ -1301,7 +1305,33 @@ def check_gdn_ops(heads: int, dk: int, dv: int, rows: int, w: int) -> dict:
     out["chunk_s"] = rel(gdn.unpack_state(after[1, 2:], heads), want_s)
     check(bool(jnp.array_equal(after[1, 1], stack[1, 1])),
           "gdn: a chunk moved another slot's state")
-    for name in ("step_o", "step_s", "chunk_o", "chunk_s"):
+    # the pass between projections and rule: the decode rows (one dead),
+    # then a carried chunk row whose last 40 positions are pads
+    c = heads * (2 * dk + dv)
+    ks = jax.random.split(jax.random.PRNGKey(3), 4)
+    bf = lambda a: a.astype(jnp.bfloat16)                   # noqa: E731
+    taps = bf(jax.random.uniform(ks[0], (4, c), minval=-0.5, maxval=0.5))
+    conv = bf(jax.random.normal(ks[1], (2, 3, gdn.conv_slot_rows(rows), c)))
+    conv = conv.at[:, :, 0].set(0).at[:, :, rows + 1:].set(0)
+    groups = {"prep_step": (bf(jax.random.normal(ks[2], (rows, c))), slots,
+                            None, None),
+              "prep_chunk": (bf(jax.random.normal(ks[3], (1, w, c))),
+                             jnp.asarray([2]), jnp.zeros((1,), bool), n_live)}
+    out["prep_path"] = gdn.prep_path((rows, c), conv.shape, heads, dk)
+    for name, (x, sl, fresh, n_tok) in groups.items():
+        *got, after = jax.jit(gdn.gdn_prep_rows, static_argnums=(3, 7, 8))(
+            x, taps, conv, 1, sl, fresh, n_tok, heads, dk)
+        *want, left = gdn.xla_prep(x, taps, conv, 1, sl, fresh, n_tok, heads,
+                                   dk)
+        keep = (np.asarray(sl) > 0) if n_tok is None else slice(None)
+        cut = (lambda a: a[keep]) if n_tok is None \
+            else (lambda a: a[:, :w - 40])
+        out[name] = max(rel(cut(a), cut(b).astype(jnp.float32))
+                        for a, b in zip(got, want))
+        check(bool(jnp.array_equal(after[:, :, 1:], left[:, :, 1:])),
+              f"gdn: {name} leaves another history than its spelling")
+    for name in ("step_o", "step_s", "chunk_o", "chunk_s", "prep_step",
+                 "prep_chunk"):
         check(out[name] <= TOL_GDN_OPS, f"gdn: {name} is {out[name]:.2e} "
               f"from its jax.numpy spelling (tol {TOL_GDN_OPS})")
     return out
@@ -1310,10 +1340,11 @@ def check_gdn_ops(heads: int, dk: int, dv: int, rows: int, w: int) -> dict:
 def phase_olmoh(cfg, num_slots: int, page_size: int, pages_per_slot: int,
                 chunk: int, ops_shape, want_path: str,
                 requests=GDN_REQUESTS) -> dict:
-    """Olmo-Hybrid's pass (models/olmo_hybrid.py): the two kernels of the
-    served gated delta rule against their ``jax.numpy`` spellings at
-    ``ops_shape`` (heads, dk, dv, decode rows, chunk tokens), which must go
-    by ``want_path`` (on the chip the kernels'), then a small model of both
+    """Olmo-Hybrid's pass (models/olmo_hybrid.py): the three kernels of the
+    served gated delta rule (the pass between projections and rule, the
+    step, the chunk) against their ``jax.numpy`` spellings at ``ops_shape``
+    (heads, dk, dv, decode rows, chunk tokens), which must go by
+    ``want_path`` (on the chip the kernels'), then a small model of both
     kinds of layer through the engine (a ``LazyGuard`` model drawn on the
     device, K/V pages beside a state a slot, prompts of several chunks):
     what it emitted is the float32 reference's
@@ -1330,17 +1361,18 @@ def phase_olmoh(cfg, num_slots: int, page_size: int, pages_per_slot: int,
     from paddle_tpu.serving import ServingConfig, ServingEngine
 
     errs = check_gdn_ops(*ops_shape)
-    path = errs.pop("path")
+    path, prep = errs.pop("path"), errs.pop("prep_path")
     say("olmoh", f"{ops_shape[0]} heads of {ops_shape[1]} x {ops_shape[2]}, "
         f"{ops_shape[3]} decode rows and a chunk of {ops_shape[4]}: "
         + ", ".join(f"{k} {v:.2e}" for k, v in errs.items())
         + f" from the jax.numpy spellings (allowed {TOL_GDN_OPS}); path "
         f"here: {path}")
-    check(path == want_path, f"the delta rule of {ops_shape[:3]} went by "
-          f"{path}, not {want_path}")
+    check(path == prep == want_path, f"the delta rule of {ops_shape[:3]} "
+          f"went by {path} and what lies before it by {prep}, not "
+          f"{want_path}")
     reg = registry()
     counters = {kind: "gdn/%s_calls{path=%%s}" % kind
-                for kind in ("step", "chunk")}
+                for kind in ("step", "chunk", "prep")}
     calls0 = {(kind, p): reg.counter(c % p).value
               for kind, c in counters.items() for p in ("pallas", "xla")}
     paddle.seed(0)
@@ -1385,9 +1417,9 @@ def phase_olmoh(cfg, num_slots: int, page_size: int, pages_per_slot: int,
           f"and {worst:.4f} at the worst (allowed {TOL_GDN_WORST})")
     tick = {kind: sorted(p for p in ("pallas", "xla") if reg.counter(
         c % p).value > calls0[(kind, p)]) for kind, c in counters.items()}
-    check(tick == {"step": [want_path], "chunk": [want_path]},
+    check(tick == dict.fromkeys(counters, [want_path]),
           f"the engine's ticks counted their delta rule by {tick}, not "
-          f"{want_path} (gdn/step_calls, gdn/chunk_calls)")
+          f"{want_path} (gdn/step_calls, gdn/chunk_calls, gdn/prep_calls)")
     say("olmoh", f"{len(rids)} requests through K/V pages and a state a slot "
         f"({cfg.num_hidden_layers} layers, chunks of {chunk}): shortfall "
         f"{median:.4f} at the median (allowed {TOL_GDN_SHORTFALL}), "
@@ -1810,7 +1842,8 @@ def main() -> int:
     # Olmo-Hybrid: the served delta rule's kernels at its heads (30 of
     # 96 x 192, the cell's 40 decode rows and chunk of 256), and a small
     # model of both kinds of layer through the engine, whose heads are of
-    # those sizes too, so that its ticks take the kernels
+    # those sizes too and whose eight decode rows fill a sublane tile, so
+    # that its ticks take the kernels
     from paddle_tpu.models.olmo_hybrid import OlmoHybridConfig
 
     run("olmoh", lambda: phase_olmoh(
@@ -1819,7 +1852,7 @@ def main() -> int:
             num_hidden_layers=4, num_attention_heads=4,
             num_key_value_heads=4, linear_num_key_heads=4,
             linear_num_value_heads=4, max_position_embeddings=512),
-        3, 16, 32, 128, (30, 96, 192, 40, 256), "pallas"))
+        8, 16, 32, 128, (30, 96, 192, 40, 256), "pallas"))
     run("train", lambda: phase_train(cfg, micro=2, n_micro=6, steps=4))
     if len(jax.devices()) >= 4 and "train" not in failed:
         run("multichip", lambda: phase_multichip(

@@ -18,9 +18,10 @@ the protocol ``ServingEngine`` asks of a model, ``LayerwiseLM``,
 StatePools``: K/V pages for the full layers and, for the linear ones, one
 float32 state a slot, whatever the context, beside the three positions the
 convolution looks back on. The forward touches them through the pools'
-methods alone: ``scatter`` and ``attend`` as a K/V model does, ``history``,
-``keep_history``, ``step`` (decode rows: one token against a state) and
-``chunk`` (chunk rows: ``w`` tokens from a carried state). A tenant's first
+methods alone: ``scatter`` and ``attend`` as a K/V model does, ``prep``
+(what lies between a linear layer's projections and its rule, over the
+history a slot carries), ``step`` (decode rows: one token against a state)
+and ``chunk`` (chunk rows: ``w`` tokens from a carried state). A tenant's first
 chunk (``row_pos0 == 0``) enters at zero; a dead decode row (an empty slot,
 a slot still prefilling) carries the null slot and touches no state.
 ``models/olmo_hybrid_reference.py`` is the plain float32 reference of the
@@ -46,8 +47,6 @@ import numpy as np
 
 from .. import nn
 from ..nn import initializer as I
-from ..ops import kda_prep
-from ..ops.gdn import conv_rows, conv_step
 from ..profiler.trace import annotate
 from .tick import (LayerwiseLM, SwiGLUMLP, TickRows, Weight, count_stats,
                    rms)
@@ -307,26 +306,6 @@ class OlmoHybrid(LayerwiseLM):
 # --------------------------------------------------------------------------
 # the tick's forward
 # --------------------------------------------------------------------------
-def _normed(c: OlmoHybridConfig, y, dtype):
-    """The convolution's output ``y`` ``[..., C]`` float32 through SiLU, its q
-    and k columns ``l2norm``-ed a head (``ops/kda_prep``'s sums over a head's
-    columns: the activations keep their heads side by side). -> ``(q, k,
-    v)``, each ``[..., heads, d]``."""
-    heads, dk, dv = c.linear_num_key_heads, c.linear_key_head_dim, \
-        c.linear_value_head_dim
-    y = jax.nn.silu(y).astype(dtype)
-    kw = c.key_width
-
-    def l2(a):
-        af = a.astype(_F32)
-        inv = jax.lax.rsqrt(kda_prep.head_sums(af * af, heads) + 1e-6)
-        return (af * kda_prep.over_heads(inv, dk)).astype(a.dtype)
-
-    split = lambda a, d: a.reshape(a.shape[:-1] + (heads, d))   # noqa: E731
-    return (split(l2(y[..., :kw]), dk), split(l2(y[..., kw:2 * kw]), dk),
-            split(y[..., 2 * kw:], dv))
-
-
 def _gates(c: OlmoHybridConfig, ab, p):
     """``(g, beta)`` float32 a head from the ``[a | b]`` projection."""
     heads = c.linear_num_value_heads
@@ -373,7 +352,6 @@ def olmo_hybrid_ragged_apply(c: OlmoHybridConfig, stacked, other, pools,
     dec_slots = jnp.where(page[:nd] > 0, slots[:nd], 0)
     ch_slots, ch_len = slots[nd:], row_len[nd:]
     fresh = row_pos0[nd:] == 0
-    no_rows = jnp.zeros((nd,), bool)
     live_tok = rows_.live(tab, row_len)
     keys = jnp.where((row_len > 0) & (tab[:, 0] > 0), jnp.minimum(
         row_pos0 + row_len, nps * ps), 0).astype(_F32)
@@ -389,28 +367,24 @@ def olmo_hybrid_ragged_apply(c: OlmoHybridConfig, stacked, other, pools,
             gate = x @ p["mix.gate.weight"]
             g, beta = _gates(c, x @ p["mix.ab.weight"], p)
         taps = p["mix.conv.weight"]
+        # between projections and rule, a row group a call: the pool's
+        # history read and written inside it (StatePools.prep)
+        prep = lambda rows, slots, **kw: pl.prep(           # noqa: E731
+            layer, slots, rows, taps, c.linear_num_key_heads,
+            c.linear_key_head_dim, **kw)
         outs = []
         if nd:
-            with annotate("blk/state_io"):
-                hist = pl.history(layer, dec_slots, no_rows)
             with annotate("blk/gdn/prep"):
-                y, left = conv_step(qkv[:nd], taps, hist)
-                q, k, v = _normed(c, y, qkv.dtype)
-            with annotate("blk/state_io"):
-                pl = pl.keep_history(layer, dec_slots, left)
+                q, k, v, pl = prep(qkv[:nd], dec_slots)
             with annotate("blk/gdn/step"):
                 o, pl = pl.step(layer, dec_slots, q, k, v, g[:nd], beta[:nd])
             outs.append(o)
         if nch:
             cut = lambda a: a[nd:].reshape(                 # noqa: E731
                 (nch, w) + a.shape[1:])
-            with annotate("blk/state_io"):
-                hist = pl.history(layer, ch_slots, fresh)
             with annotate("blk/gdn/prep"):
-                y, left = conv_rows(cut(qkv), taps, hist, ch_len)
-                q, k, v = _normed(c, y, qkv.dtype)
-            with annotate("blk/state_io"):
-                pl = pl.keep_history(layer, ch_slots, left)
+                q, k, v, pl = prep(cut(qkv), ch_slots, fresh=fresh,
+                                   row_len=ch_len)
             with annotate("blk/gdn/chunk"):
                 o, pl = pl.chunk(layer, ch_slots, fresh, ch_len, q, k, v,
                                  cut(g), cut(beta))

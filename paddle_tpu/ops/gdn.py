@@ -17,50 +17,88 @@ behind:
                     (``ops/kda._chunk_fwd``, the body the trained scan runs,
                     with the gate broadcast); positions at and past a row's
                     length are the identity (``beta = 0, g = 0``)
-``conv_step``,      the depthwise causal convolution before them, with the
-``conv_rows``       ``taps - 1`` positions it looks back on carried in
+``gdn_prep_rows``   what lies between a layer's projections and those two,
+                    for either row group: the depthwise causal convolution
+                    after the ``taps - 1`` positions a slot carries, SiLU,
+                    q's and k's l2norm a head; the slots' history is left
+                    holding the positions before the rows' next token
+                    (``conv_step``, ``conv_rows`` and ``normed`` are its
+                    ``jax.numpy`` parts)
 
 **The state's layout** is this file's, held by ``serving.paged_cache.
 StatePools``: ``[layers, slots + 1, heads / 2, dk, 2 dv]`` float32, two
 heads' values side by side on the lanes (``pack_state``). 192 is not a
 multiple of a tile's 128 lanes and 384 is: a head alone would be stored and
-moved at 256. Slot 0 is the null slot, as page 0 is the null page: a dead
-row (``slots`` 0: an empty slot, one still prefilling) reads and writes it
-and no tenant's state is touched. The stack is updated in place.
+moved at 256. The history's is ``[layers, taps - 1, rows, C]`` in the pools'
+type, a slot a row, ``rows`` whole tiles (``conv_slot_rows``). Slot 0 is the
+null slot, as page 0 is the null page: a dead row (``slots`` 0: an empty
+slot, one still prefilling) reads and writes it and no tenant's state is
+touched. Both stacks are updated in place.
 
-**Two spellings, picked where the program is traced** (``gdn_path``: the
-TPU as the target, no auto mesh, an even number of heads, pairs that fill
-the lanes), never by an argument, and counted there in
-``gdn/step_calls{path=}`` and ``gdn/chunk_calls{path=}``. The ``jax.numpy``
-spelling (``xla_step``, ``xla_chunk``) is the kernels' reference and the
-path off the chip. The kernels (``gdn_step``, ``gdn_chunk``) reach a row's
-slot by a scalar-prefetched index, so nothing gathers or scatters a state
-outside them. ``gdn_chunk`` pads the keys to 128 channels (exact: a zero
-channel adds nothing) and runs a pair's two heads over the pair's whole
-lanes with the other head's masked to zero, so every slice it takes is a
-tile's.
+**Two spellings of each, picked where the program is traced** (``gdn_path``,
+``prep_path``: the TPU as the target, no auto mesh, sizes that fit: an even
+number of heads and pairs that fill the lanes; rows and slots of whole
+tiles and columns that cut into whole heads on whole lanes), never by an
+argument, and counted there in ``gdn/step_calls{path=}``,
+``gdn/chunk_calls{path=}`` and ``gdn/prep_calls{path=}``. The ``jax.numpy``
+spelling (``xla_step``, ``xla_chunk``, ``xla_prep``) is the kernels'
+reference and the path off the chip. The kernels (``gdn_step``,
+``gdn_chunk``) reach a row's slot by a scalar-prefetched index, so nothing
+gathers or scatters a state outside them. ``gdn_chunk`` pads the keys to
+128 channels (exact: a zero channel adds nothing) and runs a pair's two
+heads over the pair's whole lanes with the other head's masked to zero, so
+every slice it takes is a tile's.
+
+**The pass between projections and rule** (``gdn_prep_step``,
+``gdn_prep_chunk``; PR 46) takes a layer's whole history ``[taps - 1, rows,
+C]`` through VMEM a column tile at a time (3.3 MB in bf16 at 48 rows of
+11,520 channels) and writes it back in place, because a bf16 row of a tiled
+array is no DMA's unit and a gather or scatter of rows by slot outside a
+kernel cost 9 and 33 us for 0.9 MB (PERF.md section 6, PR 46). The decode
+rows run **in slot space**: their tokens go to their slots' rows by an exact
+0/1 product on the MXU, the chain runs beside the history as it lies, the
+history moves up a tap where a slot has a row (a select), and the outputs
+come back to the rows by the product the other way; a dead row brings
+nothing, takes zeros and leaves the null slot as it was. A chunk row's
+tokens lie on the sublanes under its slot's history (``ops/kda_prep``'s
+halo) and the ``taps - 1`` positions before ``row_len`` are selected into
+its slot's rows; a row of no tokens (the chunk row of a tick without a
+chunk) skips the chain, keeps its projections as its outputs (nothing
+reads them) and holds its blocks at one column tile, so such a call moves
+one tile and not the layer's 18 MB. The l2norm's sums over a head's 96 columns, which
+fill no lanes, are one 0/1 product over groups of ``lcm(dk, 128)`` columns.
+The chain is float32 and rounds to the activations' type once, at the
+output (the spelling: after the SiLU and after the norm).
 """
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from . import kda
+from . import kda, kda_prep
 from .flash_attention import _interpret
 
-__all__ = ["gdn_step_rows", "gdn_chunk_rows", "conv_step", "conv_rows",
-           "gdn_path",
-           "pack_state", "unpack_state", "xla_step", "xla_chunk",
-           "pallas_step", "pallas_chunk", "gdn_recurrent"]
+__all__ = ["gdn_step_rows", "gdn_chunk_rows", "gdn_prep_rows", "conv_step",
+           "conv_rows", "normed", "gdn_path", "prep_path", "conv_slot_rows",
+           "pack_state", "unpack_state", "xla_step", "xla_chunk", "xla_prep",
+           "pallas_step", "pallas_chunk", "pallas_prep", "gdn_recurrent"]
 
 _F32 = jnp.float32
 _HIGHEST = jax.lax.Precision.HIGHEST
 _LANES = 128
 _VMEM_LIMIT = 96 * 1024 * 1024
+_NORM_EPS = 1e-6    # the l2norm's, a head (fla ``l2norm``)
+_SLOT_ROWS = 16     # a history's slots are whole tiles of any pool type
+_HALO = 8           # float32 sublanes above a chunk row: its history's place
+# The columns of a grid step of the pass (whole groups of ``lcm(dk, 128)``
+# columns: whole heads on whole lanes), measured on the v5e at the hybrid
+# cell's two shapes (benchmarks/gdn_prep_bench.py; PERF.md section 6)
+_PREP_COLS = 2048
 
 
 def pack_state(s):
@@ -378,3 +416,310 @@ def conv_rows(x, taps, hist, row_len):
     left = jax.vmap(lambda c, n: jax.lax.dynamic_slice_in_dim(
         c, n, nt - 1, 0))(cat, row_len)
     return y, jnp.moveaxis(left, 1, 0)
+
+
+# ---------------------------------------------------------------------------
+# between a layer's projections and its rule: the short convolution over a
+# carried history, SiLU, and q's and k's l2norm a head
+# ---------------------------------------------------------------------------
+def conv_slot_rows(num_slots: int) -> int:
+    """The rows of a history ``[.., taps - 1, rows, C]`` that holds
+    ``num_slots`` slots and the null slot: whole tiles of any pool type, so
+    that a layer's block passes through VMEM as it lies and the 0/1 products
+    over its rows meet no padding (the rows past ``num_slots`` are never
+    written and stay zero)."""
+    return -(-(num_slots + 1) // _SLOT_ROWS) * _SLOT_ROWS
+
+
+def normed(y, heads: int, dk: int, dtype):
+    """The convolution's output ``y`` ``[..., C]`` float32 (``C`` = ``[q
+    heads x dk | k heads x dk | v heads x dv]``) through SiLU, its q and k
+    columns ``l2norm``-ed a head (``ops/kda_prep``'s sums over a head's
+    columns: the activations keep their heads side by side), rounded to
+    ``dtype`` after the SiLU and after the norm. -> ``(q, k, v)``, each
+    ``[..., heads, d]``."""
+    y = jax.nn.silu(y).astype(dtype)
+    kw = heads * dk
+
+    def l2(a):
+        af = a.astype(_F32)
+        inv = jax.lax.rsqrt(kda_prep.head_sums(af * af, heads) + _NORM_EPS)
+        return (af * kda_prep.over_heads(inv, dk)).astype(a.dtype)
+
+    return _heads_of(l2(y[..., :kw]), l2(y[..., kw:2 * kw]), y[..., 2 * kw:],
+                     heads)
+
+
+def _heads_of(q, k, v, heads: int):
+    split = lambda a: a.reshape(                            # noqa: E731
+        a.shape[:-1] + (heads, a.shape[-1] // heads))
+    return split(q), split(k), split(v)
+
+
+def xla_prep(x, taps, conv, layer, slots, fresh, row_len, heads: int,
+             dk: int):
+    """``gdn_prep_rows`` in ``jax.numpy``: the history's rows gathered and
+    scattered a tap at a time, rows ``[n, C]`` by slot (what a gather or
+    scatter by slot moves as they lie; PERF.md section 6, PR 44),
+    ``conv_step`` or ``conv_rows`` and ``normed`` between them."""
+    hist = jnp.stack([conv[layer, j, slots] for j in range(conv.shape[1])])
+    if fresh is not None:
+        hist = jnp.where(fresh[None, :, None], 0, hist)
+    y, left = conv_step(x, taps, hist) if x.ndim == 2 \
+        else conv_rows(x, taps, hist, row_len)
+    for j in range(conv.shape[1]):
+        conv = conv.at[layer, j, slots].set(left[j].astype(conv.dtype))
+    return normed(y, heads, dk, x.dtype) + (conv,)
+
+
+def _prep_cols(heads: int, dk: int, dv: int):
+    """``(group, tile)``: the columns the pass norms at a time (whole heads
+    on whole lanes) and those of a grid step (whole groups, of q and k or of
+    v alone); ``None`` where the widths have no such cut."""
+    g = math.lcm(dk, _LANES)
+    both = math.gcd(2 * heads * dk, heads * dv)
+    if both % g:
+        return None
+    return g, max(t for t in range(g, both + 1, g)
+                  if both % t == 0 and t <= max(_PREP_COLS, g))
+
+
+def prep_path(x_shape, conv_shape, heads: int, dk: int) -> str:
+    """``"pallas"`` or ``"xla"`` for rows ``x_shape`` against a history of
+    ``conv_shape`` traced here: the TPU as the target, no auto mesh, rows
+    and slots of whole sublane tiles, columns that cut into whole heads on
+    whole lanes, a history the chunk rows' halo holds."""
+    from ..core.place import target_platform
+    from ..distributed import context as dctx
+
+    c = x_shape[-1]
+    dv = (c - 2 * heads * dk) // heads
+    if (target_platform() == "tpu" and dctx.kernel_auto_axes() is None
+            and x_shape[-2] % 8 == 0 and conv_shape[2] % _SLOT_ROWS == 0
+            and conv_shape[1] <= _HALO and dv > 0
+            and _prep_cols(heads, dk, dv) is not None):
+        return "pallas"
+    return "xla"
+
+
+def _segments(g: int, dk: int, dtype):
+    """0/1 ``[g, g]``: columns of one head. A row of squares times it is
+    every column's own head's sum (``kda_prep.head_sums`` and ``over_heads``
+    in one product)."""
+    head = jnp.arange(g, dtype=jnp.int32) // dk
+    return (head[:, None] == head[None, :]).astype(dtype)
+
+
+def _silu_norm(y, seg_ref, norm: bool):
+    """``y`` float32 ``[m, g]`` through SiLU and, where ``norm``, the l2norm
+    a head: the heads' sums of squares as one 0/1 product on the MXU, of
+    float32 operands under test and of the squares split ``hi + lo`` into
+    the activations' type on the chip (2^-17 of a sum; the reference's own
+    product takes the squares at one pass)."""
+    a = y * jax.nn.sigmoid(y)
+    if not norm:
+        return a
+    a2, seg = a * a, seg_ref[...]
+    if seg.dtype == _F32:
+        sums = kda._mm(a2, seg, kda._NN, _F32)
+    else:
+        hi = a2.astype(seg.dtype)
+        sums = kda._mm(hi, seg, kda._NN, seg.dtype) + kda._mm(
+            a2 - hi.astype(_F32), seg, kda._NN, seg.dtype)
+    return a * jax.lax.rsqrt(sums + _NORM_EPS)
+
+
+def _prep_step_kernel(layer_ref, to_rows, to_slots, has_ref, live_ref,
+                      seg_ref, x_ref, w_ref, c_ref, y_ref, c_out, *,
+                      group: int, norm_tiles: int):
+    """Grid (column tile,): the decode rows **in slot space**. The rows'
+    tokens go to their slots' rows by a 0/1 product (``to_slots`` ``[S, n]``,
+    exact: one term a sum), the chain runs on ``[S, group]`` beside the
+    layer's history as it lies, the history moves up a tap where a slot has
+    a row, and the outputs come back to the rows by the product the other
+    way (``to_rows`` ``[n, S]``). A dead row (the null slot) brings nothing
+    and takes zeros; the null slot's rows are never written."""
+    del layer_ref
+    dt, cdt = x_ref.dtype, c_ref.dtype
+    has = has_ref[...] > 0                                   # [S, 1]
+    live = live_ref[...] > 0                                 # [n, 1]
+
+    def tile(norm):
+        for c0 in range(0, x_ref.shape[1], group):
+            cols = slice(c0, c0 + group)
+            x = jnp.where(live, x_ref[:, cols], jnp.zeros((), dt))
+            xs = kda._mm(to_slots[...], x, kda._NN, dt)     # [S, g] f32
+            w = w_ref[:, cols].astype(_F32)
+            h = [c_ref[0, j, :, cols].astype(_F32)
+                 for j in range(c_ref.shape[1])]
+            y = xs * w[-1:] + sum(h[j] * w[j:j + 1] for j in range(len(h)))
+            a = _silu_norm(y, seg_ref, norm).astype(dt)
+            y_ref[:, cols] = kda._mm(to_rows[...], a, kda._NN, dt).astype(dt)
+            for j, new in enumerate(h[1:] + [xs]):
+                c_out[0, j, :, cols] = jnp.where(has, new, h[j]).astype(cdt)
+
+    i = pl.program_id(0)
+    pl.when(i < norm_tiles)(lambda: tile(True))
+    pl.when(i >= norm_tiles)(lambda: tile(False))
+
+
+def _prep_chunk_kernel(layer_ref, slots_ref, fresh_ref, len_ref, seg_ref,
+                       x_ref, w_ref, c_ref, y_ref, c_out, xs_ref, *,
+                       group: int, norm_tiles: int):
+    """Grid (column tile, row): a chunk row's tokens on the sublanes under
+    ``_HALO`` rows of which the last ``taps - 1`` are its slot's history
+    (zeros where ``fresh``), the taps' views read at a row's offset
+    (``ops/kda_prep``'s way). The history it leaves, the ``taps - 1``
+    positions before ``row_len``, is selected into its slot's rows of the
+    layer's block, which stays in VMEM over the rows. A row of no tokens
+    (a tick without a chunk) skips the chain and keeps its projections."""
+    del layer_ref
+    i, r = pl.program_id(0), pl.program_id(1)
+    dt, cdt = x_ref.dtype, c_ref.dtype
+    w_rows, back = x_ref.shape[1], c_ref.shape[1]
+    slot, n_tok = slots_ref[r], len_ref[r]
+    at = jax.lax.broadcasted_iota(jnp.int32, (c_ref.shape[2], 1), 0) == slot
+
+    @pl.when(r == 0)
+    def _enter():
+        c_out[...] = c_ref[...]
+
+    @pl.when(jnp.logical_or(n_tok > 0, slot > 0))
+    def _history():
+        mine = jnp.logical_and(at, fresh_ref[r] == 0)
+        for j in range(back):
+            xs_ref[_HALO - back + j:_HALO - back + j + 1, :] = jnp.sum(
+                jnp.where(mine, c_ref[0, j].astype(_F32), 0.0), axis=0,
+                keepdims=True)
+
+    @pl.when(n_tok > 0)
+    def _tokens():
+        xs_ref[_HALO:_HALO + w_rows, :] = x_ref[0].astype(_F32)
+
+    def tile(norm):
+        for c0 in range(0, x_ref.shape[2], group):
+            cols = slice(c0, c0 + group)
+            w = w_ref[:, cols].astype(_F32)
+            y = sum(xs_ref[_HALO - back + j:_HALO - back + j + w_rows, cols]
+                    * w[j:j + 1] for j in range(back + 1))
+            y_ref[0, :, cols] = _silu_norm(y, seg_ref, norm).astype(dt)
+
+    @pl.when(jnp.logical_and(n_tok == 0, i == 0))
+    def _empty():             # the row's blocks stay at this tile
+        y_ref[...] = x_ref[...]
+
+    pl.when(jnp.logical_and(n_tok > 0, i < norm_tiles))(lambda: tile(True))
+    pl.when(jnp.logical_and(n_tok > 0, i >= norm_tiles))(lambda: tile(False))
+
+    @pl.when(slot > 0)
+    def _leave():
+        # rows ``n_tok + _HALO - back ...`` of the scratch: Mosaic reads a
+        # dynamic row range at a tile's edge, so two tiles and a select
+        first = n_tok + _HALO - back
+        edge = pl.multiple_of(first // 8 * 8, 8)
+        near = xs_ref[pl.ds(edge, 16), :]
+        row = jax.lax.broadcasted_iota(jnp.int32, (16, 1), 0) + edge
+        for j in range(back):
+            left = jnp.sum(jnp.where(row == first + j, near, 0.0), axis=0,
+                           keepdims=True)
+            c_out[0, j] = jnp.where(at, left, c_out[0, j].astype(
+                _F32)).astype(cdt)
+
+
+def pallas_prep(x, taps, conv, layer, slots, fresh, row_len, heads: int,
+                dk: int):
+    """The kernels ``gdn_prep_step`` (``x`` ``[n, C]``: a token a row) and
+    ``gdn_prep_chunk`` (``x`` ``[n, w, C]``: ``row_len`` tokens a row, a row
+    ``fresh`` at a sequence's start), whatever the platform (interpreted on
+    the CPU): ``taps`` ``[T, C]``, ``conv`` the whole stack ``[layers, T - 1,
+    rows, C]``, read and written in place at ``(layer, slots)``. -> ``(q,
+    k ``[.., heads, dk]``, v ``[.., heads, dv]``, conv)``."""
+    c = x.shape[-1]
+    kw, back, s = heads * dk, conv.shape[1], conv.shape[2]
+    group, tile = _prep_cols(heads, dk, (c - 2 * kw) // heads)
+    kind = dict(group=group, norm_tiles=2 * kw // tile)
+    seg = _segments(group, dk, x.dtype)
+    layer = jnp.asarray(layer, jnp.int32).reshape(1)
+    slots = slots.astype(jnp.int32)
+    whole = lambda a: pl.BlockSpec(                         # noqa: E731
+        a.shape, lambda *_: (0,) * a.ndim)
+    if x.ndim == 2:
+        n = x.shape[0]
+        hot = (slots[:, None] == jnp.arange(s, dtype=jnp.int32)[None, :]) \
+            & (slots[:, None] > 0)                          # [n, S]
+        sides = (hot.astype(x.dtype), hot.T.astype(x.dtype),
+                 jnp.any(hot, 0)[:, None].astype(_F32),
+                 (slots[:, None] > 0).astype(_F32), seg)
+        cut = lambda rows: pl.BlockSpec(                    # noqa: E731
+            (rows, tile), lambda i, ly: (0, i))
+        hist = pl.BlockSpec((1, back, s, tile),
+                            lambda i, ly: (ly[0], 0, 0, i))
+        y, conv = pl.pallas_call(
+            functools.partial(_prep_step_kernel, **kind),
+            name="gdn_prep_step",
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=1, grid=(c // tile,),
+                in_specs=[whole(a) for a in sides]
+                + [cut(n), cut(taps.shape[0]), hist],
+                out_specs=[cut(n), hist]),
+            out_shape=[jax.ShapeDtypeStruct(x.shape, x.dtype),
+                       jax.ShapeDtypeStruct(conv.shape, conv.dtype)],
+            input_output_aliases={len(sides) + 3: 1},
+            compiler_params=_params("arbitrary"),
+            interpret=_interpret(),
+        )(layer, *sides, x, taps, conv)
+    else:
+        n, w = x.shape[:2]
+        # what has nothing to do stays at the first column tile, and a block
+        # whose index does not move is neither fetched nor written again: a
+        # row of no tokens (the rest of its output is its input, aliased),
+        # and the history where every row is dead. A tick without a chunk
+        # moves one tile of each
+        some = lambda sl: functools.reduce(                 # noqa: E731
+            jnp.logical_or, [sl[k] > 0 for k in range(n)])
+        rows_ = pl.BlockSpec(
+            (1, w, tile), lambda i, r, ly, sl, fr, ln: (
+                r, 0, jnp.where(ln[r] > 0, i, 0)))
+        hist = pl.BlockSpec(
+            (1, back, s, tile), lambda i, r, ly, sl, fr, ln: (
+                ly[0], 0, 0, jnp.where(some(sl), i, 0)))
+        y, conv = pl.pallas_call(
+            functools.partial(_prep_chunk_kernel, **kind),
+            name="gdn_prep_chunk",
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=4, grid=(c // tile, n),
+                in_specs=[whole(seg), rows_,
+                          pl.BlockSpec((taps.shape[0], tile),
+                                       lambda i, r, *_: (0, i)), hist],
+                out_specs=[rows_, hist],
+                scratch_shapes=[pltpu.VMEM((_HALO + w + 16, tile), _F32)]),
+            out_shape=[jax.ShapeDtypeStruct(x.shape, x.dtype),
+                       jax.ShapeDtypeStruct(conv.shape, conv.dtype)],
+            input_output_aliases={5: 0, 7: 1},
+            compiler_params=_params("arbitrary", "arbitrary"),
+            interpret=_interpret(),
+        )(layer, slots, fresh.astype(jnp.int32), row_len.astype(jnp.int32),
+          seg, x, taps, conv)
+    return _heads_of(y[..., :kw], y[..., kw:2 * kw], y[..., 2 * kw:],
+                     heads) + (conv,)
+
+
+def gdn_prep_rows(x, taps, conv, layer, slots, fresh, row_len, heads: int,
+                  dk: int):
+    """What lies between a linear layer's projections and its rule, for one
+    group of rows: ``x`` ``[n, C]`` (the decode rows, a token each) or ``[n,
+    w, C]`` (the chunk rows, ``row_len`` tokens each), ``C`` = ``[q | k |
+    v]`` heads side by side, through the depthwise causal convolution of
+    ``taps`` ``[T, C]`` after the ``T - 1`` positions their slots carry in
+    ``conv`` ``[layers, T - 1, rows, C]`` (zeros where ``fresh``), SiLU, and
+    q's and k's l2norm a head. The rows' slots of ``conv[layer]`` are left
+    holding the positions before the rows' next token (a chunk row's, the
+    ``T - 1`` before ``row_len``). Dead rows carry slot 0, the null slot,
+    whose content no tenant reads. -> ``(q, k ``[.., heads, dk]``, v ``[..,
+    heads, dv]``, conv)``."""
+    path = prep_path(x.shape, conv.shape, heads, dk)
+    _count("prep_calls", path)
+    if path == "pallas":
+        return pallas_prep(x, taps, conv, layer, slots, fresh, row_len,
+                           heads, dk)
+    return xla_prep(x, taps, conv, layer, slots, fresh, row_len, heads, dk)

@@ -60,8 +60,8 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from ..ops.gdn import (gdn_chunk_rows, gdn_step_rows, pack_state,
-                       unpack_state)
+from ..ops.gdn import (conv_slot_rows, gdn_chunk_rows, gdn_prep_rows,
+                       gdn_step_rows, pack_state, unpack_state)
 from ..ops.paged_attention import (
     index_scores, latent_attention, latent_scatter, paged_kv_scatter,
     ragged_paged_attention, selected_latent_attention,
@@ -1229,10 +1229,22 @@ class StatePools(NamedTuple):
            batches a float32 product otherwise)
     state  ``[Ll, slots + 1, ...]`` float32: one state a linear layer and
            slot, whatever the context, in ``ops/gdn.pack_state``'s layout
-    conv   ``[Ll, taps - 1, slots + 1, C]``: the positions each linear
-           layer's short convolution looks back on, the oldest first. It is
-           read and written a tap at a time, rows ``[n, C]`` by slot: rows
-           are what a gather or scatter by slot moves as they lie (one
+    conv   ``[Ll, taps - 1, rows, C]``: the positions each linear layer's
+           short convolution looks back on, the oldest first, a slot a row;
+           ``rows`` is ``slots + 1`` rounded up to whole tiles
+           (``ops/gdn.conv_slot_rows``: 48 for 40 slots; the rows past the
+           last slot are never written). **One reader and one writer**:
+           ``prep`` hands the layer's history to ``ops/gdn.gdn_prep_rows``
+           with a group of rows and takes the pool back, as ``step`` and
+           ``chunk`` do for the state. On the chip that is one Pallas call a
+           row group through which the layer's block (3.3 MB) passes as it
+           lies and is written **in place**: a row's slot is reached inside
+           VMEM, so nothing gathers or scatters the pool outside it (the
+           ``jax.numpy`` spelling's three row gathers and three row
+           scatters a layer were 1.6 of the tick's 24.8 ms, a scatter 33 us
+           for 0.9 MB; PERF.md section 6, PR 46). Off the chip the spelling
+           reads and writes it a tap at a time, rows ``[n, C]`` by slot:
+           rows are what a gather or scatter by slot moves as they lie (one
            scatter over the taps and slots together made XLA:TPU re-lay the
            whole array around every write, 24 copies of 34 MB a tick, and
            a slot's taps as one row of ``3 C`` cost more in re-laying the
@@ -1242,11 +1254,11 @@ class StatePools(NamedTuple):
     page: rows that carry no tenant's token read and write it. A pytree
     like ``Pools``, and the one place that knows this format: a forward
     writes and reads K/V through ``scatter`` and ``attend`` as a K/V model
-    does, the convolution's history through ``history`` and
-    ``keep_history``, and the state through ``step`` (decode rows) and
-    ``chunk`` (chunk rows), ``ops/gdn``'s functions on the field they are
-    for. A tenant's first chunk enters at zero (``fresh``): nothing else
-    clears a slot."""
+    does, and passes a linear layer's rows through ``prep`` (the
+    convolution over the carried history, SiLU, l2norm) and then ``step``
+    (decode rows) or ``chunk`` (chunk rows), ``ops/gdn``'s functions on the
+    field they are for. A tenant's first chunk enters at zero (``fresh``):
+    nothing else clears a slot."""
 
     kv: Pools
     state: jax.Array
@@ -1268,7 +1280,8 @@ class StatePools(NamedTuple):
             jnp.zeros((caches["state_layers"], num_slots + 1) + one.shape,
                       jnp.float32),
             jnp.zeros((caches["state_layers"], caches["conv_taps"] - 1,
-                       num_slots + 1, caches["conv_width"]), dtype))
+                       conv_slot_rows(num_slots), caches["conv_width"]),
+                      dtype))
 
     @property
     def page_size(self) -> int:
@@ -1294,18 +1307,17 @@ class StatePools(NamedTuple):
                               true_len)[..., :q.shape[-2], :]
 
     # -- the linear layers: a state and a history a slot ---------------
-    def history(self, layer, slots, fresh):
-        """``[taps - 1, n, C]``: the positions before each row's first
-        token, zeros where the row is a sequence's first (``fresh``)."""
-        return jnp.where(fresh[None, :, None], 0, jnp.stack(
-            [self.conv[layer, j, slots]
-             for j in range(self.conv.shape[1])]))
-
-    def keep_history(self, layer, slots, hist) -> "StatePools":
-        conv = self.conv
-        for j in range(conv.shape[1]):
-            conv = conv.at[layer, j, slots].set(hist[j].astype(conv.dtype))
-        return self._replace(conv=conv)
+    def prep(self, layer, slots, x, taps, heads: int, key_dim: int,
+             fresh=None, row_len=None):
+        """``ops/gdn.gdn_prep_rows`` at ``layer``'s history: the rows' ``[q
+        | k | v]`` projections ``x`` (``[n, C]`` decode rows, ``[n, w, C]``
+        chunk rows of ``row_len`` tokens, ``fresh`` at a sequence's start)
+        through the convolution after what their slots carry, SiLU and the
+        l2norm. -> ``(q, k, v, the pools with the history the rows
+        leave)``."""
+        q, k, v, conv = gdn_prep_rows(x, taps, self.conv, layer, slots,
+                                      fresh, row_len, heads, key_dim)
+        return q, k, v, self._replace(conv=conv)
 
     def step(self, layer, slots, q, k, v, g, beta):
         """``ops/gdn.gdn_step_rows`` at ``layer``'s states."""
@@ -1423,12 +1435,13 @@ class StatePagePool(PagePool):
         for slot in np.flatnonzero(self._stateful):
             if not self._held[slot]:
                 out.append(f"slot {int(slot)} holds a state and no page")
-        want = (self.num_slots + 1,)
-        for name, a, axis in (("state", self.pools.state, 1),
-                              ("conv", self.pools.conv, 2)):
-            if a.shape[axis:axis + 1] != want:
-                out.append(f"{name} holds {a.shape[axis]} slots, not "
-                           f"{want[0]} (the null slot and one a slot)")
+        for name, a, axis, want in (
+                ("state", self.pools.state, 1, self.num_slots + 1),
+                ("conv", self.pools.conv, 2,
+                 conv_slot_rows(self.num_slots))):
+            if a.shape[axis] != want:
+                out.append(f"{name} holds {a.shape[axis]} slot rows, not "
+                           f"{want} (the null slot and one a slot)")
         return out
 
 

@@ -252,9 +252,11 @@ def test_olmoh_rehearsal():
     out = chip_smoke.phase_olmoh(cfg, 3, 4, 24, 8, (6, 24, 48, 5, 128),
                                  "xla", requests=((23, 12), (41, 10),
                                                   (7, 16)))
-    assert out["tick_paths"] == {"step": ["xla"], "chunk": ["xla"]}
+    assert out["tick_paths"] == {"step": ["xla"], "chunk": ["xla"],
+                                 "prep": ["xla"]}
     assert max(out["step_o"], out["step_s"], out["chunk_o"],
                out["chunk_s"]) <= chip_smoke.TOL_GDN_OPS
+    assert out["prep_step"] == out["prep_chunk"] == 0   # the spelling itself
     assert out["median"] <= out["worst"] <= chip_smoke.TOL_GDN_WORST
     assert out["weights_bytes"] == 2 * cfg.num_params()
 

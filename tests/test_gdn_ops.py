@@ -206,3 +206,116 @@ def test_the_path_is_observed_and_counted(monkeypatch):
                       _stack(s0), 0, jnp.asarray([1, 2]))
     assert metrics.registry().counter(
         "gdn/step_calls{path=xla}").value == before + 1
+
+
+# ---------------------------------------------------------------------------
+# the pass between projections and rule (gdn_prep_step, gdn_prep_chunk)
+# ---------------------------------------------------------------------------
+PREP = (4, 96, 192)                            # heads, dk, dv: C = 1,536
+
+
+def _prep_inputs(seed, shape, pool_dtype, dtype, slots=5, layers=2):
+    h, dk, dv = PREP
+    c = 2 * h * dk + h * dv
+    ks = jax.random.split(jax.random.PRNGKey(seed), 3)
+    x = jax.random.normal(ks[0], shape + (c,)).astype(dtype)
+    taps = jax.random.uniform(ks[1], (4, c), minval=-0.5,
+                              maxval=0.5).astype(dtype)
+    conv = jax.random.normal(
+        ks[2], (layers, 3, gdn.conv_slot_rows(slots), c)).astype(pool_dtype)
+    # the null slot and the rows past the last slot hold zeros, as a pool's
+    conv = conv.at[:, :, 0].set(0).at[:, :, slots + 1:].set(0)
+    return x, taps, conv
+
+
+PREP_CASES = {
+    # decode rows: slots of the rows
+    "decode live rows": ("step", [3, 1, 5, 2, 4]),
+    "decode one dead row": ("step", [3, 0, 5, 2, 4, 1]),
+    "decode dead rows collide": ("step", [0, 2, 0, 0, 5, 0, 0, 1]),
+    "decode all dead": ("step", [0] * 8),
+    # chunk rows: (slot, fresh, row_len) a row, rows of 16
+    "chunk whole carried": ("chunk", [(2, False, 16)]),
+    "chunk whole fresh": ("chunk", [(4, True, 16)]),
+    "chunk partial carried": ("chunk", [(1, False, 7)]),
+    "chunk partial fresh": ("chunk", [(5, True, 2)]),
+    "chunk no token": ("chunk", [(3, False, 0)]),
+    "chunk no token fresh": ("chunk", [(3, True, 0)]),
+    "chunk dead row": ("chunk", [(0, False, 0)]),
+    "chunk two rows": ("chunk", [(2, False, 9), (5, True, 16)]),
+}
+
+
+@pytest.mark.parametrize("cols", [384, 2048])
+@pytest.mark.parametrize("pool_dtype,dtype", [
+    (jnp.float32, jnp.float32), (jnp.bfloat16, jnp.bfloat16)])
+@pytest.mark.parametrize("case", sorted(PREP_CASES))
+def test_the_prep_pass_equals_its_xla_spelling(case, pool_dtype, dtype, cols,
+                                               monkeypatch):
+    """q, k, v within one rounding of the reference (the kernel rounds
+    once, the spelling after the SiLU and after the norm), the history the
+    rows leave in the pool bit for bit, every other row of the pool
+    untouched."""
+    monkeypatch.setattr(gdn, "_PREP_COLS", cols)    # 4 column tiles, or 2
+    h, dk, dv = PREP
+    kind, rows = PREP_CASES[case]
+    if kind == "step":
+        slots = jnp.asarray(rows, jnp.int32)
+        x, taps, conv = _prep_inputs(21, (len(rows),), pool_dtype, dtype)
+        fresh = row_len = None
+    else:
+        slots = jnp.asarray([r[0] for r in rows], jnp.int32)
+        fresh = jnp.asarray([r[1] for r in rows])
+        row_len = jnp.asarray([r[2] for r in rows], jnp.int32)
+        x, taps, conv = _prep_inputs(22, (len(rows), 16), pool_dtype, dtype)
+    want = gdn.xla_prep(x, taps, conv, 1, slots, fresh, row_len, h, dk)
+    q, k, v, after = gdn.pallas_prep(x, taps, conv, 1, slots, fresh, row_len,
+                                     h, dk)
+    assert q.shape == x.shape[:-1] + (h, dk) and v.shape[-2:] == (h, dv)
+    assert after.dtype == conv.dtype and q.dtype == x.dtype
+    live = np.asarray(slots) > 0
+    if kind == "chunk":
+        live = live & (np.asarray(row_len) > 0)
+    tol = 2e-5 if dtype == jnp.float32 else 2 ** -7
+    for got, ref in zip((q, k, v), want[:3]):
+        np.testing.assert_allclose(
+            np.asarray(got, np.float32)[live], np.asarray(ref, np.float32)[
+                live], atol=tol, rtol=tol)
+        assert np.isfinite(np.asarray(got, np.float32)).all()
+    # every tenant's slot as the reference leaves it: the rows' own row for
+    # row, the others untouched (the null slot's content is nobody's)
+    after, conv = np.asarray(after, np.float32), np.asarray(conv, np.float32)
+    np.testing.assert_array_equal(
+        after[1][:, 1:], np.asarray(want[3], np.float32)[1][:, 1:])
+    idle = [s for s in range(1, conv.shape[2])
+            if s not in np.asarray(slots).tolist()]
+    np.testing.assert_array_equal(after[1][:, idle], conv[1][:, idle])
+    np.testing.assert_array_equal(after[0], conv[0])        # the other layer
+
+
+def test_the_prep_path_is_observed_and_counted(monkeypatch):
+    from paddle_tpu.profiler import metrics
+
+    x, conv = (40, 11520), (12, 3, gdn.conv_slot_rows(40), 11520)
+    assert gdn.conv_slot_rows(40) == 48 and gdn.conv_slot_rows(15) == 16
+    assert gdn.prep_path(x, conv, 30, 96) == "xla"          # the CPU
+    monkeypatch.setenv("PADDLE_TPU_TARGET_PLATFORM", "tpu")
+    assert gdn.prep_path(x, conv, 30, 96) == "pallas"
+    assert gdn.prep_path((1, 256, 11520), conv, 30, 96) == "pallas"
+    assert gdn._prep_cols(30, 96, 192) == (384, 1920)
+    assert gdn.prep_path((3, 11520), conv, 30, 96) == "xla"     # rows
+    assert gdn.prep_path(x, (12, 3, 41, 11520), 30, 96) == "xla"  # slots
+    assert gdn.prep_path((40, 6 * 24 * 4), (2, 3, 48, 6 * 24 * 4), 6,
+                         24) == "xla"       # heads that fill no lanes
+    monkeypatch.delenv("PADDLE_TPU_TARGET_PLATFORM")
+    count = lambda: metrics.registry().counter(             # noqa: E731
+        "gdn/prep_calls{path=xla}").value
+    before = count()
+    x, taps, conv = _prep_inputs(23, (3,), jnp.float32, jnp.float32)
+    q, k, v, after = gdn.gdn_prep_rows(x, taps, conv, 0, jnp.asarray(
+        [2, 0, 1]), None, None, 4, 96)
+    assert count() == before + 1
+    want = gdn.xla_prep(x, taps, conv, 0, jnp.asarray([2, 0, 1]), None,
+                        None, 4, 96)
+    np.testing.assert_array_equal(q, want[0])
+    np.testing.assert_array_equal(after, want[3])

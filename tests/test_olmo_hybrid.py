@@ -151,6 +151,41 @@ def test_the_engine_serves_the_references_logits_through_its_states(net,
         eng.pool.pools.state.nbytes + eng.pool.pools.conv.nbytes
 
 
+def test_the_engine_emits_the_same_tokens_on_both_prep_paths(tokens,
+                                                             monkeypatch):
+    """What lies between a linear layer's projections and its rule
+    (``StatePools.prep``) as the ``jax.numpy`` spelling and as the Pallas
+    pass (interpreted), at heads whose columns cut into whole lanes (4 of 32
+    x 64): the same requests, the same tokens, and the tick counts the path
+    it was traced with. The path is picked at one seam, ``ops/gdn.
+    prep_path``, and that is where this test substitutes."""
+    from paddle_tpu.ops import gdn
+
+    net = build(linear_num_key_heads=4, linear_num_value_heads=4,
+                linear_key_head_dim=32, linear_value_head_dim=64)
+    reg = metrics.registry()
+    calls = lambda path: reg.counter(                       # noqa: E731
+        "gdn/prep_calls{path=%s}" % path).value
+
+    def serve():
+        eng = engine(net)
+        rids = [eng.submit(tokens[:21], 12), eng.submit(tokens[30:43], 7)]
+        outs = eng.run()
+        assert eng.pool.check_consistency() == []
+        return [outs[r].tolist() for r in rids], eng
+
+    before = calls("xla"), calls("pallas")
+    want, eng = serve()
+    # four linear layers, a call for the decode rows and one for the chunk's
+    assert (calls("xla"), calls("pallas")) == (before[0] + 8, before[1])
+    _against_reference(net, eng, 0, tokens[:21])
+    monkeypatch.setattr(gdn, "prep_path", lambda *a: "pallas")
+    got, eng = serve()
+    assert calls("pallas") == before[1] + 8
+    assert got == want
+    _against_reference(net, eng, 0, tokens[:21])
+
+
 def test_a_live_slots_state_is_the_references(net, tokens):
     """What the check reads: while a request is decoding, its slot's state
     in every linear layer is the reference's after the tokens the slot
@@ -266,9 +301,10 @@ def test_the_pools_of_a_state_and_their_consistency(net):
     assert isinstance(pool, StatePagePool)
     assert isinstance(pool.pools, StatePools)
     # two full layers of 6 heads at 8 rows; four linear layers, 3 + 1 slots
+    # (the history's slot rows are whole tiles: ops/gdn.conv_slot_rows)
     assert pool.pools.kv.k.shape == (2, 40, PAGE, 8, 8)
     assert pool.pools.state.shape == (4, 4, 3, 24, 96)
-    assert pool.pools.conv.shape == (4, 3, 4, 2 * 144 + 288)
+    assert pool.pools.conv.shape == (4, 3, 16, 2 * 144 + 288)
     assert pool.pools.state.dtype == jnp.float32
     assert set(pool.live_shares()) == {"kv", "state"}
     assert pool.grow_slot(1, 3)

@@ -745,6 +745,17 @@ def test_tpu_compile_the_hybrid_models_forward(monkeypatch):
     text = compiled.as_text()
     assert len(re.findall(r"%gdn_step[\w.\-]* = ", text)) == 3
     assert len(re.findall(r"%gdn_chunk[\w.\-]* = ", text)) == 3
+    # ISSUE 46: what lies between projections and rule is one call a row
+    # group, the history read and written inside it: no gather, scatter or
+    # copy of the history's stack is left in the program
+    assert len(re.findall(r"%gdn_prep_step[\w.\-]* = ", text)) == 3
+    assert len(re.findall(r"%gdn_prep_chunk[\w.\-]* = ", text)) == 3
+    assert pools.conv.shape == (3, 3, 48, 11520)
+    moved = [ln for ln in text.splitlines()
+             if re.search(r" = bf16\[3,3,48,11520\]\S* (?!custom-call|"
+                          r"parameter|get-tuple-element|bitcast)", ln)]
+    assert not moved, moved[:3]
+    assert "remat_compressed" not in text
     assert len(re.findall(r"%ragged_paged_attn[\w.\-]* = ", text)) == 2
     ma = compiled.memory_analysis()
     pool_bytes = sum(a.size * a.dtype.itemsize
