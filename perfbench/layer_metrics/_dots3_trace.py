@@ -17,9 +17,9 @@ the parent of the PR that brought it) gives ``None`` and raises nothing.
 from __future__ import annotations
 
 import re
-from typing import Dict, Optional
+from typing import Dict, Optional, Tuple
 
-from perfbench import loader, tracered, yardstick
+from perfbench import loader, tracered, yardstick, yardstick_mla
 
 _PART = {"blk/attn/mla": "mla", "blk/attn/swa": "swa",
          "blk/latent_scatter": "scatter", "blk/index": "index",
@@ -99,11 +99,29 @@ def tick_shape(run) -> Optional[dict]:
             "peak": yardstick.chip_peak(run["ctx"].devices[0].device_kind)}
 
 
+def tick_needs(run) -> Optional[Tuple[dict, float, float]]:
+    """``(tick_shape, bytes the mean tick must move, operations it must
+    do)`` by ``yardstick_mla``: what the ``served.*`` shares of the whole
+    tick are taken over (``_served`` asks every helper that has this)."""
+    s = tick_shape(run)
+    if s is None:
+        return None
+    rows = (run["ctx"].config, s["decode"], s["chunks"], s["chunk"],
+            s["context"], s["sampled"])
+    return (s, yardstick_mla.tick_bytes(*rows, s["touched"]),
+            yardstick_mla.tick_flops(*rows, s["expert_rows"]))
+
+
+def experts_bytes(run) -> Optional[float]:
+    """Bytes of the held experts' matrices that a tick gave a row."""
+    s = tick_shape(run)
+    return None if s is None else yardstick_mla.experts_bytes(
+        run["ctx"].config, s["touched"])
+
+
 def roofline_pct(run, name: str, ops_bytes) -> Optional[float]:
     """``ops_bytes(config, decode, chunks, chunk, context)``'s least time
     over part ``name``'s device time."""
-    from perfbench import yardstick_mla
-
     s, ms = tick_shape(run), read_part(run, name)
     if s is None or not ms:
         return None

@@ -18,9 +18,10 @@ A program that names no ``blk/attn/mla_chunk`` or ``blk/attn/mla_decode``
 from __future__ import annotations
 
 import re
-from typing import Dict, Optional
+from typing import Dict, Optional, Tuple
 
-from perfbench import loader, tracered, yardstick
+from perfbench import loader, tracered, yardstick, \
+    yardstick_mla_dense
 
 _PART = {"blk/attn/mla_chunk": "mla_chunk",
          "blk/attn/mla_decode": "mla_decode",
@@ -101,3 +102,24 @@ def tick_shape(run) -> Optional[dict]:
             "touched": f.get("tick_experts_touched_share", 0.0),
             "expert_rows": f.get("tick_expert_rows", 0.0),
             "peak": yardstick.chip_peak(run["ctx"].devices[0].device_kind)}
+
+
+def tick_needs(run) -> Optional[Tuple[dict, float, float]]:
+    """``(tick_shape, bytes the mean tick must move, operations it must
+    do)`` by ``yardstick_mla_dense``: what the ``served.*`` shares of the
+    whole tick are taken over (``_served`` asks every helper that has
+    this)."""
+    s = tick_shape(run)
+    if s is None:
+        return None
+    rows = (run["ctx"].config, s["tokens"], (s["decode"], s["chunk"]),
+            s["sampled"])
+    return (s, yardstick_mla_dense.tick_bytes(*rows, s["touched"]),
+            yardstick_mla_dense.tick_flops(*rows, s["expert_rows"]))
+
+
+def experts_bytes(run) -> Optional[float]:
+    """Bytes of the held experts' matrices that a tick gave a row."""
+    s = tick_shape(run)
+    return None if s is None else yardstick_mla_dense.experts_bytes(
+        run["ctx"].config, s["touched"])

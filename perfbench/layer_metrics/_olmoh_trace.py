@@ -18,9 +18,9 @@ parent of the PR that brought it) gives ``None`` and raises nothing.
 from __future__ import annotations
 
 import re
-from typing import Dict, Optional
+from typing import Dict, Optional, Tuple
 
-from perfbench import loader, yardstick
+from perfbench import loader, yardstick, yardstick_gdn
 
 _PART = {"blk/gdn/proj": "dense", "blk/gdn/prep": "gdn_prep",
          "blk/gdn/step": "gdn_step", "blk/gdn/chunk": "gdn_chunk",
@@ -95,6 +95,18 @@ def tick_shape(run) -> Optional[dict]:
             "chunk_keys": f["tick_chunk_keys"],
             "chunk_pairs": f["tick_chunk_pairs"],
             "peak": yardstick.chip_peak(run["ctx"].devices[0].device_kind)}
+
+
+def tick_needs(run) -> Optional[Tuple[dict, float, float]]:
+    """``(tick_shape, bytes the mean tick must move, operations it must
+    do)`` by ``yardstick_gdn``: what the ``served.*`` shares of the whole
+    tick are taken over (``_served`` asks every helper that has this). No
+    ``experts_bytes``: this tick holds no experts."""
+    s = tick_shape(run)
+    if s is None:
+        return None
+    c = run["ctx"].config
+    return s, yardstick_gdn.tick_bytes(c, s), yardstick_gdn.tick_flops(c, s)
 
 
 def roofline_pct(run, name: str, least) -> Optional[float]:

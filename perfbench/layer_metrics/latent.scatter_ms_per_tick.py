@@ -1,8 +1,9 @@
-"""Device time the tick spends writing the tokens' rows into the latent, the
-indexer-key and the windowed pools (``blk/latent_scatter``), all layers."""
+"""Device time the tick spends writing its tokens' rows into the pages they
+touch (``blk/latent_scatter``), all layers: dots3's latent, indexer-key and
+windowed pools; DeepSeek-V2's latent pool (five layers)."""
 from perfbench import loader
 
 
 def read(run):
-    return loader.load_module("layer_metrics", "_dots3_trace").read_part(
+    return loader.load_module("layer_metrics", "_served").read_part(
         run, "scatter")

@@ -1,5 +1,6 @@
 """The fullest held expert's rows over the mean held expert's, mean over the
-expert layers and the run's ticks, as the ticks report it."""
+expert layers and the run's ticks, as the ticks report it (dots3's cell and
+DeepSeek-V2's)."""
 
 
 def read(run):

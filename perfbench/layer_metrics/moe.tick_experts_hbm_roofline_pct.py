@@ -1,16 +1,16 @@
 """The held experts' share of their memory roofline in a serving tick: the time
 to read, once, the matrices of the held experts that were given a row (the
-ticks' own count, ``experts_touched_share``), over
-``moe.tick_experts_ms_per_tick``. With about 8 rows an expert the products are
+ticks' own count, ``experts_touched_share``; the family's own yardstick,
+its trace helper's ``experts_bytes``), over ``moe.tick_experts_ms_per_tick``.
+With about 8 rows an expert (dots3) or 20 (DeepSeek-V2) the products are
 bound by the weights' bytes, not by arithmetic."""
-from perfbench import loader, yardstick_mla
+from perfbench import loader
 
 
 def read(run):
-    tr = loader.load_module("layer_metrics", "_dots3_trace")
-    s, ms = tr.tick_shape(run), tr.read_part(run, "experts")
-    if s is None or not ms:
+    needs = loader.load_module("layer_metrics", "_served").experts_needs(run)
+    if needs is None:
         return None
-    least = yardstick_mla.experts_bytes(run["ctx"].config, s["touched"]) \
-        / s["peak"].hbm_bytes_per_s * 1e3
+    s, moved, ms = needs
+    least = moved / s["peak"].hbm_bytes_per_s * 1e3
     return 100.0 * least / ms

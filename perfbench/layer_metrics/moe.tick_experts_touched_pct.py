@@ -1,5 +1,6 @@
 """Share of the held experts a tick gave at least one row, mean over the
-expert layers and the run's ticks: what of their weights a tick must read."""
+expert layers and the run's ticks, as the ticks report it (dots3's cell and
+DeepSeek-V2's): what of their weights the grouped matmuls must read."""
 
 
 def read(run):

@@ -1,9 +1,12 @@
-"""Device time the tick spends routing (``moe/route``: the router's product,
-the rounds of argmax, the counting sort of the held assignments), all expert
-layers."""
+"""Device time the tick spends routing (``moe/route``), all expert layers,
+in the two cells that hold a share of an expert-parallel layer. dots3: the
+router's product, the rounds of argmax, the counting sort of the held
+assignments; DeepSeek-V2: the softmax router under its group limit, the
+counting sort of the held rows and the tick's routing statistics (four
+expert layers)."""
 from perfbench import loader
 
 
 def read(run):
-    return loader.load_module("layer_metrics", "_dots3_trace").read_part(
+    return loader.load_module("layer_metrics", "_served").read_part(
         run, "route")

@@ -1,8 +1,9 @@
-"""Device time the tick spends in the shared expert every token passes
-(``moe/shared``), all expert layers."""
+"""Device time the tick spends in the shared experts every token passes
+(``moe/shared``), all expert layers: dots3's one shared expert,
+DeepSeek-V2's two (one SwiGLU of 3,072, four expert layers)."""
 from perfbench import loader
 
 
 def read(run):
-    return loader.load_module("layer_metrics", "_dots3_trace").read_part(
+    return loader.load_module("layer_metrics", "_served").read_part(
         run, "shared")

@@ -1,7 +1,9 @@
-"""What the host itself spends on a tick: the ``pt:step/admit``, ``chunks``,
-``grow``, ``build`` and ``dispatch`` spans and the drains that did not have
-to wait (``waited=0``), summed over the traced stretch, over the ticks
-dispatched in it."""
+"""What the host itself spends on a tick in the two open-loop cells
+(``serve-chat-steady``; ``serve-ouro-reason-steady``, where it stands against
+a tick of 56 ms): the ``pt:step/admit``, ``chunks``, ``grow``, ``build`` and
+``dispatch`` spans and the drains that did not have to wait (``waited=0``),
+summed over the traced stretch, over the ticks dispatched in it.
+"""
 from perfbench import loader
 
 

@@ -22,22 +22,22 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 TOY = os.path.join(HERE, "toy_dots3")
 CELL = "serve-dots3-longdoc-backlog"
 CATALOG = "/opt/skills/guides/model-configs/architectures.jsonl"
-NEW = ("dots3.tick_device_ms_p50", "dots3.dense_ms_per_tick",
-       "dots3.head_sample_ms_per_tick", "dots3.unscoped_ms_per_tick",
+NEW = ("served.tick_device_ms_p50", "served.dense_ms_per_tick",
+       "served.head_sample_ms_per_tick", "served.unscoped_ms_per_tick",
        "latent.scatter_ms_per_tick", "dsa.index_ms_per_tick",
        "dsa.select_ms_per_tick", "mla.attn_ms_per_tick",
        "swa.attn_ms_per_tick", "moe.tick_route_ms_per_tick",
        "moe.tick_experts_ms_per_tick", "moe.tick_shared_ms_per_tick",
        "dsa.index_roofline_pct", "mla.attn_roofline_pct",
        "swa.attn_roofline_pct", "moe.tick_experts_hbm_roofline_pct",
-       "dots3.tick_hbm_roofline_pct", "dots3.tick_mfu_pct",
-       "dsa.selected_share_pct", "pool.live_latent_pct.longdoc",
+       "served.tick_hbm_roofline_pct", "served.tick_mfu_pct",
+       "dsa.selected_share_pct", "pool.live_latent_pct",
        "pool.window_pages_freed_per_tick",
        "moe.tick_expert_load_max_over_mean", "moe.tick_experts_touched_pct",
-       "sched.prefill_tokens_per_tick.longdoc",
-       "sched.decode_rows_per_tick.longdoc",
-       "sched.serve_tokens_per_s_slice_p50.longdoc",
-       "sched.host_ms_per_tick.longdoc")
+       "served.prefill_tokens_per_tick",
+       "served.decode_rows_per_tick",
+       "served.tokens_per_s_slice_p50",
+       "served.host_ms_per_tick")
 WIDTHS = ("hidden_size", "intermediate_size", "moe_intermediate_size",
           "num_attention_heads", "q_lora_rank", "kv_lora_rank",
           "qk_nope_head_dim", "qk_rope_head_dim", "v_head_dim",
@@ -311,26 +311,28 @@ def test_the_readers_split_a_tick_by_the_programs_names(monkeypatch):
     run, pt = _run_with(doc, real_config(), dict(FACTS))
     monkeypatch.setattr(pt, "load", lambda: doc)
     read = lambda name: loader.load_module("layer_metrics", name).read(run)
-    want = {"dots3.tick_device_ms_p50": 60.0, "dots3.dense_ms_per_tick": 6.0,
-            "dots3.head_sample_ms_per_tick": 6.0,
+    want = {"served.tick_device_ms_p50": 60.0,
+            "served.dense_ms_per_tick": 6.0,
+            "served.head_sample_ms_per_tick": 6.0,
             "latent.scatter_ms_per_tick": 2.0, "dsa.index_ms_per_tick": 2.0,
             "dsa.select_ms_per_tick": 2.0, "mla.attn_ms_per_tick": 2.0,
             "swa.attn_ms_per_tick": 2.0, "moe.tick_route_ms_per_tick": 2.0,
             "moe.tick_experts_ms_per_tick": 10.0,
             "moe.tick_shared_ms_per_tick": 2.0,
             "dsa.selected_share_pct": 20.0,
-            "pool.live_latent_pct.longdoc": 50.0,
+            "pool.live_latent_pct": 50.0,
             "pool.window_pages_freed_per_tick": 2.1,
             "moe.tick_expert_load_max_over_mean": 2.5,
             "moe.tick_experts_touched_pct": 90.0,
-            "sched.prefill_tokens_per_tick.longdoc": 256.0,
-            "sched.decode_rows_per_tick.longdoc": 11.0,
-            "sched.serve_tokens_per_s_slice_p50.longdoc": 4000.0}
+            "served.prefill_tokens_per_tick": 256.0,
+            "served.decode_rows_per_tick": 11.0,
+            "served.tokens_per_s_slice_p50": 4000.0}
     for name, value in want.items():
         assert read(name) == pytest.approx(value), name
     # the parts and what no name covers add up to the tick
-    named = sum(read(n) for n in NEW[1:12] if n != "dots3.unscoped_ms_per_tick")
-    assert named + read("dots3.unscoped_ms_per_tick") == pytest.approx(60.0)
+    named = sum(read(n) for n in NEW[1:12]
+                if n != "served.unscoped_ms_per_tick")
+    assert named + read("served.unscoped_ms_per_tick") == pytest.approx(60.0)
     for name in NEW[12:18]:
         assert 0 < read(name) < 100, name
     assert sorted(NEW) == sorted(
@@ -356,7 +358,7 @@ def test_the_readers_find_nothing_in_a_program_without_the_model(
             name
     run["ctx"].trace_doc = None
     assert loader.load_module(
-        "layer_metrics", "dots3.tick_mfu_pct").read(run) is None
+        "layer_metrics", "served.tick_mfu_pct").read(run) is None
 
 
 def test_the_cells_lists_name_the_new_metrics(bench):
@@ -365,11 +367,9 @@ def test_the_cells_lists_name_the_new_metrics(bench):
     assert set(NEW) <= names
     assert {m["name"] for m in cell["end_to_end"]} == {
         "serve_tokens_per_s", "setup_s"}
-    assert len(bench["workloads"]) == 8
-    assert sum(w["chips"] == 4 for w in bench["workloads"]) == 1
     for m in bench["per_layer"]:
         if m["name"] in NEW:
-            assert m["workloads"] == [CELL] \
+            assert CELL in m["workloads"] \
                 and m["moves"] == "serve_tokens_per_s"
 
 
@@ -496,12 +496,12 @@ def test_a_traced_rehearsal_reports_what_the_cpu_can(copy):
     line, out = rehearse(copy, 1)
     assert line["correct"] is True, out[-2000:]
     got = set(line["metrics"])
-    assert {"dsa.selected_share_pct", "pool.live_latent_pct.longdoc",
+    assert {"dsa.selected_share_pct", "pool.live_latent_pct",
             "pool.window_pages_freed_per_tick",
             "moe.tick_expert_load_max_over_mean",
             "moe.tick_experts_touched_pct",
-            "sched.prefill_tokens_per_tick.longdoc",
-            "sched.decode_rows_per_tick.longdoc",
-            "sched.serve_tokens_per_s_slice_p50.longdoc"} <= got
+            "served.prefill_tokens_per_tick",
+            "served.decode_rows_per_tick",
+            "served.tokens_per_s_slice_p50"} <= got
     assert line["metrics"]["pool.window_pages_freed_per_tick"]["value"] > 0
     assert 0 < line["metrics"]["dsa.selected_share_pct"]["value"] < 100

@@ -23,21 +23,24 @@ TOY = os.path.join(HERE, "toy_dsv2")
 CELL = "serve-dsv2-docqa-backlog"
 CONFIG = "deepseek-v2-serve"
 CATALOG = "/opt/skills/guides/model-configs/architectures.jsonl"
-NEW = ("dsv2.tick_device_ms_p50", "dsv2.dense_ms_per_tick",
-       "dsv2.head_sample_ms_per_tick", "dsv2.unscoped_ms_per_tick",
-       "latent.scatter_ms_per_tick.dsv2", "mla.dense_chunk_ms_per_tick",
-       "mla.dense_decode_ms_per_tick", "moe.tick_route_ms_per_tick.dsv2",
-       "moe.tick_experts_ms_per_tick.dsv2",
-       "moe.tick_shared_ms_per_tick.dsv2",
+NEW = ("served.tick_device_ms_p50", "served.dense_ms_per_tick",
+       "served.head_sample_ms_per_tick", "served.unscoped_ms_per_tick",
+       "latent.scatter_ms_per_tick", "mla.dense_chunk_ms_per_tick",
+       "mla.dense_decode_ms_per_tick", "moe.tick_route_ms_per_tick",
+       "moe.tick_experts_ms_per_tick",
+       "moe.tick_shared_ms_per_tick",
        "mla.dense_attn_roofline_pct",
-       "moe.tick_experts_hbm_roofline_pct.dsv2",
-       "dsv2.tick_hbm_roofline_pct", "dsv2.tick_mfu_pct",
-       "moe.tick_group_hit_pct", "moe.tick_expert_load_max_over_mean.dsv2",
-       "moe.tick_experts_touched_pct.dsv2", "pool.live_latent_pct.dsv2",
-       "sched.prefill_tokens_per_tick.dsv2",
-       "sched.decode_rows_per_tick.dsv2",
-       "sched.serve_tokens_per_s_slice_p50.dsv2",
-       "sched.host_ms_per_tick.dsv2")
+       "moe.tick_experts_hbm_roofline_pct",
+       "served.tick_hbm_roofline_pct", "served.tick_mfu_pct",
+       "moe.tick_group_hit_pct", "moe.tick_expert_load_max_over_mean",
+       "moe.tick_experts_touched_pct", "pool.live_latent_pct",
+       "served.prefill_tokens_per_tick",
+       "served.decode_rows_per_tick",
+       "served.tokens_per_s_slice_p50",
+       "served.host_ms_per_tick")
+#: the entries that list this cell alone: its own mechanism's
+OWN = ("mla.dense_chunk_ms_per_tick", "mla.dense_decode_ms_per_tick",
+       "mla.dense_attn_roofline_pct", "moe.tick_group_hit_pct")
 WIDTHS = ("hidden_size", "intermediate_size", "moe_intermediate_size",
           "num_attention_heads", "q_lora_rank", "kv_lora_rank",
           "qk_nope_head_dim", "qk_rope_head_dim", "v_head_dim",
@@ -327,26 +330,28 @@ def test_the_readers_split_a_tick_by_the_programs_names(monkeypatch):
     run, pt = _run_with(doc, real_config(), dict(FACTS))
     monkeypatch.setattr(pt, "load", lambda: doc)
     read = lambda name: loader.load_module("layer_metrics", name).read(run)
-    want = {"dsv2.tick_device_ms_p50": 60.0, "dsv2.dense_ms_per_tick": 6.0,
-            "dsv2.head_sample_ms_per_tick": 6.0,
-            "latent.scatter_ms_per_tick.dsv2": 2.0,
+    want = {"served.tick_device_ms_p50": 60.0,
+            "served.dense_ms_per_tick": 6.0,
+            "served.head_sample_ms_per_tick": 6.0,
+            "latent.scatter_ms_per_tick": 2.0,
             "mla.dense_chunk_ms_per_tick": 2.0,
             "mla.dense_decode_ms_per_tick": 2.0,
-            "moe.tick_route_ms_per_tick.dsv2": 2.0,
-            "moe.tick_experts_ms_per_tick.dsv2": 10.0,
-            "moe.tick_shared_ms_per_tick.dsv2": 2.0,
+            "moe.tick_route_ms_per_tick": 2.0,
+            "moe.tick_experts_ms_per_tick": 10.0,
+            "moe.tick_shared_ms_per_tick": 2.0,
             "moe.tick_group_hit_pct": 37.5,
-            "moe.tick_expert_load_max_over_mean.dsv2": 1.8,
-            "moe.tick_experts_touched_pct.dsv2": 95.0,
-            "pool.live_latent_pct.dsv2": 50.0,
-            "sched.prefill_tokens_per_tick.dsv2": 512.0,
-            "sched.decode_rows_per_tick.dsv2": 12.0,
-            "sched.serve_tokens_per_s_slice_p50.dsv2": 12000.0}
+            "moe.tick_expert_load_max_over_mean": 1.8,
+            "moe.tick_experts_touched_pct": 95.0,
+            "pool.live_latent_pct": 50.0,
+            "served.prefill_tokens_per_tick": 512.0,
+            "served.decode_rows_per_tick": 12.0,
+            "served.tokens_per_s_slice_p50": 12000.0}
     for name, value in want.items():
         assert read(name) == pytest.approx(value), name
     # the parts and what no name covers add up to the tick
-    named = sum(read(n) for n in NEW[1:10] if n != "dsv2.unscoped_ms_per_tick")
-    assert named + read("dsv2.unscoped_ms_per_tick") == pytest.approx(60.0)
+    named = sum(read(n) for n in NEW[1:10]
+                if n != "served.unscoped_ms_per_tick")
+    assert named + read("served.unscoped_ms_per_tick") == pytest.approx(60.0)
     # the attention's roofline: the yardstick's least time over 4 ms
     peak = yardstick.chip_peak("TPU v5 lite")
     least = ymd.attention_least_ms(
@@ -365,9 +370,12 @@ def test_the_readers_split_a_tick_by_the_programs_names(monkeypatch):
 def test_the_readers_find_nothing_in_a_program_without_the_model(
         monkeypatch):
     """The dots3 tick names ``blk/attn/mla`` and neither of this model's two
-    attention scopes, and its family's facts hold no group hits: every
-    reader but the scheduler's returns ``None`` and raises nothing; so with
-    no trace at all."""
+    attention scopes, and its family's facts hold no group hits: the
+    readers of this cell's own mechanism return ``None`` there and raise
+    nothing (the folded ones read that tick as dots3's: test_pb_fold.py);
+    a GPT tick names no served mechanism at all and its facts hold no
+    experts: every reader but the scheduler's and the pool's returns
+    ``None``; so with no trace at all."""
     doc = _synthetic(["blk/qkv", "blk/attn/mla", "blk/ffn", "tick/head"])
     dots3 = loader.load_json(loader.root_file(
         "perfbench/configs/dots3-note-prev-serve.json"))
@@ -377,12 +385,22 @@ def test_the_readers_find_nothing_in_a_program_without_the_model(
         "tick_expert_load_max_over_mean": 2.0,
         "tick_experts_touched_share": 0.9})
     monkeypatch.setattr(pt, "load", lambda: doc)
-    for name in NEW[:18]:
+    for name in OWN:
+        assert loader.load_module("layer_metrics", name).read(run) is None, \
+            name
+    doc = _synthetic(["blk/qkv", "blk/attn", "blk/ffn", "tick/head"])
+    gpt = loader.load_json(loader.root_file(
+        "perfbench/configs/gpt3-1.3b-serve.json"))
+    run, pt = _run_with(doc, gpt, {
+        "decode_rows_per_tick": 9.0, "prefill_rows_per_tick": 0.25,
+        "prefill_chunk": 32, "live_kv_share": 0.5})
+    monkeypatch.setattr(pt, "load", lambda: doc)
+    for name in NEW[:17]:
         assert loader.load_module("layer_metrics", name).read(run) is None, \
             name
     run["ctx"].trace_doc = None
     assert loader.load_module(
-        "layer_metrics", "dsv2.tick_mfu_pct").read(run) is None
+        "layer_metrics", "served.tick_mfu_pct").read(run) is None
 
 
 def test_the_cells_lists_name_the_new_metrics(bench):
@@ -393,15 +411,14 @@ def test_the_cells_lists_name_the_new_metrics(bench):
         "serve_tokens_per_s", "setup_s"}
     assert cell["cell"]["chips"] == 1 \
         and cell["cell"]["traffic"] == "docqa-8k-backlog"
-    assert sum(w["chips"] == 4 for w in bench["workloads"]) == 1
     for m in bench["per_layer"]:
         if m["name"] in NEW:
-            assert m["workloads"] == [CELL] \
+            assert CELL in m["workloads"] \
                 and m["moves"] == "serve_tokens_per_s"
-    # no accepted metric's list of cells was touched but the end-to-end one
-    for m in bench["per_layer"]:
-        if m["name"] not in NEW:
+        else:       # no other metric's list of cells names this cell
             assert CELL not in m.get("workloads", ())
+        if m["name"] in OWN:
+            assert m["workloads"] == [CELL]
 
 
 # --- the check, controls included, through check() itself -------------------
@@ -526,12 +543,12 @@ def test_a_traced_rehearsal_reports_what_the_cpu_can(copy):
     line, out = rehearse(copy, 1)
     assert line["correct"] is True, out[-2000:]
     got = set(line["metrics"])
-    assert {"moe.tick_group_hit_pct", "pool.live_latent_pct.dsv2",
-            "moe.tick_expert_load_max_over_mean.dsv2",
-            "moe.tick_experts_touched_pct.dsv2",
-            "sched.prefill_tokens_per_tick.dsv2",
-            "sched.decode_rows_per_tick.dsv2",
-            "sched.serve_tokens_per_s_slice_p50.dsv2"} <= got
+    assert {"moe.tick_group_hit_pct", "pool.live_latent_pct",
+            "moe.tick_expert_load_max_over_mean",
+            "moe.tick_experts_touched_pct",
+            "served.prefill_tokens_per_tick",
+            "served.decode_rows_per_tick",
+            "served.tokens_per_s_slice_p50"} <= got
     assert 0 < line["metrics"]["moe.tick_group_hit_pct"]["value"] <= 100
     # two chunks a tick: more than one chunk's tokens in the mean tick
-    assert line["metrics"]["sched.prefill_tokens_per_tick.dsv2"]["value"] > 8
+    assert line["metrics"]["served.prefill_tokens_per_tick"]["value"] > 8

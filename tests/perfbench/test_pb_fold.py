@@ -1,0 +1,343 @@
+"""One entry a quantity among the three newer backlog cells (PR 48): a folded
+reader returns in each cell, to the last digit, what that cell's retired copy
+returned (``dots3.*``, ``dsv2.*``, ``olmoh.*``, the ``.longdoc``, ``.dsv2``
+and ``.olmoh`` suffixes). The copies' bodies are spelt out here against the
+cell's own trace helper, on each cell's synthetic tick and on every piece of
+a real trace recorded under ``recorded_served/``; a tick that names none of
+the three mechanisms (the recorded GPT tick) reads nothing. The two sparse
+training cells share ``moe.train_mfu_pct`` and ``moe.experts_roofline_pct``
+the same way (``solar2.train_mfu_pct`` and ``moe.held_experts_roofline_pct``
+were Solar-Open2's copies)."""
+import json
+import os
+import types
+
+import pytest
+
+from perfbench import loader, yardstick, yardstick_gdn, yardstick_kda, \
+    yardstick_mla, yardstick_mla_dense, yardstick_moe
+
+import test_pb_dots3 as dots3
+import test_pb_dsv2 as dsv2
+import test_pb_olmo_hybrid as olmoh
+import test_pb_olmoe as olmoe
+import test_pb_solar_open2 as solar
+
+HERE = os.path.dirname(os.path.abspath(__file__))
+RECORDED = os.path.join(HERE, "recorded_served")
+
+
+def _helper(name):
+    return loader.load_module("layer_metrics", name)
+
+
+def _part(part):
+    return lambda tr, run: tr.read_part(run, part)
+
+
+def _fact(key, scale=1.0):
+    def read(tr, run):
+        value = run["facts"].get(key)
+        return None if value is None else scale * value
+    return read
+
+
+def _device_ms_p50(tr, run):
+    if tr.parts_ms(run) is None:
+        return None
+    return _helper("_tick").device_ms_p50(run)
+
+
+def _prefill_tokens(tr, run):
+    f = run["facts"]
+    return f["prefill_rows_per_tick"] * f["prefill_chunk"]
+
+
+def _host_ms(tr, run):
+    pt = _helper("_program_trace")
+    return pt.host_ms_per_tick(pt.doc_of(run))
+
+
+def _hbm_pct(tick_bytes):
+    """A retired ``*.tick_hbm_roofline_pct``: the family's bytes a tick at
+    the chip's peak over the tick's time."""
+    def read(tr, run):
+        s = tr.tick_shape(run)
+        moved = tick_bytes(run["ctx"].config, s)
+        return 100.0 * moved / s["peak"].hbm_bytes_per_s * 1e3 / s["ms"]
+    return read
+
+
+def _mfu_pct(tick_flops):
+    """A retired ``*.tick_mfu_pct``: the family's operations a tick over
+    the tick's time and the chip's peak."""
+    def read(tr, run):
+        s = tr.tick_shape(run)
+        ops = tick_flops(run["ctx"].config, s)
+        return 100.0 * ops / (s["ms"] * 1e-3) / s["peak"].bf16_flops
+    return read
+
+
+def _experts_hbm(experts_bytes):
+    def read(tr, run):
+        s, ms = tr.tick_shape(run), tr.read_part(run, "experts")
+        least = experts_bytes(run["ctx"].config, s["touched"]) \
+            / s["peak"].hbm_bytes_per_s * 1e3
+        return 100.0 * least / ms
+    return read
+
+
+#: what every cell's copies did alike: folded name -> the copy's body
+SHARED = {
+    "served.tick_device_ms_p50": _device_ms_p50,
+    "served.dense_ms_per_tick": _part("dense"),
+    "served.head_sample_ms_per_tick": _part("head_sample"),
+    "served.unscoped_ms_per_tick": _part("unscoped"),
+    "served.prefill_tokens_per_tick": _prefill_tokens,
+    "served.decode_rows_per_tick": _fact("decode_rows_per_tick"),
+    "served.tokens_per_s_slice_p50": _fact("serve_tokens_per_s_slice_p50"),
+    "served.host_ms_per_tick": _host_ms,
+}
+#: the two latent-attention cells' shared names
+LATENT = {
+    "latent.scatter_ms_per_tick": _part("scatter"),
+    "moe.tick_route_ms_per_tick": _part("route"),
+    "moe.tick_experts_ms_per_tick": _part("experts"),
+    "moe.tick_shared_ms_per_tick": _part("shared"),
+    "moe.tick_expert_load_max_over_mean":
+        _fact("tick_expert_load_max_over_mean"),
+    "moe.tick_experts_touched_pct":
+        _fact("tick_experts_touched_share", 100.0),
+    "pool.live_latent_pct": _fact("live_kv_share", 100.0),
+}
+CELLS = {
+    "serve-dots3-longdoc-backlog": dict(
+        helper="_dots3_trace", test=dots3,
+        kernels=("%moe_gmm.3 = custom-call",),
+        copies={**SHARED, **LATENT,
+                "served.tick_hbm_roofline_pct": _hbm_pct(
+                    lambda c, s: yardstick_mla.tick_bytes(
+                        c, s["decode"], s["chunks"], s["chunk"],
+                        s["context"], s["sampled"], s["touched"])),
+                "served.tick_mfu_pct": _mfu_pct(
+                    lambda c, s: yardstick_mla.tick_flops(
+                        c, s["decode"], s["chunks"], s["chunk"],
+                        s["context"], s["sampled"], s["expert_rows"])),
+                "moe.tick_experts_hbm_roofline_pct": _experts_hbm(
+                    yardstick_mla.experts_bytes)}),
+    "serve-dsv2-docqa-backlog": dict(
+        helper="_dsv2_trace", test=dsv2,
+        kernels=("%moe_gmm.3 = custom-call",),
+        copies={**SHARED, **LATENT,
+                "served.tick_hbm_roofline_pct": _hbm_pct(
+                    lambda c, s: yardstick_mla_dense.tick_bytes(
+                        c, s["tokens"], (s["decode"], s["chunk"]),
+                        s["sampled"], s["touched"])),
+                "served.tick_mfu_pct": _mfu_pct(
+                    lambda c, s: yardstick_mla_dense.tick_flops(
+                        c, s["tokens"], (s["decode"], s["chunk"]),
+                        s["sampled"], s["expert_rows"])),
+                "moe.tick_experts_hbm_roofline_pct": _experts_hbm(
+                    yardstick_mla_dense.experts_bytes)}),
+    "serve-olmo-hybrid-gen-backlog": dict(
+        helper="_olmoh_trace", test=olmoh, kernels=None,
+        copies={**SHARED,
+                "served.tick_hbm_roofline_pct": _hbm_pct(
+                    yardstick_gdn.tick_bytes),
+                "served.tick_mfu_pct": _mfu_pct(yardstick_gdn.tick_flops)}),
+}
+CASES = [(cell, name) for cell, case in CELLS.items()
+         for name in case["copies"]]
+TRACE_READERS = sorted(
+    name for name in {n for case in CELLS.values() for n in case["copies"]}
+    if name not in ("served.prefill_tokens_per_tick",
+                    "served.decode_rows_per_tick",
+                    "served.tokens_per_s_slice_p50",
+                    "moe.tick_expert_load_max_over_mean",
+                    "moe.tick_experts_touched_pct", "pool.live_latent_pct"))
+
+
+def _host(doc):
+    """The engine's spans of two ticks, for the host's reader."""
+    events = []
+    for tick in range(2):
+        t0 = tick * 70_000_000
+        for i, name in enumerate(("admit", "build", "dispatch")):
+            events.append({"name": f"pt:step/{name}", "start_ns": t0 + i * 100,
+                           "dur_ns": 400_000, "stats": {"tick": tick}})
+    doc["planes"].append({"name": "/host:CPU", "lines": [
+        {"name": "python3", "events": events}]})
+    return doc
+
+
+def _synthetic_run(cell, monkeypatch):
+    case = CELLS[cell]
+    t = case["test"]
+    doc = t._synthetic(t.SCOPES) if case["kernels"] is None \
+        else t._synthetic(t.SCOPES, kernels=case["kernels"])
+    run, pt = t._run_with(_host(doc), t.real_config(), dict(t.FACTS))
+    monkeypatch.setattr(pt, "load", lambda: doc)
+    return run
+
+
+@pytest.mark.parametrize("cell,name", CASES)
+def test_a_folded_reader_returns_what_the_cells_copy_returned(
+        cell, name, monkeypatch):
+    run = _synthetic_run(cell, monkeypatch)
+    want = CELLS[cell]["copies"][name](_helper(CELLS[cell]["helper"]), run)
+    assert want is not None and want > 0, name
+    assert loader.load_module("layer_metrics", name).read(run) == want
+
+
+@pytest.mark.parametrize("cell", sorted(CELLS))
+def test_the_cells_own_helper_and_no_other_reads_its_tick(cell, monkeypatch):
+    """``_served`` names no family: it finds the helpers by listing its
+    directory, and the run's tick picks the one that reads it."""
+    run = _synthetic_run(cell, monkeypatch)
+    served = _helper("_served")
+    assert served.trace_of(run) is _helper(CELLS[cell]["helper"])
+    assert served.helpers() == sorted(c["helper"] for c in CELLS.values())
+    assert [n for n in served.helpers()
+            if _helper(n).parts_ms(run) is not None] \
+        == [CELLS[cell]["helper"]]
+    with open(served.__file__, encoding="utf-8") as f:
+        source = f.read()
+    for name in ("dots3", "dsv2", "olmoh", "yardstick"):
+        assert name not in source.split('"""', 2)[2], name
+
+
+@pytest.mark.parametrize("cell", sorted(CELLS))
+def test_benchmark_json_lists_the_cell_under_every_folded_name(cell):
+    bench = loader.load_json(loader.root_file("BENCHMARK.json"))
+    lists = {m["name"]: m.get("workloads", ()) for m in bench["per_layer"]}
+    for name in CELLS[cell]["copies"]:
+        assert cell in lists[name], name
+    for name in lists:                    # and under no copy's name
+        assert not name.startswith(("dots3.", "dsv2.", "olmoh.")) \
+            and not name.endswith((".longdoc", ".dsv2", ".olmoh")), name
+
+
+@pytest.mark.parametrize("name", TRACE_READERS)
+def test_a_folded_reader_finds_nothing_in_a_tick_that_names_no_mechanism(
+        name, monkeypatch):
+    """The recorded tick is a served GPT's (``blk/attn``, ``blk/ffn``):
+    none of the three helpers reads it, so no folded reader does."""
+    pt = _helper("_program_trace")
+    doc = pt.load_recorded(os.path.join(HERE,
+                                        "recorded_scoped_tick.json.gz"))
+    ctx = types.SimpleNamespace(
+        trace_doc=doc, config=loader.load_json(loader.root_file(
+            "perfbench/configs/gpt3-1.3b-serve.json")),
+        devices=[types.SimpleNamespace(device_kind="TPU v5 lite")])
+    run = {"ctx": ctx, "notes": [], "facts": {
+        "decode_rows_per_tick": 9.0, "prefill_rows_per_tick": 0.25,
+        "prefill_chunk": 32, "live_kv_share": 0.5}}
+    monkeypatch.setattr(pt, "load", lambda: doc)
+    assert _helper("_served").trace_of(run) is None
+    if name == "served.host_ms_per_tick":     # the host's spans are there
+        assert loader.load_module("layer_metrics", name).read(run) > 0
+    else:
+        assert loader.load_module("layer_metrics", name).read(run) is None
+
+
+# --- the two sparse training cells' shared names --------------------------------
+def _train_mfu(flops_per_token):
+    """A retired ``*.train_mfu_pct``."""
+    def read(run):
+        f, ctx = run["facts"], run["ctx"]
+        peak = yardstick.chip_peak(ctx.devices[0].device_kind).bf16_flops
+        flops = flops_per_token(ctx.config, f["seq"])
+        return 100.0 * f["tokens_per_s"] * flops / (len(ctx.devices) * peak)
+    return read
+
+
+def _experts_ms(run):
+    return _helper("_moe_trace").read_part(run, "experts")
+
+
+def _olmoe_experts_roofline(run):
+    f, ctx = run["facts"], run["ctx"]
+    peak = yardstick.chip_peak(ctx.devices[0].device_kind)
+    return yardstick_moe.experts_roofline_pct(
+        _experts_ms(run), f["micro"] * f["seq"], f["n_micro"], ctx.config,
+        peak)
+
+
+def _solar_experts_roofline(run):
+    f, ctx = run["facts"], run["ctx"]
+    peak = yardstick.chip_peak(ctx.devices[0].device_kind)
+    return yardstick_kda.held_experts_roofline_pct(
+        _experts_ms(run), f["moe_rows_held"], f["n_micro"], ctx.config, peak)
+
+
+#: cell -> (its tests' module, the step's facts, folded name -> the body of
+#: the reader the cell had: ``moe.*`` were OLMoE's, ``solar2.train_mfu_pct``
+#: and ``moe.held_experts_roofline_pct`` Solar-Open2's)
+TRAIN_CELLS = {
+    "train-olmoe-1chip-4k": (
+        olmoe, {"traced_steps": 1, "micro": 1, "seq": 4096, "n_micro": 8,
+                "tokens_per_s": 30000.0},
+        {"moe.train_mfu_pct": _train_mfu(
+            yardstick_moe.olmoe_train_flops_per_token),
+         "moe.experts_roofline_pct": _olmoe_experts_roofline}),
+    "train-solar-open2-1chip": (
+        solar, {"traced_steps": 1, "micro": 1, "seq": 8192, "n_micro": 2,
+                "tokens_per_s": 12000.0, "moe_rows_held": 13104.0},
+        {"moe.train_mfu_pct": _train_mfu(yardstick_kda.train_flops_per_token),
+         "moe.experts_roofline_pct": _solar_experts_roofline}),
+}
+
+
+@pytest.mark.parametrize("cell,name", [
+    (cell, name) for cell, case in TRAIN_CELLS.items() for name in case[2]])
+def test_a_sparse_training_cells_shared_reader_returns_its_copys_number(
+        cell, name, monkeypatch):
+    t, facts, copies = TRAIN_CELLS[cell]
+    pt = _helper("_program_trace")
+    monkeypatch.setitem(pt._DOC, "doc", t.synthetic_doc())
+    run = {"ctx": t.FakeCtx(t.real_config()), "facts": dict(facts)}
+    want = copies[name](run)
+    assert want is not None and want > 0
+    assert loader.load_module("layer_metrics", name).read(run) == want
+    bench = loader.load_json(loader.root_file("BENCHMARK.json"))
+    lists = {m["name"]: m.get("workloads", ()) for m in bench["per_layer"]}
+    assert cell in lists[name]
+    assert "solar2.train_mfu_pct" not in lists
+    assert "moe.held_experts_roofline_pct" not in lists
+
+
+# --- pieces of real traces, recorded on the chip ------------------------------
+def _recorded():
+    if not os.path.isdir(RECORDED):
+        return []
+    return sorted(f[:-len(".json.gz")] for f in os.listdir(RECORDED)
+                  if f.endswith(".json.gz"))
+
+
+@pytest.mark.parametrize("cell", _recorded())
+def test_a_recorded_piece_of_the_cells_trace_reads_the_same_both_ways(
+        cell, monkeypatch):
+    """``recorded_served/<cell>.json.gz``: some ticks of the cell's traced
+    run on the chip in ``_program_trace``'s plain form, with the run's
+    facts beside them (``<cell>.facts.json``): every folded reader against
+    the copy's body, and every value a positive number."""
+    pt = _helper("_program_trace")
+    doc = pt.load_recorded(os.path.join(RECORDED, cell + ".json.gz"))
+    with open(os.path.join(RECORDED, cell + ".facts.json"),
+              encoding="utf-8") as f:
+        facts = json.load(f)
+    config = loader.load_json(loader.root_file(
+        loader.load_cell(cell)["config_entry"]["file"]))
+    ctx = types.SimpleNamespace(
+        trace_doc=doc, config=config,
+        devices=[types.SimpleNamespace(device_kind="TPU v5 lite")])
+    run = {"ctx": ctx, "facts": facts, "notes": []}
+    monkeypatch.setattr(pt, "load", lambda: doc)
+    tr = _helper(CELLS[cell]["helper"])
+    for name, copy in CELLS[cell]["copies"].items():
+        want = copy(tr, run)
+        assert want is not None and want > 0, name
+        assert loader.load_module("layer_metrics", name).read(run) == want, \
+            name
+    for name in ("served.tick_hbm_roofline_pct", "served.tick_mfu_pct"):
+        assert loader.load_module("layer_metrics", name).read(run) < 100

@@ -193,11 +193,11 @@ def test_the_loop_readers_split_a_tick_by_the_programs_names(monkeypatch):
     read = lambda name: loader.load_module("layer_metrics", name).read(run)
     assert read("loop.dense_ms_per_tick") == pytest.approx(15.0)
     # what the cell reads of prefill and the host, none of it judged
-    assert read("loop.ttft_p85_ms") == yardstick.percentile(
+    assert read("sched.ttft_p85_ms") == yardstick.percentile(
         facts["ttft_ms"], 85)
-    assert read("sched.queue_wait_p50_ms.loop") == 20.0
-    assert read("load.generator_late_ms_max.loop") == 1.5
-    assert read("pool.live_kv_pct.loop") == 50.0
+    assert read("sched.queue_wait_p50_ms") == 20.0
+    assert read("load.generator_late_ms_max") == 1.5
+    assert read("pool.live_kv_pct.chat") == 50.0
     bw = read("loop.tick_hbm_roofline_pct")
     mfu = read("loop.tick_mfu_pct")
     assert 50 < bw < 100 and 0 < mfu < 100
@@ -214,11 +214,11 @@ def test_the_loop_readers_find_nothing_in_a_program_without_the_loop(
         "decode_rows_per_tick": 9.0, "prefill_rows_per_tick": 0.25,
         "prefill_chunk": 32, "live_kv_share": 0.5})
     monkeypatch.setattr(pt, "load", lambda: doc)
-    for m in ("dense_ms_per_tick", "attn_ms_per_tick", "exit_ms_per_tick",
-              "unscoped_ms_per_tick", "tick_hbm_roofline_pct",
-              "tick_mfu_pct", "ttft_p85_ms"):
-        assert loader.load_module("layer_metrics", "loop." + m).read(run) \
-            is None, m
+    for m in ("loop.dense_ms_per_tick", "loop.attn_ms_per_tick",
+              "loop.exit_ms_per_tick", "loop.unscoped_ms_per_tick",
+              "loop.tick_hbm_roofline_pct", "loop.tick_mfu_pct",
+              "sched.ttft_p85_ms"):
+        assert loader.load_module("layer_metrics", m).read(run) is None, m
     # and with no trace at all
     run["ctx"].trace_doc = None
     assert loader.load_module(
@@ -337,11 +337,10 @@ def test_the_traced_rehearsal_reads_what_a_cpu_run_can(copy):
     host's numbers are there."""
     line, out = rehearse(copy, 1)
     assert line["correct"] is True, out[-2000:]
-    host = {"loop.ttft_p85_ms", "sched.queue_wait_p50_ms.loop",
-            "load.generator_late_ms_max.loop", "pool.live_kv_pct.loop"}
+    host = {"sched.ttft_p85_ms", "sched.queue_wait_p50_ms",
+            "load.generator_late_ms_max", "pool.live_kv_pct.chat"}
     assert set(line["metrics"]) >= {"proc.compiles_in_window"} | host
-    assert {m for m in line["metrics"] if m.startswith("loop.")} == \
-        {"loop.ttft_p85_ms"}
+    assert not {m for m in line["metrics"] if m.startswith("loop.")}
     assert line["metrics"]["proc.compiles_in_window"]["value"] == 0
-    assert line["metrics"]["loop.ttft_p85_ms"]["value"] > 0
-    assert 0 < line["metrics"]["pool.live_kv_pct.loop"]["value"] <= 100
+    assert line["metrics"]["sched.ttft_p85_ms"]["value"] > 0
+    assert 0 < line["metrics"]["pool.live_kv_pct.chat"]["value"] <= 100

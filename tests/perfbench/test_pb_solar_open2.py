@@ -14,7 +14,7 @@ import ml_dtypes
 import numpy as np
 import pytest
 
-from perfbench import loader, yardstick, yardstick_kda
+from perfbench import loader, yardstick, yardstick_kda, yardstick_moe
 from test_pb_contract import config_file_is_sound
 
 HERE = os.path.dirname(os.path.abspath(__file__))
@@ -22,8 +22,8 @@ TOY = os.path.join(HERE, "toy_solar")
 CELL = "train-solar-open2-1chip"
 NEW = ("kda.scan_ms_per_step", "kda.scan_roofline_pct",
        "kda.proj_ms_per_step", "kda.out_ms_per_step",
-       "moe.shared_ms_per_step", "solar2.train_mfu_pct",
-       "moe.held_experts_roofline_pct")
+       "moe.shared_ms_per_step", "moe.train_mfu_pct",
+       "moe.experts_roofline_pct")
 
 
 def real_config():
@@ -85,12 +85,12 @@ def test_the_cell_rehearses_end_to_end_on_the_cpu(copy):
 def test_the_traced_rehearsal_reads_what_a_cpu_run_can(copy):
     """No device in a CPU trace: the ``*_ms_per_step`` readers and the
     roofline return nothing and are left out; the program's counter is
-    there (``solar2.train_mfu_pct`` raises on a CPU, which has no
+    there (``moe.train_mfu_pct`` raises on a CPU, which has no
     published peak, so the toy cell is run untraced for it: see the
     readers' own tests below)."""
     bench = json.loads((copy / "BENCHMARK.json").read_text())
     for m in bench["per_layer"]:
-        if m["name"] == "solar2.train_mfu_pct":
+        if m["name"] == "moe.train_mfu_pct":
             m["workloads"].remove("toy-solar-cell")
     (copy / "BENCHMARK.json").write_text(json.dumps(bench))
     line, out = rehearse(copy, 1)
@@ -308,11 +308,11 @@ def test_the_new_readers_on_a_synthetic_trace(monkeypatch):
     assert read(run, "kda.out_ms_per_step") == pytest.approx(0.005)
     assert read(run, "moe.shared_ms_per_step") == pytest.approx(0.008)
     peak = yardstick.chip_peak("TPU v5 lite")
-    assert read(run, "moe.held_experts_roofline_pct") == pytest.approx(
+    assert read(run, "moe.experts_roofline_pct") == pytest.approx(
         yardstick_kda.held_experts_roofline_pct(0.002, 13104.0, 2, c, peak))
     assert read(run, "kda.scan_roofline_pct") == pytest.approx(
         yardstick_kda.scan_roofline_pct(0.050, 8192, 2, c, peak))
-    assert read(run, "solar2.train_mfu_pct") == pytest.approx(
+    assert read(run, "moe.train_mfu_pct") == pytest.approx(
         100.0 * 12000.0 * yardstick_kda.train_flops_per_token(c, 8192)
         / peak.bf16_flops)
     assert any("linear-attention layer's parts" in n for n in run["notes"])
@@ -335,8 +335,20 @@ def test_the_new_readers_return_nothing_where_nothing_is_named(monkeypatch):
     run = {"ctx": FakeCtx(olmoe),
            "facts": {"traced_steps": 1, "micro": 1, "seq": 4096,
                      "n_micro": 8, "tokens_per_s": 30000.0}}
-    for name in NEW:
+    for name in NEW[:5]:
         assert read(run, name) is None, name
+    # the two names the sparse training cells share (PR 48) read OLMoE's
+    # step by OLMoE's yardstick: no ``moe_rows_held``, no
+    # ``linear_attn_config``
+    peak = yardstick.chip_peak("TPU v5 lite")
+    assert read(run, "moe.experts_roofline_pct") == pytest.approx(
+        yardstick_moe.experts_roofline_pct(0.002, 4096, 8, olmoe, peak))
+    assert read(run, "moe.train_mfu_pct") == pytest.approx(
+        100.0 * 30000.0
+        * yardstick_moe.olmoe_train_flops_per_token(olmoe, 4096)
+        / peak.bf16_flops)
+    held_none = dict(run, facts=dict(run["facts"], moe_rows_held=0.0))
+    assert read(held_none, "moe.experts_roofline_pct") is None
     untraced = {"ctx": FakeCtx(real_config()), "facts": {"traced_steps": 1}}
     untraced["ctx"].trace_doc = None
     for name in NEW[:5] + NEW[6:]:
