@@ -1251,12 +1251,16 @@ TOL_GDN_WORST = 1.0
 GDN_REQUESTS = ((150, 24), (300, 20), (70, 40))
 
 
-def check_gdn_ops(heads: int, dk: int, dv: int, rows: int, w: int) -> dict:
+def check_gdn_ops(heads: int, dk: int, dv: int, rows: int, w: int,
+                  channel: bool = False) -> dict:
     """``gdn_step_rows`` over ``rows`` decode rows and ``gdn_chunk_rows``
     over one row of ``w`` tokens, from random states, by the path observed
     here (on the chip: the kernels) against ``xla_step`` and ``xla_chunk``;
     before them ``gdn_prep_rows`` over the same two row groups (4 taps, a
-    history of ``rows`` slots) against ``xla_prep``."""
+    history of ``rows`` slots) against ``xla_prep``. ``channel``: the decay
+    a key channel and bounded in (-5, 0), ``beta`` in (0, 1) (Ling-3.0's
+    gate; the kernels ``kda_step`` and ``kda_chunk``), where Olmo-Hybrid's
+    is a head's and ``beta`` in (0, 2)."""
     import jax
     import jax.numpy as jnp
 
@@ -1269,13 +1273,17 @@ def check_gdn_ops(heads: int, dk: int, dv: int, rows: int, w: int) -> dict:
         return (bf(unit(jax.random.normal(ks[0], (n, t, heads, dk)))),
                 bf(unit(jax.random.normal(ks[1], (n, t, heads, dk)))),
                 bf(jax.random.normal(ks[2], (n, t, heads, dv))),
-                -jax.nn.softplus(jax.random.normal(ks[3], (n, t, heads))),
-                2 * jax.nn.sigmoid(jax.random.normal(ks[4], (n, t, heads))),
+                -5 * jax.nn.sigmoid(jax.random.normal(
+                    ks[3], (n, t, heads, dk)) - 2.0) if channel
+                else -jax.nn.softplus(jax.random.normal(ks[3],
+                                                        (n, t, heads))),
+                (1 if channel else 2) * jax.nn.sigmoid(
+                    jax.random.normal(ks[4], (n, t, heads))),
                 jax.random.normal(ks[5], (n, heads, dk, dv)))
 
     rel = lambda a, b: float(jnp.linalg.norm(                # noqa: E731
         a.astype(jnp.float32) - b) / jnp.linalg.norm(b))
-    path = gdn.gdn_path(heads, dk, dv)
+    path = (gdn.kda_path if channel else gdn.gdn_path)(heads, dk, dv)
     out = {"path": path}
     # the step: rows 1.. live, one dead row on the null slot
     q, k, v, g, beta, s0 = operands(1, rows, 1)
@@ -1337,6 +1345,77 @@ def check_gdn_ops(heads: int, dk: int, dv: int, rows: int, w: int) -> dict:
     return out
 
 
+def serve_against_reference(tag: str, net, sizes, requests, forward,
+                            counters: dict, want_path: str, what: str) -> dict:
+    """What the two phases of a model with a state a slot share: ``net`` (a
+    ``LazyGuard`` model, drawn on the device in bf16) through an engine of
+    ``sizes`` (slots, page, pages a slot, chunk), prompts of several chunks;
+    what it emitted is the float32 reference's (``forward(layers, other,
+    tokens) -> {"state"}`` and the reference module ``forward.ref`` for the
+    shortfall), and its ticks counted every counter of ``counters`` (``{kind:
+    name with %s for the path}``) by ``want_path`` alone."""
+    import jax
+
+    from paddle_tpu.profiler import registry
+    from paddle_tpu.serving import ServingConfig, ServingEngine
+
+    cfg, reg = net.config, registry()
+    calls0 = {(kind, p): reg.counter(c % p).value
+              for kind, c in counters.items() for p in ("pallas", "xla")}
+    net.eval()
+    net.bfloat16()
+    num_slots, page_size, pages_per_slot, chunk = sizes
+    eng = ServingEngine(net, ServingConfig(
+        num_slots=num_slots, page_size=page_size,
+        pages_per_slot=pages_per_slot, prefill_chunk=chunk,
+        prefix_cache=False))
+    layers, other = eng.served_weights()
+    on_default_platform((layers, other, eng.pool.pools),
+                        f"{tag} serving state")
+    weights = reg.gauge("serving/weights_bytes").value
+    check(weights == 2 * cfg.num_params(),
+          f"serving/weights_bytes {weights:.0f} is not one bf16 copy of "
+          f"{cfg.num_params()} parameters")
+    rng = np.random.RandomState(13)
+    prompts = [rng.randint(0, cfg.vocab_size, n).astype(np.int32)
+               for n, _ in requests]
+    rids = [eng.submit(p, new) for p, (_, new) in zip(prompts, requests)]
+    results = eng.run()
+    jax.block_until_ready(eng.pool.pools)
+    check(eng.pool.check_consistency() == [], "the pools' books disagree")
+    shorts = []
+    for rid, prompt in zip(rids, prompts):
+        out = results[rid]
+        seq = np.concatenate([prompt, out[:-1]])
+        got = forward(layers, other, seq)
+        at = np.arange(len(prompt) - 1, len(seq))
+        shorts.append(forward.ref.shortfall(
+            np.asarray(got["state"])[at], other, out)[0])
+    shorts = np.concatenate(shorts)
+    worst, median = float(shorts.max()), float(np.median(shorts))
+    check(median <= TOL_GDN_SHORTFALL and worst <= TOL_GDN_WORST,
+          f"an emitted token's logit lies {median:.4f} below the float32 "
+          f"reference's largest at the median (allowed {TOL_GDN_SHORTFALL}) "
+          f"and {worst:.4f} at the worst (allowed {TOL_GDN_WORST})")
+    tick = {kind: sorted(p for p in ("pallas", "xla") if reg.counter(
+        c % p).value > calls0[(kind, p)]) for kind, c in counters.items()}
+    check(tick == dict.fromkeys(counters, [want_path]),
+          f"the engine's ticks counted {tick}, not {want_path} alone ("
+          + ", ".join(c % "" for c in counters.values()) + ")")
+    say(tag, f"{len(rids)} requests through {what} "
+        f"({cfg.num_hidden_layers} layers, chunks of {chunk}): shortfall "
+        f"{median:.4f} at the median (allowed {TOL_GDN_SHORTFALL}), "
+        f"{worst:.4f} at the worst (allowed {TOL_GDN_WORST}); the ticks' "
+        f"paths {tick}; weights {_gb(weights)}")
+    return {"worst": worst, "median": median, "weights_bytes": weights,
+            "tick_paths": tick}
+
+
+#: the served delta rule's three counters, the path left open
+GDN_COUNTERS = {kind: "gdn/%s_calls{path=%%s}" % kind
+                for kind in ("step", "chunk", "prep")}
+
+
 def phase_olmoh(cfg, num_slots: int, page_size: int, pages_per_slot: int,
                 chunk: int, ops_shape, want_path: str,
                 requests=GDN_REQUESTS) -> dict:
@@ -1352,13 +1431,9 @@ def phase_olmoh(cfg, num_slots: int, page_size: int, pages_per_slot: int,
     rule by ``want_path``."""
     import dataclasses as dc
 
-    import jax
-
     import paddle_tpu as paddle
     from paddle_tpu.models import olmo_hybrid_reference as ref
     from paddle_tpu.models.olmo_hybrid import OlmoHybrid
-    from paddle_tpu.profiler import registry
-    from paddle_tpu.serving import ServingConfig, ServingEngine
 
     errs = check_gdn_ops(*ops_shape)
     path, prep = errs.pop("path"), errs.pop("prep_path")
@@ -1370,63 +1445,71 @@ def phase_olmoh(cfg, num_slots: int, page_size: int, pages_per_slot: int,
     check(path == prep == want_path, f"the delta rule of {ops_shape[:3]} "
           f"went by {path} and what lies before it by {prep}, not "
           f"{want_path}")
-    reg = registry()
-    counters = {kind: "gdn/%s_calls{path=%%s}" % kind
-                for kind in ("step", "chunk", "prep")}
-    calls0 = {(kind, p): reg.counter(c % p).value
-              for kind, c in counters.items() for p in ("pallas", "xla")}
     paddle.seed(0)
     with paddle.LazyGuard():
         net = OlmoHybrid(cfg)
-    net.eval()
-    net.bfloat16()
-    eng = ServingEngine(net, ServingConfig(
-        num_slots=num_slots, page_size=page_size,
-        pages_per_slot=pages_per_slot, prefill_chunk=chunk,
-        prefix_cache=False))
-    layers, other = eng.served_weights()
-    on_default_platform((layers, other, eng.pool.pools),
-                        "olmoh serving state")
-    weights = reg.gauge("serving/weights_bytes").value
-    check(weights == 2 * cfg.num_params(),
-          f"serving/weights_bytes {weights:.0f} is not one bf16 copy of "
-          f"{cfg.num_params()} parameters")
-    rng = np.random.RandomState(13)
-    prompts = [rng.randint(0, cfg.vocab_size, n).astype(np.int32)
-               for n, _ in requests]
-    rids = [eng.submit(p, new) for p, (_, new) in zip(prompts, requests)]
-    results = eng.run()
-    jax.block_until_ready(eng.pool.pools)
-    check(eng.pool.check_consistency() == [], "the pools' books disagree")
-    config = dc.asdict(cfg)
-    shorts = []
-    for rid, prompt in zip(rids, prompts):
-        out = results[rid]
-        seq = np.concatenate([prompt, out[:-1]])
-        got = ref.forward(((kind, layers[f"layer{i}"])
-                           for i, kind in enumerate(cfg.layer_types)),
-                          other, seq, config)
-        at = np.arange(len(prompt) - 1, len(seq))
-        shorts.append(ref.shortfall(np.asarray(got["state"])[at], other,
-                                    out)[0])
-    shorts = np.concatenate(shorts)
-    worst, median = float(shorts.max()), float(np.median(shorts))
-    check(median <= TOL_GDN_SHORTFALL and worst <= TOL_GDN_WORST,
-          f"an emitted token's logit lies {median:.4f} below the float32 "
-          f"reference's largest at the median (allowed {TOL_GDN_SHORTFALL}) "
-          f"and {worst:.4f} at the worst (allowed {TOL_GDN_WORST})")
-    tick = {kind: sorted(p for p in ("pallas", "xla") if reg.counter(
-        c % p).value > calls0[(kind, p)]) for kind, c in counters.items()}
-    check(tick == dict.fromkeys(counters, [want_path]),
-          f"the engine's ticks counted their delta rule by {tick}, not "
-          f"{want_path} (gdn/step_calls, gdn/chunk_calls, gdn/prep_calls)")
-    say("olmoh", f"{len(rids)} requests through K/V pages and a state a slot "
-        f"({cfg.num_hidden_layers} layers, chunks of {chunk}): shortfall "
-        f"{median:.4f} at the median (allowed {TOL_GDN_SHORTFALL}), "
-        f"{worst:.4f} at the worst (allowed {TOL_GDN_WORST}); the ticks' "
-        f"delta rule by {tick}; weights {_gb(weights)}")
-    return {"worst": worst, "median": median, "weights_bytes": weights,
-            "tick_paths": tick, **errs}
+
+    def forward(layers, other, seq):
+        return ref.forward(((kind, layers[f"layer{i}"])
+                            for i, kind in enumerate(cfg.layer_types)),
+                           other, seq, dc.asdict(cfg))
+
+    forward.ref = ref
+    return {**serve_against_reference(
+        "olmoh", net, (num_slots, page_size, pages_per_slot, chunk),
+        requests, forward, GDN_COUNTERS, want_path,
+        "K/V pages and a state a slot"), **errs}
+
+
+LING_REQUESTS = ((300, 24), (520, 20), (140, 40))
+
+
+def phase_ling(cfg, num_slots: int, page_size: int, pages_per_slot: int,
+               chunk: int, ops_shape, want_path: str,
+               requests=LING_REQUESTS) -> dict:
+    """Ling-3.0's pass (models/ling3.py): the served delta rule **with a
+    decay a key channel** (the kernels ``kda_step`` and ``kda_chunk``, and
+    the pass before them at these widths) against the ``jax.numpy``
+    spellings at ``ops_shape`` (heads, dk, dv, decode rows, chunk tokens),
+    which must go by ``want_path`` (on the chip the kernels'), then a small
+    model of both kinds of layer and a routed FFN through the engine (a
+    state a slot beside latent pages in one pool): what it emitted is the
+    float32 reference's (models/ling3_reference.py), and its ticks counted
+    their state step, chunk and pass and their latent attention by
+    ``want_path``."""
+    import dataclasses as dc
+
+    import paddle_tpu as paddle
+    from paddle_tpu.models import ling3_reference as ref
+    from paddle_tpu.models.ling3 import Ling3
+
+    errs = check_gdn_ops(*ops_shape, channel=True)
+    path, prep = errs.pop("path"), errs.pop("prep_path")
+    say("ling", f"{ops_shape[0]} heads of {ops_shape[1]} x {ops_shape[2]}, a "
+        f"decay a channel, {ops_shape[3]} decode rows and a chunk of "
+        f"{ops_shape[4]}: " + ", ".join(f"{k} {v:.2e}"
+                                        for k, v in errs.items())
+        + f" from the jax.numpy spellings (allowed {TOL_GDN_OPS}); path "
+        f"here: {path}")
+    check(path == prep == want_path, f"the per-channel rule of "
+          f"{ops_shape[:3]} went by {path} and what lies before it by "
+          f"{prep}, not {want_path}")
+    paddle.seed(0)
+    with paddle.LazyGuard():
+        net = Ling3(cfg)
+
+    def forward(layers, other, seq):
+        return ref.forward(((kind, cfg.is_moe(i), layers[f"layer{i}"])
+                            for i, kind in enumerate(cfg.layer_kinds)),
+                           other, seq, dc.asdict(cfg), held=cfg.held)
+
+    forward.ref = ref
+    counters = dict(GDN_COUNTERS,
+                    latent="serving/latent_attn_calls{path=%s,kind=dense}")
+    return {**serve_against_reference(
+        "ling", net, (num_slots, page_size, pages_per_slot, chunk),
+        requests, forward, counters, want_path,
+        "a state a slot beside latent pages"), **errs}
 
 
 # ---------------------------------------------------------------------------
@@ -1853,6 +1936,24 @@ def main() -> int:
             num_key_value_heads=4, linear_num_key_heads=4,
             linear_num_value_heads=4, max_position_embeddings=512),
         8, 16, 32, 128, (30, 96, 192, 40, 256), "pallas"))
+    # Ling-3.0: the served per-channel rule's kernels at its heads (32 of
+    # 128 x 128, the cell's 64 decode rows and chunk of 256), and a small
+    # model (a dense layer, two KDA layers, an MLA layer; 16 heads of 128,
+    # latent rows of 128 + 64 in pages of 128, eight decode rows) through
+    # the engine, whose ticks must take the kernels for the state step and
+    # for the latent attention
+    from paddle_tpu.models.ling3 import Ling3Config
+
+    run("ling", lambda: phase_ling(
+        Ling3Config(
+            vocab_size=1024, hidden_size=512, intermediate_size=1024,
+            moe_intermediate_size=128,
+            moe_shared_expert_intermediate_size=128, num_hidden_layers=4,
+            layer_ids=(1, 3, 4, 5), num_attention_heads=16, kv_lora_rank=128,
+            num_experts=16, n_group=4, topk_group=2, num_experts_per_tok=4,
+            experts_held=(4, 8), select_bias_range=0.02,
+            max_position_embeddings=1024),
+        8, 128, 8, 256, (32, 128, 128, 64, 256), "pallas"))
     run("train", lambda: phase_train(cfg, micro=2, n_micro=6, steps=4))
     if len(jax.devices()) >= 4 and "train" not in failed:
         run("multichip", lambda: phase_multichip(

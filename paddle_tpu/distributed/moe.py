@@ -375,15 +375,24 @@ def held_window_rows(t: int, top_k: int, held: int, e: int) -> int:
     return min(most, -(-fair // 128) * 128)
 
 
-def kept_groups(scores_t, n_group: int, topk_group: int):
-    """The group limit of DeepSeek-V2's router (``group_limited_greedy``,
-    arXiv:2405.04434 section 2.2's device-limited routing): the experts
-    ``[E, T]`` are ``n_group`` runs of ``E / n_group``, a group's score is
-    its best expert's, and a token keeps its ``topk_group`` best groups
+def kept_groups(scores_t, n_group: int, topk_group: int, best: int = 1):
+    """The group limit of DeepSeek's routers: the experts ``[E, T]`` are
+    ``n_group`` runs of ``E / n_group``, a group's score is the sum of its
+    ``best`` largest experts' (1: DeepSeek-V2's ``group_limited_greedy``,
+    arXiv:2405.04434 section 2.2's device-limited routing, over softmax
+    scores; 2: DeepSeek-V3's ``noaux_tc``, over sigmoid scores plus the
+    selection bias), and a token keeps its ``topk_group`` best groups
     (rounds of argmax, a tie to the lower group, as the experts' rounds).
     Returns bool ``[n_group, T]``."""
     e, t = scores_t.shape
-    remaining = jnp.max(scores_t.reshape(n_group, e // n_group, t), axis=1)
+    by_group = scores_t.reshape(n_group, e // n_group, t)
+    remaining = jnp.max(by_group, axis=1)
+    for _ in range(best - 1):
+        # the next largest of each group: the first of the largest set aside
+        rows_m = jnp.arange(e // n_group, dtype=jnp.int32)[None, :, None]
+        first = jnp.argmax(by_group, axis=1).astype(jnp.int32)[:, None, :]
+        by_group = jnp.where(rows_m == first, -jnp.inf, by_group)
+        remaining = remaining + jnp.max(by_group, axis=1)
     rows_g = jnp.arange(n_group, dtype=jnp.int32)[:, None]
     kept = jnp.zeros((n_group, t), bool)
     for _ in range(topk_group):
@@ -401,17 +410,16 @@ def _route(x, router_w, top_k, scoring="softmax", select_bias=None,
     gates of each round and, **a counting sort**, every assignment's place
     within its expert's group (rows of earlier rounds, then the earlier
     tokens of this round), kept for the experts ``held=(first, count)``
-    alone where that is given. ``n_group`` > 1 (softmax scores): a token
-    chooses within its ``topk_group`` best of ``n_group`` groups of experts
-    (``kept_groups``), the others' scores set to 0 before the rounds.
+    alone where that is given. ``n_group`` > 1: a token chooses within its
+    ``topk_group`` best of ``n_group`` groups of experts (``kept_groups``).
+    Over softmax scores (DeepSeek-V2's) a group's score is its best
+    expert's and the others' scores are set to 0 before the rounds; over
+    sigmoid scores (DeepSeek-V3's ``noaux_tc``) it is the sum of its two
+    largest ``score + select_bias`` and the others leave the rounds.
     Returns ``(probs_t [E, T], z, expert_rounds, gate_rounds, pos_rounds,
     counts)``, ``counts`` [E] or [count] float32 the rows given out."""
     e = router_w.shape[1]
     softmax = scoring == "softmax"
-    if n_group > 1 and not softmax:
-        raise NotImplementedError(
-            "a group limit over sigmoid scores (DeepSeek-V3's, a group's "
-            "score the sum of its two best): only V2's over softmax is here")
     held_rows = (lambda a: a) if held is None \
         else (lambda a: a[held[0]:held[0] + held[1]])
     logits_t = jnp.dot(router_w.astype(x.dtype).T, x.T,
@@ -430,6 +438,10 @@ def _route(x, router_w, top_k, scoring="softmax", select_bias=None,
         z = jnp.zeros((), jnp.float32)
         remaining = probs_t if select_bias is None else probs_t + \
             jax.lax.stop_gradient(select_bias).astype(jnp.float32)[:, None]
+        if n_group > 1:
+            remaining = jnp.where(jnp.repeat(
+                kept_groups(remaining, n_group, topk_group, best=2),
+                e // n_group, axis=0), remaining, -jnp.inf)
     rows_e = jnp.arange(e, dtype=jnp.int32)[:, None]
     counts = jnp.zeros((e if held is None else held[1],), jnp.float32)
     expert_rounds, gate_rounds, pos_rounds = [], [], []
@@ -527,8 +539,8 @@ def held_moe(x, router_w, w_gate, w_up, w_down, top_k, held,
     ``"softmax"``: as ``dropless_moe``, unrenormalised.
     ``shared=(w_gate, w_up, w_down)`` [H, F], [H, F], [F, H] adds one
     SwiGLU expert every token passes (two shared experts of 1,536 are one
-    of 3,072). ``n_group``, ``topk_group``: the softmax router's group
-    limit (``_route``); ``routed_scaling`` multiplies the routed experts'
+    of 3,072). ``n_group``, ``topk_group``: the router's group limit, in
+    its scoring's form (``_route``); ``routed_scaling`` multiplies the routed experts'
     weights and not the shared expert. At their defaults the program is
     what it was without them.
 

@@ -9,7 +9,18 @@ that lives in a slot of a pool between ticks::
 over a head's key channels, which commutes with the reflection), ``beta`` in
 [0, 2]. ``ops/kda.py`` is the rule trained: every sequence starts at zero and
 only ``o`` leaves. Here rows enter with their slot's state and leave it
-behind:
+behind.
+
+**Two gate forms are served**, told apart by ``g``'s rank where the program
+is traced, each with its own kernels, so that one model's program never
+holds the other's. ``g`` ``[.., H]``, **a decay a head** (Gated DeltaNet,
+Olmo-Hybrid), as above. ``g`` ``[.., H, dk]``, **a decay a key channel**
+(Kimi Delta Attention, arXiv:2510.26692, Ling-3.0's linear layers)::
+
+    S_t = (I - beta_t k_t k_t^T) Diag(exp g_t) S_{t-1} + beta_t k_t v_t^T
+
+which is ``ops/kda.py``'s rule itself: the decay scales the state's rows
+before the reflection reads them. The entries:
 
 ``gdn_step_rows``   the decode rows, one token against a state (read it,
                     write it: bound by HBM)
@@ -25,11 +36,16 @@ behind:
                     (``conv_step``, ``conv_rows`` and ``normed`` are its
                     ``jax.numpy`` parts)
 
+The kernels: ``gdn_step`` and ``gdn_chunk`` (a decay a head, heads in
+pairs), ``kda_step`` and ``kda_chunk`` (a decay a channel, a head alone).
+
 **The state's layout** is this file's, held by ``serving.paged_cache.
 StatePools``: ``[layers, slots + 1, heads / 2, dk, 2 dv]`` float32, two
 heads' values side by side on the lanes (``pack_state``). 192 is not a
 multiple of a tile's 128 lanes and 384 is: a head alone would be stored and
-moved at 256. The history's is ``[layers, taps - 1, rows, C]`` in the pools'
+moved at 256. Heads whose values fill whole tiles (``dv % 128 == 0``:
+Ling-3.0's 128 x 128) lie each alone, ``[layers, slots + 1, heads, dk,
+dv]``. The history's is ``[layers, taps - 1, rows, C]`` in the pools'
 type, a slot a row, ``rows`` whole tiles (``conv_slot_rows``). Slot 0 is the
 null slot, as page 0 is the null page: a dead row (``slots`` 0: an empty
 slot, one still prefilling) reads and writes it and no tenant's state is
@@ -86,7 +102,8 @@ from .flash_attention import _interpret
 __all__ = ["gdn_step_rows", "gdn_chunk_rows", "gdn_prep_rows", "conv_step",
            "conv_rows", "normed", "gdn_path", "prep_path", "conv_slot_rows",
            "pack_state", "unpack_state", "xla_step", "xla_chunk", "xla_prep",
-           "pallas_step", "pallas_chunk", "pallas_prep", "gdn_recurrent"]
+           "pallas_step", "pallas_chunk", "pallas_prep", "gdn_recurrent",
+           "kda_path", "pallas_kda_step", "pallas_kda_chunk"]
 
 _F32 = jnp.float32
 _HIGHEST = jax.lax.Precision.HIGHEST
@@ -101,11 +118,17 @@ _HALO = 8           # float32 sublanes above a chunk row: its history's place
 _PREP_COLS = 2048
 
 
+def _paired(heads: int, dv: int) -> bool:
+    """Whether the state keeps two heads' values side by side."""
+    return heads % 2 == 0 and dv % _LANES != 0
+
+
 def pack_state(s):
     """``[..., heads, dk, dv]`` -> ``[..., heads / 2, dk, 2 dv]``: heads
-    ``2p`` and ``2p + 1`` side by side (an odd count: each head alone)."""
+    ``2p`` and ``2p + 1`` side by side (an odd count, or values of whole
+    tiles of 128 lanes: each head alone)."""
     *lead, h, dk, dv = s.shape
-    if h % 2:
+    if not _paired(h, dv):
         return s
     s = s.reshape(*lead, h // 2, 2, dk, dv)
     return jnp.swapaxes(s, -3, -2).reshape(*lead, h // 2, dk, 2 * dv)
@@ -126,8 +149,21 @@ def gdn_path(heads: int, dk: int, dv: int) -> str:
     from ..distributed import context as dctx
 
     if (target_platform() == "tpu" and dctx.kernel_auto_axes() is None
-            and heads % 2 == 0 and dk % 8 == 0 and dk <= _LANES
+            and _paired(heads, dv) and dk % 8 == 0 and dk <= _LANES
             and (2 * dv) % _LANES == 0):
+        return "pallas"
+    return "xla"
+
+
+def kda_path(heads: int, dk: int, dv: int) -> str:
+    """``gdn_path`` for a decay a key channel: the kernels ``kda_step`` and
+    ``kda_chunk`` take heads that lie alone, keys of one tile's 128 lanes
+    and values of whole tiles."""
+    from ..core.place import target_platform
+    from ..distributed import context as dctx
+
+    if (target_platform() == "tpu" and dctx.kernel_auto_axes() is None
+            and dk == _LANES and dv % _LANES == 0):
         return "pallas"
     return "xla"
 
@@ -142,11 +178,17 @@ def _count(name: str, path: str) -> None:
 # the jax.numpy spelling: pure functions of the rows' own states
 # ---------------------------------------------------------------------------
 def xla_step(q, k, v, g, beta, s):
-    """One token a row: q, k ``[n, H, dk]``, v ``[n, H, dv]``, g, beta ``[n,
-    H]``, s ``[n, H, dk, dv]`` float32 -> ``(o [n, H, dv] float32, s)``."""
+    """One token a row: q, k ``[n, H, dk]``, v ``[n, H, dv]``, beta ``[n,
+    H]``, g ``[n, H]`` (a decay a head) or ``[n, H, dk]`` (a key channel),
+    s ``[n, H, dk, dv]`` float32 -> ``(o [n, H, dv] float32, s)``."""
     hi = functools.partial(jnp.einsum, precision=_HIGHEST)
     qf, kf, vf = (a.astype(_F32) for a in (q, k, v))
     dec = jnp.exp(g.astype(_F32))
+    if g.ndim == 3:         # the state's rows decay, then the reflection
+        s = dec[..., None] * s
+        w = beta.astype(_F32)[..., None] * (vf - hi("nhk,nhkv->nhv", kf, s))
+        s = s + kf[..., :, None] * w[..., None, :]
+        return hi("nhk,nhkv->nhv", qf, s) * q.shape[-1] ** -0.5, s
     ks = hi("nhk,nhkv->nhv", kf, s)
     w = beta.astype(_F32)[..., None] * (vf - dec[..., None] * ks)
     s = dec[..., None, None] * s + kf[..., :, None] * w[..., None, :]
@@ -168,21 +210,23 @@ def gdn_recurrent(q, k, v, g, beta, s0):
 
 
 def _masked(g, beta, row_len):
-    """``g`` and ``beta`` ``[n, w, H]`` float32 with the positions at and
-    past each row's length made the identity, ``g`` at ``kda.G_MIN`` or
-    above (the chunked form's floor)."""
+    """``g`` ``[n, w, H]`` or ``[n, w, H, dk]`` and ``beta`` ``[n, w, H]``
+    float32 with the positions at and past each row's length made the
+    identity, ``g`` at ``kda.G_MIN`` or above (the chunked form's floor)."""
     w = g.shape[1]
     keep = (jnp.arange(w, dtype=jnp.int32)[None, :] < row_len[:, None])[
         ..., None]
-    return (jnp.where(keep, jnp.maximum(g.astype(_F32), kda.G_MIN), 0.0),
+    return (jnp.where(keep.reshape(keep.shape + (1,) * (g.ndim - 3)),
+                      jnp.maximum(g.astype(_F32), kda.G_MIN), 0.0),
             jnp.where(keep, beta.astype(_F32), 0.0))
 
 
 def xla_chunk(q, k, v, g, beta, s0, row_len):
     """``w`` tokens a row from ``s0``: q, k ``[n, w, H, dk]``, v ``[n, w, H,
-    dv]``, g, beta ``[n, w, H]``, s0 ``[n, H, dk, dv]`` float32, row_len
-    ``[n]`` -> ``(o [n, w, H, dv] float32, s1)``. A ``lax.scan`` over chunks
-    of ``kda.CHUNK`` tokens of ``kda._chunk_fwd`` under ``vmap``."""
+    dv]``, beta ``[n, w, H]``, g ``[n, w, H]`` or, a key channel, ``[n, w,
+    H, dk]``, s0 ``[n, H, dk, dv]`` float32, row_len ``[n]`` -> ``(o [n, w,
+    H, dv] float32, s1)``. A ``lax.scan`` over chunks of ``kda.CHUNK``
+    tokens of ``kda._chunk_fwd`` under ``vmap``."""
     n, w, h, dk = q.shape
     c = kda.CHUNK
     g, beta = _masked(g, beta, row_len)
@@ -190,8 +234,10 @@ def xla_chunk(q, k, v, g, beta, s0, row_len):
     if pad:
         q, k, v = (jnp.pad(a, ((0, 0), (0, pad), (0, 0), (0, 0)))
                    for a in (q, k, v))
-        g, beta = (jnp.pad(a, ((0, 0), (0, pad), (0, 0))) for a in (g, beta))
-    gk = jnp.broadcast_to(g[..., None], g.shape + (dk,))
+        g, beta = (jnp.pad(a, ((0, 0), (0, pad)) + ((0, 0),) * (a.ndim - 2))
+                   for a in (g, beta))
+    gk = g if g.ndim == 4 else jnp.broadcast_to(g[..., None],
+                                                g.shape + (dk,))
     xs = (kda._chunked(q, c), kda._chunked(k, c), kda._chunked(v, c),
           kda._chunked(gk, c), kda._beta_rows(beta, c))
     body = kda._over_heads(functools.partial(kda._chunk_fwd,
@@ -357,19 +403,146 @@ def pallas_chunk(q, k, v, g, beta, state, layer, slots, fresh, row_len):
     return o.reshape(n, w, h, dv), state
 
 
+def _kda_step_kernel(slots_ref, layer_ref, q_ref, k_ref, v_ref, g_ref, b_ref,
+                     s_ref, o_ref, s_out):
+    """Grid (row,): every head of the row, one after the other, a head's
+    state ``[dk, dv]`` alone on whole tiles. ``S' = Diag(e^g) S``, ``S' += k
+    (beta (v - k^T S'))^T``, ``o = S'^T q``, all on the vector unit in
+    float32; keys, queries and decays are columns over the state's rows."""
+    del slots_ref, layer_ref
+    heads, dk = s_ref.shape[2:4]
+    scale = dk ** -0.5
+    col = lambda ref, h: kda._to_col(                       # noqa: E731
+        ref[0, h:h + 1, :].astype(_F32))                    # [dk, 1]
+    for h in range(heads):
+        s = jnp.exp(col(g_ref, h)) * s_ref[0, 0, h]
+        kcol = col(k_ref, h)
+        ks = jnp.sum(kcol * s, axis=0, keepdims=True)       # [1, dv]
+        s = s + kcol * (b_ref[0, h:h + 1, :]
+                        * (v_ref[0, h:h + 1, :].astype(_F32) - ks))
+        s_out[0, 0, h] = s
+        o_ref[0, h:h + 1, :] = scale * jnp.sum(col(q_ref, h) * s, axis=0,
+                                               keepdims=True)
+
+
+def pallas_kda_step(q, k, v, g, beta, state, layer, slots):
+    """The kernel ``kda_step`` over rows ``[n, ...]`` (shapes as
+    ``xla_step``'s with ``g`` ``[n, H, dk]``; ``state`` the whole stack
+    ``[layers, slots + 1, H, dk, dv]``, updated in place at ``(layer,
+    slots)``) -> ``(o [n, H, dv] float32, state)``."""
+    n, h, dk = q.shape
+    dv = v.shape[-1]
+    row = lambda *tail: pl.BlockSpec(                       # noqa: E731
+        (1,) + tail, lambda i, sl, ly: (i,) + (0,) * len(tail))
+    st = pl.BlockSpec((1, 1, h, dk, dv),
+                      lambda i, sl, ly: (ly[0], sl[i], 0, 0, 0))
+    return tuple(pl.pallas_call(
+        _kda_step_kernel,
+        name="kda_step",
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=(n,),
+            in_specs=[row(h, dk), row(h, dk), row(h, dv), row(h, dk),
+                      row(h, dv), st],
+            out_specs=[row(h, dv), st]),
+        out_shape=[jax.ShapeDtypeStruct((n, h, dv), _F32),
+                   jax.ShapeDtypeStruct(state.shape, state.dtype)],
+        input_output_aliases={7: 1},
+        compiler_params=_params("arbitrary"),
+        interpret=_interpret(),
+    )(slots.astype(jnp.int32), jnp.asarray(layer, jnp.int32).reshape(1),
+      q, k, v, g.astype(_F32),
+      jnp.broadcast_to(beta.astype(_F32)[..., None], (n, h, dv)), state))
+
+
+def _kda_chunk_kernel(slots_ref, layer_ref, fresh_ref, len_ref, q_ref, k_ref,
+                      v_ref, g_ref, b_ref, s_ref, o_ref, s_out, acc):
+    """Grid (row, head, chunk): the head's state stays in ``acc`` ``[dk,
+    dv]`` over the row's chunks, each ``kda._chunk_fwd`` as the trained scan
+    runs it, the decay a channel as it comes. A row of no tokens (the chunk
+    row of a tick without a chunk: every tick of a window that only
+    decodes) skips the rule: zeros out, its slot's state as it was."""
+    del slots_ref, layer_ref
+    r, c = pl.program_id(0), pl.program_id(2)
+    some = len_ref[r] > 0
+
+    @pl.when(c == 0)
+    def _enter():
+        acc[...] = jnp.zeros_like(acc)
+
+        @pl.when(fresh_ref[r] == 0)
+        def _carried():
+            acc[...] = s_ref[0, 0, 0]
+
+    @pl.when(some)
+    def _rule():
+        o, s1, _ = kda._chunk_fwd(q_ref[0], k_ref[0], v_ref[0], g_ref[0],
+                                  b_ref[0, 0, pl.ds(c, 1), :], acc[...],
+                                  acc.shape[0] ** -0.5)
+        o_ref[0] = o
+        acc[...] = s1
+
+    @pl.when(jnp.logical_not(some))
+    def _empty():
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+    @pl.when(c + 1 == pl.num_programs(2))
+    def _leave():
+        s_out[0, 0, 0] = jnp.where(some, acc[...], s_ref[0, 0, 0])
+
+
+def pallas_kda_chunk(q, k, v, g, beta, state, layer, slots, fresh, row_len):
+    """The kernel ``kda_chunk`` over rows ``[n, w, ...]`` (shapes as
+    ``xla_chunk``'s with ``g`` ``[n, w, H, dk]``, ``w`` a multiple of
+    ``kda.CHUNK``; ``state`` the whole stack ``[layers, slots + 1, H, dk,
+    dv]``, updated in place at ``(layer, slots)``; ``fresh`` rows enter at
+    zero) -> ``(o [n, w, H, dv] float32, state)``."""
+    n, w, h, dk = q.shape
+    dv = v.shape[-1]
+    cs, nc = kda.CHUNK, w // kda.CHUNK
+    g, beta = _masked(g, beta, row_len)
+    flat = lambda a: a.reshape(n, w, -1)                    # noqa: E731
+    cols = lambda d: pl.BlockSpec(                          # noqa: E731
+        (1, cs, d), lambda i, j, c, sl, ly, fr, ln: (i, c, j))
+    gate = pl.BlockSpec((1, 1, nc, cs),
+                        lambda i, j, c, sl, ly, fr, ln: (i, j, 0, 0))
+    st = pl.BlockSpec((1, 1, 1, dk, dv), lambda i, j, c, sl, ly, fr, ln: (
+        ly[0], sl[i], j, 0, 0))
+    o, state = pl.pallas_call(
+        _kda_chunk_kernel,
+        name="kda_chunk",
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4, grid=(n, h, nc),
+            in_specs=[cols(dk), cols(dk), cols(dv), cols(dk), gate, st],
+            out_specs=[cols(dv), st],
+            scratch_shapes=[pltpu.VMEM((dk, dv), _F32)]),
+        out_shape=[jax.ShapeDtypeStruct((n, w, h * dv), _F32),
+                   jax.ShapeDtypeStruct(state.shape, state.dtype)],
+        input_output_aliases={9: 1},
+        compiler_params=_params("arbitrary", "arbitrary", "arbitrary"),
+        interpret=_interpret(),
+    )(slots.astype(jnp.int32), jnp.asarray(layer, jnp.int32).reshape(1),
+      fresh.astype(jnp.int32), row_len.astype(jnp.int32), flat(q), flat(k),
+      flat(v), flat(g),
+      jnp.transpose(beta.reshape(n, nc, cs, h), (0, 3, 1, 2)), state)
+    return o.reshape(n, w, h, dv), state
+
+
 # ---------------------------------------------------------------------------
 # the entries: rows against the slots of a state stack
 # ---------------------------------------------------------------------------
 def gdn_step_rows(q, k, v, g, beta, state, layer, slots):
     """The decode rows: one token each against the state at ``(layer,
     slots)`` of ``state`` ``[layers, slots + 1, ...]`` (``pack_state``'s
-    layout), which is left updated. A dead row has ``slots`` 0, the null
+    layout), which is left updated; ``g`` ``[n, H]`` a decay a head or ``[n,
+    H, dk]`` a key channel. A dead row has ``slots`` 0, the null
     slot. -> ``(o [n, H, dv] float32, state)``."""
     h, dk, dv = q.shape[1], q.shape[2], v.shape[-1]
-    path = gdn_path(h, dk, dv)
+    channel = g.ndim == 3           # a decay a key channel (KDA)
+    path = (kda_path if channel else gdn_path)(h, dk, dv)
     _count("step_calls", path)
     if path == "pallas":
-        return pallas_step(q, k, v, g, beta, state, layer, slots)
+        return (pallas_kda_step if channel else pallas_step)(
+            q, k, v, g, beta, state, layer, slots)
     o, s = xla_step(q, k, v, g, beta, unpack_state(state[layer, slots], h))
     return o, state.at[layer, slots].set(pack_state(s))
 
@@ -379,11 +552,13 @@ def gdn_chunk_rows(q, k, v, g, beta, state, layer, slots, fresh, row_len):
     slots)``, or from zero where ``fresh``; positions at and past
     ``row_len`` change nothing. -> ``(o [n, w, H, dv] float32, state)``."""
     w, h, dk, dv = q.shape[1], q.shape[2], q.shape[3], v.shape[-1]
-    path = gdn_path(h, dk, dv) if w % kda.CHUNK == 0 else "xla"
+    channel = g.ndim == 4           # a decay a key channel (KDA)
+    path = (kda_path if channel else gdn_path)(h, dk, dv) \
+        if w % kda.CHUNK == 0 else "xla"
     _count("chunk_calls", path)
     if path == "pallas":
-        return pallas_chunk(q, k, v, g, beta, state, layer, slots, fresh,
-                            row_len)
+        return (pallas_kda_chunk if channel else pallas_chunk)(
+            q, k, v, g, beta, state, layer, slots, fresh, row_len)
     s0 = jnp.where(fresh[:, None, None, None], 0.0,
                    unpack_state(state[layer, slots], h))
     o, s1 = xla_chunk(q, k, v, g, beta, s0, row_len)

@@ -1217,16 +1217,21 @@ class LatentPagePool(PagePool):
 
 
 class StatePools(NamedTuple):
-    """The caches of a model whose layers are of two kinds (ISSUE 44;
-    ``models/olmo_hybrid.py``): full attention over K/V pages in some, a
-    gated delta rule's recurrent state in the others, as the device holds
-    them:
+    """The caches of a model whose layers are of two kinds (ISSUE 44,
+    ``models/olmo_hybrid.py``; ISSUE 49, ``models/ling3.py``): attention over
+    pages in some, a gated delta rule's recurrent state in the others, as
+    the device holds them:
 
-    kv     ``Pools``: the full layers' K and V pages, ``[Lf, P, ps, NHr, D]``.
-           ``NHr`` is the heads rounded up to whole tiles of the pools' type
-           (30 heads of bf16 ride at 32, two of zeros: the ragged kernel
-           reads a head by a strided load when the heads fill tiles, and
-           batches a float32 product otherwise)
+    kv     the attention layers' pages, in either format. ``Pools``: K and V
+           pages, ``[Lf, P, ps, NHr, D]``; ``NHr`` is the heads rounded up
+           to whole tiles of the pools' type (30 heads of bf16 ride at 32,
+           two of zeros: the ragged kernel reads a head by a strided load
+           when the heads fill tiles, and batches a float32 product
+           otherwise). Or, for a ``cache_spec()`` that gives a
+           ``latent_width``, ``LatentPools`` of latent rows ``[Lf, P, C + R,
+           ps]`` with no indexer keys and no window space (latent attention
+           beside the state: written and read through ``scatter_latent``
+           and ``attend_latent``)
     state  ``[Ll, slots + 1, ...]`` float32: one state a linear layer and
            slot, whatever the context, in ``ops/gdn.pack_state``'s layout
     conv   ``[Ll, taps - 1, rows, C]``: the positions each linear layer's
@@ -1254,13 +1259,14 @@ class StatePools(NamedTuple):
     page: rows that carry no tenant's token read and write it. A pytree
     like ``Pools``, and the one place that knows this format: a forward
     writes and reads K/V through ``scatter`` and ``attend`` as a K/V model
-    does, and passes a linear layer's rows through ``prep`` (the
+    does (latent rows through ``scatter_latent`` and ``attend_latent`` as a
+    latent model does), and passes a linear layer's rows through ``prep`` (the
     convolution over the carried history, SiLU, l2norm) and then ``step``
     (decode rows) or ``chunk`` (chunk rows), ``ops/gdn``'s functions on the
     field they are for. A tenant's first chunk enters at zero (``fresh``):
     nothing else clears a slot."""
 
-    kv: Pools
+    kv: "Pools | LatentPools"
     state: jax.Array
     conv: jax.Array
 
@@ -1273,10 +1279,16 @@ class StatePools(NamedTuple):
         one = pack_state(jnp.zeros(
             (caches["state_heads"], caches["key_dim"], caches["value_dim"]),
             jnp.float32))
+        if "latent_width" in caches:
+            pages = LatentPools.zeros(caches["layers"], num_pages, 0, 2,
+                                      page_size, caches["latent_width"], 0,
+                                      0, dtype)
+        else:
+            pages = Pools.zeros(caches["layers"], num_pages, page_size,
+                                -(-caches["heads"] // tile) * tile,
+                                caches["head_dim"], dtype)
         return cls(
-            Pools.zeros(caches["layers"], num_pages, page_size,
-                        -(-caches["heads"] // tile) * tile,
-                        caches["head_dim"], dtype),
+            pages,
             jnp.zeros((caches["state_layers"], num_slots + 1) + one.shape,
                       jnp.float32),
             jnp.zeros((caches["state_layers"], caches["conv_taps"] - 1,
@@ -1305,6 +1317,16 @@ class StatePools(NamedTuple):
     def attend(self, layer, q, page_table, pos0, true_len):
         return self.kv.attend(layer, self._head_rows(q), page_table, pos0,
                               true_len)[..., :q.shape[-2], :]
+
+    # -- the attention layers over latent pages ------------------------
+    def scatter_latent(self, layer, page, off, rows, touched):
+        return self._replace(kv=self.kv.scatter_latent(layer, page, off,
+                                                       rows, touched))
+
+    def attend_latent(self, layer, q, page_table, pos0, true_len,
+                      c_width: int, scale: float):
+        return self.kv.attend(layer, q, page_table, pos0, true_len, c_width,
+                              scale)
 
     # -- the linear layers: a state and a history a slot ---------------
     def prep(self, layer, slots, x, taps, heads: int, key_dim: int,
@@ -1336,7 +1358,9 @@ class StatePools(NamedTuple):
 
 
 class StatePagePool(PagePool):
-    """``PagePool`` for ``StatePools``: the full layers' pages are the pool's
+    """``PagePool`` for ``StatePools``: the attention layers' pages (K/V
+    pages, or latent rows where the ``cache_spec()`` gives a
+    ``latent_width``) are the pool's
     own, allocated, grown and freed as a K/V model's are; a slot's state and
     history are slot ``s + 1`` of their arrays for as long as the engine has
     slot ``s``, so nothing is allocated for them and they never bind.
@@ -1355,7 +1379,7 @@ class StatePagePool(PagePool):
 
     CANNOT = {
         "prefix": (
-            "{doing} over a recurrent state: a cached K/V page is of no use "
+            "{doing} over a recurrent state: a cached page is of no use "
             "without the linear layers' states at that page's boundary, and "
             "no snapshot of them is kept (ROADMAP R5)"),
         "rewinds": (
@@ -1364,12 +1388,13 @@ class StatePagePool(PagePool):
             "tick (serving/spec.py make_spec_tick) carries Pools of K and V"),
         "int8": (
             "int8 pages beside a recurrent state: the scales' reset list "
-            "and the quantized ragged kernel are Pools' own, and StatePools "
-            "pads its heads to whole tiles of a float type"),
+            "and the quantized ragged kernel are Pools' own; StatePools "
+            "pads its heads to whole tiles of a float type, and a latent "
+            "row has no head axis"),
         "handoff": (
-            "{doing} over a recurrent state: a handoff moves Pools of K and "
-            "V by page (serving/disagg.py); a slot's state and convolution "
-            "history are not pages and nothing ships them"),
+            "{doing} over a recurrent state: a handoff moves pages "
+            "(serving/disagg.py: Pools of K and V); a slot's state and "
+            "convolution history are not pages and nothing ships them"),
         "chunk_rows": (
             "{doing} over a recurrent state: under fifo two chunk rows of a "
             "tick are one prompt's consecutive chunks, and the second needs "
@@ -1393,16 +1418,21 @@ class StatePagePool(PagePool):
                 "the page its decode row's token would need (StatePagePool)")
         pools = StatePools.zeros(caches, num_pages, page_size, num_slots,
                                  dtype)
-        super().__init__(caches["layers"], num_pages, page_size,
-                         caches["heads"], caches["head_dim"], num_slots,
-                         pages_per_slot, dtype=dtype, pools=pools)
+        #: the pages' format, as the engine's gauges name it
+        self.pages_kind = "latent" if "latent_width" in caches else "kv"
+        heads, width = (1, caches["latent_width"]) \
+            if self.pages_kind == "latent" \
+            else (caches["heads"], caches["head_dim"])
+        super().__init__(caches["layers"], num_pages, page_size, heads,
+                         width, num_slots, pages_per_slot, dtype=dtype,
+                         pools=pools)
         #: slots whose state a chunk row has entered since they were released
         self._stateful = np.zeros(num_slots, bool)
         _registry().gauge("serving/state_bytes").set(
             float(pools.state.nbytes + pools.conv.nbytes))
 
     def live_shares(self) -> Dict[str, float]:
-        return {"kv": self.allocator.utilization(),
+        return {self.pages_kind: self.allocator.utilization(),
                 "state": float(np.mean(self._stateful))}
 
     def row_tables(self, rows):

@@ -1,0 +1,9 @@
+"""The fullest held expert's rows over the mean held expert's, mean over the
+six expert layers and the run's ticks, as the ticks report it: with about 128
+rows over 128 experts a tick, a Poisson's fullest (4 to 5) and no sign of a
+bias that chooses."""
+
+
+def read(run):
+    value = run["facts"].get("tick_expert_load_max_over_mean")
+    return None if value is None else 1.0 * value

@@ -314,3 +314,23 @@ def test_compile_cache_placement(monkeypatch, keep_cache_config):
     want = os.path.join(REPO, ".jax_cache")
     assert compile_cache.enable_compile_cache() == want
     assert jax.config.jax_compilation_cache_dir == want
+
+
+def test_ling_rehearsal():
+    """Ling-3.0's pass at toy sizes: the served per-channel rule against its
+    spellings (off the chip: the spellings themselves), a small model's
+    tokens through a state a slot beside latent pages against the float32
+    reference."""
+    from paddle_tpu.models.ling3 import Ling3Config
+
+    cfg = Ling3Config.tiny(initializer_range=0.05, experts_held=(4, 8))
+    out = chip_smoke.phase_ling(cfg, 3, 4, 24, 8, (4, 16, 16, 5, 128),
+                                "xla", requests=((23, 12), (41, 10),
+                                                 (7, 16)))
+    assert out["tick_paths"] == {"step": ["xla"], "chunk": ["xla"],
+                                 "prep": ["xla"], "latent": ["xla"]}
+    assert max(out["step_o"], out["step_s"], out["chunk_o"],
+               out["chunk_s"]) <= chip_smoke.TOL_GDN_OPS
+    assert out["prep_step"] == out["prep_chunk"] == 0   # the spelling itself
+    assert out["median"] <= out["worst"] <= chip_smoke.TOL_GDN_WORST
+    assert out["weights_bytes"] == 2 * cfg.num_params()

@@ -153,8 +153,8 @@ def test_one_group_is_plain_top_k_and_the_defaults_change_no_program():
     # every group kept is no limit either
     e3, _ = _route_rows(x, w, 3, n_group=4, topk_group=4)
     np.testing.assert_array_equal(e1, e3)
-    with pytest.raises(NotImplementedError, match="sigmoid"):
-        moe._route(x, w, 3, scoring="sigmoid", n_group=4, topk_group=2)
+    # (the group limit over sigmoid scores, a group's score the sum of its
+    # two best: tests/test_moe.py, since ISSUE 49)
     # held_moe with the new arguments at their defaults lowers to the text
     # it lowered to without them (OLMoE's, Solar's and dots3's callers)
     wg = jnp.asarray(rng.normal(size=(4, 16, 8)), jnp.float32)

@@ -319,3 +319,134 @@ def test_the_prep_path_is_observed_and_counted(monkeypatch):
                         None, 4, 96)
     np.testing.assert_array_equal(q, want[0])
     np.testing.assert_array_equal(after, want[3])
+
+
+# --- a decay a key channel (Kimi Delta Attention; ISSUE 49) -------------------
+def _channel_rows(seed, n, w, h, dk, dv):
+    """``_rows`` with ``g`` a key channel, bounded as Ling-3.0's gate bounds
+    it (in (-5, 0)), and ``beta`` in (0, 1)."""
+    q, k, v, _, beta, s0 = _rows(seed, n, w, h, dk, dv)
+    g = -5.0 * jax.nn.sigmoid(
+        jax.random.normal(jax.random.PRNGKey(seed + 100), (n, w, h, dk)))
+    return q, k, v, g, beta / 2, s0
+
+
+@pytest.mark.parametrize("h,dk,dv", [(4, 16, 16), (2, 128, 128)])
+def test_the_per_channel_rule_from_a_state_equals_kdas_recurrence(h, dk, dv):
+    """From a zero state the served rule is ``ops/kda.kda_recurrent`` itself;
+    from a carried one the chunked form and the step are the recurrence."""
+    q, k, v, g, beta, s0 = _channel_rows(21, 2, 80, h, dk, dv)
+    zero = jnp.zeros_like(s0)
+    o0, _ = gdn.gdn_recurrent(q, k, v, g, beta, zero)
+    np.testing.assert_allclose(o0, kda.kda_recurrent(q, k, v, g, beta),
+                               atol=1e-5, rtol=1e-5)
+    want_o, want_s = gdn.gdn_recurrent(q, k, v, g, beta, s0)
+    o, s1 = gdn.xla_chunk(q, k, v, g, beta, s0, jnp.full((2,), 80))
+    np.testing.assert_allclose(o, want_o, atol=2e-4, rtol=2e-4)
+    np.testing.assert_allclose(s1, want_s, atol=2e-4, rtol=2e-4)
+    # a decay equal over a head's channels is the scalar rule
+    flat = jnp.broadcast_to(g[..., :1], g.shape)
+    a, sa = gdn.gdn_recurrent(q, k, v, flat, beta, s0)
+    b, sb = gdn.gdn_recurrent(q, k, v, g[..., 0], beta, s0)
+    np.testing.assert_allclose(a, b, atol=1e-5, rtol=1e-5)
+    np.testing.assert_allclose(sa, sb, atol=1e-5, rtol=1e-5)
+    # and the channels matter: the mean over them is another model
+    mean = jnp.broadcast_to(jnp.mean(g, -1, keepdims=True), g.shape)
+    assert np.abs(gdn.gdn_recurrent(q, k, v, mean, beta, s0)[0]
+                  - want_o).max() > 1e-2
+
+
+@pytest.mark.parametrize("h,dk,dv", [(4, 16, 16), (2, 128, 128)])
+def test_per_channel_step_rows_equal_the_recurrence(h, dk, dv):
+    q, k, v, g, beta, s0 = _channel_rows(22, 3, 5, h, dk, dv)
+    want_o, want_s = gdn.gdn_recurrent(q, k, v, g, beta, s0)
+    state, slots = _stack(s0), jnp.arange(1, 4)
+    # heads of whole tiles lie alone, the others in pairs
+    assert state.shape[2:] == ((h, dk, dv) if dv % 128 == 0
+                               else (h // 2, dk, 2 * dv))
+    outs = []
+    for t in range(5):
+        o, state = gdn.gdn_step_rows(q[:, t], k[:, t], v[:, t], g[:, t],
+                                     beta[:, t], state, 1, slots)
+        outs.append(o)
+    np.testing.assert_allclose(jnp.stack(outs, 1), want_o, atol=1e-5,
+                               rtol=1e-5)
+    np.testing.assert_allclose(gdn.unpack_state(state[1, 1:], h), want_s,
+                               atol=1e-5, rtol=1e-5)
+    np.testing.assert_array_equal(state[0], 7.0)    # the other layer
+    # chunk rows through the entry: a fresh row enters at zero, positions
+    # past a row's length change nothing
+    q, k, v, g, beta, s0 = _channel_rows(23, 2, 64, h, dk, dv)
+    state = _stack(s0)
+    o, after = gdn.gdn_chunk_rows(q, k, v, g, beta, state, 1,
+                                  jnp.asarray([2, 1]),
+                                  jnp.asarray([False, True]),
+                                  jnp.asarray([40, 64]))
+    want_o, want_s = gdn.gdn_recurrent(         # row 0 from slot 2's state
+        q[:1, :40], k[:1, :40], v[:1, :40], g[:1, :40], beta[:1, :40],
+        s0[1:])
+    np.testing.assert_allclose(o[0, :40], want_o[0], atol=2e-4, rtol=2e-4)
+    np.testing.assert_allclose(gdn.unpack_state(after[1, 2:3], h), want_s,
+                               atol=2e-4, rtol=2e-4)
+    fresh_o, _ = gdn.gdn_recurrent(q[1:], k[1:], v[1:], g[1:], beta[1:],
+                                   jnp.zeros_like(s0[:1]))
+    np.testing.assert_allclose(o[1], fresh_o[0], atol=2e-4, rtol=2e-4)
+
+
+def test_the_per_channel_step_kernel_equals_its_xla_spelling():
+    h, dk, dv = 2, 128, 128
+    q, k, v, g, beta, s0 = _channel_rows(24, 4, 1, h, dk, dv)
+    state = _stack(s0)
+    slots = jnp.asarray([2, 0, 4, 1])
+    args = (q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0])
+    want_o, want_s = gdn.xla_step(*args, state[1, slots])
+    o, after = gdn.pallas_kda_step(*args, state, 1, slots)
+    live = np.asarray(slots) > 0
+    np.testing.assert_allclose(o[live], want_o[live], atol=1e-5, rtol=1e-5)
+    np.testing.assert_allclose(after[1, slots][live], want_s[live],
+                               atol=1e-5, rtol=1e-5)
+    np.testing.assert_array_equal(after[1, 3], state[1, 3])   # no row's
+    np.testing.assert_array_equal(after[0], state[0])
+
+
+def test_the_per_channel_chunk_kernel_equals_its_xla_spelling():
+    h, dk, dv = 2, 128, 128
+    q, k, v, g, beta, s0 = _channel_rows(25, 3, 128, h, dk, dv)
+    state = _stack(s0)
+    slots = jnp.asarray([3, 1, 0])
+    fresh = jnp.asarray([False, True, False])
+    row_len = jnp.asarray([128, 70, 0])
+    s_in = jnp.where(fresh[:, None, None, None], 0.0, state[1, slots])
+    want_o, want_s = gdn.xla_chunk(q, k, v, g, beta, s_in, row_len)
+    o, after = gdn.pallas_kda_chunk(q, k, v, g, beta, state, 1, slots,
+                                    fresh, row_len)
+    for r, n in enumerate([128, 70]):
+        np.testing.assert_allclose(o[r, :n], want_o[r, :n], atol=2e-4,
+                                   rtol=2e-4)
+    np.testing.assert_allclose(after[1, slots[:2]], want_s[:2], atol=2e-4,
+                               rtol=2e-4)
+    np.testing.assert_array_equal(after[1, 2], state[1, 2])   # no row's
+    np.testing.assert_array_equal(after[0], state[0])
+
+
+def test_the_per_channel_path_is_observed_and_the_scalar_one_is_as_it_was(
+        monkeypatch):
+    from paddle_tpu.profiler import metrics
+
+    assert gdn.kda_path(32, 128, 128) == "xla"      # the CPU
+    monkeypatch.setenv("PADDLE_TPU_TARGET_PLATFORM", "tpu")
+    assert gdn.kda_path(32, 128, 128) == "pallas"
+    assert gdn.kda_path(4, 16, 16) == "xla"         # keys of no whole tile
+    # the scalar gate's kernels take heads in pairs: Olmo-Hybrid's as ever,
+    # and never heads that lie alone
+    assert gdn.gdn_path(30, 96, 192) == "pallas"
+    assert gdn.gdn_path(32, 128, 128) == "xla"
+    monkeypatch.delenv("PADDLE_TPU_TARGET_PLATFORM")
+    assert gdn.pack_state(jnp.zeros((30, 96, 192))).shape == (15, 96, 384)
+    assert gdn.pack_state(jnp.zeros((32, 128, 128))).shape == (32, 128, 128)
+    before = metrics.registry().counter("gdn/step_calls{path=xla}").value
+    q, k, v, g, beta, s0 = _channel_rows(26, 2, 1, 2, 8, 16)
+    gdn.gdn_step_rows(q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0],
+                      _stack(s0), 1, jnp.arange(1, 3))
+    assert metrics.registry().counter(
+        "gdn/step_calls{path=xla}").value == before + 1

@@ -363,3 +363,87 @@ def test_custom_vjp_dispatch_combine_grads_match_autodiff():
         for a, b in zip(gn, gr):
             np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                        rtol=2e-4, atol=2e-5)
+
+
+# --- the group limit over sigmoid scores (DeepSeek-V3's noaux_tc; ISSUE 49) --
+def _sigmoid_route(x, w, top_k, **kw):
+    from paddle_tpu.distributed import moe
+
+    _, _, experts, gates, _, _ = moe._route(x, w, top_k, scoring="sigmoid",
+                                            **kw)
+    return np.stack([np.asarray(e) for e in experts], 1), \
+        np.stack([np.asarray(g) for g in gates], 1)
+
+
+def _plain_noaux_tc(score, bias, top_k, n_group, topk_group):
+    """The rule spelled with sorts, a token at a time: a group's score the
+    sum of its two largest biased scores, the best groups kept (a tie to the
+    lower group), the largest biased scores within them chosen (a tie to the
+    lower expert), the chosen *unbiased* scores the weights."""
+    t, e = score.shape
+    out_e, out_g = [], []
+    for s in score:
+        b = s + bias
+        groups = b.reshape(n_group, e // n_group)
+        two = np.sort(groups, -1)[:, -2:].sum(-1)
+        kept = np.argsort(-two, kind="stable")[:topk_group]
+        inside = np.where(np.isin(np.arange(e) // (e // n_group), kept), b,
+                          -np.inf)
+        chosen = np.argsort(-inside, kind="stable")[:top_k]
+        out_e.append(chosen)
+        out_g.append(s[chosen])
+    return np.stack(out_e), np.stack(out_g)
+
+
+@pytest.mark.parametrize("case", ["drawn", "biased", "tied", "one_group",
+                                  "every_group"])
+def test_the_sigmoid_group_limit_is_the_plain_top_k_spelling(case):
+    rng = np.random.default_rng({"drawn": 3, "biased": 4, "tied": 5,
+                                 "one_group": 6, "every_group": 7}[case])
+    t, h, e, top_k, n_group, topk_group = 48, 16, 32, 4, 8, 3
+    x = jnp.asarray(rng.normal(size=(t, h)), jnp.float32)
+    w = rng.normal(size=(h, e)).astype(np.float32)
+    bias = np.zeros(e, np.float32)
+    if case == "biased":
+        bias = rng.normal(size=e).astype(np.float32) * 0.3
+    if case == "tied":
+        # experts in pairs of equal columns and equal bias: every score ties
+        # with its neighbour's, within a group and across two groups' sums
+        w[:, 1::2] = w[:, ::2]
+        w[:, 4:8] = w[:, :4]
+        bias[:8] = 0.1
+    if case == "one_group":
+        n_group, topk_group = 1, 1
+    if case == "every_group":
+        topk_group = n_group
+    experts, gates = _sigmoid_route(x, jnp.asarray(w), top_k,
+                                    select_bias=jnp.asarray(bias),
+                                    n_group=n_group, topk_group=topk_group)
+    score = np.asarray(jax.nn.sigmoid(jnp.dot(
+        jnp.asarray(w).T, x.T, preferred_element_type=jnp.float32))).T
+    want_e, want_g = _plain_noaux_tc(score, bias, top_k, n_group, topk_group)
+    np.testing.assert_array_equal(experts, want_e)
+    np.testing.assert_allclose(gates, want_g, rtol=1e-6)
+    if case in ("one_group", "every_group"):
+        # no limit: the plain sigmoid top-k its callers had before
+        plain, _ = _sigmoid_route(x, jnp.asarray(w), top_k,
+                                  select_bias=jnp.asarray(bias))
+        np.testing.assert_array_equal(experts, plain)
+    if case == "drawn":
+        plain, _ = _sigmoid_route(x, jnp.asarray(w), top_k)
+        assert (plain != experts).any()     # the limit binds for some token
+        assert all(len(set(r // (e // n_group))) <= topk_group
+                   for r in experts)
+
+
+def test_kept_groups_at_its_default_is_the_softmax_routers_program():
+    """``best=1`` (DeepSeek-V2's) traces what it traced before the sum of the
+    two best was added: one reshape, one max, the rounds."""
+    from paddle_tpu.distributed import moe
+
+    scores = jax.ShapeDtypeStruct((24, 16), jnp.float32)
+    one = jax.make_jaxpr(lambda s: moe.kept_groups(s, 6, 2))(scores)
+    two = jax.make_jaxpr(lambda s: moe.kept_groups(s, 6, 2, best=2))(scores)
+    assert "argmax" in str(one) and len(one.eqns) < len(two.eqns)
+    assert str(one) == str(jax.make_jaxpr(
+        lambda s: moe.kept_groups(s, 6, 2, best=1))(scores))

@@ -764,3 +764,73 @@ def test_tpu_compile_the_hybrid_models_forward(monkeypatch):
         "the donated pools and states are not aliased"
     assert ma.temp_size_in_bytes < pools.state.size * 4 / 3, \
         "a layer's states are copied"
+
+
+def test_tpu_compile_the_ling3_models_forward(monkeypatch):
+    """ISSUE 49: Ling-3.0-flash's tick forward (``models/ling3.
+    ling3_ragged_apply``: a float32 state of 32 heads of 128 x 128 a slot,
+    each head alone on whole tiles, beside latent rows of 576 in pages of
+    128; a 512-way sigmoid router limited to 4 of 8 groups with 128 experts
+    held) compiles for the v5e from a ``LazyGuard`` model at the published
+    widths of the dense layer, two KDA layers and the MLA layer, the cell's
+    64 decode rows and its chunk row of 256: the served per-channel rule's
+    two kernels, the pass before them, the dense latent kernel and the
+    grouped matmuls are in the program, and the donated pools are aliased
+    and no layer's states are copied."""
+    import jax.numpy as jnp
+
+    import paddle_tpu as paddle
+    from paddle_tpu.models.ling3 import (Ling3, Ling3Config,
+                                         ling3_ragged_apply)
+    from paddle_tpu.models.tick import state_drawer
+    from paddle_tpu.serving.paged_cache import LatentPools, StatePools
+
+    dev = _tpu_topology_devices()[0]
+    cfg = Ling3Config(num_hidden_layers=4, layer_ids=(1, 3, 4, 5),
+                      vocab_size=1024, experts_held=(0, 128))
+    assert cfg.layer_kinds == ("kda", "kda", "kda", "mla")
+    with paddle.LazyGuard():
+        net = Ling3(cfg)
+    net.bfloat16()
+    state = jax.eval_shape(state_drawer(net),
+                           jax.ShapeDtypeStruct((2,), jnp.uint32))
+    ns, ps, nps, w = 64, 128, 133, 256
+    pools = jax.eval_shape(lambda: StatePools.zeros(
+        net.cache_spec(), ns * nps + 1, ps, ns, jnp.bfloat16))
+    assert isinstance(pools.kv, LatentPools)
+    assert pools.kv.latent.shape == (1, ns * nps + 1, 576, ps)
+    assert pools.state.shape == (3, ns + 1, 32, 128, 128)
+    assert pools.conv.shape == (3, 3, 80, 12288)
+    nt = ns + w
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32)
+
+    args = (state[0], state[1], pools, i32(nt), i32(nt), i32(nt),
+            (i32(ns + 1, nps), i32(ns + 1)), i32(ns + 1), i32(ns + 1),
+            i32(ns))
+    args = jax.tree_util.tree_map(
+        lambda a: _on_tpu(dev, a.shape, a.dtype), args)
+
+    def forward(*a):
+        return ling3_ragged_apply(cfg, *a, decode_rows=ns, chunk_width=w)
+
+    monkeypatch.setenv("PADDLE_TPU_TARGET_PLATFORM", "tpu")
+    with jax.default_matmul_precision("default"):
+        compiled = jax.jit(forward, donate_argnums=2).lower(*args).compile()
+    text = compiled.as_text()
+    assert len(re.findall(r"%kda_step[\w.\-]* = ", text)) == 3
+    assert len(re.findall(r"%kda_chunk[\w.\-]* = ", text)) == 3
+    assert len(re.findall(r"%gdn_prep_step[\w.\-]* = ", text)) == 3
+    assert len(re.findall(r"%gdn_prep_chunk[\w.\-]* = ", text)) == 3
+    assert not re.findall(r"%gdn_step[\w.\-]* = ", text)
+    assert len(re.findall(r"%latent_attn[\w.\-]* = ", text)) == 2
+    assert re.findall(r"%moe_gmm[\w.\-]* = ", text)
+    assert "remat_compressed" not in text
+    ma = compiled.memory_analysis()
+    pool_bytes = sum(a.size * a.dtype.itemsize
+                     for a in jax.tree_util.tree_leaves(pools))
+    assert ma.alias_size_in_bytes >= pool_bytes, \
+        "the donated pools and states are not aliased"
+    assert ma.temp_size_in_bytes < pools.state.size * 4 / 3, \
+        "a layer's states are copied"

@@ -1,4 +1,4 @@
-"""The lowered serving ticks of the six served configurations, for a TPU v5e,
+"""The lowered serving ticks of the served configurations, for a TPU v5e,
 without the chip: the proof that a refactor of the engine <-> model <-> cache
 seam left every cell's program as it was (ISSUE 43).
 
@@ -10,7 +10,8 @@ change's (``git archive <commit> | tar -x -C <dir>``), with ``JAX_PLATFORMS=cpu`
 an engine is built and ticked on the CPU at the rows of ``gpt3-1.3b-serve`` and
 ``ouro-2.6b-serve``, at a tiny width with a draft model (both ticks), for a
 dots3 and a DeepSeek-V2 model at the published head counts and latent widths,
-and for an Olmo-Hybrid model at the published head sizes (PR 44);
+and for an Olmo-Hybrid (PR 44) and a Ling-3.0 model (PR 49) at the published
+head sizes;
 each tick is lowered again from the avals of its first dispatch as a program
 traced for the TPU (the attention kernels inside), and its StableHLO text,
 which carries no locations, is written to ``<out_dir>/<name>.<site>.txt``,
@@ -207,3 +208,32 @@ if OlmoHybrid is not None:
         eng.step()
     eng.drain(0)
     lower("olmoh", eng)
+
+# Ling-3.0-flash at its published head sizes (4 KDA heads of 128 x 128, a
+# decay a channel, beside 16 latent heads over rows of 128 + 64; 16 experts
+# in 4 groups under the sigmoid group limit): a state a slot beside latent
+# pages in one pool. A tree without the model (before PR 49) writes no file.
+try:
+    from paddle_tpu.models.ling3 import Ling3, Ling3Config
+except ImportError:
+    Ling3 = None
+if Ling3 is not None:
+    paddle.seed(0)
+    with paddle.LazyGuard():
+        ling = Ling3(Ling3Config(
+            vocab_size=512, hidden_size=512, intermediate_size=512,
+            moe_intermediate_size=128,
+            moe_shared_expert_intermediate_size=128, num_hidden_layers=4,
+            layer_ids=(1, 3, 4, 5), num_attention_heads=16,
+            kv_lora_rank=128, num_experts=16, n_group=4, topk_group=2,
+            num_experts_per_tok=4, experts_held=(4, 8),
+            select_bias_range=0.02, max_position_embeddings=2048))
+    ling.bfloat16()
+    eng = ServingEngine(ling, ServingConfig(
+        num_slots=8, page_size=128, pages_per_slot=8, prefill_chunk=256,
+        prefix_cache=False))
+    eng.submit(np.arange(300, dtype=np.int32) % 512, 2)
+    for _ in range(3):
+        eng.step()
+    eng.drain(0)
+    lower("ling3", eng)
