@@ -21,7 +21,10 @@ Olmo-Hybrid's served tick as ``perfbench/layer_metrics/_olmoh_trace.py`` cuts
 it (``gdn_prep``, ``gdn_step``, ``gdn_chunk``, ``attn``, ``head_sample``) is
 listed after a ``--trace 1`` run of ``serve-olmo-hybrid-gen-backlog``,
 milliseconds a tick, each operation with the end of its scope path (PR 46
-read ``gdn_prep`` that way before writing ``ops/gdn.py``'s pass).
+read ``gdn_prep`` that way before writing ``ops/gdn.py``'s pass); another
+served cell's tick by its own helper's parts, the helper named before a
+colon (``ling3:experts``, ``dots3:experts``, ``dsv2:experts``: PR 50 listed
+what lies around the grouped products that way).
 """
 import json
 import os
@@ -53,7 +56,8 @@ def main():
     if doc is None:
         raise SystemExit("no trace in .perfbench_trace: run a cell with "
                          "--trace 1 first")
-    ot = loader.load_module("layer_metrics", "_olmoh_trace")
+    helper, _, part = part.rpartition(":")
+    ot = loader.load_module("layer_metrics", f"_{helper or 'olmoh'}_trace")
     word = "tick" if any(pt.program_runs(p, "tick")
                          for p in tracered.device_planes(doc)) else "step"
     part_of, title = (ot.part, part) if word == "tick" \

@@ -487,13 +487,15 @@ def phase_experts(t: int, h: int, f: int, e: int, k: int) -> None:
 
     t0 = time.perf_counter()
     for forced in (False, True):
+        tiles0 = gmm_tiles()
         r = check_dropless(t, h, f, e, k, jnp.bfloat16, forced)
         path = r.pop("path")
         check(path == "pallas", f"experts: the grouped products took the "
               f"{path} path on one TPU device at widths that tile")
+        tiles = check_gmm_tiles(tiles0, h, f, "experts")
         say("experts", f"dropless_moe T={t} h={h} {e} experts of {f}, "
             f"top-{k}, {'one expert forced' if forced else 'balanced'}, "
-            f"grouped products through {path}: "
+            f"grouped products through {path} in tiles {sorted(tiles)}: "
             f"load max/mean {r.pop('load'):.2f}, dropped 0; vs float32 "
             "reference " + " ".join(f"{n}={v:.2e}" for n, v in r.items())
             + f" (tol {TOL_EXPERTS_FWD}/{TOL_EXPERTS_BWD})")
@@ -638,6 +640,38 @@ def attn_calls(counter: str = "serving/attn_calls") -> dict:
 
     return {p: registry().counter("%s{path=%s}" % (counter, p)).value
             for p in ("pallas", "xla")}
+
+
+def gmm_tiles() -> dict:
+    """``moe/grouped_matmul_tiles{tile=}``: the grouped products that took
+    the kernel, by the tile (rows, contraction, columns a grid step) they
+    walk a group's matrix in; counted on the host while a layer is traced."""
+    from paddle_tpu.profiler import registry
+
+    head = "moe/grouped_matmul_tiles{tile="
+    return {tuple(int(x) for x in name[len(head):-1].split("x")): m["value"]
+            for name, m in registry().snapshot().items()
+            if name.startswith(head)}
+
+
+def check_gmm_tiles(before: dict, h: int, f: int, what: str) -> dict:
+    """The expert layers of ``[h, f]`` matrices traced since ``before``
+    (``gmm_tiles()`` then) counted each of their grouped products that took
+    the kernel (none where they went by ``ragged_dot``: off the chip, or
+    widths that do not tile) under one tile, the rule's, and that tile
+    holds the whole contraction: one grid step a visit of a group."""
+    from paddle_tpu.ops.grouped_matmul import kernel_path, tile_for
+
+    took = {t: n - before.get(t, 0) for t, n in gmm_tiles().items()
+            if n > before.get(t, 0)}
+    want = {kn: tile_for(128, *kn) for kn in ((h, f), (f, h))
+            if kernel_path(128, *kn) == "pallas"}
+    check(set(took) == set(want.values())
+          and all(t[1] == k for (k, _), t in want.items()),
+          f"{what}: the grouped products of [{h}, {f}] experts counted the "
+          f"tiles {took}, not {sorted(want.values())} with the whole "
+          "contraction a step (moe/grouped_matmul_tiles)")
+    return took
 
 
 def check_attn_path(before: dict) -> str:
@@ -1022,6 +1056,7 @@ def phase_latent(cfg, num_slots: int, page_size: int, pages_per_slot: int,
         errs = {k: max(v, errs.get(k, 0.0)) for k, v in got.items()}
     reg = registry()
     calls0 = attn_calls("serving/latent_attn_calls")
+    tiles0 = gmm_tiles()
     freed0 = reg.counter("serving/window_pages_freed").value
     paddle.seed(0)
     with paddle.LazyGuard():
@@ -1076,9 +1111,10 @@ def phase_latent(cfg, num_slots: int, page_size: int, pages_per_slot: int,
     check(differ <= TOL_LATENT_SELECTED,
           f"a selected set differs from the reference's in {differ:.3f} of "
           f"it, allowed {TOL_LATENT_SELECTED}")
-    paths = {k: v for k, v in reg.snapshot().items()
-             if k.startswith("moe/grouped_matmul_calls")} \
-        if hasattr(reg, "snapshot") else {}
+    paths = {k: v["value"] for k, v in reg.snapshot().items()
+             if k.startswith("moe/grouped_matmul_calls")}
+    paths["tiles"] = check_gmm_tiles(
+        tiles0, cfg.hidden_size, cfg.moe_intermediate_size, "latent")
     tick = {p: n - calls0[p] for p, n in
             attn_calls("serving/latent_attn_calls").items() if n > calls0[p]}
     check(tick, "no tick counted its selected latent attention")
@@ -1092,7 +1128,7 @@ def phase_latent(cfg, num_slots: int, page_size: int, pages_per_slot: int,
         f"set "
         f"differs by at most {differ:.3f} (allowed {TOL_LATENT_SELECTED}), "
         f"{freed:.0f} windowed pages given back; weights {_gb(weights)}"
-        + (f"; {paths}" if paths else ""))
+        f"; {paths}")
     return {"worst": worst, "median": median, "differ": differ,
             "freed": freed, "weights_bytes": weights, "tick_paths": tick,
             **errs}
@@ -1176,6 +1212,7 @@ def phase_dsv2(cfg, num_slots: int, page_size: int, pages_per_slot: int,
     reg = registry()
     counter = "serving/latent_attn_calls{path=%s,kind=dense}"
     calls0 = {p: reg.counter(counter % p).value for p in ("pallas", "xla")}
+    tiles0 = gmm_tiles()
     paddle.seed(0)
     with paddle.LazyGuard():
         net = DeepseekV2(cfg)
@@ -1228,7 +1265,8 @@ def phase_dsv2(cfg, num_slots: int, page_size: int, pages_per_slot: int,
         f"({cfg.num_hidden_layers} layers, two chunks a tick): shortfall "
         f"{median:.4f} at the median (allowed {TOL_LATENT_SHORTFALL}), "
         f"{worst:.4f} at the worst (allowed {TOL_LATENT_WORST}); weights "
-        f"{_gb(weights)}")
+        f"{_gb(weights)}; grouped products' tiles " + str(check_gmm_tiles(
+            tiles0, cfg.hidden_size, cfg.moe_intermediate_size, "dsv2")))
     return {"worst": worst, "median": median, "weights_bytes": weights,
             "tick_paths": tick, **errs}
 
@@ -1481,7 +1519,8 @@ def phase_ling(cfg, num_slots: int, page_size: int, pages_per_slot: int,
 
     import paddle_tpu as paddle
     from paddle_tpu.models import ling3_reference as ref
-    from paddle_tpu.models.ling3 import Ling3
+    from paddle_tpu.models.ling3 import Ling3, Ling3Config
+    from paddle_tpu.ops.grouped_matmul import tile_for
 
     errs = check_gdn_ops(*ops_shape, channel=True)
     path, prep = errs.pop("path"), errs.pop("prep_path")
@@ -1506,10 +1545,26 @@ def phase_ling(cfg, num_slots: int, page_size: int, pages_per_slot: int,
     forward.ref = ref
     counters = dict(GDN_COUNTERS,
                     latent="serving/latent_attn_calls{path=%s,kind=dense}")
-    return {**serve_against_reference(
+    tiles0 = gmm_tiles()
+    out = serve_against_reference(
         "ling", net, (num_slots, page_size, pages_per_slot, chunk),
         requests, forward, counters, want_path,
-        "a state a slot beside latent pages"), **errs}
+        "a state a slot beside latent pages")
+    # the routed experts' products: one tile each, the whole contraction a
+    # step, at this model's widths and at the published ones (a function of
+    # the sides: nothing is run for those)
+    tiles = check_gmm_tiles(tiles0, cfg.hidden_size,
+                            cfg.moe_intermediate_size, "ling")
+    wide = Ling3Config()
+    h, f = wide.hidden_size, wide.moe_intermediate_size
+    gate_up, down = tile_for(1024, h, f), tile_for(1024, f, h)
+    check(gate_up == (128, h, f) and down == (128, f, h),
+          f"an expert of [{h}, {f}] is not one grid step a visit: "
+          f"{gate_up}, {down}")
+    say("ling", f"the ticks' grouped products counted the tiles {tiles} "
+        f"(moe/grouped_matmul_tiles); at [{h}, {f}] the rule gives "
+        f"{gate_up} and {down}")
+    return {**out, **errs, "gmm_tiles": tiles}
 
 
 # ---------------------------------------------------------------------------

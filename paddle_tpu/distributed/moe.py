@@ -262,12 +262,19 @@ def publish_expert_load(stats) -> None:
 def _expert_product(rows, w, group_sizes):
     """One of the three grouped products, counted at trace time under the
     path it takes (``moe/grouped_matmul_calls{path=pallas|xla}`` in the
-    profiler's registry: a count on the host, nothing in the program)."""
+    profiler's registry: a count on the host, nothing in the program) and,
+    where that is the kernel, under the tile it walks a group's matrix in
+    (``moe/grouped_matmul_tiles{tile=128x2560x768}``: rows, contraction
+    and columns a grid step, ``ops.grouped_matmul.tile_for``)."""
     from ..profiler import metrics
 
-    path = _gmm.kernel_path(rows.shape[0], rows.shape[1], w.shape[2])
-    metrics.registry().counter(
-        "moe/grouped_matmul_calls{path=%s}" % path).add(1)
+    shape = rows.shape[0], rows.shape[1], w.shape[2]
+    path = _gmm.kernel_path(*shape)
+    reg = metrics.registry()
+    reg.counter("moe/grouped_matmul_calls{path=%s}" % path).add(1)
+    if path == "pallas":
+        reg.counter("moe/grouped_matmul_tiles{tile=%dx%dx%d}"
+                    % _gmm.tile_for(*shape)).add(1)
     return _gmm.grouped_matmul(rows, w.astype(rows.dtype), group_sizes)
 
 

@@ -14,7 +14,10 @@ the one of jax's ``pallas.ops.tpu.megablox``: row tiles are visited group
 by group, a tile that two groups share once by each, and scalar-prefetched
 tables say which group and which tile a grid step works on. Here the
 tables are three gathers, the kernels mask only the tiles a group's edge
-cuts, and a whole contraction fits one step where the tile says so.
+cuts, and a grid step holds a group's matrix over the whole contraction
+(``tile_for``): the rows' block then stays in VMEM from visit to visit and
+a visit fetches ``[k, tn]`` weights in one copy, which is what a served
+tick of one or two rows an expert is made of.
 
 Which path a call takes is decided by what the trace can observe
 (``kernel_path``): the platform compiled for, whether a multi-device auto
@@ -40,27 +43,42 @@ __all__ = ["grouped_matmul", "kernel_path", "pallas_grouped_matmul",
 # the kernels keep whole weight tiles resident: more than Mosaic's default
 # 16 MiB of scoped VMEM, well inside the v5e's 128 MiB
 _VMEM_LIMIT = 64 * 1024 * 1024
+# the weights of a group's matrix that one grid step holds, at most: 4 MB of
+# bf16 a buffer. Two buffers of it, two of the rows' block [128, k], the
+# output block and its float32 result fit ``_VMEM_LIMIT`` many times over;
+# ``moe_tgmm`` holds a tile as two output buffers and a float32 sum, 16 MB
+_TILE_WEIGHTS = 2 ** 21
 
 
-def _largest_divisor(x: int, sizes) -> Optional[int]:
-    return next((s for s in sizes if x % s == 0), None)
+def _divisors(x: int):
+    """The multiples of 128 that divide ``x`` (one itself), largest first."""
+    return [d for d in range(x, 0, -128) if x % d == 0]
 
 
 def tile_for(m: int, k: int, n: int) -> Optional[Tuple[int, int, int]]:
     """(tm, tk, tn) for a product of ``m`` rows, contraction ``k`` and
-    ``n`` columns, or None where the shape does not tile. Found on the
-    v5e for OLMoE's three products and their backward forms (PERF.md
-    section 6, PR 27): 128 rows, because a tile that a group's edge cuts
-    is computed once for each group it holds and 64 groups cut up to 63
-    tiles; the whole contraction and all columns in one step where 2 M
-    weights fit (a group's matrix is then fetched once), else the columns
-    first."""
-    sizes = (2048, 1024, 512, 256, 128)
-    tn = _largest_divisor(n, sizes)
-    if m % 128 or tn is None:
+    ``n`` columns, or None where the shape does not tile (a side that is
+    no multiple of 128). A function of the three sides and nothing else.
+
+    128 rows, because a tile that a group's edge cuts is computed once for
+    each group it holds and 64 groups cut up to 63 tiles (found on the v5e
+    for OLMoE's products, PERF.md section 6, PR 27). **The whole
+    contraction in one step** (``tk == k``: no accumulator, and the rows'
+    block index does not turn with the steps, so the rows are fetched once
+    a row tile and not once a step) with ``tn`` the largest divisor of
+    ``n`` whose ``[k, tn]`` weights are within ``_TILE_WEIGHTS``; only a
+    contraction of which not even 128 columns fit is cut, at its largest
+    divisor that leaves 128. The sides' divisors are all multiples of 128,
+    not powers of two: an expert of ``[2560, 768]`` (Ling-3.0-flash) is one
+    step a visit where the powers of two made fifteen of 256 KB, each as
+    long in its fixed cost as in its copy (PERF.md section 6, PR 50;
+    ``benchmarks/grouped_matmul_bench.py`` is where the budget was read).
+    OLMoE's ``[2048, 1024]`` is the whole matrix, as it was."""
+    if min(m, k, n) <= 0 or m % 128 or k % 128 or n % 128:
         return None
-    tk = _largest_divisor(k, [s for s in sizes if s * tn <= 2 ** 21])
-    return None if tk is None else (128, tk, tn)
+    tk = next(d for d in _divisors(k) if d * 128 <= _TILE_WEIGHTS)
+    tn = next(d for d in _divisors(n) if tk * d <= _TILE_WEIGHTS)
+    return 128, tk, tn
 
 
 def kernel_path(m: int, k: int, n: int) -> str:

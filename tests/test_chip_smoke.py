@@ -334,3 +334,34 @@ def test_ling_rehearsal():
     assert out["prep_step"] == out["prep_chunk"] == 0   # the spelling itself
     assert out["median"] <= out["worst"] <= chip_smoke.TOL_GDN_WORST
     assert out["weights_bytes"] == 2 * cfg.num_params()
+
+
+@pytest.mark.parametrize("counted,fault", [
+    ({(128, 512, 128): 12, (128, 128, 512): 6}, None),
+    # a product that went by the kernel and counted no tile
+    ({(128, 512, 128): 12}, "not"),
+    # a contraction cut into steps: the powers of two's walk
+    ({(128, 512, 128): 12, (128, 128, 512): 6, (128, 256, 128): 1}, "not"),
+    # off the chip nothing takes the kernel, and nothing may be counted
+    ({}, "cpu"), ({(128, 512, 128): 1}, "cpu"),
+])
+def test_the_tile_check_holds_the_products_to_one_whole_contraction_each(
+        monkeypatch, counted, fault):
+    """``check_gmm_tiles``: what phases ``experts``, ``latent``, ``dsv2``
+    and ``ling`` hold ``moe/grouped_matmul_tiles{tile=}`` to."""
+    from paddle_tpu.profiler import registry
+
+    if fault != "cpu":
+        monkeypatch.setenv("PADDLE_TPU_TARGET_PLATFORM", "tpu")
+    registry().counter(
+        "moe/grouped_matmul_tiles{tile=128x2048x1024}").add(2)  # an earlier
+    before = chip_smoke.gmm_tiles()                             # phase's
+    assert before[128, 2048, 1024] >= 2
+    for tile, n in counted.items():
+        registry().counter(
+            "moe/grouped_matmul_tiles{tile=%dx%dx%d}" % tile).add(n)
+    if fault is None or (fault == "cpu" and not counted):
+        assert chip_smoke.check_gmm_tiles(before, 512, 128, "x") == counted
+    else:
+        with pytest.raises(chip_smoke.SmokeFailure, match="whole"):
+            chip_smoke.check_gmm_tiles(before, 512, 128, "x")

@@ -467,20 +467,24 @@ def test_the_other_share_is_another_model(net, tokens):
 @pytest.mark.parametrize("shape,width,tile", [
     # train-solar-open2-1chip: 8,192 tokens, 8 of 320 held, products of
     # [H 4096, F 1280]; train-olmoe-1chip-4k: 4,096 x 8 rows, 64 experts of
-    # [2048, 1024]; the serving tick of dots3-note-prev: 268 tokens
-    (("solar", 8192, 8, 8, 320, 4096, 1280), 2560, (128, 2048, 256)),
+    # [2048, 1024]; the serving tick of dots3-note-prev: 268 tokens; the
+    # one of Ling-3.0-flash: 64 decode rows beside a chunk row of 256
+    (("solar", 8192, 8, 8, 320, 4096, 1280), 2560, (128, 4096, 256)),
     (("olmoe", 4096, 8, 64, 64, 2048, 1024), 32768, (128, 2048, 1024)),
-    (("dots3-tick", 268, 8, 32, 256, 5120, 1536), 512, (128, 1024, 512)),
+    (("dots3-tick", 268, 8, 32, 256, 5120, 1536), 512, (128, 5120, 384)),
+    (("ling3-tick", 320, 8, 128, 512, 2560, 768), 1024, (128, 2560, 768)),
 ])
 def test_the_grouped_products_pick_the_tiles_they_picked(shape, width, tile):
-    """A held expert of a serving tick gets ~8 rows: its window is the
-    rows there are, rounded up to the kernels' 128-row tiles once (not a
-    tile a group), and the training shapes keep the windows and tiles
-    they had."""
+    """A held expert of a serving tick gets ~8 rows (Ling's one or two):
+    its window is the rows there are, rounded up to the kernels' 128-row
+    tiles once (not a tile a group), and the training shapes keep the
+    windows they had. The tiles are the whole contraction a step since
+    PR 50 (OLMoE's were: the whole matrix, as before); Ling's expert is
+    one step a visit."""
     _, t, top_k, held, e, h, f = shape
     assert held_window_rows(t, top_k, held, e) == width
     assert gmm.tile_for(width, h, f) == tile
-    assert gmm.tile_for(width, f, h) is not None
+    assert gmm.tile_for(width, f, h)[:2] == (128, f)
 
 
 # --- the vocabulary in eighths ---------------------------------------------

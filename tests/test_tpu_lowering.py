@@ -464,6 +464,49 @@ def test_tpu_compile_dropless_moe_at_olmoe_widths(monkeypatch, mesh_devices):
     assert needed <= compiled.cost_analysis()["flops"] < 1.1 * needed
 
 
+@pytest.mark.parametrize("cell,m,h,f,e,grad", [
+    ("serve-ling3-longgen-backlog", 1024, 2560, 768, 128, False),
+    ("serve-dots3-longdoc-backlog", 512, 5120, 1536, 32, False),
+    ("train-solar-open2-1chip", 2560, 4096, 1280, 8, True),
+])
+def test_tpu_compile_the_grouped_products_at_the_cells_tiles(monkeypatch,
+                                                             cell, m, h, f,
+                                                             e, grad):
+    """PR 50: ``tile_for`` gives a grid step the whole contraction and any
+    multiple of 128 columns that fits its budget; Mosaic takes those blocks
+    at the cells' widths (Ling's whole ``[2560, 768]`` expert a step, dots3's
+    ``[5120, 384]``, Solar's ``[4096, 256]`` and, towards the weights,
+    ``moe_tgmm``'s ``[4096, 256]`` and ``[1280, 1024]`` blocks with their
+    float32 sums) inside the kernels' VMEM limit."""
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+
+    from paddle_tpu.ops import grouped_matmul as gm
+
+    monkeypatch.setenv("PADDLE_TPU_TARGET_PLATFORM", "tpu")
+    one = SingleDeviceSharding(_tpu_topology_devices()[0])
+
+    def sds(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    def product(a, b, sizes):
+        return jnp.sum(gm.pallas_grouped_matmul(a, b, sizes)
+                       .astype(jnp.float32))
+
+    for k, n in ((h, f), (f, h)):
+        assert gm.tile_for(m, k, n)[1] == k
+        fn = jax.grad(product, (0, 1)) if grad else product
+        with jax.default_matmul_precision("default"):
+            text = jax.jit(fn).lower(
+                sds((m, k)), sds((e, k, n)),
+                sds((e,), jnp.int32)).compile().as_text()
+        # differentiated, the sum's forward product is dead: d rows and
+        # d weights are left, named ``transpose_jvp_moe_...``
+        assert len(re.findall(r"%[\w.\-]*moe_gmm[\w.\-]* = ", text)) == 1
+        assert len(re.findall(r"%[\w.\-]*moe_tgmm[\w.\-]* = ",
+                              text)) == int(grad)
+
+
 def test_tpu_compile_olmoe_step_of_the_cell(monkeypatch):
     """The step of ``train-olmoe-1chip-4k`` (OLMoE at published widths,
     depth 2, 8 micro-batches of one sequence of 4,096, bf16 parameters and
