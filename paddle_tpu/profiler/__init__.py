@@ -37,6 +37,17 @@ call is open, else to ``eager``: the inventory's ``trace_s`` /
 counters ``compile/*`` and one ``compile`` event a program. Nothing of
 it runs on a tick or a step.
 
+**Every tick, on the engine's own record** (ISSUE 51; always on, because
+the window it must see is the one in which ``scope`` is a no-op):
+``ServingEngine.tick_log()`` / ``profiler.tick_logs()`` (ticklog.py): one
+row a ``step()`` with the ``pt:step/*`` boundaries on ``perf_counter_ns``,
+the interval's parts, the tick's arrival and the engine thread's own
+counters (proc.py: CPU time, run-queue wait, involuntary switches, major
+faults; the collector's time from one ``gc.callbacks`` entry that also
+feeds ``proc/gc_ms{gen=}`` and ``proc/gc_collections{gen=}``). A stall of
+the loop is one ``hold`` event and adds to ``serving/holds{kind=}``,
+``serving/hold_ms{kind=}`` and ``serving/hold_lost_ms``.
+
 Three pillars, one switch (``profiler.enable()``):
 
 1. **Tracing** (``trace.py``): ``profiler.scope("name")`` /
@@ -106,7 +117,7 @@ Quick use::
 from __future__ import annotations
 
 from . import device_trace, events, instrument, metrics  # noqa: F401
-from . import recompile, sink, trace, xla_stats  # noqa: F401
+from . import proc, recompile, sink, ticklog, trace, xla_stats  # noqa: F401
 from . import disttrace, live, sketch  # noqa: F401
 from .live import AlertRule, LiveAggregator, default_rules  # noqa: F401
 from .sketch import QuantileSketch  # noqa: F401
@@ -125,6 +136,7 @@ from .instrument import (collective_stats, device_memory_stats,  # noqa: F401
                          tokens_in_batch)
 from .metrics import (Counter, Gauge, Histogram, MetricsRegistry,  # noqa: F401
                       registry)
+from .ticklog import TickLog, tick_logs  # noqa: F401
 from .recompile import (mark_trace, retraces, suppressed,  # noqa: F401
                         trace_counts, unique_site, watch)
 from .sink import (MetricsSink, active_sink, disable_sink,  # noqa: F401
@@ -162,7 +174,12 @@ __all__ = [
     "trace_id", "clock_state", "set_clock_state", "ClockSync",
     # live mesh telemetry plane (sketch.py / live.py, ISSUE 16)
     "QuantileSketch", "LiveAggregator", "AlertRule", "default_rules",
+    # the engine's record of every tick and its holds (ticklog.py, proc.py)
+    "TickLog", "tick_logs",
 ]
+
+# the collector on the record, from the package's import on (proc.py)
+proc.install()
 
 
 def enable(trace_dir=None, reset: bool = True) -> None:
@@ -217,6 +234,7 @@ def summary(aggregate: bool = False) -> dict:
     bounded ring — a truncated timeline is a fact about THIS process,
     not just the sink's file) and ``sink`` health (flush count, failed
     flushes, last error)."""
+    proc.publish()      # the collector's time, onto its counters
     reg = metrics.registry()
     snap = reg.aggregate() if aggregate else reg.snapshot()
     window_s = trace.enabled_window_s()

@@ -105,6 +105,16 @@ __all__ = [
 #: compiled or fetched from the persistent cache, recompile.py's
 #: listener — attrs ``site``/``trace_s``/``lower_s``/``backend_s``/
 #: ``cache_fetch_s``/``cache_hit``; one a compilation).
+#: The tick log (ISSUE 51; ticklog.py) adds ``hold``: the serving loop was
+#: held longer than its own running baseline allows. Attrs ``eng``,
+#: ``tick``, ``t0_ns``, ``ms``, ``excess_ms``, ``lost_ms`` (what the device
+#: lost; None where no later arrival sized it), ``side`` (``host`` or
+#: ``device``: an event's own ``kind`` is taken), ``where`` (the part, or
+#: ``device_wait``), ``starved``, ``cpu_ms``, ``proc_cpu_ms``, ``runq_ms``,
+#: ``gc_ms``,
+#: ``nivcsw``, ``majflt``, ``rows``, ``chunk_tokens``, ``unexplained_ms``
+#: and, where the kernel keeps it, ``psi_some_ms`` / ``psi_age_ms``. One a
+#: hold, zero to a few a minute: never per tick.
 EVENT_KINDS = (
     "submit", "admit", "prefix_hit", "cow_copy", "chunk",
     "first_token", "draft", "verify", "accept",
@@ -113,7 +123,7 @@ EVENT_KINDS = (
     "vote_window_expiry",
     "member_join", "member_leave", "redispatch", "cancel",
     "preempt", "requeue", "finish", "rollback", "alert",
-    "phase", "compile",
+    "phase", "compile", "hold",
 )
 
 

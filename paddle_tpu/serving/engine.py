@@ -115,7 +115,11 @@ own outputs, set when a tick is drained),
 ``serving/drain_waited`` / ``serving/drain_ready`` (drained ticks whose
 tokens the host had to wait for / found ready),
 ``serving/tick_turnaround_ms`` (histogram, one observation a drained
-tick: dispatch to tokens on the host), ``serving/submit_ms``
+tick: dispatch to tokens on the host; both fed from the tick log's clock
+reads), ``serving/holds{kind=}`` / ``serving/hold_ms{kind=}`` /
+``serving/hold_lost_ms`` (the stalls of the serving loop the tick log
+named: ``tick_log()``, profiler/ticklog.py, one ``hold`` event each),
+``serving/submit_ms``
 (histogram: host time of each ``submit()``), ``serving/prefix_lookups``,
 ``serving/prefix_hit_tokens``, ``serving/mixed_rows`` (+ the
 ``_decode``/``_prefill`` split: rows of each kind in the last unified
@@ -164,6 +168,7 @@ from ..ops.paged_attention import live_block_share
 from ..profiler import events as _events
 from ..profiler import recompile as _recompile
 from ..profiler import registry as _registry
+from ..profiler import ticklog as _ticklog
 from ..profiler import trace as _ptrace
 from .paged_cache import Pools, page_pool
 from .sched import SCHED_POLICIES, ChunkScheduler, SpecKController
@@ -331,7 +336,9 @@ class _Inflight(NamedTuple):
     #: sampled row's query stood at
     aux: dict
     positions: np.ndarray
-    dispatch_t: float
+    #: the tick log's row of this tick, and its ``t_dispatch``
+    row: int
+    dispatch_ns: int
 
 
 #: one selected-but-not-yet-dispatched prompt chunk of the unified tick
@@ -381,6 +388,10 @@ class ServingEngine:
         # process index folded in: ids stay unique when rank-tagged
         # event streams from N processes are merged (ISSUE 13)
         self._eng_id = (_proc_index() << 20) | next(_ENGINE_SEQ)
+        #: the always-on record of every tick and the holds it names
+        #: (profiler/ticklog.py); ``step``, ``_dispatch_unified`` and
+        #: ``_drain`` write it, and read no clock of their own
+        self._ticks = _ticklog.TickLog(self._eng_id)
         # {site: (jitted fn, arg avals)} captured at first dispatch —
         # record_program_stats() re-lowers from these for cost analysis
         self._program_args: Dict[str, tuple] = {}
@@ -790,26 +801,40 @@ class ServingEngine:
         ONE unified tick carrying the selected chunks plus every
         resident decode. Returns whether any device work was
         dispatched."""
+        n = self._tick_no
+        log = self._ticks
+        log.enter(n)
         self._sched.on_tick()
         self._drain(self.config.max_inflight)
         # host phases of the tick about to be dispatched, on the
-        # profiler's clock (profiler/trace.py: ``pt:step/*``)
-        n = self._tick_no
+        # profiler's clock (profiler/trace.py: ``pt:step/*``); the tick
+        # log reads the clock as each closes
         with _ptrace.scope("step/admit", tick=n):
             self._admit()
+        log.mark(_ticklog.ADMIT)
         with _ptrace.scope("step/chunks", tick=n):
             chunks = self._collect_chunks()
+        log.mark(_ticklog.CHUNKS)
         with _ptrace.scope("step/grow", tick=n):
             self._grow_pages()
-        dispatched = self._dispatch_spec(chunks) \
-            if self._spec is not None \
-            else self._dispatch_unified(chunks)
+        log.mark(_ticklog.GROW)
+        if self._spec is not None:
+            dispatched = self._dispatch_spec(chunks)
+            log.mark(_ticklog.DISPATCH)
+        else:
+            dispatched = self._dispatch_unified(chunks)
         reg = _registry()
+        active = sum(r is not None for r in self._slot_rid)
         reg.gauge("serving/queue_depth").set(float(len(self._queue)))
-        reg.gauge("serving/active_slots").set(
-            float(sum(r is not None for r in self._slot_rid)))
+        reg.gauge("serving/active_slots").set(float(active))
         reg.gauge("serving/page_util").set(self.pool.allocator.utilization())
+        log.leave(bool(active or self._queue))
         return dispatched
+
+    def tick_log(self) -> _ticklog.TickLog:
+        """This engine's record of every tick (profiler/ticklog.py): the
+        rows ``profiler.tick_logs()[engine id]`` finds."""
+        return self._ticks
 
     def run(self) -> Dict[int, np.ndarray]:
         """Drive until every submitted request finished; returns
@@ -1215,18 +1240,22 @@ class ServingEngine:
     def _drain(self, target: int) -> None:
         """Materialize in-flight ticks oldest-first until at most
         ``target`` remain. The ONLY place device data reaches the host."""
+        log = self._ticks
         while len(self._inflight) > target:
             ent = self._inflight.popleft()
             # did the host get here before the device finished the tick?
             waited = not ent.tok.is_ready()
+            log.drain_begin()
             with _ptrace.scope("step/drain", tick=ent.tick,
                                waited=int(waited)):
                 toks = np.asarray(ent.tok)
+                # the tick's arrival, on ``time.perf_counter``'s clock
+                arrived_ns = log.drain_got(ent.row, ent.tick, waited)
+                now = arrived_ns * 1e-9
                 note = None
                 if self.tick_record is not None:
                     note = self.tick_record.tick(
                         ent.aux, ent.positions, [m[2] for m in ent.meta])
-                now = time.perf_counter()
                 for idx, slot, rid in ent.meta:
                     req = self._requests[rid]
                     if req.done:
@@ -1256,11 +1285,12 @@ class ServingEngine:
                         self._finish(slot, rid, reason="eos")
                     elif len(req.out) >= req.max_new:
                         self._finish(slot, rid, reason="max_new")
+            log.drain_end()
             reg = _registry()
             reg.counter("serving/drain_waited" if waited
                         else "serving/drain_ready").add(1)
             reg.histogram("serving/tick_turnaround_ms").observe(
-                (now - ent.dispatch_t) * 1000.0)
+                (arrived_ns - ent.dispatch_ns) / 1e6)
 
     def _insert_prefix(self, slot: int, tokens: np.ndarray,
                        written: int) -> None:
@@ -1670,11 +1700,19 @@ class ServingEngine:
         ticking = self._ticking_slots()
         if not ticking and not chunks:
             return False
+        log = self._ticks
         with _ptrace.scope("step/build", tick=self._tick_no):
             args, finishers = self._build_unified(chunks, ticking)
+        log.mark(_ticklog.BUILD)
+        # does the device have anything left to run? The last tick's output
+        # says so for chunk-only ticks too, which ``_inflight`` never holds
+        starved = self._last_tok.is_ready()
         with _ptrace.scope("step/dispatch", tick=self._tick_no):
             self.pool.pools, tok, self._last_tok, aux = \
                 self._run_tick(args)
+        dispatch_ns = log.mark(_ticklog.DISPATCH)
+        row = log.tick(self._tick_no, len(ticking),
+                       sum(c[3] - c[2] for c in chunks), int(starved))
         meta = [(s, s, self._slot_rid[s]) for s in ticking]
         meta += [(s, s, rid) for s, rid in finishers]
         if meta:
@@ -1688,8 +1726,7 @@ class ServingEngine:
                 if end >= t0:
                     positions[s] = t0 - 1
             self._inflight.append(_Inflight(
-                tok, meta, self._tick_no, aux, positions,
-                time.perf_counter()))
+                tok, meta, self._tick_no, aux, positions, row, dispatch_ns))
         self._tick_no += 1
         self.max_inflight_seen = max(self.max_inflight_seen,
                                      len(self._inflight))
@@ -2088,7 +2125,10 @@ class ServingEngine:
                 row_tab, row_pos0, row_len, sample.reshape(-1), k_arr,
                 vsample, np.bool_(len(chunks) > 0), np.bool_(has_drafts))
         args = (self._stacked, self._other) + self._pool_args() + tail
-        dispatch_t = time.perf_counter()
+        # the tick log's clock; the inline sync below lands in the row's
+        # ``dispatch`` part, and the row carries no arrival (a speculative
+        # engine's holds are named, not sized)
+        dispatch_ns = self._ticks.clock()
         self.pool.pools, tok_m, acc = self._run_tick(args)
 
         # ---- overlap: chain draft tick N+1 on the un-materialized
@@ -2150,10 +2190,11 @@ class ServingEngine:
         # ---- synchronous absorb: acceptance, rewind, finishes ----
         toks = np.asarray(tok_m)                       # [ns, 1+k]
         accs = np.asarray(acc)
-        now = time.perf_counter()
+        arrived_ns = self._ticks.clock()
+        now = arrived_ns * 1e-9
         reg.counter("serving/drain_waited").add(1)      # an inline sync
         reg.histogram("serving/tick_turnaround_ms").observe(
-            (now - dispatch_t) * 1000.0)
+            (arrived_ns - dispatch_ns) / 1e6)
         eos = self.config.eos_token_id
         for s, rid in [(t, self._slot_rid[t]) for t in ticking] \
                 + finishers:
@@ -2229,6 +2270,8 @@ class ServingEngine:
             self._slot_dispatched[s] = len(req.out)
             if finished is not None:
                 self._finish(s, rid, reason=finished)
+        self._ticks.tick(self._tick_no, len(ticking),
+                         sum(c[3] - c[2] for c in chunks), -1)
         self._tick_no += 1
         reg.counter("serving/ticks").add(1)
         if chunks:

@@ -1,0 +1,10 @@
+"""The run's own ``serve_tokens_per_s`` times ``seconds / (seconds - lost)``:
+the rate the window would have read without its holds. Steady over runs whose
+judged rate spreads, the holds are the cell's noise; if it spreads as much,
+they are not."""
+from perfbench import loader
+
+
+def read(run):
+    return loader.load_module("layer_metrics",
+                              "_holds").tokens_per_s_outside(run)
