@@ -27,8 +27,10 @@ result.
 """
 import json
 import os
+import re
 import shutil
 import sys
+from collections import defaultdict
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
@@ -83,10 +85,10 @@ SHAPES = {
 }
 
 
-def device_us(fn, *args):
-    """Microseconds of device time a call: the ``moe_`` kernel's events over
-    ``CALLS`` traced calls (the three small gathers of ``_visits`` beside
-    them are not the kernel's)."""
+def device_ops_us(fn, *args):
+    """{operation: microseconds of device time a call} over ``CALLS`` traced
+    calls, an operation named without its number (``moe_gmm`` of
+    ``moe_gmm.12``); ``benchmarks/combine_bench.py`` reads it too."""
     jax.block_until_ready(fn(*args))
     log = os.path.join("chiprun_out", "gmm_bench_trace")
     shutil.rmtree(log, ignore_errors=True)
@@ -99,10 +101,20 @@ def device_us(fn, *args):
     jax.profiler.stop_trace()
     doc = tracered.read_xplane(tracered.find_xplane(log))
     shutil.rmtree(log, ignore_errors=True)
-    ns = sum(ev["dur_ns"] for plane in tracered.device_planes(doc)
-             for ev in tracered.op_events(plane)
-             if tracered.short_name(ev).startswith("moe_"))
-    return ns / 1e3 / CALLS
+    by_name = defaultdict(float)
+    for plane in tracered.device_planes(doc):
+        for ev in tracered.op_events(plane):
+            by_name[re.sub(r"\.\d+$", "", tracered.short_name(ev))] += \
+                ev["dur_ns"] / 1e3 / CALLS
+    return dict(by_name)
+
+
+def device_us(fn, *args):
+    """Microseconds of device time a call: the ``moe_`` kernel's events
+    (the three small gathers of ``_visits`` beside them are not the
+    kernel's)."""
+    return sum(us for name, us in device_ops_us(fn, *args).items()
+               if name.startswith("moe_"))
 
 
 def tiles_of(m, k, n, budgets):

@@ -302,6 +302,44 @@ def _held_window(token_of_row, gate_of_row, group_sizes, p, width):
             sizes.astype(jnp.int32), live)
 
 
+#: ``t * h`` up to which ``_combine`` takes the 0/1 product. A row of the
+#: product costs ``2 * t * h`` operations, a row of XLA's scatter-add with
+#: repeated indices (it sorts them and adds row by row) does not grow so. On
+#: the v5e (PERF.md section 6, PR 52): the serving ticks' windows, 0.8-2.7 M,
+#: 12-23 us where the scatter-add took 72-1,050; Solar's step, ``(8192, 2560,
+#: 4096)`` = 33.6 M, 0.99 ms a window where the scatter-add, in place there,
+#: takes 0.66 (``train_tokens_per_s_per_chip`` -0.22 % with the product, in
+#: both of two pairs). No cell lies between; the line stands at 2**24.
+_ONEHOT_MAX_T_X_H = 1 << 24
+
+
+def _combine_onehot(y, tok, rows):
+    """``y.at[tok].add(rows)`` as a product on the MXU: ``onehot[t, r] =
+    (tok[r] == t)`` in the rows' dtype times ``rows``. A 0/1 operand times a
+    row is exact and the rows of a token are summed in float32 and cast
+    once, where the scatter-add rounds to ``y``'s dtype after every row.
+    **A value that is not finite spoils its column of every token**, not of
+    its own alone: ``0 * inf`` is NaN in the product."""
+    onehot = (jnp.arange(y.shape[0], dtype=jnp.int32)[:, None]
+              == tok[None, :]).astype(rows.dtype)
+    return (y + jnp.dot(onehot, rows, precision=jax.lax.Precision.HIGHEST,
+                        preferred_element_type=jnp.float32)).astype(y.dtype)
+
+
+def _combine(y, tok, rows):
+    """A window's rows added to their tokens, counted at trace time under
+    the form taken (``moe/combine_calls{path=onehot|scatter}`` in the
+    profiler's registry, as ``_expert_product`` counts its path)."""
+    from ..profiler import metrics
+
+    t, h = y.shape
+    path = "onehot" if t * h <= _ONEHOT_MAX_T_X_H else "scatter"
+    metrics.registry().counter("moe/combine_calls{path=%s}" % path).add(1)
+    if path == "scatter":
+        return y.at[tok].add(rows)
+    return _combine_onehot(y, tok, rows)
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(7,))
 def _held_experts(x, gate_of_row, w_gate, w_up, w_down, token_of_row,
                   group_sizes, width):
@@ -311,11 +349,11 @@ def _held_experts(x, gate_of_row, w_gate, w_up, w_down, token_of_row,
     multiple of ``width``), ``group_sizes`` [held] the rows of each.
 
     Exact for any routing at the cost of the rows there are: the rows go
-    through in windows of ``width`` (gather, three grouped products,
-    scatter-add), the first always, the others while rows are left, so
-    the buffers are ``[width, H]`` and a routing that sends everything
-    here takes ``n_max / width`` rounds, not more memory. The backward
-    pass walks the same windows and recomputes each."""
+    through in windows of ``width`` (gather, three grouped products, the
+    rows added to their tokens: ``_combine``), the first always, the others
+    while rows are left, so the buffers are ``[width, H]`` and a routing
+    that sends everything here takes ``n_max / width`` rounds, not more
+    memory. The backward pass walks the same windows and recomputes each."""
     def add(p, y):
         tok, gt, sizes, live = _held_window(token_of_row, gate_of_row,
                                             group_sizes, p, width)
@@ -324,7 +362,7 @@ def _held_experts(x, gate_of_row, w_gate, w_up, w_down, token_of_row,
         with _annotate("moe/experts"):
             rows = _swiglu_rows(xs, gt, w_gate, w_up, w_down, sizes, live)
         with _annotate("moe/combine"):
-            return y.at[tok].add(rows)
+            return _combine(y, tok, rows)
 
     windows = (jnp.sum(group_sizes) + width - 1) // width
     return jax.lax.fori_loop(1, windows, add, add(0, jnp.zeros_like(x)))
@@ -534,7 +572,7 @@ def held_moe(x, router_w, w_gate, w_up, w_down, top_k, held,
     after it, because what follows must cost what the rows held cost and
     not ``T * top_k``: the held assignments' rows, sorted by expert, go
     through ``_held_experts`` in windows (gather, the three grouped
-    products, scatter-add), where the full layer's gather-only dispatch and
+    products, ``_combine``), where the full layer's gather-only dispatch and
     combine read every assignment's row. Every assignment to a held expert
     is computed, whatever share of the ``T * top_k`` falls here; what the
     absent experts would add is left out.

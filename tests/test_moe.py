@@ -447,3 +447,254 @@ def test_kept_groups_at_its_default_is_the_softmax_routers_program():
     assert "argmax" in str(one) and len(one.eqns) < len(two.eqns)
     assert str(one) == str(jax.make_jaxpr(
         lambda s: moe.kept_groups(s, 6, 2, best=1))(scores))
+
+
+# --- the held experts' combine (PR 52) --------------------------------------
+def _window(rng, t, width, live, h, dtype, top_k=4):
+    """A window as ``_held_experts`` has it in hand: ``tok`` [width] with no
+    token more than ``top_k`` times among its ``live`` rows and token 0 past
+    them, ``rows`` [width, h] zeros past them, and a carry ``y`` [t, h]."""
+    pairs = rng.permutation(np.repeat(np.arange(t), top_k))[:live]
+    tok = np.zeros(width, np.int32)
+    tok[:live] = pairs
+    rows = rng.normal(size=(width, h)).astype(np.float32)
+    rows[live:] = 0
+    y = rng.normal(size=(t, h)).astype(np.float32)
+    return (jnp.asarray(y).astype(dtype), jnp.asarray(tok),
+            jnp.asarray(rows).astype(dtype))
+
+
+def _float32_sum(y, tok, rows):
+    return np.asarray(y.astype(jnp.float32).at[tok].add(
+        rows.astype(jnp.float32)))
+
+
+@pytest.mark.parametrize("seed", [0, 1, 2])
+@pytest.mark.parametrize("case", ["several_rows_a_token", "rows_past_the_end",
+                                  "bfloat16"])
+def test_the_onehot_combine_is_the_scatter_add(case, seed):
+    """``_combine_onehot`` against ``y.at[tok].add(rows)`` taken in float32:
+    float32 rows to 2e-6 of the largest sum (the order of a token's adds),
+    bfloat16 rows to one rounding of the result, and then no further from
+    the float32 sum than the scatter-add, which rounds after every row."""
+    from paddle_tpu.distributed import moe
+
+    rng = np.random.default_rng(seed)
+    t, width, h = 24, 128, 64
+    live = {"rows_past_the_end": 37}.get(case, width)
+    dtype = jnp.bfloat16 if case == "bfloat16" else jnp.float32
+    y, tok, rows = _window(rng, t, width, live, h, dtype, top_k=8)
+    if case == "several_rows_a_token":
+        assert np.bincount(np.asarray(tok)).max() > 1
+    if case == "rows_past_the_end":
+        # what lies past the groups' end is token 0 and zeros: garbage the
+        # kernels left there was already cut by ``live`` and adds nothing
+        assert not np.asarray(rows[live:]).any() and not tok[live:].any()
+    want = _float32_sum(y, tok, rows)
+    out = moe._combine_onehot(y, tok, rows)
+    assert out.dtype == dtype
+    got = np.asarray(out, np.float32)
+    scale = float(np.abs(want).max())
+    if dtype == jnp.float32:
+        np.testing.assert_allclose(got, want, rtol=0, atol=2e-6 * scale)
+    else:
+        # one rounding to bfloat16 (2**-9 relative) of the float32 sum
+        np.testing.assert_allclose(got, want, rtol=2.0 ** -8, atol=1e-30)
+        old = np.asarray(y.at[tok].add(rows), np.float32)
+        assert np.abs(got - want).max() <= np.abs(old - want).max()
+        assert np.abs(got - want).mean() <= np.abs(old - want).mean()
+
+
+def _held_layer(rng, t, h, f, e, held, dtype=jnp.float32):
+    first, count = held
+    n = lambda *s: rng.normal(size=s).astype(np.float32)
+    x = jnp.asarray(n(t, h)).astype(dtype)
+    return x, (jnp.asarray(n(h, e)),
+               jnp.asarray(0.3 * n(count, h, f)).astype(dtype),
+               jnp.asarray(0.3 * n(count, h, f)).astype(dtype),
+               jnp.asarray(0.3 * n(count, f, h)).astype(dtype))
+
+
+def _scatter_spelling(monkeypatch):
+    """No window small enough for the product: ``_combine`` takes its
+    scatter-add, ``y.at[tok].add(rows)``, as it does at Solar's step."""
+    from paddle_tpu.distributed import moe
+
+    monkeypatch.setattr(moe, "_ONEHOT_MAX_T_X_H", 0)
+
+
+@pytest.mark.parametrize("seed", [3, 4])
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_a_routing_that_sends_every_token_here_walks_its_windows(
+        monkeypatch, dtype, seed):
+    """Every assignment of every token to the held experts: four windows,
+    the carry ``y`` passed from one to the next through the one-hot form,
+    against the same walk with the scatter-add and, in float32, a plain sum
+    over each token's experts."""
+    from paddle_tpu.distributed import moe
+    from paddle_tpu.profiler import metrics
+
+    rng = np.random.default_rng(seed)
+    t, h, f, e, top_k, held = 64, 32, 16, 16, 4, (4, 4)
+    x, (router, wg, wu, wd) = _held_layer(rng, t, h, f, e, held,
+                                          jnp.dtype(dtype))
+    bias = jnp.where((jnp.arange(e) >= 4) & (jnp.arange(e) < 8), 5.0, 0.0)
+    width = moe.held_window_rows(t, top_k, held[1], e)
+    assert t * top_k == 2 * width
+    call = lambda: moe.held_moe(x, router, wg, wu, wd, top_k, held,
+                                select_bias=bias)
+    metrics.registry().reset()
+    y, rows = call()
+    counted = metrics.registry().snapshot()
+    # the first window and the loop's body: two windows traced
+    assert counted["moe/combine_calls{path=onehot}"]["value"] == 2
+    assert "moe/combine_calls{path=scatter}" not in counted
+    assert int(rows.sum()) == t * top_k
+    _scatter_spelling(monkeypatch)
+    old, old_rows = call()
+    counted = metrics.registry().snapshot()
+    assert counted["moe/combine_calls{path=scatter}"]["value"] == 2
+    np.testing.assert_array_equal(np.asarray(rows), np.asarray(old_rows))
+    tol = 2e-6 if dtype == "float32" else 2.0 ** -6
+    scale = float(jnp.abs(old.astype(jnp.float32)).max())
+    np.testing.assert_allclose(np.asarray(y, np.float32),
+                               np.asarray(old, np.float32), rtol=0,
+                               atol=tol * scale)
+    if dtype == "float32":
+        score = np.asarray(jax.nn.sigmoid(x @ router))
+        want = np.zeros((t, h), np.float32)
+        for i in range(t):
+            gate = score[i, 4:8] / score[i, 4:8].sum()
+            for j in range(4):
+                mid = jax.nn.silu(x[i] @ wg[j]) * (x[i] @ wu[j])
+                want[i] += gate[j] * np.asarray(mid @ wd[j])
+        np.testing.assert_allclose(np.asarray(y), want, rtol=0,
+                                   atol=2e-5 * np.abs(want).max())
+
+
+#: the toy shapes of the three served models' expert layers: how each calls
+#: ``held_moe`` (models/deepseek_v2.py, dots3.py, ling3.py)
+_SERVED_LAYERS = {
+    "deepseek_v2": dict(e=16, held=(4, 4), top_k=3, kw=dict(
+        scoring="softmax", n_group=4, topk_group=2, routed_scaling=16.0)),
+    "dots3": dict(e=16, held=(0, 4), top_k=4, kw=dict(scoring="sigmoid")),
+    "ling3": dict(e=32, held=(8, 8), top_k=4, kw=dict(
+        scoring="sigmoid", n_group=8, topk_group=4, routed_scaling=2.5)),
+}
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("model", sorted(_SERVED_LAYERS))
+def test_held_moe_gives_what_it_gave_with_the_scatter_add(monkeypatch, model,
+                                                          dtype):
+    """``held_moe``'s output and ``rows`` at the served models' toy shapes,
+    the one-hot combine against the scatter-add: the rows equal, float32
+    outputs to 2e-6 of the largest, bfloat16 to two roundings of it."""
+    from paddle_tpu.distributed import moe
+
+    spec = _SERVED_LAYERS[model]
+    rng = np.random.default_rng(len(model))
+    t, h, f = 40, 32, 16
+    x, (router, wg, wu, wd) = _held_layer(rng, t, h, f, spec["e"],
+                                          spec["held"], jnp.dtype(dtype))
+    bias = jnp.asarray(0.3 * rng.normal(size=spec["e"]), jnp.float32)
+    kw = dict(spec["kw"])
+    if kw["scoring"] == "sigmoid":
+        kw["select_bias"] = bias
+    shared = tuple(jnp.asarray(0.3 * rng.normal(size=s), jnp.float32)
+                   for s in ((h, f), (h, f), (f, h)))
+    call = lambda: moe.held_moe(x, router, wg, wu, wd, spec["top_k"],
+                                spec["held"], shared=shared, **kw)
+    y, rows = call()
+    assert int(rows.sum()) > 0
+    _scatter_spelling(monkeypatch)
+    old, old_rows = call()
+    np.testing.assert_array_equal(np.asarray(rows), np.asarray(old_rows))
+    tol = 2e-6 if dtype == "float32" else 2.0 ** -7
+    np.testing.assert_allclose(
+        np.asarray(y, np.float32), np.asarray(old, np.float32), rtol=0,
+        atol=tol * float(jnp.abs(old.astype(jnp.float32)).max()))
+
+
+@pytest.mark.parametrize("bad", [np.inf, np.nan])
+def test_a_value_that_is_not_finite_spoils_its_column_of_every_token(bad):
+    """What the product does where the scatter-add did not: ``0 * inf`` is
+    NaN, so one value of a live row that is not finite makes that column of
+    every token NaN, where its own token alone read it (pinned, not wished
+    for: PERF.md section 7, "What PR 52 left open")."""
+    from paddle_tpu.distributed import moe
+
+    y, tok, rows = _window(np.random.default_rng(5), 24, 128, 100, 64,
+                           jnp.bfloat16, top_k=8)
+    rows = rows.at[3, 7].set(bad)
+    old = np.asarray(y.at[tok].add(rows), np.float32)
+    spoiled = ~np.isfinite(old)
+    assert spoiled.sum() == 1 and spoiled[int(tok[3]), 7]
+    got = np.asarray(moe._combine_onehot(y, tok, rows), np.float32)
+    owner = np.arange(24) == int(tok[3])
+    assert np.isnan(got[~owner, 7]).all()
+    np.testing.assert_array_equal(got[owner, 7], old[owner, 7])
+    keep = np.arange(64) != 7
+    np.testing.assert_allclose(got[:, keep], old[:, keep], rtol=2.0 ** -6,
+                               atol=2.0 ** -6)
+
+
+#: (T, width, H) of the four cells that run ``_held_experts``, ``(top_k,
+#: held, e)`` of their routers, and the form each window takes
+_CELL_WINDOWS = {
+    "serve-dsv2-docqa-backlog": ((532, 640, 5120), (6, 20, 160), "onehot"),
+    "serve-dots3-longdoc-backlog": ((268, 512, 5120), (8, 32, 256),
+                                    "onehot"),
+    "serve-ling3-longgen-backlog": ((320, 1024, 2560), (8, 128, 512),
+                                    "onehot"),
+    "train-solar-open2-1chip": ((8192, 2560, 4096), (8, 8, 320), "scatter"),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(_CELL_WINDOWS))
+def test_each_cells_window_is_counted_under_its_form(cell):
+    """The line on ``t * h`` and the trace-time counter at the cells' ``(T,
+    width, H)`` (nothing is compiled: ``eval_shape``); the widths are
+    ``held_window_rows``'."""
+    from paddle_tpu.distributed import moe
+    from paddle_tpu.profiler import metrics
+
+    (t, width, h), routed, want = _CELL_WINDOWS[cell]
+    assert moe.held_window_rows(t, *routed) == width
+    metrics.registry().reset()
+    out = jax.eval_shape(
+        moe._combine, jax.ShapeDtypeStruct((t, h), jnp.bfloat16),
+        jax.ShapeDtypeStruct((width,), jnp.int32),
+        jax.ShapeDtypeStruct((width, h), jnp.bfloat16))
+    assert out.shape == (t, h) and out.dtype == jnp.bfloat16
+    counted = {k: v["value"] for k, v in metrics.registry().snapshot().items()
+               if k.startswith("moe/combine_calls")}
+    assert counted == {"moe/combine_calls{path=%s}" % want: 1}
+
+
+@pytest.mark.parametrize("what", ["forward", "gradients"])
+def test_solars_held_experts_lower_to_the_text_they_lowered_to(monkeypatch,
+                                                               what):
+    """At Solar's ``(8192, 2560, 4096)`` ``_held_experts`` is the program it
+    was: its lowered text equals the text with ``y.at[tok].add(rows)``
+    written in ``_combine``'s place (nothing is compiled)."""
+    from paddle_tpu.distributed import moe
+
+    t, h, f, held, width = 8192, 4096, 1280, 8, 2560
+    bf, f32, i32 = jnp.bfloat16, jnp.float32, jnp.int32
+    shapes = [jax.ShapeDtypeStruct(s, d) for s, d in (
+        ((t, h), bf), ((width,), f32), ((held, h, f), bf), ((held, h, f), bf),
+        ((held, f, h), bf), ((width,), i32), ((held,), i32))]
+
+    def text():
+        run = lambda *a: moe._held_experts(*a, width)
+        if what == "gradients":
+            run = jax.grad(lambda *a: jnp.sum(moe._held_experts(
+                *a, width).astype(f32)), argnums=(0, 1, 2, 3, 4))
+        return jax.jit(run).lower(*shapes).as_text()
+
+    mine = text()
+    assert "scatter" in mine
+    monkeypatch.setattr(moe, "_combine",
+                        lambda y, tok, rows: y.at[tok].add(rows))
+    assert mine == text()
