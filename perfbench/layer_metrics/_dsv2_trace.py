@@ -41,9 +41,12 @@ def _helper(name: str):
     return loader.load_module("layer_metrics", name)
 
 
+GROUPED = _helper("_dots3_trace").GROUPED   # once: ``part`` runs an operation
+
+
 def part(ev: dict) -> str:
     """The innermost of the program's names on an operation's scope path."""
-    if tracered.short_name(ev).startswith(_helper("_dots3_trace").GROUPED):
+    if tracered.short_name(ev).startswith(GROUPED):
         return "experts"
     found = _SCOPE.findall(ev.get("scope", ""))
     return _PART[found[-1]] if found else "unscoped"

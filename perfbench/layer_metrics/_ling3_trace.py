@@ -11,19 +11,24 @@ The grouped matmuls' Pallas calls are found by their instruction's name, as
 ``_dots3_trace`` finds them. Eleven parts: ``dense`` (every matrix product
 and norm outside the experts: ``blk/kda/proj``, ``blk/kda/out``, ``blk/qkv``,
 ``blk/attn_out``, what of ``blk/ffn`` is outside the ``moe/`` parts),
-``kda_step``, ``kda_chunk``, ``kda_prep``, ``mla_decode``, ``mla_chunk``,
+``kda_step``, ``kda_chunk``, ``gdn_prep``, ``mla_decode``, ``mla_chunk``,
 ``scatter``, ``route``, ``experts`` (dispatch and combine with them),
-``shared``, ``head_sample`` and ``unscoped``.
+``shared``, ``head_sample`` and ``unscoped``. A part that another served
+family's helper also cuts carries that helper's name, so that one entry's
+reader asks ``_served`` for it in every cell: ``gdn_prep`` is
+``blk/kda/prep`` (``ops/gdn.gdn_prep_rows``, the pass Olmo-Hybrid's tick runs
+under ``blk/gdn/prep``), ``mla_decode`` DeepSeek-V2's word for the decode
+rows' dense latent attention.
 
 The MLA layer's attention runs under ``blk/mla/...`` and not under the
 ``blk/attn/mla...`` names of the two latent-attention families, so that at
-most one served family's helper answers for a tick. ``_served.helpers()``
-lists the helpers that hand out ``tick_needs``, and an accepted test
-(``tests/perfbench/test_pb_fold.py``) holds that list at the three families
-folded at PR 48; a PR that adds a cell may not edit it. So this helper hands
-out the same thing as ``needs`` and its ``ling.*`` readers ask it directly;
-the ``benchmark`` PR that folds them into ``served.*`` renames it and moves
-that test along.
+most one served family's helper answers for a tick. This helper is one of
+the four ``_served.helpers()`` lists (it hands out ``tick_needs``; ``needs``
+until PR 53, when the cell's own ``ling.*`` entries were folded into the
+``served.*``, ``moe.tick_*``, ``latent.*``, ``mla.dense_decode``, ``pool.*``
+and ``gdn.prep`` entries). Three readers ask it directly, for what only this
+tick has: ``kda.step_ms_per_tick``, ``kda.step_hbm_roofline_pct`` and
+``ling.mla_decode_roofline_pct``.
 A program that names no ``blk/kda/step`` (one that serves no such model: the
 parent of the PR that brought it) gives ``None`` and raises nothing.
 """
@@ -34,7 +39,7 @@ from typing import Dict, Optional, Tuple
 
 from perfbench import loader, tracered, yardstick, yardstick_ling3
 
-_PART = {"blk/kda/proj": "dense", "blk/kda/prep": "kda_prep",
+_PART = {"blk/kda/proj": "dense", "blk/kda/prep": "gdn_prep",
          "blk/kda/step": "kda_step", "blk/kda/chunk": "kda_chunk",
          "blk/kda/out": "dense", "blk/mla/decode": "mla_decode",
          "blk/mla/chunk": "mla_chunk", "blk/latent_scatter": "scatter",
@@ -45,7 +50,7 @@ _PART = {"blk/kda/proj": "dense", "blk/kda/prep": "kda_prep",
          "tick/head": "head_sample", "tick/sample": "head_sample"}
 _SCOPE = re.compile(r"\b(" + "|".join(
     re.escape(n) for n in sorted(_PART, key=len, reverse=True)) + r")\b")
-ORDER = ("experts", "kda_step", "dense", "mla_decode", "kda_prep", "route",
+ORDER = ("experts", "kda_step", "dense", "mla_decode", "gdn_prep", "route",
          "shared", "scatter", "kda_chunk", "mla_chunk", "head_sample",
          "unscoped")
 
@@ -54,9 +59,12 @@ def _helper(name: str):
     return loader.load_module("layer_metrics", name)
 
 
+GROUPED = _helper("_dots3_trace").GROUPED   # once: ``part`` runs an operation
+
+
 def part(ev: dict) -> str:
     """The innermost of the program's names on an operation's scope path."""
-    if tracered.short_name(ev).startswith(_helper("_dots3_trace").GROUPED):
+    if tracered.short_name(ev).startswith(GROUPED):
         return "experts"
     found = _SCOPE.findall(ev.get("scope", ""))
     return _PART[found[-1]] if found else "unscoped"
@@ -115,10 +123,10 @@ def tick_shape(run) -> Optional[dict]:
             "peak": yardstick.chip_peak(run["ctx"].devices[0].device_kind)}
 
 
-def needs(run) -> Optional[Tuple[dict, float, float]]:
+def tick_needs(run) -> Optional[Tuple[dict, float, float]]:
     """``(tick_shape, bytes the mean tick must move, operations it must
-    do)`` by ``yardstick_ling3``: what the other served families' helpers
-    hand out as ``tick_needs``."""
+    do)`` by ``yardstick_ling3``: what the ``served.*`` shares of the whole
+    tick are taken over (``_served`` asks every helper that has this)."""
     s = tick_shape(run)
     if s is None:
         return None

@@ -243,10 +243,11 @@ def parts_ms(doc: dict, word: str, label_of: Callable[[dict], str],
         if not runs:
             return None
         inside = tracered.merge(tracered.intervals(runs))
+        starts = [s for s, _ in inside]
         by_label: Dict[str, list] = defaultdict(list)
         for ev in tracered.op_events(p):
             iv = (ev["start_ns"], ev["start_ns"] + ev["dur_ns"])
-            if tracered.intersection_ns([iv], inside):
+            if tracered.overlaps(iv, inside, starts):
                 by_label[label_of(ev)].append(iv)
         covered: list = []
         for label in list(order) + sorted(set(by_label) - set(order)):

@@ -1,9 +1,10 @@
-"""What the folded readers of the three newer backlog cells share
+"""What the folded readers of the four newer backlog cells share
 (``serve-dots3-longdoc-backlog``, ``serve-dsv2-docqa-backlog``,
-``serve-olmo-hybrid-gen-backlog``): which family's trace helper reads this
-run's tick. One entry a quantity stands in ``BENCHMARK.json`` where each
-cell brought a copy (``dots3.*``, ``dsv2.*``, ``olmoh.*`` and the
-``.longdoc``, ``.dsv2``, ``.olmoh`` suffixes, retired at PR 48); a folded
+``serve-olmo-hybrid-gen-backlog``, ``serve-ling3-longgen-backlog``): which
+family's trace helper reads this run's tick. One entry a quantity stands in
+``BENCHMARK.json`` where each cell brought a copy (``dots3.*``, ``dsv2.*``,
+``olmoh.*`` and the ``.longdoc``, ``.dsv2``, ``.olmoh`` suffixes, retired at
+PR 48; ``ling.*`` and ``kda.prep_ms_per_tick``, retired at PR 53); a folded
 reader returns in each cell the number that cell's copy returned.
 
 A served family's helper is a file ``_<family>_trace.py`` beside this one
@@ -15,10 +16,14 @@ file names none of them: it lists the directory, so a new family brings its
 helper and edits nothing here. The helper is found from the run and not
 from a cell's or a family's name: each gives ``None`` for a tick that does
 not name its own mechanism (``blk/attn/mla``; ``blk/attn/mla_chunk`` or
-``_decode``; ``blk/gdn/step``), so at most one answers and a toy family's
-tick is read like its model's. Ouro's ``loop.*`` readers stay as they are:
-that cell reports no ``serve_tokens_per_s``, and a per-layer entry moves
-one end-to-end metric.
+``_decode``; ``blk/gdn/step``; ``blk/kda/step``), so at most one answers and
+a toy family's tick is read like its model's. A part that two families' ticks
+both have carries one name in both helpers (``dense``, ``head_sample``,
+``unscoped``, ``scatter``, ``route``, ``experts``, ``shared``,
+``mla_decode``, ``gdn_prep``): a shared reader asks for it by that name and
+names no family. Ouro's ``loop.*`` readers stay as they are: that cell
+reports no ``serve_tokens_per_s``, and a per-layer entry moves one
+end-to-end metric.
 """
 from __future__ import annotations
 

@@ -2,8 +2,9 @@
 to read, once, the matrices of the held experts that were given a row (the
 ticks' own count, ``experts_touched_share``; the family's own yardstick,
 its trace helper's ``experts_bytes``), over ``moe.tick_experts_ms_per_tick``.
-With about 8 rows an expert (dots3) or 20 (DeepSeek-V2) the products are
-bound by the weights' bytes, not by arithmetic."""
+With about 8 rows an expert (dots3), 20 (DeepSeek-V2) or one
+(Ling-3.0-flash) the products are bound by the weights' bytes, not by
+arithmetic."""
 from perfbench import loader
 
 

@@ -1,8 +1,10 @@
-"""``pool.live_kv_pct.*`` in the two latent-attention cells: the share of the
+"""``pool.live_kv_pct.*`` in the cells that keep latent pages: the share of the
 latent pools' positions (slots x capacity) that hold a live request's
 tokens, mean over the window's ticks. dots3: the full layers' pools (the
 windowed layers' pools hold the window alone and are sized for it);
-DeepSeek-V2: its one pool (no indexer keys, no window space)."""
+DeepSeek-V2: its one pool (no indexer keys, no window space);
+Ling-3.0-flash: the one MLA layer's pages (64 slots x 17,024 positions,
+1,152 B a token)."""
 
 
 def read(run):

@@ -1,6 +1,7 @@
 """Share of the state pool's slots that hold a tenant's state (the gauge
 ``serving/live_pages{pool=state}`` as the run's last tick left it): a state
-is a slot's whatever the context, so this is the share of the 1.1 GB in use."""
+is a slot's whatever the context, so this is the share of the pool in use
+(Olmo-Hybrid: 1.1 GB; Ling-3.0-flash: 12.6 MB a slot over six KDA layers)."""
 
 
 def read(run):

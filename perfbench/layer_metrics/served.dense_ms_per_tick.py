@@ -6,7 +6,9 @@ RoPE, the latent and indexer projections, the absorbed queries, gates, the
 output projection, the leading dense FFN). Olmo-Hybrid (``_olmoh_trace``):
 every matrix product and norm of both kinds of layer (``blk/gdn/proj``,
 ``blk/gdn/out``, ``blk/qkv``, ``blk/kv_scatter``, ``blk/attn_out``,
-``blk/ffn``): the read of the weights."""
+``blk/ffn``). Ling-3.0-flash (``_ling3_trace``): ``blk/kda/proj``,
+``blk/kda/out``, ``blk/qkv``, ``blk/attn_out`` and what of ``blk/ffn`` is
+outside the ``moe/`` parts. In every cell: the read of the weights."""
 from perfbench import loader
 
 

@@ -5,7 +5,10 @@ one tick must, by the family's own yardstick (its trace helper's
 the head once, the touched experts once, the caches its attention reads, the
 rows it writes; ``yardstick_gdn.tick_bytes`` for Olmo-Hybrid: every weight
 and the head once, the live rows' states both ways, the K and V its
-attention reads, what it writes), over the tick's median device time."""
+attention reads, what it writes; ``yardstick_ling3.tick_bytes`` for
+Ling-3.0-flash: every dense weight and the head once, the experts touched
+once, the live rows' states both ways, the latents its attention reads, what
+it writes), over the tick's median device time."""
 from perfbench import loader
 
 

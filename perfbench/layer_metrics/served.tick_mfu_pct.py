@@ -5,8 +5,11 @@ operations, by the family's own yardstick (its trace helper's
 ``tick_needs``: ``yardstick_mla.tick_flops`` for dots3's three attention parts,
 ``yardstick_mla_dense.tick_flops`` for DeepSeek-V2's dense attention in its
 lesser form, ``yardstick_gdn.tick_flops`` for Olmo-Hybrid's delta rule in
-both forms and its full layers' visible pairs), over the tick's median
-device time and the chip's published bf16 peak."""
+both forms and its full layers' visible pairs, ``yardstick_ling3.tick_flops``
+for Ling-3.0-flash's per-channel delta rule in both forms and its latent
+attention's lesser form), over the tick's median device time and the chip's
+published bf16 peak. A tick of some tens of rows is bound by HBM: this reads
+low."""
 from perfbench import loader
 
 

@@ -1,7 +1,8 @@
 """Median interval between the arrivals of consecutive waited-for ticks over
 the whole judged window, from the engine's tick log: the device's tick seen
-without a trace, to lay beside ``served.tick_device_ms_p50`` /
-``ling.tick_device_ms_p50`` from the seconds after the window."""
+without a trace, to lay beside ``served.tick_device_ms_p50``
+(``tick.device_ms_p50.backlog`` in the long-prompt cell) from the seconds
+after the window."""
 from perfbench import loader
 
 

@@ -13,6 +13,7 @@ The interval arithmetic is copied from paddle_tpu/profiler/device_trace.py
 """
 from __future__ import annotations
 
+import bisect
 import glob
 import os
 import re
@@ -153,6 +154,21 @@ def intersection_ns(a: Sequence[Interval], b: Sequence[Interval]) -> int:
     return total
 
 
+def overlaps(iv: Interval, merged: Sequence[Interval],
+             starts: Sequence[int]) -> bool:
+    """``intersection_ns([iv], merged) > 0`` for ``merged`` as ``merge``
+    gives it from intervals of some length (``intervals`` keeps no other)
+    and ``starts`` its intervals' starts, by bisection and not by a
+    walk: a traced stretch holds some hundreds of program runs and a million
+    operations, and asking each operation against every run took minutes
+    (three such passes made Ling's traced run 1,015 s long, PR 53)."""
+    lo, hi = iv
+    i = bisect.bisect_right(starts, lo) - 1
+    if i >= 0 and merged[i][1] > lo:
+        return hi > lo
+    return i + 1 < len(merged) and merged[i + 1][0] < hi
+
+
 def clip(ivs: Sequence[Interval], lo: int, hi: int) -> List[Interval]:
     return [(max(s, lo), min(e, hi)) for s, e in ivs
             if min(e, hi) > max(s, lo)]
@@ -233,19 +249,6 @@ def top_ops(doc: dict, n: int = 10) -> List[List]:
             total[op_label(ev)] += ev["dur_ns"] / 1e9 / len(planes)
     return [[k, v] for k, v in
             sorted(total.items(), key=lambda kv: -kv[1])[:n]]
-
-
-def is_whole_pool(shape: Optional[Tuple[str, Tuple[int, ...]]],
-                  pool_dims: Sequence[int]) -> bool:
-    """Whether an operation's result is a whole page pool, all layers:
-    ``[L, P, page, heads, head_dim]`` exactly."""
-    return shape is not None and tuple(shape[1]) == tuple(pool_dims)
-
-
-def whole_pool_ops_s(doc: dict, pool_dims: Sequence[int]) -> float:
-    """Device seconds of the operations whose result is a whole pool."""
-    return kernel_s(doc, lambda ev: is_whole_pool(result_shape(ev),
-                                                  pool_dims))
 
 
 def collective_kind(ev: dict) -> Optional[str]:

@@ -161,11 +161,12 @@ def test_no_two_readers_of_a_cell_are_the_same_code(cell):
 
 
 #: the cells that reported a share of the whole step's peak when the
-#: per-layer list was folded (PR 48); the two others have none yet (B4)
+#: per-layer list was folded (PR 48) and Ling's, whose share is the same
+#: entry since PR 53; the two GPT serving cells have none yet (B4)
 MFU_CELLS = ("train-1chip-bf16", "train-6.7b-pp2tp2", "train-olmoe-1chip-4k",
              "train-solar-open2-1chip", "serve-ouro-reason-steady",
              "serve-dots3-longdoc-backlog", "serve-dsv2-docqa-backlog",
-             "serve-olmo-hybrid-gen-backlog")
+             "serve-olmo-hybrid-gen-backlog", "serve-ling3-longgen-backlog")
 
 
 @pytest.mark.parametrize("cell", MFU_CELLS)
@@ -185,7 +186,12 @@ def test_a_cell_keeps_its_share_of_the_whole_steps_peak(cell):
 # benchmark PR has every cell measured anew, these too): ``ttft_p85_ms``
 # left ``end_to_end`` for the per-layer ``sched.ttft_p85_ms``, so the chat
 # cell's five entries that moved it move ``itl_p95_ms`` and take Ouro's cell
-# into their lists, whose five copies of them (``*.loop``) went.
+# into their lists, whose five copies of them (``*.loop``) went. Two more
+# since, PR 53's (a benchmark PR too): ``flash.device_ms_per_step`` (the sum
+# of ``flash.fwd_`` and ``flash.bwd_ms_per_step``) and
+# ``pool.whole_pool_ops_ms_per_tick`` (the two scatters
+# ``tick.kv_scatter_ms_per_tick`` times by scope) went, and the six entries
+# of the holds (PR 51) list every serving cell, the two GPT ones among them.
 T1, CHAT, LP, T67 = ("train-1chip-bf16", "serve-chat-steady",
                      "serve-longprompt-backlog", "train-6.7b-pp2tp2")
 OLMOE, SOLAR, OURO = ("train-olmoe-1chip-4k", "train-solar-open2-1chip",
@@ -197,6 +203,14 @@ PROC, SCHED, TICK, POOL, TRAIN, FLASH = (
 TPS, ITL, STPS = ("train_tokens_per_s_per_chip", "itl_p95_ms",
                   "serve_tokens_per_s")
 ALL_TRAIN = (T1, T67, OLMOE, SOLAR)
+BACKLOGS = (LP, "serve-dots3-longdoc-backlog", "serve-dsv2-docqa-backlog",
+            "serve-olmo-hybrid-gen-backlog", "serve-ling3-longgen-backlog")
+#: the holds of the judged window as every backlog cell reports them (PR 51;
+#: every serving cell since PR 53): the cells' own tests import this
+BACKLOG_HOLDS = ("served.hold_lost_ms_in_window",
+                 "served.hold_unexplained_pct",
+                 "served.tokens_per_s_outside_holds",
+                 "served.tick_ms_p50_in_window")
 GPT_AND_LISTLESS = [
     ("proc.compiles_in_window", "count", "lower", "program_counter", PROC, "setup_s", None),
     ("sched.queue_wait_p50_ms", "ms", "lower", "program_span", SCHED, ITL, (CHAT, OURO)),
@@ -206,13 +220,11 @@ GPT_AND_LISTLESS = [
     ("sched.serve_tokens_per_s_slice_p50", "tokens/s", "higher", "host_clock", SCHED, STPS, (LP,)),
     ("tick.device_ms_p50.chat", "ms", "lower", "device_trace", TICK, ITL, (CHAT, OURO)),
     ("tick.device_ms_p50.backlog", "ms", "lower", "device_trace", TICK, STPS, (LP,)),
-    ("pool.whole_pool_ops_ms_per_tick", "ms", "lower", "device_trace", POOL, STPS, (LP,)),
     ("pool.live_kv_pct.chat", "%", "higher", "program_counter", POOL, ITL, (CHAT, OURO)),
     ("pool.live_kv_pct.backlog", "%", "higher", "program_counter", POOL, STPS, (LP,)),
     ("train.mfu_pct", "%", "higher", "host_clock", TRAIN, TPS, (T1, T67)),
     ("train.peak_hbm_gb", "GB", "lower", "program_counter", TRAIN, TPS, ALL_TRAIN),
     ("train.live_hbm_gb", "GB", "lower", "program_counter", TRAIN, TPS, ALL_TRAIN),
-    ("flash.device_ms_per_step", "ms", "lower", "device_trace", FLASH, TPS, (T1, T67)),
     ("coll.exposed_ms_per_step", "ms", "lower", "device_trace", "parallel layout", TPS, (T67,)),
     ("tick.kv_scatter_ms_per_tick", "ms", "lower", "device_trace", TICK, STPS, (LP,)),
     ("tick.attn_ms_per_tick", "ms", "lower", "device_trace", TICK, STPS, (LP,)),
@@ -238,6 +250,12 @@ GPT_AND_LISTLESS = [
     ("setup.cache_fetch_s", "s", "lower", "program_counter", PROC, "setup_s", None),
     ("setup.programs_before_window", "count", "lower", "program_counter", PROC, "setup_s", None),
     ("setup.unaccounted_s", "s", "lower", "host_clock", PROC, "setup_s", None),
+    ("served.hold_lost_ms_in_window", "ms", "lower", "program_counter", SCHED, STPS, BACKLOGS),
+    ("sched.hold_lost_ms_in_window", "ms", "lower", "program_counter", SCHED, ITL, (CHAT, OURO)),
+    ("served.hold_unexplained_pct", "%", "lower", "program_counter", SCHED, STPS, BACKLOGS),
+    ("sched.hold_unexplained_pct", "%", "lower", "program_counter", SCHED, ITL, (CHAT, OURO)),
+    ("served.tokens_per_s_outside_holds", "tokens/s", "higher", "program_counter", SCHED, STPS, BACKLOGS),
+    ("served.tick_ms_p50_in_window", "ms", "lower", "program_counter", TICK, STPS, BACKLOGS),
 ]
 
 

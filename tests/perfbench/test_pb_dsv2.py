@@ -16,7 +16,8 @@ import pytest
 
 from perfbench import loader, yardstick, yardstick_mla_dense as ymd
 
-from test_pb_contract import config_file_is_sound, family_is_only_a_model
+from test_pb_contract import BACKLOG_HOLDS as HOLDS, config_file_is_sound, \
+    family_is_only_a_model
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 TOY = os.path.join(HERE, "toy_dsv2")
@@ -37,10 +38,10 @@ NEW = ("served.tick_device_ms_p50", "served.dense_ms_per_tick",
        "served.prefill_tokens_per_tick",
        "served.decode_rows_per_tick",
        "served.tokens_per_s_slice_p50",
-       "served.host_ms_per_tick")
-#: the entries that list this cell alone: its own mechanism's
-OWN = ("mla.dense_chunk_ms_per_tick", "mla.dense_decode_ms_per_tick",
-       "mla.dense_attn_roofline_pct", "moe.tick_group_hit_pct")
+       "served.host_ms_per_tick") + HOLDS    # this cell's since PR 53
+#: the entries that list this cell alone: its own mechanism's (the decode
+#: rows' time and the group hits are Ling's cell's too since PR 53)
+OWN = ("mla.dense_chunk_ms_per_tick", "mla.dense_attn_roofline_pct")
 WIDTHS = ("hidden_size", "intermediate_size", "moe_intermediate_size",
           "num_attention_heads", "q_lora_rank", "kv_lora_rank",
           "qk_nope_head_dim", "qk_rope_head_dim", "v_head_dim",
@@ -543,6 +544,8 @@ def test_a_traced_rehearsal_reports_what_the_cpu_can(copy):
     line, out = rehearse(copy, 1)
     assert line["correct"] is True, out[-2000:]
     got = set(line["metrics"])
+    # the engine's own record of its ticks reads on the CPU too
+    assert set(HOLDS) <= got
     assert {"moe.tick_group_hit_pct", "pool.live_latent_pct",
             "moe.tick_expert_load_max_over_mean",
             "moe.tick_experts_touched_pct",

@@ -15,26 +15,37 @@ import pytest
 
 from perfbench import loader, yardstick, yardstick_ling3 as yl
 
-from test_pb_contract import config_file_is_sound, family_is_only_a_model
+from test_pb_contract import BACKLOG_HOLDS as HOLDS, config_file_is_sound, \
+    family_is_only_a_model
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 TOY = os.path.join(HERE, "toy_ling3")
 CELL = "serve-ling3-longgen-backlog"
 CATALOG = "/opt/skills/guides/model-configs/architectures.jsonl"
-PARTS = ("ling.dense_ms_per_tick", "ling.head_sample_ms_per_tick",
-         "kda.step_ms_per_tick", "kda.prep_ms_per_tick",
-         "ling.mla_decode_ms_per_tick", "ling.latent_scatter_ms_per_tick",
-         "ling.moe_route_ms_per_tick", "ling.moe_experts_ms_per_tick",
-         "ling.moe_shared_ms_per_tick", "ling.unscoped_ms_per_tick")
-SHARES = ("ling.tick_mfu_pct", "ling.tick_hbm_roofline_pct",
+# The cell's 25 quantities under the names they carry since PR 53: 21 of them
+# are entries other cells report too (``served.*``, ``moe.tick_*``,
+# ``latent.*``, ``mla.dense_decode``, ``pool.*``, ``gdn.prep``; the cell's
+# own ``ling.*`` copies and ``kda.prep_ms_per_tick`` went), four are its own.
+PARTS = ("served.dense_ms_per_tick", "served.head_sample_ms_per_tick",
+         "kda.step_ms_per_tick", "gdn.prep_ms_per_tick",
+         "mla.dense_decode_ms_per_tick", "latent.scatter_ms_per_tick",
+         "moe.tick_route_ms_per_tick", "moe.tick_experts_ms_per_tick",
+         "moe.tick_shared_ms_per_tick", "served.unscoped_ms_per_tick")
+SHARES = ("served.tick_mfu_pct", "served.tick_hbm_roofline_pct",
           "kda.step_hbm_roofline_pct", "ling.mla_decode_roofline_pct",
-          "ling.moe_experts_hbm_roofline_pct")
-COUNTED = ("ling.host_ms_per_tick", "ling.moe_expert_load_max_over_mean",
-           "ling.moe_experts_touched_pct", "ling.moe_group_hit_pct",
-           "ling.live_latent_pct", "ling.live_state_slots_pct",
-           "ling.decode_rows_per_tick", "ling.tokens_per_s_slice_p50",
+          "moe.tick_experts_hbm_roofline_pct")
+COUNTED = ("served.host_ms_per_tick", "moe.tick_expert_load_max_over_mean",
+           "moe.tick_experts_touched_pct", "moe.tick_group_hit_pct",
+           "pool.live_latent_pct", "pool.live_state_slots_pct",
+           "served.decode_rows_per_tick", "served.tokens_per_s_slice_p50",
            "ling.warm_prefill_tokens_per_s")
-NEW = ("ling.tick_device_ms_p50",) + PARTS + SHARES + COUNTED
+NEW = ("served.tick_device_ms_p50",) + PARTS + SHARES + COUNTED
+#: the entries that list this cell alone: what only this tick has (the
+#: per-channel step; the decode rows' attention alone over its own roofline,
+#: where ``mla.dense_attn_roofline_pct`` is DeepSeek-V2's chunk and decode
+#: calls together; the chunk path's reading from warm-in)
+OWN = ("kda.step_ms_per_tick", "kda.step_hbm_roofline_pct",
+       "ling.mla_decode_roofline_pct", "ling.warm_prefill_tokens_per_s")
 WIDTHS = ("hidden_size", "intermediate_size", "moe_intermediate_size",
           "moe_shared_expert_intermediate_size", "num_attention_heads",
           "head_dim", "short_conv_kernel_size", "kv_lora_rank",
@@ -316,20 +327,22 @@ def test_the_readers_split_a_tick_by_the_programs_names(monkeypatch):
     run, pt = _run_with(doc, real_config(), dict(FACTS))
     monkeypatch.setattr(pt, "load", lambda: doc)
     read = lambda name: loader.load_module("layer_metrics", name).read(run)
-    want = {"ling.tick_device_ms_p50": 30.0, "ling.dense_ms_per_tick": 10.0,
-            "ling.head_sample_ms_per_tick": 4.0, "kda.step_ms_per_tick": 2.0,
-            "kda.prep_ms_per_tick": 2.0, "ling.mla_decode_ms_per_tick": 2.0,
-            "ling.latent_scatter_ms_per_tick": 2.0,
-            "ling.moe_route_ms_per_tick": 2.0,
-            "ling.moe_experts_ms_per_tick": 2.0,
-            "ling.moe_shared_ms_per_tick": 2.0,
-            "ling.unscoped_ms_per_tick": 2.0,
-            "ling.moe_expert_load_max_over_mean": 4.5,
-            "ling.moe_experts_touched_pct": 60.0,
-            "ling.moe_group_hit_pct": 80.0, "ling.live_latent_pct": 25.0,
-            "ling.live_state_slots_pct": 100.0,
-            "ling.decode_rows_per_tick": 64.0,
-            "ling.tokens_per_s_slice_p50": 3000.0,
+    want = {"served.tick_device_ms_p50": 30.0,
+            "served.dense_ms_per_tick": 10.0,
+            "served.head_sample_ms_per_tick": 4.0,
+            "kda.step_ms_per_tick": 2.0, "gdn.prep_ms_per_tick": 2.0,
+            "mla.dense_decode_ms_per_tick": 2.0,
+            "latent.scatter_ms_per_tick": 2.0,
+            "moe.tick_route_ms_per_tick": 2.0,
+            "moe.tick_experts_ms_per_tick": 2.0,
+            "moe.tick_shared_ms_per_tick": 2.0,
+            "served.unscoped_ms_per_tick": 2.0,
+            "moe.tick_expert_load_max_over_mean": 4.5,
+            "moe.tick_experts_touched_pct": 60.0,
+            "moe.tick_group_hit_pct": 80.0, "pool.live_latent_pct": 25.0,
+            "pool.live_state_slots_pct": 100.0,
+            "served.decode_rows_per_tick": 64.0,
+            "served.tokens_per_s_slice_p50": 3000.0,
             "ling.warm_prefill_tokens_per_s": 9000.0}
     for name, value in want.items():
         assert read(name) == pytest.approx(value), name
@@ -340,7 +353,7 @@ def test_the_readers_split_a_tick_by_the_programs_names(monkeypatch):
     assert read("kda.step_hbm_roofline_pct") == pytest.approx(
         100 * yl.least_ms(yl.step_flops(c, 64.0), yl.step_bytes(c, 64.0),
                           peak) / 2.0)
-    assert read("ling.moe_experts_hbm_roofline_pct") == pytest.approx(
+    assert read("moe.tick_experts_hbm_roofline_pct") == pytest.approx(
         100 * yl.experts_bytes(c, 0.6) / peak.hbm_bytes_per_s * 1e3 / 2.0)
     for name in SHARES:
         assert 0 < read(name), name
@@ -349,12 +362,12 @@ def test_the_readers_split_a_tick_by_the_programs_names(monkeypatch):
                                                 "layer_metrics"))
         if f[:-3] in NEW)
     # the other served families' helpers do not read this tick, nor this
-    # one theirs: at most one answers
+    # one theirs: at most one answers, and ``_served`` finds this one
     for other in ("_dots3_trace", "_dsv2_trace", "_olmoh_trace"):
         assert loader.load_module("layer_metrics", other).parts_ms(run) \
             is None, other
     assert loader.load_module("layer_metrics", "_served").trace_of(run) \
-        is None
+        is loader.load_module("layer_metrics", "_ling3_trace")
 
 
 def test_the_readers_find_nothing_in_a_program_without_the_model(
@@ -363,41 +376,55 @@ def test_the_readers_find_nothing_in_a_program_without_the_model(
     ``blk/kda/step``, and its family's facts hold no state rows: every
     reader of the device returns ``None`` and raises nothing; so with no
     trace at all; and a hybrid's tick under ``blk/gdn/step`` is not this
-    helper's either."""
+    helper's either: the readers of this cell's own mechanism find nothing
+    there (the folded ones read that tick as Olmo-Hybrid's:
+    test_pb_fold.py)."""
     gpt = loader.load_json(loader.root_file(
         "perfbench/configs/gpt3-1.3b-serve.json"))
-    for scopes in (["blk/qkv", "blk/attn", "blk/ffn", "tick/head"],
-                   ["blk/gdn/proj", "blk/gdn/step", "blk/ffn"]):
+    everything = ("served.tick_device_ms_p50",) + PARTS + SHARES \
+        + COUNTED[1:4] + COUNTED[5:6] + COUNTED[8:]
+    for scopes, names in (
+            (["blk/qkv", "blk/attn", "blk/ffn", "tick/head"], everything),
+            (["blk/gdn/proj", "blk/gdn/step", "blk/ffn"], OWN)):
         doc = _synthetic(scopes)
         run, pt = _run_with(doc, gpt, {
             "decode_rows_per_tick": 9.0, "prefill_rows_per_tick": 0.25,
             "prefill_chunk": 32, "live_kv_share": 0.5})
         monkeypatch.setattr(pt, "load", lambda doc=doc: doc)
-        for name in ("ling.tick_device_ms_p50",) + PARTS + SHARES \
-                + COUNTED[1:4] + COUNTED[5:6] + COUNTED[8:]:
+        assert loader.load_module(
+            "layer_metrics", "_ling3_trace").parts_ms(run) is None
+        for name in names:
             assert loader.load_module("layer_metrics", name).read(run) \
                 is None, name
     run["ctx"].trace_doc = None
-    assert loader.load_module(
-        "layer_metrics", "ling.tick_mfu_pct").read(run) is None
+    for name in ("served.tick_mfu_pct", "kda.step_hbm_roofline_pct"):
+        assert loader.load_module("layer_metrics", name).read(run) is None
 
 
 def test_the_cells_lists_name_the_new_metrics_of_this_cell(bench):
     cell = loader.load_cell(CELL)
     names = {m["name"] for m in cell["per_layer"]}
-    assert set(NEW) <= names and len(NEW) == 25
+    assert set(NEW) | set(HOLDS) <= names and len(NEW) == 25
     assert {m["name"] for m in cell["end_to_end"]} == {
         "serve_tokens_per_s", "setup_s"}
     assert cell["cell"]["chips"] == 1 \
         and cell["cell"]["traffic"] == "longgen-12k-backlog"
-    assert len(bench["per_layer"]) == 121
-    assert bench["workloads"][-1]["name"] == CELL
     for m in bench["per_layer"]:
-        if m["name"] in NEW:        # a new cell's entries list it alone
-            assert m["workloads"] == [CELL] \
+        if m["name"] in NEW + HOLDS:
+            assert CELL in m["workloads"] \
                 and m["moves"] == "serve_tokens_per_s"
-        else:       # no accepted metric's list of cells names this cell
+        else:       # no other metric's list of cells names this cell
             assert CELL not in m.get("workloads", ())
+        if m["name"] in OWN:        # what only this tick has
+            assert m["workloads"] == [CELL]
+        elif m["name"] in NEW:      # a quantity another cell reports too
+            assert len(m["workloads"]) > 1, m["name"]
+    # the cell's own copies went with the fold (PR 53): two names are left
+    assert sorted(m["name"] for m in bench["per_layer"]
+                  if m["name"].startswith("ling.")) == [
+        "ling.mla_decode_roofline_pct", "ling.warm_prefill_tokens_per_s"]
+    assert "kda.prep_ms_per_tick" not in {
+        m["name"] for m in bench["per_layer"]}
 
 
 # --- the check, controls included, through check() itself -------------------
@@ -532,7 +559,9 @@ def test_a_traced_rehearsal_reports_what_the_cpu_can(copy):
     line, out = rehearse(copy, 1)
     assert line["correct"] is True, out[-2000:]
     got = set(line["metrics"])
+    # the engine's own record of its ticks reads on the CPU too
+    assert set(HOLDS) <= got
     assert set(COUNTED[1:]) <= got
     assert not got & (set(PARTS) | set(SHARES))
-    assert 0 < line["metrics"]["ling.live_state_slots_pct"]["value"] <= 100
+    assert 0 < line["metrics"]["pool.live_state_slots_pct"]["value"] <= 100
     assert line["metrics"]["ling.warm_prefill_tokens_per_s"]["value"] > 0

@@ -15,7 +15,8 @@ import pytest
 
 from perfbench import loader, yardstick, yardstick_gdn as yg
 
-from test_pb_contract import config_file_is_sound, family_is_only_a_model
+from test_pb_contract import BACKLOG_HOLDS as HOLDS, config_file_is_sound, \
+    family_is_only_a_model
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 TOY = os.path.join(HERE, "toy_olmo_hybrid")
@@ -31,9 +32,13 @@ SHARES = ("served.tick_mfu_pct", "served.tick_hbm_roofline_pct",
 COUNTED = ("pool.live_state_slots_pct", "served.tokens_per_s_slice_p50",
            "served.prefill_tokens_per_tick", "served.decode_rows_per_tick",
            "served.host_ms_per_tick")
-NEW = ("served.tick_device_ms_p50",) + PARTS + SHARES + COUNTED
-#: the entries that list this cell alone: its own mechanism's
-OWN = tuple(n for n in NEW if not n.startswith("served."))
+#: with the holds of the judged window (PR 51; this cell's since PR 53)
+NEW = ("served.tick_device_ms_p50",) + PARTS + SHARES + COUNTED + HOLDS
+#: the entries that list this cell alone: its own mechanism's (the pass
+#: before the rule and the state pool's slots are Ling's cell's too since
+#: PR 53)
+OWN = tuple(n for n in NEW if not n.startswith("served.") and n not in (
+    "gdn.prep_ms_per_tick", "pool.live_state_slots_pct"))
 WIDTHS = ("vocab_size", "hidden_size", "intermediate_size",
           "num_attention_heads", "num_key_value_heads",
           "linear_num_key_heads", "linear_num_value_heads",
@@ -473,6 +478,8 @@ def test_a_traced_rehearsal_reports_what_the_cpu_can(copy):
     line, out = rehearse(copy, 1)
     assert line["correct"] is True, out[-2000:]
     got = set(line["metrics"])
+    # the engine's own record of its ticks reads on the CPU too
+    assert set(HOLDS) <= got
     assert {"pool.live_state_slots_pct", "served.tokens_per_s_slice_p50",
             "served.prefill_tokens_per_tick",
             "served.decode_rows_per_tick"} <= got

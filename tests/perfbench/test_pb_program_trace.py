@@ -98,6 +98,59 @@ def test_parts_and_unscoped_add_up_to_the_ticks_time(doc):
         parts["head_sample"] > parts["kv_scatter"] > 0
 
 
+def _parts_ms_by_walking(doc, word, label_of, order):
+    """``parts_ms`` as it stood until PR 53: an operation laid against the
+    runs by ``intersection_ns``, which walks them all (and sorts them anew)
+    for every operation. Kept here as the reference."""
+    from collections import defaultdict
+    planes = tracered.device_planes(doc)
+    out = defaultdict(float)
+    for p in planes:
+        runs = PT.whole_runs(PT.program_runs(p, word))
+        inside = tracered.merge(tracered.intervals(runs))
+        by_label = defaultdict(list)
+        for ev in tracered.op_events(p):
+            iv = (ev["start_ns"], ev["start_ns"] + ev["dur_ns"])
+            if tracered.intersection_ns([iv], inside):
+                by_label[label_of(ev)].append(iv)
+        covered = []
+        for label in list(order) + sorted(set(by_label) - set(order)):
+            ivs = tracered.merge(by_label.get(label, []))
+            out[label] += (tracered.union_ns(ivs)
+                           - tracered.intersection_ns(ivs, covered)) / 1e6
+            covered = tracered.merge(covered + ivs)
+        out["runs"] += tracered.union_ns(inside) / 1e6
+        out["in no operation"] += (
+            tracered.union_ns(inside)
+            - tracered.intersection_ns(inside, covered)) / 1e6
+        out["n_runs"] += len(runs)
+    return {k: v / len(planes) for k, v in out.items()}
+
+
+RECORDED = ["recorded_scoped_tick.json.gz"] + sorted(
+    os.path.join("recorded_served", f)
+    for f in os.listdir(os.path.join(HERE, "recorded_served"))
+    if f.endswith(".json.gz"))
+
+
+@pytest.mark.parametrize("piece", RECORDED)
+def test_the_parts_are_what_walking_every_run_gave(piece):
+    """Every recorded piece, cut by every label function there is: the
+    same parts to the last digit (the pass is linear in the operations
+    since PR 53; it was operations x runs)."""
+    doc = PT.load_recorded(os.path.join(HERE, piece))
+    cuts = [(PT.tick_part, PT.TICK_ORDER)] + [
+        (tr.part, tr.ORDER) for tr in (
+            loader.load_module("layer_metrics", n)
+            for n in loader.load_module("layer_metrics",
+                                        "_served").helpers())]
+    assert len(cuts) == 5
+    for label_of, order in cuts:
+        want = _parts_ms_by_walking(doc, "tick", label_of, order)
+        assert want["n_runs"] >= 2
+        assert PT.parts_ms(doc, "tick", label_of, order) == want
+
+
 def test_a_run_cut_by_the_traces_edge_is_left_out(doc):
     """As the chip's traces begin: with the recorded tail of a tick that
     was running when the profiler started."""

@@ -95,13 +95,19 @@ def rehearse(copy, workload, trace, devices=1, seconds="1.5"):
                           "sched.ttft_p85_ms",
                           "sched.queue_wait_p50_ms",
                           "load.generator_late_ms_max",
-                          "pool.live_kv_pct.chat"}),
+                          "pool.live_kv_pct.chat",
+                          "sched.hold_lost_ms_in_window",
+                          "sched.hold_unexplained_pct"}),
     ("toy-backlog-cell", 0, {"serve_tokens_per_s", "setup_s"}),
     ("toy-backlog-cell", 1, {"proc.compiles_in_window",
                              "sched.prefill_tokens_per_tick",
                              "sched.decode_rows_per_tick",
                              "sched.serve_tokens_per_s_slice_p50",
-                             "pool.live_kv_pct.backlog"}),
+                             "pool.live_kv_pct.backlog",
+                             "served.hold_lost_ms_in_window",
+                             "served.hold_unexplained_pct",
+                             "served.tokens_per_s_outside_holds",
+                             "served.tick_ms_p50_in_window"}),
 ])
 def test_added_files_are_found_and_the_cell_runs(copy, workload, trace,
                                                  metrics):

@@ -8,6 +8,7 @@ reduction is checked against a slow computation written out here.
 """
 import json
 import os
+import random
 import types
 
 import pytest
@@ -94,18 +95,16 @@ def test_idle_gaps_go_to_the_span_that_was_open(doc):
     assert gaps["step"] > 10 * gaps.get("unattributed", 0.0)
 
 
-def test_whole_pool_operations_are_matched_by_shape(doc):
+def test_the_operations_that_took_most_time_are_named_with_their_shape(doc):
+    """The recorded tick is PR 23's, whose longest operations were copies of
+    a whole page pool: ``top_ops`` names an operation with its result's
+    shape, which is how such a temporary shows in a run's ``breakdown``
+    (the entry that summed them, ``pool.whole_pool_ops_ms_per_tick``, went
+    at PR 53 with ``tracered.whole_pool_ops_s``)."""
     ops = ops_of(doc)
     whole = [e for e in ops if " = bf16[24,1537,16,16,128]{" in e["name"]]
     assert {tracered.opcode(e) for e in whole} >= {"copy", "fusion"}
-    assert tracered.whole_pool_ops_s(doc, POOL) * 1e9 == \
-        pytest.approx(sum(e["dur_ns"] for e in whole))
-    assert tracered.whole_pool_ops_s(doc, POOL) > 0.02      # of 0.07 s
-    # one layer's slice of the pool, or another pool, is not the pool
-    assert not tracered.is_whole_pool(("bf16", (1537, 16, 16, 128)), POOL)
-    assert not tracered.is_whole_pool(("bf16", (24, 1025, 16, 16, 128)),
-                                      POOL)
-    assert tracered.whole_pool_ops_s(doc, (24, 1025, 16, 16, 128)) == 0.0
+    assert all(tracered.result_shape(e) == ("bf16", POOL) for e in whole)
     top = tracered.top_ops(doc, 3)
     assert top[0][0] == "copy.117_bf16_24_1537_16_16_128_"
     assert top[0][1] >= top[1][1] >= top[2][1] > 0
@@ -146,21 +145,39 @@ def test_exposed_collective_time_is_what_no_compute_covers(doc):
 
 
 def test_layer_metric_readers_on_the_recorded_tick(doc):
-    run = {"ctx": types.SimpleNamespace(trace_doc=doc),
-           "facts": {"pool_dims": POOL}}
+    run = {"ctx": types.SimpleNamespace(trace_doc=doc), "facts": {}}
     tick = loader.load_module("layer_metrics", "tick.device_ms_p50.backlog")
     assert tick.read(run) == pytest.approx(57.463127)   # of 7.3, 57.46, 57.46
-    pool = loader.load_module("layer_metrics",
-                              "pool.whole_pool_ops_ms_per_tick")
-    assert pool.read(run) == pytest.approx(
-        tracered.whole_pool_ops_s(doc, POOL) * 1e3 / 3)
     # nothing to read: the reader returns nothing, the line leaves it out
     blind = {"ctx": types.SimpleNamespace(trace_doc=None), "facts": {}}
-    for name in ("tick.device_ms_p50.chat", "flash.device_ms_per_step",
-                 "pool.whole_pool_ops_ms_per_tick",
+    for name in ("tick.device_ms_p50.chat", "flash.fwd_ms_per_step",
+                 "flash.bwd_ms_per_step", "tick.kv_scatter_ms_per_tick",
                  "coll.exposed_ms_per_step", "sched.queue_wait_p50_ms",
                  "sched.decode_rows_per_tick", "train.mfu_pct"):
         assert loader.load_module("layer_metrics", name).read(blind) is None
+
+
+def test_an_operation_is_laid_against_the_runs_by_bisection():
+    """``overlaps`` answers what ``intersection_ns([iv], merged) > 0``
+    answered by walking every run (minutes over a traced stretch of some
+    hundreds of ticks, PR 53): the same, seeded, touching ends and empty
+    operations among them."""
+    rng = random.Random(53)
+    for _ in range(500):
+        merged = tracered.merge([
+            (a, a + rng.randint(1, 8))
+            for a in (rng.randint(0, 60) for _ in range(rng.randint(0, 6)))])
+        starts = [s for s, _ in merged]
+        for _ in range(40):
+            lo = rng.randint(-2, 70)
+            iv = (lo, lo + rng.randint(0, 12))
+            assert tracered.overlaps(iv, merged, starts) == (
+                tracered.intersection_ns([iv], merged) > 0), (iv, merged)
+    merged = [(10, 20), (30, 40)]
+    for iv, said in (((20, 30), False), ((19, 20), True), ((20, 31), True),
+                     ((5, 10), False), ((15, 15), False), ((0, 50), True),
+                     ((40, 45), False)):
+        assert tracered.overlaps(iv, merged, [10, 30]) is said, iv
 
 
 def test_interval_arithmetic():
