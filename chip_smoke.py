@@ -1567,6 +1567,200 @@ def phase_ling(cfg, num_slots: int, page_size: int, pages_per_slot: int,
     return {**out, **errs, "gmm_tiles": tiles}
 
 
+#: the served state-space rule's three counters and the attention's
+SSD_COUNTERS = dict(
+    {kind: "ssd/%s_calls{path=%%s}" % kind
+     for kind in ("step", "chunk", "prep")},
+    attn="serving/attn_calls{path=%s}")
+
+
+def check_ssd_ops(heads: int, p: int, n: int, groups: int, rows: int,
+                  w: int) -> dict:
+    """``ssd_step_rows`` over ``rows`` decode rows and ``ssd_chunk_rows``
+    over one row of ``w`` tokens, from random states, by the path observed
+    here (on the chip: the kernels ``ssd_step`` and ``ssd_chunk``) against
+    ``xla_step`` and ``xla_chunk``; before them ``ssd_prep_rows`` over the
+    same two row groups (4 taps and a bias, a history of ``rows`` slots)
+    against ``xla_prep``."""
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.ops import gdn, ssd
+
+    def operands(seed, lead):
+        ks = jax.random.split(jax.random.PRNGKey(seed), 7)
+        bf = lambda a: a.astype(jnp.bfloat16)               # noqa: E731
+        return (bf(jax.random.normal(ks[0], lead + (heads, p))),
+                bf(jax.random.normal(ks[1], lead + (groups, n))),
+                bf(jax.random.normal(ks[2], lead + (groups, n))),
+                jax.nn.softplus(jax.random.normal(ks[3], lead + (heads,))
+                                - 3.0),
+                -jax.random.uniform(ks[4], (heads,), minval=1.0,
+                                    maxval=16.0),
+                jnp.ones((heads,)),
+                jax.random.normal(ks[5], lead[:1] + (heads, n, p)))
+
+    rel = lambda a, b: float(jnp.linalg.norm(                # noqa: E731
+        a.astype(jnp.float32) - b) / jnp.linalg.norm(b))
+    path = ssd.ssd_path(heads, n, p)
+    out = {"path": path}
+    # the step: rows 1.. live, one dead row on the null slot
+    x, B, C, dt, A, D, s0 = operands(1, (rows,))
+    stack = jnp.zeros((2, rows + 1, heads, n, p),
+                      jnp.float32).at[1, 1:].set(s0)
+    slots = jnp.arange(1, rows + 1).at[1].set(0)
+    y, after = jax.jit(ssd.ssd_step_rows, static_argnums=7)(
+        x, B, C, dt, A, D, stack, 1, slots)
+    want_y, want_s = ssd.xla_step(x, B, C, dt, A, D, s0)
+    live = np.asarray(slots) > 0
+    out["step_y"] = rel(y[live], want_y[live])
+    out["step_s"] = rel(after[1, 1:][live], want_s[live])
+    check(bool(jnp.array_equal(after[1, 2], stack[1, 2])),
+          "ssd: a dead row's state moved")
+    # the chunk: a carried state, the last 40 positions pads
+    x, B, C, dt, A, D, s0 = operands(2, (1, w))
+    stack = jnp.zeros((2, 3, heads, n, p), jnp.float32).at[1, 2:].set(s0)
+    n_live = jnp.asarray([w - 40])
+    y, after = jax.jit(ssd.ssd_chunk_rows, static_argnums=7)(
+        x, B, C, dt, A, D, stack, 1, jnp.asarray([2]),
+        jnp.zeros((1,), bool), n_live)
+    f32 = lambda a: a.astype(jnp.float32)                   # noqa: E731
+    want_y, want_s = ssd.ssd_recurrent(
+        f32(x)[:, :w - 40], f32(B)[:, :w - 40], f32(C)[:, :w - 40],
+        dt[:, :w - 40], A, D, s0)
+    out["chunk_y"] = rel(y[:, :w - 40], want_y)
+    out["chunk_s"] = rel(after[1, 2:], want_s)
+    check(bool(jnp.array_equal(after[1, 1], stack[1, 1])),
+          "ssd: a chunk moved another slot's state")
+    # the pass between projection and rule: the decode rows (one dead), then
+    # a carried chunk row whose last 40 positions are pads
+    c = heads * p + 2 * groups * n
+    ks = jax.random.split(jax.random.PRNGKey(3), 5)
+    bf = lambda a: a.astype(jnp.bfloat16)                   # noqa: E731
+    taps = bf(jax.random.uniform(ks[0], (4, c), minval=-0.5, maxval=0.5))
+    bias = bf(jax.random.uniform(ks[4], (c,), minval=-0.5, maxval=0.5))
+    conv = bf(jax.random.normal(ks[1], (2, 3, gdn.conv_slot_rows(rows), c)))
+    conv = conv.at[:, :, 0].set(0).at[:, :, rows + 1:].set(0)
+    row_groups = {
+        "prep_step": (bf(jax.random.normal(ks[2], (rows, c))), slots, None,
+                      None),
+        "prep_chunk": (bf(jax.random.normal(ks[3], (1, w, c))),
+                       jnp.asarray([2]), jnp.zeros((1,), bool), n_live)}
+    out["prep_path"] = ssd.prep_path((rows, c), conv.shape)
+    for name, (xs, sl, fresh, n_tok) in row_groups.items():
+        got, after = jax.jit(ssd.ssd_prep_rows, static_argnums=4)(
+            xs, taps, bias, conv, 1, sl, fresh, n_tok)
+        want, left = ssd.xla_prep(xs, taps, bias, conv, 1, sl, fresh, n_tok)
+        cut = (lambda a: a[np.asarray(sl) > 0]) if n_tok is None \
+            else (lambda a: a[:, :w - 40])
+        out[name] = rel(cut(got), cut(want).astype(jnp.float32))
+        check(bool(jnp.array_equal(after[:, :, 1:], left[:, :, 1:])),
+              f"ssd: {name} leaves another history than its spelling")
+    for name in ("step_y", "step_s", "chunk_y", "chunk_s", "prep_step",
+                 "prep_chunk"):
+        check(out[name] <= TOL_GDN_OPS, f"ssd: {name} is {out[name]:.2e} "
+              f"from its reference (tol {TOL_GDN_OPS})")
+    return out
+
+
+def check_grouped_attention(rows: int, t: int, heads: int, kvh: int, d: int,
+                            ps: int, nps: int, live: int) -> float:
+    """``grouped_paged_attention`` by the platform's path (on the chip the
+    kernel ``grouped_paged_attn``) against its XLA spelling: ``rows`` rows of
+    ``t`` queries over ``live`` positions each, bf16 pages of ``kvh`` heads
+    under ``heads`` query heads, written by ``grouped_kv_scatter``."""
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.ops import paged_attention as pa
+
+    ks = jax.random.split(jax.random.PRNGKey(11), 3)
+    pages = -(-live // ps)
+    table = (1 + jnp.arange(rows * pages, dtype=jnp.int32)).reshape(
+        rows, pages)
+    table = jnp.pad(table, ((0, 0), (0, nps - pages)))
+    pool = jnp.zeros((1, rows * pages + 1, 2 * kvh, ps, d), jnp.bfloat16)
+    pos = jnp.tile(jnp.arange(live, dtype=jnp.int32), rows)
+    page = table[jnp.repeat(jnp.arange(rows), live), pos // ps]
+    kv = jax.random.normal(ks[0], (2, rows * live, kvh, d)).astype(
+        jnp.bfloat16)
+    pool = jax.jit(pa.grouped_kv_scatter, static_argnums=5)(
+        pool, page, pos % ps, kv[0], kv[1], 0, table.reshape(-1))
+    q = jax.random.normal(ks[1], (rows, t, heads, d)).astype(jnp.bfloat16)
+    pos0 = jnp.full((rows,), live - t, jnp.int32)
+    tl = jnp.full((rows,), t, jnp.int32)
+    args = (q, pool, table, pos0, tl, 0)
+    got = jax.jit(pa.grouped_paged_attention, static_argnums=5)(*args)
+    want = jax.jit(pa.grouped_paged_attention, static_argnums=(5, 6))(
+        *args, "xla")
+    return _nerr(got, want)
+
+
+FALCON_REQUESTS = ((300, 24), (520, 20), (140, 40))
+
+
+def phase_falcon(cfg, num_slots: int, page_size: int, pages_per_slot: int,
+                 chunk: int, ops_shape, attn_shapes, want_path: str,
+                 requests=FALCON_REQUESTS) -> dict:
+    """Falcon-H1's pass (models/falcon_h1.py): the served state-space rule's
+    kernels (``ssd_step``, ``ssd_chunk`` and the pass before them) against
+    their references at ``ops_shape`` (heads, P, N, groups, decode rows,
+    chunk tokens), which must go by ``want_path`` (on the chip the
+    kernels'); grouped-query attention over pages without padded heads at
+    ``attn_shapes`` (the cell's decode rows and a chunk row's piece) against
+    its XLA spelling; then a small model through the engine (a state a slot
+    **and** grouped K/V pages in every layer): what it emitted is the
+    float32 reference's (models/falcon_h1_reference.py; shortfalls in units
+    of a position's own spread of logits), and its ticks counted their
+    step, chunk, pass and attention by ``want_path``."""
+    import dataclasses as dc
+    import types
+
+    import paddle_tpu as paddle
+    from paddle_tpu.models import falcon_h1_reference as ref
+    from paddle_tpu.models.falcon_h1 import FalconH1
+
+    errs = check_ssd_ops(*ops_shape)
+    path, prep = errs.pop("path"), errs.pop("prep_path")
+    say("falcon", f"{ops_shape[0]} heads of {ops_shape[1]} x {ops_shape[2]} "
+        f"in {ops_shape[3]} groups, {ops_shape[4]} decode rows and a chunk "
+        f"of {ops_shape[5]}: " + ", ".join(f"{k} {v:.2e}"
+                                           for k, v in errs.items())
+        + f" from their references (allowed {TOL_GDN_OPS}); path here: "
+        f"{path}")
+    check(path == prep == want_path, f"the state-space rule of "
+          f"{ops_shape[:4]} went by {path} and what lies before it by "
+          f"{prep}, not {want_path}")
+    for shape in attn_shapes:
+        err = check_grouped_attention(*shape)
+        errs["attn_%dx%d" % shape[:2]] = err
+        say("falcon", f"grouped attention, {shape[0]} rows of {shape[1]} "
+            f"queries, {shape[2]} heads over {shape[3]}, {shape[7]} live "
+            f"positions in pages of {shape[5]}: {err:.2e} from the XLA "
+            f"spelling (allowed {TOL_RAGGED})")
+        check(err <= TOL_RAGGED, f"grouped attention {shape} is {err:.2e} "
+              f"from its XLA spelling (tol {TOL_RAGGED})")
+    paddle.seed(0)
+    with paddle.LazyGuard():
+        net = FalconH1(cfg)
+    config = dc.asdict(cfg)
+
+    def forward(layers, other, seq):
+        return ref.forward((layers[f"layer{i}"]
+                            for i in range(cfg.num_hidden_layers)),
+                           other, seq, config)
+
+    def shortfall(state, other, targets):
+        short, mine, sigma = ref.shortfall(state, other, config, targets)
+        return short / sigma, mine
+
+    forward.ref = types.SimpleNamespace(shortfall=shortfall)
+    return {**serve_against_reference(
+        "falcon", net, (num_slots, page_size, pages_per_slot, chunk),
+        requests, forward, SSD_COUNTERS, want_path,
+        "a state a slot and grouped K/V pages in every layer"), **errs}
+
+
 # ---------------------------------------------------------------------------
 # phases 3 and 4: the hybrid trainer
 # ---------------------------------------------------------------------------
@@ -2009,6 +2203,23 @@ def main() -> int:
             experts_held=(4, 8), select_bias_range=0.02,
             max_position_embeddings=1024),
         8, 128, 8, 256, (32, 128, 128, 64, 256), "pallas"))
+    # Falcon-H1: the served state-space rule's kernels at its heads (32 of
+    # 128 x 256 in 2 groups, the cell's 80 decode rows and chunk of 256),
+    # grouped-query attention at the cell's rows (80 decode rows at 660 live
+    # positions, a chunk row's piece of 64 queries; 20 heads over 4, pages of
+    # 16), and a small model (8 SSD heads of 128 x 256, a convolution over
+    # 2,048 channels, 20 query heads over 4, eight decode rows, chunks of one
+    # SSD block) through the engine, whose ticks must take every kernel
+    from paddle_tpu.models.falcon_h1 import FalconH1Config
+
+    run("falcon", lambda: phase_falcon(
+        FalconH1Config(
+            vocab_size=1024, hidden_size=512, intermediate_size=1024,
+            num_hidden_layers=3, mamba_d_ssm=1024, mamba_n_heads=8,
+            max_position_embeddings=1024),
+        8, 16, 64, 128, (32, 128, 256, 2, 80, 256),
+        [(80, 1, 20, 4, 128, 16, 88, 660), (4, 64, 20, 4, 128, 16, 88, 512)],
+        "pallas"))
     run("train", lambda: phase_train(cfg, micro=2, n_micro=6, steps=4))
     if len(jax.devices()) >= 4 and "train" not in failed:
         run("multichip", lambda: phase_multichip(

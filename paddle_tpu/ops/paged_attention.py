@@ -1255,3 +1255,244 @@ def _selected_latent_pallas(q, pool, layer, page_table, pos0, true_len,
         interpret=_interpret(),
     )(page_table, pos0, true_len, jnp.asarray(layer, jnp.int32).reshape(1),
       q, *selection, pool)
+
+
+# --------------------------------------------------------------------------
+# grouped-query pages (ISSUE 54): fewer key/value heads than query heads
+# --------------------------------------------------------------------------
+# ``Pools`` keeps a page as ``[ps, NH, D]``, the heads on the sublanes, and
+# the ragged kernel reads a head by a strided load when the heads fill whole
+# tiles (16 rows of bf16). Four key/value heads fill no tile: rounded up to
+# 16 a token's K and V would be four times their size. A grouped pool is
+# ``[L, P, 2 KVH, ps, D]``: **a page's positions on the sublanes**, a head of
+# a page of 16 one bf16 tile, K's heads and then V's in ONE array, so that a
+# page is one contiguous 2 KVH x ps x D block that one copy fetches. Nothing
+# is padded: Falcon-H1's 4 heads of 128 are 2,048 B a token a layer. A
+# key/value head meets the ``G = NH / KVH`` query heads it serves in one
+# product: their queries lie side by side on the rows of its left operand.
+# Writes go a page at a time (``latent_scatter``'s way: a token's row is one
+# sublane of a tile, and XLA:TPU re-lays a whole pool around a scatter of
+# such rows): the pages a tick touches are read, given their new rows and
+# written back whole. (This section stands at the file's end, its names
+# appended to ``__all__`` here, so that no line above it moved: a Mosaic
+# kernel's serialized body carries its operations' line numbers, and the
+# accepted cells' programs are compared byte for byte,
+# tools/lower_served_ticks.py.)
+
+__all__ += ["grouped_paged_attention", "grouped_kv_scatter"]
+
+
+def grouped_kv_scatter(pool, page, off, kk, vv, layer, touched=None):
+    """Each token's keys and values ``kk``, ``vv`` [NT, KVH, D] written at
+    its ``(layer, page, off)`` of ``pool`` [L, P, 2 KVH, ps, D] (null page 0
+    for rows that write nothing). ``touched`` [n] names every page a token
+    writes to, as ``latent_scatter`` takes it (left out: every token's own).
+    The stack is written in place and returned."""
+    ps = pool.shape[-2]
+    vals = jnp.concatenate([kk, vv], axis=1)                # [NT, 2 KVH, D]
+    vals = vals if vals.dtype == pool.dtype else vals.astype(pool.dtype)
+    touched = page if touched is None else touched
+    hit = (page[None, None, :] == touched[:, None, None]) \
+        & (off[None, None, :] == jnp.arange(ps, dtype=off.dtype)[None, :, None])
+    # one token at most writes a row: a sum over one term, exact
+    new = _einsum_f32("thd,qot->qhod", vals, hit.astype(vals.dtype))
+    old = pool[layer, touched]                              # [n, 2 KVH, ps, D]
+    return pool.at[layer, touched].set(
+        jnp.where(jnp.any(hit, axis=-1)[:, None, :, None],
+                  new.astype(pool.dtype), old))
+
+
+def _grouped_gather_attend(q, pool, page_table, qpos, layer):
+    """``_gather_attend`` over a grouped pool: the rows' pages gathered into
+    ``[R, KVH, S_cap, D]`` views of K and of V, every key/value head against
+    its ``G`` query heads, the same mask constant and float32 softmax."""
+    r, t, nh, hd = q.shape
+    kvh, ps = pool.shape[-3] // 2, pool.shape[-2]
+    g = nh // kvh
+    got = pool[layer, page_table]                   # [R, NPs, 2 KVH, ps, D]
+    if got.dtype != q.dtype:
+        got = got.astype(jnp.promote_types(got.dtype, q.dtype))
+    got = jnp.swapaxes(got, 1, 2).reshape(r, 2 * kvh, -1, hd)
+    k_c, v_c = got[:, :kvh], got[:, kvh:]
+    key_pos = jnp.arange(page_table.shape[1] * ps)
+    mask = key_pos[None, None, None, None, :] <= qpos[:, None, None, :, None]
+    att = jnp.einsum("btkgd,bksd->bkgts", q.reshape(r, t, kvh, g, hd),
+                     k_c) / math.sqrt(hd)
+    att = jnp.where(mask, att, _NEG_INF)
+    w = jax.nn.softmax(att.astype(jnp.float32), axis=-1).astype(q.dtype)
+    out = jnp.einsum("bkgts,bksd->btkgd", w, v_c).reshape(r, t, nh, hd)
+    return out if out.dtype == q.dtype else out.astype(q.dtype)
+
+
+def grouped_paged_attention(q, pool, page_table, pos0, true_len, layer,
+                            impl=None):
+    """``ragged_paged_attention`` over a grouped pool ``[L, P, 2 KVH, ps,
+    D]``: ``q`` [R, T, NH, D] with ``NH`` a multiple of ``KVH`` (query heads
+    ``k G .. (k + 1) G - 1`` read key/value head ``k``), the rows' metadata as
+    there. The same two spellings, picked and counted the same way
+    (``resolve_impl``; ``serving/attn_calls{path=}``). Returns [R, T, NH,
+    D]."""
+    from ..profiler import metrics
+
+    impl = resolve_impl(impl)
+    if impl not in ("xla", "pallas"):
+        raise ValueError(f"unknown paged attention impl {impl!r}")
+    metrics.registry().counter(
+        "serving/attn_calls{path=%s}" % impl).add(1)
+    if impl == "xla":
+        t = q.shape[1]
+        qpos = pos0[:, None] + jnp.arange(t, dtype=pos0.dtype)[None, :]
+        return _grouped_gather_attend(q, pool, page_table, qpos, layer)
+    return _grouped_attention_pallas(q, pool, page_table, pos0, true_len,
+                                     layer)
+
+
+def _grouped_kernel(pt_ref, pos0_ref, tl_ref, layer_ref, q_ref, kv_hbm, o_ref,
+                    buf, sem, slot_ref, m_ref, l_ref, acc_ref, *, group: int):
+    """Grid (r,): row ``r``, ``_ragged_kernel``'s walk (the pool in HBM, the
+    KV axis a loop over blocks of ``bp`` pages whose trip count is the row's
+    own, two buffers, the next block or the next row's first in flight). A
+    page is one copy, K's heads and V's together. ``q_ref`` ``[1, KVH, G Tp,
+    D]``: a key/value head's ``G`` query heads of ``Tp`` queries each, row
+    ``j Tp + i`` query ``i`` of head ``j``, one left operand of its two
+    products a block."""
+    _, bp, kv2, ps, hd = buf.shape
+    kvh = kv2 // 2
+    m = q_ref.shape[2]
+    tp = m // group
+    nps = pt_ref.shape[1]
+    bt = bp * ps
+    r = pl.program_id(0)
+    last_row = r + 1 == pl.num_programs(0)
+    layer = layer_ref[0]
+
+    def kv_len(row):
+        n = jnp.minimum(pos0_ref[row] + tl_ref[row], nps * ps)
+        return jnp.where(tl_ref[row] > 0, n, 0)
+
+    def copies(row, blk, slot, act):
+        first = blk * bp
+        count = jnp.minimum(pl.cdiv(kv_len(row), ps) - first, bp)
+
+        def one(i, carry):
+            act(pltpu.make_async_copy(
+                kv_hbm.at[layer, pt_ref[row, first + i]], buf.at[slot, i],
+                sem.at[slot]))
+            return carry
+
+        jax.lax.fori_loop(0, count, one, 0)
+
+    start = lambda c: c.start()
+    wait = lambda c: c.wait()
+    n_live = kv_len(r)
+    nblk = pl.cdiv(n_live, bt)
+
+    @pl.when(r == 0)
+    def _first():
+        slot_ref[0] = 0
+        copies(r, 0, 0, start)
+
+    slot0 = slot_ref[0]
+    m_ref[:] = jnp.full_like(m_ref, _NEG_INF)
+    l_ref[:] = jnp.zeros_like(l_ref)
+    acc_ref[:] = jnp.zeros_like(acc_ref)
+    # the query of each row of a head's operand
+    qi = jax.lax.broadcasted_iota(jnp.int32, (group, tp, bt), 1).reshape(
+        1, m, bt)
+    qpos = pos0_ref[r] + qi
+
+    def block(b, carry):
+        slot = (slot0 + b) % 2
+
+        @pl.when(b + 1 < nblk)
+        def _next_block():
+            copies(r, b + 1, 1 - slot, start)
+
+        @pl.when(jnp.logical_and(b + 1 == nblk, jnp.logical_not(last_row)))
+        def _next_row():
+            copies(r + 1, 0, 1 - slot, start)
+
+        copies(r, b, slot, wait)
+        src = buf.at[slot]
+        left = n_live - b * bt              # live positions of this block
+        head = lambda h: src[:, h].reshape(bt, hd)          # noqa: E731
+        s = jnp.stack([_dot(q_ref[0, h], head(h), (((1,), (1,)), ((), ())))
+                       for h in range(kvh)]) / math.sqrt(hd)  # [KVH, M, bt]
+        kpos = b * bt + jax.lax.broadcasted_iota(jnp.int32, (1, m, bt), 2)
+        keep = kpos <= jnp.minimum(qpos, n_live - 1)
+        s = jnp.where(keep, s, _NEG_INF)
+        m_prev = m_ref[:]                               # [KVH, M, 1]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=2, keepdims=True))
+        p = jnp.exp(s - m_new)
+        corr = jnp.exp(m_prev - m_new)
+        l_ref[:] = corr * l_ref[:] + jnp.sum(p, axis=2, keepdims=True)
+        m_ref[:] = m_new
+        # rows past the live ones hold what an earlier block left there, or
+        # nothing at all: 0 x NaN must not reach the accumulator
+        dead = jax.lax.broadcasted_iota(jnp.int32, (bt, hd), 0) >= left
+        pv = []
+        for h in range(kvh):
+            v = head(kvh + h)
+            pv.append(_dot(p[h].astype(v.dtype),
+                           jnp.where(dead, jnp.zeros_like(v), v),
+                           (((1,), (0,)), ((), ()))))
+        acc_ref[:] = corr * acc_ref[:] + jnp.stack(pv)
+        return carry
+
+    jax.lax.fori_loop(0, nblk, block, 0)
+
+    @pl.when(jnp.logical_and(nblk == 0, jnp.logical_not(last_row)))
+    def _next_row_of_an_empty_one():
+        copies(r + 1, 0, slot0, start)
+
+    slot_ref[0] = (slot0 + nblk) % 2
+    l = l_ref[:]
+    o_ref[0] = acc_ref[:] / jnp.where(l == 0.0, 1.0, l)
+
+
+def _grouped_attention_pallas(q, pool, page_table, pos0, true_len, layer):
+    r, t, nh, hd = q.shape
+    kv2, ps = pool.shape[-3], pool.shape[-2]
+    kvh = kv2 // 2
+    g = nh // kvh
+    nps = page_table.shape[1]
+    bp = kv_block_pages(ps, nps)
+    kv_dtype = jnp.promote_types(pool.dtype, q.dtype)
+    if pool.dtype != kv_dtype:
+        raise NotImplementedError(
+            f"grouped pages of {pool.dtype} under queries of {q.dtype}: the "
+            "kernel multiplies pages as they lie")
+    rows = 8 * _rows_per_word(kv_dtype)
+    tp = -(-t // rows) * rows
+    # [R, KVH, G Tp, D]: a key/value head's query heads one after the other
+    qk = jnp.transpose(q.astype(kv_dtype).reshape(r, t, kvh, g, hd),
+                       (0, 2, 3, 1, 4))
+    if tp != t:
+        qk = jnp.pad(qk, ((0, 0),) * 3 + ((0, tp - t), (0, 0)))
+    m = g * tp
+    block = pl.BlockSpec((1, kvh, m, hd),
+                         lambda i, pt, p0, tl, ly: (i, 0, 0, 0))
+    out = pl.pallas_call(
+        functools.partial(_grouped_kernel, group=g),
+        name="grouped_paged_attn",
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4,
+            grid=(r,),
+            in_specs=[block, pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=block,
+            scratch_shapes=[pltpu.VMEM((2, bp, kv2, ps, hd), pool.dtype),
+                            pltpu.SemaphoreType.DMA((2,)),
+                            pltpu.SMEM((1,), jnp.int32),
+                            pltpu.VMEM((kvh, m, 1), jnp.float32),
+                            pltpu.VMEM((kvh, m, 1), jnp.float32),
+                            pltpu.VMEM((kvh, m, hd), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct((r, kvh, m, hd), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=_interpret(),
+    )(page_table, pos0, true_len,
+      jnp.asarray(layer, jnp.int32).reshape(1),
+      qk.reshape(r, kvh, m, hd), pool)
+    out = out.reshape(r, kvh, g, tp, hd)[:, :, :, :t]
+    return jnp.transpose(out, (0, 3, 1, 2, 4)).reshape(q.shape).astype(
+        q.dtype)

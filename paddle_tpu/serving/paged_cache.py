@@ -63,9 +63,11 @@ import jax.numpy as jnp
 from ..ops.gdn import (conv_slot_rows, gdn_chunk_rows, gdn_prep_rows,
                        gdn_step_rows, pack_state, unpack_state)
 from ..ops.paged_attention import (
-    index_scores, latent_attention, latent_scatter, paged_kv_scatter,
+    grouped_kv_scatter, grouped_paged_attention, index_scores,
+    latent_attention, latent_scatter, paged_kv_scatter,
     ragged_paged_attention, selected_latent_attention,
     window_latent_attention)
+from ..ops.ssd import ssd_chunk_rows, ssd_prep_rows, ssd_step_rows
 
 NULL_PAGE = 0
 
@@ -1216,6 +1218,55 @@ class LatentPagePool(PagePool):
         return out
 
 
+class GroupedPools(NamedTuple):
+    """K/V pages of a model with fewer key/value heads than query heads
+    (ISSUE 54, ``models/falcon_h1.py``: 20 query heads over 4), as the device
+    holds them: ONE array ``[L, P, 2 KVH, ps, D]``, K's heads and then V's,
+    **a page's positions on the sublanes** (``ops/paged_attention`` says why:
+    ``Pools`` keeps the heads there and rounds them up to whole tiles, which
+    four heads would pay four times over). No head is padded: a token is ``2
+    KVH D`` numbers a layer. A pytree like ``Pools`` and the one place that
+    knows this format: ``scatter`` writes a page at a time and ``attend``
+    gives every key/value head its ``NH / KVH`` query heads in one product
+    (``grouped_kv_scatter``, ``grouped_paged_attention``)."""
+
+    kv: jax.Array
+
+    quantized = False
+
+    @classmethod
+    def zeros(cls, num_layers: int, num_pages: int, page_size: int,
+              kv_heads: int, head_dim: int, dtype) -> "GroupedPools":
+        return cls(jnp.zeros((num_layers, num_pages, 2 * kv_heads, page_size,
+                              head_dim), dtype))
+
+    @property
+    def page_size(self) -> int:
+        return self.kv.shape[-2]
+
+    def arrays(self) -> Dict[str, jax.Array]:
+        return {"kv": self.kv}
+
+    def scatter(self, layer, page, off, kk, vv, touched=None):
+        """``kk``/``vv`` [NT, 1, KVH, D] at ``(layer, page, off)``;
+        ``touched`` the pages the tick's tokens write to
+        (``models/tick.TickRows.touched``)."""
+        return GroupedPools(grouped_kv_scatter(
+            self.kv, page, off, kk[:, 0], vv[:, 0], layer, touched))
+
+    def attend(self, layer, q, page_table, pos0, true_len):
+        return grouped_paged_attention(q, self.kv, page_table, pos0,
+                                       true_len, layer)
+
+    def rows_of(self, layer, pages):
+        """``(k, v)`` of ``pages`` [n], each ``[n ps, KVH, D]``: the
+        positions of those pages in order, what a check reads."""
+        got = self.kv[layer, pages]                     # [n, 2 KVH, ps, D]
+        kvh = got.shape[1] // 2
+        flat = jnp.swapaxes(got, 1, 2).reshape(-1, 2 * kvh, got.shape[-1])
+        return flat[:, :kvh], flat[:, kvh:]
+
+
 class StatePools(NamedTuple):
     """The caches of a model whose layers are of two kinds (ISSUE 44,
     ``models/olmo_hybrid.py``; ISSUE 49, ``models/ling3.py``): attention over
@@ -1276,24 +1327,33 @@ class StatePools(NamedTuple):
     def zeros(cls, caches: dict, num_pages: int, page_size: int,
               num_slots: int, dtype) -> "StatePools":
         tile = 8 * (4 // jnp.dtype(dtype).itemsize)
-        one = pack_state(jnp.zeros(
-            (caches["state_heads"], caches["key_dim"], caches["value_dim"]),
-            jnp.float32))
         if "latent_width" in caches:
             pages = LatentPools.zeros(caches["layers"], num_pages, 0, 2,
                                       page_size, caches["latent_width"], 0,
                                       0, dtype)
+        elif caches.get("key_value_heads", caches["heads"]) \
+                < caches["heads"]:
+            pages = GroupedPools.zeros(caches["layers"], num_pages,
+                                       page_size, caches["key_value_heads"],
+                                       caches["head_dim"], dtype)
         else:
             pages = Pools.zeros(caches["layers"], num_pages, page_size,
                                 -(-caches["heads"] // tile) * tile,
                                 caches["head_dim"], dtype)
         return cls(
             pages,
-            jnp.zeros((caches["state_layers"], num_slots + 1) + one.shape,
-                      jnp.float32),
+            jnp.zeros((caches["state_layers"], num_slots + 1)
+                      + cls.state_shape(caches), jnp.float32),
             jnp.zeros((caches["state_layers"], caches["conv_taps"] - 1,
                        conv_slot_rows(num_slots), caches["conv_width"]),
                       dtype))
+
+    @staticmethod
+    def state_shape(caches: dict) -> Tuple[int, ...]:
+        """One slot's state of one layer (``ops/gdn.pack_state``'s layout)."""
+        return pack_state(jnp.zeros(
+            (caches["state_heads"], caches["key_dim"], caches["value_dim"]),
+            jnp.float32)).shape
 
     @property
     def page_size(self) -> int:
@@ -1310,11 +1370,18 @@ class StatePools(NamedTuple):
         pad = self.kv.k.shape[-2] - a.shape[-2]
         return jnp.pad(a, ((0, 0),) * (a.ndim - 2) + ((0, pad), (0, 0)))
 
-    def scatter(self, layer, page, off, kk, vv) -> "StatePools":
+    def scatter(self, layer, page, off, kk, vv, touched=None):
+        """``touched``: the pages the tokens write to, for grouped pages,
+        which are written a page at a time (``GroupedPools``)."""
+        if isinstance(self.kv, GroupedPools):
+            return self._replace(kv=self.kv.scatter(layer, page, off, kk, vv,
+                                                    touched))
         return self._replace(kv=self.kv.scatter(
             layer, page, off, self._head_rows(kk), self._head_rows(vv)))
 
     def attend(self, layer, q, page_table, pos0, true_len):
+        if isinstance(self.kv, GroupedPools):
+            return self.kv.attend(layer, q, page_table, pos0, true_len)
         return self.kv.attend(layer, self._head_rows(q), page_table, pos0,
                               true_len)[..., :q.shape[-2], :]
 
@@ -1355,6 +1422,50 @@ class StatePools(NamedTuple):
     def state_of(self, layer, slots, heads: int):
         """``[n, heads, dk, dv]`` float32: the states a check reads."""
         return unpack_state(self.state[layer, slots], heads)
+
+
+class SSDStatePools(StatePools):
+    """``StatePools`` of a model whose state follows the state-space rule of
+    Mamba-2 (ISSUE 54, ``models/falcon_h1.py``; a ``cache_spec()`` with
+    ``"rule": "ssd"``): the same three fields, the same null slot, the same
+    ``fresh``; ``prep``, ``step`` and ``chunk`` are ``ops/ssd``'s functions
+    on them. The state is ``[Ll, slots + 1, heads, N, P]`` float32 (the
+    spec's ``key_dim`` the state's size ``N``, its ``value_dim`` a head's
+    channels ``P``: a head's state transposed, ``ops/ssd`` says why), the
+    history ``[Ll, taps - 1, rows, C]`` of ``C = [x | B | C]``."""
+
+    __slots__ = ()
+
+    @staticmethod
+    def state_shape(caches: dict) -> Tuple[int, ...]:
+        return (caches["state_heads"], caches["key_dim"],
+                caches["value_dim"])
+
+    def prep(self, layer, slots, x, taps, bias, fresh=None, row_len=None):
+        """``ops/ssd.ssd_prep_rows`` at ``layer``'s history: the rows'
+        ``[x | B | C]`` projections through the convolution after what their
+        slots carry, its bias and SiLU. -> ``(the activated rows, the pools
+        with the history the rows leave)``."""
+        y, conv = ssd_prep_rows(x, taps, bias, self.conv, layer, slots,
+                                fresh, row_len)
+        return y, self._replace(conv=conv)
+
+    def step(self, layer, slots, x, B, C, dt, A, D):
+        """``ops/ssd.ssd_step_rows`` at ``layer``'s states."""
+        y, state = ssd_step_rows(x, B, C, dt, A, D, self.state, layer, slots)
+        return y, self._replace(state=state)
+
+    def chunk(self, layer, slots, fresh, row_len, x, B, C, dt, A, D):
+        """``ops/ssd.ssd_chunk_rows`` at ``layer``'s states."""
+        y, state = ssd_chunk_rows(x, B, C, dt, A, D, self.state, layer,
+                                  slots, fresh, row_len)
+        return y, self._replace(state=state)
+
+    def state_of(self, layer, slots, heads: int):
+        """``[n, heads, P, N]`` float32, a head's state as the rule is
+        written (``S`` in ``R^{P x N}``): the states a check reads."""
+        del heads
+        return jnp.swapaxes(self.state[layer, slots], -1, -2)
 
 
 class StatePagePool(PagePool):
@@ -1416,13 +1527,14 @@ class StatePagePool(PagePool):
                 f"prefill_chunk {chunk} is not whole pages of {page_size}: "
                 "a tick tells a slot between two chunks of its prompt by "
                 "the page its decode row's token would need (StatePagePool)")
-        pools = StatePools.zeros(caches, num_pages, page_size, num_slots,
-                                 dtype)
+        kind = SSDStatePools if caches.get("rule") == "ssd" else StatePools
+        pools = kind.zeros(caches, num_pages, page_size, num_slots, dtype)
         #: the pages' format, as the engine's gauges name it
         self.pages_kind = "latent" if "latent_width" in caches else "kv"
         heads, width = (1, caches["latent_width"]) \
             if self.pages_kind == "latent" \
-            else (caches["heads"], caches["head_dim"])
+            else (caches.get("key_value_heads", caches["heads"]),
+                  caches["head_dim"])
         super().__init__(caches["layers"], num_pages, page_size, heads,
                          width, num_slots, pages_per_slot, dtype=dtype,
                          pools=pools)

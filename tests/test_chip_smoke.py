@@ -336,6 +336,28 @@ def test_ling_rehearsal():
     assert out["weights_bytes"] == 2 * cfg.num_params()
 
 
+def test_falcon_rehearsal():
+    """Falcon-H1's pass at toy sizes: the served state-space rule against its
+    references (off the chip: the spellings themselves), grouped attention
+    against its spelling, a small model's tokens through a state a slot and
+    grouped K/V pages in every layer against the float32 reference."""
+    from paddle_tpu.models.falcon_h1 import FalconH1Config
+
+    cfg = FalconH1Config.tiny(initializer_range=0.05)
+    out = chip_smoke.phase_falcon(
+        cfg, 3, 4, 24, 8, (4, 8, 16, 2, 5, 128),
+        [(3, 1, 4, 2, 16, 4, 24, 37)], "xla",
+        requests=((23, 12), (41, 10), (7, 16)))
+    assert out["tick_paths"] == {"step": ["xla"], "chunk": ["xla"],
+                                 "prep": ["xla"], "attn": ["xla"]}
+    assert max(out["step_y"], out["step_s"], out["chunk_y"],
+               out["chunk_s"]) <= chip_smoke.TOL_GDN_OPS
+    assert out["prep_step"] == out["prep_chunk"] == 0   # the spelling itself
+    assert out["attn_3x1"] == 0
+    assert out["median"] <= out["worst"] <= chip_smoke.TOL_GDN_WORST
+    assert out["weights_bytes"] == 2 * cfg.num_params()
+
+
 @pytest.mark.parametrize("counted,fault", [
     ({(128, 512, 128): 12, (128, 128, 512): 6}, None),
     # a product that went by the kernel and counted no tile

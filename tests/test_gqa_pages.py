@@ -1,0 +1,176 @@
+"""Grouped-query attention over K/V pages (``paged_cache.GroupedPools``,
+``ops/paged_attention.grouped_paged_attention`` and ``grouped_kv_scatter``):
+fewer key/value heads than query heads, a page's positions on the sublanes,
+no padded head. Both spellings (the kernel interpreted) against dense
+attention; the write a page at a time; and that a multi-head spec builds the
+pool it built before."""
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from paddle_tpu.ops import paged_attention as pa
+from paddle_tpu.serving.paged_cache import (GroupedPools, Pools, StatePools,
+                                            page_pool)
+
+PS = 8
+
+
+def _dense(q, k, v, pos0, true_len):
+    """Plain causal attention a row: q [T, NH, D] at positions ``pos0 +
+    i`` over k, v [S, KVH, D], head ``j`` reading key/value head ``j //
+    (NH / KVH)``; float64."""
+    t, nh, d = q.shape
+    per = nh // k.shape[1]
+    out = np.zeros((t, nh, d))
+    for i in range(min(t, true_len)):
+        seen = pos0 + i + 1
+        for j in range(nh):
+            s = k[:seen, j // per] @ q[i, j] / np.sqrt(d)
+            w = np.exp(s - s.max())
+            out[i, j] = (w / w.sum()) @ v[:seen, j // per]
+    return out
+
+
+def _filled(seed, layers, rows, nps, kvh, d, lens, dtype=jnp.float32):
+    """A grouped pool whose rows hold ``lens`` positions each on pages of
+    their own (shuffled ids), NaN on every page no row reaches."""
+    rng = np.random.default_rng(seed)
+    n_pages = 1 + rows * nps
+    ids = rng.permutation(np.arange(1, n_pages)).reshape(rows, nps)
+    pool = np.full((layers, n_pages, 2 * kvh, PS, d), np.nan, np.float32)
+    pool[:, 0] = 0.0
+    ks, vs = [], []
+    for r, n in enumerate(lens):
+        k = rng.standard_normal((layers, n, kvh, d)).astype(np.float32)
+        v = rng.standard_normal((layers, n, kvh, d)).astype(np.float32)
+        ks.append(k), vs.append(v)
+        for p in range(-(-n // PS)):
+            got = slice(p * PS, min((p + 1) * PS, n))
+            m = got.stop - got.start
+            pool[:, ids[r, p], :kvh, :m] = np.swapaxes(k[:, got], 1, 2)
+            pool[:, ids[r, p], kvh:, :m] = np.swapaxes(v[:, got], 1, 2)
+            pool[:, ids[r, p], :, m:] = 0.0 if m < PS else pool[
+                :, ids[r, p], :, m:]
+    table = np.where(np.arange(nps)[None, :] < -(-np.asarray(lens)[:, None]
+                                                 // PS), ids, 0)
+    return jnp.asarray(pool, dtype), jnp.asarray(table, jnp.int32), ks, vs
+
+
+@pytest.mark.parametrize("impl", ["xla", "pallas"])
+@pytest.mark.parametrize("heads,kvh,d", [(20, 4, 32), (4, 2, 16)])
+def test_decode_rows_against_dense_attention(impl, heads, kvh, d):
+    lens = [37, 0, 8, 63]
+    pool, table, ks, vs = _filled(0, 2, 4, 8, kvh, d, lens)
+    rng = np.random.default_rng(1)
+    q = rng.standard_normal((4, 1, heads, d)).astype(np.float32)
+    pos0 = jnp.asarray([n - 1 if n else 0 for n in lens], jnp.int32)
+    tl = jnp.asarray([1 if n else 0 for n in lens], jnp.int32)
+    if impl == "xla":
+        # the gather reads whole tables: no NaN behind the mask's zeros
+        pool = jnp.nan_to_num(pool)
+    got = pa.grouped_paged_attention(jnp.asarray(q), pool, table, pos0, tl,
+                                     1, impl=impl)
+    assert got.shape == q.shape
+    for r, n in enumerate(lens):
+        if n:
+            want = _dense(q[r], ks[r][1], vs[r][1], n - 1, 1)
+            np.testing.assert_allclose(got[r], want, atol=2e-5, rtol=2e-5)
+    assert bool(jnp.all(jnp.isfinite(got)))
+
+
+@pytest.mark.parametrize("impl", ["xla", "pallas"])
+@pytest.mark.parametrize("heads,kvh,d", [(20, 4, 32), (4, 2, 16)])
+def test_chunk_rows_against_dense_attention(impl, heads, kvh, d):
+    t = 16
+    lens = [40, 21]                 # a chunk of 16 after 24, one of 13 after 8
+    pool, table, ks, vs = _filled(2, 1, 2, 8, kvh, d, lens)
+    q = np.random.default_rng(3).standard_normal(
+        (2, t, heads, d)).astype(np.float32)
+    pos0, tl = jnp.asarray([24, 8], jnp.int32), jnp.asarray([16, 13],
+                                                            jnp.int32)
+    if impl == "xla":
+        pool = jnp.nan_to_num(pool)
+    got = pa.grouped_paged_attention(jnp.asarray(q), pool, table, pos0, tl,
+                                     0, impl=impl)
+    for r in range(2):
+        n = int(tl[r])
+        want = _dense(q[r], ks[r][0], vs[r][0], int(pos0[r]), n)
+        np.testing.assert_allclose(got[r, :n], want[:n], atol=2e-5,
+                                   rtol=2e-5)
+
+
+def test_bf16_pages_meet_the_product_as_they_lie():
+    lens = [29, 50]
+    pool, table, ks, vs = _filled(4, 1, 2, 8, 4, 32, lens, jnp.bfloat16)
+    q = jnp.asarray(np.random.default_rng(5).standard_normal(
+        (2, 1, 20, 32)), jnp.bfloat16)
+    pos0 = jnp.asarray([28, 49], jnp.int32)
+    tl = jnp.asarray([1, 1], jnp.int32)
+    a = pa.grouped_paged_attention(q, jnp.nan_to_num(pool), table, pos0, tl,
+                                   0, impl="xla")
+    b = pa.grouped_paged_attention(q, pool, table, pos0, tl, 0,
+                                   impl="pallas")
+    assert b.dtype == jnp.bfloat16
+    np.testing.assert_allclose(np.asarray(a, np.float32),
+                               np.asarray(b, np.float32), atol=3e-2)
+
+
+def test_the_write_goes_a_page_at_a_time_and_touches_no_other():
+    kvh, d = 2, 16
+    pools = GroupedPools.zeros(2, 6, PS, kvh, d, jnp.float32)
+    pools = GroupedPools(pools.kv + 7.0)
+    rng = np.random.default_rng(6)
+    k = rng.standard_normal((5, 1, kvh, d)).astype(np.float32)
+    v = rng.standard_normal((5, 1, kvh, d)).astype(np.float32)
+    page = jnp.asarray([3, 3, 5, 0, 1], jnp.int32)      # one token writes
+    off = jnp.asarray([2, 3, 0, 4, 7], jnp.int32)       # nothing (page 0)
+    touched = jnp.asarray([3, 5, 1, 0, 0, 3], jnp.int32)
+    new = pools.scatter(1, page, off, jnp.asarray(k), jnp.asarray(v),
+                        touched)
+    np.testing.assert_array_equal(new.kv[0], pools.kv[0])
+    for t, (p, o) in enumerate(zip([3, 3, 5, None, 1], [2, 3, 0, 4, 7])):
+        if p is None:
+            continue
+        np.testing.assert_array_equal(new.kv[1, p, :kvh, o], k[t, 0])
+        np.testing.assert_array_equal(new.kv[1, p, kvh:, o], v[t, 0])
+    # every other position of the touched pages, and every other page, is as
+    # it was (the token that writes nothing wrote to the null page)
+    moved = np.asarray(new.kv[1] != pools.kv[1])
+    assert moved.sum() == 5 * 2 * kvh * d and moved[0, :, 4].all()
+    np.testing.assert_array_equal(new.kv[1, jnp.asarray([2, 4])], 7.0)
+    # without the list every token's own page is read and written
+    same = pools.scatter(1, page, off, jnp.asarray(k), jnp.asarray(v))
+    np.testing.assert_array_equal(
+        np.asarray(same.kv[:, 1:]), np.asarray(new.kv[:, 1:]))
+    ks, vs = new.rows_of(1, jnp.asarray([3]))
+    np.testing.assert_array_equal(ks[2], k[0, 0])
+    np.testing.assert_array_equal(vs[3], v[1, 0])
+
+
+def test_a_multi_head_spec_builds_the_pool_it_built_before():
+    """``StatePools`` of a spec that names no ``key_value_heads`` (Olmo-
+    Hybrid's, Ling's) are ``Pools`` of head rows rounded to whole tiles, and
+    its ``scatter`` and ``attend`` take the calls they took."""
+    spec = {"kind": "state", "layers": 2, "heads": 6, "head_dim": 8,
+            "state_layers": 4, "state_heads": 6, "key_dim": 24,
+            "value_dim": 48, "conv_width": 576, "conv_taps": 4}
+    pools = StatePools.zeros(spec, 10, 4, 3, jnp.bfloat16)
+    assert isinstance(pools.kv, Pools) and type(pools) is StatePools
+    assert pools.kv.k.shape == (2, 10, 4, 16, 8)
+    assert pools.state.shape == (4, 4, 3, 24, 96)
+    same = dict(spec, key_value_heads=6)
+    assert isinstance(StatePools.zeros(same, 10, 4, 3, jnp.bfloat16).kv,
+                      Pools)
+    pools = StatePools.zeros(spec, 10, 4, 3, jnp.float32)
+    k = jnp.ones((3, 1, 6, 8))
+    new = pools.scatter(0, jnp.asarray([1, 2, 0]), jnp.asarray([0, 1, 2]),
+                        k, 2 * k)
+    assert float(new.kv.v[0, 2, 1, 0, 0]) == 2.0
+    lowered = jax.jit(lambda p, q: p.attend(
+        1, q, jnp.zeros((2, 3), jnp.int32), jnp.zeros((2,), jnp.int32),
+        jnp.ones((2,), jnp.int32))).lower(pools, jnp.ones((2, 1, 6, 8)))
+    assert "grouped" not in lowered.as_text()
+    pool = page_pool({"kind": "kv", "layers": 2, "heads": 4, "head_dim": 8},
+                     10, 4, 2, 4, 8, jnp.float32, False, False)
+    assert isinstance(pool.pools, Pools)

@@ -877,3 +877,69 @@ def test_tpu_compile_the_ling3_models_forward(monkeypatch):
         "the donated pools and states are not aliased"
     assert ma.temp_size_in_bytes < pools.state.size * 4 / 3, \
         "a layer's states are copied"
+
+
+def test_tpu_compile_the_falcon_h1_models_forward(monkeypatch):
+    """ISSUE 54: Falcon-H1's tick forward (``models/falcon_h1.
+    falcon_h1_ragged_apply``: in every layer a float32 SSD state of 32 heads
+    of 256 x 128 a slot beside grouped K/V pages of 4 heads under 20)
+    compiles for the v5e from a ``LazyGuard`` model at the published widths
+    of two layers, the cell's 80 decode rows and its chunk row of 256: the
+    rule's two kernels, the pass before them and the grouped ragged kernel
+    are in the program (the decode rows' call and the chunk row's), the
+    donated pools are aliased, no layer's states are copied and no pool is
+    re-laid around a write."""
+    import jax.numpy as jnp
+
+    import paddle_tpu as paddle
+    from paddle_tpu.models.falcon_h1 import (FalconH1, FalconH1Config,
+                                             falcon_h1_ragged_apply)
+    from paddle_tpu.models.tick import state_drawer
+    from paddle_tpu.serving.paged_cache import GroupedPools, SSDStatePools
+
+    dev = _tpu_topology_devices()[0]
+    cfg = FalconH1Config(num_hidden_layers=2, vocab_size=1024)
+    with paddle.LazyGuard():
+        net = FalconH1(cfg)
+    net.bfloat16()
+    state = jax.eval_shape(state_drawer(net),
+                           jax.ShapeDtypeStruct((2,), jnp.uint32))
+    ns, ps, nps, w = 80, 16, 88, 256
+    pools = jax.eval_shape(lambda: SSDStatePools.zeros(
+        net.cache_spec(), ns * nps + 1, ps, ns, jnp.bfloat16))
+    assert isinstance(pools.kv, GroupedPools)
+    assert pools.kv.kv.shape == (2, ns * nps + 1, 8, ps, 128)
+    assert pools.state.shape == (2, ns + 1, 32, 256, 128)
+    assert pools.conv.shape == (2, 3, 96, 5120)
+    nt = ns + w
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32)
+
+    args = (state[0], state[1], pools, i32(nt), i32(nt), i32(nt),
+            (i32(ns + 1, nps), i32(ns + 1)), i32(ns + 1), i32(ns + 1),
+            i32(ns))
+    args = jax.tree_util.tree_map(
+        lambda a: _on_tpu(dev, a.shape, a.dtype), args)
+
+    def forward(*a):
+        return falcon_h1_ragged_apply(cfg, *a, decode_rows=ns, chunk_width=w)
+
+    monkeypatch.setenv("PADDLE_TPU_TARGET_PLATFORM", "tpu")
+    with jax.default_matmul_precision("default"):
+        compiled = jax.jit(forward, donate_argnums=2).lower(*args).compile()
+    text = compiled.as_text()
+    assert len(re.findall(r"%ssd_step[\w.\-]* = ", text)) == 2
+    assert len(re.findall(r"%ssd_chunk[\w.\-]* = ", text)) == 2
+    assert len(re.findall(r"%ssd_prep_step[\w.\-]* = ", text)) == 2
+    assert len(re.findall(r"%ssd_prep_chunk[\w.\-]* = ", text)) == 2
+    assert len(re.findall(r"%grouped_paged_attn[\w.\-]* = ", text)) == 4
+    assert not re.findall(r"%ragged_paged_attn[\w.\-]* = ", text)
+    assert "remat_compressed" not in text
+    ma = compiled.memory_analysis()
+    pool_bytes = sum(a.size * a.dtype.itemsize
+                     for a in jax.tree_util.tree_leaves(pools))
+    assert ma.alias_size_in_bytes >= pool_bytes, \
+        "the donated pools and states are not aliased"
+    assert ma.temp_size_in_bytes < pools.state.size * 4 / 2, \
+        "a layer's states are copied"

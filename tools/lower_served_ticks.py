@@ -10,8 +10,8 @@ change's (``git archive <commit> | tar -x -C <dir>``), with ``JAX_PLATFORMS=cpu`
 an engine is built and ticked on the CPU at the rows of ``gpt3-1.3b-serve`` and
 ``ouro-2.6b-serve``, at a tiny width with a draft model (both ticks), for a
 dots3 and a DeepSeek-V2 model at the published head counts and latent widths,
-and for an Olmo-Hybrid (PR 44) and a Ling-3.0 model (PR 49) at the published
-head sizes;
+and for an Olmo-Hybrid (PR 44), a Ling-3.0 (PR 49) and a Falcon-H1 model
+(PR 54) at the published head sizes;
 each tick is lowered again from the avals of its first dispatch as a program
 traced for the TPU (the attention kernels inside), and its StableHLO text,
 which carries no locations, is written to ``<out_dir>/<name>.<site>.txt``,
@@ -237,3 +237,28 @@ if Ling3 is not None:
         eng.step()
     eng.drain(0)
     lower("ling3", eng)
+
+# Falcon-H1 at its published head sizes (8 SSD heads of 128 channels and a
+# state of 256 in 2 groups, a convolution over 2,048 channels; 20 query heads
+# over 4 key/value heads of 128): a state a slot AND grouped K/V pages in
+# every layer. A tree without the model (before PR 54) writes no file.
+try:
+    from paddle_tpu.models.falcon_h1 import FalconH1, FalconH1Config
+except ImportError:
+    FalconH1 = None
+if FalconH1 is not None:
+    paddle.seed(0)
+    with paddle.LazyGuard():
+        falcon = FalconH1(FalconH1Config(
+            vocab_size=512, hidden_size=512, intermediate_size=512,
+            num_hidden_layers=2, mamba_d_ssm=1024, mamba_n_heads=8,
+            max_position_embeddings=2048))
+    falcon.bfloat16()
+    eng = ServingEngine(falcon, ServingConfig(
+        num_slots=8, page_size=16, pages_per_slot=88, prefill_chunk=256,
+        prefix_cache=False))
+    eng.submit(np.arange(300, dtype=np.int32) % 512, 2)
+    for _ in range(3):
+        eng.step()
+    eng.drain(0)
+    lower("falcon-h1", eng)
