@@ -55,6 +55,7 @@ are one matrix's columns (storage, not mathematics).
 from __future__ import annotations
 
 from dataclasses import dataclass
+from functools import partial
 from typing import Tuple
 
 import jax
@@ -292,10 +293,19 @@ def falcon_h1_ragged_apply(c: FalconH1Config, stacked, other, pools, tokens,
     tick) take the null slot. A chunk row at position 0 is a tenant's first:
     it enters at a zero state and a zero history.
 
+    **``has_chunks``** (``models/tick.py``: false on a tick whose chunk row
+    is a pad) goes to ``TickRows.dense``: what a layer does to its rows
+    before the pools (``before``: ``ln_1``, the mixer's and the attention's
+    input matrices, RoPE) and after them (``after``: the gated norm, both
+    output matrices, the SwiGLU) is row-wise, so one layer's ``after`` and
+    the next one's ``before`` are one stretch that such a tick runs over
+    its decode rows alone (80 rows read a matrix as fast as the HBM gives
+    it; 336 were bound by the products on 256 pads). ``mixer`` and
+    ``attention``, the calls on the pools, stay outside every ``cond``.
+
     Returns ``(logits [S, V], pools, aux)`` with ``aux`` as
     ``models/olmo_hybrid.olmo_hybrid_ragged_apply`` gives it (``stats`` in
     ``TICK_STATS``' order, ``top_logit``)."""
-    del has_chunks
     tab, slots = row_tab
     nt, nd, w = tokens.shape[0], decode_rows, chunk_width
     ps, nps = pools.page_size, tab.shape[1]
@@ -306,7 +316,8 @@ def falcon_h1_ragged_apply(c: FalconH1Config, stacked, other, pools, tokens,
     d_ssm, cw = c.mamba_d_ssm, c.conv_width
     with annotate("tick/embed"):
         x = other["embeddings.wte.weight"][tokens] * c.embedding_multiplier
-    rows_ = TickRows(ps, nps, tok_pos, tok_limit, row_pos0, nt, nd, w)
+    rows_ = TickRows(ps, nps, tok_pos, tok_limit, row_pos0, nt, nd, w,
+                     has_chunks)
     page = rows_.page_of(tab)
     off = tok_pos % ps
     touched = rows_.touched(page, tab)
@@ -335,13 +346,32 @@ def falcon_h1_ragged_apply(c: FalconH1Config, stacked, other, pools, tokens,
                 a[..., d_ssm:d_ssm + bc].reshape(lead + (sg, sn)),
                 a[..., d_ssm + bc:].reshape(lead + (sg, sn)))
 
-    def mixer(n, pl, p, layer):
+    def before(p, x, pos):
+        """What a layer makes of its rows before it touches the pools: the
+        normed input through the mixer's and the attention's matrices."""
         with annotate("blk/ssd/proj"):
+            n = rms(x, p["ln_1.weight"], eps)
             u = (n * c.ssm_in_multiplier) @ p["ssd.w_in.weight"]
             u = (u.astype(_F32) * mup).astype(n.dtype)
             z, xbc = u[:, :d_ssm], u[:, d_ssm:d_ssm + cw]
             dt = jax.nn.softplus(u[:, d_ssm + cw:].astype(_F32)
                                  + p["ssd.dt_bias.weight"].astype(_F32))
+        with annotate("blk/qkv"):
+            qkv = (n * c.attention_in_multiplier) @ p["attn.qkv.weight"]
+            qw, kw = c.q_width, c.kv_width
+            q = qkv[:, :qw].reshape(-1, 1, heads, hd)
+            k = (qkv[:, qw:qw + kw] * c.key_multiplier).reshape(
+                -1, 1, kvh, hd)
+            v = qkv[:, qw + kw:].reshape(-1, 1, kvh, hd)
+            q = rope_at(q, pos, c.rope_theta)
+            k = rope_at(k, pos, c.rope_theta)
+        return z, xbc, dt, q, k, v
+
+    def mixer(pl, p, layer, xbc, dt):
+        """The state's rule over the projected rows -> ``y`` float32, the
+        decode rows' ``[nd, heads, P]`` and the chunk rows' ``[nch w, heads,
+        P]``."""
+        with annotate("blk/ssd/proj"):
             a_neg = -jnp.exp(p["ssd.A_log.weight"].astype(_F32))
             skip = p["ssd.D.weight"].astype(_F32)
         taps, bias = p["ssd.conv.weight"], p["ssd.conv_bias.weight"]
@@ -365,27 +395,9 @@ def falcon_h1_ragged_apply(c: FalconH1Config, stacked, other, pools, tokens,
                 y, pl = pl.chunk(layer, ch_slots, fresh, ch_len, xs, bs, cs,
                                  cut(dt), a_neg, skip)
             outs.append(y.reshape(nch * w, sh, sp))
-        with annotate("blk/ssd/out"):
-            y = jnp.concatenate(outs, 0).reshape(nt, d_ssm)     # float32
-            y = y * jax.nn.silu(z.astype(_F32))
-            grp = y.reshape(nt, sg, d_ssm // sg)
-            grp = grp * jax.lax.rsqrt(
-                jnp.mean(jnp.square(grp), axis=-1, keepdims=True) + eps)
-            y = grp.reshape(nt, d_ssm) * p["ssd.norm.weight"].astype(_F32)
-            out = (y.astype(n.dtype) @ p["ssd.w_out.weight"]) \
-                * c.ssm_out_multiplier
-        return out, pl
+        return outs, pl
 
-    def attention(n, pl, p, layer):
-        with annotate("blk/qkv"):
-            qkv = (n * c.attention_in_multiplier) @ p["attn.qkv.weight"]
-            qw, kw = c.q_width, c.kv_width
-            q = qkv[:, :qw].reshape(nt, 1, heads, hd)
-            k = (qkv[:, qw:qw + kw] * c.key_multiplier).reshape(
-                nt, 1, kvh, hd)
-            v = qkv[:, qw + kw:].reshape(nt, 1, kvh, hd)
-            q = rope_at(q, tok_pos[:, None], c.rope_theta)
-            k = rope_at(k, tok_pos[:, None], c.rope_theta)
+    def attention(pl, layer, q, k, v):
         with annotate("blk/kv_scatter"):
             pl = pl.scatter(layer, page, off, k, v, touched)
 
@@ -405,24 +417,50 @@ def falcon_h1_ragged_apply(c: FalconH1Config, stacked, other, pools, tokens,
                     jnp.clip(rep(row_len) - first, 0, t // pieces))
                 return cut.flat(o.reshape(n_, t, heads, hd))
 
-        o = rows_.groups(attend)
-        with annotate("blk/attn_out"):
-            out = (o.reshape(nt, -1).astype(n.dtype) @ p["attn.o.weight"]) \
-                * c.attention_out_multiplier
-        return out, pl
+        return rows_.groups(attend, join=False), pl
 
-    for i in range(c.num_hidden_layers):
-        p = stacked[f"layer{i}"]
-        with annotate("blk/ssd/proj"):
-            n = rms(x, p["ln_1.weight"], eps)
-        m, pools = mixer(n, pools, p, i)
-        a, pools = attention(n, pools, p, i)
+    def after(p, x, y, z, o):
+        """What a layer makes of its rows once the pools have answered: the
+        mixer's gated norm and both output matrices, then the SwiGLU."""
+        with annotate("blk/ssd/out"):
+            y = y.reshape(-1, d_ssm) * jax.nn.silu(z.astype(_F32))  # f32
+            grp = y.reshape(-1, sg, d_ssm // sg)
+            grp = grp * jax.lax.rsqrt(
+                jnp.mean(jnp.square(grp), axis=-1, keepdims=True) + eps)
+            y = grp.reshape(-1, d_ssm) * p["ssd.norm.weight"].astype(_F32)
+            m = (y.astype(x.dtype) @ p["ssd.w_out.weight"]) \
+                * c.ssm_out_multiplier
+        with annotate("blk/attn_out"):
+            a = (o.reshape(o.shape[0], -1).astype(x.dtype)
+                 @ p["attn.o.weight"]) * c.attention_out_multiplier
         with annotate("blk/ffn"):
             x = x + m + a
             g = rms(x, p["ln_2.weight"], eps)
             mid = (g @ p["ffn.fc_in.weight"]) * jax.nn.silu(
                 (g @ p["ffn.fc_gate.weight"]) * c.mlp_multipliers[0])
-            x = x + (mid @ p["ffn.fc_out.weight"]) * c.mlp_multipliers[1]
+            return x + (mid @ p["ffn.fc_out.weight"]) * c.mlp_multipliers[1]
+
+    def between(p, nxt, x, y, z, o, pos):
+        x = after(p, x, y, z, o)
+        return x, before(nxt, x, pos)
+
+    # a layer's rows meet the pools in the middle of it, so the dense
+    # stretch between two layers' pool calls is one layer's end and the
+    # next one's start: one ``rows_.dense`` each (its own scope names the
+    # branch itself, whose turnaround is the dense part's)
+    layers = [stacked[f"layer{i}"] for i in range(c.num_hidden_layers)]
+    pos = tok_pos[:, None]
+    with annotate("blk/ssd/proj"):
+        z, xbc, dt, q, k, v = rows_.dense(partial(before, layers[0]), x, pos)
+    for i, p in enumerate(layers):
+        y, pools = mixer(pools, p, i, xbc, dt)
+        o, pools = attention(pools, i, q, k, v)
+        with annotate("blk/ffn"):
+            if i + 1 < len(layers):
+                x, (z, xbc, dt, q, k, v) = rows_.dense(
+                    partial(between, p, layers[i + 1]), x, y, z, o, pos)
+            else:
+                x = rows_.dense(partial(after, p), x, y, z, o)
     with annotate("tick/head"):
         last = rms(x[sample_ix], other["ln_f.weight"], eps)
         logits = (last @ other["lm_head.weight"]) * c.lm_head_multiplier

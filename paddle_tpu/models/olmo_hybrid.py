@@ -39,7 +39,8 @@ from __future__ import annotations
 
 import math
 from dataclasses import dataclass
-from typing import Tuple
+from functools import partial
+from typing import Callable, NamedTuple, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -306,6 +307,18 @@ class OlmoHybrid(LayerwiseLM):
 # --------------------------------------------------------------------------
 # the tick's forward
 # --------------------------------------------------------------------------
+class _Layer(NamedTuple):
+    """A layer as the tick's forward walks it: its weights, its index among
+    the layers of its kind (the pools') and its kind's three functions:
+    ``before(p, x) -> (what the pools take, what ``out`` keeps)``,
+    ``pools(pools, p, ix, *taken) -> (o, pools)``, ``out(p, x, o, *kept)``."""
+    p: dict
+    ix: int
+    before: Callable
+    pools: Callable
+    out: Callable
+
+
 def _gates(c: OlmoHybridConfig, ab, p):
     """``(g, beta)`` float32 a head from the ``[a | b]`` projection."""
     heads = c.linear_num_value_heads
@@ -330,12 +343,18 @@ def olmo_hybrid_ragged_apply(c: OlmoHybridConfig, stacked, other, pools,
     tick) take the null slot. A chunk row at position 0 is a tenant's
     first: it enters at a zero state and a zero history.
 
+    **``has_chunks``** (``models/tick.py``: false on a tick whose chunk row
+    is a pad) goes to ``TickRows.dense``: a layer's projections, gates and
+    norms before the pools and its output matrix and SwiGLU after them are
+    row-wise, so one layer's end and the next one's start are one stretch
+    that such a tick runs over its decode rows alone. The calls on the
+    pools (``linear_pools``, ``full_pools``) stay outside every ``cond``.
+
     Returns ``(logits [S, V], pools, aux)``: ``aux["stats"]`` float32
     ``[len(TICK_STATS)]`` (the live decode rows, the chunk rows' tokens, the
     keys the decode rows' and the chunk rows' full attention read a layer,
     the chunk rows' visible query-key pairs) and ``aux["top_logit"]`` ``[S]``
     float32, the sampled rows' largest logit."""
-    del has_chunks
     tab, slots = row_tab
     nt, nd, w = tokens.shape[0], decode_rows, chunk_width
     ps, nps = pools.page_size, tab.shape[1]
@@ -343,7 +362,8 @@ def olmo_hybrid_ragged_apply(c: OlmoHybridConfig, stacked, other, pools,
     lin_heads, dv = c.linear_num_value_heads, c.linear_value_head_dim
     with annotate("tick/embed"):
         x = other["embeddings.wte.weight"][tokens]              # [NT, h]
-    rows_ = TickRows(ps, nps, tok_pos, tok_limit, row_pos0, nt, nd, w)
+    rows_ = TickRows(ps, nps, tok_pos, tok_limit, row_pos0, nt, nd, w,
+                     has_chunks)
     page = rows_.page_of(tab)
     off = tok_pos % ps
     nch = rows_.nch
@@ -361,11 +381,18 @@ def olmo_hybrid_ragged_apply(c: OlmoHybridConfig, stacked, other, pools,
         jnp.sum(jnp.where(dec_slots > 0, keys[:nd], 0.0)),
         jnp.sum(keys[nd:]), jnp.sum(pairs[nd:])])
 
-    def linear(x, pl, p, layer):
+    # A layer's rows meet the pools in the middle of it. What it makes of
+    # them before (``*_before``: the projections, gates and norms) and after
+    # (``after``: the output matrix and the SwiGLU) is row-wise and touches
+    # no pool; what lies between (``*_pools``) is the pools' methods.
+    def linear_before(p, x):
         with annotate("blk/gdn/proj"):
             qkv = x @ p["mix.qkv.weight"]
             gate = x @ p["mix.gate.weight"]
             g, beta = _gates(c, x @ p["mix.ab.weight"], p)
+        return (qkv, g, beta), (gate,)
+
+    def linear_pools(pl, p, layer, qkv, g, beta):
         taps = p["mix.conv.weight"]
         # between projections and rule, a row group a call: the pool's
         # history read and written inside it (StatePools.prep)
@@ -389,23 +416,27 @@ def olmo_hybrid_ragged_apply(c: OlmoHybridConfig, stacked, other, pools,
                 o, pl = pl.chunk(layer, ch_slots, fresh, ch_len, q, k, v,
                                  cut(g), cut(beta))
             outs.append(o.reshape(nch * w, lin_heads, dv))
+        return outs, pl                 # [nd, H, dv], [nch w, H, dv] f32
+
+    def linear_out(p, x, o, gate):
         with annotate("blk/gdn/out"):
-            o = jnp.concatenate(outs, 0)                    # [NT, H, dv] f32
             ms = jnp.mean(jnp.square(o), axis=-1, keepdims=True)
             y = o * jax.lax.rsqrt(ms + eps) \
                 * p["mix.o_norm.weight"].astype(_F32)
-            y = y.reshape(nt, -1) * jax.nn.silu(gate.astype(_F32))
-            out = y.astype(x.dtype) @ p["mix.o.weight"]
-        return out, pl
+            y = y.reshape(o.shape[0], -1) * jax.nn.silu(gate.astype(_F32))
+            return y.astype(x.dtype) @ p["mix.o.weight"]
 
-    def full(x, pl, p, layer):
+    def full_before(p, x):
         with annotate("blk/qkv"):
             qkv = x @ p["attn.qkv.weight"]
             h = c.hidden_size
             q = rms(qkv[:, :h], p["attn.q_norm.weight"], eps)
             k = rms(qkv[:, h:2 * h], p["attn.k_norm.weight"], eps)
-            split = lambda a: a.reshape(nt, 1, heads, c.head_dim)  # noqa
-            q, k, v = split(q), split(k), split(qkv[:, 2 * h:])
+            split = lambda a: a.reshape(-1, 1, heads, c.head_dim)  # noqa
+            return (split(q), split(k), split(qkv[:, 2 * h:])), ()
+
+    def full_pools(pl, p, layer, q, k, v):
+        del p                           # no weight lies between
         with annotate("blk/kv_scatter"):
             pl = pl.scatter(layer, page, off, k, v)
 
@@ -427,25 +458,47 @@ def olmo_hybrid_ragged_apply(c: OlmoHybridConfig, stacked, other, pools,
                     jnp.clip(rep(row_len) - first, 0, t // pieces))
                 return cut.flat(o.reshape(n, t, heads, c.head_dim))
 
-        o = rows_.groups(attend)
-        with annotate("blk/attn_out"):
-            out = o.reshape(nt, -1).astype(x.dtype) @ p["attn.o.weight"]
-        return out, pl
+        return rows_.groups(attend, join=False), pl
 
-    n_full = n_lin = 0
-    for i, kind in enumerate(c.layer_types):
-        p = stacked[f"layer{i}"]
-        if kind == "full_attention":
-            out, pools = full(x, pools, p, n_full)
-            n_full += 1
-        else:
-            out, pools = linear(x, pools, p, n_lin)
-            n_lin += 1
+    def full_out(p, x, o):
+        with annotate("blk/attn_out"):
+            return o.reshape(o.shape[0], -1).astype(x.dtype) \
+                @ p["attn.o.weight"]
+
+    def after(layer, x, o, *kept):
+        p = layer.p
+        out = layer.out(p, x, o, *kept)
         with annotate("blk/ffn"):
             x = x + rms(out, p["ln_1.weight"], eps)
             mid = jax.nn.silu(x @ p["ffn.fc_gate.weight"]) \
                 * (x @ p["ffn.fc_in.weight"])
-            x = x + rms(mid @ p["ffn.fc_out.weight"], p["ln_2.weight"], eps)
+            return x + rms(mid @ p["ffn.fc_out.weight"], p["ln_2.weight"],
+                           eps)
+
+    def between(layer, nxt, x, o, *kept):
+        x = after(layer, x, o, *kept)
+        return x, nxt.before(nxt.p, x)
+
+    kinds = {"full_attention": (full_before, full_pools, full_out),
+             "linear_attention": (linear_before, linear_pools, linear_out)}
+    layers, seen = [], dict.fromkeys(kinds, 0)
+    for i, kind in enumerate(c.layer_types):
+        layers.append(_Layer(stacked[f"layer{i}"], seen[kind], *kinds[kind]))
+        seen[kind] += 1
+    # the dense stretch between two layers' pool calls is one layer's end
+    # and the next one's start: one ``rows_.dense`` each (its own scope
+    # names the branch itself, whose turnaround is the dense part's)
+    with annotate("blk/ffn"):
+        to_pools, kept = rows_.dense(
+            partial(layers[0].before, layers[0].p), x)
+    for layer, nxt in zip(layers, layers[1:] + [None]):
+        o, pools = layer.pools(pools, layer.p, layer.ix, *to_pools)
+        with annotate("blk/ffn"):
+            if nxt is None:
+                x = rows_.dense(partial(after, layer), x, o, *kept)
+            else:
+                x, (to_pools, kept) = rows_.dense(
+                    partial(between, layer, nxt), x, o, *kept)
     with annotate("tick/head"):
         last = rms(x[sample_ix], other["ln_f.weight"], eps)
         logits = last @ other["lm_head.weight"]                 # [S, V]

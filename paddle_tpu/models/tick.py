@@ -31,6 +31,22 @@ row_pos0, row_len, sample_ix, *, decode_rows, chunk_width, has_chunks)
     ``aux`` is a dict of device arrays, what the tick says of itself beside
     its tokens: empty for a model that reports nothing, and then no output of
     the program.
+    ``has_chunks`` is a bool scalar of the program (``None`` where a caller
+    does not say: a test, ``LayerwiseLM.forward``): false on a tick none of
+    whose chunk rows carries a token. The program is one body for every mix
+    and such a tick's chunk rows ride as pad rows (``tok_limit`` 0, an
+    all-null table, ``row_len`` 0: they write to the null page and slot and
+    nothing samples them), so a forward may ignore it (``del has_chunks``:
+    a model whose ticks all carry a chunk, or whose pads cost elsewhere).
+    What it may do with it is skip work **on the pad rows alone, under a
+    ``cond`` that neither takes nor returns ``pools``**: the pools are
+    donated and updated in place, one body for every mix, and a ``cond``
+    that carried them cost two whole-pool copies a tick on both branches
+    (ROADMAP S3). ``gpt_ragged_apply`` skips the chunk rows' attention that
+    way (its result, not the pools, leaves the ``cond``);
+    ``TickRows.dense`` below runs a forward's row-wise stretches, between
+    two calls on the pools, over the decode rows alone (Falcon-H1,
+    Olmo-Hybrid: half of their ticks was products on 256 pad tokens).
 
 **The record.** ``cache_spec()["tick_record"]()`` is made once an engine
 (``engine.tick_record``). The engine calls ``tick(aux, positions, rids)`` for
@@ -227,12 +243,14 @@ def state_drawer(model):
 class TickRows:
     """One tick's flat token buffer against its rows, as a forward of unlike
     layers reads it: ``nd`` decode rows of one token, then ``nch`` chunk rows
-    of ``w``; ``ps`` the page size and ``nps`` the pages of a slot's table."""
+    of ``w``; ``ps`` the page size and ``nps`` the pages of a slot's table;
+    ``has_chunks`` the protocol's (a bool scalar; ``None``: not told)."""
 
     def __init__(self, ps: int, nps: int, tok_pos, tok_limit, row_pos0,
-                 nt: int, nd: int, w: int):
+                 nt: int, nd: int, w: int, has_chunks=None):
         self.ps, self.nps, self.nd, self.w = ps, nps, nd, w
         self.nch = nch = (nt - nd) // w if w else 0
+        self.has_chunks = has_chunks
         self.row_pos0 = row_pos0
         parts = [jnp.arange(nd, dtype=jnp.int32)]
         if nch:
@@ -274,11 +292,44 @@ class TickRows:
         return (tok_ix < row_len[self.tok_row]) \
             & (table[self.tok_row, 0] > 0)
 
-    def groups(self, fn):
+    def dense(self, fn, *arrays):
+        """``fn(*arrays)`` for a ``fn`` that is row-wise (per-token arrays
+        ``[n, ...]`` to a pytree of ``[n, ...]``: norms, products with a
+        layer's matrices, gates, activations; the matrices it closes over).
+        One of ``arrays`` is ``[NT, ...]`` or the list of its groups' parts
+        as the pools leave them (``groups(fn, join=False)``: the decode
+        rows' ``[nd, ...]``, then the chunk rows' ``[nch w, ...]``), joined
+        where ``fn`` runs. Told that no chunk rides in this tick
+        (``has_chunks`` false), it is ``fn`` over the ``nd`` decode rows
+        alone and zeros for the chunk rows' ``nch w`` pad tokens, which
+        nothing reads: a ``cond`` whose branches read each matrix once and
+        give one shape. **``fn`` touches no pool**: a pool that a ``cond``
+        takes or returns is copied whole (the module's docstring). Not told
+        (``None``), or of one group only, it is ``fn`` over all rows and no
+        ``cond``."""
+        parts = lambda a: isinstance(a, (list, tuple))      # noqa: E731
+
+        def all_rows(*arrays):
+            return fn(*(jnp.concatenate(a, 0) if parts(a) else a
+                        for a in arrays))
+
+        if self.has_chunks is None or not (self.nd and self.nch):
+            return all_rows(*arrays)
+        nd, pad = self.nd, self.nch * self.w
+
+        def decode_rows(*arrays):
+            return jax.tree.map(
+                lambda o: jnp.pad(o, [(0, pad)] + [(0, 0)] * (o.ndim - 1)),
+                fn(*(a[0] if parts(a) else a[:nd] for a in arrays)))
+
+        return jax.lax.cond(self.has_chunks, all_rows, decode_rows, *arrays)
+
+    def groups(self, fn, join: bool = True):
         """``fn(rows, cut)`` over the decode rows and then the chunk rows, one
-        call a width, back in flat-token order: ``rows`` the group's slice of
-        the tick's rows (``n`` of them, ``t`` tokens each), ``cut(a)`` its
-        tokens of a per-token array ``a`` [NT, ...] as ``[n, t, ...]`` and
+        call a width, back in flat-token order (with ``join`` false the
+        groups' results as a list, for ``dense`` to join): ``rows`` the
+        group's slice of the tick's rows (``n`` of them, ``t`` tokens each),
+        ``cut(a)`` its tokens of a per-token array ``a`` [NT, ...] as ``[n, t, ...]`` and
         ``cut.flat(out)`` the way back, ``[n, t, ...]`` arrays as ``[n t,
         ...]``, which ``fn`` returns (inside its own scope: on the chip the
         reshape is a copy, and a trace charges it to the scope it is in)."""
@@ -288,6 +339,8 @@ class TickRows:
         if self.nch:
             outs.append(fn(slice(self.nd, self.nd + self.nch),
                            _Cut(self.nd, self.nch, self.w)))
+        if not join:
+            return outs
         return jax.tree.map(lambda *a: jnp.concatenate(a, 0), *outs)
 
 

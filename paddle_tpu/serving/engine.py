@@ -101,6 +101,8 @@ preempt → re-prefill start — requeue cycles used to fold back into the
 submit-anchored wait, conflating scheduler delay with preemption
 cost), ``serving/tokens_generated``,
 ``serving/prefills``, ``serving/prefill_chunks``, ``serving/ticks``,
+``serving/ticks_without_chunk`` (the unified ticks told ``has_chunks``
+false),
 ``serving/tick_temp_bytes`` / ``serving/tick_alias_bytes`` (gauges, set
 once when the tick is first compiled: its ``memory_analysis()``; in
 place means temporaries far under one pool and every pool aliased),
@@ -1745,6 +1747,8 @@ class ServingEngine:
             self._insert_prefix(s, self._requests[rid].prompt, end)
         reg = _registry()
         reg.counter("serving/ticks").add(1)
+        if not chunks:                  # what the tick was told: has_chunks
+            reg.counter("serving/ticks_without_chunk").add(1)
         # pages of windowed layers that no later query can see go back now:
         # every tick that reads them is already dispatched
         reg.counter("serving/window_pages_freed").add(sum(
@@ -1863,7 +1867,12 @@ class ServingEngine:
             # rows the program already knows (``tok_limit`` 0: their
             # writes land on the null page; all-null tables), and
             # ``has_chunks`` only lets the block skip their attention,
-            # which reads the pools and returns ``[nch, w, NH, D]``.
+            # which reads the pools and returns ``[nch, w, NH, D]``
+            # (``gpt_ragged_apply``), or a forward of unlike layers run
+            # its row-wise stretches between two calls on the pools over
+            # the decode rows alone (Falcon-H1, Olmo-Hybrid:
+            # ``models/tick.TickRows.dense``; dots3, DeepSeek-V2 and Ling
+            # ignore it): neither ``cond`` takes or returns the pools.
             # what the forward says of itself (``aux``: a looped model's
             # exit steps, a latent model's statistics) leaves the tick as
             # outputs beside the tokens, no callback; of no entries, none

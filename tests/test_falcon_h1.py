@@ -9,6 +9,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from chunkless_tick import (a_tick, check_a_chunk_tick, check_a_pad_tick,
+                            check_the_engines_count, conds_without_a_pool)
 from falcon_h1_toy import PAGE, build, engine, reference, some_tokens
 from paddle_tpu.models.falcon_h1 import (TICK_STATS, FalconH1Config,
                                          falcon_h1_ragged_apply)
@@ -194,6 +196,30 @@ def test_the_ticks_statistics_and_its_dead_rows(net):
     page = int(tab[0, 2])
     assert np.asarray(after.kv.kv[:, page, :, 1]).any()
     assert not np.asarray(after.kv.kv[:, page, :, 2:]).any()
+
+
+# --- a tick without a chunk (ISSUE 55; tests/chunkless_tick.py) -------------
+@pytest.mark.parametrize("told", [True, False])
+def test_a_tick_whose_chunk_row_is_a_pad_is_one_tick_however_it_is_told(
+        net, told):
+    check_a_pad_tick(net, falcon_h1_ragged_apply, told)
+
+
+def test_a_tick_with_a_chunk_is_the_program_it_was(net):
+    check_a_chunk_tick(net, falcon_h1_ragged_apply)
+
+
+def test_no_cond_of_the_tick_takes_or_returns_a_pool(net):
+    """A ``cond`` a dense stretch: one before the first layer, one between
+    two layers, one after the last."""
+    tick, pools = a_tick(net, falcon_h1_ragged_apply, chunk=False)
+    assert conds_without_a_pool(tick, pools) \
+        == net.config.num_hidden_layers + 1
+
+
+def test_the_engine_counts_the_ticks_it_tells_have_no_chunk(net, tokens):
+    """A prompt of three chunks of 8 and eleven more ticks."""
+    check_the_engines_count(engine(net), tokens[:21], 12, chunks=3)
 
 
 # --- the pool: pages and a state in every layer ----------------------------

@@ -11,6 +11,8 @@ import numpy as np
 import pytest
 
 import paddle_tpu as paddle
+from chunkless_tick import (a_tick, check_a_chunk_tick, check_a_pad_tick,
+                            check_the_engines_count, conds_without_a_pool)
 from paddle_tpu.models import olmo_hybrid_reference as ref
 from paddle_tpu.models.olmo_hybrid import (TICK_STATS, OlmoHybrid,
                                            OlmoHybridConfig,
@@ -291,6 +293,30 @@ def test_the_ticks_statistics_and_its_dead_rows(net):
                                       pools.state[:, dead])
         np.testing.assert_array_equal(after.conv[:, :, dead],
                                       pools.conv[:, :, dead])
+
+
+# --- a tick without a chunk (ISSUE 55; tests/chunkless_tick.py) -------------
+@pytest.mark.parametrize("told", [True, False])
+def test_a_tick_whose_chunk_row_is_a_pad_is_one_tick_however_it_is_told(
+        net, told):
+    check_a_pad_tick(net, olmo_hybrid_ragged_apply, told)
+
+
+def test_a_tick_with_a_chunk_is_the_program_it_was(net):
+    check_a_chunk_tick(net, olmo_hybrid_ragged_apply)
+
+
+def test_no_cond_of_the_tick_takes_or_returns_a_pool(net):
+    """A ``cond`` a dense stretch: one before the first layer, one between
+    two layers of either kind, one after the last."""
+    tick, pools = a_tick(net, olmo_hybrid_ragged_apply, chunk=False)
+    assert conds_without_a_pool(tick, pools) \
+        == net.config.num_hidden_layers + 1
+
+
+def test_the_engine_counts_the_ticks_it_tells_have_no_chunk(net, tokens):
+    """A prompt of three chunks of 8 and eleven more ticks."""
+    check_the_engines_count(engine(net), tokens[:21], 12, chunks=3)
 
 
 # --- the pool of a state ---------------------------------------------------

@@ -212,3 +212,48 @@ def test_the_pool_is_built_from_the_spec(spec):
     # a window's pages behind the frontier go back; any other pool frees none
     assert pool.free_behind(0, 14) == (2 if spec.get("window_layers") else 0)
     assert pool.check_consistency() == []
+
+
+# --- TickRows.dense: the row-wise stretches of a tick (ISSUE 55) -----------
+@pytest.mark.parametrize("has_chunks", [None, True, False])
+def test_tick_rows_dense_is_fn_over_the_rows_that_exist(has_chunks):
+    """Not told whether a chunk rides (``None``) ``dense`` is ``fn`` over
+    all rows and no ``cond``; told there is one, the same values under a
+    ``cond``; told there is none, ``fn`` over the decode rows and zeros for
+    the chunk rows' pad tokens, in one shape."""
+    from paddle_tpu.models.tick import TickRows
+
+    nd, w = 3, 4
+    nt = nd + w
+    pos = jnp.arange(nt, dtype=jnp.int32)
+    x = jnp.arange(nt * 2, dtype=jnp.float32).reshape(nt, 2) + 1.0
+    m = jnp.asarray([[1.0, 2.0, 3.0], [0.5, -1.0, 0.0]])
+
+    def fn(x, pos):
+        return {"y": x @ m, "p": (pos + 1)[:, None] * x}
+
+    def stretch(x, pos, told):
+        rows = TickRows(PAGE, 2, pos, pos, pos[:nd + 1], nt, nd, w, told)
+        return rows.dense(fn, x, pos)
+
+    told = None if has_chunks is None else jnp.asarray(has_chunks)
+    got = stretch(x, pos, told)
+    want = fn(x, pos)
+    if has_chunks is False:
+        want = jax.tree.map(lambda a: a.at[nd:].set(0), want)
+    for key in want:
+        np.testing.assert_array_equal(got[key], want[key])
+    # an array as its groups' parts (what the pools leave) is joined inside
+    in_parts = stretch([x[:nd], x[nd:]], pos, told)
+    for key in want:
+        np.testing.assert_array_equal(in_parts[key], want[key])
+    text = str(jax.make_jaxpr(lambda x, pos, t: stretch(x, pos, t))(
+        x, pos, jnp.asarray(True)))
+    assert " cond[" in text
+    assert " cond[" not in str(jax.make_jaxpr(
+        lambda x, pos: stretch(x, pos, None))(x, pos))
+    # one group alone (a forward of a whole sequence: no decode row): no cond
+    rows = TickRows(PAGE, 2, pos[:w], pos[:w], pos[:1], w, 0, w,
+                    jnp.asarray(False))
+    np.testing.assert_array_equal(rows.dense(fn, x[:w], pos[:w])["y"],
+                                  x[:w] @ m)

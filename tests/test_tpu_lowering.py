@@ -737,6 +737,54 @@ def test_tpu_compile_the_dense_latent_models_forward(monkeypatch):
         "the donated latent pool is not aliased"
 
 
+def _hybrid_tick(monkeypatch, layers: int, told: bool):
+    """Olmo-Hybrid's tick forward compiled for the v5e from a ``LazyGuard``
+    model of ``layers`` layers at the published widths, the cell's 40 decode
+    rows and its chunk row of 256 -> ``(text, memory_analysis, pools)``.
+    ``told``: ``has_chunks`` an argument of the program, as the engine's is
+    (ISSUE 55); else not given, the forward of one body."""
+    import jax.numpy as jnp
+
+    import paddle_tpu as paddle
+    from paddle_tpu.models.olmo_hybrid import (OlmoHybrid, OlmoHybridConfig,
+                                               olmo_hybrid_ragged_apply)
+    from paddle_tpu.models.tick import state_drawer
+    from paddle_tpu.serving.paged_cache import StatePools
+
+    dev = _tpu_topology_devices()[0]
+    cfg = OlmoHybridConfig(num_hidden_layers=layers, vocab_size=1024)
+    with paddle.LazyGuard():
+        net = OlmoHybrid(cfg)
+    net.bfloat16()
+    state = jax.eval_shape(state_drawer(net),
+                           jax.ShapeDtypeStruct((2,), jnp.uint32))
+    ns, ps, nps, w = 40, 16, 88, 256
+    pools = jax.eval_shape(lambda: StatePools.zeros(
+        net.cache_spec(), ns * nps + 1, ps, ns, jnp.bfloat16))
+    assert pools.kv.k.shape == (layers // 4, ns * nps + 1, ps, 32, 128)
+    assert pools.state.shape == (layers // 4 * 3, ns + 1, 15, 96, 384)
+    nt = ns + w
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32)
+
+    args = (state[0], state[1], pools, i32(nt), i32(nt), i32(nt),
+            (i32(ns + 1, nps), i32(ns + 1)), i32(ns + 1), i32(ns + 1),
+            i32(ns)) + ((jax.ShapeDtypeStruct((), jnp.bool_),) * told)
+    args = jax.tree_util.tree_map(
+        lambda a: _on_tpu(dev, a.shape, a.dtype), args)
+
+    def forward(*a):
+        return olmo_hybrid_ragged_apply(
+            cfg, *a[:10], decode_rows=ns, chunk_width=w,
+            has_chunks=a[10] if told else None)
+
+    monkeypatch.setenv("PADDLE_TPU_TARGET_PLATFORM", "tpu")
+    with jax.default_matmul_precision("default"):
+        compiled = jax.jit(forward, donate_argnums=2).lower(*args).compile()
+    return compiled.as_text(), compiled.memory_analysis(), pools
+
+
 def test_tpu_compile_the_hybrid_models_forward(monkeypatch):
     """ISSUE 44: Olmo-Hybrid's tick forward
     (``models/olmo_hybrid.olmo_hybrid_ragged_apply``: K/V pages at 32 head
@@ -747,45 +795,7 @@ def test_tpu_compile_the_hybrid_models_forward(monkeypatch):
     and the ragged kernel are in the program (a chunk row attends in pieces
     of 32 queries: 64 of 32 head rows pass the kernel's VMEM), and the
     donated pools, the 1.1 GB of states among them, are aliased."""
-    import jax.numpy as jnp
-
-    import paddle_tpu as paddle
-    from paddle_tpu.models.olmo_hybrid import (OlmoHybrid, OlmoHybridConfig,
-                                               olmo_hybrid_ragged_apply)
-    from paddle_tpu.models.tick import state_drawer
-    from paddle_tpu.serving.paged_cache import StatePools
-
-    dev = _tpu_topology_devices()[0]
-    cfg = OlmoHybridConfig(num_hidden_layers=4, vocab_size=1024)
-    with paddle.LazyGuard():
-        net = OlmoHybrid(cfg)
-    net.bfloat16()
-    state = jax.eval_shape(state_drawer(net),
-                           jax.ShapeDtypeStruct((2,), jnp.uint32))
-    ns, ps, nps, w = 40, 16, 88, 256
-    pools = jax.eval_shape(lambda: StatePools.zeros(
-        net.cache_spec(), ns * nps + 1, ps, ns, jnp.bfloat16))
-    assert pools.kv.k.shape == (1, ns * nps + 1, ps, 32, 128)
-    assert pools.state.shape == (3, ns + 1, 15, 96, 384)
-    nt = ns + w
-
-    def i32(*shape):
-        return jax.ShapeDtypeStruct(shape, jnp.int32)
-
-    args = (state[0], state[1], pools, i32(nt), i32(nt), i32(nt),
-            (i32(ns + 1, nps), i32(ns + 1)), i32(ns + 1), i32(ns + 1),
-            i32(ns))
-    args = jax.tree_util.tree_map(
-        lambda a: _on_tpu(dev, a.shape, a.dtype), args)
-
-    def forward(*a):
-        return olmo_hybrid_ragged_apply(cfg, *a, decode_rows=ns,
-                                        chunk_width=w)
-
-    monkeypatch.setenv("PADDLE_TPU_TARGET_PLATFORM", "tpu")
-    with jax.default_matmul_precision("default"):
-        compiled = jax.jit(forward, donate_argnums=2).lower(*args).compile()
-    text = compiled.as_text()
+    text, ma, pools = _hybrid_tick(monkeypatch, 4, told=False)
     assert len(re.findall(r"%gdn_step[\w.\-]* = ", text)) == 3
     assert len(re.findall(r"%gdn_chunk[\w.\-]* = ", text)) == 3
     # ISSUE 46: what lies between projections and rule is one call a row
@@ -800,12 +810,51 @@ def test_tpu_compile_the_hybrid_models_forward(monkeypatch):
     assert not moved, moved[:3]
     assert "remat_compressed" not in text
     assert len(re.findall(r"%ragged_paged_attn[\w.\-]* = ", text)) == 2
-    ma = compiled.memory_analysis()
     pool_bytes = sum(a.size * a.dtype.itemsize
                      for a in jax.tree_util.tree_leaves(pools))
     assert ma.alias_size_in_bytes >= pool_bytes, \
         "the donated pools and states are not aliased"
     assert ma.temp_size_in_bytes < pools.state.size * 4 / 3, \
+        "a layer's states are copied"
+
+
+def test_tpu_compile_the_hybrid_models_tick_told_of_its_chunks(monkeypatch):
+    """ISSUE 55: the engine's program, ``has_chunks`` an argument and the
+    dense stretches under ``TickRows.dense``'s ``cond``s, at the cell's own
+    sixteen layers: ISSUE 46's guard on the history's stack (40 MB for the
+    twelve linear layers) holds to the letter, every kernel stands outside
+    the branches once a layer, no branch carries a pool, and the donated
+    pools are aliased with less than one layer's states in temporaries.
+    (At one period's depth XLA takes the 10 MB stack into its fast memory
+    around a ``conditional`` and writes it back, a ``copy-start`` of the
+    whole stack that no cell's program has; the guard is therefore held at
+    the depth the cell runs, which that memory cannot hold.)"""
+    text, ma, pools = _hybrid_tick(monkeypatch, 16, told=True)
+    for kernel, n in (("gdn_step", 12), ("gdn_chunk", 12),
+                      ("gdn_prep_step", 12), ("gdn_prep_chunk", 12),
+                      ("ragged_paged_attn", 8)):
+        assert len(re.findall(rf"%{kernel}[\w.\-]* = ", text)) == n, kernel
+    assert pools.conv.shape == (12, 3, 48, 11520)
+    moved = [ln for ln in text.splitlines()
+             if re.search(r" = bf16\[12,3,48,11520\]\S* (?!custom-call|"
+                          r"parameter|get-tuple-element|bitcast)", ln)]
+    assert not moved, moved[:3]
+    # (nor taken to fast memory and back, whole or a layer at a time)
+    assert not [ln for ln in text.splitlines() if "3,48,11520]" in ln
+                and re.search(r" (copy|slice)-start\(", ln)]
+    assert "remat_compressed" not in text
+    # a dense stretch a branch (before the first layer, between two, after
+    # the last), and no pool's shape among a branch's operands or results
+    branches = [ln for ln in text.splitlines() if " conditional(" in ln]
+    assert len(branches) == 17
+    for a in jax.tree_util.tree_leaves(pools):
+        dims = ",".join(map(str, a.shape))
+        assert not [ln for ln in branches if f"[{dims}]" in ln], dims
+    pool_bytes = sum(a.size * a.dtype.itemsize
+                     for a in jax.tree_util.tree_leaves(pools))
+    assert ma.alias_size_in_bytes >= pool_bytes, \
+        "the donated pools and states are not aliased"
+    assert ma.temp_size_in_bytes < pools.state.size * 4 / 12, \
         "a layer's states are copied"
 
 
@@ -916,14 +965,16 @@ def test_tpu_compile_the_falcon_h1_models_forward(monkeypatch):
     def i32(*shape):
         return jax.ShapeDtypeStruct(shape, jnp.int32)
 
+    # the engine's program: ``has_chunks`` an argument (ISSUE 55)
     args = (state[0], state[1], pools, i32(nt), i32(nt), i32(nt),
             (i32(ns + 1, nps), i32(ns + 1)), i32(ns + 1), i32(ns + 1),
-            i32(ns))
+            i32(ns), jax.ShapeDtypeStruct((), jnp.bool_))
     args = jax.tree_util.tree_map(
         lambda a: _on_tpu(dev, a.shape, a.dtype), args)
 
     def forward(*a):
-        return falcon_h1_ragged_apply(cfg, *a, decode_rows=ns, chunk_width=w)
+        return falcon_h1_ragged_apply(cfg, *a[:-1], decode_rows=ns,
+                                      chunk_width=w, has_chunks=a[-1])
 
     monkeypatch.setenv("PADDLE_TPU_TARGET_PLATFORM", "tpu")
     with jax.default_matmul_precision("default"):
@@ -935,6 +986,9 @@ def test_tpu_compile_the_falcon_h1_models_forward(monkeypatch):
     assert len(re.findall(r"%ssd_prep_chunk[\w.\-]* = ", text)) == 2
     assert len(re.findall(r"%grouped_paged_attn[\w.\-]* = ", text)) == 4
     assert not re.findall(r"%ragged_paged_attn[\w.\-]* = ", text)
+    # a dense stretch a branch (before the first layer, between the two,
+    # after the second), none around a kernel and none that carries a pool
+    assert len(re.findall(r" conditional\(", text)) == 3
     assert "remat_compressed" not in text
     ma = compiled.memory_analysis()
     pool_bytes = sum(a.size * a.dtype.itemsize
