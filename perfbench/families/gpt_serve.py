@@ -61,12 +61,5 @@ def device_state(eng):
     return eng.pool.pools
 
 
-def facts_after(ctx, eng) -> dict:
-    """The K pool's shape ``[L, P, page, heads, d]``, by which a reader
-    finds the operations that touch a whole pool."""
-    return {"pool_dims": tuple(eng.pool.k.shape)}
-
-
 run = functools.partial(serve_loop.run, build=build, warm_up=warm_up,
-                        limits=limits, device_state=device_state,
-                        facts_after=facts_after)
+                        limits=limits, device_state=device_state)

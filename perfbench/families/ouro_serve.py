@@ -20,8 +20,8 @@ import functools
 from perfbench import loader, serve_loop
 
 _gpt = loader.load_module("families", "gpt_serve")
-warm_up, limits, device_state, facts_after = \
-    _gpt.warm_up, _gpt.limits, _gpt.device_state, _gpt.facts_after
+warm_up, limits, device_state = \
+    _gpt.warm_up, _gpt.limits, _gpt.device_state
 
 
 def check_widths(c: dict) -> None:
@@ -91,5 +91,4 @@ def build(ctx):
 
 
 run = functools.partial(serve_loop.run, build=build, warm_up=warm_up,
-                        limits=limits, device_state=device_state,
-                        facts_after=facts_after)
+                        limits=limits, device_state=device_state)

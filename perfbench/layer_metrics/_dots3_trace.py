@@ -12,7 +12,8 @@ as ``_moe_trace`` finds XLA's. Dispatch and combine (a gather and a
 scatter-add of the held rows) are counted with the experts.
 
 A program that names no ``blk/attn/mla`` (one that serves no such model:
-the parent of the PR that brought it) gives ``None`` and raises nothing.
+the parent of the PR that brought it) gives ``None`` and raises nothing, and
+before the trace is cut (``_program_trace.names_scope``).
 """
 from __future__ import annotations
 
@@ -33,6 +34,8 @@ _SCOPE = re.compile(r"\b(" + "|".join(
 ORDER = ("experts", "mla", "swa", "index", "select", "scatter", "route",
          "shared", "dense", "head_sample", "unscoped")
 GROUPED = ("moe_gmm", "ragged-dot")
+#: the tick's own mechanism: no operation under it, not this helper's tick
+MECHANISM = ("blk/attn/mla",)
 
 
 def part(ev: dict) -> str:
@@ -53,6 +56,8 @@ def parts_ms(run) -> Optional[Dict[str, float]]:
         return None
 
     def compute():
+        if not pt.names_scope(doc, _SCOPE, MECHANISM):
+            return None
         parts = pt.parts_ms(doc, "tick", part, ORDER)
         if not parts or not parts.get("mla"):
             return None

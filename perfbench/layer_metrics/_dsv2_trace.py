@@ -13,7 +13,8 @@ imported, neither is edited.
 
 A program that names no ``blk/attn/mla_chunk`` or ``blk/attn/mla_decode``
 (one that serves no such model: the parent of the PR that brought it) gives
-``None`` and raises nothing.
+``None`` and raises nothing, and before the trace is cut
+(``_program_trace.names_scope``).
 """
 from __future__ import annotations
 
@@ -35,6 +36,8 @@ _SCOPE = re.compile(r"\b(" + "|".join(
     re.escape(n) for n in sorted(_PART, key=len, reverse=True)) + r")\b")
 ORDER = ("experts", "mla_chunk", "mla_decode", "scatter", "route", "shared",
          "dense", "head_sample", "unscoped")
+#: the tick's own mechanism: no operation under it, not this helper's tick
+MECHANISM = ("blk/attn/mla_chunk", "blk/attn/mla_decode")
 
 
 def _helper(name: str):
@@ -62,6 +65,8 @@ def parts_ms(run) -> Optional[Dict[str, float]]:
         return None
 
     def compute():
+        if not pt.names_scope(doc, _SCOPE, MECHANISM):
+            return None
         parts = pt.parts_ms(doc, "tick", part, ORDER)
         if not parts or not (parts.get("mla_chunk")
                              or parts.get("mla_decode")):

@@ -9,13 +9,18 @@ norm: ``blk/ssd/proj``, ``blk/ssd/out``, ``blk/qkv``, ``blk/kv_scatter``,
 ``blk/attn_out``, ``blk/ffn``), ``ssd_step``, ``ssd_chunk``, ``ssd_prep``,
 ``attn``, ``head_sample`` and ``unscoped``.
 
-**It hands out ``needs``, not ``tick_needs``**: an accepted test holds
-``_served.helpers()`` at the four accepted helpers and an accepted entry's
-``workloads`` list is closed to the PR that brought this cell, so the cell
-reports under its own names (``fh1.*``, ``ssd.*``) and its readers ask this
-helper directly, as Ling's did at PR 49, until a ``benchmark`` PR folds them.
+A recurrent state's three passes carry one name in every served family's
+helper, whatever the rule (``SHARED``: ``state_step``, ``state_chunk``,
+``state_prep``; ``attn`` is Olmo-Hybrid's word too): a ``state.*`` or
+``attn.full_*`` reader asks ``_served`` for the part by that name and names
+no family. ``least_ms(run, part)`` is the part's floor by ``yardstick_ssd``,
+which ``_served.roofline_pct`` divides by the part's time. The helper is one
+of those ``_served.helpers()`` lists: it hands out ``tick_needs`` (``needs``
+until PR 56, when the cell's own ``fh1.*`` and ``ssd.*`` entries were folded
+into ``served.*``, ``state.*``, ``attn.full_*`` and ``pool.*``).
 A program that names no ``blk/ssd/step`` (one that serves no such model: the
-parent of the PR that brought it) gives ``None`` and raises nothing.
+parent of the PR that brought it) gives ``None`` and raises nothing, and
+before the trace is cut (``_program_trace.names_scope``).
 """
 from __future__ import annotations
 
@@ -35,6 +40,25 @@ _SCOPE = re.compile(r"\b(" + "|".join(
     re.escape(n) for n in sorted(_PART, key=len, reverse=True)) + r")\b")
 ORDER = ("dense", "ssd_step", "attn", "ssd_prep", "ssd_chunk", "head_sample",
          "unscoped")
+#: the tick's own mechanism: no operation under it, not this helper's tick
+MECHANISM = ("blk/ssd/step",)
+#: the names a shared reader asks a recurrent state's passes by
+SHARED = {"state_step": "ssd_step", "state_chunk": "ssd_chunk",
+          "state_prep": "ssd_prep"}
+#: part -> ``(config, tick_shape) -> least milliseconds``: the slower of doing
+#: the part's operations and moving its bytes
+_FLOOR = {
+    "state_step": lambda c, s: yardstick_ssd.least_ms(
+        yardstick_ssd.step_flops(c, s["live"]),
+        yardstick_ssd.step_bytes(c, s["live"]), s["peak"]),
+    "state_chunk": lambda c, s: yardstick_ssd.least_ms(
+        yardstick_ssd.chunk_flops(c, s["chunk"]),
+        yardstick_ssd.chunk_bytes(c, s["chunk"], s["chunk_rows"]), s["peak"]),
+    "attn": lambda c, s: yardstick_ssd.least_ms(
+        yardstick_ssd.attention_flops(c, s["decode_keys"] + s["chunk_pairs"]),
+        yardstick_ssd.attention_bytes(c, s["decode_keys"] + s["chunk_keys"]),
+        s["peak"]),
+}
 
 
 def _helper(name: str):
@@ -57,6 +81,8 @@ def parts_ms(run) -> Optional[Dict[str, float]]:
         return None
 
     def compute():
+        if not pt.names_scope(doc, _SCOPE, MECHANISM):
+            return None
         parts = pt.parts_ms(doc, "tick", part, ORDER)
         if not parts or not parts.get("ssd_step"):
             return None
@@ -73,16 +99,10 @@ def read_part(run, name: str) -> Optional[float]:
     parts = parts_ms(run)
     if parts is None:
         return None
+    name = SHARED.get(name, name)
     if name == "unscoped":       # what no name covers, operation or gap
         return parts.get("unscoped", 0.0) + parts.get("in no operation", 0.0)
     return parts.get(name, 0.0)
-
-
-def tick_ms(run) -> Optional[float]:
-    """The tick program's median device time, of a tick this helper reads."""
-    if parts_ms(run) is None:
-        return None
-    return _helper("_tick").device_ms_p50(run)
 
 
 def tick_shape(run) -> Optional[dict]:
@@ -91,8 +111,10 @@ def tick_shape(run) -> Optional[dict]:
     counted them. ``None`` where the ticks counted no state rows or no tick
     was traced."""
     f = run["facts"]
-    ms = tick_ms(run)
-    if "tick_live_state_rows" not in f or not ms:
+    if "tick_live_state_rows" not in f or parts_ms(run) is None:
+        return None
+    ms = _helper("_tick").device_ms_p50(run)
+    if not ms:
         return None
     return {"ms": ms, "live": f["tick_live_state_rows"],
             "chunk": f["tick_chunk_tokens"],
@@ -104,10 +126,11 @@ def tick_shape(run) -> Optional[dict]:
             "peak": yardstick.chip_peak(run["ctx"].devices[0].device_kind)}
 
 
-def needs(run) -> Optional[Tuple[dict, float, float]]:
+def tick_needs(run) -> Optional[Tuple[dict, float, float]]:
     """``(tick_shape, bytes the mean tick must move, operations it must
-    do)`` by ``yardstick_ssd``: what the cell's shares of the whole tick are
-    taken over."""
+    do)`` by ``yardstick_ssd``: what the ``served.*`` shares of the whole
+    tick are taken over (``_served`` asks every helper that has this). No
+    ``experts_bytes``: this tick holds no experts."""
     s = tick_shape(run)
     if s is None:
         return None
@@ -115,10 +138,11 @@ def needs(run) -> Optional[Tuple[dict, float, float]]:
     return s, yardstick_ssd.tick_bytes(c, s), yardstick_ssd.tick_flops(c, s)
 
 
-def roofline_pct(run, name: str, least) -> Optional[float]:
-    """``least(config, shape, peak)`` milliseconds over part ``name``'s."""
-    s = tick_shape(run)
-    ms = read_part(run, name)
-    if s is None or not ms:
+def least_ms(run, part: str) -> Optional[float]:
+    """The least device milliseconds the run's mean tick needs in ``part``
+    by ``yardstick_ssd`` (the slower of moving its bytes and doing its
+    operations); ``None`` for a part with no floor here."""
+    s, floor = tick_shape(run), _FLOOR.get(part)
+    if s is None or floor is None:
         return None
-    return 100.0 * least(run["ctx"].config, s, s["peak"]) / ms
+    return floor(run["ctx"].config, s)

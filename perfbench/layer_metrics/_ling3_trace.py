@@ -14,23 +14,29 @@ and norm outside the experts: ``blk/kda/proj``, ``blk/kda/out``, ``blk/qkv``,
 ``kda_step``, ``kda_chunk``, ``gdn_prep``, ``mla_decode``, ``mla_chunk``,
 ``scatter``, ``route``, ``experts`` (dispatch and combine with them),
 ``shared``, ``head_sample`` and ``unscoped``. A part that another served
-family's helper also cuts carries that helper's name, so that one entry's
-reader asks ``_served`` for it in every cell: ``gdn_prep`` is
-``blk/kda/prep`` (``ops/gdn.gdn_prep_rows``, the pass Olmo-Hybrid's tick runs
-under ``blk/gdn/prep``), ``mla_decode`` DeepSeek-V2's word for the decode
-rows' dense latent attention.
+family's helper also cuts is asked for by one name in every cell, so that
+one entry's reader asks ``_served`` for it and names no family:
+``mla_decode`` is DeepSeek-V2's word for the decode rows' dense latent
+attention, and a recurrent state's three passes are ``state_step``,
+``state_chunk`` and ``state_prep`` whatever the rule (``SHARED``;
+``gdn_prep`` is ``blk/kda/prep``, ``ops/gdn.gdn_prep_rows``, the pass
+Olmo-Hybrid's tick runs under ``blk/gdn/prep``). ``least_ms(run, part)`` is
+the part's floor by ``yardstick_ling3``, which ``_served.roofline_pct``
+divides by the part's time.
 
 The MLA layer's attention runs under ``blk/mla/...`` and not under the
 ``blk/attn/mla...`` names of the two latent-attention families, so that at
 most one served family's helper answers for a tick. This helper is one of
-the four ``_served.helpers()`` lists (it hands out ``tick_needs``; ``needs``
+those ``_served.helpers()`` lists (it hands out ``tick_needs``; ``needs``
 until PR 53, when the cell's own ``ling.*`` entries were folded into the
-``served.*``, ``moe.tick_*``, ``latent.*``, ``mla.dense_decode``, ``pool.*``
-and ``gdn.prep`` entries). Three readers ask it directly, for what only this
-tick has: ``kda.step_ms_per_tick``, ``kda.step_hbm_roofline_pct`` and
-``ling.mla_decode_roofline_pct``.
+``served.*``, ``moe.tick_*``, ``latent.*``, ``mla.dense_decode`` and
+``pool.*`` entries; its step and the pass before it report under ``state.*``
+since PR 56). ``ling.mla_decode_roofline_pct``, the decode rows' attention
+alone over its own floor, is this cell's entry alone and asks ``_served`` for
+it like the others.
 A program that names no ``blk/kda/step`` (one that serves no such model: the
-parent of the PR that brought it) gives ``None`` and raises nothing.
+parent of the PR that brought it) gives ``None`` and raises nothing, and
+before the trace is cut (``_program_trace.names_scope``).
 """
 from __future__ import annotations
 
@@ -53,6 +59,20 @@ _SCOPE = re.compile(r"\b(" + "|".join(
 ORDER = ("experts", "kda_step", "dense", "mla_decode", "gdn_prep", "route",
          "shared", "scatter", "kda_chunk", "mla_chunk", "head_sample",
          "unscoped")
+#: the tick's own mechanism: no operation under it, not this helper's tick
+MECHANISM = ("blk/kda/step",)
+#: the names a shared reader asks a recurrent state's passes by
+SHARED = {"state_step": "kda_step", "state_chunk": "kda_chunk",
+          "state_prep": "gdn_prep"}
+#: part -> ``(config, tick_shape) -> least milliseconds`` (the window is all
+#: decode: the chunk rows' passes have no entry and no floor here)
+_FLOOR = {
+    "state_step": lambda c, s: yardstick_ling3.least_ms(
+        yardstick_ling3.step_flops(c, s["live"]),
+        yardstick_ling3.step_bytes(c, s["live"]), s["peak"]),
+    "mla_decode": lambda c, s: yardstick_ling3.attention_least_ms(
+        c, (s["decode"],), s["peak"]),
+}
 
 
 def _helper(name: str):
@@ -80,6 +100,8 @@ def parts_ms(run) -> Optional[Dict[str, float]]:
         return None
 
     def compute():
+        if not pt.names_scope(doc, _SCOPE, MECHANISM):
+            return None
         parts = pt.parts_ms(doc, "tick", part, ORDER)
         if not parts or not parts.get("kda_step"):
             return None
@@ -96,6 +118,7 @@ def read_part(run, name: str) -> Optional[float]:
     parts = parts_ms(run)
     if parts is None:
         return None
+    name = SHARED.get(name, name)
     if name == "unscoped":       # what no name covers, operation or gap
         return parts.get("unscoped", 0.0) + parts.get("in no operation", 0.0)
     return parts.get(name, 0.0)
@@ -142,10 +165,12 @@ def experts_bytes(run) -> Optional[float]:
         run["ctx"].config, s["touched"])
 
 
-def roofline_pct(run, name: str, least) -> Optional[float]:
-    """``least(config, shape, peak)`` milliseconds over part ``name``'s."""
-    s = tick_shape(run)
-    ms = read_part(run, name)
-    if s is None or not ms:
+def least_ms(run, part: str) -> Optional[float]:
+    """The least device milliseconds the run's mean tick needs in ``part``
+    by ``yardstick_ling3`` (the slower of moving its bytes and doing its
+    operations; the decode rows' attention alone in the lesser of its two
+    forms); ``None`` for a part with no floor here."""
+    s, floor = tick_shape(run), _FLOOR.get(part)
+    if s is None or floor is None:
         return None
-    return 100.0 * least(run["ctx"].config, s, s["peak"]) / ms
+    return floor(run["ctx"].config, s)

@@ -12,8 +12,18 @@ and norm of both kinds of layer: ``blk/gdn/proj``, ``blk/gdn/out``,
 ``blk/state_io``: what lies between a linear layer's projections and its
 delta rule), ``attn``, ``head_sample`` and ``unscoped``.
 
+A recurrent state's three passes carry one name in every served family's
+helper, whatever the rule (``SHARED``: ``state_step``, ``state_chunk``,
+``state_prep``; ``attn`` is Falcon-H1's word too): a ``state.*`` or
+``attn.full_*`` reader asks ``_served`` for the part by that name and names
+no family. The cut's own labels stay the scopes' words, which
+``benchmarks/tick_kinds.py`` and ``benchmarks/kda_proj_ops.py`` ask
+``part`` and ``ORDER`` for. ``least_ms(run, part)`` is the part's floor by
+``yardstick_gdn``, which ``_served.roofline_pct`` divides by the part's time.
+
 A program that names no ``blk/gdn/step`` (one that serves no such model: the
-parent of the PR that brought it) gives ``None`` and raises nothing.
+parent of the PR that brought it) gives ``None`` and raises nothing, and
+before the trace is cut (``_program_trace.names_scope``).
 """
 from __future__ import annotations
 
@@ -33,6 +43,25 @@ _SCOPE = re.compile(r"\b(" + "|".join(
     re.escape(n) for n in sorted(_PART, key=len, reverse=True)) + r")\b")
 ORDER = ("dense", "gdn_step", "attn", "gdn_prep", "gdn_chunk",
          "head_sample", "unscoped")
+#: the tick's own mechanism: no operation under it, not this helper's tick
+MECHANISM = ("blk/gdn/step",)
+#: the names a shared reader asks a recurrent state's passes by
+SHARED = {"state_step": "gdn_step", "state_chunk": "gdn_chunk",
+          "state_prep": "gdn_prep"}
+#: part -> ``(config, tick_shape) -> least milliseconds``: the slower of doing
+#: the part's operations and moving its bytes
+_FLOOR = {
+    "state_step": lambda c, s: yardstick_gdn.least_ms(
+        yardstick_gdn.step_flops(c, s["live"]),
+        yardstick_gdn.step_bytes(c, s["live"]), s["peak"]),
+    "state_chunk": lambda c, s: yardstick_gdn.least_ms(
+        yardstick_gdn.chunk_flops(c, s["chunk"]),
+        yardstick_gdn.chunk_bytes(c, s["chunk"], s["chunk_rows"]), s["peak"]),
+    "attn": lambda c, s: yardstick_gdn.least_ms(
+        yardstick_gdn.attention_flops(c, s["decode_keys"] + s["chunk_pairs"]),
+        yardstick_gdn.attention_bytes(c, s["decode_keys"] + s["chunk_keys"]),
+        s["peak"]),
+}
 
 
 def _helper(name: str):
@@ -55,6 +84,8 @@ def parts_ms(run) -> Optional[Dict[str, float]]:
         return None
 
     def compute():
+        if not pt.names_scope(doc, _SCOPE, MECHANISM):
+            return None
         parts = pt.parts_ms(doc, "tick", part, ORDER)
         if not parts or not parts.get("gdn_step"):
             return None
@@ -71,6 +102,7 @@ def read_part(run, name: str) -> Optional[float]:
     parts = parts_ms(run)
     if parts is None:
         return None
+    name = SHARED.get(name, name)
     if name == "unscoped":       # what no name covers, operation or gap
         return parts.get("unscoped", 0.0) + parts.get("in no operation", 0.0)
     return parts.get(name, 0.0)
@@ -109,10 +141,11 @@ def tick_needs(run) -> Optional[Tuple[dict, float, float]]:
     return s, yardstick_gdn.tick_bytes(c, s), yardstick_gdn.tick_flops(c, s)
 
 
-def roofline_pct(run, name: str, least) -> Optional[float]:
-    """``least(config, shape, peak)`` milliseconds over part ``name``'s."""
-    s = tick_shape(run)
-    ms = read_part(run, name)
-    if s is None or not ms:
+def least_ms(run, part: str) -> Optional[float]:
+    """The least device milliseconds the run's mean tick needs in ``part``
+    by ``yardstick_gdn`` (the slower of moving its bytes and doing its
+    operations); ``None`` for a part with no floor here."""
+    s, floor = tick_shape(run), _FLOOR.get(part)
+    if s is None or floor is None:
         return None
-    return 100.0 * least(run["ctx"].config, s, s["peak"]) / ms
+    return floor(run["ctx"].config, s)

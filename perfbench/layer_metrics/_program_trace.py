@@ -235,8 +235,12 @@ def parts_ms(doc: dict, word: str, label_of: Callable[[dict], str],
     part, and where operations overlap the time goes to the part that
     comes first in ``order``. ``"runs"`` is the programs' own time and
     ``"n_runs"`` their count (on one chip); parts and ``"in no
-    operation"`` add up to ``"runs"``."""
+    operation"`` add up to ``"runs"``. A pass is linear in the trace
+    (11-14 s on Ling's 4 s, PR 53), so each is counted: ``cuts_of(doc)``
+    names the label functions' modules the trace was cut for."""
     planes = tracered.device_planes(doc)
+    doc.setdefault("_memo", {}).setdefault("cuts", []).append(
+        label_of.__module__.rsplit(".", 1)[-1])
     out: Dict[str, float] = defaultdict(float)
     for p in planes:
         runs = whole_runs(program_runs(p, word))
@@ -261,6 +265,30 @@ def parts_ms(doc: dict, word: str, label_of: Callable[[dict], str],
             - tracered.intersection_ns(inside, covered)) / 1e6
         out["n_runs"] += len(runs)
     return {k: v / len(planes) for k, v in out.items()} if planes else None
+
+
+def cuts_of(doc: dict) -> List[str]:
+    """The module of every label function ``parts_ms`` cut ``doc`` for, in
+    order: a traced run should cut its trace for its own helper alone."""
+    return list(doc.get("_memo", {}).get("cuts", ()))
+
+
+def names_scope(doc: dict, scope_re, names: Sequence[str]) -> bool:
+    """Whether some device operation's innermost name, of those
+    ``scope_re`` finds on its scope path, is one of ``names``: what a served
+    family's helper asks before it cuts the trace (its tick's own
+    mechanism, ``blk/gdn/step``), so that the helpers whose tick this is
+    not pay one look at the trace's distinct scope paths, some thousands and
+    gathered once a trace, and no pass of ``parts_ms``."""
+    scopes = _once(doc, "scopes", lambda: frozenset(
+        ev.get("scope", "") for p in tracered.device_planes(doc)
+        for ev in tracered.op_events(p)))
+
+    def innermost(scope: str) -> str:
+        found = scope_re.findall(scope)
+        return found[-1] if found else ""
+
+    return any(innermost(s) in names for s in scopes)
 
 
 TICK_ORDER = ("kv_scatter", "attn", "dense", "head_sample", "unscoped")

@@ -2,7 +2,9 @@
 live request's tokens, mean over the window's ticks: prompt tokens made
 resident (the engine's ``chunk`` and ``prefix_hit`` events) plus tokens
 emitted, of requests that have not finished. The rest of the pools is
-reserved and idle, or parked by the prefix cache."""
+reserved and idle, or parked by the prefix cache. The long-prompt cell's GPT
+pools and Falcon-H1's nine layers of grouped pages (80 slots x 1,408,
+18,432 B a token; ``fh1.live_kv_pct`` until PR 56)."""
 
 
 def read(run):

@@ -8,7 +8,11 @@ every matrix product and norm of both kinds of layer (``blk/gdn/proj``,
 ``blk/gdn/out``, ``blk/qkv``, ``blk/kv_scatter``, ``blk/attn_out``,
 ``blk/ffn``). Ling-3.0-flash (``_ling3_trace``): ``blk/kda/proj``,
 ``blk/kda/out``, ``blk/qkv``, ``blk/attn_out`` and what of ``blk/ffn`` is
-outside the ``moe/`` parts. In every cell: the read of the weights."""
+outside the ``moe/`` parts. Falcon-H1 (``_falcon_h1_trace``):
+``blk/ssd/proj``, ``blk/ssd/out``, ``blk/qkv``, ``blk/kv_scatter``,
+``blk/attn_out``, ``blk/ffn`` (norms, both mixers' projections and ways out,
+the gated norm, RoPE, the K/V write and the SwiGLU). In every cell: the read
+of the weights."""
 from perfbench import loader
 
 

@@ -1,7 +1,8 @@
 """Median device time of one run of the served model's tick program
 (``tick.device_ms_p50.*``'s reading, in the cells whose tick is a
-latent-attention model's, DeepSeek-V2's, Olmo-Hybrid's or Ling-3.0-flash's: a
-run whose tick none of their trace helpers reads gives nothing)."""
+latent-attention model's, DeepSeek-V2's, Olmo-Hybrid's, Ling-3.0-flash's or
+Falcon-H1's: a run whose tick none of their trace helpers reads gives
+nothing)."""
 from perfbench import loader
 
 

@@ -8,7 +8,10 @@ and the head once, the live rows' states both ways, the K and V its
 attention reads, what it writes; ``yardstick_ling3.tick_bytes`` for
 Ling-3.0-flash: every dense weight and the head once, the experts touched
 once, the live rows' states both ways, the latents its attention reads, what
-it writes), over the tick's median device time."""
+it writes; ``yardstick_ssd.tick_bytes`` for Falcon-H1: every weight and the
+head once, the live rows' states both ways, the chunk rows' state and
+operands, the K and V its attention reads, what it writes), over the tick's
+median device time."""
 from perfbench import loader
 
 

@@ -7,7 +7,8 @@ operations, by the family's own yardstick (its trace helper's
 lesser form, ``yardstick_gdn.tick_flops`` for Olmo-Hybrid's delta rule in
 both forms and its full layers' visible pairs, ``yardstick_ling3.tick_flops``
 for Ling-3.0-flash's per-channel delta rule in both forms and its latent
-attention's lesser form), over the tick's median device time and the chip's
+attention's lesser form, ``yardstick_ssd.tick_flops`` for Falcon-H1's
+state-space rule in both forms and its attention's visible pairs), over the tick's median device time and the chip's
 published bf16 peak. A tick of some tens of rows is bound by HBM: this reads
 low."""
 from perfbench import loader

@@ -9,16 +9,19 @@ carries.
 A tick's shape is what its ticks counted (``models/olmo_hybrid.TICK_STATS``,
 means over the run): ``live`` decode rows that moved a state, ``chunk``
 tokens of prompt, and for one full layer the ``keys`` its decode rows and
-its chunk rows read and the chunk rows' visible query-key ``pairs``.
+its chunk rows read and the chunk rows' visible query-key ``pairs``. The
+cell's trace helper hands these out part by part (``_olmoh_trace.least_ms``:
+the floors of ``state.step_hbm_roofline_pct``, ``state.chunk_roofline_pct``
+and ``attn.full_roofline_pct`` in this cell) and whole (``tick_needs``).
 
-``gdn.step``   a live row a linear layer: the state read and written once
+``step``       a live row a linear layer: the state read and written once
                (2 x 2.21 MB), the row's q, k, v in and o out; about 7
                products a state entry. HBM binds it.
-``gdn.chunk``  a chunk token a linear layer and head, in the chunked form at
+``chunk``      a chunk token a linear layer and head, in the chunked form at
                chunks of 64: three products against the state (2 dk dv
                each), the pair matrices and their use over half a chunk (2
                C dk + 2 C dv); the row's state read and written once.
-``attn.full``  a full layer: K and V of the rows' live keys read once, 4 d
+``attn``       a full layer: K and V of the rows' live keys read once, 4 d
                operations a visible pair and head.
 """
 from __future__ import annotations

@@ -3,14 +3,16 @@ the configuration file (the keys of HF's ``config.json``) and the ticks' own
 counts of themselves (``models/ling3.TICK_STATS``, means over the run):
 the benchmark's own arithmetic, which imports none of the program's and
 reads the same work whatever implements it. Bytes and products are the
-**unpadded** ones.
+**unpadded** ones. The cell's trace helper hands these out part by part
+(``_ling3_trace.least_ms``: the floor of ``state.step_hbm_roofline_pct`` in
+this cell) and whole (``tick_needs``).
 
-``kda.step``   a live row a KDA layer: the state ``32 x 128 x 128`` float32
+``step``       a live row a KDA layer: the state ``32 x 128 x 128`` float32
                read and written once (2 x 2.10 MB), the row's q, k, v, its
                decay a channel and its beta in, o out; about 7 products a
                state entry (decay, ``k^T S``, the rank-one update, ``S^T
                q``). HBM binds it.
-``kda.chunk``  a chunk token a KDA layer and head, in the chunked form at
+``chunk``      a chunk token a KDA layer and head, in the chunked form at
                chunks of 64 (``yardstick_gdn``'s count at these widths);
                the row's state read and written once.
 ``mla``        the one MLA layer's two calls (decode rows, chunk rows), by

@@ -10,13 +10,16 @@ A tick's shape is what its ticks counted (``models/falcon_h1.TICK_STATS``,
 means over the run): ``live`` decode rows that moved a state, ``chunk``
 tokens of prompt in ``chunk_rows`` rows, and for one layer the ``keys`` its
 decode rows and its chunk rows read and the chunk rows' visible query-key
-``pairs``. Every layer has both mixers.
+``pairs``. Every layer has both mixers. The cell's trace helper hands these
+out part by part (``_falcon_h1_trace.least_ms``: the floors of
+``state.step_hbm_roofline_pct``, ``state.chunk_roofline_pct`` and
+``attn.full_roofline_pct`` in this cell) and whole (``tick_needs``).
 
-``ssd.step``   a live row a layer: the state read and written once (2 x 4.19
+``step``       a live row a layer: the state read and written once (2 x 4.19
                MB), the row's x, B, C, dt in and y out; 5 operations a state
                entry (decay, outer product and sum, the read against C and
                its sum). HBM binds it.
-``ssd.chunk``  a chunk token a layer, in the chunked form at blocks of
+``chunk``      a chunk token a layer, in the chunked form at blocks of
                ``mamba_chunk_size``: a group's scores against half a block
                (Q N), a head's use of them (Q P) and its two products against
                the state (2 x 2 N P); the row's state read and written once.

@@ -203,8 +203,10 @@ PROC, SCHED, TICK, POOL, TRAIN, FLASH = (
 TPS, ITL, STPS = ("train_tokens_per_s_per_chip", "itl_p95_ms",
                   "serve_tokens_per_s")
 ALL_TRAIN = (T1, T67, OLMOE, SOLAR)
-BACKLOGS = (LP, "serve-dots3-longdoc-backlog", "serve-dsv2-docqa-backlog",
-            "serve-olmo-hybrid-gen-backlog", "serve-ling3-longgen-backlog")
+NEWER = ("serve-dots3-longdoc-backlog", "serve-dsv2-docqa-backlog",
+         "serve-olmo-hybrid-gen-backlog", "serve-ling3-longgen-backlog",
+         "serve-falcon-h1-gen-backlog")
+BACKLOGS = (LP,) + NEWER
 #: the holds of the judged window as every backlog cell reports them (PR 51;
 #: every serving cell since PR 53): the cells' own tests import this
 BACKLOG_HOLDS = ("served.hold_lost_ms_in_window",
@@ -215,13 +217,10 @@ GPT_AND_LISTLESS = [
     ("proc.compiles_in_window", "count", "lower", "program_counter", PROC, "setup_s", None),
     ("sched.queue_wait_p50_ms", "ms", "lower", "program_span", SCHED, ITL, (CHAT, OURO)),
     ("load.generator_late_ms_max", "ms", "lower", "host_clock", SCHED, ITL, (CHAT, OURO)),
-    ("sched.prefill_tokens_per_tick", "tokens", "higher", "program_counter", SCHED, STPS, (LP,)),
-    ("sched.decode_rows_per_tick", "rows", "higher", "program_counter", SCHED, STPS, (LP,)),
-    ("sched.serve_tokens_per_s_slice_p50", "tokens/s", "higher", "host_clock", SCHED, STPS, (LP,)),
     ("tick.device_ms_p50.chat", "ms", "lower", "device_trace", TICK, ITL, (CHAT, OURO)),
     ("tick.device_ms_p50.backlog", "ms", "lower", "device_trace", TICK, STPS, (LP,)),
     ("pool.live_kv_pct.chat", "%", "higher", "program_counter", POOL, ITL, (CHAT, OURO)),
-    ("pool.live_kv_pct.backlog", "%", "higher", "program_counter", POOL, STPS, (LP,)),
+    ("pool.live_kv_pct.backlog", "%", "higher", "program_counter", POOL, STPS, (LP, NEWER[4])),
     ("train.mfu_pct", "%", "higher", "host_clock", TRAIN, TPS, (T1, T67)),
     ("train.peak_hbm_gb", "GB", "lower", "program_counter", TRAIN, TPS, ALL_TRAIN),
     ("train.live_hbm_gb", "GB", "lower", "program_counter", TRAIN, TPS, ALL_TRAIN),
@@ -250,6 +249,13 @@ GPT_AND_LISTLESS = [
     ("setup.cache_fetch_s", "s", "lower", "program_counter", PROC, "setup_s", None),
     ("setup.programs_before_window", "count", "lower", "program_counter", PROC, "setup_s", None),
     ("setup.unaccounted_s", "s", "lower", "host_clock", PROC, "setup_s", None),
+    # the long-prompt cell's three readers of facts are the newer cells' since
+    # PR 56 (``sched.prefill_tokens_per_tick``, ``sched.decode_rows_per_tick``,
+    # ``sched.serve_tokens_per_s_slice_p50`` until then); Ling's window is all
+    # decode and lists no prefill
+    ("served.prefill_tokens_per_tick", "tokens", "higher", "program_counter", SCHED, STPS, NEWER[:3] + NEWER[4:] + (LP,)),
+    ("served.decode_rows_per_tick", "rows", "higher", "program_counter", SCHED, STPS, NEWER + (LP,)),
+    ("served.tokens_per_s_slice_p50", "tokens/s", "higher", "host_clock", SCHED, STPS, NEWER + (LP,)),
     ("served.hold_lost_ms_in_window", "ms", "lower", "program_counter", SCHED, STPS, BACKLOGS),
     ("sched.hold_lost_ms_in_window", "ms", "lower", "program_counter", SCHED, ITL, (CHAT, OURO)),
     ("served.hold_unexplained_pct", "%", "lower", "program_counter", SCHED, STPS, BACKLOGS),

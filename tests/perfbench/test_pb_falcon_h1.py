@@ -1,6 +1,6 @@
 """Falcon-H1's cell: the configuration file against the catalog's row and its
 family's ``check_widths``, the toy family through the contract's rules,
-``yardstick_ssd``'s counts by hand, the 23 new readers on a synthetic trace,
+``yardstick_ssd``'s counts by hand, the cell's 23 readers on a synthetic trace,
 the check and its controls through ``check()`` itself at a small size, and a
 CPU rehearsal of the cell on a toy configuration in a temporary copy."""
 import json
@@ -15,25 +15,28 @@ import pytest
 
 from perfbench import loader, yardstick, yardstick_ssd as ys
 
-from test_pb_contract import config_file_is_sound, family_is_only_a_model
+from test_pb_contract import BACKLOG_HOLDS as HOLDS, config_file_is_sound, \
+    family_is_only_a_model
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 TOY = os.path.join(HERE, "toy_falcon_h1")
 CELL = "serve-falcon-h1-gen-backlog"
 CATALOG = "/opt/skills/guides/model-configs/architectures.jsonl"
-PARTS = ("fh1.dense_ms_per_tick", "fh1.head_sample_ms_per_tick",
-         "ssd.step_ms_per_tick", "ssd.chunk_ms_per_tick",
-         "ssd.prep_ms_per_tick", "fh1.attn_ms_per_tick",
-         "fh1.unscoped_ms_per_tick")
-SHARES = ("fh1.tick_mfu_pct", "fh1.tick_hbm_roofline_pct",
-          "ssd.step_hbm_roofline_pct", "ssd.chunk_roofline_pct",
-          "fh1.attn_roofline_pct")
-COUNTED = ("fh1.live_state_slots_pct", "fh1.live_kv_pct",
-           "fh1.tokens_per_s_slice_p50", "fh1.prefill_tokens_per_tick",
-           "fh1.decode_rows_per_tick", "fh1.host_ms_per_tick")
-HOLDS = ("fh1.hold_lost_ms_in_window", "fh1.hold_unexplained_pct",
-         "fh1.tokens_per_s_outside_holds", "fh1.tick_ms_p50_in_window")
-NEW = ("fh1.tick_device_ms_p50",) + PARTS + SHARES + COUNTED + HOLDS
+# The cell's 23 quantities under the names they carry since PR 56: every one
+# is an entry another cell reports too (``served.*``, ``state.*``,
+# ``attn.full_*``, ``pool.*``; the cell's own ``fh1.*`` and ``ssd.*`` copies
+# went), none lists this cell alone.
+PARTS = ("served.dense_ms_per_tick", "served.head_sample_ms_per_tick",
+         "state.step_ms_per_tick", "state.chunk_ms_per_tick",
+         "state.prep_ms_per_tick", "attn.full_ms_per_tick",
+         "served.unscoped_ms_per_tick")
+SHARES = ("served.tick_mfu_pct", "served.tick_hbm_roofline_pct",
+          "state.step_hbm_roofline_pct", "state.chunk_roofline_pct",
+          "attn.full_roofline_pct")
+COUNTED = ("pool.live_state_slots_pct", "pool.live_kv_pct.backlog",
+           "served.tokens_per_s_slice_p50", "served.prefill_tokens_per_tick",
+           "served.decode_rows_per_tick", "served.host_ms_per_tick")
+NEW = ("served.tick_device_ms_p50",) + PARTS + SHARES + COUNTED + HOLDS
 WIDTHS = ("hidden_size", "intermediate_size", "num_attention_heads",
           "num_key_value_heads", "head_dim", "mamba_d_ssm", "mamba_n_heads",
           "mamba_d_head", "mamba_d_state", "mamba_n_groups", "mamba_d_conv",
@@ -224,13 +227,13 @@ def test_the_loader_finds_every_piece_of_the_cell(bench):
     assert callable(loader.load_module("families", c["family"]).run)
     for name in NEW:
         assert callable(loader.load_module("layer_metrics", name).read), name
+    # the helper is in the served form since PR 56: ``_served`` lists it
     helper = loader.load_module("layer_metrics", "_falcon_h1_trace")
-    assert hasattr(helper, "needs") and not hasattr(helper, "tick_needs")
+    assert hasattr(helper, "tick_needs") and hasattr(helper, "least_ms") \
+        and not hasattr(helper, "needs")
     served = loader.load_module("layer_metrics", "_served")
-    assert len(served.helpers()) == 4 \
-        and "_falcon_h1_trace" not in served.helpers()
-    assert len(bench["workloads"]) == 12 and len(bench["configs"]) == 11 \
-        and len(bench["per_layer"]) == 127
+    assert "_falcon_h1_trace" in served.helpers()
+    assert len(bench["workloads"]) >= 12 and len(bench["configs"]) >= 11
     assert sum(w["chips"] == 4 for w in bench["workloads"]) == 1
 
 
@@ -351,28 +354,35 @@ def test_the_readers_split_a_tick_by_the_programs_names(monkeypatch):
     run, pt = _run_with(doc, real_config(), dict(FACTS))
     monkeypatch.setattr(pt, "load", lambda: doc)
     read = lambda name: loader.load_module("layer_metrics", name).read(run)
-    want = {"fh1.tick_device_ms_p50": 30.0, "fh1.dense_ms_per_tick": 12.0,
-            "fh1.head_sample_ms_per_tick": 4.0, "ssd.step_ms_per_tick": 2.0,
-            "ssd.chunk_ms_per_tick": 2.0, "ssd.prep_ms_per_tick": 2.0,
-            "fh1.attn_ms_per_tick": 2.0, "fh1.live_state_slots_pct": 97.5,
-            "fh1.live_kv_pct": 47.0, "fh1.tokens_per_s_slice_p50": 4000.0,
-            "fh1.prefill_tokens_per_tick": 0.2 * 256,
-            "fh1.decode_rows_per_tick": 80.0}
+    want = {"served.tick_device_ms_p50": 30.0,
+            "served.dense_ms_per_tick": 12.0,
+            "served.head_sample_ms_per_tick": 4.0,
+            "state.step_ms_per_tick": 2.0, "state.chunk_ms_per_tick": 2.0,
+            "state.prep_ms_per_tick": 2.0, "attn.full_ms_per_tick": 2.0,
+            "pool.live_state_slots_pct": 97.5,
+            "pool.live_kv_pct.backlog": 47.0,
+            "served.tokens_per_s_slice_p50": 4000.0,
+            "served.prefill_tokens_per_tick": 0.2 * 256,
+            "served.decode_rows_per_tick": 80.0}
     for name, value in want.items():
         assert read(name) == pytest.approx(value), name
     # the parts and what no name covers add up to the tick
     assert sum(read(n) for n in PARTS) == pytest.approx(30.0)
     peak = yardstick.chip_peak("TPU v5 lite")
     c = real_config()
-    assert read("ssd.step_hbm_roofline_pct") == pytest.approx(
+    assert read("state.step_hbm_roofline_pct") == pytest.approx(
         100 * ys.least_ms(ys.step_flops(c, 79.0), ys.step_bytes(c, 79.0),
                           peak) / 2.0)
     shape = {"live": 79.0, "chunk": 50.0, "chunk_rows": 0.2, "sampled": 80.0,
              "decode_keys": 79 * 660.0, "chunk_keys": 120.0,
              "chunk_pairs": 15000.0}
-    assert read("fh1.tick_hbm_roofline_pct") == pytest.approx(
+    assert read("attn.full_roofline_pct") == pytest.approx(
+        100 * ys.least_ms(ys.attention_flops(c, 79 * 660.0 + 15000.0),
+                          ys.attention_bytes(c, 79 * 660.0 + 120.0), peak)
+        / 2.0)                          # four grouped heads: its own floor
+    assert read("served.tick_hbm_roofline_pct") == pytest.approx(
         100 * ys.tick_bytes(c, shape) / peak.hbm_bytes_per_s * 1e3 / 30.0)
-    assert read("fh1.tick_mfu_pct") == pytest.approx(
+    assert read("served.tick_mfu_pct") == pytest.approx(
         100 * ys.tick_flops(c, shape) / 30e-3 / peak.bf16_flops)
     for name in SHARES:
         assert 0 < read(name), name
@@ -380,6 +390,14 @@ def test_the_readers_split_a_tick_by_the_programs_names(monkeypatch):
         f[:-3] for f in os.listdir(os.path.join(loader.HERE,
                                                 "layer_metrics"))
         if f[:-3] in NEW)
+    # the other served families' helpers do not read this tick: at most one
+    # answers, and ``_served`` finds this one
+    for other in ("_dots3_trace", "_dsv2_trace", "_olmoh_trace",
+                  "_ling3_trace"):
+        assert loader.load_module("layer_metrics", other).parts_ms(run) \
+            is None, other
+    assert loader.load_module("layer_metrics", "_served").trace_of(run) \
+        is loader.load_module("layer_metrics", "_falcon_h1_trace")
 
 
 def test_the_readers_find_nothing_in_a_program_without_the_model(
@@ -388,7 +406,8 @@ def test_the_readers_find_nothing_in_a_program_without_the_model(
     ``blk/ssd/step``, and its family's facts hold no state rows: every
     reader of the trace returns ``None`` and raises nothing; so with no
     trace at all. The hybrid's tick (``blk/gdn/step``) is not this
-    helper's either."""
+    helper's either (the folded readers read that tick as Olmo-Hybrid's,
+    and with a GPT run's facts find no tick shape: test_pb_fold.py)."""
     gpt = loader.load_json(loader.root_file(
         "perfbench/configs/gpt3-1.3b-serve.json"))
     for scopes in (["blk/qkv", "blk/attn", "blk/ffn", "tick/head"],
@@ -398,13 +417,16 @@ def test_the_readers_find_nothing_in_a_program_without_the_model(
             "decode_rows_per_tick": 9.0, "prefill_rows_per_tick": 0.25,
             "prefill_chunk": 32, "live_kv_share": 0.5})
         monkeypatch.setattr(pt, "load", lambda doc=doc: doc)
-        for name in ("fh1.tick_device_ms_p50",) + PARTS + SHARES \
-                + COUNTED[:1]:
+        assert loader.load_module(
+            "layer_metrics", "_falcon_h1_trace").parts_ms(run) is None
+        names = ("served.tick_device_ms_p50",) + PARTS + SHARES \
+            + COUNTED[:1] if "blk/gdn/step" not in scopes else SHARES
+        for name in names:
             assert loader.load_module("layer_metrics", name).read(run) \
                 is None, name
     run["ctx"].trace_doc = None
     assert loader.load_module(
-        "layer_metrics", "fh1.tick_mfu_pct").read(run) is None
+        "layer_metrics", "served.tick_mfu_pct").read(run) is None
 
 
 def test_the_cells_lists_name_the_new_metrics_of_this_cell(bench):
@@ -416,16 +438,19 @@ def test_the_cells_lists_name_the_new_metrics_of_this_cell(bench):
     assert cell["cell"]["chips"] == 1 \
         and cell["cell"]["traffic"] == "gen-640-backlog"
     for m in bench["per_layer"]:
-        if m["name"] in NEW:
-            assert m["workloads"] == [CELL] \
-                and m["moves"] == "serve_tokens_per_s"
-        else:       # no accepted metric's list of cells names this cell
+        if m["name"] in NEW:    # a quantity another cell reports too
+            assert CELL in m["workloads"] and len(m["workloads"]) > 1 \
+                and m["moves"] == "serve_tokens_per_s", m["name"]
+        else:       # no other metric's list of cells names this cell
             assert CELL not in m.get("workloads", ())
+    # the cell's own copies went with the fold (PR 56)
+    assert not [m["name"] for m in bench["per_layer"]
+                if m["name"].startswith(("fh1.", "ssd."))]
     layers = {m["layer"] for m in bench["per_layer"] if m["name"] in NEW}
     assert layers == {
         "serving tick (device)", "serving scheduler (host)",
         "paged attention / page pool",
-        "state-space step and scan, pages and a state in one layer"}
+        "recurrent state pool: step, chunked scan, in-place pass"}
 
 
 # --- the check, controls included, through check() itself -------------------
@@ -565,7 +590,7 @@ def test_a_traced_rehearsal_reports_what_the_cpu_can(copy):
     got = set(line["metrics"])
     # the engine's own record of its ticks reads on the CPU too
     assert set(HOLDS) <= got
-    assert {"fh1.live_state_slots_pct", "fh1.live_kv_pct",
-            "fh1.tokens_per_s_slice_p50", "fh1.prefill_tokens_per_tick",
-            "fh1.decode_rows_per_tick"} <= got
-    assert 0 < line["metrics"]["fh1.live_state_slots_pct"]["value"] <= 100
+    assert {"pool.live_state_slots_pct", "pool.live_kv_pct.backlog",
+            "served.tokens_per_s_slice_p50", "served.prefill_tokens_per_tick",
+            "served.decode_rows_per_tick"} <= got
+    assert 0 < line["metrics"]["pool.live_state_slots_pct"]["value"] <= 100

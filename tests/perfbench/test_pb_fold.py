@@ -1,11 +1,17 @@
-"""One entry a quantity among the four newer backlog cells (PR 48; Ling's
-cell since PR 53): a folded reader returns in each cell, to the last digit,
-what that cell's retired copy returned (``dots3.*``, ``dsv2.*``, ``olmoh.*``,
-the ``.longdoc``, ``.dsv2`` and ``.olmoh`` suffixes; ``ling.*`` and
-``kda.prep_ms_per_tick``). The copies' bodies are spelt out here against the
-cell's own trace helper, on each cell's synthetic tick and on every piece of
-a real trace recorded under ``recorded_served/``; a tick that names none of
-the four mechanisms (the recorded GPT tick) reads nothing. The two sparse
+"""One entry a quantity among the five newer backlog cells (PR 48; Ling's
+cell since PR 53, Falcon-H1's since PR 56): a folded reader returns in each
+cell, to the last digit, what that cell's retired copy returned (``dots3.*``,
+``dsv2.*``, ``olmoh.*``, the ``.longdoc``, ``.dsv2`` and ``.olmoh`` suffixes;
+``ling.*`` and ``kda.prep_ms_per_tick``; ``fh1.*``, ``ssd.*``, ``gdn.*`` and
+``kda.step_*``, a recurrent state's three passes under one name whatever the
+rule). The copies' bodies are spelt out here against the cell's own trace
+helper, on each cell's synthetic tick and on every piece of a real trace
+recorded under ``recorded_served/``; a tick that names none of the five
+mechanisms (the recorded GPT tick) reads nothing, and the long-prompt cell's
+three readers of facts alone are cases against a GPT run's facts. The
+helpers are pinned from below: the accepted ones must be there and exactly
+one must answer a tick, and a helper a later PR brings beside them fails
+nothing. The two sparse
 training cells share ``moe.train_mfu_pct`` and ``moe.experts_roofline_pct``
 the same way (``solar2.train_mfu_pct`` and ``moe.held_experts_roofline_pct``
 were Solar-Open2's copies)."""
@@ -15,11 +21,16 @@ import types
 
 import pytest
 
+import shutil
+import sys
+
 from perfbench import loader, yardstick, yardstick_gdn, yardstick_kda, \
-    yardstick_ling3, yardstick_mla, yardstick_mla_dense, yardstick_moe
+    yardstick_ling3, yardstick_mla, yardstick_mla_dense, yardstick_moe, \
+    yardstick_ssd
 
 import test_pb_dots3 as dots3
 import test_pb_dsv2 as dsv2
+import test_pb_falcon_h1 as falcon
 import test_pb_ling3 as ling3
 import test_pb_olmo_hybrid as olmoh
 import test_pb_olmoe as olmoe
@@ -80,6 +91,40 @@ def _mfu_pct(tick_flops):
     return read
 
 
+def _roofline(part, y, least):
+    """A retired ``*_roofline_pct`` of one part: the reader spelt the
+    family's helper, its own word for the part and its yardstick ``y`` out:
+    ``least(y, config, tick_shape)`` gives ``(operations, bytes)``."""
+    def read(tr, run):
+        s, ms = tr.tick_shape(run), tr.read_part(run, part)
+        return 100.0 * y.least_ms(*least(y, run["ctx"].config, s),
+                                  s["peak"]) / ms
+    return read
+
+
+def _step(y, c, s):
+    return y.step_flops(c, s["live"]), y.step_bytes(c, s["live"])
+
+
+def _chunk(y, c, s):
+    return (y.chunk_flops(c, s["chunk"]),
+            y.chunk_bytes(c, s["chunk"], s["chunk_rows"]))
+
+
+def _attention(y, c, s):
+    return (y.attention_flops(c, s["decode_keys"] + s["chunk_pairs"]),
+            y.attention_bytes(c, s["decode_keys"] + s["chunk_keys"]))
+
+
+def _ling_decode_roofline(tr, run):
+    """``ling.mla_decode_roofline_pct`` as it stood until PR 56: it named
+    ``_ling3_trace`` and ``yardstick_ling3``; the helper hands the floor out
+    since (``least_ms``) and the reader asks ``_served``."""
+    s, ms = tr.tick_shape(run), tr.read_part(run, "mla_decode")
+    return 100.0 * yardstick_ling3.attention_least_ms(
+        run["ctx"].config, (s["decode"],), s["peak"]) / ms
+
+
 def _experts_hbm(experts_bytes):
     def read(tr, run):
         s, ms = tr.tick_shape(run), tr.read_part(run, "experts")
@@ -125,9 +170,27 @@ LATENT = {
 #: what the cells that hold a share of an expert-parallel layer under a group
 #: limit count besides (DeepSeek-V2's own entry until Ling's cell joined it)
 GROUPS = {"moe.tick_group_hit_pct": _fact("tick_group_hit_share", 100.0)}
-#: the two cells that keep a recurrent state a slot
-STATE = {"gdn.prep_ms_per_tick": _part("gdn_prep"),
-         "pool.live_state_slots_pct": _fact("live_state_share", 100.0)}
+def state(own, y, chunk=True, attn=True):
+    """The copies of a cell that keeps a recurrent state a slot: ``gdn.*``
+    (Olmo-Hybrid), ``kda.step_*`` and ``gdn.prep`` (Ling), ``ssd.*`` and
+    ``fh1.attn_*`` (Falcon-H1), each against the helper's ``own`` words for
+    the passes and the family's yardstick ``y``."""
+    out = {"state.step_ms_per_tick": _part(own + "_step"),
+           "state.step_hbm_roofline_pct": _roofline(own + "_step", y, _step),
+           "state.prep_ms_per_tick": _part(
+               "gdn_prep" if own == "kda" else own + "_prep"),
+           "pool.live_state_slots_pct": _fact("live_state_share", 100.0)}
+    if chunk:
+        out.update({
+            "state.chunk_ms_per_tick": _part(own + "_chunk"),
+            "state.chunk_roofline_pct": _roofline(own + "_chunk", y, _chunk)})
+    if attn:
+        out.update({
+            "attn.full_ms_per_tick": _part("attn"),
+            "attn.full_roofline_pct": _roofline("attn", y, _attention)})
+    return out
+
+
 #: the decode rows' dense latent attention (DeepSeek-V2's own until PR 53)
 DENSE_MLA = {"mla.dense_decode_ms_per_tick": _part("mla_decode")}
 CELLS = {
@@ -161,7 +224,7 @@ CELLS = {
                     yardstick_mla_dense.experts_bytes)}),
     "serve-olmo-hybrid-gen-backlog": dict(
         helper="_olmoh_trace", test=olmoh, kernels=None,
-        copies={**SHARED, **STATE,
+        copies={**SHARED, **state("gdn", yardstick_gdn),
                 "served.tick_hbm_roofline_pct": _hbm_pct(
                     yardstick_gdn.tick_bytes),
                 "served.tick_mfu_pct": _mfu_pct(yardstick_gdn.tick_flops)}),
@@ -172,11 +235,22 @@ CELLS = {
         helper="_ling3_trace", test=ling3, kernels=None,
         copies={**{k: v for k, v in SHARED.items()
                    if k != "served.prefill_tokens_per_tick"},
-                **LATENT, **GROUPS, **STATE, **DENSE_MLA,
+                **LATENT, **GROUPS, **DENSE_MLA,
+                **state("kda", yardstick_ling3, chunk=False, attn=False),
                 "served.tick_hbm_roofline_pct": _hbm_pct(
                     yardstick_ling3.tick_bytes),
                 "served.tick_mfu_pct": _mfu_pct(yardstick_ling3.tick_flops),
-                "moe.tick_experts_hbm_roofline_pct": _ling_experts_hbm}),
+                "moe.tick_experts_hbm_roofline_pct": _ling_experts_hbm,
+                "ling.mla_decode_roofline_pct": _ling_decode_roofline}),
+    # the bodies of ``fh1.*`` and ``ssd.*`` (PR 54), which asked
+    # ``_falcon_h1_trace`` directly (``needs``, its own words for the parts)
+    "serve-falcon-h1-gen-backlog": dict(
+        helper="_falcon_h1_trace", test=falcon, kernels=None,
+        copies={**SHARED, **state("ssd", yardstick_ssd),
+                "pool.live_kv_pct.backlog": _fact("live_kv_share", 100.0),
+                "served.tick_hbm_roofline_pct": _hbm_pct(
+                    yardstick_ssd.tick_bytes),
+                "served.tick_mfu_pct": _mfu_pct(yardstick_ssd.tick_flops)}),
 }
 #: the one copy whose arithmetic ran in another order than its folded reader's
 LAST_PLACE = {("serve-ling3-longgen-backlog",
@@ -197,7 +271,8 @@ TRACE_READERS = sorted(
                     "served.tokens_per_s_slice_p50",
                     "moe.tick_expert_load_max_over_mean",
                     "moe.tick_experts_touched_pct", "pool.live_latent_pct",
-                    "moe.tick_group_hit_pct", "pool.live_state_slots_pct"))
+                    "moe.tick_group_hit_pct", "pool.live_state_slots_pct",
+                    "pool.live_kv_pct.backlog"))
 
 
 def _host(doc):
@@ -240,14 +315,148 @@ def test_the_cells_own_helper_and_no_other_reads_its_tick(cell, monkeypatch):
     run = _synthetic_run(cell, monkeypatch)
     served = _helper("_served")
     assert served.trace_of(run) is _helper(CELLS[cell]["helper"])
-    assert served.helpers() == sorted(c["helper"] for c in CELLS.values())
+    # the accepted helpers are there; a later PR may bring one more
+    assert set(served.helpers()) >= {c["helper"] for c in CELLS.values()}
     assert [n for n in served.helpers()
             if _helper(n).parts_ms(run) is not None] \
         == [CELLS[cell]["helper"]]
+    # ... and the trace was cut for that helper alone: the others looked
+    # for their mechanism's scope and found none (``names_scope``)
+    pt = _helper("_program_trace")
+    assert pt.cuts_of(pt.doc_of(run)) == [CELLS[cell]["helper"]]
     with open(served.__file__, encoding="utf-8") as f:
         source = f.read()
-    for name in ("dots3", "dsv2", "olmoh", "ling", "yardstick"):
+    for name in FAMILY_WORDS:
         assert name not in source.split('"""', 2)[2], name
+
+
+#: what a reader that names no family does not spell: the five families'
+#: helpers and every yardstick (``_program_trace``, ``_tick``, ``_holds`` and
+#: ``_served`` are no family's)
+FAMILY_WORDS = ("dots3", "dsv2", "olmoh", "ling", "falcon", "yardstick")
+SHARED_PREFIXES = ("served.", "state.", "attn.full_", "moe.tick_", "latent.",
+                   "pool.")
+
+
+def _shared_names():
+    bench = loader.load_json(loader.root_file("BENCHMARK.json"))
+    return sorted(m["name"] for m in bench["per_layer"]
+                  if m["name"].startswith(SHARED_PREFIXES))
+
+
+@pytest.mark.parametrize("name", _shared_names())
+def test_a_shared_reader_names_no_family_and_no_yardstick(name):
+    """A reader an entry of several cells names asks ``_served`` (or
+    ``_program_trace``, ``_tick``, ``_holds``, the run's facts): the code
+    under its docstring spells no family's helper and no yardstick, so a
+    later family's cell joins its list and edits no reader."""
+    path = os.path.join(loader.HERE, "layer_metrics", name + ".py")
+    with open(path, encoding="utf-8") as f:
+        code = f.read().split('"""', 2)[2]
+    for word in FAMILY_WORDS + ("_trace",):
+        assert word not in code.replace("_program_trace", ""), (name, word)
+
+
+def test_a_sixth_helper_beside_the_five_fails_nothing(tmp_path, monkeypatch):
+    """The door a later ``model_config`` PR comes through: a file
+    ``_<family>_trace.py`` in the served form (``tick_needs``, ``least_ms``,
+    the shared part names). ``_served.helpers()`` lists it, it answers for
+    none of the accepted ticks, and a tick that names its mechanism is read
+    through the shared readers with no reader edited."""
+    dst, real = str(tmp_path / "perfbench"), loader.HERE
+    shutil.copytree(real, dst, ignore=shutil.ignore_patterns("__pycache__"))
+    with open(os.path.join(dst, "layer_metrics", "_sixth_trace.py"), "w",
+              encoding="utf-8") as f:
+        f.write(SIXTH)
+    loader.HERE = dst
+    kept = {name: sys.modules.pop(name) for name in list(sys.modules)
+            if name.startswith("perfbench.layer_metrics.")}
+    try:
+        served = _helper("_served")             # the copy's, loaded anew
+        assert served.helpers() == sorted(
+            [c["helper"] for c in CELLS.values()] + ["_sixth_trace"])
+        for cell in CELLS:                    # none of theirs is its tick
+            run = _synthetic_run(cell, monkeypatch)
+            assert served.trace_of(run).__name__.endswith(
+                CELLS[cell]["helper"])
+            assert _helper("_sixth_trace").parts_ms(run) is None
+        t = falcon                            # a tick of the sixth rule
+        doc = t._synthetic(["blk/xyz/step", "blk/ffn", "tick/head"])
+        run, pt = t._run_with(_host(doc), t.real_config(), dict(t.FACTS))
+        monkeypatch.setattr(pt, "load", lambda: doc)
+        assert served.trace_of(run) is _helper("_sixth_trace")
+        read = lambda n: loader.load_module("layer_metrics", n).read(run)
+        assert read("state.step_ms_per_tick") == 2.0
+        assert read("state.step_hbm_roofline_pct") == 100.0 * 1.5 / 2.0
+        assert read("state.chunk_roofline_pct") is None   # no floor: nothing
+        assert read("served.dense_ms_per_tick") == 2.0
+        assert read("served.tick_hbm_roofline_pct") > 0
+        assert pt.cuts_of(doc) == ["_sixth_trace"]
+    finally:
+        loader.HERE = real
+        for name, mod in list(sys.modules.items()):
+            if name.startswith("perfbench.") and (getattr(
+                    mod, "__file__", None) or "").startswith(dst):
+                del sys.modules[name]
+        sys.modules.update(kept)
+
+
+SIXTH = '''"""A served family's helper as a later PR would bring it."""
+import re
+
+from perfbench import loader, yardstick
+
+_PART = {"blk/xyz/step": "xyz_step", "blk/ffn": "dense",
+         "tick/head": "head_sample"}
+_SCOPE = re.compile(r"\\b(" + "|".join(
+    re.escape(n) for n in sorted(_PART, key=len, reverse=True)) + r")\\b")
+ORDER = ("dense", "xyz_step", "head_sample", "unscoped")
+MECHANISM = ("blk/xyz/step",)
+SHARED = {"state_step": "xyz_step"}
+
+
+def part(ev):
+    found = _SCOPE.findall(ev.get("scope", ""))
+    return _PART[found[-1]] if found else "unscoped"
+
+
+def parts_ms(run):
+    pt = loader.load_module("layer_metrics", "_program_trace")
+    doc = pt.doc_of(run)
+    if doc is None:
+        return None
+
+    def compute():
+        if not pt.names_scope(doc, _SCOPE, MECHANISM):
+            return None
+        parts = pt.parts_ms(doc, "tick", part, ORDER)
+        n = parts.pop("n_runs")
+        return {k: v / n for k, v in parts.items()}
+
+    return pt._once(doc, "sixth parts", compute)
+
+
+def read_part(run, name):
+    parts = parts_ms(run)
+    return None if parts is None else parts.get(SHARED.get(name, name), 0.0)
+
+
+def tick_shape(run):
+    if parts_ms(run) is None:
+        return None
+    return {"ms": loader.load_module("layer_metrics",
+                                     "_tick").device_ms_p50(run),
+            "peak": yardstick.chip_peak("TPU v5 lite")}
+
+
+def tick_needs(run):
+    s = tick_shape(run)
+    return None if s is None else (s, 1e9, 1e12)
+
+
+def least_ms(run, part):
+    return 1.5 if part == "state_step" and parts_ms(run) is not None else None
+'''
 
 
 @pytest.mark.parametrize("cell", sorted(CELLS))
@@ -263,13 +472,21 @@ def test_benchmark_json_lists_the_cell_under_every_folded_name(cell):
             "ling.warm_prefill_tokens_per_s",   # the chunk path, warm-in's
             "ling.mla_decode_roofline_pct"), name   # the decode rows alone
     assert "kda.prep_ms_per_tick" not in lists
+    # PR 56: Falcon-H1's copies, the three rules' names for one state's
+    # passes and the long-prompt cell's three fact readers
+    for name in lists:
+        assert not name.startswith(("fh1.", "ssd.", "gdn.", "kda.step_")), \
+            name
+    assert not {"sched.prefill_tokens_per_tick", "sched.decode_rows_per_tick",
+                "sched.serve_tokens_per_s_slice_p50"} & set(lists)
 
 
 @pytest.mark.parametrize("name", TRACE_READERS)
 def test_a_folded_reader_finds_nothing_in_a_tick_that_names_no_mechanism(
         name, monkeypatch):
     """The recorded tick is a served GPT's (``blk/attn``, ``blk/ffn``):
-    none of the four helpers reads it, so no folded reader does."""
+    none of the five helpers reads it, so no folded reader does, and none
+    of them cut the trace to say so."""
     pt = _helper("_program_trace")
     doc = pt.load_recorded(os.path.join(HERE,
                                         "recorded_scoped_tick.json.gz"))
@@ -282,10 +499,44 @@ def test_a_folded_reader_finds_nothing_in_a_tick_that_names_no_mechanism(
         "prefill_chunk": 32, "live_kv_share": 0.5}}
     monkeypatch.setattr(pt, "load", lambda: doc)
     assert _helper("_served").trace_of(run) is None
+    assert pt.cuts_of(doc) == []
     if name == "served.host_ms_per_tick":     # the host's spans are there
         assert loader.load_module("layer_metrics", name).read(run) > 0
     else:
         assert loader.load_module("layer_metrics", name).read(run) is None
+
+
+# --- the long-prompt cell's three readers of facts alone ------------------------
+#: folded name -> (the retired ``sched.*`` copy's body, spelt out; a GPT
+#: run's facts: the copies read no trace, so none is made)
+LONGPROMPT = {
+    "served.prefill_tokens_per_tick": lambda f: (
+        f["prefill_rows_per_tick"] * f["prefill_chunk"]
+        if "prefill_rows_per_tick" in f else None),
+    "served.decode_rows_per_tick": lambda f: f.get("decode_rows_per_tick"),
+    "served.tokens_per_s_slice_p50":
+        lambda f: f.get("serve_tokens_per_s_slice_p50"),
+}
+
+
+@pytest.mark.parametrize("name", sorted(LONGPROMPT))
+def test_the_long_prompt_cells_fact_readers_are_the_served_ones(name):
+    """``sched.prefill_tokens_per_tick``, ``sched.decode_rows_per_tick`` and
+    ``sched.serve_tokens_per_s_slice_p50`` were the ``served.*`` readers
+    letter for letter under the docstring: the cell joins their lists."""
+    facts = {"decode_rows_per_tick": 11.25, "prefill_rows_per_tick": 0.75,
+             "prefill_chunk": 512, "serve_tokens_per_s_slice_p50": 5170.5}
+    ctx = types.SimpleNamespace(trace_doc=None, config=loader.load_json(
+        loader.root_file("perfbench/configs/gpt3-1.3b-serve.json")))
+    read = loader.load_module("layer_metrics", name).read
+    want = LONGPROMPT[name](facts)
+    assert want is not None and read({"ctx": ctx, "facts": facts}) == want
+    assert read({"ctx": ctx, "facts": {}}) is None      # nothing to read
+    bench = loader.load_json(loader.root_file("BENCHMARK.json"))
+    lists = {m["name"]: m.get("workloads", ()) for m in bench["per_layer"]}
+    assert "serve-longprompt-backlog" in lists[name]
+    assert name.replace("served.", "sched.").replace(
+        "tokens_per_s_slice", "serve_tokens_per_s_slice") not in lists
 
 
 # --- the two sparse training cells' shared names --------------------------------

@@ -22,17 +22,19 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 TOY = os.path.join(HERE, "toy_ling3")
 CELL = "serve-ling3-longgen-backlog"
 CATALOG = "/opt/skills/guides/model-configs/architectures.jsonl"
-# The cell's 25 quantities under the names they carry since PR 53: 21 of them
+# The cell's 25 quantities under the names they carry since PR 56: 23 of them
 # are entries other cells report too (``served.*``, ``moe.tick_*``,
-# ``latent.*``, ``mla.dense_decode``, ``pool.*``, ``gdn.prep``; the cell's
-# own ``ling.*`` copies and ``kda.prep_ms_per_tick`` went), four are its own.
+# ``latent.*``, ``mla.dense_decode``, ``pool.*`` since PR 53, when the cell's
+# own ``ling.*`` copies and ``kda.prep_ms_per_tick`` went; the step and the
+# pass before it under ``state.*`` since PR 56, whatever the rule), two are
+# its own.
 PARTS = ("served.dense_ms_per_tick", "served.head_sample_ms_per_tick",
-         "kda.step_ms_per_tick", "gdn.prep_ms_per_tick",
+         "state.step_ms_per_tick", "state.prep_ms_per_tick",
          "mla.dense_decode_ms_per_tick", "latent.scatter_ms_per_tick",
          "moe.tick_route_ms_per_tick", "moe.tick_experts_ms_per_tick",
          "moe.tick_shared_ms_per_tick", "served.unscoped_ms_per_tick")
 SHARES = ("served.tick_mfu_pct", "served.tick_hbm_roofline_pct",
-          "kda.step_hbm_roofline_pct", "ling.mla_decode_roofline_pct",
+          "state.step_hbm_roofline_pct", "ling.mla_decode_roofline_pct",
           "moe.tick_experts_hbm_roofline_pct")
 COUNTED = ("served.host_ms_per_tick", "moe.tick_expert_load_max_over_mean",
            "moe.tick_experts_touched_pct", "moe.tick_group_hit_pct",
@@ -41,11 +43,10 @@ COUNTED = ("served.host_ms_per_tick", "moe.tick_expert_load_max_over_mean",
            "ling.warm_prefill_tokens_per_s")
 NEW = ("served.tick_device_ms_p50",) + PARTS + SHARES + COUNTED
 #: the entries that list this cell alone: what only this tick has (the
-#: per-channel step; the decode rows' attention alone over its own roofline,
-#: where ``mla.dense_attn_roofline_pct`` is DeepSeek-V2's chunk and decode
-#: calls together; the chunk path's reading from warm-in)
-OWN = ("kda.step_ms_per_tick", "kda.step_hbm_roofline_pct",
-       "ling.mla_decode_roofline_pct", "ling.warm_prefill_tokens_per_s")
+#: decode rows' attention alone over its own roofline, where
+#: ``mla.dense_attn_roofline_pct`` is DeepSeek-V2's chunk and decode calls
+#: together; the chunk path's reading from warm-in)
+OWN = ("ling.mla_decode_roofline_pct", "ling.warm_prefill_tokens_per_s")
 WIDTHS = ("hidden_size", "intermediate_size", "moe_intermediate_size",
           "moe_shared_expert_intermediate_size", "num_attention_heads",
           "head_dim", "short_conv_kernel_size", "kv_lora_rank",
@@ -330,7 +331,7 @@ def test_the_readers_split_a_tick_by_the_programs_names(monkeypatch):
     want = {"served.tick_device_ms_p50": 30.0,
             "served.dense_ms_per_tick": 10.0,
             "served.head_sample_ms_per_tick": 4.0,
-            "kda.step_ms_per_tick": 2.0, "gdn.prep_ms_per_tick": 2.0,
+            "state.step_ms_per_tick": 2.0, "state.prep_ms_per_tick": 2.0,
             "mla.dense_decode_ms_per_tick": 2.0,
             "latent.scatter_ms_per_tick": 2.0,
             "moe.tick_route_ms_per_tick": 2.0,
@@ -350,7 +351,7 @@ def test_the_readers_split_a_tick_by_the_programs_names(monkeypatch):
     assert sum(read(n) for n in PARTS) == pytest.approx(30.0)
     peak = yardstick.chip_peak("TPU v5 lite")
     c = real_config()
-    assert read("kda.step_hbm_roofline_pct") == pytest.approx(
+    assert read("state.step_hbm_roofline_pct") == pytest.approx(
         100 * yl.least_ms(yl.step_flops(c, 64.0), yl.step_bytes(c, 64.0),
                           peak) / 2.0)
     assert read("moe.tick_experts_hbm_roofline_pct") == pytest.approx(
@@ -363,7 +364,8 @@ def test_the_readers_split_a_tick_by_the_programs_names(monkeypatch):
         if f[:-3] in NEW)
     # the other served families' helpers do not read this tick, nor this
     # one theirs: at most one answers, and ``_served`` finds this one
-    for other in ("_dots3_trace", "_dsv2_trace", "_olmoh_trace"):
+    for other in ("_dots3_trace", "_dsv2_trace", "_olmoh_trace",
+                  "_falcon_h1_trace"):
         assert loader.load_module("layer_metrics", other).parts_ms(run) \
             is None, other
     assert loader.load_module("layer_metrics", "_served").trace_of(run) \
@@ -397,7 +399,7 @@ def test_the_readers_find_nothing_in_a_program_without_the_model(
             assert loader.load_module("layer_metrics", name).read(run) \
                 is None, name
     run["ctx"].trace_doc = None
-    for name in ("served.tick_mfu_pct", "kda.step_hbm_roofline_pct"):
+    for name in ("served.tick_mfu_pct", "state.step_hbm_roofline_pct"):
         assert loader.load_module("layer_metrics", name).read(run) is None
 
 
@@ -423,8 +425,9 @@ def test_the_cells_lists_name_the_new_metrics_of_this_cell(bench):
     assert sorted(m["name"] for m in bench["per_layer"]
                   if m["name"].startswith("ling.")) == [
         "ling.mla_decode_roofline_pct", "ling.warm_prefill_tokens_per_s"]
-    assert "kda.prep_ms_per_tick" not in {
-        m["name"] for m in bench["per_layer"]}
+    assert not [m["name"] for m in bench["per_layer"] if m["name"] in (
+        "kda.prep_ms_per_tick", "gdn.prep_ms_per_tick",
+        "kda.step_ms_per_tick", "kda.step_hbm_roofline_pct")]
 
 
 # --- the check, controls included, through check() itself -------------------

@@ -23,22 +23,21 @@ TOY = os.path.join(HERE, "toy_olmo_hybrid")
 CELL = "serve-olmo-hybrid-gen-backlog"
 CATALOG = "/opt/skills/guides/model-configs/architectures.jsonl"
 PARTS = ("served.dense_ms_per_tick", "served.head_sample_ms_per_tick",
-         "gdn.step_ms_per_tick", "gdn.chunk_ms_per_tick",
-         "gdn.prep_ms_per_tick", "attn.full_ms_per_tick",
+         "state.step_ms_per_tick", "state.chunk_ms_per_tick",
+         "state.prep_ms_per_tick", "attn.full_ms_per_tick",
          "served.unscoped_ms_per_tick")
 SHARES = ("served.tick_mfu_pct", "served.tick_hbm_roofline_pct",
-          "gdn.step_hbm_roofline_pct", "gdn.chunk_roofline_pct",
+          "state.step_hbm_roofline_pct", "state.chunk_roofline_pct",
           "attn.full_roofline_pct")
 COUNTED = ("pool.live_state_slots_pct", "served.tokens_per_s_slice_p50",
            "served.prefill_tokens_per_tick", "served.decode_rows_per_tick",
            "served.host_ms_per_tick")
 #: with the holds of the judged window (PR 51; this cell's since PR 53)
 NEW = ("served.tick_device_ms_p50",) + PARTS + SHARES + COUNTED + HOLDS
-#: the entries that list this cell alone: its own mechanism's (the pass
-#: before the rule and the state pool's slots are Ling's cell's too since
-#: PR 53)
-OWN = tuple(n for n in NEW if not n.startswith("served.") and n not in (
-    "gdn.prep_ms_per_tick", "pool.live_state_slots_pct"))
+#: the entries that list this cell alone: none since PR 56 (a recurrent
+#: state's passes are ``state.*`` whatever the rule, in Ling's and
+#: Falcon-H1's cells too; full attention over K/V pages is Falcon-H1's too)
+OWN = ()
 WIDTHS = ("vocab_size", "hidden_size", "intermediate_size",
           "num_attention_heads", "num_key_value_heads",
           "linear_num_key_heads", "linear_num_value_heads",
@@ -285,8 +284,8 @@ def test_the_readers_split_a_tick_by_the_programs_names(monkeypatch):
     want = {"served.tick_device_ms_p50": 30.0,
             "served.dense_ms_per_tick": 12.0,
             "served.head_sample_ms_per_tick": 4.0,
-            "gdn.step_ms_per_tick": 2.0, "gdn.chunk_ms_per_tick": 2.0,
-            "gdn.prep_ms_per_tick": 4.0, "attn.full_ms_per_tick": 2.0,
+            "state.step_ms_per_tick": 2.0, "state.chunk_ms_per_tick": 2.0,
+            "state.prep_ms_per_tick": 4.0, "attn.full_ms_per_tick": 2.0,
             "pool.live_state_slots_pct": 97.5,
             "served.tokens_per_s_slice_p50": 4000.0,
             "served.prefill_tokens_per_tick": 0.2 * 256,
@@ -297,9 +296,13 @@ def test_the_readers_split_a_tick_by_the_programs_names(monkeypatch):
     assert sum(read(n) for n in PARTS) == pytest.approx(30.0)
     peak = yardstick.chip_peak("TPU v5 lite")
     c = real_config()
-    assert read("gdn.step_hbm_roofline_pct") == pytest.approx(
+    assert read("state.step_hbm_roofline_pct") == pytest.approx(
         100 * yg.least_ms(yg.step_flops(c, 39.0), yg.step_bytes(c, 39.0),
                           peak) / 2.0)
+    assert read("attn.full_roofline_pct") == pytest.approx(
+        100 * yg.least_ms(yg.attention_flops(c, 39 * 700.0 + 15000.0),
+                          yg.attention_bytes(c, 39 * 700.0 + 120.0), peak)
+        / 2.0)                          # thirty heads: its own floor
     for name in SHARES:
         assert 0 < read(name), name
     assert sorted(NEW) == sorted(
@@ -343,8 +346,10 @@ def test_the_cells_lists_name_the_new_metrics_of_this_cell(bench):
                 and m["moves"] == "serve_tokens_per_s"
         else:       # no other metric's list of cells names this cell
             assert CELL not in m.get("workloads", ())
-        if m["name"] in OWN:
-            assert m["workloads"] == [CELL]
+        if m["name"] in NEW:    # a quantity another cell reports too
+            assert len(m["workloads"]) > 1, m["name"]
+    assert not [m["name"] for m in bench["per_layer"]
+                if m["name"].startswith("gdn.")]
 
 
 # --- the check, controls included, through check() itself -------------------

@@ -144,11 +144,50 @@ def test_the_parts_are_what_walking_every_run_gave(piece):
             loader.load_module("layer_metrics", n)
             for n in loader.load_module("layer_metrics",
                                         "_served").helpers())]
-    assert len(cuts) == 5
+    assert len(cuts) >= 6       # the GPT tick's and five served families'
     for label_of, order in cuts:
         want = _parts_ms_by_walking(doc, "tick", label_of, order)
         assert want["n_runs"] >= 2
         assert PT.parts_ms(doc, "tick", label_of, order) == want
+    # every pass is counted, by the module of its label function
+    assert PT.cuts_of(doc) == [
+        label_of.__module__.rsplit(".", 1)[-1] for label_of, _ in cuts]
+
+
+@pytest.mark.parametrize("piece", RECORDED)
+def test_a_helper_looks_for_its_mechanism_before_it_cuts(piece):
+    """``names_scope`` (one look at the trace's distinct scope paths) says
+    what the cut said: the helper whose mechanism's part has device time is
+    the one that finds an operation under its mechanism's scope, none on
+    the GPT tick; so a traced run cuts its trace for its own helper alone
+    (PR 53: three and four passes, 11-14 s each on Ling's trace)."""
+    doc = PT.load_recorded(os.path.join(HERE, piece))
+    helpers = [loader.load_module("layer_metrics", n)
+               for n in loader.load_module("layer_metrics",
+                                           "_served").helpers()]
+    looked = [tr.__name__.rsplit(".", 1)[-1] for tr in helpers
+              if PT.names_scope(doc, tr._SCOPE, tr.MECHANISM)]
+    assert PT.cuts_of(doc) == []                    # a look is no pass
+    by_cut = []
+    for tr in helpers:
+        parts = PT.parts_ms(doc, "tick", tr.part, tr.ORDER)
+        if any(parts.get(_PART_OF[m]) for m in tr.MECHANISM):
+            by_cut.append(tr.__name__.rsplit(".", 1)[-1])
+    cell = os.path.basename(piece)[:-len(".json.gz")]
+    assert looked == by_cut == (
+        [_HELPER_OF[cell]] if cell in _HELPER_OF else [])
+
+
+#: a recorded cell -> the helper that reads its tick (the GPT tick: none)
+_HELPER_OF = {"serve-dots3-longdoc-backlog": "_dots3_trace",
+              "serve-dsv2-docqa-backlog": "_dsv2_trace",
+              "serve-olmo-hybrid-gen-backlog": "_olmoh_trace",
+              "serve-ling3-longgen-backlog": "_ling3_trace",
+              "serve-falcon-h1-gen-backlog": "_falcon_h1_trace"}
+#: a mechanism's scope -> the part its helper's cut gives it
+_PART_OF = {"blk/attn/mla": "mla", "blk/attn/mla_chunk": "mla_chunk",
+            "blk/attn/mla_decode": "mla_decode", "blk/gdn/step": "gdn_step",
+            "blk/kda/step": "kda_step", "blk/ssd/step": "ssd_step"}
 
 
 def test_a_run_cut_by_the_traces_edge_is_left_out(doc):

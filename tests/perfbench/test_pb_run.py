@@ -100,9 +100,9 @@ def rehearse(copy, workload, trace, devices=1, seconds="1.5"):
                           "sched.hold_unexplained_pct"}),
     ("toy-backlog-cell", 0, {"serve_tokens_per_s", "setup_s"}),
     ("toy-backlog-cell", 1, {"proc.compiles_in_window",
-                             "sched.prefill_tokens_per_tick",
-                             "sched.decode_rows_per_tick",
-                             "sched.serve_tokens_per_s_slice_p50",
+                             "served.prefill_tokens_per_tick",
+                             "served.decode_rows_per_tick",
+                             "served.tokens_per_s_slice_p50",
                              "pool.live_kv_pct.backlog",
                              "served.hold_lost_ms_in_window",
                              "served.hold_unexplained_pct",

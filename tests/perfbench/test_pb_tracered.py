@@ -153,7 +153,7 @@ def test_layer_metric_readers_on_the_recorded_tick(doc):
     for name in ("tick.device_ms_p50.chat", "flash.fwd_ms_per_step",
                  "flash.bwd_ms_per_step", "tick.kv_scatter_ms_per_tick",
                  "coll.exposed_ms_per_step", "sched.queue_wait_p50_ms",
-                 "sched.decode_rows_per_tick", "train.mfu_pct"):
+                 "served.decode_rows_per_tick", "train.mfu_pct"):
         assert loader.load_module("layer_metrics", name).read(blind) is None
 
 
