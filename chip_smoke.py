@@ -1664,9 +1664,11 @@ def check_ssd_ops(heads: int, p: int, n: int, groups: int, rows: int,
 
 
 def check_grouped_attention(rows: int, t: int, heads: int, kvh: int, d: int,
-                            ps: int, nps: int, live: int) -> float:
+                            ps: int, nps: int, live: int,
+                            window: int = None) -> float:
     """``grouped_paged_attention`` by the platform's path (on the chip the
-    kernel ``grouped_paged_attn``) against its XLA spelling: ``rows`` rows of
+    kernel ``grouped_paged_attn``; under ``window`` its sibling
+    ``grouped_window_attn``) against its XLA spelling: ``rows`` rows of
     ``t`` queries over ``live`` positions each, bf16 pages of ``kvh`` heads
     under ``heads`` query heads, written by ``grouped_kv_scatter``."""
     import jax
@@ -1690,9 +1692,10 @@ def check_grouped_attention(rows: int, t: int, heads: int, kvh: int, d: int,
     pos0 = jnp.full((rows,), live - t, jnp.int32)
     tl = jnp.full((rows,), t, jnp.int32)
     args = (q, pool, table, pos0, tl, 0)
-    got = jax.jit(pa.grouped_paged_attention, static_argnums=5)(*args)
-    want = jax.jit(pa.grouped_paged_attention, static_argnums=(5, 6))(
-        *args, "xla")
+    got = jax.jit(pa.grouped_paged_attention, static_argnums=(5, 6, 7))(
+        *args, None, window)
+    want = jax.jit(pa.grouped_paged_attention, static_argnums=(5, 6, 7))(
+        *args, "xla", window)
     return _nerr(got, want)
 
 
@@ -1759,6 +1762,70 @@ def phase_falcon(cfg, num_slots: int, page_size: int, pages_per_slot: int,
         "falcon", net, (num_slots, page_size, pages_per_slot, chunk),
         requests, forward, SSD_COUNTERS, want_path,
         "a state a slot and grouped K/V pages in every layer"), **errs}
+
+
+LAGUNA_REQUESTS = ((300, 24), (520, 20), (140, 40))
+#: a tick of Laguna's counts its two kinds of attention in one counter
+LAGUNA_COUNTERS = {"attn": "serving/attn_calls{path=%s}"}
+
+
+def phase_laguna(cfg, num_slots: int, page_size: int, pages_per_slot: int,
+                 chunk: int, attn_shapes, want_path: str,
+                 requests=LAGUNA_REQUESTS) -> dict:
+    """Laguna's pass (models/laguna.py): grouped-query attention under a
+    sliding window at ``attn_shapes`` (``check_grouped_attention``'s
+    arguments, the window last: the cell's decode rows and a chunk row's
+    piece at six and nine query heads a key/value head) against its XLA
+    spelling; then a small model through the engine (full and windowed
+    layers of unlike query heads over one set of key/value heads, a gate a
+    head, held experts; the windowed layers' pages freed behind the
+    window): what it emitted is the float32 reference's
+    (models/laguna_reference.py; shortfalls in units of a position's own
+    spread of logits), and its ticks counted their attention by
+    ``want_path``."""
+    import dataclasses as dc
+    import types
+
+    import paddle_tpu as paddle
+    from paddle_tpu.models import laguna_reference as ref
+    from paddle_tpu.models.laguna import Laguna
+    from paddle_tpu.profiler import registry
+
+    errs = {}
+    for shape in attn_shapes:
+        err = check_grouped_attention(*shape)
+        errs["attn_%dx%dx%d" % (shape[0], shape[1], shape[2])] = err
+        say("laguna", f"windowed grouped attention, {shape[0]} rows of "
+            f"{shape[1]} queries, {shape[2]} heads over {shape[3]}, "
+            f"{shape[7]} live positions in pages of {shape[5]}, a window of "
+            f"{shape[8]}: {err:.2e} from the XLA spelling (allowed "
+            f"{TOL_RAGGED})")
+        check(err <= TOL_RAGGED, f"windowed grouped attention {shape} is "
+              f"{err:.2e} from its XLA spelling (tol {TOL_RAGGED})")
+    paddle.seed(0)
+    with paddle.LazyGuard():
+        net = Laguna(cfg)
+    config = dc.asdict(cfg)
+    freed0 = registry().counter("serving/window_pages_freed").value
+
+    def forward(layers, other, seq):
+        return ref.forward((layers[f"layer{i}"]
+                            for i in range(cfg.num_hidden_layers)),
+                           other, seq, config, held=cfg.held)
+
+    def shortfall(state, other, targets):
+        short, top, sigma = ref.shortfall(state, other, targets)
+        return short / sigma, top
+
+    forward.ref = types.SimpleNamespace(shortfall=shortfall)
+    out = serve_against_reference(
+        "laguna", net, (num_slots, page_size, pages_per_slot, chunk),
+        requests, forward, LAGUNA_COUNTERS, want_path,
+        "full and windowed grouped K/V pages and held experts")
+    freed = registry().counter("serving/window_pages_freed").value - freed0
+    check(freed > 0, "no page of the windowed layers was freed behind the "
+          "window")
+    return {**out, **errs, "window_pages_freed": freed}
 
 
 # ---------------------------------------------------------------------------
@@ -2220,6 +2287,28 @@ def main() -> int:
         8, 16, 64, 128, (32, 128, 256, 2, 80, 256),
         [(80, 1, 20, 4, 128, 16, 88, 660), (4, 64, 20, 4, 128, 16, 88, 512)],
         "pallas"))
+    # Laguna: the windowed grouped attention at the cell's rows (38 decode
+    # rows 7,000 deep and a chunk row's piece of 32 queries, 8 key/value
+    # heads, six and nine query heads each, pages of 16, a window of 512),
+    # and a small model (12 and 18 query heads over 2 key/value heads of
+    # 128, a window of 64, 8 of 16 experts held) through the engine, whose
+    # ticks must take both kernels
+    from paddle_tpu.models.laguna import FULL, SLIDING, LagunaConfig
+
+    run("laguna", lambda: phase_laguna(
+        LagunaConfig(
+            vocab_size=1024, hidden_size=512, intermediate_size=1024,
+            num_hidden_layers=3, layer_types=(FULL, SLIDING, SLIDING),
+            num_attention_heads=12, num_key_value_heads=2,
+            num_attention_heads_per_layer=(12, 18, 18), num_experts=16,
+            num_experts_per_tok=4, moe_intermediate_size=128,
+            shared_expert_intermediate_size=128, sliding_window=64,
+            experts_held=(4, 8), max_position_embeddings=1024),
+        8, 16, 64, 128,
+        [(38, 1, 48, 8, 128, 16, 448, 7000, 512),
+         (38, 1, 72, 8, 128, 16, 448, 7000, 512),
+         (8, 32, 48, 8, 128, 16, 128, 1500, 512),
+         (8, 32, 72, 8, 128, 16, 128, 1500, 512)], "pallas"))
     run("train", lambda: phase_train(cfg, micro=2, n_micro=6, steps=4))
     if len(jax.devices()) >= 4 and "train" not in failed:
         run("multichip", lambda: phase_multichip(

@@ -79,21 +79,27 @@ def yarn_table(dim: int, theta: float, scaling: Optional[dict]):
     host: a ramp from 0 at ``low`` to 1 at ``high`` over the pairs
     (``yarn_bounds``), ``inv_freq = f (1 - ramp) + (f / factor) ramp``; cos
     and sin are multiplied by ``m(mscale) / m(mscale_all_dim)`` and the
-    scores by ``m(mscale_all_dim) ** 2``, ``m(x) = 0.1 x ln(factor) + 1``."""
+    scores by ``m(mscale_all_dim) ** 2``, ``m(x) = 0.1 x ln(factor) + 1``.
+    ``dim`` may be a part of a head (``models/laguna.py``: the rotated half;
+    ``rope_by_table`` passes the rest), the kind may stand under
+    ``rope_type`` as newer files write it, and an ``attention_factor`` the
+    file gives is the cos and sin's scale as given."""
     f = float(theta) ** (-np.arange(0, dim, 2, dtype=np.float64) / dim)
     if scaling is None:
         return f.astype(np.float32), 1.0, 1.0
-    if scaling.get("type") != "yarn":
-        raise NotImplementedError(f"rope_scaling {scaling.get('type')!r}")
+    kind = scaling.get("type", scaling.get("rope_type"))
+    if kind != "yarn":
+        raise NotImplementedError(f"rope_scaling {kind!r}")
     factor = float(scaling["factor"])
     low, high = yarn_bounds(dim, float(theta), scaling)
     ramp = np.clip((np.arange(dim // 2, dtype=np.float64) - low)
                    / max(high - low, 1e-3), 0.0, 1.0)
     inv = f * (1.0 - ramp) + f / factor * ramp
     m_all = _yarn_mscale(factor, scaling.get("mscale_all_dim", 0.0))
-    return (inv.astype(np.float32),
-            _yarn_mscale(factor, scaling.get("mscale", 1.0)) / m_all,
-            m_all * m_all)
+    return (inv.astype(np.float32), scaling.get(
+        "attention_factor",
+        _yarn_mscale(factor, scaling.get("mscale", 1.0)) / m_all),
+        m_all * m_all)
 
 
 @dataclass
@@ -266,8 +272,13 @@ class DeepseekV2(LayerwiseLM):
 def rope_by_table(x, pos, inv_freq, scale: float = 1.0):
     """``x`` [NT, heads, d] rotated by ``pos`` [NT] in the rotate-half
     convention, the angle of pair ``(j, j + d/2)`` ``pos * inv_freq[j]``;
-    cos and sin times ``scale`` (YaRN's ``mscale`` ratio: 1 as published)."""
-    d = x.shape[-1]
+    cos and sin times ``scale`` (YaRN's ``mscale`` ratio: 1 as published).
+    A table of fewer than ``d / 2`` pairs turns the first ``2 len(inv_freq)``
+    columns of a head and passes the others (a partial rotary factor)."""
+    d = 2 * len(inv_freq)
+    if d < x.shape[-1]:
+        return jnp.concatenate([rope_by_table(x[..., :d], pos, inv_freq,
+                                              scale), x[..., d:]], -1)
     ang = pos.astype(jnp.float32)[:, None, None] \
         * jnp.asarray(inv_freq, jnp.float32)
     cos = jnp.concatenate([jnp.cos(ang)] * 2, -1) * scale

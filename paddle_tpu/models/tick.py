@@ -10,6 +10,11 @@ nothing else of it but ``model.config`` (``vocab_size``, ``max_seq_len``):
     "full_layers", "latent_width"}`` and, where the model has them,
     ``"index_width"``, ``"window_layers"``, ``"window_width"``, ``"window"``:
     latent, indexer-key and windowed pools (``paged_cache.LatentPools``).
+    ``{"kind": "windowed_kv", "full_layers", "window_layers", "window",
+    "key_value_heads", "head_dim"}``: grouped K/V pages for the full layers
+    and, in a page space that holds only the window, for the windowed ones,
+    whatever the query heads of a layer (``paged_cache.WindowedKVPools``;
+    ``row_tab`` is then the pair of page tables, as a latent model's).
     ``{"kind": "state", "layers", "heads", "head_dim", "state_layers",
     "state_heads", "key_dim", "value_dim", "conv_width", "conv_taps"}``: K and
     V pages for some layers and, for the others, a recurrent state and a short
@@ -58,12 +63,15 @@ hands to a request, and ``forget(keep)`` when results are reset: ``TickRecord``
 A model whose layers are one block repeated stacks its weights and scans
 (``models/gpt.py``). A model of unlike layers keeps each layer's weights their
 own arrays and threads the pools through its layers in turn (``models/
-dots3.py``, ``models/deepseek_v2.py``); what those share is here:
+dots3.py``, ``models/deepseek_v2.py``, ``models/olmo_hybrid.py``, ``models/
+ling3.py``, ``models/falcon_h1.py``, ``models/laguna.py``); what those share
+is here:
 ``LayerwiseLM`` (the weights and their state), ``HeldExpertsConfig``,
 ``TickRows`` (the flat tokens against their rows) and ``rms``.
 """
 from __future__ import annotations
 
+import functools
 import time
 from typing import Tuple
 
@@ -199,12 +207,20 @@ class LayerwiseLM(nn.Layer):
         pos = jnp.arange(w, dtype=jnp.int32)
         # the tables of a tick of one (empty) decode row and the chunk row,
         # less the decode row
-        row_tab = jax.tree.map(lambda a: a[1:], pool.row_tables([None, 0]))
-        return self.ragged_apply(
+        row_tab = jax.tree.map(lambda a: jnp.asarray(a[1:]),
+                               pool.row_tables([None, 0]))
+        # one program, as a tick is (a forward dispatched operation by
+        # operation compiles every one of them for itself), compiled once a
+        # chunk width: jit keys its cache on the function it was given
+        ticks = self.__dict__.setdefault("_forward_ticks", {})
+        if w not in ticks:
+            ticks[w] = jax.jit(functools.partial(
+                self.ragged_apply, decode_rows=0, chunk_width=w))
+        return ticks[w](
             stacked, other, pool.pools, jnp.pad(toks, (0, w - s)), pos,
             jnp.full((w,), s, jnp.int32), row_tab,
             jnp.zeros((1,), jnp.int32), jnp.full((1,), s, jnp.int32),
-            pos[:s], decode_rows=0, chunk_width=w)[0]
+            pos[:s])[0]
 
 
 def _state_names(model):

@@ -1277,7 +1277,8 @@ def _selected_latent_pallas(q, pool, layer, page_table, pos0, true_len,
 # appended to ``__all__`` here, so that no line above it moved: a Mosaic
 # kernel's serialized body carries its operations' line numbers, and the
 # accepted cells' programs are compared byte for byte,
-# tools/lower_served_ticks.py.)
+# tools/lower_served_ticks.py. An edit inside this section moves the grouped
+# kernel's own locations: ``--compare`` then reads the bodies without them.)
 
 __all__ += ["grouped_paged_attention", "grouped_kv_scatter"]
 
@@ -1324,38 +1325,89 @@ def _grouped_gather_attend(q, pool, page_table, qpos, layer):
     return out if out.dtype == q.dtype else out.astype(q.dtype)
 
 
+def _grouped_window_xla(q, pool, page_table, pos0, true_len, layer,
+                        window: int):
+    """The ``jax.numpy`` spelling: only the ``ceil((window - 1 + T) / ps) +
+    1`` pages that can hold a visible key are gathered
+    (``window_latent_attention``'s walk), every key/value head against its
+    ``G`` query heads, float32 softmax."""
+    r, t, nh, hd = q.shape
+    kvh, ps = pool.shape[-3] // 2, pool.shape[-2]
+    g, nps = nh // kvh, page_table.shape[1]
+    wp = min(nps, -(-(window - 1 + t) // ps) + 1)
+    first = jnp.maximum(pos0 - (window - 1), 0) // ps           # [R]
+    cols = first[:, None] + jnp.arange(wp, dtype=pos0.dtype)[None, :]
+    pages = jnp.where(cols < nps, jnp.take_along_axis(
+        page_table, jnp.minimum(cols, nps - 1), axis=1), 0)
+    got = pool[layer, pages]                        # [R, wp, 2 KVH, ps, D]
+    if got.dtype != q.dtype:
+        got = got.astype(jnp.promote_types(got.dtype, q.dtype))
+    got = jnp.swapaxes(got, 1, 2).reshape(r, 2 * kvh, wp * ps, hd)
+    kpos = first[:, None] * ps + jnp.arange(wp * ps, dtype=pos0.dtype)
+    live = jnp.where(true_len > 0, pos0 + true_len, 0)
+    qpos = jnp.minimum(pos0[:, None] + jnp.arange(t, dtype=pos0.dtype),
+                       live[:, None] - 1)
+    k3, q3 = kpos[:, None, :], qpos[:, :, None]
+    keep = (k3 <= q3) & (k3 > q3 - window)                  # [R, T, S]
+    # a page behind the window may be gone and a position past the live
+    # ones holds whatever was there: a weight of 0 must meet no NaN
+    held = (kpos < live[:, None]) & (kpos > pos0[:, None] - window)
+    got = jnp.where(held[:, None, :, None], got, 0)
+    k_c, v_c = got[:, :kvh], got[:, kvh:]
+    att = jnp.einsum("btkgd,bksd->bkgts", q.reshape(r, t, kvh, g, hd),
+                     k_c) / math.sqrt(hd)
+    att = jnp.where(keep[:, None, None], att, _NEG_INF)
+    w = jax.nn.softmax(att.astype(jnp.float32), axis=-1).astype(q.dtype)
+    out = jnp.einsum("bkgts,bksd->btkgd", w, v_c).reshape(r, t, nh, hd)
+    return out if out.dtype == q.dtype else out.astype(q.dtype)
+
+
 def grouped_paged_attention(q, pool, page_table, pos0, true_len, layer,
-                            impl=None):
+                            impl=None, window=None):
     """``ragged_paged_attention`` over a grouped pool ``[L, P, 2 KVH, ps,
-    D]``: ``q`` [R, T, NH, D] with ``NH`` a multiple of ``KVH`` (query heads
-    ``k G .. (k + 1) G - 1`` read key/value head ``k``), the rows' metadata as
-    there. The same two spellings, picked and counted the same way
-    (``resolve_impl``; ``serving/attn_calls{path=}``). Returns [R, T, NH,
-    D]."""
+    D]``: ``q`` [R, T, NH, D], query heads ``k G .. (k + 1) G - 1`` read
+    key/value head ``k``; the same two spellings, picked and counted the
+    same way. ``window`` (static; ISSUE 57): a query at ``t`` sees keys ``t
+    - window < j <= t``, and a row's table entries behind the window may be
+    null (``serving.paged_cache.WindowSpace`` keeps only the window's
+    pages). Both spellings then start a row's walk at the page (the kernel:
+    the block) of its first query's oldest visible key and end it at its
+    last live page; what lies between the walk's start and the window's edge
+    is masked, and so is a null page's content."""
     from ..profiler import metrics
 
     impl = resolve_impl(impl)
     if impl not in ("xla", "pallas"):
         raise ValueError(f"unknown paged attention impl {impl!r}")
-    metrics.registry().counter(
-        "serving/attn_calls{path=%s}" % impl).add(1)
-    if impl == "xla":
-        t = q.shape[1]
-        qpos = pos0[:, None] + jnp.arange(t, dtype=pos0.dtype)[None, :]
-        return _grouped_gather_attend(q, pool, page_table, qpos, layer)
-    return _grouped_attention_pallas(q, pool, page_table, pos0, true_len,
-                                     layer)
+    metrics.registry().counter("serving/attn_calls{path=%s}" % impl).add(1)
+    if impl == "pallas":
+        return _grouped_attention_pallas(q, pool, page_table, pos0, true_len,
+                                         layer, window)
+    if window is not None:
+        return _grouped_window_xla(q, pool, page_table, pos0, true_len, layer,
+                                   int(window))
+    qpos = pos0[:, None] + jnp.arange(q.shape[1], dtype=pos0.dtype)
+    return _grouped_gather_attend(q, pool, page_table, qpos, layer)
 
 
 def _grouped_kernel(pt_ref, pos0_ref, tl_ref, layer_ref, q_ref, kv_hbm, o_ref,
-                    buf, sem, slot_ref, m_ref, l_ref, acc_ref, *, group: int):
+                    buf, sem, slot_ref, m_ref, l_ref, acc_ref, *, group: int,
+                    window=None):
     """Grid (r,): row ``r``, ``_ragged_kernel``'s walk (the pool in HBM, the
     KV axis a loop over blocks of ``bp`` pages whose trip count is the row's
     own, two buffers, the next block or the next row's first in flight). A
     page is one copy, K's heads and V's together. ``q_ref`` ``[1, KVH, G Tp,
     D]``: a key/value head's ``G`` query heads of ``Tp`` queries each, row
     ``j Tp + i`` query ``i`` of head ``j``, one left operand of its two
-    products a block."""
+    products a block. Under a ``window`` the walk starts at the block of the
+    row's oldest visible key: row ``r`` visits blocks ``b0(r) .. b0(r) +
+    nblk(r) - 1`` (a decode row under a window of 512 and blocks of 256
+    positions two or three, whatever its context), and a score is kept where
+    ``j <= t``, ``j > t - window`` and ``j`` is live; a query whose first
+    blocks hold nothing it sees accumulates under the mask's constant until
+    its first visible key arrives, whose rescale (``exp(-1e9 - s)``, 0)
+    wipes that. With no window ``b0`` is the number 0 and no operation is
+    traced for it or for the lower mask."""
     _, bp, kv2, ps, hd = buf.shape
     kvh = kv2 // 2
     m = q_ref.shape[2]
@@ -1369,6 +1421,13 @@ def _grouped_kernel(pt_ref, pos0_ref, tl_ref, layer_ref, q_ref, kv_hbm, o_ref,
     def kv_len(row):
         n = jnp.minimum(pos0_ref[row] + tl_ref[row], nps * ps)
         return jnp.where(tl_ref[row] > 0, n, 0)
+
+    def first_block(row):
+        if window is None:
+            return 0
+        oldest = jnp.maximum(pos0_ref[row] - (window - 1), 0)
+        return jnp.minimum(oldest // bt,
+                           jnp.maximum(pl.cdiv(kv_len(row), bt) - 1, 0))
 
     def copies(row, blk, slot, act):
         first = blk * bp
@@ -1385,12 +1444,15 @@ def _grouped_kernel(pt_ref, pos0_ref, tl_ref, layer_ref, q_ref, kv_hbm, o_ref,
     start = lambda c: c.start()
     wait = lambda c: c.wait()
     n_live = kv_len(r)
-    nblk = pl.cdiv(n_live, bt)
+    b0 = first_block(r)
+    nblk = pl.cdiv(n_live, bt)                  # the blocks the row visits
+    if window is not None:
+        nblk = nblk - b0
 
     @pl.when(r == 0)
     def _first():
         slot_ref[0] = 0
-        copies(r, 0, 0, start)
+        copies(r, b0, 0, start)
 
     slot0 = slot_ref[0]
     m_ref[:] = jnp.full_like(m_ref, _NEG_INF)
@@ -1401,16 +1463,17 @@ def _grouped_kernel(pt_ref, pos0_ref, tl_ref, layer_ref, q_ref, kv_hbm, o_ref,
         1, m, bt)
     qpos = pos0_ref[r] + qi
 
-    def block(b, carry):
-        slot = (slot0 + b) % 2
+    def block(j, carry):
+        b = j if window is None else b0 + j
+        slot = (slot0 + j) % 2
 
-        @pl.when(b + 1 < nblk)
+        @pl.when(j + 1 < nblk)
         def _next_block():
             copies(r, b + 1, 1 - slot, start)
 
-        @pl.when(jnp.logical_and(b + 1 == nblk, jnp.logical_not(last_row)))
+        @pl.when(jnp.logical_and(j + 1 == nblk, jnp.logical_not(last_row)))
         def _next_row():
-            copies(r + 1, 0, 1 - slot, start)
+            copies(r + 1, first_block(r + 1), 1 - slot, start)
 
         copies(r, b, slot, wait)
         src = buf.at[slot]
@@ -1419,7 +1482,11 @@ def _grouped_kernel(pt_ref, pos0_ref, tl_ref, layer_ref, q_ref, kv_hbm, o_ref,
         s = jnp.stack([_dot(q_ref[0, h], head(h), (((1,), (1,)), ((), ())))
                        for h in range(kvh)]) / math.sqrt(hd)  # [KVH, M, bt]
         kpos = b * bt + jax.lax.broadcasted_iota(jnp.int32, (1, m, bt), 2)
-        keep = kpos <= jnp.minimum(qpos, n_live - 1)
+        # a query past the row's live tokens (a pad) sees what the last does
+        seen = jnp.minimum(qpos, n_live - 1)
+        keep = kpos <= seen
+        if window is not None:
+            keep = jnp.logical_and(keep, kpos > seen - window)
         s = jnp.where(keep, s, _NEG_INF)
         m_prev = m_ref[:]                               # [KVH, M, 1]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=2, keepdims=True))
@@ -1443,14 +1510,15 @@ def _grouped_kernel(pt_ref, pos0_ref, tl_ref, layer_ref, q_ref, kv_hbm, o_ref,
 
     @pl.when(jnp.logical_and(nblk == 0, jnp.logical_not(last_row)))
     def _next_row_of_an_empty_one():
-        copies(r + 1, 0, slot0, start)
+        copies(r + 1, first_block(r + 1), slot0, start)
 
     slot_ref[0] = (slot0 + nblk) % 2
     l = l_ref[:]
     o_ref[0] = acc_ref[:] / jnp.where(l == 0.0, 1.0, l)
 
 
-def _grouped_attention_pallas(q, pool, page_table, pos0, true_len, layer):
+def _grouped_attention_pallas(q, pool, page_table, pos0, true_len, layer,
+                              window=None):
     r, t, nh, hd = q.shape
     kv2, ps = pool.shape[-3], pool.shape[-2]
     kvh = kv2 // 2
@@ -1473,8 +1541,9 @@ def _grouped_attention_pallas(q, pool, page_table, pos0, true_len, layer):
     block = pl.BlockSpec((1, kvh, m, hd),
                          lambda i, pt, p0, tl, ly: (i, 0, 0, 0))
     out = pl.pallas_call(
-        functools.partial(_grouped_kernel, group=g),
-        name="grouped_paged_attn",
+        functools.partial(_grouped_kernel, group=g,
+                          window=None if window is None else int(window)),
+        name="grouped_paged_attn" if window is None else "grouped_window_attn",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=4,
             grid=(r,),

@@ -48,6 +48,20 @@ only) are evicted LRU leaf-first when the allocator runs dry.
 Allocation, sharing and freeing are host-side bookkeeping only — no
 device op; the tables are tiny int32 arrays shipped with each tick's
 arguments.
+
+Pools of other formats follow ``Pools`` / ``PagePool`` below, each the one
+place that knows its format (``POOL_KINDS`` maps a ``cache_spec()``'s kind
+to its pool): ``LatentPools`` / ``LatentPagePool`` (latent rows, indexer
+keys, windowed latents), ``GroupedPools`` (fewer key/value heads than query
+heads), ``WindowedKVPools`` / ``WindowedKVPagePool`` (grouped K/V pages of
+full and of windowed layers) and ``StatePools`` / ``StatePagePool`` (pages
+beside a recurrent state a slot). **A window's page space lives in one
+place, ``WindowSpace``**: the second allocator and tables that hold only
+the window, ``grow_slot`` over both spaces or neither, ``free_behind``,
+``release_slot``, ``row_tables``, ``live_shares`` and its half of
+``check_consistency``; ``LatentPagePool`` and ``WindowedKVPagePool`` mix it
+in (ISSUE 57: it was ``LatentPagePool``'s own until a second kind of pool
+needed it).
 """
 from __future__ import annotations
 
@@ -1045,91 +1059,54 @@ class LatentPools(NamedTuple):
             c_width, scale)
 
 
-class LatentPagePool(PagePool):
-    """``PagePool`` for ``LatentPools``: the full layers' pages are the
-    pool's own (``allocator``, ``tables``: a page a ``page_size`` tokens of
-    a slot, for as long as the slot lives), and the windowed layers' pages
-    come from a second page space (``window_allocator``,
-    ``window_tables``, indexed by the same logical page of the slot) that
-    holds **only the window**: ``free_behind(slot, frontier)`` gives back
-    every page that no query at or past ``frontier`` can see, and the
-    engine calls it as it dispatches. The window space is sized for every
-    slot's worst case (``ceil((window - 1 + chunk) / page_size) + 2`` pages
-    a slot), so it never binds: admission, exhaustion and preemption are
-    decided by the full layers' pages alone.
+class WindowSpace:
+    """A second page space beside a ``PagePool``'s own, for layers that see
+    only a window (a mixin, before ``PagePool`` in a pool's bases): the
+    pool's own pages (``allocator``, ``tables``) hold a page a ``page_size``
+    tokens of a slot for as long as the slot lives, and the windowed layers'
+    pages come from ``window_allocator`` / ``window_tables``, indexed by the
+    same logical page of the slot, which hold **only the window**:
+    ``free_behind(slot, frontier)`` gives back every page that no query at
+    or past ``frontier`` can see, and the engine calls it as it dispatches.
+    The window space is sized for every slot's worst case (``ceil((window -
+    1 + chunk) / page_size) + 2`` pages a slot), so it never binds:
+    admission, exhaustion and preemption are decided by the pool's own pages
+    alone. A model without windowed layers (``window_layers`` 0) has no
+    window space: no page of it is allocated, grown or freed.
 
-    A model without windowed layers (``window_layers`` 0) has no window
-    space: no page of it is allocated, grown or freed.
-
-    What it lacks is refused by name: a prefix cache (a cached page would
-    have to say which layers it serves: a windowed layer's page is gone
-    once the window has passed; and without windowed layers
-    ``share_into_slot`` and ``copy_page`` are still not written over latent
-    pools), speculative rewinds (``shrink_slot``), auxiliary tables, int8
-    pages and a page handoff (``CANNOT``)."""
+    ``LatentPagePool`` (latent rows) and ``WindowedKVPagePool`` (K/V pages)
+    are the two pools with one; ``PAGES`` is the name of the pool's own
+    pages in the engine's gauges and ``POOLS`` what its refusals call it."""
 
     #: False keeps every windowed page for the slot's life (the window
-    #: space is then as large as the full layers'): what the tests compare
+    #: space is then as large as the pool's own): what the tests compare
     #: a freeing run with
     FREE_BEHIND = True
+    PAGES = "kv"
+    POOLS = "windowed pools"
 
-    CANNOT = {
-        "rewinds": (
-            "{doing} over latent and windowed pools: the verify tick "
-            "(serving/spec.py make_spec_tick) and the draft runner carry "
-            "Pools of K and V, and a rejected draft would have to rewind "
-            "pages a window has already given back"),
-        "int8": (
-            "int8 latent pools: the per-page per-head scales are K's and "
-            "V's; a latent row has no head axis"),
-        "handoff": (
-            "{doing} over latent and windowed pools: a handoff moves Pools "
-            "of K and V by page (serving/disagg.py), and a windowed layer's "
-            "pages behind the window no longer exist to be moved"),
-    }
-
-    for_spec = classmethod(_from_spec)
-
-    def __init__(self, caches: dict, num_pages: int, page_size: int,
-                 num_slots: int, pages_per_slot: int, chunk: int,
-                 dtype=jnp.float32, prefix_cache: bool = False):
-        if jnp.dtype(dtype) == jnp.int8:
-            self.require("int8", "kv_dtype='int8'")
+    def _open_window_space(self, caches: dict, page_size: int,
+                           num_slots: int, pages_per_slot: int,
+                           chunk: int) -> int:
+        """Sizes and opens the window space of a ``cache_spec()`` (before
+        ``PagePool.__init__``: the device pools are made from the answer).
+        -> the pages it has, the null page among them."""
         windowed = caches.get("window_layers", 0)
-        if prefix_cache and windowed:
-            raise NotImplementedError(
-                "prefix_cache=True with windowed layers: PrefixCache shares "
-                "a page into every layer's pool, and a windowed layer's "
-                "page is given back once the window has passed it; pass "
-                "prefix_cache=False (ROADMAP R4)")
-        if prefix_cache:
-            raise NotImplementedError(
-                "prefix_cache=True over latent pools: sharing a cached page "
-                "into a slot (share_into_slot) and copying a shared page "
-                "before a write (LatentPools.copy_page) are not written "
-                "for them; pass prefix_cache=False (ROADMAP R4)")
         self.window = int(caches.get("window", 0))
         held = -(-(self.window - 1 + chunk) // page_size) + 2
         self.window_pages_per_slot = 0 if not windowed else min(
             pages_per_slot, held) if self.FREE_BEHIND else pages_per_slot
         # (an allocator wants two pages; of no layers they are no bytes)
         window_pages = max(num_slots * self.window_pages_per_slot + 1, 2)
-        pools = LatentPools.zeros(
-            caches["full_layers"], num_pages, windowed,
-            window_pages, page_size, caches["latent_width"],
-            caches.get("index_width", 0), caches.get("window_width", 0),
-            dtype)
-        super().__init__(caches["full_layers"] + windowed,
-                         num_pages, page_size, 1, caches["latent_width"],
-                         num_slots, pages_per_slot, dtype=dtype, pools=pools)
         self.window_allocator = PageAllocator(window_pages)
         self.window_tables = np.zeros((num_slots, pages_per_slot), np.int32)
         #: logical page -> page id of the window space, per slot
         self._window_held: List[Dict[int, int]] = [
             {} for _ in range(num_slots)]
+        return window_pages
 
     def live_shares(self) -> Dict[str, float]:
-        shares = {"latent": self.allocator.utilization()}
+        shares = {self.PAGES: self.allocator.utilization()}
         if self.window_pages_per_slot:
             shares["window"] = self.window_allocator.utilization()
         return shares
@@ -1143,7 +1120,7 @@ class LatentPagePool(PagePool):
 
     def grow_slot(self, slot: int, n_pages: int) -> bool:
         """Both page spaces or neither: ``n_pages`` more logical pages of
-        the slot, each with a page of the full layers and one of the
+        the slot, each with a page of the pool's own and one of the
         windowed layers."""
         if n_pages <= 0:
             return True
@@ -1183,15 +1160,14 @@ class LatentPagePool(PagePool):
 
     def share_into_slot(self, slot: int, pages) -> None:
         raise NotImplementedError(
-            "sharing pages into a slot of latent and windowed pools")
+            f"sharing pages into a slot of {self.POOLS}")
 
     def shrink_slot(self, slot: int, keep_pages: int) -> int:
-        raise NotImplementedError(
-            "rewinding a slot of latent and windowed pools")
+        raise NotImplementedError(f"rewinding a slot of {self.POOLS}")
 
     def register_aux(self, aux) -> None:
         raise NotImplementedError(
-            "an auxiliary page table over latent and windowed pools")
+            f"an auxiliary page table over {self.POOLS}")
 
     def check_consistency(self) -> List[str]:
         out = super().check_consistency()
@@ -1216,6 +1192,70 @@ class LatentPagePool(PagePool):
             out.append(f"window pages allocated {alloc.num_allocated} != "
                        f"held {len(seen)}")
         return out
+
+
+class LatentPagePool(WindowSpace, PagePool):
+    """``PagePool`` for ``LatentPools``: the full layers' pages are the
+    pool's own, and the windowed layers' pages come from the second page
+    space that ``WindowSpace`` keeps (sized, grown, freed behind the window
+    and audited there: the one copy of that logic, which
+    ``WindowedKVPagePool`` shares).
+
+    What it lacks is refused by name: a prefix cache (a cached page would
+    have to say which layers it serves: a windowed layer's page is gone
+    once the window has passed; and without windowed layers
+    ``share_into_slot`` and ``copy_page`` are still not written over latent
+    pools), speculative rewinds (``shrink_slot``), auxiliary tables, int8
+    pages and a page handoff (``CANNOT``)."""
+
+    PAGES = "latent"
+    POOLS = "latent and windowed pools"
+
+    CANNOT = {
+        "rewinds": (
+            "{doing} over latent and windowed pools: the verify tick "
+            "(serving/spec.py make_spec_tick) and the draft runner carry "
+            "Pools of K and V, and a rejected draft would have to rewind "
+            "pages a window has already given back"),
+        "int8": (
+            "int8 latent pools: the per-page per-head scales are K's and "
+            "V's; a latent row has no head axis"),
+        "handoff": (
+            "{doing} over latent and windowed pools: a handoff moves Pools "
+            "of K and V by page (serving/disagg.py), and a windowed layer's "
+            "pages behind the window no longer exist to be moved"),
+    }
+
+    for_spec = classmethod(_from_spec)
+
+    def __init__(self, caches: dict, num_pages: int, page_size: int,
+                 num_slots: int, pages_per_slot: int, chunk: int,
+                 dtype=jnp.float32, prefix_cache: bool = False):
+        if jnp.dtype(dtype) == jnp.int8:
+            self.require("int8", "kv_dtype='int8'")
+        windowed = caches.get("window_layers", 0)
+        if prefix_cache and windowed:
+            raise NotImplementedError(
+                "prefix_cache=True with windowed layers: PrefixCache shares "
+                "a page into every layer's pool, and a windowed layer's "
+                "page is given back once the window has passed it; pass "
+                "prefix_cache=False (ROADMAP R4)")
+        if prefix_cache:
+            raise NotImplementedError(
+                "prefix_cache=True over latent pools: sharing a cached page "
+                "into a slot (share_into_slot) and copying a shared page "
+                "before a write (LatentPools.copy_page) are not written "
+                "for them; pass prefix_cache=False (ROADMAP R4)")
+        window_pages = self._open_window_space(
+            caches, page_size, num_slots, pages_per_slot, chunk)
+        pools = LatentPools.zeros(
+            caches["full_layers"], num_pages, windowed,
+            window_pages, page_size, caches["latent_width"],
+            caches.get("index_width", 0), caches.get("window_width", 0),
+            dtype)
+        super().__init__(caches["full_layers"] + windowed,
+                         num_pages, page_size, 1, caches["latent_width"],
+                         num_slots, pages_per_slot, dtype=dtype, pools=pools)
 
 
 class GroupedPools(NamedTuple):
@@ -1254,9 +1294,12 @@ class GroupedPools(NamedTuple):
         return GroupedPools(grouped_kv_scatter(
             self.kv, page, off, kk[:, 0], vv[:, 0], layer, touched))
 
-    def attend(self, layer, q, page_table, pos0, true_len):
+    def attend(self, layer, q, page_table, pos0, true_len, window=None):
+        """``window``: query ``i`` of a row sees positions ``pos0 + i -
+        window < s <= pos0 + i`` alone, and entries of ``page_table``
+        behind the window may be null (``WindowSpace``)."""
         return grouped_paged_attention(q, self.kv, page_table, pos0,
-                                       true_len, layer)
+                                       true_len, layer, window=window)
 
     def rows_of(self, layer, pages):
         """``(k, v)`` of ``pages`` [n], each ``[n ps, KVH, D]``: the
@@ -1265,6 +1308,115 @@ class GroupedPools(NamedTuple):
         kvh = got.shape[1] // 2
         flat = jnp.swapaxes(got, 1, 2).reshape(-1, 2 * kvh, got.shape[-1])
         return flat[:, :kvh], flat[:, kvh:]
+
+
+class WindowedKVPools(NamedTuple):
+    """Grouped K/V pages of a model whose layers are of two kinds (ISSUE 57,
+    ``models/laguna.py``): full attention in some, attention under a sliding
+    window in the others, **over one set of key/value heads** whatever the
+    query heads of a layer (48 and 72 over 8), as the device holds them:
+
+    kv      ``[Lf, P, 2 KVH, ps, D]``   the full layers' pages
+    window  ``[Lw, Pw, 2 KVH, ps, D]``  the windowed layers', in a page space
+                                        of its own that holds only the window
+                                        (``WindowedKVPagePool``)
+
+    both in ``GroupedPools``' format (no padded head). One pytree the tick
+    donates, and the one place that knows the format: a forward writes and
+    reads the full layers through ``scatter`` and ``attend`` and the windowed
+    ones through ``scatter_window`` and ``attend_window``
+    (``ops/paged_attention.grouped_paged_attention`` under ``window``)."""
+
+    kv: GroupedPools
+    window: GroupedPools
+
+    quantized = False
+
+    @classmethod
+    def zeros(cls, full_layers: int, num_pages: int, window_layers: int,
+              window_pages: int, page_size: int, kv_heads: int,
+              head_dim: int, dtype) -> "WindowedKVPools":
+        return cls(
+            GroupedPools.zeros(full_layers, num_pages, page_size, kv_heads,
+                               head_dim, dtype),
+            GroupedPools.zeros(window_layers, window_pages, page_size,
+                               kv_heads, head_dim, dtype))
+
+    @property
+    def page_size(self) -> int:
+        return self.kv.page_size
+
+    def arrays(self) -> Dict[str, jax.Array]:
+        return {"kv": self.kv.kv, "window": self.window.kv}
+
+    def reset_scales(self, pages) -> "WindowedKVPools":
+        return self
+
+    def scatter(self, layer, page, off, kk, vv, touched=None):
+        return self._replace(kv=self.kv.scatter(layer, page, off, kk, vv,
+                                                touched))
+
+    def attend(self, layer, q, page_table, pos0, true_len):
+        return self.kv.attend(layer, q, page_table, pos0, true_len)
+
+    def scatter_window(self, layer, page, off, kk, vv, touched=None):
+        return self._replace(window=self.window.scatter(
+            layer, page, off, kk, vv, touched))
+
+    def attend_window(self, layer, q, page_table, pos0, true_len,
+                      window: int):
+        return self.window.attend(layer, q, page_table, pos0, true_len,
+                                  window)
+
+
+class WindowedKVPagePool(WindowSpace, PagePool):
+    """``PagePool`` for ``WindowedKVPools`` (a ``cache_spec()`` of kind
+    ``"windowed_kv"``: ``full_layers``, ``window_layers``, ``window``,
+    ``key_value_heads``, ``head_dim``): the full layers' pages are the pool's
+    own, the windowed layers' come from ``WindowSpace``'s second page space,
+    as a latent model's windows do. What it cannot do is refused by name
+    (``CANNOT``), before anything is allocated."""
+
+    POOLS = "full and windowed K/V pools"
+
+    CANNOT = {
+        "prefix": (
+            "{doing} over windowed K/V layers: PrefixCache shares a page "
+            "into every layer's pool, and a windowed layer's page is given "
+            "back once the window has passed it (ROADMAP R4)"),
+        "rewinds": (
+            "{doing} over full and windowed K/V pools: the verify tick "
+            "(serving/spec.py make_spec_tick) carries Pools of K and V, and "
+            "a rejected draft would have to rewind pages a window has "
+            "already given back"),
+        "int8": (
+            "int8 grouped pages: the per-page per-head scales and the "
+            "quantized ragged kernel are Pools' own (ROADMAP R4)"),
+        "handoff": (
+            "{doing} over full and windowed K/V pools: a handoff moves "
+            "Pools of K and V by page (serving/disagg.py), and a windowed "
+            "layer's pages behind the window no longer exist to be moved"),
+    }
+
+    for_spec = classmethod(_from_spec)
+
+    def __init__(self, caches: dict, num_pages: int, page_size: int,
+                 num_slots: int, pages_per_slot: int, chunk: int,
+                 dtype=jnp.float32, prefix_cache: bool = False):
+        if jnp.dtype(dtype) == jnp.int8:
+            self.require("int8", "kv_dtype='int8'")
+        if prefix_cache:
+            self.require("prefix", "prefix_cache=True")
+        windowed = caches["window_layers"]
+        window_pages = self._open_window_space(
+            caches, page_size, num_slots, pages_per_slot, chunk)
+        pools = WindowedKVPools.zeros(
+            caches["full_layers"], num_pages, windowed, window_pages,
+            page_size, caches["key_value_heads"], caches["head_dim"], dtype)
+        super().__init__(caches["full_layers"] + windowed, num_pages,
+                         page_size, caches["key_value_heads"],
+                         caches["head_dim"], num_slots, pages_per_slot,
+                         dtype=dtype, pools=pools)
 
 
 class StatePools(NamedTuple):
@@ -1589,7 +1741,7 @@ class StatePagePool(PagePool):
 
 #: the pool of each kind of ``cache_spec()`` (``models/tick.py``)
 POOL_KINDS = {"kv": PagePool, "latent": LatentPagePool,
-              "state": StatePagePool}
+              "state": StatePagePool, "windowed_kv": WindowedKVPagePool}
 
 
 def page_pool(caches: dict, num_pages: int, page_size: int, num_slots: int,

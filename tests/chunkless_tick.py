@@ -39,11 +39,21 @@ def a_tick(net, ragged_apply, chunk: bool):
             jnp.asarray([1, 1, 1, 6 * chunk], jnp.int32),
             jnp.asarray([0, 3 + 5 if chunk else 0, 0], jnp.int32))
 
-    def tick(has_chunks, pools=pools):
+    def raw(has_chunks, pools=pools):
         told = None if has_chunks is None else jnp.asarray(has_chunks)
         return ragged_apply(net.config, stacked, other, pools, *rest,
                             decode_rows=3, chunk_width=w, has_chunks=told)
 
+    told_tick = jax.jit(raw)
+    not_told = jax.jit(lambda pools: raw(None, pools))
+
+    def tick(has_chunks, pools=pools):
+        """One program a way of telling, as the engine's tick is one."""
+        if has_chunks is None:
+            return not_told(pools)
+        return told_tick(jnp.asarray(has_chunks), pools)
+
+    tick.raw = raw          # what ``conds_without_a_pool`` reads the jaxpr of
     return tick, pools
 
 
@@ -71,7 +81,8 @@ def conds_without_a_pool(tick, pools) -> int:
     """How many ``cond``s the tick told ``has_chunks`` holds; fails where
     one has an operand or a result of a pool's shape (a pool that a
     ``cond`` carries is copied whole, ROADMAP S3)."""
-    jaxpr = jax.make_jaxpr(lambda pl, told: tick(told, pl))(
+    raw = getattr(tick, "raw", tick)
+    jaxpr = jax.make_jaxpr(lambda pl, told: raw(told, pl))(
         pools, jnp.asarray(False))
     shapes = {a.shape for a in jax.tree_util.tree_leaves(pools)}
     conds = [e for e in jaxpr.jaxpr.eqns if e.primitive.name == "cond"]

@@ -358,6 +358,25 @@ def test_falcon_rehearsal():
     assert out["weights_bytes"] == 2 * cfg.num_params()
 
 
+def test_laguna_rehearsal():
+    """Laguna's pass at toy sizes: the windowed grouped attention against
+    its spelling (off the chip: the spelling itself), a small model's tokens
+    through full and windowed grouped K/V pages and held experts against the
+    float32 reference, pages freed behind the window."""
+    from paddle_tpu.models.laguna import LagunaConfig
+
+    cfg = LagunaConfig.tiny(initializer_range=0.05)
+    out = chip_smoke.phase_laguna(
+        cfg, 3, 4, 24, 8, [(3, 1, 12, 2, 16, 4, 24, 37, 6),
+                           (2, 8, 18, 2, 16, 4, 24, 40, 6)], "xla",
+        requests=((23, 8), (41, 6)))
+    assert out["tick_paths"] == {"attn": ["xla"]}
+    assert out["attn_3x1x12"] == out["attn_2x8x18"] == 0
+    assert out["median"] <= out["worst"] <= chip_smoke.TOL_GDN_WORST
+    assert out["weights_bytes"] == 2 * cfg.num_params()
+    assert out["window_pages_freed"] > 0
+
+
 @pytest.mark.parametrize("counted,fault", [
     ({(128, 512, 128): 12, (128, 128, 512): 6}, None),
     # a product that went by the kernel and counted no tile

@@ -9,6 +9,7 @@ shares' sum; the pools of a model with no indexer and no window; and what
 is refused."""
 import dataclasses
 
+import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -312,10 +313,12 @@ def test_the_ticks_statistics_count_what_the_tick_held(net):
     row_len = jnp.asarray([1, 0, 4, 3], jnp.int32)
     limit = jnp.asarray([10, 0, 8, 8, 8, 8, 11, 11, 11, 11], jnp.int32)
     toks = jnp.arange(10, dtype=jnp.int32)
-    _, _, aux = deepseek_v2_ragged_apply(
-        cfg, stacked, other, pools, toks, tok_pos, limit,
+    tick = jax.jit(functools.partial(          # one program, as a tick is
+        deepseek_v2_ragged_apply, cfg, decode_rows=2, chunk_width=w))
+    _, _, aux = tick(
+        stacked, other, pools, toks, tok_pos, limit,
         (tab, jnp.zeros_like(tab)), row_pos0, row_len,
-        jnp.asarray([0, 1], jnp.int32), decode_rows=2, chunk_width=w)
+        jnp.asarray([0, 1], jnp.int32))
     stats = dict(zip(TICK_STATS, np.asarray(aux["stats"])))
     assert stats["decode_pairs"] == 10 and stats["decode_keys"] == 10
     assert stats["chunk_pairs"] == (5 + 6 + 7 + 8) + (9 + 10 + 11)

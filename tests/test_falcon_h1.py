@@ -5,6 +5,8 @@ and state slots **in the same layer**, against the float32 reference
 state, history and K/V; tenants of one slot in turn; the kernels interpreted;
 the pool and what it refuses. The equations' side is
 ``tests/test_falcon_h1_equations.py``."""
+import functools
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -176,12 +178,14 @@ def test_the_ticks_statistics_and_its_dead_rows(net):
     assert slots.tolist() == [1, 2, 3, 0]
     tok_pos = jnp.asarray([9, 8, 0] + [0] * w, jnp.int32)
     limit = jnp.asarray([32, 32, 32] + [0] * w, jnp.int32)
-    _, after, aux = falcon_h1_ragged_apply(
-        cfg, stacked, other, pools, jnp.arange(3 + w, dtype=jnp.int32),
+    tick = jax.jit(functools.partial(          # one program, as a tick is
+        falcon_h1_ragged_apply, cfg, decode_rows=3, chunk_width=w))
+    _, after, aux = tick(
+        stacked, other, pools, jnp.arange(3 + w, dtype=jnp.int32),
         tok_pos, limit, (jnp.asarray(tab), slots),
         jnp.asarray([9, 8, 0, 0], jnp.int32),
         jnp.asarray([1, 1, 1, 0], jnp.int32),
-        jnp.asarray([0, 1, 2], jnp.int32), decode_rows=3, chunk_width=w)
+        jnp.asarray([0, 1, 2], jnp.int32))
     assert isinstance(after, SSDStatePools)
     stats = dict(zip(TICK_STATS, np.asarray(aux["stats"])))
     assert stats["live_state_rows"] == 1 and stats["chunk_tokens"] == 0

@@ -6,6 +6,8 @@ weights; tenants of one engine and of one slot; preemption; the pool of a
 state and what it refuses."""
 import dataclasses
 
+import functools
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -276,12 +278,14 @@ def test_the_ticks_statistics_and_its_dead_rows(net):
     assert slots.tolist() == [1, 2, 3, 0]
     tok_pos = jnp.asarray([9, 8, 0] + [0] * w, jnp.int32)
     limit = jnp.asarray([32, 32, 32] + [0] * w, jnp.int32)
-    _, after, aux = olmo_hybrid_ragged_apply(
-        cfg, stacked, other, pools, jnp.arange(3 + w, dtype=jnp.int32),
+    tick = jax.jit(functools.partial(          # one program, as a tick is
+        olmo_hybrid_ragged_apply, cfg, decode_rows=3, chunk_width=w))
+    _, after, aux = tick(
+        stacked, other, pools, jnp.arange(3 + w, dtype=jnp.int32),
         tok_pos, limit, (jnp.asarray(tab), slots),
         jnp.asarray([9, 8, 0, 0], jnp.int32),
         jnp.asarray([1, 1, 1, 0], jnp.int32),
-        jnp.asarray([0, 1, 2], jnp.int32), decode_rows=3, chunk_width=w)
+        jnp.asarray([0, 1, 2], jnp.int32))
     stats = dict(zip(TICK_STATS, np.asarray(aux["stats"])))
     assert stats["live_state_rows"] == 1 and stats["chunk_tokens"] == 0
     assert stats["decode_keys"] == 10 and stats["chunk_keys"] == 0

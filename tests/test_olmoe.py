@@ -119,7 +119,7 @@ def test_parameter_gradients_match_the_reference(small):
         lg, balance, z = ref.forward(layers, other, tokens, HEADS, TOP_K)
         return ref.next_token_loss(lg, tokens) + 0.01 * balance + 0.001 * z
 
-    want_layers, want_other = jax.grad(ref_loss, argnums=(0, 1))(
+    want_layers, want_other = jax.jit(jax.grad(ref_loss, argnums=(0, 1)))(
         [{k: jnp.asarray(v) for k, v in w.items()} for w in layers],
         {k: jnp.asarray(v) for k, v in other.items()})
     loss = model.loss(paddle.to_tensor(tokens))

@@ -580,8 +580,8 @@ def test_logits_match_the_reference(small):
     model, tokens = small
     got = np.asarray(model(paddle.to_tensor(tokens))._value)
     layers, other = weights_of(model)
-    want = np.asarray(ref.forward(layers, other, tokens,
-                                  ref_cfg(model.config), held=HELD))
+    want = np.asarray(jax.jit(lambda l, o: ref.forward(    # one program
+        l, o, tokens, ref_cfg(model.config), held=HELD))(layers, other))
     np.testing.assert_allclose(got, want, rtol=0,
                                atol=1e-5 * float(np.abs(want).max()))
 
@@ -599,8 +599,10 @@ def test_parameter_gradients_match_the_reference(small):
     model, tokens = small
     layers, other = weights_of(model)
     cfg = ref_cfg(model.config)
-    want_layers, want_other = jax.grad(
-        lambda l, o: ref.loss(l, o, tokens, cfg, HELD), argnums=(0, 1))(
+    # (one program: the reference's gradient dispatched operation by
+    # operation took most of this test's minute)
+    want_layers, want_other = jax.jit(jax.grad(
+        lambda l, o: ref.loss(l, o, tokens, cfg, HELD), argnums=(0, 1)))(
         [{k: jnp.asarray(v) for k, v in w.items()} for w in layers],
         {k: jnp.asarray(v) for k, v in other.items()})
     loss = model.loss(paddle.to_tensor(tokens))
@@ -653,7 +655,8 @@ def test_the_trainers_step_is_the_references_loss_and_gradients():
         return 0.5 * (ref.loss(l, o, tokens[:2], cfg, HELD)
                       + ref.loss(l, o, tokens[2:], cfg, HELD))
 
-    want, (g_layers, g_other) = jax.value_and_grad(two, argnums=(0, 1))(
+    want, (g_layers, g_other) = jax.jit(jax.value_and_grad(
+        two, argnums=(0, 1)))(        # one program, not an op at a time
         as_jnp(layers), as_jnp(other))
     tr = trainer(model, paddle.optimizer.SGD(
         1.0, parameters=model.parameters()))

@@ -997,3 +997,69 @@ def test_tpu_compile_the_falcon_h1_models_forward(monkeypatch):
         "the donated pools and states are not aliased"
     assert ma.temp_size_in_bytes < pools.state.size * 4 / 2, \
         "a layer's states are copied"
+
+
+def test_tpu_compile_the_laguna_models_forward(monkeypatch):
+    """ISSUE 57: Laguna's tick forward (``models/laguna.laguna_ragged_apply``:
+    full attention of 48 query heads and windowed attention of 72 over one
+    set of 8 key/value heads, a gate a head, held experts) compiles for the
+    v5e from a ``LazyGuard`` model at the published widths of one full and
+    one windowed layer, both sparse (8 of the 256 experts held), the cell's
+    38 decode rows and its chunk row of 256: the grouped kernel and its
+    windowed sibling are in the program (the decode rows' call and the chunk
+    row's each), the donated pools are aliased and none is re-laid around a
+    write."""
+    import jax.numpy as jnp
+
+    import paddle_tpu as paddle
+    from paddle_tpu.models.laguna import (FULL, SLIDING, Laguna,
+                                          LagunaConfig, laguna_ragged_apply)
+    from paddle_tpu.models.tick import state_drawer
+    from paddle_tpu.serving.paged_cache import WindowedKVPools
+
+    dev = _tpu_topology_devices()[0]
+    cfg = LagunaConfig(num_hidden_layers=2, vocab_size=1024,
+                       layer_types=(FULL, SLIDING),
+                       mlp_layer_types=("sparse", "sparse"),
+                       num_attention_heads_per_layer=(48, 72),
+                       experts_held=(0, 8))
+    with paddle.LazyGuard():
+        net = Laguna(cfg)
+    net.bfloat16()
+    state = jax.eval_shape(state_drawer(net),
+                           jax.ShapeDtypeStruct((2,), jnp.uint32))
+    ns, ps, nps, w = 38, 16, 1104, 256
+    held = -(-(511 + w) // ps) + 2
+    pools = jax.eval_shape(lambda: WindowedKVPools.zeros(
+        1, ns * nps + 1, 1, ns * held + 1, ps, 8, 128, jnp.bfloat16))
+    assert pools.kv.kv.shape == (1, ns * nps + 1, 16, ps, 128)
+    assert pools.window.kv.shape == (1, ns * 50 + 1, 16, ps, 128)
+    nt = ns + w
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32)
+
+    args = (state[0], state[1], pools, i32(nt), i32(nt), i32(nt),
+            (i32(ns + 1, nps), i32(ns + 1, nps)), i32(ns + 1), i32(ns + 1),
+            i32(ns), jax.ShapeDtypeStruct((), jnp.bool_))
+    args = jax.tree_util.tree_map(
+        lambda a: _on_tpu(dev, a.shape, a.dtype), args)
+
+    def forward(*a):
+        return laguna_ragged_apply(cfg, *a[:-1], decode_rows=ns,
+                                   chunk_width=w, has_chunks=a[-1])
+
+    monkeypatch.setenv("PADDLE_TPU_TARGET_PLATFORM", "tpu")
+    with jax.default_matmul_precision("default"):
+        compiled = jax.jit(forward, donate_argnums=2).lower(*args).compile()
+    text = compiled.as_text()
+    assert len(re.findall(r"%grouped_paged_attn[\w.\-]* = ", text)) == 2
+    assert len(re.findall(r"%grouped_window_attn[\w.\-]* = ", text)) == 2
+    assert len(re.findall(r" conditional\(", text)) == 3
+    assert "remat_compressed" not in text
+    ma = compiled.memory_analysis()
+    pool_bytes = sum(a.size * a.dtype.itemsize
+                     for a in jax.tree_util.tree_leaves(pools))
+    assert ma.alias_size_in_bytes >= pool_bytes, \
+        "the donated pools are not aliased"
+    assert ma.temp_size_in_bytes < pool_bytes / 4, "a pool is copied"

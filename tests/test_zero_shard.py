@@ -108,10 +108,12 @@ class TestRingCollectiveUnits:
                                                block=512, mean=True)
             return qcomm.quantized_all_gather(c, "dp", block=512)[None]
 
-        a = shard_map(fused, mesh=mesh, in_specs=(P("dp"),),
-                      out_specs=P("dp"))(x)
-        b = shard_map(split, mesh=mesh, in_specs=(P("dp"),),
-                      out_specs=P("dp"))(x)
+        # (each one program: dispatched operation by operation over eight
+        # devices the two rings took over a minute)
+        a = jax.jit(shard_map(fused, mesh=mesh, in_specs=(P("dp"),),
+                              out_specs=P("dp")))(x)
+        b = jax.jit(shard_map(split, mesh=mesh, in_specs=(P("dp"),),
+                              out_specs=P("dp")))(x)
         # the fused spelling IS the composition now — bitwise
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 

@@ -10,8 +10,8 @@ change's (``git archive <commit> | tar -x -C <dir>``), with ``JAX_PLATFORMS=cpu`
 an engine is built and ticked on the CPU at the rows of ``gpt3-1.3b-serve`` and
 ``ouro-2.6b-serve``, at a tiny width with a draft model (both ticks), for a
 dots3 and a DeepSeek-V2 model at the published head counts and latent widths,
-and for an Olmo-Hybrid (PR 44), a Ling-3.0 (PR 49) and a Falcon-H1 model
-(PR 54) at the published head sizes;
+and for an Olmo-Hybrid (PR 44), a Ling-3.0 (PR 49), a Falcon-H1 (PR 54) and
+a Laguna model (PR 57) at the published head sizes;
 each tick is lowered again from the avals of its first dispatch as a program
 traced for the TPU (the attention kernels inside), and its StableHLO text,
 which carries no locations, is written to ``<out_dir>/<name>.<site>.txt``,
@@ -21,8 +21,11 @@ The second form compares two such directories: the text outside the Mosaic
 kernels' serialized bodies, which must be equal, and the bodies, which carry
 their operations' locations and so differ by the tree's root path: they are
 compared after that path (keep the innermost frame alone, as here, or the
-call stack's line numbers are in them too). Nothing is run on a chip and no
-number comes out of this.
+call stack's line numbers are in them too). A body that still differs, as
+every kernel below an edited line of its file does, is parsed and compared
+as text without its locations (PR 57): ``equal but for locations`` is the
+same program, ``DIFFER`` is not. Nothing is run on a chip and no number
+comes out of this.
 """
 import base64
 import os
@@ -31,6 +34,20 @@ import sys
 from collections import Counter
 
 _BODY = re.compile(r'body\\22: \\22([A-Za-z0-9+/=]+)\\22')
+
+
+def _without_locations(bodies):
+    """Each serialized Mosaic body as MLIR text with no debug information."""
+    from jax._src.interpreters import mlir
+    from jax._src.lib import tpu
+    from jax._src.lib.mlir import ir
+
+    ctx = mlir.make_ir_context()
+    tpu.register_dialect(ctx)
+    ctx.allow_unregistered_dialects = True      # ``stable_mosaic.*``
+    with ctx:
+        return [ir.Module.parse(k).operation.get_asm(enable_debug_info=False)
+                for k in bodies]
 
 
 def compare(a: str, b: str) -> int:
@@ -51,9 +68,17 @@ def compare(a: str, b: str) -> int:
                      if k else b"" for k in (ka, kb)]
         same_kernels = len(ka) == len(kb) and all(
             x.replace(roots[0], roots[1]) == y for x, y in zip(ka, kb))
+        said = "equal after the root path" if same_kernels else "DIFFER"
+        if not same_kernels and len(ka) == len(kb):
+            pairs = list(zip(_without_locations(ka), _without_locations(kb)))
+            moved = [re.search(r"module @(\w+)", x).group(1)
+                     for x, y in pairs if x != y]
+            same_kernels = not moved
+            said = "equal but for locations" if same_kernels \
+                else f"DIFFER ({', '.join(moved)})"
         print(f"{name}: text outside kernels "
               f"{'equal' if same_text else 'DIFFERS'}, {len(ka)} kernels "
-              f"{'equal after the root path' if same_kernels else 'DIFFER'}")
+              f"{said}")
         for la, lb in zip(_BODY.sub("body", ta).splitlines(),
                           _BODY.sub("body", tb).splitlines()):
             if la != lb:        # where a differing line first parts
@@ -262,3 +287,34 @@ if FalconH1 is not None:
         eng.step()
     eng.drain(0)
     lower("falcon-h1", eng)
+
+# Laguna at its published head size (12 and 18 query heads over 2 key/value
+# heads of 128: six and nine a key/value head; a window of 64 over pages of
+# 16; 8 of 16 experts held): full and windowed grouped K/V pages, the
+# windowed layers' in a page space of their own. A tree without the model
+# (before PR 57) writes no file.
+try:
+    from paddle_tpu.models.laguna import Laguna, LagunaConfig
+except ImportError:
+    Laguna = None
+if Laguna is not None:
+    paddle.seed(0)
+    with paddle.LazyGuard():
+        laguna = Laguna(LagunaConfig(
+            vocab_size=512, hidden_size=512, intermediate_size=512,
+            num_hidden_layers=3, num_attention_heads=12,
+            num_key_value_heads=2,
+            layer_types=("full_attention",) + ("sliding_attention",) * 2,
+            num_attention_heads_per_layer=(12, 18, 18), num_experts=16,
+            num_experts_per_tok=4, moe_intermediate_size=128,
+            shared_expert_intermediate_size=128, sliding_window=64,
+            experts_held=(4, 8), max_position_embeddings=2048))
+    laguna.bfloat16()
+    eng = ServingEngine(laguna, ServingConfig(
+        num_slots=8, page_size=16, pages_per_slot=88, prefill_chunk=256,
+        prefix_cache=False))
+    eng.submit(np.arange(300, dtype=np.int32) % 512, 2)
+    for _ in range(3):
+        eng.step()
+    eng.drain(0)
+    lower("laguna", eng)
