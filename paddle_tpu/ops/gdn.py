@@ -63,7 +63,10 @@ reference and the path off the chip. The kernels (``gdn_step``,
 gathers or scatters a state outside them. ``gdn_chunk`` pads the keys to
 128 channels (exact: a zero channel adds nothing) and runs a pair's two
 heads over the pair's whole lanes with the other head's masked to zero, so
-every slice it takes is a tile's.
+every slice it takes is a tile's. Both chunk kernels read the rows' lengths
+and skip the rule for a row of no tokens (the chunk row of a tick without a
+chunk, most ticks of a window that decodes): zeros out, its slot's state as
+it was.
 
 **The pass between projections and rule** (``gdn_prep_step``,
 ``gdn_prep_chunk``; PR 46) takes a layer's whole history ``[taps - 1, rows,
@@ -319,18 +322,21 @@ def pallas_step(q, k, v, g, beta, state, layer, slots):
     return o.reshape(n, h, dv), state
 
 
-def _chunk_kernel(slots_ref, layer_ref, fresh_ref, q_ref, k_ref, v_ref,
-                  g_ref, b_ref, s_ref, o_ref, s_out, acc, *, dk: int,
+def _chunk_kernel(slots_ref, layer_ref, fresh_ref, len_ref, q_ref, k_ref,
+                  v_ref, g_ref, b_ref, s_ref, o_ref, s_out, acc, *, dk: int,
                   dv: int):
     """Grid (row, pair, chunk): the pair's state stays in ``acc`` ``[128, 2
     dv]`` (its keys padded to the lanes' 128) over the row's chunks; each
     head of the pair runs ``kda._chunk_fwd`` over the pair's whole lanes
     with the other head's values and state masked to zero, so the two
-    results add."""
+    results add. A row of no tokens (the chunk row of a tick without a
+    chunk) skips the rule, as ``_kda_chunk_kernel`` does: zeros out, its
+    slot's state as it was."""
     del slots_ref, layer_ref
     r, c = pl.program_id(0), pl.program_id(2)
     dkp, dv2 = acc.shape
     cs = kda.CHUNK
+    some = len_ref[r] > 0
 
     @pl.when(c == 0)
     def _enter():
@@ -340,27 +346,33 @@ def _chunk_kernel(slots_ref, layer_ref, fresh_ref, q_ref, k_ref, v_ref,
         def _carried():
             acc[0:dk, :] = s_ref[0, 0, 0]
 
-    left = jax.lax.broadcasted_iota(jnp.int32, (1, dv2), 1) < dv
-    s0 = acc[...]
-    vv = v_ref[0]                                           # [C, 2 dv]
-    o, s1 = 0.0, 0.0
-    for h in range(2):
-        mine = left if h == 0 else jnp.logical_not(left)
-        gk = jnp.broadcast_to(kda._to_col(g_ref[0, h, pl.ds(c, 1), :]),
-                              (cs, dkp))
-        oh, sh, _ = kda._chunk_fwd(
-            q_ref[0, :, h * dkp:(h + 1) * dkp],
-            k_ref[0, :, h * dkp:(h + 1) * dkp],
-            jnp.where(mine, vv, jnp.zeros_like(vv)), gk,
-            b_ref[0, h, pl.ds(c, 1), :], jnp.where(mine, s0, 0.0),
-            dk ** -0.5)
-        o, s1 = o + oh, s1 + sh
-    o_ref[0] = o
-    acc[...] = s1
+    @pl.when(some)
+    def _rule():
+        left = jax.lax.broadcasted_iota(jnp.int32, (1, dv2), 1) < dv
+        s0 = acc[...]
+        vv = v_ref[0]                                       # [C, 2 dv]
+        o, s1 = 0.0, 0.0
+        for h in range(2):
+            mine = left if h == 0 else jnp.logical_not(left)
+            gk = jnp.broadcast_to(
+                kda._to_col(g_ref[0, h, pl.ds(c, 1), :]), (cs, dkp))
+            oh, sh, _ = kda._chunk_fwd(
+                q_ref[0, :, h * dkp:(h + 1) * dkp],
+                k_ref[0, :, h * dkp:(h + 1) * dkp],
+                jnp.where(mine, vv, jnp.zeros_like(vv)), gk,
+                b_ref[0, h, pl.ds(c, 1), :], jnp.where(mine, s0, 0.0),
+                dk ** -0.5)
+            o, s1 = o + oh, s1 + sh
+        o_ref[0] = o
+        acc[...] = s1
+
+    @pl.when(jnp.logical_not(some))
+    def _empty():
+        o_ref[...] = jnp.zeros_like(o_ref)
 
     @pl.when(c + 1 == pl.num_programs(2))
     def _leave():
-        s_out[0, 0, 0] = acc[0:dk, :]
+        s_out[0, 0, 0] = jnp.where(some, acc[0:dk, :], s_ref[0, 0, 0])
 
 
 def pallas_chunk(q, k, v, g, beta, state, layer, slots, fresh, row_len):
@@ -378,28 +390,28 @@ def pallas_chunk(q, k, v, g, beta, state, layer, slots, fresh, row_len):
     rows = lambda a: jnp.transpose(                         # noqa: E731
         a.reshape(n, nc, cs, h), (0, 3, 1, 2))
     cols = lambda d: pl.BlockSpec(                          # noqa: E731
-        (1, cs, d), lambda i, j, c, sl, ly, fr: (i, c, j))
+        (1, cs, d), lambda i, j, c, sl, ly, fr, ln: (i, c, j))
     gate = pl.BlockSpec((1, 2, nc, cs),
-                        lambda i, j, c, sl, ly, fr: (i, j, 0, 0))
-    st = pl.BlockSpec((1, 1, 1, dk, dv2),
-                      lambda i, j, c, sl, ly, fr: (ly[0], sl[i], j, 0, 0))
+                        lambda i, j, c, sl, ly, fr, ln: (i, j, 0, 0))
+    st = pl.BlockSpec((1, 1, 1, dk, dv2), lambda i, j, c, sl, ly, fr, ln: (
+        ly[0], sl[i], j, 0, 0))
     o, state = pl.pallas_call(
         functools.partial(_chunk_kernel, dk=dk, dv=dv),
         name="gdn_chunk",
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3, grid=(n, hp, nc),
+            num_scalar_prefetch=4, grid=(n, hp, nc),
             in_specs=[cols(2 * _LANES), cols(2 * _LANES), cols(dv2), gate,
                       gate, st],
             out_specs=[cols(dv2), st],
             scratch_shapes=[pltpu.VMEM((_LANES, dv2), _F32)]),
         out_shape=[jax.ShapeDtypeStruct((n, w, h * dv), _F32),
                    jax.ShapeDtypeStruct(state.shape, state.dtype)],
-        input_output_aliases={8: 1},
+        input_output_aliases={9: 1},
         compiler_params=_params("arbitrary", "arbitrary", "arbitrary"),
         interpret=_interpret(),
     )(slots.astype(jnp.int32), jnp.asarray(layer, jnp.int32).reshape(1),
-      fresh.astype(jnp.int32), keys(q), keys(k), v.reshape(n, w, h * dv),
-      rows(g), rows(beta), state)
+      fresh.astype(jnp.int32), row_len.astype(jnp.int32), keys(q), keys(k),
+      v.reshape(n, w, h * dv), rows(g), rows(beta), state)
     return o.reshape(n, w, h, dv), state
 
 

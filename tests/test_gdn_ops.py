@@ -146,24 +146,43 @@ def test_the_step_kernel_equals_its_xla_spelling(h, dk, dv):
     np.testing.assert_array_equal(after[0], state[0])
 
 
+# (slots, fresh, row_len) a row: full rows, a partial last chunk and rows of
+# no tokens side by side, entering at zero and carried; the last case is the
+# chunk row of a tick without a chunk
+CHUNK_ROWS = {
+    "full-partial-null": ([3, 1, 0], [False, True, False], [128, 70, 0]),
+    "empty-beside-full": ([2, 3, 1], [False, False, True], [0, 128, 0]),
+    "empty-beside-partial": ([1, 2, 3], [True, False, False], [70, 0, 23]),
+    "empty-first-and-last": ([3, 2, 1], [True, True, False], [0, 128, 0]),
+    "all-empty": ([2, 0, 3], [False, False, True], [0, 0, 0]),
+}
+
+
+@pytest.mark.parametrize("rows", list(CHUNK_ROWS))
 @pytest.mark.parametrize("h,dk,dv", [(6, 24, 64), (2, 96, 192)])
-def test_the_chunk_kernel_equals_its_xla_spelling(h, dk, dv):
+def test_the_chunk_kernel_equals_its_xla_spelling(h, dk, dv, rows):
+    slots, fresh, row_len = (jnp.asarray(a) for a in CHUNK_ROWS[rows])
     q, k, v, g, beta, s0 = _rows(8, 3, 128, h, dk, dv)
     state = _stack(s0)
-    slots = jnp.asarray([3, 1, 0])
-    fresh = jnp.asarray([False, True, False])
-    row_len = jnp.asarray([128, 70, 0])
     s_in = jnp.where(fresh[:, None, None, None], 0.0,
                      gdn.unpack_state(state[1, slots], h))
     want_o, want_s = gdn.xla_chunk(q, k, v, g, beta, s_in, row_len)
     o, after = gdn.pallas_chunk(q, k, v, g, beta, state, 1, slots, fresh,
                                 row_len)
-    for r, n in enumerate([128, 70]):
-        np.testing.assert_allclose(o[r, :n], want_o[r, :n], atol=2e-4,
-                                   rtol=2e-4)
-    np.testing.assert_allclose(gdn.unpack_state(after[1, slots[:2]], h),
-                               want_s[:2], atol=2e-4, rtol=2e-4)
-    np.testing.assert_array_equal(after[1, 2], state[1, 2])   # no row's
+    got_s = gdn.unpack_state(after[1, slots], h)
+    for r, (slot, _, n) in enumerate(zip(*CHUNK_ROWS[rows])):
+        if n:
+            np.testing.assert_allclose(o[r, :n], want_o[r, :n], atol=2e-4,
+                                       rtol=2e-4)
+            np.testing.assert_allclose(got_s[r], want_s[r], atol=2e-4,
+                                       rtol=2e-4)
+        else:
+            # a row of no tokens: zeros out (every block written), and its
+            # slot as it was bit for bit, ``fresh`` or not
+            np.testing.assert_array_equal(o[r], 0.0)
+            np.testing.assert_array_equal(after[1, slot], state[1, slot])
+    for free in set(range(state.shape[1])) - set(CHUNK_ROWS[rows][0]):
+        np.testing.assert_array_equal(after[1, free], state[1, free])
     np.testing.assert_array_equal(after[0], state[0])
 
 

@@ -737,6 +737,20 @@ def test_tpu_compile_the_dense_latent_models_forward(monkeypatch):
         "the donated latent pool is not aliased"
 
 
+def _scalar_operands(text: str, kernel: str):
+    """Of each of ``kernel``'s calls in a compiled program's ``text``: how
+    many ``s32`` vectors lead its operands (a Mosaic call's scalar-prefetch
+    operands come first), and the operand its second output is aliased
+    to."""
+    calls = [ln for ln in text.splitlines()
+             if re.search(rf"%{kernel}[\w.\-]* = ", ln)]
+    lead = [re.search(r"operand_layout_constraints=\{((?:s32\[\d+\]\{0\}, )*)",
+                      ln).group(1).count("s32[") for ln in calls]
+    alias = [int(re.search(r"output_to_operand_aliasing=\{\{1\}: \((\d+),",
+                           ln).group(1)) for ln in calls]
+    return lead, alias
+
+
 def _hybrid_tick(monkeypatch, layers: int, told: bool):
     """Olmo-Hybrid's tick forward compiled for the v5e from a ``LazyGuard``
     model of ``layers`` layers at the published widths, the cell's 40 decode
@@ -798,6 +812,9 @@ def test_tpu_compile_the_hybrid_models_forward(monkeypatch):
     text, ma, pools = _hybrid_tick(monkeypatch, 4, told=False)
     assert len(re.findall(r"%gdn_step[\w.\-]* = ", text)) == 3
     assert len(re.findall(r"%gdn_chunk[\w.\-]* = ", text)) == 3
+    # ISSUE 59: the rows' lengths are the rule's fourth scalar operand (a
+    # row of no tokens skips it), the state stack the tenth of the call
+    assert _scalar_operands(text, "gdn_chunk") == ([4] * 3, [9] * 3)
     # ISSUE 46: what lies between projections and rule is one call a row
     # group, the history read and written inside it: no gather, scatter or
     # copy of the history's stack is left in the program
@@ -834,6 +851,7 @@ def test_tpu_compile_the_hybrid_models_tick_told_of_its_chunks(monkeypatch):
                       ("gdn_prep_step", 12), ("gdn_prep_chunk", 12),
                       ("ragged_paged_attn", 8)):
         assert len(re.findall(rf"%{kernel}[\w.\-]* = ", text)) == n, kernel
+    assert _scalar_operands(text, "gdn_chunk") == ([4] * 12, [9] * 12)
     assert pools.conv.shape == (12, 3, 48, 11520)
     moved = [ln for ln in text.splitlines()
              if re.search(r" = bf16\[12,3,48,11520\]\S* (?!custom-call|"
