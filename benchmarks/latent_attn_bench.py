@@ -22,7 +22,7 @@ import jax                                                      # noqa: E402
 import jax.numpy as jnp                                         # noqa: E402
 import numpy as np                                              # noqa: E402
 
-from paddle_tpu.ops import paged_attention as pa                # noqa: E402
+from paddle_tpu.ops import latent_attention as pa               # noqa: E402
 
 NH, W, C, PS, NPS, TOPK = 128, 576, 512, 128, 264, 2048
 SLOTS, CHUNK = 12, 256

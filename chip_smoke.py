@@ -35,7 +35,7 @@ finds, and checks what comes out by the repo's own means:
               forward pass
   2 serve     ServingEngine.submit/step/run, ten requests over ~150 ticks;
               the tick took its attention through the ragged kernel
-  2d dsv2     DeepSeek-V2's dense latent attention (ops/paged_attention.
+  2d dsv2     DeepSeek-V2's dense latent attention (ops/latent_attention.
               latent_attention) at its cell's row shapes (two chunk rows of
               256, twenty decode rows; 128 heads over latents of 576) through
               both of its spellings against a float32 softmax, and a small
@@ -946,7 +946,7 @@ def phase_serve_looped(cfg, num_slots: int, page_size: int,
 def check_latent_ops(rows: int, t: int, heads: int, width: int, c: int,
                      page: int, pages: int, topk: int, window: int,
                      dtype) -> dict:
-    """``ops/paged_attention``'s latent-pool reads at the given row shape
+    """``ops/latent_attention``'s latent-pool reads at the given row shape
     (``rows`` rows of ``t`` queries over ``pages`` pages of ``page``
     tokens, half of them live) against float32 spellings over the whole
     rows: the indexer's scores, the selection (threshold against
@@ -954,7 +954,7 @@ def check_latent_ops(rows: int, t: int, heads: int, width: int, c: int,
     import jax
     import jax.numpy as jnp
 
-    from paddle_tpu.ops import paged_attention as pa
+    from paddle_tpu.ops import latent_attention as pa
 
     rng = np.random.default_rng(5)
     cap = pages * page
@@ -1136,14 +1136,14 @@ def phase_latent(cfg, num_slots: int, page_size: int, pages_per_slot: int,
 
 def check_dense_latent(rows: int, t: int, heads: int, width: int, c: int,
                        page: int, pages: int, dtype) -> dict:
-    """``ops/paged_attention.latent_attention`` at the given row shape
+    """``ops/latent_attention.latent_attention`` at the given row shape
     (``rows`` rows of ``t`` queries over ``pages`` pages of ``page`` tokens,
     half of them live) through both spellings against a float32 softmax
     over the whole rows."""
     import jax
     import jax.numpy as jnp
 
-    from paddle_tpu.ops import paged_attention as pa
+    from paddle_tpu.ops import latent_attention as pa
 
     rng = np.random.default_rng(6)
     cap = pages * page

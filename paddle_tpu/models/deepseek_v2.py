@@ -327,7 +327,7 @@ def deepseek_v2_ragged_apply(c: DeepseekV2Config, stacked, other, pools,
     """Mixed prefill/decode forward over latent pools: the arguments of
     ``models/dots3.dots3_ragged_apply`` (``row_tab`` its pair, of which the
     windowed layers' tables are not read). Decode rows and chunk rows attend
-    in the absorbed form (``ops/paged_attention.latent_attention``, in the
+    in the absorbed form (``ops/latent_attention.latent_attention``, in the
     spelling the platform and the shapes pick where this is traced), under
     two scopes (``blk/attn/mla_decode``, ``blk/attn/mla_chunk``) so that a
     trace tells them apart.

@@ -53,7 +53,7 @@ import jax.numpy as jnp
 from .. import nn
 from ..distributed.moe import HeldMoEMLP, held_moe
 from ..nn import initializer as I
-from ..ops.paged_attention import select_threshold, selection_mask
+from ..ops.latent_attention import select_threshold, selection_mask
 from ..profiler.trace import annotate
 from . import tick as _tick
 from .gpt import rope_at
@@ -331,7 +331,7 @@ def dots3_ragged_apply(c: Dots3Config, stacked, other, pools, tokens,
     ``row_tab`` the pair ``(tables of the full layers' pages, tables of the
     windowed layers' pages)``, both ``[R, NPs]``, ``stacked`` the layers'
     own weights (``{"layer<i>": {...}}``). The spelling of the full layers'
-    attention is picked where this is traced (``ops/paged_attention.
+    attention is picked where this is traced (``ops/latent_attention.
     latent_attention_path``: the Pallas kernel on the chip at the published
     widths); every other read has one spelling. ``has_chunks`` is taken and
     not used: one body whatever the mix.
@@ -346,7 +346,7 @@ def dots3_ragged_apply(c: Dots3Config, stacked, other, pools, tokens,
     ``aux["top_logit"]`` ``[S]`` float32 the sampled rows' largest logit and
     ``aux["window_lse"]`` ``[sliding layers, S]`` float32 the log of the sum
     of each sampled row's exponentiated scores in a sliding layer, mean over
-    its heads (``ops/paged_attention.window_latent_attention``)."""
+    its heads (``ops/latent_attention.window_latent_attention``)."""
     del has_chunks
     tab, wtab = row_tab
     nt, nd, w = tokens.shape[0], decode_rows, chunk_width

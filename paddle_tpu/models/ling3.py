@@ -38,7 +38,7 @@ latent rows ``(c_kv, RoPE(k_pe))`` a token for the MLA layers, in one pool.
 The forward touches them through the pools' methods alone: ``prep``,
 ``step`` and ``chunk`` (``ops/gdn.py``, the forms that take a decay a
 channel) and ``scatter_latent`` and ``attend_latent`` (``ops/
-paged_attention.latent_attention``, DeepSeek-V2's dense path).
+latent_attention.latent_attention``, DeepSeek-V2's dense path).
 ``models/ling3_reference.py`` is the plain float32 reference of the same
 equations; it reads this model's weights by the names given here and none
 of its code. There is no training forward, and the multi-token-prediction

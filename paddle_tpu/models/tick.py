@@ -32,7 +32,7 @@ row_pos0, row_len, sample_ix, *, decode_rows, chunk_width, has_chunks)
     gpt_ragged_apply`` documents the arguments; ``row_tab`` is what the pool's
     ``row_tables`` gives). It writes and reads the caches through ``pools``'
     methods alone, which pick the attention's spelling where the program is
-    traced (``ops/paged_attention.resolve_impl``, ``latent_attention_path``).
+    traced (``paged_attention.resolve_impl``, ``latent_attention_path``).
     ``aux`` is a dict of device arrays, what the tick says of itself beside
     its tokens: empty for a model that reports nothing, and then no output of
     the program.

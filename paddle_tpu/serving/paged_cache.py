@@ -76,11 +76,12 @@ import jax.numpy as jnp
 
 from ..ops.gdn import (conv_slot_rows, gdn_chunk_rows, gdn_prep_rows,
                        gdn_step_rows, pack_state, unpack_state)
+from ..ops.latent_attention import (
+    index_scores, latent_attention, latent_scatter,
+    selected_latent_attention, window_latent_attention)
 from ..ops.paged_attention import (
-    grouped_kv_scatter, grouped_paged_attention, index_scores,
-    latent_attention, latent_scatter, paged_kv_scatter,
-    ragged_paged_attention, selected_latent_attention,
-    window_latent_attention)
+    grouped_kv_scatter, grouped_paged_attention, paged_kv_scatter,
+    ragged_paged_attention)
 from ..ops.ssd import ssd_chunk_rows, ssd_prep_rows, ssd_step_rows
 
 NULL_PAGE = 0
@@ -982,7 +983,7 @@ class LatentPools(NamedTuple):
                                       page space of its own that holds only
                                       the window (``LatentPagePool``)
 
-    A page's tokens lie along the last axis (``ops/paged_attention`` says
+    A page's tokens lie along the last axis (``ops/latent_attention`` says
     why). A model with no indexer or no windowed layer (DeepSeek-V2,
     ``models/deepseek_v2.py``: dense latent attention in every layer) has
     ``index_k`` or ``window`` of no bytes: width 0, no layers.
@@ -992,7 +993,7 @@ class LatentPools(NamedTuple):
     place that knows its format: a forward writes through ``scatter_latent``,
     ``scatter_index`` and ``scatter_window`` and reads through
     ``index_scores``, ``attend_selected``, ``attend`` and ``attend_window``,
-    each ``ops/paged_attention``'s function on the field it is for."""
+    each ``ops/latent_attention``'s function on the field it is for."""
 
     latent: jax.Array
     index_k: jax.Array

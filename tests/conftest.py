@@ -50,14 +50,15 @@ def attention_spelling(monkeypatch):
     attention by ``path`` (``"pallas"``: the kernels, interpreted on the CPU;
     ``"xla"``) where it names none, whatever the platform. A tick names none:
     the spelling is picked at one seam, ``ops/paged_attention.resolve_impl``
-    and ``latent_attention_path``, and that is where a test that drives a
-    whole engine through the other spelling substitutes."""
+    and ``ops/latent_attention.latent_attention_path``, and that is where a
+    test that drives a whole engine through the other spelling substitutes."""
+    from paddle_tpu.ops import latent_attention as la
     from paddle_tpu.ops import paged_attention as pa
 
     def take(path: str) -> None:
         monkeypatch.setattr(pa, "resolve_impl",
                             lambda impl=None: impl or path)
-        monkeypatch.setattr(pa, "latent_attention_path",
+        monkeypatch.setattr(la, "latent_attention_path",
                             lambda q, pool, c_width, impl=None: impl or path)
 
     return take

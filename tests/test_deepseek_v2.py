@@ -22,7 +22,7 @@ from paddle_tpu.models.deepseek_v2 import (TICK_STATS, YARN, DeepseekV2,
                                            DeepseekV2Config,
                                            deepseek_v2_ragged_apply,
                                            yarn_bounds, yarn_table)
-from paddle_tpu.ops import paged_attention as pa
+from paddle_tpu.ops import latent_attention as pa
 from paddle_tpu.profiler import metrics
 from paddle_tpu.serving import ServingConfig, ServingEngine
 from paddle_tpu.serving.paged_cache import LatentPagePool

@@ -18,7 +18,7 @@ from paddle_tpu.models import dots3_reference as ref
 from paddle_tpu.models.dots3 import (FULL, SLIDING, Dots3, Dots3Config,
                                      dots3_ragged_apply)
 from paddle_tpu.ops import grouped_matmul as gmm
-from paddle_tpu.ops import paged_attention as pa
+from paddle_tpu.ops import latent_attention as pa
 from paddle_tpu.serving import ServingConfig, ServingEngine
 from paddle_tpu.serving.paged_cache import LatentPagePool, LatentPools
 

@@ -32,9 +32,12 @@ def _dense(q, k, v, pos0, true_len):
     return out
 
 
-def _filled(seed, layers, rows, nps, kvh, d, lens, dtype=jnp.float32):
+def _filled(seed, layers, rows, nps, kvh, d, lens, dtype=jnp.float32,
+            named=False):
     """A grouped pool whose rows hold ``lens`` positions each on pages of
-    their own (shuffled ids), NaN on every page no row reaches."""
+    their own (shuffled ids), NaN on every page no row reaches. The table
+    names a row's live pages alone (null entries behind them) or, ``named``,
+    every page of the row."""
     rng = np.random.default_rng(seed)
     n_pages = 1 + rows * nps
     ids = rng.permutation(np.arange(1, n_pages)).reshape(rows, nps)
@@ -52,8 +55,8 @@ def _filled(seed, layers, rows, nps, kvh, d, lens, dtype=jnp.float32):
             pool[:, ids[r, p], kvh:, :m] = np.swapaxes(v[:, got], 1, 2)
             pool[:, ids[r, p], :, m:] = 0.0 if m < PS else pool[
                 :, ids[r, p], :, m:]
-    table = np.where(np.arange(nps)[None, :] < -(-np.asarray(lens)[:, None]
-                                                 // PS), ids, 0)
+    table = ids if named else np.where(
+        np.arange(nps)[None, :] < -(-np.asarray(lens)[:, None] // PS), ids, 0)
     return jnp.asarray(pool, dtype), jnp.asarray(table, jnp.int32), ks, vs
 
 
@@ -98,6 +101,73 @@ def test_chunk_rows_against_dense_attention(impl, heads, kvh, d):
         want = _dense(q[r], ks[r][0], vs[r][0], int(pos0[r]), n)
         np.testing.assert_allclose(got[r, :n], want[:n], atol=2e-5,
                                    rtol=2e-5)
+
+
+def _rows_of(lens, t):
+    """Decode rows (``t`` 1) or chunk rows whose last ``min(n, t)`` positions
+    are the queries, for rows of ``lens`` positions."""
+    tl = np.minimum(np.asarray(lens), t)
+    return (jnp.asarray(np.asarray(lens) - tl, jnp.int32),
+            jnp.asarray(tl, jnp.int32))
+
+
+@pytest.mark.parametrize("window", [None, 21], ids=["full", "window"])
+def test_nothing_past_a_rows_last_position_is_read(window, t=8):
+    """The mechanism (``_walk_pages``) under this kernel's body, rows of up
+    to eight queries (a row of one position is a decode row): every page
+    past each row's last live one, what lies behind the last position inside
+    that page and, under a window, every page before the block of the row's
+    oldest visible key, filled with NaN and +inf and *named by the table*,
+    leaves the kernel's output as it was bit for bit, and finite. The
+    capacity-wide spelling cannot pass this (0 x NaN; the windowed one
+    gathers the window's pages alone and zeroes what it does not hold)."""
+    kvh, d, nps = 2, 16, 12
+    lens = [37, 0, 8, 96, 63, 1]
+    pool, ids, _, _ = _filled(8, 1, len(lens), nps, kvh, d, lens, named=True)
+    pool, ids = np.array(jnp.nan_to_num(pool)), np.asarray(ids)
+    table = np.where(np.arange(nps)[None, :] < -(-np.asarray(lens)[:, None]
+                                                 // PS), ids, 0)
+    pos0, tl = _rows_of(lens, t)
+    q = jnp.asarray(np.random.default_rng(9).standard_normal(
+        (len(lens), t, 12, d)), jnp.float32)
+    attend = lambda pool_, table_, impl: pa.grouped_paged_attention(  # noqa
+        q, jnp.asarray(pool_), jnp.asarray(table_, jnp.int32), pos0, tl, 0,
+        impl=impl, window=window)
+    clean = attend(pool, table, "pallas")
+    bad = np.array([np.nan, np.inf], np.float32)
+    dirty, bt = pool.copy(), pa.kv_block_pages(PS, nps) * PS
+    for r, n in enumerate(lens):
+        dirty[0, ids[r, -(-n // PS):]] = bad[r % 2]
+        if n % PS:
+            dirty[0, ids[r, n // PS], :, n % PS:] = bad[(r + 1) % 2]
+        if window is not None and n:
+            oldest = max(int(pos0[r]) - (window - 1), 0)
+            dirty[0, ids[r, :oldest // bt * (bt // PS)]] = bad[r % 2]
+    got = attend(dirty, ids, "pallas")
+    assert np.isfinite(got).all()
+    np.testing.assert_array_equal(got, clean)
+    real = np.arange(t)[None, :] < np.asarray(tl)[:, None]
+    spelled = np.asarray(attend(dirty, ids, "xla"))[real]
+    assert np.isfinite(spelled).all() == (window is not None)
+
+
+@pytest.mark.parametrize("window", [None, 21], ids=["full", "window"])
+def test_a_row_of_no_tokens_costs_its_neighbours_nothing(window):
+    """An empty row (the first, the last, two in a row) walks no block and
+    hands the buffer in turn on: the rows around it read what the spelling
+    reads, and it gets zeros."""
+    kvh, d, lens = 2, 16, [0, 37, 0, 0, 63, 0]
+    pool, table, _, _ = _filled(10, 1, len(lens), 8, kvh, d, lens)
+    pos0, tl = _rows_of(lens, 1)
+    q = jnp.asarray(np.random.default_rng(11).standard_normal(
+        (len(lens), 1, 12, d)), jnp.float32)
+    got = pa.grouped_paged_attention(q, pool, table, pos0, tl, 0,
+                                     impl="pallas", window=window)
+    want = pa.grouped_paged_attention(q, jnp.nan_to_num(pool), table, pos0,
+                                      tl, 0, impl="xla", window=window)
+    live = np.asarray(lens) > 0
+    np.testing.assert_allclose(got[live], want[live], atol=2e-5, rtol=2e-5)
+    assert not np.asarray(got)[~live].any()
 
 
 def test_bf16_pages_meet_the_product_as_they_lie():

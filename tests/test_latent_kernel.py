@@ -1,4 +1,4 @@
-"""The latent-attention kernel (``ops/paged_attention._latent_kernel``,
+"""The latent-attention kernel (``ops/latent_attention._latent_kernel``,
 ISSUE 39): interpreted, at toy sizes, against the XLA spelling
 ``_selected_latent_xla`` and against a dense softmax under
 ``selection_mask``; the mechanism (no page past a tile's last visible
@@ -10,7 +10,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from paddle_tpu.ops import paged_attention as pa
+from paddle_tpu.ops import latent_attention as pa
 from paddle_tpu.profiler import metrics
 
 PS, NH, W, C = 4, 4, 24, 16

@@ -621,7 +621,7 @@ def test_a_value_that_is_not_finite_spoils_its_column_of_every_token(bad):
     """What the product does where the scatter-add did not: ``0 * inf`` is
     NaN, so one value of a live row that is not finite makes that column of
     every token NaN, where its own token alone read it (pinned, not wished
-    for: PERF.md section 7, "What PR 52 left open")."""
+    for: PERF.md section 7, "The one-hot combine's edges")."""
     from paddle_tpu.distributed import moe
 
     y, tok, rows = _window(np.random.default_rng(5), 24, 128, 100, 64,

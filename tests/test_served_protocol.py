@@ -19,7 +19,7 @@ from paddle_tpu.models import GPT, GPTConfig
 from paddle_tpu.models.deepseek_v2 import DeepseekV2, DeepseekV2Config
 from paddle_tpu.models.dots3 import Dots3, Dots3Config
 from paddle_tpu.models.tick import LoopRecord, TickRecord
-from paddle_tpu.ops import paged_attention as pa
+from paddle_tpu.ops import latent_attention as pa
 from paddle_tpu.profiler import recompile
 from paddle_tpu.serving import ServingConfig, ServingEngine
 from paddle_tpu.serving.paged_cache import (LatentPagePool, LatentPools,
@@ -101,7 +101,7 @@ _ENGINE = (r"_latent\b", r"getattr\(model", r"getattr\(mcfg",
            r"attention_kernel", r"_impl\b", r"models\.gpt", r"LatentPagePool")
 _MODEL = (r"\.index_k", r"\bpl\.(latent|window)", r"pools\.(latent|window)\b",
           r"_replace\(", r"latent_scatter\(", r"_pa\.", r"attention_kernel",
-          r"impl=", r"ops import paged_attention")
+          r"impl=", r"ops import (paged|latent)_attention")
 
 
 @pytest.mark.parametrize("path,banished", [
@@ -131,7 +131,7 @@ def _same(got, want):
 
 @pytest.mark.parametrize("field", ["latent", "index_k", "window"])
 def test_latent_pools_methods_are_the_ops_on_their_field(field):
-    """Each method of ``LatentPools`` is ``ops/paged_attention``'s function
+    """Each method of ``LatentPools`` is ``ops/latent_attention``'s function
     on the field it is for, bit for bit, the other fields untouched: the
     write, then every read of that field over what was written."""
     rng = np.random.default_rng(7)

@@ -24,8 +24,13 @@ compared after that path (keep the innermost frame alone, as here, or the
 call stack's line numbers are in them too). A body that still differs, as
 every kernel below an edited line of its file does, is parsed and compared
 as text without its locations (PR 57): ``equal but for locations`` is the
-same program, ``DIFFER`` is not. Nothing is run on a chip and no number
-comes out of this.
+same program, ``DIFFER`` is not. Where the two sides' bodies name other
+source files, the line says which: since PR 60 the latent kernels
+(``latent_attn``, ``selected_latent_attn``) are traced from
+``ops/latent_attention.py``, the ragged and grouped ones and the walk all
+three share (``_walk_pages``) from ``ops/paged_attention.py``, so against a
+tree from before it every latent body reads ``equal but for locations``.
+Nothing is run on a chip and no number comes out of this.
 """
 import base64
 import os
@@ -48,6 +53,12 @@ def _without_locations(bodies):
     with ctx:
         return [ir.Module.parse(k).operation.get_asm(enable_debug_info=False)
                 for k in bodies]
+
+
+def _sources(bodies):
+    """The repo's files that the bodies' locations name."""
+    return {f.decode() for k in bodies
+            for f in re.findall(rb"paddle_tpu/[\w/]+\.py", k)}
 
 
 def compare(a: str, b: str) -> int:
@@ -76,6 +87,9 @@ def compare(a: str, b: str) -> int:
             same_kernels = not moved
             said = "equal but for locations" if same_kernels \
                 else f"DIFFER ({', '.join(moved)})"
+            one_side = sorted(_sources(ka) ^ _sources(kb))
+            if one_side:
+                said += f" [one side alone names {', '.join(one_side)}]"
         print(f"{name}: text outside kernels "
               f"{'equal' if same_text else 'DIFFERS'}, {len(ka)} kernels "
               f"{said}")
@@ -100,7 +114,8 @@ import jax  # noqa: E402
 
 jax.config.update("jax_platforms", "cpu")
 # a Mosaic kernel's serialized body carries its ops' locations: keep the
-# innermost frame alone (ops/paged_attention.py, the same file on both sides)
+# innermost frame alone (the kernel's own file: ops/paged_attention.py or,
+# for the latent kernels since PR 60, ops/latent_attention.py)
 jax.config.update("jax_traceback_in_locations_limit", 1)
 import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
