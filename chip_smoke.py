@@ -1699,23 +1699,152 @@ def check_grouped_attention(rows: int, t: int, heads: int, kvh: int, d: int,
     return _nerr(got, want)
 
 
+def ragged_rows(rng, rows: int, t: int, ps: int, nps: int, window=None):
+    """One draw of ragged rows for ``check_grouped_attention_ragged``: how
+    many positions each row holds after its queries (``last``; 0: a row of
+    no tokens), its ``pos0`` and ``true_len``, and a table of shuffled page
+    ids that names a row's live pages alone (null entries past them and,
+    under ``window``, for every page wholly behind the row's oldest visible
+    key). Every edge a walk has is some row's last position: 1, ``ps - 1``,
+    ``ps``, 255 to 257, 511 to 513 and the slot's capacity; about one row
+    in six holds nothing, two of them neighbours; a row of ``t > 1`` queries
+    in three has a ``true_len`` short of ``t``."""
+    cap = ps * nps
+    last = rng.integers(1, cap + 1, size=rows)
+    last[rng.random(rows) < 0.15] = 0
+    pair = rng.integers(rows - 1)
+    last[[pair, pair + 1]] = 0
+    edges = [e for e in (1, ps - 1, ps, 255, 256, 257, 511, 512, 513, cap)
+             if 0 < e <= cap]
+    free = [i for i in rng.permutation(rows) if i not in (pair, pair + 1)]
+    edges = rng.permutation(edges)[:len(free)]
+    last[free[:len(edges)]] = edges
+    tl = np.minimum(last, t)
+    short = (rng.random(rows) < 1 / 3) & (tl > 1)
+    tl[short] = rng.integers(1, tl[short])
+    pos0 = last - tl
+    ids = 1 + rng.permutation(rows * nps).reshape(rows, nps)
+    col = np.arange(nps)[None, :]
+    held = col < -(-last[:, None] // ps)
+    if window is not None:
+        held &= col >= np.maximum(pos0[:, None] - (window - 1), 0) // ps
+    return last, pos0, tl, np.where(held, ids, 0)
+
+
+def check_grouped_attention_ragged(rows: int, t: int, heads: int, kvh: int,
+                                   d: int, ps: int, nps: int, window=None,
+                                   draws: int = 20, seed: int = 61) -> float:
+    """``grouped_paged_attention`` by the platform's path against its XLA
+    spelling on rows of mixed depth in one call (``ragged_rows``, ``draws``
+    draws from one seeded generator over one pool of bf16 pages, every
+    position of every page written). Compared are the live queries, a row
+    at its own scale; a draw over ``TOL_RAGGED`` fails and names its worst
+    row. Returned: the worst draw's error."""
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.ops import paged_attention as pa
+
+    rng = np.random.default_rng(seed)
+    ks = jax.random.split(jax.random.PRNGKey(seed), 2)
+    pool = jax.random.normal(ks[0], (1, rows * nps + 1, 2 * kvh, ps, d),
+                             jnp.bfloat16)
+    pool = pool.at[:, 0].set(0)
+    q = jax.random.normal(ks[1], (rows, t, heads, d)).astype(jnp.bfloat16)
+    # traced anew in every check (a function of its own)
+    attend = jax.jit(lambda *a: pa.grouped_paged_attention(*a),
+                     static_argnums=(5, 6, 7))
+    worst = 0.0
+    for draw in range(draws):
+        last, pos0, tl, table = ragged_rows(rng, rows, t, ps, nps, window)
+        args = (q, pool, jnp.asarray(table, jnp.int32),
+                jnp.asarray(pos0, jnp.int32), jnp.asarray(tl, jnp.int32), 0)
+        got = np.asarray(attend(*args, None, window), np.float32)
+        want = np.asarray(attend(*args, "xla", window), np.float32)
+        live = np.arange(t)[None, :] < tl[:, None]              # [R, T]
+        err = np.abs(got - want).max(axis=(2, 3))
+        err = np.where(live, err, 0).max(axis=1) / np.maximum(
+            np.abs(want).max(axis=(1, 2, 3)), 1e-30)
+        r = int(np.argmax(err))
+        check(np.isfinite(got[live]).all() and err[r] <= TOL_RAGGED,
+              f"grouped attention, ragged rows {(rows, t, heads, kvh)} "
+              f"window {window}, draw {draw} of seed {seed}: row {r} (pos0 "
+              f"{pos0[r]}, true_len {tl[r]}, {last[r]} positions) is "
+              f"{err[r]:.2e} from the XLA spelling (tol {TOL_RAGGED}); rows "
+              f"of no tokens: {np.flatnonzero(last == 0).tolist()}")
+        check(not got[tl == 0].any(), f"grouped attention, draw {draw}: a "
+              "row of no tokens did not read zeros")
+        worst = max(worst, float(err[r]))
+    return worst
+
+
+def say_ragged(phase: str, shapes) -> dict:
+    """``check_grouped_attention_ragged`` at each of ``shapes``, said."""
+    errs = {}
+    for shape in shapes:
+        window = shape[7] if len(shape) > 7 else None
+        err = check_grouped_attention_ragged(*shape)
+        errs["ragged_%dx%dx%d" % shape[:3]] = err
+        say(phase, f"grouped attention on ragged rows, {shape[0]} rows of "
+            f"up to {shape[1]} queries, {shape[2]} heads over {shape[3]}, "
+            f"pages of {shape[5]}, slots of {shape[5] * shape[6]}, window "
+            f"{window}: twenty draws at most {err:.2e} from the XLA spelling "
+            f"(allowed {TOL_RAGGED})")
+    return errs
+
+
+def grouped_operands() -> dict:
+    """``serving/grouped_attn_operand{queries=,rows=}``: the grouped
+    kernel's calls by how many queries a key/value head's operand held and
+    how many rows it was given; counted where the wrapper is traced."""
+    from paddle_tpu.profiler import registry
+
+    head = "serving/grouped_attn_operand{"
+    return {tuple(int(x.split("=")[1]) for x in name[len(head):-1].split(",")):
+            m["value"] for name, m in registry().snapshot().items()
+            if name.startswith(head)}
+
+
+def check_decode_operand(before: dict, groups, want_path: str,
+                         what: str) -> None:
+    """The ticks traced since ``before`` (``grouped_operands()`` then) gave
+    a decode row's ``G`` queries, for each ``G`` of ``groups``, one tile of
+    16 rows (bf16); off the chip nothing takes the kernel and nothing is
+    counted."""
+    new = {k: v - before.get(k, 0) for k, v in grouped_operands().items()
+           if v != before.get(k, 0)}
+    if want_path != "pallas":
+        check(not new, f"{what}: operands counted off the kernel: {new}")
+        return
+    for g in groups:
+        check({rows for (queries, rows) in new if queries == g} == {16},
+              f"{what}: a decode row's {g} queries a key/value head were not "
+              f"one tile of 16 rows: counted {new} "
+              "(serving/grouped_attn_operand)")
+    say(what, f"the ticks' grouped attention counted the operands {new} "
+        "(queries, rows a key/value head)")
+
+
 FALCON_REQUESTS = ((300, 24), (520, 20), (140, 40))
 
 
 def phase_falcon(cfg, num_slots: int, page_size: int, pages_per_slot: int,
                  chunk: int, ops_shape, attn_shapes, want_path: str,
-                 requests=FALCON_REQUESTS) -> dict:
+                 requests=FALCON_REQUESTS, ragged=()) -> dict:
     """Falcon-H1's pass (models/falcon_h1.py): the served state-space rule's
     kernels (``ssd_step``, ``ssd_chunk`` and the pass before them) against
     their references at ``ops_shape`` (heads, P, N, groups, decode rows,
     chunk tokens), which must go by ``want_path`` (on the chip the
     kernels'); grouped-query attention over pages without padded heads at
     ``attn_shapes`` (the cell's decode rows and a chunk row's piece) against
-    its XLA spelling; then a small model through the engine (a state a slot
-    **and** grouped K/V pages in every layer): what it emitted is the
-    float32 reference's (models/falcon_h1_reference.py; shortfalls in units
-    of a position's own spread of logits), and its ticks counted their
-    step, chunk, pass and attention by ``want_path``."""
+    its XLA spelling, and on ragged rows at ``ragged``
+    (``check_grouped_attention_ragged``'s arguments); then a small model
+    through the engine (a state a slot **and** grouped K/V pages in every
+    layer): what it emitted is the float32 reference's
+    (models/falcon_h1_reference.py; shortfalls in units of a position's own
+    spread of logits), its ticks counted their step, chunk, pass and
+    attention by ``want_path``, and a decode row's queries were one tile of
+    the kernel's operand (``check_decode_operand``)."""
     import dataclasses as dc
     import types
 
@@ -1743,6 +1872,8 @@ def phase_falcon(cfg, num_slots: int, page_size: int, pages_per_slot: int,
             f"spelling (allowed {TOL_RAGGED})")
         check(err <= TOL_RAGGED, f"grouped attention {shape} is {err:.2e} "
               f"from its XLA spelling (tol {TOL_RAGGED})")
+    errs.update(say_ragged("falcon", ragged))
+    operands = grouped_operands()
     paddle.seed(0)
     with paddle.LazyGuard():
         net = FalconH1(cfg)
@@ -1758,10 +1889,14 @@ def phase_falcon(cfg, num_slots: int, page_size: int, pages_per_slot: int,
         return short / sigma, mine
 
     forward.ref = types.SimpleNamespace(shortfall=shortfall)
-    return {**serve_against_reference(
+    out = serve_against_reference(
         "falcon", net, (num_slots, page_size, pages_per_slot, chunk),
         requests, forward, SSD_COUNTERS, want_path,
-        "a state a slot and grouped K/V pages in every layer"), **errs}
+        "a state a slot and grouped K/V pages in every layer")
+    check_decode_operand(
+        operands, [cfg.num_attention_heads // cfg.num_key_value_heads],
+        want_path, "falcon")
+    return {**out, **errs}
 
 
 LAGUNA_REQUESTS = ((300, 24), (520, 20), (140, 40))
@@ -1771,18 +1906,21 @@ LAGUNA_COUNTERS = {"attn": "serving/attn_calls{path=%s}"}
 
 def phase_laguna(cfg, num_slots: int, page_size: int, pages_per_slot: int,
                  chunk: int, attn_shapes, want_path: str,
-                 requests=LAGUNA_REQUESTS) -> dict:
+                 requests=LAGUNA_REQUESTS, ragged=()) -> dict:
     """Laguna's pass (models/laguna.py): grouped-query attention under a
     sliding window at ``attn_shapes`` (``check_grouped_attention``'s
     arguments, the window last: the cell's decode rows and a chunk row's
     piece at six and nine query heads a key/value head) against its XLA
-    spelling; then a small model through the engine (full and windowed
+    spelling, and on ragged rows at ``ragged``
+    (``check_grouped_attention_ragged``'s arguments: full and windowed);
+    then a small model through the engine (full and windowed
     layers of unlike query heads over one set of key/value heads, a gate a
     head, held experts; the windowed layers' pages freed behind the
     window): what it emitted is the float32 reference's
     (models/laguna_reference.py; shortfalls in units of a position's own
-    spread of logits), and its ticks counted their attention by
-    ``want_path``."""
+    spread of logits), its ticks counted their attention by ``want_path``,
+    and a decode row's queries were one tile of the kernel's operand in both
+    kinds of layer (``check_decode_operand``)."""
     import dataclasses as dc
     import types
 
@@ -1802,6 +1940,8 @@ def phase_laguna(cfg, num_slots: int, page_size: int, pages_per_slot: int,
             f"{TOL_RAGGED})")
         check(err <= TOL_RAGGED, f"windowed grouped attention {shape} is "
               f"{err:.2e} from its XLA spelling (tol {TOL_RAGGED})")
+    errs.update(say_ragged("laguna", ragged))
+    operands = grouped_operands()
     paddle.seed(0)
     with paddle.LazyGuard():
         net = Laguna(cfg)
@@ -1825,6 +1965,10 @@ def phase_laguna(cfg, num_slots: int, page_size: int, pages_per_slot: int,
     freed = registry().counter("serving/window_pages_freed").value - freed0
     check(freed > 0, "no page of the windowed layers was freed behind the "
           "window")
+    check_decode_operand(
+        operands, sorted({n // cfg.num_key_value_heads
+                          for n in cfg.num_attention_heads_per_layer}),
+        want_path, "laguna")
     return {**out, **errs, "window_pages_freed": freed}
 
 
@@ -2286,7 +2430,8 @@ def main() -> int:
             max_position_embeddings=1024),
         8, 16, 64, 128, (32, 128, 256, 2, 80, 256),
         [(80, 1, 20, 4, 128, 16, 88, 660), (4, 64, 20, 4, 128, 16, 88, 512)],
-        "pallas"))
+        "pallas",
+        ragged=[(80, 1, 20, 4, 128, 16, 88), (16, 64, 20, 4, 128, 16, 88)]))
     # Laguna: the windowed grouped attention at the cell's rows (38 decode
     # rows 7,000 deep and a chunk row's piece of 32 queries, 8 key/value
     # heads, six and nine query heads each, pages of 16, a window of 512),
@@ -2308,7 +2453,10 @@ def main() -> int:
         [(38, 1, 48, 8, 128, 16, 448, 7000, 512),
          (38, 1, 72, 8, 128, 16, 448, 7000, 512),
          (8, 32, 48, 8, 128, 16, 128, 1500, 512),
-         (8, 32, 72, 8, 128, 16, 128, 1500, 512)], "pallas"))
+         (8, 32, 72, 8, 128, 16, 128, 1500, 512)], "pallas",
+        ragged=[(38, 1, 48, 8, 128, 16, 448), (38, 1, 72, 8, 128, 16, 448, 512),
+                (16, 32, 48, 8, 128, 16, 128),
+                (16, 32, 72, 8, 128, 16, 128, 512)]))
     run("train", lambda: phase_train(cfg, micro=2, n_micro=6, steps=4))
     if len(jax.devices()) >= 4 and "train" not in failed:
         run("multichip", lambda: phase_multichip(

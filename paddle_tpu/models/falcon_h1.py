@@ -74,7 +74,8 @@ __all__ = ["FalconH1Config", "FalconH1", "falcon_h1_ragged_apply",
 _F32 = jnp.float32
 #: the most queries of a chunk row one call of the attention takes as a row
 #: (``falcon_h1_ragged_apply``'s ``attend``): the kernel keeps a row's
-#: scores for a key/value head's every query head in VMEM
+#: scores for a key/value head's every query head in VMEM (an operand of
+#: ``5 x 64`` rows; a decode row's 5 queries are one tile of 16)
 _ATTN_QUERIES = 64
 
 

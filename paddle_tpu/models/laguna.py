@@ -31,9 +31,10 @@ of kind ``"windowed_kv"``): the full layers' pages keep a slot's whole
 context, the windowed layers' pages live in a page space that holds only the
 window (``paged_cache.WindowSpace``, the one dots3's latent windows use), and
 query heads that differ by layer meet the same 8 key/value heads in
-``ops/paged_attention.grouped_paged_attention`` (6 or 9 heads the rows of one
-left operand). The expert layer holds a share of the routed experts
-(``distributed/moe.held_moe``), as Ling's and dots3's do.
+``ops/paged_attention.grouped_paged_attention`` (a key/value head's 6 or 9
+query heads' queries the rows of one left operand, laid end to end and padded
+once: a decode row's are one tile of 16). The expert layer holds a share of
+the routed experts (``distributed/moe.held_moe``), as Ling's and dots3's do.
 
 **The layers are unlike and the tick unrolls them** on ``tick.LayerwiseLM``,
 each layer's weights its own arrays. Like ``models/falcon_h1.py`` the forward
@@ -90,7 +91,8 @@ TICK_STATS = ("decode_rows", "chunk_tokens", "decode_keys", "chunk_keys",
               "expert_load_max_over_mean", "experts_touched_share",
               "held_rows_unaccounted")
 
-#: the most rows of a key/value head's left operand (query heads x queries)
+#: the most rows of a key/value head's left operand (``G t``: its query
+#: heads' queries end to end, which fill whole tiles at every piece's ``t``)
 #: one call of the attention takes: the kernel keeps a row's scores for every
 #: key/value head in VMEM; a chunk row attends in pieces of so many queries
 _ATTN_ROWS = 288

@@ -673,7 +673,8 @@ def _ragged_attention_pallas(q, k_pool, v_pool, page_table, pos0,
 # page is one contiguous 2 KVH x ps x D block that one copy fetches. Nothing
 # is padded: Falcon-H1's 4 heads of 128 are 2,048 B a token a layer. A
 # key/value head meets the ``G = NH / KVH`` query heads it serves in one
-# product: their queries lie side by side on the rows of its left operand.
+# product: their queries lie end to end on the rows of its left operand,
+# which is padded to whole tiles once, behind the last (ISSUE 61).
 # Writes go a page at a time (``latent_attention.latent_scatter``'s way: a
 # token's row is one sublane of a tile, and XLA:TPU re-lays a whole pool
 # around a scatter of such rows): the pages a tick touches are read, given
@@ -764,7 +765,11 @@ def grouped_paged_attention(q, pool, page_table, pos0, true_len, layer,
     """``ragged_paged_attention`` over a grouped pool ``[L, P, 2 KVH, ps,
     D]``: ``q`` [R, T, NH, D], query heads ``k G .. (k + 1) G - 1`` read
     key/value head ``k``; the same two spellings, picked and counted the
-    same way. ``window`` (static; ISSUE 57): a query at ``t`` sees keys ``t
+    same way. The kernel multiplies a key/value head by its ``G`` heads'
+    queries at once (``_grouped_operand``: ``G T`` rows padded once to whole
+    tiles, whatever ``G`` and ``T`` are) and counts the operand it was given
+    where it is traced (``serving/grouped_attn_operand{queries=,rows=}``).
+    ``window`` (static; ISSUE 57): a query at ``t`` sees keys ``t
     - window < j <= t``, and a row's table entries behind the window may be
     null (``serving.paged_cache.WindowSpace`` keeps only the window's
     pages). Both spellings then start a row's walk at the page (the kernel:
@@ -788,14 +793,18 @@ def grouped_paged_attention(q, pool, page_table, pos0, true_len, layer,
 
 
 def _grouped_kernel(pt_ref, pos0_ref, tl_ref, layer_ref, q_ref, kv_hbm, o_ref,
-                    buf, sem, slot_ref, m_ref, l_ref, acc_ref, *, group: int,
+                    buf, sem, slot_ref, m_ref, l_ref, acc_ref, *, t: int,
                     window=None):
     """Grid (r,): a step is row ``r``, which sees its ``kv_len`` positions;
     a page is one copy, K's heads and V's together (``_walk_pages``). The
-    body: ``q_ref`` ``[1, KVH, G Tp, D]`` is a key/value head's ``G`` query
-    heads of ``Tp`` queries each, row ``j Tp + i`` query ``i`` of head ``j``,
-    one left operand of its two products a block. Under a ``window`` the walk
-    starts at the block of the row's oldest visible key: row ``r`` visits
+    body: ``q_ref`` ``[1, KVH, M, D]`` (``_grouped_operand``) is a key/value
+    head's ``G`` query heads of ``t`` queries each laid end to end, row ``j
+    t + i`` query ``i`` of head ``j``, and padded once behind the last to
+    whole tiles (``M >= G t``): one left operand of its two products a
+    block. A pad row ``k >= G t`` is masked as query ``k mod t``, so it sees
+    keys as a live row does and meets no block of scores all masked; the
+    wrapper cuts it. Under a ``window`` the walk starts at the block of the
+    row's oldest visible key: row ``r`` visits
     blocks ``b0(r) .. b0(r) + nblk(r) - 1`` (a decode row under a window of
     512 and blocks of 256 positions two or three, whatever its context), and
     a score is kept where ``j <= t``, ``j > t - window`` and ``j`` is live; a
@@ -806,7 +815,6 @@ def _grouped_kernel(pt_ref, pos0_ref, tl_ref, layer_ref, q_ref, kv_hbm, o_ref,
     _, bp, kv2, ps, hd = buf.shape
     kvh = kv2 // 2
     m = q_ref.shape[2]
-    tp = m // group
     nps = pt_ref.shape[1]
     bt = bp * ps
     r = pl.program_id(0)
@@ -826,10 +834,9 @@ def _grouped_kernel(pt_ref, pos0_ref, tl_ref, layer_ref, q_ref, kv_hbm, o_ref,
         m_ref[:] = jnp.full_like(m_ref, _NEG_INF)
         l_ref[:] = jnp.zeros_like(l_ref)
         acc_ref[:] = jnp.zeros_like(acc_ref)
-        # the query of each row of a head's operand
-        qi = jax.lax.broadcasted_iota(jnp.int32, (group, tp, bt), 1).reshape(
-            1, m, bt)
-        qpos = pos0_ref[r] + qi
+        # the query of each row of a head's operand: row k is query k mod t
+        qpos = pos0_ref[r] + jax.lax.broadcasted_iota(
+            jnp.int32, (1, m, bt), 1) % t
 
         def block(b, slot, left):
             src = buf.at[slot]
@@ -874,8 +881,25 @@ def _grouped_kernel(pt_ref, pos0_ref, tl_ref, layer_ref, q_ref, kv_hbm, o_ref,
     o_ref[0] = acc_ref[:] / jnp.where(l == 0.0, 1.0, l)
 
 
+def _grouped_operand(q, kvh: int, dtype):
+    """``q`` [R, T, NH, D] as the kernel's left operands ``[R, KVH, M, D]``:
+    a key/value head's ``G`` query heads' ``T`` queries one after the other
+    (row ``j T + i`` query ``i`` of head ``j``), padded with zeros **once**,
+    behind the last, to whole sublane tiles of ``dtype``: a decode row's 5,
+    6 or 9 bf16 queries are one tile of 16, and a chunk's piece whose ``G T``
+    fills its tiles is not padded at all."""
+    r, t, nh, hd = q.shape
+    g = nh // kvh
+    pad = -(g * t) % (8 * _rows_per_word(dtype))
+    qk = jnp.transpose(q.astype(dtype).reshape(r, t, kvh, g, hd),
+                       (0, 2, 3, 1, 4)).reshape(r, kvh, g * t, hd)
+    return jnp.pad(qk, ((0, 0), (0, 0), (0, pad), (0, 0))) if pad else qk
+
+
 def _grouped_attention_pallas(q, pool, page_table, pos0, true_len, layer,
                               window=None):
+    from ..profiler import metrics
+
     r, t, nh, hd = q.shape
     kv2, ps = pool.shape[-3], pool.shape[-2]
     kvh = kv2 // 2
@@ -887,18 +911,14 @@ def _grouped_attention_pallas(q, pool, page_table, pos0, true_len, layer,
         raise NotImplementedError(
             f"grouped pages of {pool.dtype} under queries of {q.dtype}: the "
             "kernel multiplies pages as they lie")
-    rows = 8 * _rows_per_word(kv_dtype)
-    tp = -(-t // rows) * rows
-    # [R, KVH, G Tp, D]: a key/value head's query heads one after the other
-    qk = jnp.transpose(q.astype(kv_dtype).reshape(r, t, kvh, g, hd),
-                       (0, 2, 3, 1, 4))
-    if tp != t:
-        qk = jnp.pad(qk, ((0, 0),) * 3 + ((0, tp - t), (0, 0)))
-    m = g * tp
+    qk = _grouped_operand(q, kvh, kv_dtype)
+    m = qk.shape[2]
+    metrics.registry().counter(
+        "serving/grouped_attn_operand{queries=%d,rows=%d}" % (g * t, m)).add(1)
     block = pl.BlockSpec((1, kvh, m, hd),
                          lambda i, pt, p0, tl, ly: (i, 0, 0, 0))
     out = pl.pallas_call(
-        functools.partial(_grouped_kernel, group=g,
+        functools.partial(_grouped_kernel, t=t,
                           window=None if window is None else int(window)),
         name="grouped_paged_attn" if window is None else "grouped_window_attn",
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -917,8 +937,7 @@ def _grouped_attention_pallas(q, pool, page_table, pos0, true_len, layer,
             dimension_semantics=("arbitrary",)),
         interpret=_interpret(),
     )(page_table, pos0, true_len,
-      jnp.asarray(layer, jnp.int32).reshape(1),
-      qk.reshape(r, kvh, m, hd), pool)
-    out = out.reshape(r, kvh, g, tp, hd)[:, :, :, :t]
+      jnp.asarray(layer, jnp.int32).reshape(1), qk, pool)
+    out = out[:, :, :g * t].reshape(r, kvh, g, t, hd)
     return jnp.transpose(out, (0, 3, 1, 2, 4)).reshape(q.shape).astype(
         q.dtype)

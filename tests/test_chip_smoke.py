@@ -347,13 +347,14 @@ def test_falcon_rehearsal():
     out = chip_smoke.phase_falcon(
         cfg, 3, 4, 24, 8, (4, 8, 16, 2, 5, 128),
         [(3, 1, 4, 2, 16, 4, 24, 37)], "xla",
-        requests=((23, 12), (41, 10), (7, 16)))
+        requests=((23, 12), (41, 10), (7, 16)),
+        ragged=[(5, 1, 4, 2, 16, 4, 24)])
     assert out["tick_paths"] == {"step": ["xla"], "chunk": ["xla"],
                                  "prep": ["xla"], "attn": ["xla"]}
     assert max(out["step_y"], out["step_s"], out["chunk_y"],
                out["chunk_s"]) <= chip_smoke.TOL_GDN_OPS
     assert out["prep_step"] == out["prep_chunk"] == 0   # the spelling itself
-    assert out["attn_3x1"] == 0
+    assert out["attn_3x1"] == out["ragged_5x1x4"] == 0
     assert out["median"] <= out["worst"] <= chip_smoke.TOL_GDN_WORST
     assert out["weights_bytes"] == 2 * cfg.num_params()
 
@@ -369,12 +370,85 @@ def test_laguna_rehearsal():
     out = chip_smoke.phase_laguna(
         cfg, 3, 4, 24, 8, [(3, 1, 12, 2, 16, 4, 24, 37, 6),
                            (2, 8, 18, 2, 16, 4, 24, 40, 6)], "xla",
-        requests=((23, 8), (41, 6)))
+        requests=((23, 8), (41, 6)),
+        ragged=[(5, 1, 12, 2, 16, 4, 24), (4, 8, 18, 2, 16, 4, 24, 6)])
     assert out["tick_paths"] == {"attn": ["xla"]}
     assert out["attn_3x1x12"] == out["attn_2x8x18"] == 0
+    assert out["ragged_5x1x12"] == out["ragged_4x8x18"] == 0
     assert out["median"] <= out["worst"] <= chip_smoke.TOL_GDN_WORST
     assert out["weights_bytes"] == 2 * cfg.num_params()
     assert out["window_pages_freed"] > 0
+
+
+@pytest.mark.parametrize("t,heads,window", [
+    (1, 10, None), (1, 18, 300), (8, 10, None), (8, 18, 300)])
+def test_the_ragged_check_of_the_grouped_kernel(attention_spelling,
+                                                monkeypatch, t, heads,
+                                                window):
+    """``check_grouped_attention_ragged`` with the kernel interpreted: slots
+    of 640 positions in pages of 4, so every edge of its list (a page's, a
+    block's at 256 and 512, the capacity) is some row's last position; the
+    kernel passes, and a kernel that hands a key/value head's queries out
+    one row on is caught and its worst row named."""
+    from paddle_tpu.ops import paged_attention as pa
+
+    attention_spelling("pallas")
+    shape = (14, t, heads, 2, 16, 4, 160, window)
+    err = chip_smoke.check_grouped_attention_ragged(*shape, draws=2)
+    assert 0 < err <= chip_smoke.TOL_RAGGED
+    operand = pa._grouped_operand
+    monkeypatch.setattr(pa, "_grouped_operand", lambda *a: jnp.roll(
+        operand(*a), 1, axis=2))
+    with pytest.raises(chip_smoke.SmokeFailure, match=r"draw 0 .* row \d+ "):
+        chip_smoke.check_grouped_attention_ragged(*shape, draws=1)
+
+
+def test_ragged_rows_hold_every_edge_of_the_walk():
+    import numpy as np
+
+    rng = np.random.default_rng(0)
+    for window, t in ((None, 1), (512, 32)):
+        last, pos0, tl, table = chip_smoke.ragged_rows(rng, 38, t, 16, 448,
+                                                       window)
+        assert {1, 15, 16, 255, 256, 257, 511, 512, 513, 7168} <= set(
+            last.tolist())
+        empty = np.flatnonzero(last == 0)
+        assert (np.diff(empty) == 1).any() and not tl[empty].any()
+        assert ((pos0 + tl == last) & (tl <= t)).all()
+        assert (t == 1) or ((tl < np.minimum(last, t)) & (tl > 0)).any()
+        ids = table[table > 0]
+        assert len(set(ids.tolist())) == len(ids)       # a page, one owner
+        pages = (table > 0).sum(axis=1)
+        first = 0 if window is None else np.maximum(pos0 - window + 1, 0) // 16
+        assert (pages == -(-last // 16) - np.where(last > 0, first, 0)).all()
+
+
+@pytest.mark.parametrize("counted,groups,path,fault", [
+    ({(5, 16): 3, (320, 320): 3}, [5], "pallas", None),
+    ({(6, 16): 2, (9, 16): 4, (192, 192): 1}, [6, 9], "pallas", None),
+    # a decode row's heads a tile each: the layout of before ISSUE 61
+    ({(5, 80): 3, (320, 320): 3}, [5], "pallas", "not one tile"),
+    ({(6, 16): 2, (9, 144): 4}, [6, 9], "pallas", "not one tile"),
+    # no decode row's call counted at all
+    ({(192, 192): 1}, [6], "pallas", "not one tile"),
+    # off the chip nothing takes the kernel, and nothing may be counted
+    ({}, [5], "xla", None), ({(5, 16): 1}, [5], "xla", "off the kernel"),
+])
+def test_the_operand_check_holds_a_decode_rows_queries_to_one_tile(
+        counted, groups, path, fault):
+    """``check_decode_operand``: what phases ``falcon`` and ``laguna`` hold
+    ``serving/grouped_attn_operand{queries=,rows=}`` to."""
+    from paddle_tpu.profiler import registry
+
+    before = chip_smoke.grouped_operands()
+    for (queries, rows), n in counted.items():
+        registry().counter("serving/grouped_attn_operand{queries=%d,rows=%d}"
+                           % (queries, rows)).add(n)
+    if fault is None:
+        chip_smoke.check_decode_operand(before, groups, path, "test")
+    else:
+        with pytest.raises(chip_smoke.SmokeFailure, match=fault):
+            chip_smoke.check_decode_operand(before, groups, path, "test")
 
 
 @pytest.mark.parametrize("counted,fault", [

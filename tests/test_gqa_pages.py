@@ -10,6 +10,7 @@ import numpy as np
 import pytest
 
 from paddle_tpu.ops import paged_attention as pa
+from paddle_tpu.profiler import registry
 from paddle_tpu.serving.paged_cache import (GroupedPools, Pools, StatePools,
                                             page_pool)
 
@@ -244,3 +245,116 @@ def test_a_multi_head_spec_builds_the_pool_it_built_before():
     pool = page_pool({"kind": "kv", "layers": 2, "heads": 4, "head_dim": 8},
                      10, 4, 2, 4, 8, jnp.float32, False, False)
     assert isinstance(pool.pools, Pools)
+
+
+# --------------------------------------------------------------------------
+# the kernel's operand (ISSUE 61): a key/value head's G query heads' t
+# queries laid end to end and padded once, ragged rows in one call
+# --------------------------------------------------------------------------
+_BT, _NPS = 32, 12              # blocks of 4 pages, 3 blocks a slot of 96
+#: last positions on every edge a walk has: 1, a page's (ps - 1, ps), a
+#: block's (31, 32, 33 and 63, 64, 65), the slot's capacity; rows of no
+#: tokens first, between live rows and two in a row
+_RAGGED = [0, 1, 7, 0, 8, 31, 32, 0, 0, 33, 63, 64, 65, 96, 40]
+
+
+def _ragged_rows(t, window, seed):
+    """The rows of ``_RAGGED`` as decode rows (``t`` 1) or chunk rows whose
+    queries are the last ``min(n, t)`` positions, the last row two short of
+    ``t`` besides; shuffled page ids, and under a window a null table entry
+    for every page wholly behind the row's oldest visible key."""
+    lens = np.asarray(_RAGGED)
+    tl = np.minimum(lens, t)
+    tl[-1] = max(1, t - 2)
+    pos0 = lens - tl
+    pool, table, _, _ = _filled(seed, 1, len(lens), _NPS, 2, 16, _RAGGED)
+    if window is not None:
+        behind = np.maximum(pos0 - (window - 1), 0) // PS
+        table = jnp.where(np.arange(_NPS)[None, :] < behind[:, None], 0,
+                          table)
+    return pool, table, jnp.asarray(pos0, jnp.int32), jnp.asarray(
+        tl, jnp.int32)
+
+
+@pytest.mark.parametrize("window", [None, 21], ids=["full", "window"])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("t", [1, 3, 16, 32])
+@pytest.mark.parametrize("g", [2, 5, 6, 9])
+def test_ragged_rows_of_every_group_against_the_spelling(monkeypatch, g, t,
+                                                         dtype, window):
+    monkeypatch.setattr(pa, "_BLOCK_TOKENS", _BT)
+    pool, table, pos0, tl = _ragged_rows(t, window, 100 * g + t)
+    q = jnp.asarray(np.random.default_rng(g + t).standard_normal(
+        (len(_RAGGED), t, 2 * g, 16)), dtype)
+    got = pa.grouped_paged_attention(q, pool.astype(dtype), table, pos0, tl,
+                                     0, impl="pallas", window=window)
+    want = pa.grouped_paged_attention(
+        q, jnp.nan_to_num(pool).astype(dtype), table, pos0, tl, 0,
+        impl="xla", window=window)
+    assert got.dtype == dtype and bool(jnp.all(jnp.isfinite(got)))
+    live = np.arange(t)[None, :] < np.asarray(tl)[:, None]
+    tol = 2e-5 if dtype == jnp.float32 else 3e-2
+    err = np.abs(np.asarray(got, np.float32)
+                 - np.asarray(want, np.float32)).max(axis=(2, 3))
+    worst = np.unravel_index(np.argmax(np.where(live, err, 0)), err.shape)
+    assert err[live].max() <= tol, (
+        f"row {worst[0]} ({_RAGGED[worst[0]]} positions), query {worst[1]}: "
+        f"{err[worst]:.2e}")
+    assert not np.asarray(got, np.float32)[np.asarray(tl) == 0].any()
+
+
+@pytest.mark.parametrize("g,t,dtype,rows", [
+    (5, 1, jnp.bfloat16, 16), (6, 1, jnp.bfloat16, 16),
+    (9, 1, jnp.bfloat16, 16), (5, 1, jnp.float32, 8),
+    (9, 1, jnp.float32, 16), (5, 3, jnp.bfloat16, 16),
+    # a chunk's piece fills its tiles: as many rows as queries, as before
+    (5, 64, jnp.bfloat16, 320), (6, 32, jnp.bfloat16, 192),
+    (9, 32, jnp.bfloat16, 288), (2, 16, jnp.float32, 32)])
+def test_the_operand_is_a_rows_queries_padded_once(g, t, dtype, rows):
+    """``ceil(G t / tile) tile`` rows a key/value head, not ``G ceil(t /
+    tile) tile``: counted where the wrapper is traced, and the shape of the
+    kernel's operand, scratch and result."""
+    kvh, d, r = 2, 16, 3
+    args = (jax.ShapeDtypeStruct((r, t, kvh * g, d), dtype),
+            jax.ShapeDtypeStruct((1, 9, 2 * kvh, PS, d), dtype),
+            jax.ShapeDtypeStruct((r, 4), jnp.int32),
+            jax.ShapeDtypeStruct((r,), jnp.int32),
+            jax.ShapeDtypeStruct((r,), jnp.int32))
+    counted = registry().counter(
+        "serving/grouped_attn_operand{queries=%d,rows=%d}" % (g * t, rows))
+    before = counted.value
+    jaxpr = jax.make_jaxpr(lambda *a: pa.grouped_paged_attention(
+        *a, 0, impl="pallas"))(*args)
+    assert counted.value == before + 1
+    call, = [e for e in jaxpr.eqns if e.primitive.name == "pallas_call"]
+    assert call.outvars[0].aval.shape == (r, kvh, rows, d)
+    assert call.invars[-2].aval.shape == (r, kvh, rows, d)
+
+
+@pytest.mark.parametrize("window", [None, 21], ids=["full", "window"])
+@pytest.mark.parametrize("g,t", [(5, 1), (9, 1), (5, 3)])
+def test_what_a_pad_row_holds_reaches_no_live_head(monkeypatch, g, t, window):
+    """The operand's rows behind the last query are the wrapper's to fill
+    and to cut: NaN and infinities planted there leave every live head's
+    result as it was bit for bit."""
+    monkeypatch.setattr(pa, "_BLOCK_TOKENS", _BT)
+    pool, table, pos0, tl = _ragged_rows(t, window, 7)
+    q = jnp.asarray(np.random.default_rng(12).standard_normal(
+        (len(_RAGGED), t, 2 * g, 16)), jnp.float32)
+    attend = lambda: pa.grouped_paged_attention(            # noqa: E731
+        q, pool, table, pos0, tl, 0, impl="pallas", window=window)
+    clean = attend()
+    operand = pa._grouped_operand
+
+    def planted(q, kvh, dtype):
+        qk = operand(q, kvh, dtype)
+        assert qk.shape[2] > g * t
+        bad = jnp.asarray([jnp.nan, jnp.inf, -jnp.inf], dtype)
+        fill = bad[jnp.arange(qk.shape[2] - g * t) % 3]
+        return qk.at[:, :, g * t:].set(fill[None, None, :, None])
+
+    monkeypatch.setattr(pa, "_grouped_operand", planted)
+    got = attend()
+    assert bool(jnp.all(jnp.isfinite(got)))
+    np.testing.assert_array_equal(got, clean)
